@@ -61,16 +61,33 @@ def detect_labels() -> dict[str, str]:
     return labels
 
 
+def detect_chips(dev_dir: str = "/dev") -> int:
+    """TPU chips this host exposes as device nodes: `accel<N>` entries,
+    else numbered entries under `vfio/` (ray: python/ray/_private/
+    accelerators/tpu.py counts both styles)."""
+    try:
+        n = sum(d.startswith("accel") for d in os.listdir(dev_dir))
+    except OSError:
+        return 0
+    if n == 0:
+        try:
+            n = sum(d.isdigit()
+                    for d in os.listdir(os.path.join(dev_dir, "vfio")))
+        except OSError:
+            pass
+    return n
+
+
 def detect_resources() -> dict[str, float]:
-    """Best-effort host resource detection (ray: python/ray/_private/
-    accelerators/tpu.py detects chips via env + metadata)."""
+    """Best-effort host resource detection.  RAY_TPU_CHIPS is the
+    outside override: the node is TOLD its chip count (tests, hosts
+    whose chips are not device nodes)."""
     res: dict[str, float] = {"CPU": float(os.cpu_count() or 1)}
     tpu = os.environ.get("RAY_TPU_CHIPS")
     if tpu is not None:
         n = float(tpu)
     else:
-        n = float(len([d for d in os.listdir("/dev")
-                       if d.startswith("accel")])) if os.path.isdir("/dev") else 0.0
+        n = float(detect_chips())
         if n == 0 and os.environ.get("TPU_NAME"):
             n = 1.0
     if n > 0:
@@ -82,6 +99,24 @@ def detect_resources() -> dict[str, float]:
     except Exception:  # noqa: BLE001
         pass
     return res
+
+
+def device_worker_env(env: dict[str, str], chips_detected: bool) -> None:
+    """The jax-facing part of the device worker's environment (set
+    before it starts, so nothing here imports jax)."""
+    if chips_detected:
+        # jax raises at backend init when a platform NAMED here cannot
+        # come up: on a node that found chips, the device worker's
+        # first device call fails with that error instead of running
+        # on the CPU under a TPU lease.  A node that was TOLD its chip
+        # count (resources= / RAY_TPU_CHIPS) keeps the caller's choice.
+        env["JAX_PLATFORMS"] = "tpu,cpu"
+    # Compile cache: placed from outside when the variable is set, else
+    # one fixed in-tree path (the path is part of the cache key, so it
+    # never derives from a temp name, a pid or the time).
+    env.setdefault("JAX_COMPILATION_CACHE_DIR", os.path.join(
+        os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))), ".jax_cache"))
 
 
 @dataclass
@@ -126,6 +161,11 @@ class NodeAgent:
         self.node_id = node_id or NodeID.from_random().hex()
         self.host = host
         self.resources = dict(resources) if resources else detect_resources()
+        # Chips this node FOUND (not told about through `resources=` /
+        # RAY_TPU_CHIPS): its device worker must come up on them.
+        self._chips_detected = (
+            not resources and "RAY_TPU_CHIPS" not in os.environ
+            and self.resources.get("TPU", 0) > 0)
         self.labels = {**detect_labels(), **(labels or {}),
                        "ray_tpu.io/node-id": self.node_id}
         self.available = dict(self.resources)
@@ -333,7 +373,9 @@ class NodeAgent:
                "RAY_TPU_PUB_ADDR": self.pub_addr,
                "RAY_TPU_STORE_NAME": self.store.shm_name if self.store else "",
                "RAY_TPU_IS_DEVICE_WORKER": "1" if device_worker else "0"}
-        if not device_worker:
+        if device_worker:
+            device_worker_env(env, self._chips_detected)
+        else:
             # Plain workers must never grab the TPU chip
             # (ray analog: CUDA_VISIBLE_DEVICES isolation in worker_pool).
             env["JAX_PLATFORMS"] = "cpu"

@@ -65,7 +65,40 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         use_flash = (on_tpu and causal and q.shape[1] == k.shape[1]
                      and q.shape[1] % 128 == 0 and q.shape[-1] % 128 == 0)
     if use_flash:
-        from ray_tpu.ops.flash_attention import flash_attention
-
-        return flash_attention(q, k, v, causal=causal)
+        return _flash_per_shard(q, k, v, causal)
     return xla_attention(q, k, v, causal=causal, q_offset=q_offset)
+
+
+def _flash_per_shard(q, k, v, causal: bool):
+    """The Pallas kernel; under an ambient multi-device mesh, one call
+    per shard.  GSPMD cannot partition a Mosaic kernel (jax refuses at
+    lowering: "wrap the call in a shard_map"), so the call is made
+    manual here: batch over the data axes, heads over "tensor"
+    (contiguous head chunks keep each GQA group on one shard), the
+    sequence whole (a seq-sharded layout takes ring attention)."""
+    import math
+
+    from jax.sharding import PartitionSpec as P
+
+    from ray_tpu.ops.flash_attention import flash_attention
+    from ray_tpu.parallel import sharding as sh
+
+    fn = functools.partial(flash_attention, causal=causal)
+    mesh = sh.current_abstract_mesh()
+    auto = set(mesh.axis_names) - sh._manual_axes(mesh) if mesh else set()
+    if all(mesh.shape[a] == 1 for a in auto):
+        return fn(q, k, v)
+
+    def spec(x, heads: str):
+        entries = list(sh.auto_axes_spec(mesh, ("batch", None, heads, None)))
+        for i, e in enumerate(entries):
+            names = (e,) if isinstance(e, str) else (e or ())
+            if x.shape[i] % math.prod(mesh.shape[a] for a in names):
+                entries[i] = None       # not divisible: stays whole
+        return P(*entries)
+
+    q_spec = spec(q, "heads")
+    return jax.shard_map(
+        fn, mesh=mesh, axis_names=auto, check_vma=False,
+        in_specs=(q_spec, spec(k, "kv_heads"), spec(v, "kv_heads")),
+        out_specs=q_spec)(q, k, v)

@@ -336,8 +336,15 @@ def _reshape_heads(x, hkv, n_rep):
 
 
 def _interpret() -> bool:
-    """Interpret mode off-TPU so CPU tests exercise the same kernel code."""
-    return jax.default_backend() != "tpu"
+    """Compiled on a TPU; interpreted on the CPU so tests exercise the
+    same kernel code.  Any other backend is an error — never a silent
+    interpreter run."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            "Pallas TPU kernels run compiled on 'tpu' and interpreted "
+            f"on 'cpu'; the default backend is {backend!r}")
+    return backend == "cpu"
 
 
 # ---------------------------------------------------------------- dispatch

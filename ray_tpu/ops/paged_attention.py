@@ -39,15 +39,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ray_tpu._private.jax_compat import install as _jax_compat
-
-_jax_compat()
+from ray_tpu.ops.flash_attention import _interpret
 
 NEG_INF = -1e30
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _kernel(table_ref, pos_ref, ts_ref,       # scalar prefetch

@@ -69,11 +69,14 @@ def create_mesh(config: MeshConfig | None = None,
     mesh indices physically adjacent (contiguous rings per axis)."""
     devices = devices if devices is not None else jax.devices()
     shape = mesh_shape_for(len(devices), config)
-    try:
+    if devices[0].platform == "tpu":
         from jax.experimental import mesh_utils
 
+        # A shape the slice's ICI topology cannot host raises here; a
+        # plain reshape would hide it behind non-adjacent rings.
         dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-    except Exception:  # noqa: BLE001 - CPU/virtual devices: plain reshape
+    else:
+        # CPU/virtual devices carry no coordinates: plain reshape.
         dev_array = np.array(devices).reshape(shape)
     return Mesh(dev_array, AXES)
 

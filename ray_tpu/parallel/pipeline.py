@@ -22,13 +22,8 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-from ray_tpu._private.jax_compat import install as _jax_compat
-
-_jax_compat()
-from jax import shard_map  # noqa: E402 - gated above on older jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
+from jax import lax, shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
 def pipeline_apply(stage_fn: Callable, stage_params, microbatches,

@@ -22,10 +22,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu._private.jax_compat import install as _jax_compat
-
-_jax_compat()
-
 
 def _repeat_kv(x: jnp.ndarray, n_rep: int) -> jnp.ndarray:
     if n_rep == 1:

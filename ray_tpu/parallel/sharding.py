@@ -12,10 +12,6 @@ from __future__ import annotations
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ray_tpu._private.jax_compat import install as _jax_compat
-
-_jax_compat()
-
 # logical axis -> mesh axes (None = replicated).
 # fsdp shards the *largest* param axis; tensor shards the Megatron axis.
 LOGICAL_RULES: dict[str, tuple | str | None] = {
@@ -132,18 +128,24 @@ def with_sharding_constraint(x, logical_axes: tuple[str | None, ...],
         mesh = current_abstract_mesh()
         if mesh is None:
             return x
-    manual = _manual_axes(mesh)
-    if manual and set(mesh.axis_names) <= manual:
+    if set(mesh.axis_names) <= _manual_axes(mesh):
         # Fully-manual shard_map: layout is already explicit per-shard
         # and constraints are meaningless there.
         return x
-    spec = logical_spec(logical_axes, rules)
-    # Inside a *partially* manual shard_map (e.g. the pipeline: "stage"
-    # manual, the rest auto) constraints still steer GSPMD over the auto
-    # axes — just strip the manual ones from the spec.
-    spec = P(*[_prune(mesh, s, exclude=manual) for s in spec])
+    spec = auto_axes_spec(mesh, logical_axes, rules)
     return jax.lax.with_sharding_constraint(
         x, NamedSharding(mesh, spec) if isinstance(mesh, Mesh) else spec)
+
+
+def auto_axes_spec(mesh, logical_axes: tuple[str | None, ...],
+                   rules: dict | None = None) -> P:
+    """logical_spec on the axes of `mesh` that GSPMD still owns.  Inside
+    a *partially* manual shard_map (e.g. the pipeline: "stage" manual,
+    the rest auto) layouts still apply over the auto axes — the manual
+    ones are stripped from the spec."""
+    manual = _manual_axes(mesh)
+    return P(*[_prune(mesh, s, exclude=manual)
+               for s in logical_spec(logical_axes, rules)])
 
 
 def _manual_axes(mesh) -> set:

@@ -11,6 +11,7 @@ import asyncio
 import concurrent.futures
 import dataclasses
 import inspect
+import os
 import time
 from typing import Any
 
@@ -309,6 +310,9 @@ class Replica:
                "max_ongoing": self._max_ongoing,
                "max_queued": self._max_queued,
                "num_rejected": self._num_rejected,
+               # Which process hosts this replica (TPU replicas share
+               # the node's device worker).
+               "pid": os.getpid(),
                # Recent slot-wait percentiles (ms) — the queue-wait SLO
                # signal the controller's scaling loop consumes for
                # deployments that report no engine stats.

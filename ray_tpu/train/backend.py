@@ -77,7 +77,7 @@ def _jax_distributed_init(coordinator: str, num_processes: int,
 
     from jax._src import distributed as jdist
 
-    orig = jdist.xla_extension.get_distributed_runtime_client
+    orig = jdist._jax.get_distributed_runtime_client
 
     def _factory(addr, node_id, **kw):
         kw["missed_heartbeat_callback"] = lambda *a: _logging.getLogger(
@@ -88,13 +88,13 @@ def _jax_distributed_init(coordinator: str, num_processes: int,
         kw["shutdown_timeout"] = 5
         return orig(addr, node_id, **kw)
 
-    jdist.xla_extension.get_distributed_runtime_client = _factory
+    jdist._jax.get_distributed_runtime_client = _factory
     try:
         jax.distributed.initialize(coordinator_address=coordinator,
                                    num_processes=num_processes,
                                    process_id=process_id)
     finally:
-        jdist.xla_extension.get_distributed_runtime_client = orig
+        jdist._jax.get_distributed_runtime_client = orig
     return True
 
 

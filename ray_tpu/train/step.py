@@ -19,11 +19,8 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ray_tpu._private.jax_compat import install as _jax_compat
 from ray_tpu.models import llama
 from ray_tpu.parallel.sharding import logical_sharding, param_shardings
-
-_jax_compat()
 
 
 def model_module(cfg: llama.LlamaConfig):

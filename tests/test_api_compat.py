@@ -202,7 +202,7 @@ def test_runtime_context_actor_name(ray_shared):
     ray_tpu.kill(a)
 
 
-def test_exception_taxonomy(ray_shared):
+def test_exception_hierarchy(ray_shared):
     """Reference-spelled exception names are the SAME classes (ray:
     exceptions.py), and the typed subclasses come from real raise
     sites: an except on either spelling catches both."""

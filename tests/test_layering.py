@@ -19,14 +19,10 @@ PKG = os.path.join(REPO, "ray_tpu")
 
 LIBRARY_LAYERS = ("data", "train", "tune", "serve", "rl", "collective")
 
-# The only runtime-internal modules library code may import, and why:
-#   jax_compat — environment shim (version-gates missing jax APIs); it
-#     touches jax, not the runtime, and must run before any jax use.
-# Everything else must come through public surfaces: the ray_tpu core
+# The runtime-internal modules library code may import: none.
+# Everything must come through public surfaces: the ray_tpu core
 # API, ray_tpu.profiling, ray_tpu.failpoints, ray_tpu.exceptions, ...
-SANCTIONED = {
-    "ray_tpu._private.jax_compat",
-}
+SANCTIONED: set[str] = set()
 
 
 def _imports_of(path: str):
@@ -66,8 +62,8 @@ def _violations():
                         continue
                     if mod in SANCTIONED:
                         continue
-                    # `from ray_tpu._private.jax_compat import install`
-                    # yields "...jax_compat.install" — still sanctioned.
+                    # a from-import of a sanctioned module's name
+                    # yields "<module>.<name>" — still sanctioned.
                     if any(mod.startswith(s + ".") for s in SANCTIONED):
                         continue
                     out.append(f"{rel}:{lineno}: imports {mod}")

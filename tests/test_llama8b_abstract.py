@@ -11,13 +11,6 @@ import pytest
 
 
 def test_llama3_8b_sharded_step_traces_over_64_device_mesh():
-    from ray_tpu._private.jax_compat import is_legacy
-
-    if is_legacy():
-        import pytest as _pytest
-
-        _pytest.skip("legacy jax: no AxisType/use_abstract_mesh "
-                     "(abstract 64-device tracing needs current jax)")
     import jax
     import jax.numpy as jnp
     from jax.sharding import AbstractMesh

@@ -12,17 +12,6 @@ import pytest
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 8, reason="needs the 8-device test platform")
 
-from ray_tpu._private.jax_compat import is_legacy  # noqa: E402
-
-# Partial-AUTO shard_map (stage manual, other axes GSPMD-automatic)
-# lowers a PartitionId op the legacy build's CPU SPMD partitioner does
-# not implement ("PartitionId instruction is not supported for SPMD
-# partitioning") — a backend gap, not a framework one; gate, don't
-# emulate.
-_needs_partial_auto = pytest.mark.skipif(
-    is_legacy(), reason="legacy jax: CPU SPMD partitioner cannot lower "
-    "partial-auto shard_map (PartitionId unimplemented)")
-
 
 def test_moe_forward_and_loss():
     from ray_tpu.models import moe
@@ -133,7 +122,6 @@ def test_pipeline_matches_sequential():
                                rtol=1e-5, atol=1e-5)
 
 
-@_needs_partial_auto
 def test_pipelined_llama_loss_matches_sequential():
     """llama.pipelined_loss_fn over a stage x data mesh must reproduce the
     plain loss_fn numerics (same params, same batch) — and its gradients
@@ -175,7 +163,6 @@ def test_pipelined_llama_loss_matches_sequential():
         assert rel < 1e-4, f"{ka}: grad rel err {rel}"
 
 
-@_needs_partial_auto
 @pytest.mark.parametrize("mesh_kw", [
     dict(stage=2, fsdp=2, data=2),      # PP x FSDP x DP
     dict(stage=2, data=2, tensor=2),    # PP x DP x TP
@@ -223,7 +210,6 @@ def test_pipelined_loss_composes_with_fsdp_tensor(mesh_kw):
         assert rel < 1e-4, f"{ka}: grad rel err {rel}"
 
 
-@_needs_partial_auto
 def test_train_step_composes_pp_fsdp():
     """Full sharded_train_step on {stage:2, fsdp:2, data:2}: the loss
     decreases and no NotImplementedError fires (the lifted
@@ -251,7 +237,6 @@ def test_train_step_composes_pp_fsdp():
     assert losses[-1] < losses[0], losses
 
 
-@_needs_partial_auto
 def test_train_step_uses_pipeline_on_stage_mesh():
     """sharded_train_step on a stage-bearing mesh wires the GPipe trunk
     automatically and the loss decreases over steps."""

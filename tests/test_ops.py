@@ -49,6 +49,35 @@ class TestFlashAttention:
         o_ref = xla_attention(q, k, v, causal=True)
         np.testing.assert_allclose(o, o_ref, atol=2e-2, rtol=1e-2)
 
+    @pytest.mark.parametrize("hkv", [2, 1], ids=["gqa", "mqa-unsplit"])
+    def test_flash_runs_per_shard_under_a_mesh(self, hkv):
+        """Under an ambient multi-device mesh the dispatcher makes the
+        kernel call per shard (GSPMD cannot partition a Mosaic kernel):
+        batch over data x fsdp, heads over tensor — a kv-head count the
+        tensor axis does not divide stays whole.  Values and gradients
+        match the unsharded XLA reference."""
+        from ray_tpu.parallel.mesh import MeshConfig, create_mesh
+
+        mesh = create_mesh(MeshConfig(data=2, fsdp=2, tensor=2),
+                           devices=jax.devices()[:8])
+        q, k, v = _qkv(b=4, s=128, hq=4, hkv=hkv)
+
+        def loss(att):
+            return lambda q, k, v: (att(q, k, v) ** 2).sum()
+
+        flash = lambda q, k, v: attention(q, k, v, impl="flash")  # noqa: E731
+        with jax.set_mesh(mesh):
+            txt = jax.jit(flash).lower(q, k, v).as_text()
+            o = jax.jit(flash)(q, k, v)
+            g = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(q, k, v)
+        assert "shard_map" in txt or "manual" in txt.lower()
+        np.testing.assert_allclose(o, xla_attention(q, k, v), atol=2e-2,
+                                   rtol=1e-2)
+        g_ref = jax.grad(loss(xla_attention), argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(g, g_ref):
+            rel = jnp.abs(a - b).max() / (jnp.abs(b).max() + 1e-9)
+            assert rel < 5e-3, f"grad rel err {rel}"
+
     def test_dispatcher_fallback_short_seq(self):
         # s=64 not a multiple of 128 → XLA path; just must run + match.
         q, k, v = _qkv(s=64, d=64)
@@ -100,11 +129,6 @@ class TestFlashAttention:
         assert 0 < n_flash < n_nothing, (n_flash, n_nothing)
 
 
-@pytest.mark.skipif(
-    __import__("ray_tpu._private.jax_compat",
-               fromlist=["is_legacy"]).is_legacy(),
-    reason="legacy jax: shard_map+ppermute over a partial-auto mesh "
-    "hard-aborts the CPU backend's SPMD compile (AllReduce promotion)")
 class TestRingAttention:
     @pytest.fixture
     def mesh(self):

@@ -104,12 +104,6 @@ def _engine(paged: bool, **kw):
 
 
 def test_paged_engine_matches_dense_greedy():
-    from ray_tpu._private.jax_compat import is_legacy
-
-    if is_legacy():
-        pytest.skip("legacy jax: dense-vs-paged greedy tokens diverge "
-                    "on this build's CPU lowering (kernel-level tests "
-                    "above still pin the paged path's numerics)")
     dense = _engine(False)
     paged = _engine(True, page_size=16)
     try:

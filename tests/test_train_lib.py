@@ -265,11 +265,6 @@ class TestHostCollective:
         assert result.metrics["v"] == 5.0
 
 
-@pytest.mark.skipif(
-    __import__("ray_tpu._private.jax_compat",
-               fromlist=["is_legacy"]).is_legacy(),
-    reason="legacy jax: the CPU backend has no multiprocess "
-    "computations (jax.distributed global mesh needs current jax)")
 class TestMultiHostJax:
     def test_jax_distributed_global_mesh_psum(self, ray_shared, tmp_path):
         """Two train workers = two jax processes forming ONE global mesh

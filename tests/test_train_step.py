@@ -37,24 +37,11 @@ def _run(mesh_cfg, n_steps=3, cfg=CFG):
     return losses
 
 
-
-from ray_tpu._private.jax_compat import is_legacy as _legacy_jax
-
-# Legacy-jax gates (this image's 0.4.x graft): cross-layout GSPMD
-# numerics drift past tolerance on the old CPU backend, the seq layout
-# rides partial-auto shard_map (PartitionId unimplemented there), and
-# the dryrun's pipeline section hits the same lowering gap.  All three
-# run (and must pass) on a current-jax container.
-_needs_current_jax = pytest.mark.skipif(
-    _legacy_jax(), reason="legacy jax: CPU GSPMD lowering drift / "
-    "partial-auto shard_map unimplemented")
-
 class TestShardedTrainStep:
     def test_loss_decreases_dp(self):
         losses = _run(MeshConfig(data=8))
         assert losses[-1] < losses[0]
 
-    @_needs_current_jax
     def test_layouts_agree(self):
         ref = _run(MeshConfig(data=8))
         for mc in (MeshConfig(data=2, fsdp=4),
@@ -64,7 +51,6 @@ class TestShardedTrainStep:
             np.testing.assert_allclose(got, ref, rtol=2e-3,
                                        err_msg=f"{mc} diverged from dp")
 
-    @_needs_current_jax
     def test_ring_attention_layout_agrees(self):
         ref = _run(MeshConfig(data=8))
         import dataclasses
@@ -93,7 +79,6 @@ class TestGraftEntry:
         out = jax.jit(fn)(*args)
         assert out.shape[-1] == 2048
 
-    @_needs_current_jax
     def test_dryrun_multichip(self):
         import __graft_entry__ as g
 
