@@ -71,34 +71,17 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
 def _flash_per_shard(q, k, v, causal: bool):
     """The Pallas kernel; under an ambient multi-device mesh, one call
-    per shard.  GSPMD cannot partition a Mosaic kernel (jax refuses at
-    lowering: "wrap the call in a shard_map"), so the call is made
-    manual here: batch over the data axes, heads over "tensor"
-    (contiguous head chunks keep each GQA group on one shard), the
-    sequence whole (a seq-sharded layout takes ring attention)."""
-    import math
-
-    from jax.sharding import PartitionSpec as P
-
+    per shard (jax refuses a Mosaic kernel under GSPMD at lowering:
+    "wrap the call in a shard_map").  The layout — what splits over
+    which mesh axes, and the GQA rule — is parallel/sharding's."""
     from ray_tpu.ops.flash_attention import flash_attention
-    from ray_tpu.parallel import sharding as sh
+    from ray_tpu.parallel.sharding import attention_shard_specs
 
     fn = functools.partial(flash_attention, causal=causal)
-    mesh = sh.current_abstract_mesh()
-    auto = set(mesh.axis_names) - sh._manual_axes(mesh) if mesh else set()
-    if all(mesh.shape[a] == 1 for a in auto):
+    specs = attention_shard_specs(q.shape, k.shape)
+    if specs is None:
         return fn(q, k, v)
-
-    def spec(x, heads: str):
-        entries = list(sh.auto_axes_spec(mesh, ("batch", None, heads, None)))
-        for i, e in enumerate(entries):
-            names = (e,) if isinstance(e, str) else (e or ())
-            if x.shape[i] % math.prod(mesh.shape[a] for a in names):
-                entries[i] = None       # not divisible: stays whole
-        return P(*entries)
-
-    q_spec = spec(q, "heads")
+    mesh, axis_names, q_spec, kv_spec = specs
     return jax.shard_map(
-        fn, mesh=mesh, axis_names=auto, check_vma=False,
-        in_specs=(q_spec, spec(k, "kv_heads"), spec(v, "kv_heads")),
-        out_specs=q_spec)(q, k, v)
+        fn, mesh=mesh, axis_names=axis_names, check_vma=False,
+        in_specs=(q_spec, kv_spec, kv_spec), out_specs=q_spec)(q, k, v)

@@ -9,6 +9,8 @@ tokens sharded over "seq").
 """
 from __future__ import annotations
 
+import math
+
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -146,6 +148,48 @@ def auto_axes_spec(mesh, logical_axes: tuple[str | None, ...],
     manual = _manual_axes(mesh)
     return P(*[_prune(mesh, s, exclude=manual)
                for s in logical_spec(logical_axes, rules)])
+
+
+def attention_shard_specs(q_shape, kv_shape, mesh=None):
+    """Layout for one attention-kernel call per shard.  GSPMD cannot
+    partition a Mosaic kernel, so the caller wraps it in a shard_map
+    over the axes GSPMD still owns; this is the one place that says how
+    [b, s, h, d] q and k/v split over them.  Returns None when there is
+    nothing to split (no mesh, or every auto axis of size 1), else
+    `(mesh, axis_names, q_spec, kv_spec)`.
+
+    Batch goes over the "batch" axes when they divide it; the sequence
+    stays whole (a seq-sharded layout takes ring attention).  Heads are
+    decided ONCE for q, k and v: contiguous head chunks keep every GQA
+    group on one shard only if the head axes divide the kv-head count
+    too, so q heads split when they do (k/v split alike) or when there
+    is a single kv head (k/v stay whole, every shard reads it).  Any
+    other count keeps heads whole on all three — splitting q alone
+    would pair its heads with the wrong kv groups."""
+    if mesh is None:
+        mesh = current_abstract_mesh()
+    if mesh is None:
+        return None
+    auto = set(mesh.axis_names) - _manual_axes(mesh)
+    if all(mesh.shape[a] == 1 for a in auto):
+        return None
+    batch, _, heads, _ = auto_axes_spec(
+        mesh, ("batch", None, "heads", None))
+
+    def size(entry) -> int:
+        names = (entry,) if isinstance(entry, str) else (entry or ())
+        return math.prod(mesh.shape[a] for a in names)
+
+    if q_shape[0] % size(batch):
+        batch = None
+    hq, hkv = q_shape[2], kv_shape[2]
+    kv_heads = heads
+    if hq % size(heads) or (hkv % size(heads) and hkv != 1):
+        heads = kv_heads = None
+    elif hkv == 1:
+        kv_heads = None
+    return (mesh, auto, P(batch, None, heads, None),
+            P(batch, None, kv_heads, None))
 
 
 def _manual_axes(mesh) -> set:

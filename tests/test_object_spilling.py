@@ -44,6 +44,8 @@ def test_spill_through_public_api():
     every object after spilling."""
     import ray_tpu
 
+    if ray_tpu.is_initialized():    # a ray_shared file ran before on
+        ray_tpu.shutdown()          # this worker and left its cluster up
     ray_tpu.init(resources={"CPU": 2},
                  object_store_memory=8 * 1024 * 1024)
     try:

@@ -49,18 +49,23 @@ class TestFlashAttention:
         o_ref = xla_attention(q, k, v, causal=True)
         np.testing.assert_allclose(o, o_ref, atol=2e-2, rtol=1e-2)
 
-    @pytest.mark.parametrize("hkv", [2, 1], ids=["gqa", "mqa-unsplit"])
-    def test_flash_runs_per_shard_under_a_mesh(self, hkv):
+    @pytest.mark.parametrize("axes,hq,hkv", [
+        (dict(data=2, fsdp=2, tensor=2), 4, 2),    # q and k/v heads split
+        (dict(data=2, fsdp=2, tensor=2), 4, 1),    # MQA: q split, k/v whole
+        (dict(data=2, tensor=4), 8, 2),            # kv heads don't divide:
+        (dict(data=2, fsdp=2, tensor=2), 12, 3),   #   heads whole on all three
+    ], ids=["gqa-split", "mqa-kv-whole", "gqa-8q2kv-tensor4",
+            "gqa-12q3kv-tensor2"])
+    def test_flash_runs_per_shard_under_a_mesh(self, axes, hq, hkv):
         """Under an ambient multi-device mesh the dispatcher makes the
         kernel call per shard (GSPMD cannot partition a Mosaic kernel):
-        batch over data x fsdp, heads over tensor — a kv-head count the
-        tensor axis does not divide stays whole.  Values and gradients
-        match the unsharded XLA reference."""
+        batch over data x fsdp, heads over tensor only where every GQA
+        group stays on one shard (parallel/sharding.attention_shard_specs).
+        Values and gradients match the unsharded XLA reference."""
         from ray_tpu.parallel.mesh import MeshConfig, create_mesh
 
-        mesh = create_mesh(MeshConfig(data=2, fsdp=2, tensor=2),
-                           devices=jax.devices()[:8])
-        q, k, v = _qkv(b=4, s=128, hq=4, hkv=hkv)
+        mesh = create_mesh(MeshConfig(**axes), devices=jax.devices()[:8])
+        q, k, v = _qkv(b=4, s=128, hq=hq, hkv=hkv)
 
         def loss(att):
             return lambda q, k, v: (att(q, k, v) ** 2).sum()
@@ -77,6 +82,35 @@ class TestFlashAttention:
         for a, b in zip(g, g_ref):
             rel = jnp.abs(a - b).max() / (jnp.abs(b).max() + 1e-9)
             assert rel < 5e-3, f"grad rel err {rel}"
+
+    @pytest.mark.parametrize("axes,hq,hkv,b,want", [
+        (dict(fsdp=2, tensor=2), 32, 8, 4,
+         (("data", "fsdp"), "tensor", "tensor")),          # llama3-8b
+        (dict(tensor=8), 16, 4, 4,
+         (("data", "fsdp"), None, None)),                  # bench-350m
+        (dict(tensor=8), 64, 8, 4, (("data", "fsdp"), "tensor", "tensor")),
+        (dict(data=2, tensor=4), 8, 1, 4, (("data", "fsdp"), "tensor", None)),
+        (dict(data=2, tensor=4), 6, 1, 4, (("data", "fsdp"), None, None)),
+        (dict(data=4, tensor=2), 4, 2, 2, (None, "tensor", "tensor")),
+    ], ids=["split", "kv-indivisible", "kv-divides", "mqa", "q-indivisible",
+            "batch-indivisible"])
+    def test_attention_shard_specs_decide_heads_once(self, axes, hq, hkv, b,
+                                                     want):
+        """q heads split over the tensor axis only when k/v heads split
+        with them (or there is one kv head); otherwise all three stay
+        whole.  Returned as (batch entry, q heads entry, kv heads entry)."""
+        from ray_tpu.parallel.mesh import MeshConfig, create_mesh
+        from ray_tpu.parallel.sharding import attention_shard_specs
+
+        mesh = create_mesh(MeshConfig(**axes), devices=jax.devices()[:8])
+        _, auto, q_spec, kv_spec = attention_shard_specs(
+            (b, 128, hq, 128), (b, 128, hkv, 128), mesh=mesh)
+        assert auto == set(mesh.axis_names)
+        assert q_spec[0] == kv_spec[0]
+        assert (q_spec[0], q_spec[2], kv_spec[2]) == want
+        one = create_mesh(MeshConfig(), devices=jax.devices()[:1])
+        assert attention_shard_specs((b, 128, hq, 128), (b, 128, hkv, 128),
+                                     mesh=one) is None
 
     def test_dispatcher_fallback_short_seq(self):
         # s=64 not a multiple of 128 → XLA path; just must run + match.
