@@ -34,6 +34,19 @@ Design contract (the tentpole's cost rules):
 Clock: spans carry wall time (`time.time()`, shared across processes on
 a host — the same basis as the task-event timeline), so buffers from
 different processes merge onto one timeline directly.
+
+Engine-scope spans (the convention `serve/llm.py` follows for its loop
+thread, `llm.loop.<phase>`): a long-lived thread that serves many
+requests records ITS OWN time under one trace per engine, rooted at a
+zero-length span (`llm.engine`, `emit(..., ctx=(new_id(), ""))`, whose
+return value is the children's `ctx`) — the cause of a phase is the
+engine, not whichever request happened to be resident.  The phases of
+one pass through the loop carry its iteration counter (`iter`) and
+partition the thread's time; there is NO umbrella span over an
+iteration, because a reader that gives an interval to the span covering
+most of it would give everything to the umbrella.  Request-scoped spans
+(`llm.queue`, `llm.prefill`, `llm.decode_window`) stay on the request's
+own trace.
 """
 from __future__ import annotations
 
@@ -105,6 +118,11 @@ def _new_id() -> str:
     chars: pid + per-process counter — never `hash()`, never random
     state that a fork would duplicate)."""
     return f"{_pid & 0xFFFFFFFF:08x}{next(_span_seq) & 0xFFFFFFFF:08x}"
+
+
+# Public: a caller that roots a trace of its own (an engine's timeline)
+# passes `ctx=(new_id(), "")` to emit().
+new_id = _new_id
 
 
 def _append(rec: dict) -> None:
@@ -191,22 +209,27 @@ def _clean_attrs(attrs: dict | None) -> dict:
 
 
 def emit(name: str, t0: float, t1: float | None = None,
-         ctx: tuple | None = None, attrs: dict | None = None) -> None:
+         ctx: tuple | None = None, attrs: dict | None = None
+         ) -> tuple | None:
     """Record one completed span.  `ctx` is an explicit (trace_id,
     parent_span_id) pair — e.g. captured at request submission and
     replayed from the engine loop thread; None uses `current()`; with
-    no context anywhere the span roots its own trace."""
+    no context anywhere the span roots its own trace.  Returns the
+    span's own (trace_id, span_id) — the `ctx` of its children — or
+    None with the recorder off."""
     if not ENABLED:
-        return
+        return None
     c = ctx if ctx is not None else current()
     if c is not None:
         tid, par = c
     else:
         tid, par = _new_id(), ""
-    _append({"tid": tid, "sid": _new_id(), "par": par or "",
+    sid = _new_id()
+    _append({"tid": tid, "sid": sid, "par": par or "",
              "name": name, "t0": t0,
              "t1": time.time() if t1 is None else t1,
              "pid": _pid, "attrs": _clean_attrs(attrs)})
+    return (tid, sid)
 
 
 def emit_task(trace: dict | None, name: str, t0: float,

@@ -29,6 +29,15 @@ from ray_tpu.ops.norms import rmsnorm
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
 from ray_tpu.parallel.sharding import with_sharding_constraint
 
+# Names on the device side: every part of the decoder runs under a
+# `jax.named_scope` (embed, layer_weights, attn_qkv, rope, attn,
+# attn_out, mlp, norm, lm_head, kv_write), so XProf and the benchmark's
+# --dump-trace show which part an op belongs to.  Scopes change op
+# metadata only, never the compiled program.
+rmsnorm = jax.named_call(rmsnorm, name="norm")
+apply_rope = jax.named_call(apply_rope, name="rope")
+attention = jax.named_call(attention, name="attn")
+
 
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:
@@ -188,9 +197,10 @@ def remat_policy(cfg: "LlamaConfig | None" = None):
 def _attention_block(x, lp, cfg: LlamaConfig, cos, sin):
     b, s, d = x.shape
     h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-    q = (h @ lp["wq"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
-    k = (h @ lp["wk"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
-    v = (h @ lp["wv"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+    with jax.named_scope("attn_qkv"):
+        q = (h @ lp["wq"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
+        k = (h @ lp["wk"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
+        v = (h @ lp["wv"]).reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
     if cfg.use_ring_attention:
@@ -200,9 +210,11 @@ def _attention_block(x, lp, cfg: LlamaConfig, cos, sin):
     else:
         o = attention(q, k, v, causal=True)
     o = o.reshape(b, s, cfg.n_heads * cfg.head_dim)
-    return x + (o @ lp["wo"])
+    with jax.named_scope("attn_out"):
+        return x + (o @ lp["wo"])
 
 
+@functools.partial(jax.named_call, name="mlp")
 def _mlp_block(x, lp, cfg: LlamaConfig):
     h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
     gate = h @ lp["w_gate"]
@@ -223,10 +235,12 @@ def head_loss(params: dict, x: jnp.ndarray, targets: jnp.ndarray,
               mask, cfg: LlamaConfig) -> jnp.ndarray:
     """Shared trunk tail: final norm → lm_head (fp32) → cross entropy."""
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    logits = (x @ params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        logits = (x @ params["lm_head"]).astype(jnp.float32)
     return cross_entropy(logits, targets, mask)
 
 
+@functools.partial(jax.named_call, name="embed")
 def embed_lookup(table: jnp.ndarray, tokens: jnp.ndarray,
                  dtype) -> jnp.ndarray:
     """Token-embedding lookup that stays efficient under a vocab-sharded
@@ -269,7 +283,8 @@ def run_trunk(params: dict, tokens: jnp.ndarray, cfg: LlamaConfig,
     (x, aux), _ = lax.scan(body, (x, jnp.zeros((), jnp.float32)),
                            params["layers"])
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    logits = (x @ params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        logits = (x @ params["lm_head"]).astype(jnp.float32)
     return with_sharding_constraint(logits, ("batch", "seq", "vocab")), aux
 
 
@@ -516,17 +531,19 @@ def prefill(params: dict, tokens: jnp.ndarray, cfg: LlamaConfig,
         lp = scanned[0]
         lb = scanned[1] if lora else {}
         h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-        q = _lora_proj(h, lp["wq"], lb.get("wq"), idx) \
-            .reshape(b, P, cfg.n_heads, cfg.head_dim)
-        k = _lora_proj(h, lp["wk"], lb.get("wk"), idx) \
-            .reshape(b, P, cfg.n_kv_heads, cfg.head_dim)
-        v = _lora_proj(h, lp["wv"], lb.get("wv"), idx) \
-            .reshape(b, P, cfg.n_kv_heads, cfg.head_dim)
+        with jax.named_scope("attn_qkv"):
+            q = _lora_proj(h, lp["wq"], lb.get("wq"), idx) \
+                .reshape(b, P, cfg.n_heads, cfg.head_dim)
+            k = _lora_proj(h, lp["wk"], lb.get("wk"), idx) \
+                .reshape(b, P, cfg.n_kv_heads, cfg.head_dim)
+            v = _lora_proj(h, lp["wv"], lb.get("wv"), idx) \
+                .reshape(b, P, cfg.n_kv_heads, cfg.head_dim)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
         o = attention(q, k, v, causal=True)
-        x = x + _lora_proj(o.reshape(b, P, -1), lp["wo"],
-                           lb.get("wo"), idx)
+        with jax.named_scope("attn_out"):
+            x = x + _lora_proj(o.reshape(b, P, -1), lp["wo"],
+                               lb.get("wo"), idx)
         x = _mlp_block(x, lp, cfg)
         return x, (k.astype(cfg.dtype), v.astype(cfg.dtype))
 
@@ -550,27 +567,33 @@ def _decode_layer(x, lp, ck, cv, pos, cos, sin, mask, cfg: LlamaConfig):
         return lax.dynamic_update_slice(c, kv, (p, 0, 0))
 
     h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-    q = (h @ lp["wq"]).reshape(b, 1, cfg.n_heads, cfg.head_dim)
-    k = (h @ lp["wk"]).reshape(b, 1, cfg.n_kv_heads, cfg.head_dim)
-    v = (h @ lp["wv"]).reshape(b, 1, cfg.n_kv_heads, cfg.head_dim)
+    with jax.named_scope("attn_qkv"):
+        q = (h @ lp["wq"]).reshape(b, 1, cfg.n_heads, cfg.head_dim)
+        k = (h @ lp["wk"]).reshape(b, 1, cfg.n_kv_heads, cfg.head_dim)
+        v = (h @ lp["wv"]).reshape(b, 1, cfg.n_kv_heads, cfg.head_dim)
     q = apply_rope(q, cos, sin, positions=pos[:, None])
     k = apply_rope(k, cos, sin, positions=pos[:, None])
-    ck = jax.vmap(write_row)(ck, k.astype(cfg.dtype), pos)
-    cv = jax.vmap(write_row)(cv, v.astype(cfg.dtype), pos)
-    # Grouped-query attention without materializing repeated K/V:
-    # queries fold into [kv-group, rep] and share the group's cache.
-    qg = q.reshape(b, 1, cfg.n_kv_heads, n_rep, cfg.head_dim)
-    a = jnp.einsum("bqgrd,bkgd->bgrqk", qg, ck,
-                   preferred_element_type=jnp.float32)
-    a *= cfg.head_dim ** -0.5
-    a = jnp.where(mask[:, None, None, None, :], a, -1e30)
-    probs = jax.nn.softmax(a, axis=-1).astype(cfg.dtype)
-    o = jnp.einsum("bgrqk,bkgd->bqgrd", probs, cv)
-    o = o.reshape(b, 1, cfg.n_heads * cfg.head_dim)
-    x = x + (o @ lp["wo"])
-    h2 = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
-    gg = jax.nn.silu((h2 @ lp["w_gate"]).astype(jnp.float32))
-    x = x + ((gg.astype(cfg.dtype) * (h2 @ lp["w_up"])) @ lp["w_down"])
+    with jax.named_scope("kv_write"):
+        ck = jax.vmap(write_row)(ck, k.astype(cfg.dtype), pos)
+        cv = jax.vmap(write_row)(cv, v.astype(cfg.dtype), pos)
+    with jax.named_scope("attn"):
+        # Grouped-query attention without materializing repeated K/V:
+        # queries fold into [kv-group, rep] and share the group's cache.
+        qg = q.reshape(b, 1, cfg.n_kv_heads, n_rep, cfg.head_dim)
+        a = jnp.einsum("bqgrd,bkgd->bgrqk", qg, ck,
+                       preferred_element_type=jnp.float32)
+        a *= cfg.head_dim ** -0.5
+        a = jnp.where(mask[:, None, None, None, :], a, -1e30)
+        probs = jax.nn.softmax(a, axis=-1).astype(cfg.dtype)
+        o = jnp.einsum("bgrqk,bkgd->bqgrd", probs, cv)
+        o = o.reshape(b, 1, cfg.n_heads * cfg.head_dim)
+    with jax.named_scope("attn_out"):
+        x = x + (o @ lp["wo"])
+    with jax.named_scope("mlp"):
+        h2 = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
+        gg = jax.nn.silu((h2 @ lp["w_gate"]).astype(jnp.float32))
+        x = x + ((gg.astype(cfg.dtype) * (h2 @ lp["w_up"]))
+                 @ lp["w_down"])
     return x, ck, cv
 
 
@@ -607,13 +630,15 @@ def decode_step_unrolled(params: dict, cache: dict, tokens: jnp.ndarray,
 
     new_k, new_v = [], []
     for lid in range(cfg.n_layers):
-        lp = jax.tree.map(lambda a: a[lid], params["layers"])
+        with jax.named_scope("layer_weights"):
+            lp = jax.tree.map(lambda a: a[lid], params["layers"])
         x, ck, cv = _decode_layer(x, lp, cache["k"][lid], cache["v"][lid],
                                   pos, cos, sin, mask, cfg)
         new_k.append(ck)
         new_v.append(cv)
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    logits = (x[:, 0] @ params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        logits = (x[:, 0] @ params["lm_head"]).astype(jnp.float32)
     return logits, {"k": new_k, "v": new_v, "pos": pos + 1}
 
 
@@ -634,6 +659,7 @@ def init_paged_kv_cache(cfg: LlamaConfig, batch: int, n_pages: int,
             "pos": jnp.zeros((batch,), jnp.int32)}
 
 
+@functools.partial(jax.named_call, name="kv_write")
 def scatter_prefill_pages(cache: dict, ks, vs, page_ids: jnp.ndarray,
                           rows: jnp.ndarray, slots: jnp.ndarray,
                           true_lens: jnp.ndarray,
@@ -744,33 +770,37 @@ def prefill_with_prefix(params: dict, tokens: jnp.ndarray,
 
     ks_out, vs_out = [], []
     for lid in range(cfg.n_layers):
-        lp = jax.tree.map(lambda a: a[lid], params["layers"])
+        with jax.named_scope("layer_weights"):
+            lp = jax.tree.map(lambda a: a[lid], params["layers"])
         lb, lidx = _lora_layer_slice(lora, lid)
         lb = lb or {}
         h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-        q = _lora_proj(h, lp["wq"], lb.get("wq"), lidx) \
-            .reshape(b, S, cfg.n_heads, cfg.head_dim)
-        k = _lora_proj(h, lp["wk"], lb.get("wk"), lidx) \
-            .reshape(b, S, cfg.n_kv_heads, cfg.head_dim)
-        v = _lora_proj(h, lp["wv"], lb.get("wv"), lidx) \
-            .reshape(b, S, cfg.n_kv_heads, cfg.head_dim)
+        with jax.named_scope("attn_qkv"):
+            q = _lora_proj(h, lp["wq"], lb.get("wq"), lidx) \
+                .reshape(b, S, cfg.n_heads, cfg.head_dim)
+            k = _lora_proj(h, lp["wk"], lb.get("wk"), lidx) \
+                .reshape(b, S, cfg.n_kv_heads, cfg.head_dim)
+            v = _lora_proj(h, lp["wv"], lb.get("wv"), lidx) \
+                .reshape(b, S, cfg.n_kv_heads, cfg.head_dim)
         q = apply_rope(q, cos, sin, positions=positions)
         k = apply_rope(k, cos, sin, positions=positions)
         ks_out.append(k.astype(cfg.dtype))
         vs_out.append(v.astype(cfg.dtype))
-        pk = gather_pages(k_pages[lid], prefix_table)   # [b, Pp, kvh, hd]
-        pv = gather_pages(v_pages[lid], prefix_table)
-        ck = jnp.concatenate([pk, k.astype(cfg.dtype)], axis=1)
-        cv = jnp.concatenate([pv, v.astype(cfg.dtype)], axis=1)
-        qg = q.reshape(b, S, cfg.n_kv_heads, n_rep, cfg.head_dim)
-        a = jnp.einsum("bsgrd,bkgd->bgrsk", qg, ck,
-                       preferred_element_type=jnp.float32)
-        a *= cfg.head_dim ** -0.5
-        a = jnp.where(admit[:, None, None, :, :], a, -1e30)
-        probs = jax.nn.softmax(a, axis=-1).astype(cfg.dtype)
-        o = jnp.einsum("bgrsk,bkgd->bsgrd", probs, cv)
-        o = o.reshape(b, S, cfg.n_heads * cfg.head_dim)
-        x = x + _lora_proj(o, lp["wo"], lb.get("wo"), lidx)
+        with jax.named_scope("attn"):
+            pk = gather_pages(k_pages[lid], prefix_table)  # [b,Pp,kvh,hd]
+            pv = gather_pages(v_pages[lid], prefix_table)
+            ck = jnp.concatenate([pk, k.astype(cfg.dtype)], axis=1)
+            cv = jnp.concatenate([pv, v.astype(cfg.dtype)], axis=1)
+            qg = q.reshape(b, S, cfg.n_kv_heads, n_rep, cfg.head_dim)
+            a = jnp.einsum("bsgrd,bkgd->bgrsk", qg, ck,
+                           preferred_element_type=jnp.float32)
+            a *= cfg.head_dim ** -0.5
+            a = jnp.where(admit[:, None, None, :, :], a, -1e30)
+            probs = jax.nn.softmax(a, axis=-1).astype(cfg.dtype)
+            o = jnp.einsum("bgrsk,bkgd->bsgrd", probs, cv)
+            o = o.reshape(b, S, cfg.n_heads * cfg.head_dim)
+        with jax.named_scope("attn_out"):
+            x = x + _lora_proj(o, lp["wo"], lb.get("wo"), lidx)
         x = _mlp_block(x, lp, cfg)
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     return x, jnp.stack(ks_out), jnp.stack(vs_out)
@@ -803,33 +833,42 @@ def decode_step_paged(params: dict, pages: dict, tails: dict,
 
     new_tk, new_tv = [], []
     for lid in range(cfg.n_layers):
-        lp = jax.tree.map(lambda a: a[lid], params["layers"])
+        with jax.named_scope("layer_weights"):
+            lp = jax.tree.map(lambda a: a[lid], params["layers"])
         lb, lidx = _lora_layer_slice(lora, lid)
         lb = lb or {}
         h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-        q = _lora_proj(h, lp["wq"], lb.get("wq"), lidx) \
-            .reshape(b, 1, cfg.n_heads, cfg.head_dim)
-        k = _lora_proj(h, lp["wk"], lb.get("wk"), lidx) \
-            .reshape(b, 1, cfg.n_kv_heads, cfg.head_dim)
-        v = _lora_proj(h, lp["wv"], lb.get("wv"), lidx) \
-            .reshape(b, 1, cfg.n_kv_heads, cfg.head_dim)
+        with jax.named_scope("attn_qkv"):
+            q = _lora_proj(h, lp["wq"], lb.get("wq"), lidx) \
+                .reshape(b, 1, cfg.n_heads, cfg.head_dim)
+            k = _lora_proj(h, lp["wk"], lb.get("wk"), lidx) \
+                .reshape(b, 1, cfg.n_kv_heads, cfg.head_dim)
+            v = _lora_proj(h, lp["wv"], lb.get("wv"), lidx) \
+                .reshape(b, 1, cfg.n_kv_heads, cfg.head_dim)
         q = apply_rope(q, cos, sin, positions=pos[:, None])
         k = apply_rope(k, cos, sin, positions=pos[:, None])
         qg = q.reshape(b, cfg.n_kv_heads, n_rep, cfg.head_dim)
         kn = k[:, 0].astype(cfg.dtype)[:, :, None, :]   # [B, kvh, 1, hd]
         vn = v[:, 0].astype(cfg.dtype)[:, :, None, :]
-        tk = lax.dynamic_update_slice(tails["k"][lid], kn, (0, 0, j, 0))
-        tv = lax.dynamic_update_slice(tails["v"][lid], vn, (0, 0, j, 0))
-        o = paged_decode_attention(
-            qg.astype(cfg.dtype), pages["k"][lid], pages["v"][lid],
-            tk, tv, page_table, pos, tail_start)
+        with jax.named_scope("kv_write"):
+            tk = lax.dynamic_update_slice(tails["k"][lid], kn,
+                                          (0, 0, j, 0))
+            tv = lax.dynamic_update_slice(tails["v"][lid], vn,
+                                          (0, 0, j, 0))
+        with jax.named_scope("attn"):
+            o = paged_decode_attention(
+                qg.astype(cfg.dtype), pages["k"][lid], pages["v"][lid],
+                tk, tv, page_table, pos, tail_start)
         new_tk.append(tk)
         new_tv.append(tv)
-        x = x + _lora_proj(o.reshape(b, 1, cfg.n_heads * cfg.head_dim),
-                           lp["wo"], lb.get("wo"), lidx)
+        with jax.named_scope("attn_out"):
+            x = x + _lora_proj(
+                o.reshape(b, 1, cfg.n_heads * cfg.head_dim),
+                lp["wo"], lb.get("wo"), lidx)
         x = _mlp_block(x, lp, cfg)
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    logits = (x[:, 0] @ params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        logits = (x[:, 0] @ params["lm_head"]).astype(jnp.float32)
     return logits, {"k": new_tk, "v": new_tv}
 
 
@@ -867,5 +906,6 @@ def decode_step(params: dict, cache: dict, tokens: jnp.ndarray,
     x, (nk, nv) = lax.scan(layer, x,
                            (params["layers"], cache["k"], cache["v"]))
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    logits = (x[:, 0] @ params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        logits = (x[:, 0] @ params["lm_head"]).astype(jnp.float32)
     return logits, {"k": nk, "v": nv, "pos": pos + 1}

@@ -107,6 +107,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k):
 
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal),
+        name="flash_fwd",
         grid=(*grid, skv // block_k),
         in_specs=[
             pl.BlockSpec((None, None, block_q, d),
@@ -261,6 +262,7 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, res, g):
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, sm_scale=sm_scale, causal=causal),
+        name="flash_bwd_dq",
         grid=(b, hq, sq // block_q, skv // block_k),
         in_specs=[
             pl.BlockSpec((None, None, block_q, d),
@@ -292,6 +294,7 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, res, g):
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, sm_scale=sm_scale, causal=causal,
                           n_rep=n_rep),
+        name="flash_bwd_dkv",
         grid=(b, hkv, skv // block_k, sq // block_q),
         in_specs=[
             pl.BlockSpec((None, None, n_rep, block_q, d),

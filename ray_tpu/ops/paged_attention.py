@@ -164,6 +164,7 @@ def paged_decode_attention(q, k_pages, v_pages, k_tail, v_tail,
                                hd=hd, kt=kt, sm_scale=sm_scale)
     return pl.pallas_call(
         kernel,
+        name="paged_attn",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, kvh, rep, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -40,6 +40,7 @@ from __future__ import annotations
 
 import collections
 import concurrent.futures
+import contextlib
 import os
 import queue
 import threading
@@ -108,6 +109,10 @@ _METRICS_LOCK = threading.Lock()
 # recorder exists to attribute.
 _MS_BUCKETS = (1.0, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0, 500.0,
                1000.0, 2500.0, 5000.0, 10000.0, 30000.0)
+# TPOT lives in a much narrower band (17-28 ms on a v5e, PERF.md): the
+# general boundaries put every reading into (10, 25].
+_TPOT_MS_BUCKETS = (1.0, 2.0, 5.0, 10.0, 15.0, 20.0, 25.0, 30.0, 40.0,
+                    50.0, 75.0, 100.0, 250.0, 1000.0)
 
 
 def _engine_metrics():
@@ -130,6 +135,19 @@ def _engine_metrics():
                 "decode_tokens": um.get_or_create(
                     um.Counter, "serve_llm_decode_tokens",
                     "Tokens decoded", tk),
+                # pad factor = prefill_padded_tokens / prefill_tokens;
+                # live lanes per decode step = lane_steps_live /
+                # decode_steps (the engine-loop counters of stats())
+                "prefill_padded_tokens": um.get_or_create(
+                    um.Counter, "serve_llm_prefill_padded_tokens",
+                    "Token positions the dispatched prefill programs "
+                    "computed (width bucket x length bucket)", tk),
+                "lane_steps_live": um.get_or_create(
+                    um.Counter, "serve_llm_lane_steps_live",
+                    "Decode steps x lanes holding a request", tk),
+                "decode_steps": um.get_or_create(
+                    um.Counter, "serve_llm_decode_steps",
+                    "Decode steps dispatched (K per sync window)", tk),
                 "preemptions": um.get_or_create(
                     um.Counter, "serve_llm_preemptions",
                     "Requests preempted for KV blocks", tk),
@@ -169,7 +187,7 @@ def _engine_metrics():
                 "tpot": um.get_or_create(
                     um.Histogram, "serve_request_tpot_ms",
                     "Time per output token after the first (ms)", tk,
-                    boundaries=_MS_BUCKETS),
+                    boundaries=_TPOT_MS_BUCKETS),
                 "stage": um.get_or_create(
                     um.Histogram, "serve_request_stage_ms",
                     "Per-request stage latency breakdown "
@@ -242,6 +260,12 @@ class _Request:
     def emit(self, tok: int | None) -> None:
         if self.token_queue is not None:
             self.token_queue.put(tok)
+
+
+# The engine thread's timeline: the phases of one loop iteration, in
+# order (`llm.loop.<phase>` spans, `stats()["loop"]["phase_s"]` keys).
+_LOOP_PHASES = ("admit", "prefill_dispatch", "prefill_sync", "fund",
+                "decode_dispatch", "decode_sync", "deliver", "idle")
 
 
 class LLMEngine:
@@ -635,6 +659,17 @@ class LLMEngine:
         self._export_thread: threading.Thread | None = None
         self.prefill_tokens = 0        # tokens actually prefilled
         self.decode_tokens = 0
+        # The engine thread's own timeline (stats()["loop"]): plain
+        # attributes bumped on the loop thread, cumulative since the
+        # engine was made, on with RAY_TPU_TRACE=0 too.
+        self._loop_trace: tuple | None = None  # (trace id, root span id)
+        self._iter = 0                 # loop iterations: the spans' `iter`
+        self.decode_steps = 0          # sum of K over the windows
+        self.lane_steps_live = 0       # sum of live lanes x K
+        self.phase_s = dict.fromkeys(_LOOP_PHASES, 0.0)
+        self.prefill_padded_tokens = 0  # width bucket x length bucket
+        self._funded_blocks = 0        # pages _ensure_decode_blocks got
+        self._demote_dispatched = 0    # gathers _maybe_demote dispatched
         # Live weight sync (online RLHF): update_weights() stages a
         # fresh param tree here; the loop swaps it in BETWEEN decode
         # sync windows (never mid-block — the compiled program must see
@@ -1278,9 +1313,48 @@ class LLMEngine:
             self._wake.set()
         return k
 
+    # ------------------------------------------- the engine's timeline
+    def _loop_ctx(self) -> tuple | None:
+        """(trace id, root span id) of this engine's own trace: every
+        `llm.loop.*` span is a child of one zero-length `llm.engine`
+        span, so what caused a phase is the engine, not a request."""
+        if self._loop_trace is None and tracing.ENABLED:
+            now = time.time()
+            self._loop_trace = tracing.emit(
+                "llm.engine", now, now, ctx=(tracing.new_id(), ""),
+                attrs={"engine": self.name, "max_batch": self.max_batch,
+                       "steps_per_sync": self.steps_per_sync,
+                       "page_size": self.page if self.paged else 0})
+        return self._loop_trace
+
+    @contextlib.contextmanager
+    def _phase(self, key: str, **attrs):
+        """One phase of the engine thread's timeline, and the only way
+        one is recorded: `with self._phase("fund", iter=it) as ph:`.
+        The block (1) adds its perf_counter duration to the cumulative
+        `phase_s` counter, always; (2) becomes one flight-recorder span
+        `llm.loop.<key>` when tracing is on; (3) runs under a
+        `jax.profiler.TraceAnnotation`, so it is a host event, on the
+        profiler's clock, in any profiler trace that is running.  `with`
+        yields the span's attrs: the block adds what it learns (the
+        annotation carries the attrs known at entry)."""
+        from jax.profiler import TraceAnnotation
+
+        with TraceAnnotation("llm.loop." + key, **attrs):
+            w0 = time.time() if tracing.ENABLED else 0.0
+            t0 = time.perf_counter()
+            try:
+                yield attrs
+            finally:
+                self.phase_s[key] += time.perf_counter() - t0
+                if w0 and tracing.ENABLED:
+                    tracing.emit("llm.loop." + key, w0, time.time(),
+                                 ctx=self._loop_ctx(), attrs=attrs)
+
     def start(self) -> None:
         if self._thread is None or not self._thread.is_alive():
             self._stop.clear()
+            self._loop_ctx()
             self._thread = threading.Thread(
                 target=self._loop, name="llm-engine", daemon=True)
             self._thread.start()
@@ -1497,6 +1571,7 @@ class LLMEngine:
                 pass
             with self._demote_lock:
                 self._demote_inflight += 1
+            self._demote_dispatched += 1
             q.put(("demote", c, arr, gen, wv))
 
     def _demote_one(self, c: dict, arr, gen: int, wv: int) -> None:
@@ -1584,11 +1659,14 @@ class LLMEngine:
         req.pages = pages
         return True
 
-    def _admit(self) -> None:
-        """Prefill a whole wave of waiting requests in ONE device call;
-        one batched fetch materializes their first tokens."""
+    def _admit(self, ph: dict) -> list:
+        """Form a wave of waiting requests (the host half of admission:
+        queue pops, adapters, block reservation, KV imports, COW copies)
+        and return the (slot, request) pairs `_prefill_wave` prefills.
+        `ph` is the admit phase's attrs."""
         import jax.numpy as jnp
 
+        linger_s = 0.0     # this wave's burst-coalescing waits
         while True:        # drain arrivals behind any preempted requests
             try:
                 self._pending.append(self._waiting.get_nowait())
@@ -1630,13 +1708,16 @@ class LLMEngine:
                         # racing submit moves _last_submit_t.
                         break
                     grace_deadline = time.perf_counter() + 0.005
-                rem = grace_deadline - time.perf_counter()
+                t_linger = time.perf_counter()
+                rem = grace_deadline - t_linger
                 if rem <= 0:
                     break
                 try:
                     self._pending.append(self._waiting.get(timeout=rem))
                 except queue.Empty:
                     break
+                finally:
+                    linger_s += time.perf_counter() - t_linger
                 continue
             req = self._pending[0]
             if req.model_id is not None \
@@ -1669,8 +1750,11 @@ class LLMEngine:
             self._temps[free] = req.temperature
             self._seeds[free] = req.sample_seed
             wave.append((free, req))
+        ph.update(admitted=len(wave),
+                  linger_ms=round(linger_s * 1e3, 3),
+                  waiting=self._waiting.qsize() + len(self._pending))
         if not wave:
-            return
+            return wave
         # Migrated-KV admissions scatter their imported pages instead of
         # prefilling; their first token was already produced (and
         # delivered) by the exporting engine, so they skip the
@@ -1692,7 +1776,7 @@ class LLMEngine:
             if self._done(req):
                 self._finish(slot)
         if not wave:
-            return
+            return wave
         if copies:
             # Materialize COW copies before any prefill reads/writes the
             # forked pages (ordering rides the donated-cache dependency).
@@ -1701,70 +1785,87 @@ class LLMEngine:
             self.cache = self._copy_pages(
                 self.cache, jnp.asarray([s for s, _ in pairs], jnp.int32),
                 jnp.asarray([d for _, d in pairs], jnp.int32))
+        return wave
+
+    def _prefill_wave(self, wave: list, it: int) -> None:
+        """Prefill a whole wave of admitted requests in ONE device call
+        per chunk; one batched fetch materializes their first tokens."""
         # Sub-waves of <=_chunk requests: dispatch every chunk's forward
         # (and, paged, its separate scatter program) back-to-back, THEN
         # fetch first tokens — chunk 1's round trip overlaps chunk 2's
         # compute, so a big burst's p50 TTFT tracks one RTT plus HALF
         # the total prefill instead of all of it.
         pending_waves = []        # (chunk, nxt_device, dispatch wall t)
-        for c0 in range(0, len(wave), self._chunk):
-            chunk = wave[c0:c0 + self._chunk]
-            t_disp = time.time()
-            if self.paged and any(r.prefill_from > 0 for _, r in chunk):
-                nxt = self._prefill_chunk_suffix(chunk)
-            else:
-                nxt = self._prefill_chunk_full(chunk)
-            pending_waves.append((chunk, nxt, t_disp))
-        for _, nxt, _t in pending_waves:
-            try:
-                nxt.copy_to_host_async()
-            except AttributeError:
-                pass
-        for chunk, nxt, t_disp in pending_waves:
-            firsts = np.asarray(nxt)[:len(chunk)]
-            now = time.perf_counter()
-            now_wall = time.time()
-            for (slot, req), first in zip(chunk, firsts):
-                if req.first_token_at is None:
-                    req.first_token_at = now
-                req.tokens.append(int(first))
-                req.emit(int(first))
-                if self._done(req):
-                    self._finish(slot)
-            if not tracing.ENABLED:
-                continue
-            for slot, req in chunk:
-                if req.trace is None:
+        with self._phase("prefill_dispatch", iter=it,
+                         rows=len(wave)) as ph:
+            true0, padded0 = self.prefill_tokens, self.prefill_padded_tokens
+            width = length = 0
+            for c0 in range(0, len(wave), self._chunk):
+                chunk = wave[c0:c0 + self._chunk]
+                t_disp = time.time()
+                if self.paged and any(r.prefill_from > 0
+                                      for _, r in chunk):
+                    nxt, w, b = self._prefill_chunk_suffix(chunk)
+                else:
+                    nxt, w, b = self._prefill_chunk_full(chunk)
+                self.prefill_padded_tokens += w * b
+                width, length = max(width, w), max(length, b)
+                pending_waves.append((chunk, nxt, t_disp))
+            # the buckets are those of the widest / longest chunk
+            ph.update(width_bucket=width, len_bucket=length,
+                      true_tokens=self.prefill_tokens - true0,
+                      padded_tokens=self.prefill_padded_tokens - padded0,
+                      chunks=len(pending_waves))
+        with self._phase("prefill_sync", iter=it, rows=len(wave)):
+            for _, nxt, _t in pending_waves:
+                try:
+                    nxt.copy_to_host_async()
+                except AttributeError:
+                    pass
+            for chunk, nxt, t_disp in pending_waves:
+                firsts = np.asarray(nxt)[:len(chunk)]
+                now = time.perf_counter()
+                now_wall = time.time()
+                for (slot, req), first in zip(chunk, firsts):
+                    if req.first_token_at is None:
+                        req.first_token_at = now
+                    req.tokens.append(int(first))
+                    req.emit(int(first))
+                    if self._done(req):
+                        self._finish(slot)
+                if not tracing.ENABLED:
                     continue
-                # The request's engine-side TTFT anatomy: queue (submit
-                # → slot), prefill (chunk dispatch → first tokens on
-                # host; chunk-mates share the device call, so they
-                # share the window), first-token marker.
-                tracing.emit("llm.queue", req.t0_wall,
-                             req.admitted_wall, ctx=req.trace)
-                if req.model_id is not None:
-                    # The adapter APPLY leg: this request's decode
-                    # gathers bank slot `lora_slot` from here on.
+                for slot, req in chunk:
+                    if req.trace is None:
+                        continue
+                    # The request's engine-side TTFT anatomy: queue
+                    # (submit → slot), prefill (chunk dispatch → first
+                    # tokens on host; chunk-mates share the device call,
+                    # so they share the window), first-token marker.
+                    tracing.emit("llm.queue", req.t0_wall,
+                                 req.admitted_wall, ctx=req.trace)
+                    attrs = {"prompt_tokens": len(req.prompt),
+                             "prefill_from": req.prefill_from,
+                             "cached_tokens": req.prefill_from}
+                    if req.model_id is not None:
+                        # this request's decode gathers bank slot
+                        # `lora_slot` from here on
+                        attrs.update(model_id=req.model_id,
+                                     slot=req.lora_slot)
+                    tracing.emit("llm.prefill", t_disp, now_wall,
+                                 ctx=req.trace, attrs=attrs)
                     tracing.emit(
-                        "serve.adapter_apply", req.admitted_wall,
-                        req.admitted_wall, ctx=req.trace,
-                        attrs={"model_id": req.model_id,
-                               "slot": req.lora_slot})
-                tracing.emit(
-                    "llm.prefill", t_disp, now_wall, ctx=req.trace,
-                    attrs={"prompt_tokens": len(req.prompt),
-                           "prefill_from": req.prefill_from,
-                           "cached_tokens": req.prefill_from})
-                tracing.emit(
-                    "llm.first_token", now_wall, now_wall,
-                    ctx=req.trace,
-                    attrs={"ttft_ms": round(
-                        (req.first_token_at - req.submitted_at)
-                        * 1000, 1)})
+                        "llm.first_token", now_wall, now_wall,
+                        ctx=req.trace,
+                        attrs={"ttft_ms": round(
+                            (req.first_token_at - req.submitted_at)
+                            * 1000, 1)})
 
     def _prefill_chunk_full(self, chunk):
         """Full-prompt prefill (no cached prefix anywhere in the chunk):
-        the original bucketed wave path, byte-for-byte."""
+        the original bucketed wave path, byte-for-byte.  Returns the
+        first tokens (on device), the width bucket and the length
+        bucket of the program it dispatched."""
         import jax.numpy as jnp
 
         W = len(chunk)
@@ -1820,7 +1921,7 @@ class LLMEngine:
                 jnp.asarray(seeds), jnp.asarray(starts))
         # Duplicate padding rows target the same slot + same token.
         self._cur_dev = self._cur_dev.at[slots_dev].set(nxt)
-        return nxt
+        return nxt, padded_w, bucket
 
     def _prefill_chunk_suffix(self, chunk):
         """Prefix-cache prefill: forward only each request's uncached
@@ -1879,7 +1980,7 @@ class LLMEngine:
             self.cache, ks, vs, jnp.asarray(page_ids),
             jnp.asarray(rows), slots_dev, jnp.asarray(true_lens))
         self._cur_dev = self._cur_dev.at[slots_dev].set(nxt)
-        return nxt
+        return nxt, padded_w, bucket
 
     def _apply_import(self, slot: int, req: _Request) -> None:
         """Scatter a migrated request's KV pages into its freshly
@@ -2181,6 +2282,7 @@ class LLMEngine:
             if got is None or self._slots[slot] is None:
                 continue
             req.pages.extend(got)
+            self._funded_blocks += len(got)
             self._table[slot, :len(req.pages)] = req.pages
             self._table_dirty = True
         return [i for i, s in enumerate(self._slots) if s is not None]
@@ -2198,38 +2300,48 @@ class LLMEngine:
             raise
 
     def _loop_inner(self) -> None:
+        while not self._stop.is_set():
+            self._loop_once()
+
+    def _loop_once(self) -> None:
+        """One iteration of the engine thread.  Its phases (see _phase)
+        partition the thread's time: none overlaps another, and nothing
+        that takes time runs outside one.  There is no span over the
+        whole iteration: `iter` ties its phases together."""
         import jax.numpy as jnp
 
-        while not self._stop.is_set():
+        self._iter = it = self._iter + 1
+        with self._phase("admit", iter=it) as ph:
             self._maybe_swap_weights()
             # Grafts apply right after the swap (the version check must
             # see the tree a commit would land in) and BEFORE admission
             # so the request that triggered the graft prefix-hits it.
             self._apply_grafts()
-            self._admit()
+            wave = self._admit(ph)
+        if wave:
+            self._prefill_wave(wave, it)
+        with self._phase("fund", iter=it) as ph:
             # ONE sync-window snapshot per iteration: funding and the
             # decode program must see the same K (set_sync_window may
             # race from a replica thread).
             k_win = self._k_live
+            blocks0, pre0 = self._funded_blocks, self.preemptions
+            demotes0 = self._demote_dispatched
             active = self._ensure_decode_blocks(k_win)
             self._maybe_demote()
             self._flush_metrics()
-            if not active:
-                if self._pending:
-                    # Head-of-line request waiting on blocks with no
-                    # active decode to free them: only finished-and-
-                    # cached blocks can help — _admit retries (allocate
-                    # evicts refcount-0 leaves), so just avoid a busy
-                    # spin.
-                    self._wake.wait(timeout=0.002)
-                else:
-                    self._wake.wait(timeout=0.05)
-                self._wake.clear()
-                continue
-            if self._table_dirty:
+            if active and self._table_dirty:
                 self._table_dev = jnp.asarray(self._table) if self.paged \
                     else jnp.zeros((1, 1), jnp.int32)
                 self._table_dirty = False
+            ph.update(blocks=self._funded_blocks - blocks0,
+                      preempted=self.preemptions - pre0,
+                      demotes=self._demote_dispatched - demotes0)
+        if not active:
+            self._idle_wait()
+            return
+        with self._phase("decode_dispatch", iter=it, lanes=len(active),
+                         steps=k_win):
             starts = np.zeros((self.max_batch,), np.int32)
             for i in active:
                 starts[i] = len(self._slots[i].tokens)
@@ -2247,12 +2359,17 @@ class LLMEngine:
                 jnp.asarray(self._seeds), jnp.asarray(starts),
                 self._lora_args(self._adapters))
             self._cur_dev = last                # stays on device
+            self.decode_steps += k_win
+            self.lane_steps_live += len(active) * k_win
+        with self._phase("decode_sync", iter=it):
             seq = np.asarray(seq)               # the ONE sync per block
+            t_win1 = time.time() if win_traced else 0.0
+        with self._phase("deliver", iter=it) as ph:
+            tokens0, done0 = self.decode_tokens, self.completed
             if win_traced:
                 # One K-step decode window per traced co-resident
                 # request: the window (dispatch → host sync) is the
                 # decode-side unit of TTFT/TPOT attribution.
-                t_win1 = time.time()
                 for i in active:
                     r = self._slots[i]
                     if r is not None and r.trace is not None:
@@ -2274,6 +2391,30 @@ class LLMEngine:
                         # Trim K-step overshoot past EOS/max_new_tokens.
                         self._finish(i)
                         break
+            ph.update(tokens=self.decode_tokens - tokens0,
+                      finished=self.completed - done0)
+
+    def _idle_wait(self) -> None:
+        """No lane is live: wait for work under ONE `idle` phase, however
+        many times the wait times out.  The phase ends when something
+        woke the loop or waits to be admitted, and the next iteration's
+        admit phase takes it from there; until then only the periodic
+        housekeeping runs, inside the phase."""
+        with self._phase("idle", pending=len(self._pending)):
+            while not self._stop.is_set():
+                # With a head-of-line request waiting on blocks and no
+                # active decode to free them, only finished-and-cached
+                # blocks can help — _admit retries (allocate evicts
+                # refcount-0 leaves), so just avoid a busy spin.
+                woke = self._wake.wait(
+                    timeout=0.002 if self._pending else 0.05)
+                self._wake.clear()
+                if (woke or self._pending or not self._waiting.empty()
+                        or not self._graft_q.empty()
+                        or self._staged_weights is not None):
+                    return
+                self._maybe_demote()
+                self._flush_metrics()
 
     def _flush_metrics(self, force: bool = False) -> None:
         """Export engine/cache counters as process metrics (→ controller
@@ -2289,7 +2430,10 @@ class LLMEngine:
             return
         tags = {"engine": self.name}
         cur = {"prefill_tokens": self.prefill_tokens,
+               "prefill_padded_tokens": self.prefill_padded_tokens,
                "decode_tokens": self.decode_tokens,
+               "decode_steps": self.decode_steps,
+               "lane_steps_live": self.lane_steps_live,
                "preemptions": self.preemptions,
                "completed": self.completed,
                "weight_updates": self.weight_updates}
@@ -2352,7 +2496,16 @@ class LLMEngine:
                # percentiles + the live sync window.
                "slo": self._slo_window.snapshot(),
                "sync_window": self._k_live,
-               "sync_window_shrinks": self.sync_window_shrinks}
+               "sync_window_shrinks": self.sync_window_shrinks,
+               # The engine thread's timeline, cumulative: pad factor =
+               # prefill_padded_tokens / prefill_true_tokens, live lanes
+               # per decode step = lane_steps_live / decode_steps.
+               "loop": {
+                   "decode_steps": self.decode_steps,
+                   "lane_steps_live": self.lane_steps_live,
+                   "phase_s": dict(self.phase_s),
+                   "prefill_true_tokens": self.prefill_tokens,
+                   "prefill_padded_tokens": self.prefill_padded_tokens}}
         if self._lora_banks is not None:
             with self._lora_lock:
                 now = time.monotonic()

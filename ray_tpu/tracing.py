@@ -32,6 +32,7 @@ from ray_tpu._private import spans as _impl
 span = _impl.span
 context = _impl.context
 emit = _impl.emit
+new_id = _impl.new_id
 emit_stamps = _impl.emit_stamps
 current = _impl.current
 capture = _impl.capture

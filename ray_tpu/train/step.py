@@ -73,10 +73,13 @@ def make_train_step(cfg: llama.LlamaConfig,
         def compute_loss(params):
             return loss_fn(params, batch, cfg)
 
-        loss, grads = jax.value_and_grad(compute_loss)(state.params)
-        updates, opt_state = optimizer.update(
-            grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        # named scopes: op metadata for XProf / --dump-trace only
+        with jax.named_scope("loss"):
+            loss, grads = jax.value_and_grad(compute_loss)(state.params)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(
+                grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
         gnorm = optax.global_norm(grads)
         new_state = TrainState(params=params, opt_state=opt_state,
                                step=state.step + 1)

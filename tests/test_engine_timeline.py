@@ -1,0 +1,523 @@
+"""The engine thread's own timeline (PR 25): `llm.loop.*` phase spans,
+the `loop` counters of `LLMEngine.stats()`, the kernels' names, and the
+benchmark's readers of them.  CPU, debug-sized model, a few seconds.
+"""
+import time
+
+import pytest
+
+PHASES = ("admit", "prefill_dispatch", "prefill_sync", "fund",
+          "decode_dispatch", "decode_sync", "deliver", "idle")
+
+
+@pytest.fixture(scope="module")
+def small():
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama
+
+    cfg = llama.LlamaConfig(
+        vocab_size=128, dim=64, n_layers=2, n_heads=4, n_kv_heads=2,
+        ffn_dim=128, max_seq=256, remat=False, dtype=jnp.float32)
+    return cfg, llama.init_params(jax.random.PRNGKey(7), cfg)
+
+
+def _engine(small, **kw):
+    from ray_tpu.serve.llm import LLMEngine
+
+    kw.setdefault("max_batch", 4)
+    kw.setdefault("max_len", 256)
+    kw.setdefault("page_size", 16)
+    kw.setdefault("steps_per_sync", 4)
+    return LLMEngine(small[0], small[1], seed=0, paged=True, **kw)
+
+
+def _one_wave(eng, prompts, max_new_tokens):
+    """Queue the prompts while the loop is stopped, so they ride ONE
+    wave (as the benchmark's warm-up does), and wait for them."""
+    eng.stop()
+    futs = [eng.submit(p, max_new_tokens=max_new_tokens, _cache_ok=False)
+            for p in prompts]
+    eng.start()
+    return [f.result(timeout=120.0) for f in futs]
+
+
+def _prompt(n, off=0):
+    return [(i * 7 + off) % 127 + 1 for i in range(n)]
+
+
+def _loop_spans(trace_id=None):
+    from ray_tpu import tracing
+
+    out = [r for r in tracing.snapshot()
+           if r["name"].startswith("llm.loop.")
+           and (trace_id is None or r["tid"] == trace_id)]
+    return sorted(out, key=lambda r: r["t0"])
+
+
+@pytest.fixture
+def traced_run(small):
+    """Two waves and twenty decode windows on a warm engine: the spans
+    of the engine's own trace, its root, and the engine."""
+    from ray_tpu import tracing
+
+    eng = _engine(small)                # K = 4
+    eng.start()
+    try:
+        # warm both wave widths and the decode program
+        _one_wave(eng, [_prompt(100, i) for i in range(3)], 3)
+        _one_wave(eng, [_prompt(40)], 3)
+        time.sleep(0.15)
+        # the measured stretch starts here (stop/start above leaves the
+        # thread's timeline with holes: there is no thread in them)
+        mark, steps0 = time.time(), eng.decode_steps
+        first = [eng.submit(_prompt(100, i), max_new_tokens=81,
+                            _cache_ok=False) for i in range(3)]
+        while eng.decode_steps < steps0 + 5 * 4:     # 5 windows
+            time.sleep(0.002)
+        second = eng.submit(_prompt(40, 9), max_new_tokens=9,
+                            _cache_ok=False)
+        for f in first + [second]:
+            f.result(timeout=120.0)
+        time.sleep(0.15)
+    finally:
+        eng.stop()
+    tid, root_sid = eng._loop_trace
+    roots = [r for r in tracing.snapshot() if r["name"] == "llm.engine"
+             and r["tid"] == tid]
+    spans = [s for s in _loop_spans(tid) if s["t1"] > mark]
+    return eng, spans, roots, root_sid
+
+
+def test_phases_partition_the_loop_thread(traced_run):
+    eng, spans, _, _ = traced_run
+    assert {s["name"][len("llm.loop."):] for s in spans} == set(PHASES)
+    assert sum(s["name"] == "llm.loop.decode_dispatch" for s in spans) >= 20
+    assert sum(s["name"] == "llm.loop.prefill_dispatch" for s in spans) == 2
+    overlap = uncovered = 0.0
+    end = spans[0]["t1"]
+    for s in spans[1:]:
+        if s["t0"] < end:
+            overlap += min(end, s["t1"]) - s["t0"]
+        else:
+            uncovered += s["t0"] - end
+        end = max(end, s["t1"])
+    busy = sum(s["t1"] - s["t0"] for s in spans
+               if s["name"] != "llm.loop.idle")
+    assert overlap == 0.0
+    assert uncovered < 0.02 * busy, (uncovered, busy)
+
+
+def test_phases_hang_off_the_engine_root_and_iter_is_monotone(traced_run):
+    eng, spans, roots, root_sid = traced_run
+    assert len(roots) == 1 and roots[0]["sid"] == root_sid
+    assert roots[0]["t0"] == roots[0]["t1"] and roots[0]["par"] == ""
+    assert roots[0]["attrs"] == {
+        "engine": eng.name, "max_batch": 4, "steps_per_sync": 4,
+        "page_size": 16}
+    assert all(s["par"] == root_sid for s in spans)
+    iters = [s["attrs"]["iter"] for s in spans
+             if s["name"] != "llm.loop.idle"]
+    assert iters == sorted(iters) and len(set(iters)) >= 20
+    # one iteration's phases come in the loop's order
+    order = {p: i for i, p in enumerate(PHASES)}
+    by_iter: dict = {}
+    for s in spans:
+        if "iter" in s["attrs"]:
+            by_iter.setdefault(s["attrs"]["iter"], []).append(
+                order[s["name"][len("llm.loop."):]])
+    assert all(v == sorted(v) and len(v) == len(set(v))
+               for v in by_iter.values())
+    disp = [s for s in spans if s["name"] == "llm.loop.prefill_dispatch"]
+    assert disp[-1]["attrs"]["rows"] == 1
+    assert set(disp[-1]["attrs"]) == {
+        "iter", "rows", "width_bucket", "len_bucket", "true_tokens",
+        "padded_tokens", "chunks"}
+    dec = [s for s in spans if s["name"] == "llm.loop.decode_dispatch"]
+    assert {s["attrs"]["steps"] for s in dec} == {4}
+    assert max(s["attrs"]["lanes"] for s in dec) == 4
+
+
+def test_consecutive_idle_iterations_are_one_span(small):
+    from ray_tpu import tracing
+
+    eng = _engine(small)
+    eng.start()
+    try:
+        _one_wave(eng, [_prompt(40)], 6)        # warm
+        time.sleep(0.1)
+        tracing.clear()
+        time.sleep(0.4)                         # >= 7 waits time out
+        eng.generate(_prompt(40, 3), max_new_tokens=6, _cache_ok=False)
+        # the reply is out before the thread is back in its wait: stop
+        # only once the iteration after the last deliver has funded
+        deadline = time.time() + 30.0
+        while _loop_spans(eng._loop_trace[0])[-1]["name"] \
+                != "llm.loop.fund" and time.time() < deadline:
+            time.sleep(0.005)
+    finally:
+        eng.stop()
+    spans = _loop_spans(eng._loop_trace[0])
+    first_admit = next(s for s in spans if s["name"] == "llm.loop.admit")
+    idle = [s for s in spans if s["name"] == "llm.loop.idle"
+            and s["t0"] < first_admit["t0"]]
+    assert len(idle) == 1
+    assert idle[0]["t1"] - idle[0]["t0"] >= 0.35
+    # the submit woke the loop: the next phase is the admit that took it
+    assert 0.0 <= first_admit["t0"] - idle[0]["t1"] < 0.05
+    assert first_admit["attrs"]["admitted"] == 1
+    # nothing is recorded inside the stretch: what runs there runs
+    # under the idle phase, in the recorder as in a profiler trace
+    assert not [s for s in spans if s is not idle[0]
+                and idle[0]["t0"] <= s["t0"] < idle[0]["t1"]]
+    # the stretch after the request is closed when the loop stops
+    assert spans[-1]["name"] == "llm.loop.idle"
+    assert eng.stats()["loop"]["phase_s"]["idle"] >= 0.35
+
+
+def test_prefill_counters_by_hand(small):
+    eng = _engine(small)
+    eng.start()
+    try:
+        s0 = eng.stats()
+        _one_wave(eng, [_prompt(100, i) for i in range(3)], 1)
+        s1 = eng.stats()
+    finally:
+        eng.stop()
+    width = next(w for w in eng._width_buckets if w >= 3)
+    length = next(b for b in eng._buckets if b >= 100)
+    d = {k: s1["loop"][k] - s0["loop"][k] for k in s1["loop"]
+         if k != "phase_s"}
+    assert (width, length) == (4, 128)
+    assert d["prefill_padded_tokens"] == width * length
+    assert d["prefill_true_tokens"] == 300
+    assert s1["loop"]["prefill_true_tokens"] == s1["prefill_tokens"]
+    assert d["decode_steps"] == 0       # one token each: prefill only
+
+
+def test_lane_steps_live_by_hand(small):
+    eng = _engine(small)                # K = 4, 4 lanes
+    eng.start()
+    try:
+        s0 = eng.stats()["loop"]
+        # the first token comes from prefill, 8 more = 2 windows
+        _one_wave(eng, [_prompt(20, i) for i in range(3)], 9)
+        s1 = eng.stats()["loop"]
+    finally:
+        eng.stop()
+    assert s1["decode_steps"] - s0["decode_steps"] == 8
+    assert s1["lane_steps_live"] - s0["lane_steps_live"] == 3 * 4 * 2
+    assert set(s1["phase_s"]) == set(PHASES)
+    assert all(s1["phase_s"][p] >= s0["phase_s"][p] for p in PHASES)
+    assert s1["phase_s"]["decode_sync"] > s0["phase_s"]["decode_sync"]
+
+
+def test_dense_engine_has_a_timeline_too(small):
+    from ray_tpu import tracing
+    from ray_tpu.serve.llm import LLMEngine
+
+    eng = LLMEngine(small[0], small[1], max_batch=2, max_len=64,
+                    paged=False, steps_per_sync=4)
+    eng.start()
+    try:
+        eng.generate(_prompt(12), max_new_tokens=9)
+        loop = eng.stats()["loop"]
+    finally:
+        eng.stop()
+    assert loop["decode_steps"] == 8 and loop["lane_steps_live"] == 8
+    assert loop["prefill_padded_tokens"] == 32      # 1 row x bucket 32
+    root = next(r for r in tracing.snapshot() if r["name"] == "llm.engine"
+                and r["tid"] == eng._loop_trace[0])
+    assert root["attrs"]["page_size"] == 0
+
+
+def test_counters_advance_with_tracing_off(small):
+    from ray_tpu import tracing
+
+    tracing.set_enabled(False)
+    try:
+        tracing.clear()
+        eng = _engine(small)
+        eng.start()
+        try:
+            _one_wave(eng, [_prompt(20, i) for i in range(2)], 9)
+            loop = eng.stats()["loop"]
+        finally:
+            eng.stop()
+        assert loop["decode_steps"] == 8 and loop["lane_steps_live"] == 16
+        assert loop["prefill_padded_tokens"] == 4 * 32
+        assert loop["phase_s"]["decode_dispatch"] > 0
+        assert loop["phase_s"]["prefill_dispatch"] > 0
+        assert eng._loop_trace is None
+        assert not [r for r in tracing.snapshot()
+                    if r["name"].startswith("llm.")]
+    finally:
+        tracing.set_enabled(True)
+
+
+def test_request_scoped_spans_are_what_they_were(small):
+    """Names, attrs and count of a traced request's spans: the
+    benchmark's readers (decode_windows, prefill_spans_in_trace,
+    paged_attn_roofline) read exactly these."""
+    from ray_tpu import tracing
+
+    eng = _engine(small)
+    eng.start()
+    try:
+        with tracing.span("client") as _:
+            ctx = tracing.current()
+            fut = eng.submit(_prompt(40), max_new_tokens=9, _cache_ok=False)
+        fut.result(timeout=120.0)
+    finally:
+        eng.stop()
+    mine = sorted((r for r in tracing.snapshot()
+                   if r["tid"] == ctx[0] and r["name"] != "client"),
+                  key=lambda r: (r["t0"], r["name"]))
+    assert all(r["par"] == ctx[1] for r in mine)
+    got = [(r["name"], r["attrs"]) for r in mine]
+    ttft = [a for n, a in got if n == "llm.first_token"]
+    assert list(ttft[0]) == ["ttft_ms"]
+    assert sorted(got, key=lambda g: g[0]) == sorted([
+        ("llm.queue", {}),
+        ("llm.prefill", {"prompt_tokens": 40, "prefill_from": 0,
+                         "cached_tokens": 0}),
+        ("llm.first_token", ttft[0]),
+        ("llm.decode_window", {"steps": 4, "weight_version": 0}),
+        ("llm.decode_window", {"steps": 4, "weight_version": 0}),
+    ], key=lambda g: g[0])
+    win = [r for r in mine if r["name"] == "llm.decode_window"]
+    pre = next(r for r in mine if r["name"] == "llm.prefill")
+    assert pre["t1"] <= win[0]["t0"] <= win[0]["t1"] <= win[1]["t0"]
+
+
+def test_lora_request_carries_its_adapter_on_the_prefill_span(small):
+    import jax
+
+    from ray_tpu import tracing
+    from ray_tpu.models import llama
+
+    eng = _engine(small, lora_slots=2, lora_rank=4)
+    eng.start()
+    try:
+        eng.load_adapter("t/a", llama.init_lora_adapter(
+            jax.random.PRNGKey(1), small[0], 4))
+        with tracing.span("client"):
+            tid = tracing.current()[0]
+            fut = eng.submit(_prompt(12), max_new_tokens=3,
+                             model_id="t/a")
+        fut.result(timeout=120.0)
+    finally:
+        eng.stop()
+    mine = [r for r in tracing.snapshot() if r["tid"] == tid]
+    pre = next(r for r in mine if r["name"] == "llm.prefill")
+    assert pre["attrs"]["model_id"] == "t/a" and pre["attrs"]["slot"] >= 1
+    assert not [r for r in tracing.snapshot()
+                if r["name"] == "serve.adapter_apply"]
+
+
+def test_operator_metrics(small):
+    """The TPOT histogram's own boundaries, and pad factor / occupancy
+    as Prometheus counters (the 1 Hz delta path)."""
+    from ray_tpu.serve import llm
+
+    eng = _engine(small, name="timeline-metrics")
+    eng.start()
+    try:
+        _one_wave(eng, [_prompt(20, i) for i in range(3)], 9)
+        eng.stats()                     # forces a flush
+    finally:
+        eng.stop()
+    m = llm._engine_metrics()
+
+    def value(key):
+        return next(v["value"] for v in m[key].snapshot()["values"]
+                    if v["tags"]["engine"] == "timeline-metrics")
+
+    assert value("prefill_padded_tokens") == 4 * 32
+    assert value("prefill_tokens") == 60
+    assert value("lane_steps_live") == 24 and value("decode_steps") == 8
+    assert m["tpot"].boundaries == [1, 2, 5, 10, 15, 20, 25, 30, 40, 50,
+                                    75, 100, 250, 1000]
+    # every TPOT the benchmark has read (17-28 ms) no longer shares a bucket
+    assert len({sum(x > b for b in m["tpot"].boundaries)
+                for x in (17.0, 22.5, 28.0)}) == 3
+    assert m["ttft"].boundaries[:4] == [1.0, 5.0, 10.0, 25.0]
+
+
+# ------------------------------------------------- the kernels' names
+def _lowered(fn, *args):
+    import jax
+
+    return jax.jit(fn).lower(*args).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("which", ["flash_fwd", "flash_bwd", "paged_attn"])
+def test_kernel_names_in_the_lowered_text(which):
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import flash_attention as fa
+    from ray_tpu.ops import paged_attention as pa
+
+    q = jnp.zeros((1, 256, 4, 128), jnp.bfloat16)
+    k = jnp.zeros((1, 256, 2, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    if which == "flash_fwd":
+        text = _lowered(fa.flash_attention, q, k, k)
+        assert "flash_fwd" in text and "flash_bwd" not in text
+    elif which == "flash_bwd":
+        text = _lowered(jax.grad(loss, argnums=(0, 1, 2)), q, k, k)
+        assert "flash_bwd_dq" in text and "flash_bwd_dkv" in text
+    else:
+        B, kvh, rep, hd, page, kt, maxp = 2, 2, 2, 128, 16, 8, 4
+        text = _lowered(
+            pa.paged_decode_attention,
+            jnp.zeros((B, kvh, rep, hd), jnp.bfloat16),
+            jnp.zeros((8, kvh, page, hd), jnp.bfloat16),
+            jnp.zeros((8, kvh, page, hd), jnp.bfloat16),
+            jnp.zeros((B, kvh, kt, hd), jnp.bfloat16),
+            jnp.zeros((B, kvh, kt, hd), jnp.bfloat16),
+            jnp.zeros((B, maxp), jnp.int32), jnp.full((B,), 20, jnp.int32),
+            jnp.full((B,), 16, jnp.int32))
+        assert "paged_attn" in text
+
+
+def test_decoder_scopes_in_the_lowered_text(small):
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama
+
+    cfg, params = small
+    text = _lowered(lambda p, t: llama.prefill(p, t, cfg), params,
+                    jnp.zeros((2, 32), jnp.int32))
+    def scoped(text, scope):        # a name-stack entry, not a file name
+        return f'"{scope}/' in text or f"/{scope}/" in text
+
+    for scope in ("embed", "attn_qkv", "rope", "attn", "attn_out", "mlp",
+                  "norm"):
+        assert scoped(text, scope), scope
+    tails = {n: [jnp.zeros((2, cfg.n_kv_heads, 4, cfg.head_dim))
+                 for _ in range(cfg.n_layers)] for n in ("k", "v")}
+    pages = llama.init_paged_kv_cache(cfg, 2, 8, 16)
+    text = _lowered(
+        lambda p, pg, tl, t, pos, ts, tab: llama.decode_step_paged(
+            p, pg, tl, t, pos, ts, 0, tab, cfg),
+        params, {"k": pages["k"], "v": pages["v"]}, tails,
+        jnp.zeros((2,), jnp.int32), jnp.full((2,), 5, jnp.int32),
+        jnp.full((2,), 5, jnp.int32), jnp.zeros((2, 4), jnp.int32))
+    for scope in ("layer_weights", "kv_write", "lm_head", "paged_attn"):
+        assert scoped(text, scope), scope
+
+
+# ------------------------------------------- the benchmark's new readers
+def _span(phase, t0, t1, **attrs):
+    return {"name": "llm.loop." + phase, "t0": t0, "t1": t1, "tid": "e",
+            "attrs": attrs}
+
+
+def _synthetic_run():
+    """Twelve iterations of 100 ms starting at t = 1000: 2 ms admit, 1 ms
+    fund, 3 ms decode_dispatch, 90 ms decode_sync, 4 ms deliver; then
+    idle.  The chip is idle in the first 10 ms of each iteration (admit +
+    fund + dispatch + 4 ms of the sync) and all through the idle phase."""
+    spans, gaps = [], []
+    for i in range(12):
+        t = 1000.0 + 0.1 * i
+        spans += [_span("admit", t, t + .002, iter=i, admitted=0),
+                  _span("fund", t + .002, t + .003, iter=i),
+                  _span("decode_dispatch", t + .003, t + .006, iter=i,
+                        lanes=3, steps=8),
+                  _span("decode_sync", t + .006, t + .096, iter=i),
+                  _span("deliver", t + .096, t + .1, iter=i)]
+        gaps.append((0.010, 0.1 * i, 0.1 * i + 0.010))
+    spans.append(_span("idle", 1001.2, 1001.7, pending=0))
+    gaps.append((0.5, 1.2, 1.7))
+    spans.append({"name": "llm.queue", "t0": 1000.0, "t1": 1002.0,
+                  "tid": "r", "attrs": {}})
+    loop0 = {"prefill_padded_tokens": 1000, "prefill_true_tokens": 400,
+             "lane_steps_live": 50, "decode_steps": 10}
+    loop1 = {"prefill_padded_tokens": 9000, "prefill_true_tokens": 2400,
+             "lane_steps_live": 2450, "decode_steps": 106}
+    return {
+        "spans": spans, "window_wall": (1000.0, 1002.0),
+        "stats": ({"loop": loop0}, {"loop": loop1}),
+        "trace": {"start_wall_s": 1000.0, "t_lo": 0.0, "t_hi": 2.0,
+                  "window_s": 2.0, "busy_s": 1.38,
+                  "devices": [{"busy_s": 1.38, "modules": [], "by_op": [],
+                               "gaps": sorted(gaps, reverse=True)}]}}
+
+
+EMPTY_RUN = {"spans": [], "window_wall": (0.0, 1.0), "stats": ({}, {}),
+             "trace": None}
+
+
+@pytest.mark.parametrize("fn,want", [
+    ("host_ms_per_window", 10.0),        # 2 + 1 + 3 + 4 ms
+    ("prefill_pad_factor", 4.0),         # 8000 / 2000
+    ("lanes_live", 25.0),                # 2400 / 96
+    # 12 gaps x (2 + 1 + 3 + 4 ms of the sync) = 120 ms of 2 s
+    ("device_idle_with_work_pct", 6.0),
+])
+def test_timeline_readers_on_a_synthetic_run(fn, want, capsys):
+    from benchmarks.harness import timeline
+
+    assert getattr(timeline, fn)(_synthetic_run()) == pytest.approx(want)
+    assert getattr(timeline, fn)(dict(EMPTY_RUN)) is None
+    if fn == "device_idle_with_work_pct":
+        import json
+
+        lines = [json.loads(ln) for ln in
+                 capsys.readouterr().out.splitlines() if ln.startswith("{")]
+        by = next(ln for ln in lines if ln["step"] == "idle_by_phase")
+        assert by["by_phase_s"]["idle"] == pytest.approx(0.5)
+        assert by["by_phase_s"]["decode_sync"] == pytest.approx(0.048)
+        assert by["share_of_idle_in_gaps"] == pytest.approx(1.0)
+        assert by["uncovered_s"] == pytest.approx(0.0, abs=1e-9)
+        assert by["longest_ms"][0][1] == {"idle": pytest.approx(500.0)}
+        part = next(ln for ln in lines if ln["step"] == "loop_partition")
+        assert part["window"]["overlap_s"] == pytest.approx(0.0, abs=1e-9)
+        # a parent without the phases: nothing to read, no exception
+        run = _synthetic_run()
+        run["spans"] = [s for s in run["spans"]
+                        if not s["name"].startswith("llm.loop.")]
+        assert timeline.device_idle_with_work_pct(run) is None
+        assert timeline.host_ms_per_window(run) is None
+
+
+def test_flash_bwd_only_roofline_reads_the_named_kernels():
+    import types
+
+    from benchmarks.harness import flops, peaks, timeline
+
+    model = {"hidden_size": 4096, "num_attention_heads": 32,
+             "num_key_value_heads": 8, "head_dim": 128,
+             "num_hidden_layers": 20}
+    cell = types.SimpleNamespace(
+        chips=4, config={"train": {"batch": 4, "seq": 4096}})
+    by_op = [
+        ["jit_step", "transpose_jvp_flash_bwd_dq__.3 custom-call bf16[2]",
+         40, 0.30],
+        ["jit_step", "transpose_jvp_flash_bwd_dkv__.2 custom-call bf16[2]",
+         40, 0.50],
+        ["jit_step", "jvp_flash_fwd_.1 custom-call bf16[2]", 80, 0.40],
+        ["jit_other", "flash_bwd_dq.9 custom-call bf16[2]", 5, 9.0],
+        ["jit_step", "fusion.7 fusion bf16[2]", 40, 1.0]]
+    run = {"cell": cell, "model": model, "rec": {"trace_steps": 2},
+           "device": {"kind": "TPU v5 lite"},
+           "trace": {"window_s": 2.6, "busy_s": 2.6,
+                     "devices": [{"by_op": by_op, "modules": [],
+                                  "gaps": [], "busy_s": 2.6}]}}
+    f, b = flops.flash_bwd_cost(model, 4, 4096)
+    least, _ = peaks.roofline_s(f * 2 * 20 / 4, b * 2 * 20 / 4,
+                                "TPU v5 lite")
+    assert timeline.flash_bwd_only_roofline(run) == pytest.approx(
+        100.0 * least / 0.80)
+    run["trace"]["devices"][0]["by_op"] = by_op[2:]     # the parent
+    assert timeline.flash_bwd_only_roofline(run) is None
+    assert timeline.flash_bwd_only_roofline(
+        {"rec": None, "trace": None}) is None
