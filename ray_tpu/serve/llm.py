@@ -55,6 +55,7 @@ from ray_tpu import tracing
 from ray_tpu.exceptions import AdapterLoadError
 from ray_tpu.serve import slo
 from ray_tpu.serve.kv_blocks import BlockManager
+from ray_tpu.serve.prefill_plan import plan_wave
 
 
 def _buckets_for(max_len: int, smallest: int = 32) -> list[int]:
@@ -142,6 +143,10 @@ def _engine_metrics():
                     um.Counter, "serve_llm_prefill_padded_tokens",
                     "Token positions the dispatched prefill programs "
                     "computed (width bucket x length bucket)", tk),
+                "prefill_programs": um.get_or_create(
+                    um.Counter, "serve_llm_prefill_programs",
+                    "Prefill programs dispatched (one or more a wave: "
+                    "serve/prefill_plan.py)", tk),
                 "lane_steps_live": um.get_or_create(
                     um.Counter, "serve_llm_lane_steps_live",
                     "Decode steps x lanes holding a request", tk),
@@ -668,6 +673,9 @@ class LLMEngine:
         self.lane_steps_live = 0       # sum of live lanes x K
         self.phase_s = dict.fromkeys(_LOOP_PHASES, 0.0)
         self.prefill_padded_tokens = 0  # width bucket x length bucket
+        self.prefill_programs = 0      # (width, length) programs dispatched
+        self.prefill_waves = 0
+        self.prefill_waves_split = 0   # plans of more programs than chunks
         self._funded_blocks = 0        # pages _ensure_decode_blocks got
         self._demote_dispatched = 0    # gathers _maybe_demote dispatched
         # Live weight sync (online RLHF): update_weights() stages a
@@ -1788,34 +1796,44 @@ class LLMEngine:
         return wave
 
     def _prefill_wave(self, wave: list, it: int) -> None:
-        """Prefill a whole wave of admitted requests in ONE device call
-        per chunk; one batched fetch materializes their first tokens."""
-        # Sub-waves of <=_chunk requests: dispatch every chunk's forward
-        # (and, paged, its separate scatter program) back-to-back, THEN
-        # fetch first tokens — chunk 1's round trip overlaps chunk 2's
+        """Prefill a whole wave of admitted requests through the cheapest
+        (width, length) programs the engine has (serve/prefill_plan.py);
+        one batched fetch materializes their first tokens."""
+        # Dispatch every program's forward (and, paged, its separate
+        # scatter program) back-to-back, shortest first, THEN fetch
+        # first tokens — program 1's round trip overlaps program 2's
         # compute, so a big burst's p50 TTFT tracks one RTT plus HALF
         # the total prefill instead of all of it.
         pending_waves = []        # (chunk, nxt_device, dispatch wall t)
         with self._phase("prefill_dispatch", iter=it,
                          rows=len(wave)) as ph:
             true0, padded0 = self.prefill_tokens, self.prefill_padded_tokens
-            width = length = 0
-            for c0 in range(0, len(wave), self._chunk):
-                chunk = wave[c0:c0 + self._chunk]
+            plan = plan_wave(
+                [len(r.prompt) + len(r.tokens) - r.prefill_from
+                 for _, r in wave],
+                self._width_buckets, self._buckets, self._chunk)
+            for rows, w, b in plan:
+                chunk = [wave[i] for i in rows]
                 t_disp = time.time()
                 if self.paged and any(r.prefill_from > 0
                                       for _, r in chunk):
-                    nxt, w, b = self._prefill_chunk_suffix(chunk)
+                    nxt = self._prefill_chunk_suffix(chunk, w, b)
                 else:
-                    nxt, w, b = self._prefill_chunk_full(chunk)
+                    nxt = self._prefill_chunk_full(chunk, w, b)
                 self.prefill_padded_tokens += w * b
-                width, length = max(width, w), max(length, b)
                 pending_waves.append((chunk, nxt, t_disp))
-            # the buckets are those of the widest / longest chunk
-            ph.update(width_bucket=width, len_bucket=length,
+            self.prefill_waves += 1
+            self.prefill_programs += len(plan)
+            # more programs than arrival-order chunks of _chunk rows
+            self.prefill_waves_split += \
+                len(plan) > -(-len(wave) // self._chunk)
+            # the buckets are those of the widest / longest program
+            ph.update(width_bucket=max(w for _, w, _ in plan),
+                      len_bucket=max(b for _, _, b in plan),
                       true_tokens=self.prefill_tokens - true0,
                       padded_tokens=self.prefill_padded_tokens - padded0,
-                      chunks=len(pending_waves))
+                      chunks=len(plan),
+                      plan=",".join(f"{w}x{b}" for _, w, b in plan))
         with self._phase("prefill_sync", iter=it, rows=len(wave)):
             for _, nxt, _t in pending_waves:
                 try:
@@ -1861,17 +1879,13 @@ class LLMEngine:
                             (req.first_token_at - req.submitted_at)
                             * 1000, 1)})
 
-    def _prefill_chunk_full(self, chunk):
-        """Full-prompt prefill (no cached prefix anywhere in the chunk):
-        the original bucketed wave path, byte-for-byte.  Returns the
-        first tokens (on device), the width bucket and the length
-        bucket of the program it dispatched."""
+    def _prefill_chunk_full(self, chunk, padded_w: int, bucket: int):
+        """Full-prompt prefill (no cached prefix anywhere in the chunk)
+        in the (padded_w, bucket) program the wave's plan chose.
+        Returns the first tokens (on device)."""
         import jax.numpy as jnp
 
         W = len(chunk)
-        bucket = next(b for b in self._buckets
-                      if b >= max(len(r.prompt) + len(r.tokens)
-                                  for _, r in chunk))
         # Pad by duplicating the last row: the duplicate writes the
         # same slot with the same data, so correctness is
         # unaffected.  Width is BUCKETED (1 / 8 / _chunk), not
@@ -1879,7 +1893,6 @@ class LLMEngine:
         # 64-wide wave paid 64x the prefill FLOPs it needed — the
         # round-3 idle-TTFT regression.  Few widths × few length
         # buckets keeps the compile count small.
-        padded_w = next(w for w in self._width_buckets if w >= W)
         tokens = np.zeros((padded_w, bucket), np.int32)
         true_lens = np.ones((padded_w,), np.int32)
         slots = np.zeros((padded_w,), np.int32)
@@ -1921,9 +1934,9 @@ class LLMEngine:
                 jnp.asarray(seeds), jnp.asarray(starts))
         # Duplicate padding rows target the same slot + same token.
         self._cur_dev = self._cur_dev.at[slots_dev].set(nxt)
-        return nxt, padded_w, bucket
+        return nxt
 
-    def _prefill_chunk_suffix(self, chunk):
+    def _prefill_chunk_suffix(self, chunk, padded_w: int, bucket: int):
         """Prefix-cache prefill: forward only each request's uncached
         SUFFIX, attending the cached prefix through the page pool; the
         suffix KV scatters at its absolute positions (prefill_from is a
@@ -1932,10 +1945,6 @@ class LLMEngine:
         import jax.numpy as jnp
 
         W = len(chunk)
-        suf = [len(r.prompt) + len(r.tokens) - r.prefill_from
-               for _, r in chunk]
-        bucket = next(b for b in self._buckets if b >= max(suf))
-        padded_w = next(w for w in self._width_buckets if w >= W)
         tokens = np.zeros((padded_w, bucket), np.int32)
         pos0 = np.zeros((padded_w,), np.int32)
         last_idx = np.zeros((padded_w,), np.int32)
@@ -1980,7 +1989,7 @@ class LLMEngine:
             self.cache, ks, vs, jnp.asarray(page_ids),
             jnp.asarray(rows), slots_dev, jnp.asarray(true_lens))
         self._cur_dev = self._cur_dev.at[slots_dev].set(nxt)
-        return nxt, padded_w, bucket
+        return nxt
 
     def _apply_import(self, slot: int, req: _Request) -> None:
         """Scatter a migrated request's KV pages into its freshly
@@ -2431,6 +2440,7 @@ class LLMEngine:
         tags = {"engine": self.name}
         cur = {"prefill_tokens": self.prefill_tokens,
                "prefill_padded_tokens": self.prefill_padded_tokens,
+               "prefill_programs": self.prefill_programs,
                "decode_tokens": self.decode_tokens,
                "decode_steps": self.decode_steps,
                "lane_steps_live": self.lane_steps_live,
@@ -2505,7 +2515,10 @@ class LLMEngine:
                    "lane_steps_live": self.lane_steps_live,
                    "phase_s": dict(self.phase_s),
                    "prefill_true_tokens": self.prefill_tokens,
-                   "prefill_padded_tokens": self.prefill_padded_tokens}}
+                   "prefill_padded_tokens": self.prefill_padded_tokens,
+                   "prefill_programs": self.prefill_programs,
+                   "prefill_waves": self.prefill_waves,
+                   "prefill_waves_split": self.prefill_waves_split}}
         if self._lora_banks is not None:
             with self._lora_lock:
                 now = time.monotonic()

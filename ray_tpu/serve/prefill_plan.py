@@ -1,0 +1,50 @@
+"""Plan one prefill wave over the (width, length) programs an engine
+already has.  Pure host arithmetic: no jax, no engine state."""
+from __future__ import annotations
+
+# Token positions under which a prefill program's device time stops
+# falling: the program streams every weight once however few tokens it
+# holds.  Read on a v5e at Mistral-7B widths (PERF.md §7 item 2): 1 x 64
+# 12.1 ms, 1 x 128 12.7, 1 x 256 15.1, 1 x 512 29.0, and 0.0465 ms a
+# position in the wide programs, at which 12.1 ms is 259 positions.  In
+# token positions, so it follows the chip's ridge (peak FLOP/s over peak
+# bytes/s), not the model's size.
+FLOOR_TOKENS = 256
+
+
+def program_cost(width: int, bucket: int) -> int:
+    """Token positions a (width, bucket) prefill program is charged."""
+    return max(width * bucket, FLOOR_TOKENS)
+
+
+def plan_wave(lengths: list[int], widths: list[int], buckets: list[int],
+              chunk: int) -> list[tuple[list[int], int, int]]:
+    """Partition a wave's rows into prefill programs of least total cost.
+
+    `lengths[i]` is the token count row i's prefill pads (prompt, or the
+    uncached suffix); `widths` and `buckets` are the engine's ascending
+    width and length buckets; no group exceeds `chunk` rows.  Rows are
+    ordered by length and cut into contiguous groups; a group runs at
+    the smallest width that holds it and the length bucket of its
+    longest row, so no program lies outside widths x buckets or outside
+    the span of the rows' own buckets.  Ties go to fewer programs: `w`
+    equal rows stay ONE w-wide program (what a warm-up that submits
+    exactly that relies on).  Returns (row indices, width, bucket) per
+    program, shortest first."""
+    order = sorted(range(len(lengths)), key=lengths.__getitem__)
+    bucket_of = [next(b for b in buckets if b >= lengths[i]) for i in order]
+    width_of = [0] + [next(w for w in widths if w >= g)
+                      for g in range(1, chunk + 1)]
+    # best[i] = (cost, programs, size of the last group) over rows [0, i)
+    best = [(0, 0, 0)]
+    for i in range(1, len(order) + 1):
+        best.append(min(
+            (best[i - g][0] + program_cost(width_of[g], bucket_of[i - 1]),
+             best[i - g][1] + 1, g)
+            for g in range(1, min(chunk, i) + 1)))
+    plan, i = [], len(order)
+    while i:
+        g = best[i][2]
+        plan.append((order[i - g:i], width_of[g], bucket_of[i - 1]))
+        i -= g
+    return plan[::-1]

@@ -1,7 +1,10 @@
 """The engine thread's own timeline (PR 25): `llm.loop.*` phase spans,
 the `loop` counters of `LLMEngine.stats()`, the kernels' names, and the
-benchmark's readers of them.  CPU, debug-sized model, a few seconds.
+benchmark's readers of them; and the engine running its prefill plans
+(PR 26; the planner alone is in test_prefill_plan.py).  CPU, debug-sized
+model, a few seconds.
 """
+import random
 import time
 
 import pytest
@@ -23,28 +26,36 @@ def small():
     return cfg, llama.init_params(jax.random.PRNGKey(7), cfg)
 
 
-def _engine(small, **kw):
+def _engine(small, paged=True, **kw):
     from ray_tpu.serve.llm import LLMEngine
 
     kw.setdefault("max_batch", 4)
     kw.setdefault("max_len", 256)
-    kw.setdefault("page_size", 16)
+    if paged:
+        kw.setdefault("page_size", 16)
     kw.setdefault("steps_per_sync", 4)
-    return LLMEngine(small[0], small[1], seed=0, paged=True, **kw)
+    return LLMEngine(small[0], small[1], seed=0, paged=paged, **kw)
 
 
-def _one_wave(eng, prompts, max_new_tokens):
+def _one_wave(eng, prompts, max_new_tokens, cache_ok=()):
     """Queue the prompts while the loop is stopped, so they ride ONE
-    wave (as the benchmark's warm-up does), and wait for them."""
+    wave (as the benchmark's warm-up does), and wait for them;
+    `cache_ok` names the rows that may match the prefix cache."""
     eng.stop()
-    futs = [eng.submit(p, max_new_tokens=max_new_tokens, _cache_ok=False)
-            for p in prompts]
+    futs = [eng.submit(p, max_new_tokens=max_new_tokens,
+                       _cache_ok=i in cache_ok)
+            for i, p in enumerate(prompts)]
     eng.start()
     return [f.result(timeout=120.0) for f in futs]
 
 
 def _prompt(n, off=0):
     return [(i * 7 + off) % 127 + 1 for i in range(n)]
+
+
+def _dispatch_spans(eng):
+    return [s for s in _loop_spans(eng._loop_trace[0])
+            if s["name"] == "llm.loop.prefill_dispatch"]
 
 
 def _loop_spans(trace_id=None):
@@ -133,7 +144,7 @@ def test_phases_hang_off_the_engine_root_and_iter_is_monotone(traced_run):
     assert disp[-1]["attrs"]["rows"] == 1
     assert set(disp[-1]["attrs"]) == {
         "iter", "rows", "width_bucket", "len_bucket", "true_tokens",
-        "padded_tokens", "chunks"}
+        "padded_tokens", "chunks", "plan"}
     dec = [s for s in spans if s["name"] == "llm.loop.decode_dispatch"]
     assert {s["attrs"]["steps"] for s in dec} == {4}
     assert max(s["attrs"]["lanes"] for s in dec) == 4
@@ -177,23 +188,124 @@ def test_consecutive_idle_iterations_are_one_span(small):
 
 
 def test_prefill_counters_by_hand(small):
-    eng = _engine(small)
+    from ray_tpu.serve.prefill_plan import FLOOR_TOKENS
+
+    eng = _engine(small)        # 4 lanes: widths {1, 4}, buckets 32 ... 256
     eng.start()
     try:
         s0 = eng.stats()
         _one_wave(eng, [_prompt(100, i) for i in range(3)], 1)
         s1 = eng.stats()
+        _one_wave(eng, [_prompt(200, 1), _prompt(20, 2), _prompt(100, 3)], 1)
+        s2 = eng.stats()
     finally:
         eng.stop()
-    width = next(w for w in eng._width_buckets if w >= 3)
-    length = next(b for b in eng._buckets if b >= 100)
-    d = {k: s1["loop"][k] - s0["loop"][k] for k in s1["loop"]
-         if k != "phase_s"}
-    assert (width, length) == (4, 128)
-    assert d["prefill_padded_tokens"] == width * length
+    assert (eng._width_buckets, FLOOR_TOKENS) == ([1, 4], 256)
+
+    def delta(a, b):
+        return {k: b["loop"][k] - a["loop"][k] for k in b["loop"]
+                if k != "phase_s"}
+
+    # three equal rows: 4 x 128 = 512 positions, under three 1 x 128 at
+    # the floor each (768): one program, as arrival order gave
+    d = delta(s0, s1)
+    assert d["prefill_padded_tokens"] == 4 * 128
     assert d["prefill_true_tokens"] == 300
     assert s1["loop"]["prefill_true_tokens"] == s1["prefill_tokens"]
     assert d["decode_steps"] == 0       # one token each: prefill only
+    assert (d["prefill_programs"], d["prefill_waves"],
+            d["prefill_waves_split"]) == (1, 1, 0)
+    # 200, 20 and 100 tokens: arrival order gave 4 x 256 = 1,024; by
+    # length, {20, 100} in 4 x 128 (512) and 200 in 1 x 256 (256) cost 768,
+    # as three 1-wide programs at the floor would: the tie goes to two
+    d = delta(s1, s2)
+    assert d["prefill_padded_tokens"] == 4 * 128 + 1 * 256
+    assert d["prefill_true_tokens"] == 320
+    assert (d["prefill_programs"], d["prefill_waves"],
+            d["prefill_waves_split"]) == (2, 1, 1)
+    first, second = [s["attrs"] for s in _dispatch_spans(eng)]
+    assert first == {
+        "iter": first["iter"], "rows": 3, "chunks": 1, "plan": "4x128",
+        "width_bucket": 4, "len_bucket": 128, "true_tokens": 300,
+        "padded_tokens": 512}
+    assert second == {
+        "iter": second["iter"], "rows": 3, "chunks": 2,
+        "plan": "4x128,1x256", "width_bucket": 4, "len_bucket": 256,
+        "true_tokens": 320, "padded_tokens": 768}
+
+
+@pytest.mark.parametrize("kind", ["paged", "dense", "cached_prefix"])
+def test_mixed_wave_gives_the_tokens_of_one_at_a_time(small, kind):
+    paged = kind != "dense"
+    kw = {"prefix_cache": True} if kind == "cached_prefix" else {}
+    # arrival order is not length order; three length buckets and more
+    prompts = [_prompt(200, 1), _prompt(20, 2), _prompt(100, 3),
+               _prompt(50, 4)]
+    plan, cached = "8x64,1x128,1x256", ()
+    ref_eng = _engine(small, paged, max_batch=8)
+    ref_eng.start()
+    eng = _engine(small, paged, max_batch=8, **kw)
+    eng.start()
+    try:
+        if kind == "cached_prefix":
+            # 48 committed tokens = 3 pages; row 4 shares them and
+            # prefills a 30-token suffix beside a 25-token full row
+            eng.generate(_prompt(48, 9), max_new_tokens=2)
+            prompts += [_prompt(48, 9) + _prompt(30, 5), _prompt(25, 6)]
+            cached = (4,)
+        ref = [ref_eng.generate(p, max_new_tokens=9, _cache_ok=False)["tokens"]
+               for p in prompts]
+        s0 = eng.stats()
+        got = [r["tokens"] for r in
+               _one_wave(eng, prompts, 9, cache_ok=cached)]
+        s1 = eng.stats()
+    finally:
+        eng.stop()
+        ref_eng.stop()
+    assert got == ref
+    assert all(len(t) == 9 for t in got)
+    span = _dispatch_spans(eng)[-1]["attrs"]
+    assert span["plan"] == plan and span["rows"] == len(prompts)
+    d = {k: s1["loop"][k] - s0["loop"][k] for k in
+         ("prefill_programs", "prefill_waves", "prefill_waves_split")}
+    assert d == {"prefill_programs": plan.count(",") + 1,
+                 "prefill_waves": 1, "prefill_waves_split": 1}
+    if kind == "cached_prefix":
+        assert s1["prefix_hit_tokens"] - s0["prefix_hit_tokens"] == 48
+        assert s1["prefill_tokens"] - s0["prefill_tokens"] \
+            == 200 + 20 + 100 + 50 + 30 + 25
+
+
+def test_no_compile_after_a_warmup_of_equal_rows(small):
+    """`bench_warmup`'s sequence (stop, submit `w` equal prompts, start)
+    for every (width, bucket) the traffic can reach, then mixed waves of
+    that range: the jitted prefill programs' caches do not grow."""
+    eng = _engine(small, max_batch=8)
+    lo, hi = 33, 128
+    buckets = [b for b in eng._buckets if 64 <= b <= 128]
+    eng.start()
+    try:
+        for b in buckets:
+            for w in eng._width_buckets:
+                _one_wave(eng, [[1 + (i + j) % 97 for j in range(min(b, hi))]
+                                for i in range(w)], 1)
+        programs = (eng._prefill_fwd, eng._scatter_pages)
+        sizes = [f._cache_size() for f in programs]
+        assert sizes[0] == len(buckets) * len(eng._width_buckets)
+        rng = random.Random(5)
+        s0 = eng.stats()["loop"]
+        for n in (1, 2, 3, 4, 5, 8, 8, 6, 2, 7):
+            _one_wave(eng, [_prompt(rng.randint(lo, hi), rng.randint(0, 90))
+                            for _ in range(n)], 1)
+        s1 = eng.stats()["loop"]
+    finally:
+        eng.stop()
+    assert [f._cache_size() for f in programs] == sizes
+    assert s1["prefill_waves"] - s0["prefill_waves"] == 10
+    assert s1["prefill_waves_split"] > s0["prefill_waves_split"]
+    plans = {p for s in _dispatch_spans(eng)
+             for p in s["attrs"]["plan"].split(",")}
+    assert plans <= {f"{w}x{b}" for w in eng._width_buckets for b in buckets}
 
 
 def test_lane_steps_live_by_hand(small):
@@ -335,6 +447,7 @@ def test_operator_metrics(small):
                     if v["tags"]["engine"] == "timeline-metrics")
 
     assert value("prefill_padded_tokens") == 4 * 32
+    assert value("prefill_programs") == 1
     assert value("prefill_tokens") == 60
     assert value("lane_steps_live") == 24 and value("decode_steps") == 8
     assert m["tpot"].boundaries == [1, 2, 5, 10, 15, 20, 25, 30, 40, 50,
