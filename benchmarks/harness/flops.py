@@ -1,37 +1,21 @@
-"""Operations and bytes the algorithm REQUIRES, from shapes alone.
+"""Operations and bytes the algorithm REQUIRES, from shapes alone: the
+arithmetic that holds for any model family (attention by heads and
+`head_dim`).  What depends on the architecture (how many parameters a
+token's step multiplies, how many layers call a kernel) is the family's
+(`families/<name>.py`).
 
 Every function takes the model as the plain dict of its published keys
-(`hidden_size`, `num_hidden_layers`, ...).  Causal attention is counted
-once: a query at position i attends i+1 keys, so a sequence of s tokens
-needs s(s+1)/2 query-key pairs, not s*s.  Recomputation (remat) is never
-counted: MFU is about required work.  A multiply-add is 2 operations.
+(`num_attention_heads`, `num_key_value_heads`, ...).  Causal attention
+is counted once: a query at position i attends i+1 keys, so a sequence
+of s tokens needs s(s+1)/2 query-key pairs, not s*s.  Recomputation
+(remat) is never counted: MFU is about required work.  A multiply-add is
+2 operations.
 """
 from __future__ import annotations
 
 
 def head_dim(m: dict) -> int:
     return m.get("head_dim") or m["hidden_size"] // m["num_attention_heads"]
-
-
-def param_count(m: dict) -> int:
-    """Parameters of the decoder as the program holds them (untied head,
-    two norms a layer and a final norm)."""
-    d, f, v = m["hidden_size"], m["intermediate_size"], m["vocab_size"]
-    hd = head_dim(m)
-    per_layer = (d * m["num_attention_heads"] * hd          # wq
-                 + 2 * d * m["num_key_value_heads"] * hd    # wk, wv
-                 + m["num_attention_heads"] * hd * d        # wo
-                 + 3 * d * f                                # gate, up, down
-                 + 2 * d)                                   # norms
-    return 2 * v * d + m["num_hidden_layers"] * per_layer + d
-
-
-def matmul_params(m: dict) -> int:
-    """Parameters that take part in a matmul for every token: all but the
-    embedding table (a lookup) and the norms."""
-    d = m["hidden_size"]
-    return (param_count(m) - m["vocab_size"] * d
-            - (2 * m["num_hidden_layers"] + 1) * d)
 
 
 def causal_pairs(s: int) -> int:
@@ -45,14 +29,15 @@ def attn_flops_fwd(pairs: int, m: dict) -> float:
     return 4.0 * pairs * m["num_attention_heads"] * head_dim(m)
 
 
-def train_flops_per_step(m: dict, batch: int, seq: int) -> float:
-    """Forward + backward of one optimizer step: 6 ops per matmul
-    parameter per token, plus causal attention forward (x1) and backward
-    (x2: dq, dk, dv need two matmul pairs)."""
+def train_flops_per_step(family, m: dict, batch: int, seq: int) -> float:
+    """Forward + backward of one optimizer step: 6 ops per parameter a
+    token's step multiplies, per token, plus causal attention forward
+    (x1) and backward (x2: dq, dk, dv need two matmul pairs) in each
+    layer that calls the flash kernel."""
     tokens = batch * seq
     attn = 3.0 * attn_flops_fwd(batch * causal_pairs(seq), m) \
-        * m["num_hidden_layers"]
-    return 6.0 * matmul_params(m) * tokens + attn
+        * family.kernel_layers(m, "flash_fwd")
+    return 6.0 * family.matmul_params(m) * tokens + attn
 
 
 def flash_fwd_cost(m: dict, lens: list[int]) -> tuple[float, float]:
@@ -89,9 +74,3 @@ def paged_attn_cost(m: dict, ctx_lens: list[int]) -> tuple[float, float]:
     flops = 4.0 * ctx * m["num_attention_heads"] * hd
     nbytes = 2.0 * ctx * 2 * m["num_key_value_heads"] * hd
     return flops, nbytes
-
-
-def decode_step_bytes(m: dict) -> float:
-    """Bytes a decode step must stream at the least: every matmul weight
-    once, bf16 (the KV read comes on top)."""
-    return 2.0 * matmul_params(m)

@@ -11,16 +11,27 @@ after), `rec` (train: the loop's record), `device`, `e2e`.
 from __future__ import annotations
 
 import json
+import re
 
-from . import flops, peaks, stats, trace_reduce
+from . import flops, peaks, spec, stats, trace_reduce
 
+# the programs, by the names `jax.jit` gives the engine's and the train
+# step's functions: a program of any family keeps them
 DECODE_PROGRAM = r"decode_k"
 PREFILL_PROGRAMS = r"prefill_fwd_only|prefill_suffix|scatter"
+FLASH_PREFILL_PROGRAM = r"prefill_fwd_only"
 TRAIN_PROGRAM = r"jit_step"
-# the Pallas kernels reach the device as custom calls; which kernel one is
-# follows from the program it runs in (see PERF.md, open questions: the
-# kernels carry no name of their own yet)
-KERNEL_OP = r" custom-call( |$)"
+
+
+def kernel_op(*names: str) -> str:
+    """Pattern of the op events of the Pallas kernels of these names.
+    `pl.pallas_call(name=...)` reaches the `XLA Ops` line as `<name>.N
+    custom-call`; where the call was differentiated the name is wrapped
+    (`jvp_<name>_.N`, `transpose_jvp_<name>__.N`).  A roofline reads the
+    kernels it is named after: another kernel in the same program has
+    another name and is not read."""
+    alt = "|".join(re.escape(n) for n in names)
+    return rf"(^|_)({alt})_*\.\d+ custom-call( |$)"
 
 
 def in_window(run: dict, spans=None) -> list[dict]:
@@ -98,14 +109,20 @@ def prefill_ms_per_ktok(run: dict) -> float | None:
     return t * 1e3 / cut / (toks / 1000.0)
 
 
-def roofline_pct(run: dict, program: str, need_flops: float,
+def kernel_layers(run: dict, kernel: str) -> int:
+    """How many layers of the run's model call the kernel of that name:
+    the family's count, never the depth."""
+    return spec.family_of(run["cell"]).kernel_layers(run["model"], kernel)
+
+
+def roofline_pct(run: dict, program: str, kernels: tuple, need_flops: float,
                  need_bytes: float, log_name: str) -> float | None:
     """Least time by the chip's peaks over the measured self time of the
-    kernel's events inside `program`, in percent."""
+    events of the kernels named `kernels` inside `program`, in percent."""
     red = traced(run)
     if red is None:
         return None
-    n, t = trace_reduce.op_time(red, program, KERNEL_OP)
+    n, t = trace_reduce.op_time(red, program, kernel_op(*kernels))
     if not n or t <= 0 or need_flops <= 0:
         return None
     least, bound = peaks.roofline_s(need_flops, need_bytes,
@@ -122,9 +139,9 @@ def flash_fwd_roofline(run: dict) -> float | None:
         return None
     lens = [int(s["attrs"].get("prompt_tokens", 0)) for s in sp]
     fl, by = flops.flash_fwd_cost(run["model"], lens)
-    scale = run["model"]["num_hidden_layers"] * cut
-    return roofline_pct(run, r"prefill_fwd_only", fl * scale, by * scale,
-                        "kernel.flash_fwd_roofline")
+    scale = kernel_layers(run, "flash_fwd") * cut
+    return roofline_pct(run, FLASH_PREFILL_PROGRAM, ("flash_fwd",),
+                        fl * scale, by * scale, "kernel.flash_fwd_roofline")
 
 
 def paged_attn_roofline(run: dict) -> float | None:
@@ -152,28 +169,31 @@ def paged_attn_roofline(run: dict) -> float | None:
     if not ctx:
         return None
     fl, by = flops.paged_attn_cost(run["model"], ctx)
-    layers = run["model"]["num_hidden_layers"]
-    return roofline_pct(run, DECODE_PROGRAM, fl * layers, by * layers,
-                        "kernel.paged_attn_roofline")
+    layers = kernel_layers(run, "paged_attn")
+    return roofline_pct(run, DECODE_PROGRAM, ("paged_attn",), fl * layers,
+                        by * layers, "kernel.paged_attn_roofline")
 
 
 def flash_bwd_roofline(run: dict) -> float | None:
-    """Train step: the three kernel calls of a layer are the forward and
-    the two backward kernels; the backward's share of the kernels' time
-    cannot be told from the op names alone (PERF.md, open questions), so
-    this reads forward + backward together against the work of both."""
+    """Train step: the three kernel calls of an attention layer, the
+    forward and the two backward kernels, read together against the work
+    of all three (`kernel.flash_bwd_only_roofline` reads the backward
+    alone)."""
     rec = run.get("rec") or {}
     if not rec.get("trace_steps"):
         return None
     t = run["cell"].config["train"]
-    chips = run["cell"].chips
-    layers = run["model"]["num_hidden_layers"]
+    model = run["model"]
     # per chip: batch over fsdp, heads over tensor
-    f_f, b_f = flops.flash_fwd_cost(run["model"], [t["seq"]] * t["batch"])
-    f_b, b_b = flops.flash_bwd_cost(run["model"], t["batch"], t["seq"])
-    n = rec["trace_steps"] * layers / chips
-    return roofline_pct(run, TRAIN_PROGRAM, (f_f + f_b) * n,
-                        (b_f + b_b) * n, "kernel.flash_bwd_roofline")
+    per_chip = rec["trace_steps"] / run["cell"].chips
+    n_f = per_chip * kernel_layers(run, "flash_fwd")
+    n_b = per_chip * kernel_layers(run, "flash_bwd_dq")
+    f_f, b_f = flops.flash_fwd_cost(model, [t["seq"]] * t["batch"])
+    f_b, b_b = flops.flash_bwd_cost(model, t["batch"], t["seq"])
+    return roofline_pct(run, TRAIN_PROGRAM,
+                        ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"),
+                        f_f * n_f + f_b * n_b, b_f * n_f + b_b * n_b,
+                        "kernel.flash_bwd_roofline")
 
 
 def train_mfu_pct(run: dict) -> float | None:
@@ -181,7 +201,8 @@ def train_mfu_pct(run: dict) -> float | None:
     if not rec:
         return None
     t = run["cell"].config["train"]
-    need = flops.train_flops_per_step(run["model"], t["batch"], t["seq"])
+    need = flops.train_flops_per_step(spec.family_of(run["cell"]),
+                                      run["model"], t["batch"], t["seq"])
     per_s = need * rec["steps"] / rec["window_s"]
     peak = peaks.peaks_for(run["device"]["kind"])["bf16_flops"]
     return 100.0 * per_s / (run["cell"].chips * peak)

@@ -10,7 +10,7 @@ import time
 
 from ray_tpu.serve.llm import LLMServer
 
-from . import model as model_mod
+from . import spec
 
 
 def _device_info() -> dict:
@@ -39,18 +39,18 @@ def seed_key(seed: int):
 
 
 class BenchLLMServer(LLMServer):
-    def __init__(self, model: dict, *, seed: int, **engine_kw):
+    def __init__(self, model: dict, *, family: str, seed: int, **engine_kw):
         import jax
 
-        from ray_tpu.models import llama
-
-        cfg = model_mod.llama_config(model, max_seq=engine_kw["max_len"])
+        fam = spec.load_family(family, "serve")
+        cfg = fam.program_config(model, max_seq=engine_kw["max_len"])
         t0 = time.perf_counter()
         # ONE jitted program makes every weight on the device from the
         # seed, in the dtype it is served in (PR 22: eager init compiled
         # a program per distinct shape, ~80 s cold).
-        params = jax.jit(lambda k: llama.init_params(k, cfg))(seed_key(seed))
+        params = jax.jit(lambda k: fam.init_params(k, cfg))(seed_key(seed))
         jax.block_until_ready(params)
+        self._bench_family = fam
         self._bench_model = dict(model)
         self._bench_times = {"init_params_s": time.perf_counter() - t0}
         t0 = time.perf_counter()
@@ -132,12 +132,11 @@ class BenchLLMServer(LLMServer):
 
     # ----------------------------------------------------------- correct
     def bench_reference(self, samples: list) -> dict:
-        """Teacher-forced logit gaps of served tokens under the plain
-        reference, on the parameters this replica serves."""
-        from .refs import decoder
-
+        """Teacher-forced logit gaps of served tokens under the family's
+        plain reference, on the parameters this replica serves."""
+        ref = self._bench_family.reference()
         t0 = time.perf_counter()
-        gaps = [decoder.teacher_forced_gaps(
+        gaps = [ref.teacher_forced_gaps(
             self.engine.params, prompt, served, self._bench_model)
             for prompt, served in samples]
         return {"gaps": gaps, "wall_s": time.perf_counter() - t0}

@@ -6,32 +6,19 @@ from __future__ import annotations
 import os
 import time
 
-from . import cluster, loadgen, model as model_mod, spec, stats
+from . import cluster, loadgen, spec, stats
 
-# Teacher-forced logit gap a served token may show under the float32
-# reference.  The served path computes in bfloat16 (8 bits of mantissa):
-# a rounding of 2**-9 relative in each of ~10 matmul-and-residual stages
-# per layer gives the final hidden state a relative error of some 1e-2,
-# and logits of random weights have unit scale, so near-ties flip at
-# gaps of a few 1e-2.  On the chip the largest gap read was 0.0616
-# (mistral d16, 32 runs) and 0.0397 (codestral d8, 22 runs), and 93-120
-# of 120 scored tokens were the reference's own choice (my chip runs,
-# PR 24).  A skipped layer or fp8 weights (3 bits of mantissa, 32x the
-# rounding) move logits by tenths to whole units: a served token is then
-# about 4 below the reference's maximum, far outside.
-REFERENCE_GAP_TOL = 0.15
+# the served tokens the reference scores; the tolerance on their
+# teacher-forced logit gap is the family's (`REFERENCE_GAP_TOL`)
 SAMPLE_REQUESTS = 4
 SAMPLE_NEW_TOKENS = 24
 
 
 def rehearsal_cell(cell: spec.Cell) -> None:
     """Shrink a cell IN MEMORY to debug-sized shapes for the CPU
-    rehearsal: tiny widths, every length a sixteenth.  A rehearsal walks
-    the code and can never report `correct: true`."""
-    cell.config.update(hidden_size=128, num_attention_heads=4,
-                       num_key_value_heads=2, head_dim=32,
-                       intermediate_size=256, vocab_size=512,
-                       num_hidden_layers=2)
+    rehearsal: the family's tiny widths, every length a sixteenth.  A
+    rehearsal walks the code and can never report `correct: true`."""
+    cell.family.rehearsal(cell.config)
     eng = cell.config["engine"]
     eng.update(max_len=eng["max_len"] // 16, page_size=eng["page_size"] // 16)
     t = cell.traffic
@@ -86,7 +73,8 @@ def run(cell: spec.Cell, args, log, t_process_wall: float) -> dict:
     from ray_tpu import serve
 
     cfg, t = cell.config, cell.traffic
-    model = model_mod.published(cfg)
+    model = cell.family.published(cfg)
+    vocab = cell.family.vocab_size(model)
     eng_kw = dict(cfg["engine"], paged=True)
     transport = t.get("transport", "stream")
     seconds = float(args.seconds)
@@ -96,13 +84,12 @@ def run(cell: spec.Cell, args, log, t_process_wall: float) -> dict:
     # the schedule is made before anything is started: a pure function
     # of (traffic file, seed, seconds)
     if cell.loop == "open":
-        reqs = loadgen.open_schedule(t, model["vocab_size"], args.seed,
-                                     seconds, rate_rps=args.rate)
+        reqs = loadgen.open_schedule(t, vocab, args.seed, seconds,
+                                     rate_rps=args.rate)
     else:
-        reqs = loadgen.closed_pool(t, model["vocab_size"], args.seed)
+        reqs = loadgen.closed_pool(t, vocab, args.seed)
     lo, hi = t["prompt_len"]["clip"]
-    sample = _sample_requests(model["vocab_size"], args.seed, lo, hi,
-                              eng_kw["max_len"])
+    sample = _sample_requests(vocab, args.seed, lo, hi, eng_kw["max_len"])
     log(step="schedule", loop=cell.loop, n=len(reqs), transport=transport,
         rate_rps=args.rate or (t.get("arrivals") or {}).get("rate_rps"),
         clients=t.get("clients"),
@@ -124,7 +111,8 @@ def run(cell: spec.Cell, args, log, t_process_wall: float) -> dict:
         t0 = time.perf_counter()
         app = serve.deployment(BenchServer()).options(
             name="llm", ray_actor_options={"num_tpus": cell.chips},
-            **cfg["deployment"]).bind(model, seed=args.seed, **eng_kw)
+            **cfg["deployment"]).bind(model, family=cell.family_name,
+                                      seed=args.seed, **eng_kw)
         handle = serve.run(app, name="bench", timeout_s=900.0)
         run_rec["setup"]["serve_run_s"] = time.perf_counter() - t0
 
@@ -192,7 +180,7 @@ def run(cell: spec.Cell, args, log, t_process_wall: float) -> dict:
                 run_rec["trace"] = call("bench_trace_reduce", dump)
             except Exception as e:  # noqa: BLE001 - no trace, no trace metrics
                 run_rec["problems"].append(f"trace reduction: {e}"[:300])
-        _check_outputs(run_rec, sent, model, sample, send, clock, call, log)
+        _check_outputs(run_rec, sent, vocab, sample, send, clock, call, log)
         run_rec["device"] = {**dev, **call("bench_device_stats")}
         serve.delete("bench")
         serve.shutdown()
@@ -239,15 +227,15 @@ def _sample_requests(vocab, seed, lo, hi, max_len) -> list[loadgen.Request]:
     return out
 
 
-def _check_outputs(run_rec, sent, model, sample, send, clock, call,
+def _check_outputs(run_rec, sent, vocab, sample, send, clock, call,
                    log) -> None:
     """`correct`, decided outside the timed window."""
     import threading
 
     problems = run_rec["problems"]
-    vocab = model["vocab_size"]
     t0, t1 = run_rec["window"]
     cell = run_rec["cell"]
+    tol = cell.family.REFERENCE_GAP_TOL
     if cell.loop == "open":
         measured = [r for r in sent if t0 <= r.due_s < t1]
     else:
@@ -299,14 +287,13 @@ def _check_outputs(run_rec, sent, model, sample, send, clock, call,
     ref = call("bench_reference", [(r.prompt, r.tokens) for r in sample])
     worst = max(max(g) for g in ref["gaps"])
     exact = sum(g == 0.0 for gs in ref["gaps"] for g in gs)
-    log(step="reference", worst_logit_gap=worst, tolerance=REFERENCE_GAP_TOL,
+    log(step="reference", worst_logit_gap=worst, tolerance=tol,
         identical_prompts_identical_tokens=same, parted_at=part,
         tokens_scored=sum(len(g) for g in ref["gaps"]),
         tokens_the_reference_also_chose=exact, wall_s=ref["wall_s"])
-    if not worst <= REFERENCE_GAP_TOL:
+    if not worst <= tol:
         problems.append(f"a served token's reference logit is {worst:.4f} "
-                        f"below the reference maximum (tolerance "
-                        f"{REFERENCE_GAP_TOL})")
+                        f"below the reference maximum (tolerance {tol})")
 
 
 # --------------------------------------------------- end-to-end metrics

@@ -1,7 +1,8 @@
 """Finds everything by name: a cell names a configuration and a traffic
-mix, each a data file; a per-layer metric is one reader file under
-`metrics/`.  Nothing here knows a cell, so a later PR adds files and
-`BENCHMARK.json` entries and edits none."""
+mix, each a data file; a configuration names its model family, one file
+under `harness/families/`; a per-layer metric is one reader file under
+`metrics/`.  Nothing here knows a cell or an architecture, so a later PR
+adds files and `BENCHMARK.json` entries and edits none."""
 from __future__ import annotations
 
 import dataclasses
@@ -14,6 +15,15 @@ BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+DEFAULT_FAMILY = "llama"        # a configuration file without `family`
+# what a family file gives (README, "A model family"); the tolerances by
+# the kind of cell that holds the program to them
+FAMILY_ATTRS = ("published", "vocab_size", "program_config", "init_params",
+                "reference", "rehearsal", "param_count", "matmul_params",
+                "decode_step_bytes", "kernel_layers")
+FAMILY_TOLERANCES = {
+    "serve": ("REFERENCE_GAP_TOL",),
+    "train": ("LOGPROB_RMS_TOL", "GRAD_NORM_RTOL", "LOSS_RTOL")}
 
 
 def load_json(path: str) -> dict:
@@ -32,6 +42,7 @@ class Cell:
     config_name: str
     traffic_name: str
     config: dict            # configs/<name>.json as it is run
+    family: object          # harness/families/<config["family"]>.py
     traffic: dict           # traffic/<name>.json
     end_to_end: list[dict]  # BENCHMARK.json entries this cell reports
     per_layer: list[dict]
@@ -39,6 +50,10 @@ class Cell:
     @property
     def kind(self) -> str:
         return self.config["kind"]          # "serve" | "train"
+
+    @property
+    def family_name(self) -> str:
+        return family_name(self.config)
 
     @property
     def loop(self) -> str:
@@ -59,13 +74,61 @@ def load_cell(workload: str, root: str = ROOT) -> Cell:
     config = load_json(os.path.join(root, c["file"]))
     traffic = load_json(os.path.join(
         root, bench["paths"][0], "traffic", w["traffic"] + ".json"))
+    family = config_family(config)
     return Cell(
         name=w["name"], chips=int(w["chips"]), config_name=c["name"],
-        traffic_name=w["traffic"], config=config, traffic=traffic,
+        traffic_name=w["traffic"], config=config, family=family,
+        traffic=traffic,
         end_to_end=[m for m in bench["end_to_end"]
                     if _applies(m, w["name"])],
         per_layer=[m for m in bench["per_layer"]
                    if _applies(m, w["name"])])
+
+
+def family_path(name: str) -> str:
+    return os.path.join(BENCH_DIR, "harness", "families", name + ".py")
+
+
+def _load_by_path(prefix: str, name: str, path: str):
+    """A module by its file: the names hold dots, and a later PR's file
+    need not be importable as a package member."""
+    spec = importlib.util.spec_from_file_location(
+        prefix + re.sub(r"\W", "_", name), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_family(name: str, kind: str | None = None):
+    """The module of model family `name`: `harness/families/<name>.py`.
+    A name with no file, or a file that lacks an attribute (of
+    FAMILY_ATTRS, and of the tolerances a cell of `kind` needs), stops
+    the run here, before anything is started."""
+    path = family_path(name)
+    if not NAME_RE.match(name) or not os.path.isfile(path):
+        raise SystemExit(f"no model family {name!r}: no file {path}")
+    mod = _load_by_path("bench_family_", name, path)
+    for attr in FAMILY_ATTRS + FAMILY_TOLERANCES.get(kind, ()):
+        if not hasattr(mod, attr):
+            raise SystemExit(f"{path} declares no {attr}")
+    return mod
+
+
+def family_name(config: dict) -> str:
+    return config.get("family", DEFAULT_FAMILY)
+
+
+def config_family(config: dict):
+    """The family a configuration file names (none: the default), held
+    to what a cell of the file's `kind` needs."""
+    return load_family(family_name(config), config.get("kind"))
+
+
+def family_of(cell):
+    """A cell's family module; a cell built by hand (a test's stand-in)
+    has only its config to name one."""
+    fam = getattr(cell, "family", None)
+    return fam if fam is not None else config_family(cell.config)
 
 
 def metric_path(name: str) -> str:
@@ -77,10 +140,7 @@ def load_reader(name: str):
     declares LAYER, SOURCE, MOVES, UNIT, BETTER and `read(run) -> float |
     None`.  Loaded by path: the names hold dots."""
     path = metric_path(name)
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + re.sub(r"\W", "_", name), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
+    mod = _load_by_path("bench_metric_", name, path)
     for attr in ("LAYER", "SOURCE", "MOVES", "UNIT", "BETTER", "read"):
         if not hasattr(mod, attr):
             raise AttributeError(f"{path} declares no {attr}")

@@ -14,14 +14,13 @@ from __future__ import annotations
 
 import json
 
-from . import flops, peaks, readers, stats, trace_reduce
+from . import flops, readers, stats
 
 PREFIX = "llm.loop."
 # phases in which the device can only wait for the host; the two `_sync`
 # phases are the host waiting for the device, `idle` is nobody waiting
 HOST_PHASES = ("admit", "prefill_dispatch", "fund", "decode_dispatch",
                "deliver")
-FLASH_BWD_OP = r"flash_bwd_d(q|kv).* custom-call( |$)"
 
 
 def _log(**kv) -> None:
@@ -154,18 +153,13 @@ def flash_bwd_only_roofline(run: dict) -> float | None:
     """Train step: least time for the flash BACKWARD's work alone over
     the self time of the two backward kernels, told by their names."""
     rec = run.get("rec") or {}
-    red = readers.traced(run)
-    if red is None or not rec.get("trace_steps"):
-        return None
-    n_ev, t = trace_reduce.op_time(red, readers.TRAIN_PROGRAM, FLASH_BWD_OP)
-    if not n_ev or t <= 0:
+    if not rec.get("trace_steps"):
         return None
     tr = run["cell"].config["train"]
     f_b, b_b = flops.flash_bwd_cost(run["model"], tr["batch"], tr["seq"])
     # per chip: batch over fsdp, heads over tensor
-    n = rec["trace_steps"] * run["model"]["num_hidden_layers"] \
-        / run["cell"].chips
-    least, bound = peaks.roofline_s(f_b * n, b_b * n, run["device"]["kind"])
-    _log(step="roofline", metric="kernel.flash_bwd_only_roofline",
-         bound=bound, kernel_events=n_ev, kernel_s=t, least_s=least)
-    return 100.0 * least / t
+    n = rec["trace_steps"] / run["cell"].chips \
+        * readers.kernel_layers(run, "flash_bwd_dq")
+    return readers.roofline_pct(
+        run, readers.TRAIN_PROGRAM, ("flash_bwd_dq", "flash_bwd_dkv"),
+        f_b * n, b_b * n, "kernel.flash_bwd_only_roofline")

@@ -4,24 +4,22 @@ from __future__ import annotations
 
 import time
 
-from . import model as model_mod, spec
+from . import spec
 
 
 def rehearsal_cell(cell: spec.Cell) -> None:
-    cell.config.update(hidden_size=128, num_attention_heads=4,
-                       num_key_value_heads=2, head_dim=32,
-                       intermediate_size=256, vocab_size=512,
-                       num_hidden_layers=2)
+    cell.family.rehearsal(cell.config)
     cell.config["train"].update(seq=128)
 
 
 def run(cell: spec.Cell, args, log, t_process_wall: float) -> dict:
     cfg, t = cell.config, cell.traffic
-    run_rec: dict = {"cell": cell, "model": model_mod.published(cfg),
+    run_rec: dict = {"cell": cell, "model": cell.family.published(cfg),
                      "seconds": float(args.seconds), "setup": {},
                      "problems": [], "trace": None, "spans": []}
     loop_config = {
-        "model": run_rec["model"], "train": cfg["train"], "seed": args.seed,
+        "model": run_rec["model"], "family": cell.family_name,
+        "train": cfg["train"], "seed": args.seed,
         "seconds": float(args.seconds), "chips": cell.chips,
         "trace": bool(args.trace), "dump_trace": bool(args.dump_trace),
         "rehearse": bool(args.rehearse), "cell": cell.name,

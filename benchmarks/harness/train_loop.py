@@ -14,45 +14,28 @@ import time
 # gradient norm it reports come from the forward, backward, collectives
 # and kernels the window runs; the schedule's first learning rate is 0,
 # so this step moves no weight) and, per position, by the program's
-# forward (`token_logprobs`).  The float32 reference scores the same
-# batch on the same parameters, its backward pass written out.
-#
-# At random init the MEAN loss sits at ln(vocab) + ~0.5 whatever the
-# layers compute (the final RMSNorm fixes the logit scale), so it proves
-# little alone.  Read on the CPU at hidden 256-1024, 4-16 layers, against
-# the float32 reference (PR 24; the chip's readings are in PERF.md):
-#
-#   the program's          mean loss   gradient norm   log-prob, rms
-#   bfloat16 as it is      4e-5..1e-4  5e-4            0.013-0.014
-#   weights through fp8    1e-4..1e-3  5e-3..1e-2      0.12-0.28
-#   one layer skipped      1e-3..1e-2  3e-2..1e-1      0.40-1.5
-#   no attention (wo = 0)  7e-3..2e-2  1.0-2.4         1.3-1.5
-#
-# So the per-position log-probabilities carry the forward check; the
-# gradient norm carries the backward one, but the step reports it in
-# bfloat16 (`optax.global_norm` over bfloat16 gradients: 2**-8 = 0.4 % of
-# rounding in the number itself), so its bound cannot go under ~1 % and
-# sees a lost collective, a dropped layer's gradients or a wrong scale,
-# not an fp8 backward.
-LOGPROB_RMS_TOL = 0.08
-GRAD_NORM_RTOL = 1.5e-2
-LOSS_RTOL = 2e-3
+# forward (`token_logprobs`).  The family's float32 reference scores the
+# same batch on the same parameters, its backward pass written out.  The
+# three tolerances, and the readings each was set from, are the family's
+# (`families/<name>.py`: LOGPROB_RMS_TOL, GRAD_NORM_RTOL, LOSS_RTOL).
 
 
-def judge(program: dict, reference: dict) -> list[str]:
+def judge(program: dict, reference: dict, family) -> list[str]:
     """Problems of the program's reading of the check batch against the
-    reference's.  Both: {"loss", "grad_norm", "logprobs" [b, s]}."""
+    reference's, by the family's tolerances.  Both: {"loss", "grad_norm",
+    "logprobs" [b, s]}."""
     import numpy as np
 
     problems = []
     d = (np.asarray(program["logprobs"], np.float64)
          - np.asarray(reference["logprobs"], np.float64))
     rms = float(np.sqrt(np.mean(d * d)))
-    if not rms <= LOGPROB_RMS_TOL:
+    if not rms <= family.LOGPROB_RMS_TOL:
         problems.append(
             f"per-position log-probabilities differ from the reference's "
-            f"by {rms:.4f} rms, tolerance {LOGPROB_RMS_TOL}")
-    for key, tol in (("loss", LOSS_RTOL), ("grad_norm", GRAD_NORM_RTOL)):
+            f"by {rms:.4f} rms, tolerance {family.LOGPROB_RMS_TOL}")
+    for key, tol in (("loss", family.LOSS_RTOL),
+                     ("grad_norm", family.GRAD_NORM_RTOL)):
         rel = abs(program[key] - reference[key]) / abs(reference[key])
         if not rel <= tol:
             problems.append(
@@ -68,12 +51,12 @@ def loop(config: dict) -> dict:
     from ray_tpu.parallel.mesh import MeshConfig, create_mesh
     from ray_tpu.train import step as train_step
 
-    from . import model as model_mod, trace_reduce
-    from .refs import decoder
+    from . import spec, trace_reduce
     from .replica import _device_info, seed_key
 
     rec: dict = {"problems": [], "times": {}}
     t = config["train"]
+    fam = spec.load_family(config["family"], "train")
     model, seed = config["model"], int(config["seed"])
     seconds, every = float(config["seconds"]), int(t["loss_every"])
     devs = jax.devices()
@@ -84,8 +67,8 @@ def loop(config: dict) -> dict:
     if len(devs) != int(config["chips"]):
         raise RuntimeError(f"{len(devs)} devices, the cell asks for "
                            f"{config['chips']}")
-    cfg = model_mod.llama_config(model, max_seq=t["seq"],
-                                 remat_mode=t["remat_mode"])
+    cfg = fam.program_config(model, max_seq=t["seq"],
+                             remat_mode=t["remat_mode"])
     mesh = create_mesh(MeshConfig(**t["mesh"]), devices=devs)
     optimizer = getattr(train_step, t["optimizer"])(
         total_steps=t["total_steps"])
@@ -96,7 +79,7 @@ def loop(config: dict) -> dict:
     rec["times"]["sharded_init_s"] = time.perf_counter() - t0
 
     rng = np.random.default_rng([seed, 5])
-    toks = rng.integers(0, model["vocab_size"],
+    toks = rng.integers(0, fam.vocab_size(model),
                         (config["distinct_batches"], t["batch"],
                          t["seq"] + 1), dtype=np.int32)
     b_sh = train_step.batch_shardings(mesh)
@@ -109,8 +92,8 @@ def loop(config: dict) -> dict:
     n_seq, n_pos = int(chk["sequences"]), min(int(chk["positions"]), t["seq"])
     check_toks = toks[0, :n_seq, :n_pos + 1]
     t0 = time.perf_counter()
-    ref = decoder.loss_and_gradient(state.params, check_toks[:, :-1],
-                                    check_toks[:, 1:], model)
+    ref = fam.reference().loss_and_gradient(
+        state.params, check_toks[:, :-1], check_toks[:, 1:], model)
     ref["logprobs"] = np.asarray(ref["logprobs"])
     rec["times"]["reference_s"] = time.perf_counter() - t0
     t0 = time.perf_counter()
@@ -177,7 +160,7 @@ def loop(config: dict) -> dict:
     elif not losses[-1] < losses[0]:
         rec["problems"].append(
             f"the loss did not fall over the run: {losses}")
-    rec["problems"] += judge(prog, ref)
+    rec["problems"] += judge(prog, ref, fam)
     d = prog["logprobs"] - ref["logprobs"]
     rec["check"] = {
         "sequences": n_seq, "positions": n_pos,

@@ -19,6 +19,7 @@ MISTRAL = dict(hidden_size=4096, num_hidden_layers=16,
 CODESTRAL = dict(hidden_size=6144, num_hidden_layers=8,
                  num_attention_heads=48, num_key_value_heads=8, head_dim=128,
                  intermediate_size=16384, vocab_size=32768)
+LLAMA = spec.load_family("llama")     # the family both shapes are of
 
 
 # ------------------------------------------------------------ arithmetic
@@ -147,13 +148,13 @@ def test_open_loop_times_from_due_and_reports_failures():
 
 # ----------------------------------------------------------------- flops
 def test_param_counts_of_both_shapes():
-    assert flops.param_count(MISTRAL) == 3_758_231_552
-    assert flops.param_count(CODESTRAL) == 3_523_319_808
+    assert LLAMA.param_count(MISTRAL) == 3_758_231_552
+    assert LLAMA.param_count(CODESTRAL) == 3_523_319_808
     # by hand, Mistral d16: embed + head 2*32768*4096; a layer:
     # 4096*4096*2 (wq, wo) + 2*4096*1024 (wk, wv) + 3*4096*14336 + 2*4096
     layer = 2 * 4096 * 4096 + 2 * 4096 * 1024 + 3 * 4096 * 14336 + 2 * 4096
-    assert flops.param_count(MISTRAL) == 2 * 32768 * 4096 + 16 * layer + 4096
-    assert flops.matmul_params(MISTRAL) == \
+    assert LLAMA.param_count(MISTRAL) == 2 * 32768 * 4096 + 16 * layer + 4096
+    assert LLAMA.matmul_params(MISTRAL) == \
         32768 * 4096 + 16 * (layer - 2 * 4096)
 
 
@@ -169,10 +170,11 @@ def test_causal_attention_is_counted_once():
 
 def test_train_flops_by_hand_no_recompute():
     m = dict(MISTRAL, num_hidden_layers=20)
-    n = flops.matmul_params(m)
+    n = LLAMA.matmul_params(m)
     attn = 3 * 4.0 * 4 * (4096 * 4097 // 2) * 32 * 128 * 20
     want = 6.0 * n * 4 * 4096 + attn
-    assert flops.train_flops_per_step(m, 4, 4096) == pytest.approx(want)
+    assert flops.train_flops_per_step(LLAMA, m, 4, 4096) == \
+        pytest.approx(want)
     assert want == pytest.approx(0.49e15, rel=0.05)   # the issue's 0.49 PFLOP
 
 
@@ -184,8 +186,8 @@ def test_kernel_costs():
     assert b == 2.0 * 400 * 2 * 8 * 128 and f == 4.0 * 400 * 32 * 128
     f2, _ = flops.flash_bwd_cost(MISTRAL, 1, 2048)
     assert f2 == 2.5 * flops.flash_fwd_cost(MISTRAL, [2048])[0]
-    assert flops.decode_step_bytes(MISTRAL) == \
-        2.0 * flops.matmul_params(MISTRAL)
+    assert LLAMA.decode_step_bytes(MISTRAL) == \
+        2.0 * LLAMA.matmul_params(MISTRAL)
 
 
 # ----------------------------------------------------------------- peaks
@@ -269,6 +271,7 @@ def test_every_name_resolves_and_uses_allowed_characters():
     for w in bench["workloads"]:
         cell = spec.load_cell(w["name"])
         assert cell.kind in ("serve", "train")
+        assert cell.family is not None and cell.family_name == "llama"
         assert cell.loop in ("open", "closed", "steps")
         assert cell.config["reduced"] == ["num_hidden_layers"]
         assert {"source", "assumed", "stands_for"} <= set(cell.config)
@@ -296,14 +299,24 @@ def test_every_name_resolves_and_uses_allowed_characters():
 
 
 def test_a_cell_of_each_kind_is_added_by_files_alone(tmp_path, monkeypatch):
-    """A throw-away configuration, traffic mix, cell and per-layer metric:
-    files and BENCHMARK.json entries only."""
+    """A throw-away configuration of a second model family, traffic mix,
+    cell and per-layer metric: files and BENCHMARK.json entries only."""
     bdir = tmp_path / "benchmarks"
     for d in ("configs", "traffic"):
         (bdir / d).mkdir(parents=True)
     cfg = spec.load_json(os.path.join(
         spec.BENCH_DIR, "configs", "mistral-7b-v0.3-d16.json"))
     cfg["num_hidden_layers"] = 12
+    # the family file of a later PR (`data/family_example.py`): named by
+    # the configuration, which spells its keys the family's way
+    cfg.update(family="example", norm_eps=cfg.pop("rms_norm_eps"),
+               rope_parameters={"rope_theta": cfg.pop("rope_theta")},
+               layer_types=["conv", "conv", "full_attention"] * 4)
+    real_family = spec.family_path
+    monkeypatch.setattr(
+        spec, "family_path",
+        lambda n: os.path.join(HERE, "data", "family_example.py")
+        if n == "example" else real_family(n))
     (bdir / "configs" / "extra-d12.json").write_text(json.dumps(cfg))
     tr = dict(_traffic("chat-poisson"),
               arrivals={"process": "gamma", "cv": 3.0, "rate_rps": 7.0})
@@ -336,6 +349,12 @@ def test_a_cell_of_each_kind_is_added_by_files_alone(tmp_path, monkeypatch):
                         else real(n))
     cell = spec.load_cell("extra.burst", root=str(tmp_path))
     assert cell.config["num_hidden_layers"] == 12
+    assert cell.family_name == "example"
+    model = cell.family.published(cell.config)
+    assert model["rope_parameters"] == {"rope_theta": 1000000.0}
+    assert cell.family.kernel_layers(model, "paged_attn") == 4    # of 12
+    assert cell.family.param_count(model) == LLAMA.param_count(
+        dict(MISTRAL, num_hidden_layers=12))
     assert cell.traffic["arrivals"]["process"] == "gamma"
     assert {m["name"] for m in cell.end_to_end} == \
         {"tpot_p50_ms", "setup_s"}

@@ -1,4 +1,6 @@
-"""The plain reference against the program's model code, tiny, on the CPU."""
+"""The Llama family's plain reference against the program's model code,
+tiny, on the CPU.  Everything is reached the way the harness reaches it:
+through the family file."""
 from __future__ import annotations
 
 import dataclasses
@@ -13,49 +15,59 @@ TINY = dict(hidden_size=64, num_hidden_layers=2, num_attention_heads=4,
 
 
 @pytest.fixture(scope="module")
-def setup():
+def fam():
+    from benchmarks.harness import spec
+
+    return spec.load_family("llama")
+
+
+@pytest.fixture(scope="module")
+def ref_mod(fam):
+    return fam.reference()
+
+
+def _program(cfg):
+    """The program's model module for a config, as `train/step.py`
+    picks it."""
+    from ray_tpu.train import step as train_step
+
+    return train_step.model_module(cfg)
+
+
+@pytest.fixture(scope="module")
+def setup(fam):
     import jax
     import jax.numpy as jnp
 
-    from benchmarks.harness import model as model_mod
-    from ray_tpu.models import llama
-
-    cfg = dataclasses.replace(model_mod.llama_config(TINY, max_seq=32),
+    cfg = dataclasses.replace(fam.program_config(TINY, max_seq=32),
                               dtype=jnp.float32, remat=False)
-    params = llama.init_params(jax.random.PRNGKey(3), cfg)
+    params = fam.init_params(jax.random.PRNGKey(3), cfg)
     toks = np.asarray(jax.random.randint(jax.random.PRNGKey(4), (1, 24), 0,
                                          TINY["vocab_size"]))
     return cfg, params, toks
 
 
-def test_reference_logits_match_the_programs_forward(setup):
-    from benchmarks.harness.refs import decoder
-    from ray_tpu.models import llama
-
+def test_reference_logits_match_the_programs_forward(setup, ref_mod):
     cfg, params, toks = setup
-    want = np.asarray(llama.forward(params, toks, cfg))[0]
-    got = np.asarray(decoder.logits(params, toks[0], TINY))
+    want = np.asarray(_program(cfg).forward(params, toks, cfg))[0]
+    got = np.asarray(ref_mod.logits(params, toks[0], TINY))
     # float32 on both sides: they differ in summation order only
     np.testing.assert_allclose(got, want, atol=2e-4, rtol=2e-4)
 
 
-def test_reference_loss_matches_the_programs_loss(setup):
-    from benchmarks.harness.refs import decoder
-    from ray_tpu.models import llama
-
+def test_reference_loss_matches_the_programs_loss(setup, ref_mod):
     cfg, params, toks = setup
     batch = {"inputs": toks[:, :-1], "targets": toks[:, 1:]}
-    want = float(llama.loss_fn(params, batch, cfg))
-    lg = decoder.logits(params, toks[0, :-1], TINY)
-    assert float(decoder.cross_entropy(lg, toks[0, 1:])) == \
+    want = float(_program(cfg).loss_fn(params, batch, cfg))
+    lg = ref_mod.logits(params, toks[0, :-1], TINY)
+    assert float(ref_mod.cross_entropy(lg, toks[0, 1:])) == \
         pytest.approx(want, rel=1e-4)
 
 
-def test_teacher_forcing_catches_a_skipped_layer(setup):
+def test_teacher_forcing_catches_a_skipped_layer(setup, ref_mod):
     import jax
 
-    from benchmarks.harness.refs import decoder
-
+    decoder = ref_mod
     _, params, toks = setup
     prompt = toks[0, :12].tolist()
     seq, out = list(prompt), []
@@ -73,7 +85,7 @@ def test_teacher_forcing_catches_a_skipped_layer(setup):
 
 # ------------------------------------------ the train cell's `correct`
 @pytest.fixture(scope="module")
-def train_check(setup):
+def train_check(setup, fam, ref_mod):
     """What the train loop compares, tiny and on one CPU device: the
     program's step (bfloat16, as the cell runs it) and forward on one
     small batch, and the reference on the same batch and parameters."""
@@ -81,14 +93,11 @@ def train_check(setup):
     import jax.numpy as jnp
     import optax
 
-    from benchmarks.harness import model as model_mod
-    from benchmarks.harness.refs import decoder
-    from ray_tpu.models import llama
     from ray_tpu.train import step as train_step
 
     model = dict(TINY, num_hidden_layers=4)
-    cfg = model_mod.llama_config(model, max_seq=32)
-    params = llama.init_params(jax.random.PRNGKey(5), cfg)
+    cfg = fam.program_config(model, max_seq=32)
+    params = fam.init_params(jax.random.PRNGKey(5), cfg)
     toks = np.asarray(jax.random.randint(
         jax.random.PRNGKey(6), (2, 33), 0, model["vocab_size"]))
     batch = {"inputs": toks[:, :-1], "targets": toks[:, 1:]}
@@ -101,19 +110,19 @@ def train_check(setup):
         _, m = jax.jit(train_step.make_train_step(cfg, opt))(state, batch)
         return {"loss": float(m["loss"]), "grad_norm": float(m["grad_norm"]),
                 "logprobs": np.asarray(
-                    llama.token_logprobs(params, toks, cfg), np.float32)}
+                    _program(cfg).token_logprobs(params, toks, cfg),
+                    np.float32)}
 
-    ref = decoder.loss_and_gradient(params, batch["inputs"],
+    ref = ref_mod.loss_and_gradient(params, batch["inputs"],
                                     batch["targets"], model)
     return model, cfg, params, batch, program, ref
 
 
-def test_the_written_out_backward_matches_autodiff(train_check):
+def test_the_written_out_backward_matches_autodiff(train_check, ref_mod):
     import jax
     import jax.numpy as jnp
 
-    from benchmarks.harness.refs import decoder
-
+    decoder = ref_mod
     model, _, params, batch, _, ref = train_check
 
     def loss(p):
@@ -130,11 +139,11 @@ def test_the_written_out_backward_matches_autodiff(train_check):
     assert ref["logprobs"].shape == batch["targets"].shape
 
 
-def test_train_check_passes_the_program_as_it_is(train_check):
+def test_train_check_passes_the_program_as_it_is(train_check, fam):
     from benchmarks.harness import train_loop
 
     _, cfg, params, _, program, ref = train_check
-    assert train_loop.judge(program(params, cfg), ref) == []
+    assert train_loop.judge(program(params, cfg), ref, fam) == []
 
 
 def _skip_a_layer(params, cfg):
@@ -162,9 +171,9 @@ def _no_attention(params, cfg):
 
 @pytest.mark.parametrize("fault", [_skip_a_layer, _through_fp8,
                                    _no_attention])
-def test_train_check_catches(train_check, fault):
+def test_train_check_catches(train_check, fam, fault):
     from benchmarks.harness import train_loop
 
     _, cfg, params, _, program, ref = train_check
-    problems = train_loop.judge(program(*fault(params, cfg)), ref)
+    problems = train_loop.judge(program(*fault(params, cfg)), ref, fam)
     assert any("log-probabilities" in p for p in problems), problems

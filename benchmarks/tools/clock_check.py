@@ -116,11 +116,9 @@ def main() -> int:
 
     import jax
 
-    from benchmarks.harness import (model as model_mod, serve_cell, spec,
-                                    trace_reduce)
+    from benchmarks.harness import serve_cell, spec, trace_reduce
     from benchmarks.harness.replica import seed_key
     from ray_tpu import tracing
-    from ray_tpu.models import llama
     from ray_tpu.serve.llm import LLMEngine
 
     cell = spec.load_cell(args.workload)
@@ -129,12 +127,14 @@ def main() -> int:
     eng_kw = dict(cell.config["engine"], paged=True)
     prompt_len = int(cell.traffic["prompt_len"]["median"])
     new_tokens = 4 * int(cell.traffic["output_len"]["clip"][0])
-    model = model_mod.published(cell.config)
+    fam = cell.family
+    model = fam.published(cell.config)
+    vocab = fam.vocab_size(model)
     dev = jax.devices()[0]
     if dev.platform != "tpu" and not args.rehearse:
         raise SystemExit(f"jax came up on {dev.platform!r}, not the chip")
-    lcfg = model_mod.llama_config(model, max_seq=eng_kw["max_len"])
-    params = jax.jit(lambda k: llama.init_params(k, lcfg))(
+    lcfg = fam.program_config(model, max_seq=eng_kw["max_len"])
+    params = jax.jit(lambda k: fam.init_params(k, lcfg))(
         seed_key(args.seed))
     eng = LLMEngine(lcfg, params=params, seed=0, **eng_kw)
     eng.start()
@@ -143,7 +143,7 @@ def main() -> int:
     def caller(i: int) -> None:
         n = 0
         while not stop.is_set():
-            prompt = [1 + (i * 131 + n * 17 + j) % (model["vocab_size"] - 1)
+            prompt = [1 + (i * 131 + n * 17 + j) % (vocab - 1)
                       for j in range(prompt_len)]
             eng.generate(prompt, max_new_tokens=new_tokens, _cache_ok=False)
             n += 1

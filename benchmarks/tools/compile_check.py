@@ -2,7 +2,10 @@
 """Compile the benchmark's programs at their REAL sizes for a DESCRIBED
 v5e (the TPU compiler is installed in the sandbox; no chip is attached)
 and print what each needs: argument, output and temporary bytes, kernel
-counts, collectives.  One process, run by hand, never imported by a test:
+counts, collectives.  The model comes from the configuration's family
+file, so a new family's programs are sized here before chip time is
+spent.  One process, run by hand (a test calls `serve` at a tiny size on
+the CPU's devices, never the described chip):
 
     JAX_PLATFORMS=cpu python3 benchmarks/tools/compile_check.py [config ...]
 
@@ -36,14 +39,14 @@ def serve(name: str, cfg: dict, topo) -> None:
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    from benchmarks.harness import model as model_mod
-    from ray_tpu.models import llama
+    from benchmarks.harness import spec
     from ray_tpu.serve.llm import LLMEngine
 
     one = SingleDeviceSharding(topo.devices[0])
-    model = model_mod.published(cfg)
+    fam = spec.config_family(cfg)
+    model = fam.published(cfg)
     eng_kw = dict(cfg["engine"], paged=True)
-    lcfg = model_mod.llama_config(model, max_seq=eng_kw["max_len"])
+    lcfg = fam.program_config(model, max_seq=eng_kw["max_len"])
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
@@ -52,13 +55,13 @@ def serve(name: str, cfg: dict, topo) -> None:
         return jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
 
     t0 = time.perf_counter()
-    init = jax.jit(lambda k: llama.init_params(k, lcfg),
+    init = jax.jit(lambda k: fam.init_params(k, lcfg),
                    out_shardings=one).lower(
         sds((2,), jnp.uint32)).compile()
     emit(config=name, program="init_params", wall_s=time.perf_counter() - t0,
-         params=lcfg.num_params(), **mem(init))
+         params=fam.param_count(model), **mem(init))
     params = abstract(jax.eval_shape(
-        lambda: llama.init_params(jax.random.PRNGKey(0), lcfg)))
+        lambda: fam.init_params(jax.random.PRNGKey(0), lcfg)))
     eng = LLMEngine(lcfg, params, **eng_kw)
     i32, f32 = jnp.int32, jnp.float32
     b, k = eng.max_batch, eng.steps_per_sync
@@ -87,14 +90,14 @@ def train(name: str, cfg: dict, topo) -> None:
     import numpy as np
     from jax.sharding import Mesh
 
-    from benchmarks.harness import model as model_mod
+    from benchmarks.harness import spec
     from ray_tpu.parallel.mesh import MeshConfig, create_mesh
     from ray_tpu.train import step as train_step
 
     t = cfg["train"]
-    model = model_mod.published(cfg)
-    lcfg = model_mod.llama_config(model, max_seq=t["seq"],
-                                  remat_mode=t["remat_mode"])
+    fam = spec.config_family(cfg)
+    lcfg = fam.program_config(fam.published(cfg), max_seq=t["seq"],
+                              remat_mode=t["remat_mode"])
     mesh = create_mesh(MeshConfig(**t["mesh"]), devices=list(topo.devices))
     assert isinstance(mesh, Mesh)
     optimizer = getattr(train_step, t["optimizer"])(
