@@ -3245,6 +3245,8 @@ class CoreWorker:
                        "caller": self.worker_id})
         if options.get("concurrency_group"):
             header["concurrency_group"] = options["concurrency_group"]
+        if options.get("unbatched"):
+            header["unbatched"] = True
         if options.get("streaming"):
             self._ret0_task_ids[return_ids[0]] = task_id.binary()
         with self._ref_lock:
@@ -3493,18 +3495,33 @@ class CoreWorker:
         try:
             while st.outbox:
                 limit = self.config.actor_call_batch_size
-                if st.outbox[0][0].header.get("streaming"):
+
+                def alone(entry) -> bool:
                     # Streaming calls ride alone: their reply waits on the
                     # LAST generated item, which would gate every batch
-                    # sibling's reply behind the whole stream.
+                    # sibling's reply behind the whole stream.  So does a
+                    # call sent `unbatched`: a batch's one reply waits for
+                    # its slowest call (96 closed-loop callers of a serve
+                    # replica got their replies 3.5 s late at the median).
+                    h = entry[0].header
+                    return bool(h.get("streaming") or h.get("unbatched"))
+
+                if alone(st.outbox[0]):
                     batch = st.outbox[:1]
                 else:
                     batch = []
                     for entry in st.outbox[:limit]:
-                        if entry[0].header.get("streaming"):
+                        if alone(entry):
                             break
                         batch.append(entry)
                 del st.outbox[:len(batch)]
+                if batch[0][0].header.get("unbatched"):
+                    # not one of the `actor_max_inflight_batches` either:
+                    # sixteen long calls in flight would hold every later
+                    # one back (the caller bounds what it has in flight:
+                    # the serve handle by max_ongoing_requests)
+                    self.loop.create_task(self._send_actor_batch(st, batch))
+                    continue
                 await st.send_sem.acquire()
                 t = self.loop.create_task(self._send_actor_batch(st, batch))
                 t.add_done_callback(lambda _t, s=st: s.send_sem.release())

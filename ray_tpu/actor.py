@@ -29,16 +29,25 @@ def _validate(opts: dict) -> None:
 class ActorMethod:
     def __init__(self, handle: "ActorHandle", name: str,
                  num_returns: int = 1,
-                 concurrency_group: str | None = None):
+                 concurrency_group: str | None = None,
+                 unbatched: bool = False):
         self._handle = handle
         self._name = name
         self._num_returns = num_returns
         self._concurrency_group = concurrency_group
+        # `.options(unbatched=True)`: the call goes out in an RPC of its
+        # own.  Queued calls to one actor otherwise share an RPC whose ONE
+        # reply waits for the slowest of them, which holds a short call's
+        # result behind a long sibling's on an actor that runs calls
+        # concurrently (serve requests to a replica).
+        self._unbatched = unbatched
 
     def _call_opts(self) -> dict:
         opts: dict = {"num_returns": self._num_returns}
         if self._concurrency_group is not None:
             opts["concurrency_group"] = self._concurrency_group
+        if self._unbatched:
+            opts["unbatched"] = True
         return opts
 
     def remote(self, *args, **kwargs):
@@ -61,7 +70,8 @@ class ActorMethod:
                 "generator methods")
         return ActorMethod(
             self._handle, self._name, nr,
-            opts.get("concurrency_group", self._concurrency_group))
+            opts.get("concurrency_group", self._concurrency_group),
+            opts.get("unbatched", self._unbatched))
 
     def bind(self, *args, **kwargs):
         """Lazy DAG node for this actor method (ray: dag/class_node.py

@@ -1,12 +1,60 @@
 """Model zoo: TPU-first functional implementations (pure param pytrees +
 jit-able apply functions; no framework lock-in, shardings are declared as
 logical-axes pytrees consumed by ray_tpu.parallel)."""
+import importlib
+
 from ray_tpu.models.llama import (LlamaConfig, llama_configs, init_params,
                                   forward, loss_fn, param_logical_axes)
 from ray_tpu.models.resnet import ResNetConfig, resnet_configs
 from ray_tpu.models.vit import ViTConfig, vit_configs
 
+# The serving seam: which module serves a config, keyed on the config's
+# type (by name, so nothing is imported until asked for).  serve/llm.py
+# asks here and names no model itself.  Every serving module gives, under
+# ONE signature each:
+#   init_params(key, cfg); init_paged_cache(cfg, batch, n_pages, page) ->
+#     {"k", "v", "pos", "state"}: the page pool of the layers that keep KV
+#     and `state`, a pytree of whatever a lane carries that no page holds
+#     (an empty list if nothing), which the engine never looks inside;
+#   serve_prefill(params, tokens, cfg, true_lens, lora) -> (hidden, ks,
+#     vs, state taken at each row's TRUE length, counts);
+#   serve_scatter(cache, ks, vs, state, page_ids, rows, slots, true_lens,
+#     aligned=True) -> cache;
+#   serve_decode_step(params, pages, tails, state, tokens, pos,
+#     tail_start, j, page_table, cfg, lora) -> (logits, tails, state,
+#     counts);
+#   project_logits(params, h); lane_state_layers(cfg) (0: the prefix
+#     cache may stay on); routed_layers(cfg): the rows of `counts`, int32
+#     [routed layers, 3] = experts that held a row, the largest load,
+#     assignments computed (0 rows: nothing is counted);
+# and `SERVING_CAPS`: the optional capabilities it has, under their own
+# names ("prefix": prefill_with_prefix; "lora": the adapter hooks;
+# "kv_transfer": KV export/import/graft; "dense": the paged=False layout:
+# prefill, decode_step_unrolled, init_kv_cache_leaves).
+_SERVING = {"LlamaConfig": "ray_tpu.models.llama",
+            "Lfm2MoeConfig": "ray_tpu.models.lfm2"}
+
+
+def serving_model(cfg):
+    """The module that serves `cfg` through serve/llm.LLMEngine (a
+    subclass of a served config type is served by its base's module)."""
+    for klass in type(cfg).__mro__:
+        if klass.__name__ in _SERVING:
+            return importlib.import_module(_SERVING[klass.__name__])
+    raise TypeError(f"no serving model for a {type(cfg).__name__}; the "
+                    f"engine serves {sorted(_SERVING)}")
+
+
+def named_config(name: str):
+    """A preset config by name (the `LLMServer(model="debug")` form)."""
+    for mod in _SERVING.values():
+        presets = importlib.import_module(mod).serving_configs()
+        if name in presets:
+            return presets[name]
+    raise KeyError(f"no preset model config {name!r}")
+
+
 __all__ = ["LlamaConfig", "llama_configs", "init_params", "forward",
-           "loss_fn", "param_logical_axes",
+           "loss_fn", "param_logical_axes", "serving_model", "named_config",
            "ResNetConfig", "resnet_configs",
            "ViTConfig", "vit_configs"]

@@ -103,6 +103,13 @@ def llama_configs() -> dict[str, LlamaConfig]:
     }
 
 
+# What this module gives the serving seam (models/__init__.py) beyond
+# the required functions (at the end of the file): every optional
+# capability.
+SERVING_CAPS = frozenset({"prefix", "lora", "kv_transfer", "dense"})
+serving_configs = llama_configs
+
+
 # ---------------------------------------------------------------- params
 def param_logical_axes(cfg: LlamaConfig) -> dict:
     """Logical-axes pytree matching init_params' structure (consumed by
@@ -909,3 +916,48 @@ def decode_step(params: dict, cache: dict, tokens: jnp.ndarray,
     with jax.named_scope("lm_head"):
         logits = (x[:, 0] @ params["lm_head"]).astype(jnp.float32)
     return logits, {"k": nk, "v": nv, "pos": pos + 1}
+
+
+# ------------------------------------------------------ the serving seam
+# The functions above under the ONE signature serve/llm.LLMEngine calls
+# for every model (models/__init__.py): a dense decoder's lanes keep no
+# state beside the page pool (an empty list: no leaf in any program) and
+# it has no routed layers to count (a [0, 3] array).
+def lane_state_layers(cfg: LlamaConfig) -> int:
+    return 0
+
+
+def routed_layers(cfg: LlamaConfig) -> int:
+    return 0
+
+
+def project_logits(params: dict, h: jnp.ndarray) -> jnp.ndarray:
+    return h @ params["lm_head"]
+
+
+def _no_counts() -> jnp.ndarray:
+    return jnp.zeros((0, 3), jnp.int32)
+
+
+def init_paged_cache(cfg: LlamaConfig, batch: int, n_pages: int,
+                     page: int) -> dict:
+    return {**init_paged_kv_cache(cfg, batch, n_pages, page), "state": []}
+
+
+def serve_prefill(params, tokens, cfg, true_lens, lora=None):
+    hidden, ks, vs = prefill(params, tokens, cfg, lora)
+    return hidden, ks, vs, [], _no_counts()
+
+
+def serve_scatter(cache, ks, vs, state, page_ids, rows, slots, true_lens,
+                  aligned: bool = True) -> dict:
+    return {**scatter_prefill_pages(cache, ks, vs, page_ids, rows, slots,
+                                    true_lens, aligned=aligned),
+            "state": state}
+
+
+def serve_decode_step(params, pages, tails, state, tokens, pos, tail_start,
+                      j, page_table, cfg, lora=None):
+    logits, tails = decode_step_paged(params, pages, tails, tokens, pos,
+                                      tail_start, j, page_table, cfg, lora)
+    return logits, tails, state, _no_counts()

@@ -7,9 +7,24 @@ materialize in HBM at long context).
 from __future__ import annotations
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
+
+logger = logging.getLogger(__name__)
+
+# Shapes the "auto" gate sent to the XLA path ON A TPU, each logged once
+# and counted here at trace time (one count a compiled program, not a
+# call): a prefill that should run in the flash kernel and does not is
+# then visible (`xla_fallbacks()`), not silent.
+_XLA_FALLBACKS: dict[tuple, int] = {}
+
+
+def xla_fallbacks() -> dict[tuple, int]:
+    """{(sq, skv, head_dim, causal): programs traced} of the attention
+    calls that took the XLA path on a TPU."""
+    return dict(_XLA_FALLBACKS)
 
 
 def _repeat_kv(k: jnp.ndarray, n_rep: int) -> jnp.ndarray:
@@ -61,15 +76,39 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         use_flash = True
     elif impl == "auto":
         on_tpu = any(d.platform == "tpu" for d in jax.devices())
-        # Flash kernel requires seq multiple of its block size.
+        # Flash kernel requires seq multiple of its block size; a
+        # head_dim that is no multiple of 128 lanes is zero-padded.
         use_flash = (on_tpu and causal and q.shape[1] == k.shape[1]
-                     and q.shape[1] % 128 == 0 and q.shape[-1] % 128 == 0)
+                     and q.shape[1] % 128 == 0)
+        if on_tpu and not use_flash:
+            key = (q.shape[1], k.shape[1], q.shape[-1], causal)
+            if key not in _XLA_FALLBACKS:
+                logger.info("attention: XLA path on a TPU for sq=%d skv=%d "
+                            "head_dim=%d causal=%s (the flash kernel takes "
+                            "causal self-attention over a multiple of 128 "
+                            "tokens)", *key)
+            _XLA_FALLBACKS[key] = _XLA_FALLBACKS.get(key, 0) + 1
     if use_flash:
-        return _flash_per_shard(q, k, v, causal)
+        return _flash_padded(q, k, v, causal)
     return xla_attention(q, k, v, causal=causal, q_offset=q_offset)
 
 
-def _flash_per_shard(q, k, v, causal: bool):
+def _flash_padded(q, k, v, causal: bool):
+    """The flash kernel at any head_dim: q, k and v zero-padded to the
+    next multiple of 128 lanes, the scale given as the TRUE head_dim's.
+    Exact: the padded columns add 0 to every score and the padded
+    columns of the output are cut off."""
+    d = q.shape[-1]
+    pad = -d % 128
+    if not pad:
+        return _flash_per_shard(q, k, v, causal)
+    widths = ((0, 0), (0, 0), (0, 0), (0, pad))
+    o = _flash_per_shard(jnp.pad(q, widths), jnp.pad(k, widths),
+                         jnp.pad(v, widths), causal, sm_scale=d ** -0.5)
+    return o[..., :d]
+
+
+def _flash_per_shard(q, k, v, causal: bool, sm_scale: float | None = None):
     """The Pallas kernel; under an ambient multi-device mesh, one call
     per shard (jax refuses a Mosaic kernel under GSPMD at lowering:
     "wrap the call in a shard_map").  The layout — what splits over
@@ -77,7 +116,8 @@ def _flash_per_shard(q, k, v, causal: bool):
     from ray_tpu.ops.flash_attention import flash_attention
     from ray_tpu.parallel.sharding import attention_shard_specs
 
-    fn = functools.partial(flash_attention, causal=causal)
+    fn = functools.partial(flash_attention, causal=causal,
+                           sm_scale=sm_scale)
     specs = attention_shard_specs(q.shape, k.shape)
     if specs is None:
         return fn(q, k, v)

@@ -665,8 +665,11 @@ class DeploymentHandle:
                 raise
             pr = {} if self._priority is None \
                 else {"priority": self._priority}
-            ref = handle.handle_request.remote(self._method, args,
-                                               kwargs, **pr)
+            # unbatched: a request's reply must not wait for another
+            # request's that happened to share its RPC (worker.py
+            # `_drain_actor_outbox`)
+            ref = handle.handle_request.options(unbatched=True).remote(
+                self._method, args, kwargs, **pr)
             ref.future().add_done_callback(lambda _f: self._done(rid))
             return ref
 

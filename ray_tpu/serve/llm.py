@@ -13,7 +13,7 @@ TPU-native shape (SURVEY §7 "Serve continuous batching on TPU"):
   - KV cache is a donated jit argument: decode updates alias in place
     (no per-step cache copy in HBM).
   - Prompt lengths are bucketed to powers of two; padding rows produce
-    garbage K/V that the decode mask never admits (llama.prefill).
+    garbage K/V that the decode mask never admits (the model's prefill).
   - Sampling (greedy / temperature) happens on device; only the [B]
     next-token vector crosses to the host per step.
 
@@ -153,6 +153,18 @@ def _engine_metrics():
                 "decode_steps": um.get_or_create(
                     um.Counter, "serve_llm_decode_steps",
                     "Decode steps dispatched (K per sync window)", tk),
+                # a routed model's decode: experts hit a layer-step =
+                # moe_experts_hit / moe_layer_steps
+                "moe_layer_steps": um.get_or_create(
+                    um.Counter, "serve_llm_moe_layer_steps",
+                    "Routed layers x decode steps run", tk),
+                "moe_experts_hit": um.get_or_create(
+                    um.Counter, "serve_llm_moe_experts_hit",
+                    "Experts that held a row, summed over routed "
+                    "layer-steps of decode", tk),
+                "moe_assignments": um.get_or_create(
+                    um.Counter, "serve_llm_moe_assignments",
+                    "Token-expert assignments computed in decode", tk),
                 "preemptions": um.get_or_create(
                     um.Counter, "serve_llm_preemptions",
                     "Requests preempted for KV blocks", tk),
@@ -274,7 +286,13 @@ _LOOP_PHASES = ("admit", "prefill_dispatch", "prefill_sync", "fund",
 
 
 class LLMEngine:
-    """Continuous-batching decode engine over llama-family params."""
+    """Continuous-batching decode engine over the params of whichever
+    model module serves `cfg` (`ray_tpu.models.serving_model`: the
+    engine names no model).  What a model lacks of the optional
+    capabilities (`SERVING_CAPS`) the engine refuses at construction; a
+    model whose lanes carry state no KV page holds (`lane_state_layers`)
+    is served with the prefix cache, suffix prefill and the prefix
+    store's demotion off, and `stats()["lane_state"]` says so."""
 
     def __init__(self, cfg, params=None, *, max_batch: int = 8,
                  max_len: int | None = None, seed: int = 0,
@@ -288,8 +306,35 @@ class LLMEngine:
         import jax
         import jax.numpy as jnp
 
-        from ray_tpu.models import llama
+        from ray_tpu.models import serving_model
 
+        model = self._model = serving_model(cfg)
+        caps = model.SERVING_CAPS
+        # Per-lane state beside the page pool (a convolution's last
+        # rows): the prefill program returns it taken at each row's
+        # TRUE length, the scatter program writes it into the lane, the
+        # decode scan carries it; the engine never looks inside.
+        self._lane_layers = int(model.lane_state_layers(cfg))
+        self._moe_layers = int(model.routed_layers(cfg))
+        stateful = self._lane_layers > 0
+        if not paged and "dense" not in caps:
+            raise ValueError(
+                f"{model.__name__} has no dense (paged=False) cache "
+                "layout")
+        if lora_slots and "lora" not in caps:
+            raise ValueError(
+                f"{model.__name__} has no LoRA hooks: lora_slots must "
+                "be 0")
+        if "prefix" not in caps:
+            # A radix hit restores KV pages only: lane state (or, for a
+            # model without prefill_with_prefix, the suffix program)
+            # cannot follow it.
+            why = ("a lane's state is held by no KV page, so a radix "
+                   "prefix hit cannot restore it" if stateful else
+                   f"{model.__name__} has no prefill_with_prefix")
+            if prefix_cache:
+                raise ValueError(f"prefix_cache=True refused: {why}")
+            prefix_cache = False
         self.cfg = cfg
         self.name = name
         self.max_batch = max_batch
@@ -300,7 +345,7 @@ class LLMEngine:
         # are checked every K tokens; overshoot is trimmed.  The value
         # is unmeasured on today's chip.
         self.steps_per_sync = max(1, steps_per_sync)
-        self.params = params if params is not None else llama.init_params(
+        self.params = params if params is not None else model.init_params(
             jax.random.PRNGKey(seed), cfg)
         self.paged = paged
         self._prefix_cache = paged and (
@@ -320,8 +365,8 @@ class LLMEngine:
                 kv_pages = 1 + max_batch * (
                     -(-min(self.max_len, 4096) // page_size))
             self.n_pages = kv_pages
-            self.cache = llama.init_paged_kv_cache(cfg, max_batch,
-                                                   kv_pages, page_size)
+            self.cache = model.init_paged_cache(cfg, max_batch,
+                                                kv_pages, page_size)
             # Host-side accounting: refcounted blocks + radix prefix
             # index over pool ids 1..n_pages-1 (serve/kv_blocks.py).
             self._mgr = BlockManager(kv_pages - 1, page_size,
@@ -331,7 +376,7 @@ class LLMEngine:
             # Dense per-layer cache leaves: the stacked [L, ...] cache
             # rode a lax.scan as xs/ys, which XLA cannot alias — every
             # decode step copied the whole cache.
-            self.cache = llama.init_kv_cache_leaves(cfg, max_batch,
+            self.cache = model.init_kv_cache_leaves(cfg, max_batch,
                                                     self.max_len)
             self._mgr = None
         self._buckets = _buckets_for(self.max_len)
@@ -364,8 +409,8 @@ class LLMEngine:
                 raise ValueError(
                     "lora_slots > 0 requires lora_rank >= 1 (bank "
                     "shapes are static — the XLA invariants)")
-            dims = llama.lora_target_dims(cfg)
-            tgts = tuple(lora_targets or llama.LORA_TARGETS)
+            dims = model.lora_target_dims(cfg)
+            tgts = tuple(lora_targets or model.LORA_TARGETS)
             bad = [t for t in tgts if t not in dims]
             if bad:
                 raise ValueError(
@@ -406,7 +451,7 @@ class LLMEngine:
             return jnp.where(temps > 0, sampled, greedy)
 
         def _first_token(params, last_h, temps, seeds, starts):
-            last = (last_h @ params["lm_head"]).astype(jnp.float32)
+            last = model.project_logits(params, last_h).astype(jnp.float32)
             keys = jax.vmap(
                 lambda s, t: jax.random.fold_in(
                     jax.random.fold_in(self._base_key, s), t))(seeds,
@@ -427,7 +472,7 @@ class LLMEngine:
 
                 def step(carry, j):
                     cache, toks = carry
-                    logits, cache = llama.decode_step_unrolled(
+                    logits, cache = model.decode_step_unrolled(
                         params, cache, toks, cfg)
                     keys = jax.vmap(jax.random.fold_in)(lane_keys,
                                                         starts + j)
@@ -444,39 +489,48 @@ class LLMEngine:
                 the block; a carried write would copy the whole pool
                 every step); new rows ride a small dense tail, merged
                 into the pages once at block end
-                (ops/paged_attention.py)."""
+                (ops/paged_attention.py).  The lanes' state (whatever
+                the model keeps beside the pool: a few rows a lane, or
+                nothing) rides the carry, and the routed layers' counts
+                ([routed layers, 3]: experts hit, largest load,
+                assignments; summed over the K steps) come back beside
+                `seq`, fetched in the same sync."""
                 from ray_tpu.ops.paged_attention import merge_tail_pages
 
                 ts = cache["pos"]
                 pages = {"k": cache["k"], "v": cache["v"]}
+                n_kv = len(pages["k"])
                 tshape = (max_batch, cfg.n_kv_heads, K, cfg.head_dim)
                 tails = {"k": [jnp.zeros(tshape, cfg.dtype)
-                               for _ in range(cfg.n_layers)],
+                               for _ in range(n_kv)],
                          "v": [jnp.zeros(tshape, cfg.dtype)
-                               for _ in range(cfg.n_layers)]}
+                               for _ in range(n_kv)]}
                 lane_keys = jax.vmap(
                     lambda s: jax.random.fold_in(self._base_key,
                                                  s))(seeds)
 
                 def step(carry, j):
-                    tails, pos, toks = carry
-                    logits, tails = llama.decode_step_paged(
-                        params, pages, tails, toks, pos, ts, j, table,
-                        cfg, lora)
+                    tails, state, pos, toks, counts = carry
+                    logits, tails, state, cnt = model.serve_decode_step(
+                        params, pages, tails, state, toks, pos, ts, j,
+                        table, cfg, lora)
                     keys = jax.vmap(jax.random.fold_in)(lane_keys,
                                                         starts + j)
                     nxt = _sample_rows(logits, temps, keys)
-                    return (tails, pos + 1, nxt), nxt
+                    return (tails, state, pos + 1, nxt, counts + cnt), nxt
 
-                (tails, pos, last), seq = jax.lax.scan(
-                    step, (tails, ts, tokens), jnp.arange(K))
+                counts0 = jnp.zeros((self._moe_layers, 3), jnp.int32)
+                (tails, state, pos, last, counts), seq = jax.lax.scan(
+                    step, (tails, cache["state"], ts, tokens, counts0),
+                    jnp.arange(K))
                 new_k = [merge_tail_pages(pages["k"][li],
                                           tails["k"][li], table, ts, K)
-                         for li in range(cfg.n_layers)]
+                         for li in range(n_kv)]
                 new_v = [merge_tail_pages(pages["v"][li],
                                           tails["v"][li], table, ts, K)
-                         for li in range(cfg.n_layers)]
-                return seq, last, {"k": new_k, "v": new_v, "pos": pos}
+                         for li in range(n_kv)]
+                return seq, last, {"k": new_k, "v": new_v, "pos": pos,
+                                   "state": state}, counts
 
             return jax.jit(_decode_k_paged if paged else _decode_k_dense,
                            donate_argnums=(1,))
@@ -502,7 +556,7 @@ class LLMEngine:
         def _prefill_wave(params, cache, tokens, true_lens, slots, temps,
                           seeds, starts):
             W = tokens.shape[0]
-            hidden, ks, vs = llama.prefill(params, tokens, cfg)
+            hidden, ks, vs = model.prefill(params, tokens, cfg)
 
             # Scatter each wave member's prompt KV into its slot with ONE
             # batched indexed write per layer leaf (duplicate padded slots
@@ -534,10 +588,13 @@ class LLMEngine:
         def _prefill_fwd_only(params, tokens, true_lens, slots, temps,
                               seeds, starts, lora):
             W = tokens.shape[0]
-            hidden, ks, vs = llama.prefill(params, tokens, cfg, lora)
+            # + each row's lane state AT ITS TRUE LENGTH (rows are
+            # padded to a length bucket) and the routed layers' counts
+            hidden, ks, vs, state, counts = model.serve_prefill(
+                params, tokens, cfg, true_lens, lora)
             last_h = hidden[jnp.arange(W), true_lens - 1]
             nxt = _first_token(params, last_h, temps, seeds, starts)
-            return nxt, ks, vs
+            return nxt, ks, vs, state, counts
 
         self._prefill_fwd = jax.jit(_prefill_fwd_only)
 
@@ -548,7 +605,7 @@ class LLMEngine:
         def _prefill_suffix_fwd(params, kp, vp, tokens, pos0, prefix_t,
                                 last_idx, temps, seeds, starts, lora):
             W = tokens.shape[0]
-            hidden, ks, vs = llama.prefill_with_prefix(
+            hidden, ks, vs = model.prefill_with_prefix(
                 params, tokens, pos0, cfg, kp, vp, prefix_t, lora)
             last_h = hidden[jnp.arange(W), last_idx]
             nxt = _first_token(params, last_h, temps, seeds, starts)
@@ -557,17 +614,17 @@ class LLMEngine:
         self._prefill_suffix = jax.jit(_prefill_suffix_fwd)
 
         self._scatter_pages = jax.jit(
-            lambda cache, ks, vs, page_ids, rows, slots, true_lens:
-            llama.scatter_prefill_pages(cache, ks, vs, page_ids, rows,
-                                        slots, true_lens),
+            lambda cache, ks, vs, state, page_ids, rows, slots, true_lens:
+            model.serve_scatter(cache, ks, vs, state, page_ids, rows,
+                                slots, true_lens),
             donate_argnums=(0,))
         # Suffix scatters start mid-span (prefill_from), so the
         # page-aligned fast paths don't apply — force the coordinate
         # form (see scatter_prefill_pages).
         self._scatter_pages_coord = jax.jit(
-            lambda cache, ks, vs, page_ids, rows, slots, true_lens:
-            llama.scatter_prefill_pages(cache, ks, vs, page_ids, rows,
-                                        slots, true_lens, aligned=False),
+            lambda cache, ks, vs, state, page_ids, rows, slots, true_lens:
+            model.serve_scatter(cache, ks, vs, state, page_ids, rows,
+                                slots, true_lens, aligned=False),
             donate_argnums=(0,))
         # KV migration surface (prefill/decode disaggregation).  Export
         # gathers a request's pages into ONE stacked [2, L, n, kvh,
@@ -590,7 +647,7 @@ class LLMEngine:
             v = [cache["v"][li].at[ids].set(kv[1, li])
                  for li in range(cfg.n_layers)]
             pos = cache["pos"].at[slot].set(kvlen)
-            return ({"k": k, "v": v, "pos": pos},
+            return ({**cache, "k": k, "v": v, "pos": pos},
                     cur.at[slot].set(tok))
 
         self._import_pages = jax.jit(_import_kv_fn,
@@ -606,7 +663,7 @@ class LLMEngine:
                  for li in range(cfg.n_layers)]
             v = [cache["v"][li].at[ids].set(kv[1, li])
                  for li in range(cfg.n_layers)]
-            return {"k": k, "v": v, "pos": cache["pos"]}
+            return {**cache, "k": k, "v": v}
 
         self._graft_pages = jax.jit(_graft_kv_fn, donate_argnums=(0,))
 
@@ -615,9 +672,9 @@ class LLMEngine:
         # no-op — so the compile count stays at a few pad widths.
         self._copy_pages = jax.jit(
             lambda cache, src, dst: {
+                **cache,
                 "k": [l.at[dst].set(l[src]) for l in cache["k"]],
-                "v": [l.at[dst].set(l[src]) for l in cache["v"]],
-                "pos": cache["pos"]},
+                "v": [l.at[dst].set(l[src]) for l in cache["v"]]},
             donate_argnums=(0,))
 
         # Slot state.  Current tokens live ON DEVICE between blocks: the
@@ -676,6 +733,16 @@ class LLMEngine:
         self.prefill_programs = 0      # (width, length) programs dispatched
         self.prefill_waves = 0
         self.prefill_waves_split = 0   # plans of more programs than chunks
+        # Routed layers (a model that declares `routed_layers`): layer
+        # x steps run, assignments computed, experts that held a row and
+        # the largest expert load, each summed over layer-steps; decode
+        # and prefill apart.  The device counts; the numbers ride the
+        # token fetch of the window (wave) they belong to.
+        self._prefill_counts: list = []    # device arrays, a wave's
+        self.moe = dict.fromkeys(
+            [p + k for p in ("", "prefill_")
+             for k in ("moe_layer_steps", "moe_assignments",
+                       "moe_experts_hit", "moe_max_load")], 0)
         self._funded_blocks = 0        # pages _ensure_decode_blocks got
         self._demote_dispatched = 0    # gathers _maybe_demote dispatched
         # Live weight sync (online RLHF): update_weights() stages a
@@ -748,6 +815,8 @@ class LLMEngine:
             raise ValueError(
                 "prefill_only requires a paged engine (KV export is "
                 "page-granular)")
+        if prefill_only:
+            self._need_kv_transfer("prefill_only")
         if model_id is not None and self._lora_banks is None:
             raise AdapterLoadError(
                 "engine has no adapter slots (set lora_slots)",
@@ -824,6 +893,7 @@ class LLMEngine:
             failpoints.fire("serve.kv_import")
         if not self.paged:
             raise ValueError("kv_import requires a paged engine")
+        self._need_kv_transfer("kv_import")
         if not tokens:
             raise ValueError("kv_import needs at least the first "
                              "generated token")
@@ -874,6 +944,12 @@ class LLMEngine:
         self._wake.set()
         return req.future
 
+    def _need_kv_transfer(self, what: str) -> None:
+        if "kv_transfer" not in self._model.SERVING_CAPS:
+            raise ValueError(
+                f"{what}: {self._model.__name__} has no KV export/import "
+                "(its lanes hold state that no page carries)")
+
     def set_prefix_store(self, publish_cb, *, min_idle: int = 256,
                          period_s: float = 0.25,
                          watermark_frac: float = 0.125,
@@ -918,6 +994,7 @@ class LLMEngine:
 
         if not self.paged:
             raise ValueError("kv_graft requires a paged engine")
+        self._need_kv_transfer("kv_graft")
         if kv_len <= 0 or kv_len % self.page != 0:
             raise ValueError(
                 f"kv_len {kv_len} must be a positive multiple of the "
@@ -982,7 +1059,6 @@ class LLMEngine:
         import jax.numpy as jnp
 
         from ray_tpu import failpoints
-        from ray_tpu.models import llama
         from ray_tpu.serve import lora as lora_mod
 
         if self._lora_banks is None:
@@ -991,7 +1067,7 @@ class LLMEngine:
                 model_id=model_id, deployment=self.name,
                 reason="lora_slots=0")
         targets = (adapter or {}).get("targets") or {}
-        dims = llama.lora_target_dims(self.cfg)
+        dims = self._model.lora_target_dims(self.cfg)
         rank = 0
         for t, ab in targets.items():
             if t not in self._lora_banks:
@@ -1835,9 +1911,10 @@ class LLMEngine:
                       chunks=len(plan),
                       plan=",".join(f"{w}x{b}" for _, w, b in plan))
         with self._phase("prefill_sync", iter=it, rows=len(wave)):
-            for _, nxt, _t in pending_waves:
+            counts, self._prefill_counts = self._prefill_counts, []
+            for a in [nxt for _, nxt, _t in pending_waves] + counts:
                 try:
-                    nxt.copy_to_host_async()
+                    a.copy_to_host_async()
                 except AttributeError:
                     pass
             for chunk, nxt, t_disp in pending_waves:
@@ -1878,6 +1955,8 @@ class LLMEngine:
                         attrs={"ttft_ms": round(
                             (req.first_token_at - req.submitted_at)
                             * 1000, 1)})
+            for c in counts:        # on the host since the tokens are
+                self._count_moe("prefill_", np.asarray(c), 1)
 
     def _prefill_chunk_full(self, chunk, padded_w: int, bucket: int):
         """Full-prompt prefill (no cached prefix anywhere in the chunk)
@@ -1920,13 +1999,16 @@ class LLMEngine:
             rows = np.tile(
                 np.arange(bucket, dtype=np.int32) % self.page,
                 (padded_w, 1))
-            nxt, ks, vs = self._prefill_fwd(
+            nxt, ks, vs, state, counts = self._prefill_fwd(
                 self.params, jnp.asarray(tokens), lens_dev,
                 slots_dev, jnp.asarray(temps), jnp.asarray(seeds),
                 jnp.asarray(starts), self._lora_args(lidx))
             self.cache = self._scatter_pages(
-                self.cache, ks, vs, jnp.asarray(page_ids),
+                self.cache, ks, vs, state, jnp.asarray(page_ids),
                 jnp.asarray(rows), slots_dev, lens_dev)
+            if self._moe_layers:
+                # fetched with the wave's first tokens
+                self._prefill_counts.append(counts)
         else:
             nxt, self.cache = self._prefill(
                 self.params, self.cache, jnp.asarray(tokens),
@@ -1986,7 +2068,7 @@ class LLMEngine:
             jnp.asarray(temps), jnp.asarray(seeds), jnp.asarray(starts),
             self._lora_args(lidx))
         self.cache = self._scatter_pages_coord(
-            self.cache, ks, vs, jnp.asarray(page_ids),
+            self.cache, ks, vs, self.cache["state"], jnp.asarray(page_ids),
             jnp.asarray(rows), slots_dev, jnp.asarray(true_lens))
         self._cur_dev = self._cur_dev.at[slots_dev].set(nxt)
         return nxt
@@ -2362,19 +2444,28 @@ class LLMEngine:
             if decode is None:
                 decode = self._decode_fns.setdefault(
                     k_win, self._make_decode(k_win))
-            seq, last, self.cache = decode(
+            out = decode(
                 self.params, self.cache, self._cur_dev,
                 jnp.asarray(self._temps), self._table_dev,
                 jnp.asarray(self._seeds), jnp.asarray(starts),
                 self._lora_args(self._adapters))
+            seq, last, self.cache = out[:3]
             self._cur_dev = last                # stays on device
+            if self._moe_layers:
+                out[3].copy_to_host_async()
             self.decode_steps += k_win
             self.lane_steps_live += len(active) * k_win
         with self._phase("decode_sync", iter=it):
             seq = np.asarray(seq)               # the ONE sync per block
+            # the routed layers' counts: a few hundred bytes of the same
+            # program, on their way since dispatch; no second wait
+            moe = np.asarray(out[3]) if self._moe_layers else None
             t_win1 = time.time() if win_traced else 0.0
         with self._phase("deliver", iter=it) as ph:
             tokens0, done0 = self.decode_tokens, self.completed
+            if moe is not None:
+                hit, load = self._count_moe("", moe, k_win)
+                ph.update(experts_hit=hit, max_load=load)
             if win_traced:
                 # One K-step decode window per traced co-resident
                 # request: the window (dispatch → host sync) is the
@@ -2402,6 +2493,19 @@ class LLMEngine:
                         break
             ph.update(tokens=self.decode_tokens - tokens0,
                       finished=self.completed - done0)
+
+    def _count_moe(self, prefix: str, counts, steps: int) -> tuple:
+        """Add one program's routed-layer counts ([layers, 3]: experts
+        hit, largest load, assignments; each summed over the program's
+        `steps`) to the `prefix`ed counters; returns (experts hit,
+        largest load) as means a layer-step."""
+        m, n = self.moe, counts.shape[0] * steps
+        m[prefix + "moe_layer_steps"] += n
+        m[prefix + "moe_experts_hit"] += int(counts[:, 0].sum())
+        m[prefix + "moe_max_load"] += int(counts[:, 1].sum())
+        m[prefix + "moe_assignments"] += int(counts[:, 2].sum())
+        return (round(float(counts[:, 0].sum()) / n, 2),
+                round(float(counts[:, 1].sum()) / n, 2))
 
     def _idle_wait(self) -> None:
         """No lane is live: wait for work under ONE `idle` phase, however
@@ -2450,6 +2554,9 @@ class LLMEngine:
         if self._mgr is not None:
             cur["prefix_hit_tokens"] = self._mgr.hit_tokens
             cur["evictions"] = self._mgr.evictions
+        if self._moe_layers:
+            cur.update({k: self.moe[k] for k in (
+                "moe_layer_steps", "moe_experts_hit", "moe_assignments")})
         with self._metrics_lock:
             self._metrics_t = now
             for key, val in cur.items():
@@ -2519,6 +2626,14 @@ class LLMEngine:
                    "prefill_programs": self.prefill_programs,
                    "prefill_waves": self.prefill_waves,
                    "prefill_waves_split": self.prefill_waves_split}}
+        if self._moe_layers:
+            out["loop"].update(self.moe)
+        if self._lane_layers:
+            out["lane_state"] = {
+                "layers": self._lane_layers,
+                "bytes": int(sum(a.size * a.dtype.itemsize
+                                 for a in self.cache["state"])),
+                "prefix_cache": "off: lane state"}
         if self._lora_banks is not None:
             with self._lora_lock:
                 now = time.monotonic()
@@ -2595,15 +2710,20 @@ class LLMServer:
                  prefix_store: dict | None = None,
                  lora_slots: int = 0, lora_rank: int = 0,
                  lora_directory=None):
-        from ray_tpu.models import llama
+        from ray_tpu.models import named_config, serving_model
 
         _check_pool_role(role, decode_deployment)
         if role == "prefill" and not paged:
             raise ValueError(
                 "role='prefill' requires a paged engine (KV migration "
                 "is page-granular)")
-        cfg = llama.llama_configs()[model] if isinstance(model, str) \
-            else model
+        cfg = named_config(model) if isinstance(model, str) else model
+        served_by = serving_model(cfg)
+        if role != "unified" and "kv_transfer" not in served_by.SERVING_CAPS:
+            raise ValueError(
+                f"role={role!r} needs KV export/import, which "
+                f"{served_by.__name__} lacks (its lanes hold state that "
+                "no page carries): serve it unified")
         name = "llm"
         self._app_name = None
         try:

@@ -9,6 +9,7 @@ metrics_agent.py, collapses to the controller here).
 """
 from __future__ import annotations
 
+import asyncio
 import os
 import threading
 import time
@@ -215,7 +216,11 @@ def _flush_loop() -> None:
                       [json.dumps({"ts": time.time(),
                                    "metrics": snaps}).encode()],
                       timeout=10.0)
-        except Exception:  # noqa: BLE001 - metrics must never crash work
+        except (Exception, asyncio.CancelledError):  # noqa: BLE001
+            # metrics must never crash work; a cluster shutting down
+            # under the call CANCELS it, and CancelledError is no
+            # Exception: uncaught it killed this thread for the life of
+            # the process, and no later cluster saw a metric
             pass
 
 

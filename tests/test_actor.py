@@ -221,3 +221,50 @@ def test_retransmitted_call_does_not_reexecute(ray_shared):
 
     # The counter must NOT have advanced: next real call returns 4.
     assert ray_tpu.get(c.inc.remote()) == 4
+
+
+def _held_back(ray_tpu, nap):
+    """(seconds until 12 short calls queued behind a 2 s call return,
+    seconds for 40 calls of 0.5 s sent 5 ms apart)."""
+    from ray_tpu._private.worker import global_worker
+
+    # the IO loop held for a moment, so that the calls below are queued
+    # together when the outbox is drained (the first rides the fused
+    # path alone)
+    global_worker()._post_to_loop(lambda: time.sleep(0.3))
+    t0 = time.perf_counter()
+    first, slow = nap.remote(0.01), nap.remote(2.0)
+    fast = [nap.remote(0.01) for _ in range(12)]
+    assert ray_tpu.get([first] + fast, timeout=30) == [0.01] * 13
+    behind = time.perf_counter() - t0
+    assert ray_tpu.get(slow, timeout=30) == 2.0
+    t0 = time.perf_counter()
+    refs = []
+    for _ in range(40):
+        refs.append(nap.remote(0.5))
+        time.sleep(0.005)
+    assert ray_tpu.get(refs, timeout=30) == [0.5] * 40
+    return behind, time.perf_counter() - t0
+
+
+def test_unbatched_calls_reply_on_their_own(ray_shared):
+    """Queued calls to one actor share an RPC whose ONE reply waits for
+    the slowest of them, and sixteen such RPCs may be in flight.  A call
+    sent `.options(unbatched=True)` (a serve request to a replica) is
+    held by neither: short calls queued with a long one return at once,
+    and forty long ones overlap."""
+    ray_tpu = ray_shared
+
+    @ray_tpu.remote(max_concurrency=64)
+    class Sleeper:
+        async def nap(self, s):
+            import asyncio
+
+            await asyncio.sleep(s)
+            return s
+
+    a = Sleeper.remote()
+    ray_tpu.get(a.nap.remote(0.0))
+    # the same calls without the option read 2.3 s and 1.0 s here
+    behind, forty = _held_back(ray_tpu, a.nap.options(unbatched=True))
+    assert behind < 1.2 and forty < 0.9

@@ -111,6 +111,45 @@ def test_flash_attention_compiles(one_chip, compiled_kernels,
     assert low.as_text().count("tpu_custom_call") == (3 if grad else 1)
 
 
+@pytest.mark.parametrize("m,k,n", [
+    (256, 2048, 3072),        # LFM2-24B-A2B decode: 64 lanes x 4 -> w13
+    (256, 1536, 2048),        # ... -> w2
+    (65536, 2048, 3072),      # its widest prefill wave: 16 x 1024 x 4
+    (4096, 1536, 2048),
+])
+def test_grouped_matmul_compiles(one_chip, compiled_kernels, m, k, n):
+    """ops/grouped_matmul.py's `moe_gmm` at the served widths: 64 experts,
+    a 16-row tile for decode and a 256-row one for prefill."""
+    from ray_tpu.ops.grouped_matmul import gmm
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    low, c = _compile(lambda x, w, g: gmm(x, w, g, impl="pallas"),
+                      s((m, k)), s((64, k, n)), s((64,), jnp.int32))
+    assert low.as_text().count("tpu_custom_call") == 1
+    assert "moe_gmm" in low.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 2 * 1024 ** 3
+
+
+@pytest.mark.parametrize("b,s", [(16, 1024), (8, 128)])
+def test_attention_pads_head_dim_64_into_the_flash_kernel(
+        topo, one_chip, compiled_kernels, monkeypatch, b, s):
+    """head_dim 64 (LFM2: 32 query over 8 kv heads of 64): attention()'s
+    gate sends it to `flash_fwd` zero-padded to 128 lanes, not to XLA."""
+    import importlib
+
+    # ray_tpu.ops exports the function under the module's name
+    attention = importlib.import_module("ray_tpu.ops.attention")
+    monkeypatch.setattr(jax, "devices", lambda *a: list(topo.devices))
+    q = jax.ShapeDtypeStruct((b, s, 32, 64), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, s, 8, 64), jnp.bfloat16, sharding=one_chip)
+    low, _ = _compile(lambda q, k, v: attention.attention(q, k, v), q, kv, kv)
+    assert low.as_text().count("tpu_custom_call") == 1
+    assert "flash_fwd" in low.as_text()
+    assert (s, s, 64, True) not in attention.xla_fallbacks()
+
+
 @pytest.mark.parametrize("op", ["merge_tail_pages", "gather_pages"])
 def test_page_ops_compile_at_served_widths(one_chip, op):
     from ray_tpu.ops import paged_attention

@@ -1,0 +1,361 @@
+"""models/lfm2.py (LFM2-MoE: gated short convolutions beside GQA
+attention, routed experts that drop no token) against the plain float32
+reference the benchmark holds it to (`benchmarks/harness/refs/
+lfm2_moe.py`, which imports nothing of the program): the prompt pass, the
+prompt pass at a padded bucket followed by paged decode through the pool
+and the lane state, the engine with lanes reused and a forced
+preempt-and-recompute, the grouped matmul under skew, expert ranges, the
+controls a sound comparison must fail, and what the engine refuses for a
+model with lane state."""
+from __future__ import annotations
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.harness.refs import lfm2_moe as ref
+from ray_tpu.models import lfm2, serving_model
+from ray_tpu.ops import grouped_matmul
+from ray_tpu.serve.llm import LLMEngine, LLMServer
+
+ATTN = lfm2.ATTN
+# float32 weights: the served path and the reference then differ by
+# summation order alone, so the bound is tight and every control stands
+# far outside it
+CFG = lfm2.Lfm2MoeConfig(
+    vocab_size=256, dim=64, layer_types=("conv", ATTN, "conv"),
+    n_dense_layers=1, n_heads=4, n_kv_heads=2, ffn_dim=96, moe_ffn_dim=32,
+    n_experts=8, top_k=4, max_seq=128, dtype=jnp.float32)
+MODEL = dict(num_attention_heads=4, num_key_value_heads=2, norm_eps=1e-5,
+             rope_parameters={"rope_theta": 1e6}, conv_L_cache=3,
+             num_experts=8, num_experts_per_tok=4, use_expert_bias=True,
+             norm_topk_prob=True, routed_scaling_factor=1,
+             layer_types=list(CFG.layer_types), num_dense_layers=1)
+TOL = 2e-4          # float32 against float32: summation order
+CONTROL = 2e-2      # what every control must exceed, 100 x TOL
+PAGE, K = 16, 4
+
+
+@pytest.fixture(scope="module")
+def params():
+    return lfm2.init_params(jax.random.PRNGKey(7), CFG)
+
+
+def _tokens(n, seed=0):
+    return np.random.default_rng(seed).integers(0, 256, n).astype(np.int32)
+
+
+def served_logits(params, prompt, follow, bucket, cfg=CFG):
+    """Logits of the served path at every position from the prompt's last
+    on: the prompt padded to `bucket` in a wave of two rows (the other a
+    longer prompt), scattered into a page pool and lane 1, then
+    teacher-forced paged decode in windows of K over `follow`."""
+    n = len(prompt)
+    other = _tokens(bucket, 99)
+    toks = np.zeros((2, bucket), np.int32)
+    toks[0], toks[1, :n] = other, prompt
+    true_lens = jnp.asarray([bucket, n], jnp.int32)
+    h, ks, vs, state, _ = lfm2.prefill(params, jnp.asarray(toks), cfg,
+                                       true_lens)
+    out = [lfm2.project_logits(params, h[1, n - 1])]
+    maxp = 4
+    cache = lfm2.init_paged_cache(cfg, 2, 1 + 2 * maxp, PAGE)
+    table = np.arange(1, 1 + 2 * maxp, dtype=np.int32).reshape(2, maxp)
+    cols = np.arange(bucket) // PAGE
+    cache = lfm2.scatter_prefill_pages(
+        cache, ks, vs, state, jnp.asarray(table[:, cols]),
+        jnp.tile(jnp.arange(bucket) % PAGE, (2, 1)), jnp.arange(2),
+        true_lens)
+    from ray_tpu.ops.paged_attention import merge_tail_pages
+
+    table = jnp.asarray(table)
+    follow = list(follow)
+    # traced anew in every call: a control patches what it calls
+    step = jax.jit(lambda *a: lfm2.decode_step_paged(*a, cfg))
+    for w0 in range(0, len(follow), K):
+        ts = cache["pos"]
+        pages = {"k": cache["k"], "v": cache["v"]}
+        kvh, hd = cfg.n_kv_heads, cfg.head_dim
+        tails = {kv: [jnp.zeros((2, kvh, K, hd), cfg.dtype)
+                      for _ in pages["k"]] for kv in "kv"}
+        st, pos = cache["state"], ts
+        for j, t in enumerate(follow[w0:w0 + K]):
+            lg, tails, st, _ = step(
+                params, pages, tails, st, jnp.asarray([1, t], jnp.int32),
+                pos, ts, j, table)
+            out.append(lg[1])
+            pos = pos + 1
+        cache = {"k": [merge_tail_pages(p, t, table, ts, K)
+                       for p, t in zip(pages["k"], tails["k"])],
+                 "v": [merge_tail_pages(p, t, table, ts, K)
+                       for p, t in zip(pages["v"], tails["v"])],
+                 "pos": ts + K, "state": st}
+    return jnp.stack(out)
+
+
+def _worst(params_served, params_ref, n=21, bucket=32, follow=2 * K):
+    prompt, nxt = _tokens(n, 1), _tokens(follow, 2)
+    got = served_logits(params_served, prompt, nxt, bucket)
+    want = ref.logits(params_ref, list(prompt) + list(nxt), MODEL,
+                      last=follow + 1)
+    return float(jnp.max(jnp.abs(got - want)))
+
+
+# ---------------------------------- (1), (2) against the full forward
+@pytest.mark.parametrize("n", [1, 2, 17, 32])
+def test_prefill_logits_equal_the_reference(params, n):
+    toks = _tokens(32, 3)[None]
+    h, *_ = lfm2.prefill(params, jnp.asarray(toks), CFG,
+                         jnp.asarray([n], jnp.int32))
+    got = lfm2.project_logits(params, h[0, :n])
+    want = ref.logits(params, toks[0, :n], MODEL)
+    assert float(jnp.max(jnp.abs(got - want))) < TOL
+
+
+@pytest.mark.parametrize("n,bucket", [(21, 32), (1, 32), (2, 32), (33, 64)])
+def test_padded_prefill_then_paged_decode_equals_the_reference(
+        params, n, bucket):
+    """true_len a multiple of nothing; the lane state must be the z rows
+    before the TRUE length (zeros where the prompt is shorter than two),
+    and two windows of K steps carry it on."""
+    assert _worst(params, params, n=n, bucket=bucket) < TOL
+
+
+# ------------------------------------------------ (3) through the engine
+def _reference_agrees(params, prompt, served) -> int:
+    """Teacher-forced under the reference's full forward: wherever its
+    top-two margin exceeds the tolerance, the served token is its
+    choice.  Returns how many positions were that clear."""
+    lg = np.asarray(ref.logits(params, list(prompt) + served[:-1], MODEL,
+                               last=len(served)))
+    top2 = np.sort(lg, axis=-1)[:, -2:]
+    clear = top2[:, 1] - top2[:, 0] > TOL
+    assert (np.argmax(lg, -1)[clear] == np.asarray(served)[clear]).all()
+    return int(clear.sum())
+
+
+def test_engine_generates_the_reference_tokens(params):
+    """Two lanes, five prompts of other lengths: lanes are reused, and a
+    pool too small for both forces a preempt-and-recompute.  Greedy
+    tokens equal the reference's wherever its top-two margin exceeds the
+    tolerance."""
+    eng = LLMEngine(CFG, params, max_batch=2, max_len=96, page_size=PAGE,
+                    kv_pages=6, steps_per_sync=K)
+    eng.start()
+    try:
+        prompts = [_tokens(n, 10 + n).tolist() for n in (40, 3, 17, 1, 29)]
+        futs = [eng.submit(p, max_new_tokens=14) for p in prompts]
+        outs = [f.result(timeout=300) for f in futs]
+        st = eng.stats()
+    finally:
+        eng.stop()
+    assert st["preemptions"] >= 1
+    assert st["completed"] == 5
+    clear = sum(_reference_agrees(params, p, o["tokens"])
+                for p, o in zip(prompts, outs))
+    assert clear >= 5 * 14 - 3
+    # the routed layers' counters, and the lane state's declaration
+    loop = st["loop"]
+    assert loop["moe_layer_steps"] > 0
+    assert loop["moe_layer_steps"] % (lfm2.routed_layers(CFG) * K) == 0
+    assert 0 < loop["moe_experts_hit"] <= 8 * loop["moe_layer_steps"]
+    assert loop["moe_max_load"] >= loop["moe_assignments"] \
+        / (8 * loop["moe_layer_steps"])
+    assert loop["prefill_moe_assignments"] >= 4 * sum(map(len, prompts)) \
+        * lfm2.routed_layers(CFG)
+    assert st["lane_state"] == {
+        "layers": 2, "bytes": 2 * 2 * 2 * 64 * 4,
+        "prefix_cache": "off: lane state"}
+    assert st["prefix_cache"] is False
+
+
+# --------------------------------------------------- (4) dropless, skewed
+def _loop_ffn(h2, lp, idx, wts):
+    """The routed FF one row and one assignment at a time."""
+    f = CFG.moe_ffn_dim
+    out = np.zeros(h2.shape, np.float32)
+    for t in range(h2.shape[0]):
+        for e, w in zip(np.asarray(idx[t]), np.asarray(wts[t])):
+            a = np.asarray(h2[t]) @ np.asarray(lp["w13"][e])
+            act = a[:f] / (1 + np.exp(-a[:f])) * a[f:]
+            out[t] += w * (act @ np.asarray(lp["w2"][e]))
+    return out
+
+
+@pytest.mark.parametrize("case", ["all_to_one", "an_empty_expert",
+                                  "as_routed"])
+def test_the_routed_layer_drops_nothing_under_skew(params, case):
+    lp = dict(params["layers"][2])
+    if case == "all_to_one":        # one expert and its three followers
+        lp["expert_bias"] = jnp.asarray([9., 8, 7, 6, 0, 0, 0, 0])
+    elif case == "an_empty_expert":
+        lp["expert_bias"] = lp["expert_bias"].at[5].set(-9.0)
+    h2 = jax.random.normal(jax.random.PRNGKey(3), (40, CFG.dim))
+    idx, wts = lfm2.route(h2, lp, CFG)
+    y, counts = lfm2.routed_ffn(h2, lp, CFG)
+    assert float(np.abs(np.asarray(y) - _loop_ffn(h2, lp, idx, wts)).max()) \
+        < TOL
+    hit, load, n = (int(c) for c in counts)
+    assert n == 40 * 4                      # every assignment computed
+    if case == "all_to_one":
+        assert (hit, load) == (4, 40)
+    elif case == "an_empty_expert":
+        assert hit <= 7 and 5 not in np.asarray(idx)
+
+
+def test_expert_bias_moves_the_selection_and_not_the_weights(params):
+    lp = dict(params["layers"][2])
+    h2 = jax.random.normal(jax.random.PRNGKey(4), (64, CFG.dim))
+    idx0, w0 = lfm2.route(h2, dict(lp, expert_bias=jnp.zeros(8)), CFG)
+    idx1, w1 = lfm2.route(h2, lp, CFG)
+    assert (np.sort(idx0) != np.sort(idx1)).any()       # selection moved
+    s = jax.nn.sigmoid(h2 @ lp["router"])
+    want = jnp.take_along_axis(s, idx1, -1)
+    want = want / (want.sum(-1, keepdims=True) + 1e-6)
+    assert float(jnp.abs(w1 - want).max()) < 1e-6       # weights: s alone
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("sizes", [[6] * 8, [48] + [0] * 7,
+                                   [0, 0, 10, 0, 20, 1, 0, 5], [0] * 8])
+def test_gmm_equals_a_loop_over_groups(impl, sizes):
+    """Both forms (the kernel interpreted here), balanced, all in one
+    group, empty groups, and rows that belong to no group (zero)."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (48, 128))
+    w = jax.random.normal(jax.random.PRNGKey(1), (8, 128, 256))
+    got = grouped_matmul.gmm(x, w, jnp.asarray(sizes, jnp.int32), impl=impl)
+    off = np.concatenate([[0], np.cumsum(sizes)])
+    want = np.zeros((48, 256), np.float32)
+    for g in range(8):
+        want[off[g]:off[g + 1]] = np.asarray(x)[off[g]:off[g + 1]] \
+            @ np.asarray(w)[g]
+    assert float(np.abs(np.asarray(got) - want).max()) < 1e-4
+
+
+def test_gmm_visits_no_empty_group():
+    """The kernel's visit list names only groups that hold a row, so an
+    expert nobody was sent to is never fetched."""
+    sizes = jnp.asarray([0, 0, 10, 0, 20, 1, 0, 5], jnp.int32)
+    g, tile, _ = grouped_matmul.visits(sizes, 48, 16)
+    assert set(np.asarray(g).tolist()) == {2, 4, 5, 7}
+    assert g.shape[0] == 48 // 16 + 8 - 1
+    assert (np.diff(np.asarray(tile)) >= 0).all()
+
+
+# ------------------------------------------------- (5) ranges of experts
+def test_the_parts_of_four_expert_ranges_add_up_to_the_layer(params):
+    cfg = dataclasses.replace(CFG, n_experts=8)
+    lp = params["layers"][1]
+    h2 = jax.random.normal(jax.random.PRNGKey(5), (24, CFG.dim))
+    whole, counts = lfm2.routed_ffn(h2, lp, cfg)
+    parts, n = 0.0, 0
+    for lo in range(0, 8, 2):
+        held = dict(lp, w13=lp["w13"][lo:lo + 2], w2=lp["w2"][lo:lo + 2])
+        y, c = lfm2.routed_ffn(h2, held, cfg, experts=(lo, lo + 2))
+        parts, n = parts + y, n + int(c[2])
+    assert float(jnp.abs(parts - whole).max()) < TOL
+    assert n == int(counts[2]) == 24 * 4
+
+
+# ---------------------------------------------------------- (6) controls
+def _through_fp8(params):
+    def q(a):
+        return a.astype(jnp.float8_e4m3fn).astype(a.dtype)
+    return dict(params, layers=[
+        dict(lp, w13=q(lp["w13"]), w2=q(lp["w2"])) if "w13" in lp else lp
+        for lp in params["layers"]])
+
+
+def _skip_routed_layer(params, lid=1):
+    layers = list(params["layers"])
+    layers[lid] = dict(layers[lid], w2=jnp.zeros_like(layers[lid]["w2"]))
+    return dict(params, layers=layers)
+
+
+def _route_dropping_one(h2, lp, cfg):
+    idx, wts = _ROUTE(h2, lp, cfg)
+    return idx, wts.at[:, -1].set(0.0)
+
+
+def _route_bias_in_weights(h2, lp, cfg):
+    idx, _ = _ROUTE(h2, lp, cfg)
+    s = jax.nn.sigmoid(h2 @ lp["router"]) + lp["expert_bias"]
+    w = jnp.take_along_axis(s, idx, -1)
+    return idx, w / (w.sum(-1, keepdims=True) + 1e-6)
+
+
+def _scatter_zero_state(cache, ks, vs, state, *a, **kw):
+    return _SCATTER(cache, ks, vs, [jnp.zeros_like(s) for s in state],
+                    *a, **kw)
+
+
+_ROUTE, _SCATTER = lfm2.route, lfm2.scatter_prefill_pages
+
+
+@pytest.mark.parametrize("control", [
+    "sound", "experts_through_fp8", "a_routed_layer_skipped",
+    "one_selected_expert_dropped", "expert_bias_in_the_weights",
+    "lane_state_zeroed_at_admission"])
+def test_every_control_exceeds_the_tolerance(params, monkeypatch, control):
+    served = params
+    if control == "experts_through_fp8":
+        served = _through_fp8(params)
+    elif control == "a_routed_layer_skipped":
+        served = _skip_routed_layer(params)
+    elif control == "one_selected_expert_dropped":
+        monkeypatch.setattr(lfm2, "route", _route_dropping_one)
+    elif control == "expert_bias_in_the_weights":
+        monkeypatch.setattr(lfm2, "route", _route_bias_in_weights)
+    elif control == "lane_state_zeroed_at_admission":
+        monkeypatch.setattr(lfm2, "scatter_prefill_pages",
+                            _scatter_zero_state)
+    worst = _worst(served, params)
+    if control == "sound":
+        assert worst < TOL
+    else:
+        assert worst > CONTROL
+
+
+# --------------------------------------------- (7) what the engine refuses
+def test_a_model_with_lane_state_is_served_without_the_prefix_cache(params):
+    assert serving_model(CFG) is lfm2
+    with pytest.raises(ValueError, match="radix prefix hit cannot restore"):
+        LLMEngine(CFG, params, max_batch=2, max_len=64, page_size=PAGE,
+                  prefix_cache=True)
+    with pytest.raises(ValueError, match="no LoRA hooks"):
+        LLMEngine(CFG, params, max_batch=2, max_len=64, page_size=PAGE,
+                  lora_slots=2, lora_rank=4)
+    with pytest.raises(ValueError, match="no dense"):
+        LLMEngine(CFG, params, max_batch=2, max_len=64, paged=False)
+    eng = LLMEngine(CFG, params, max_batch=2, max_len=64, page_size=PAGE)
+    assert eng.stats()["prefix_cache"] is False
+    assert eng.stats()["lane_state"]["prefix_cache"] == "off: lane state"
+    with pytest.raises(ValueError, match="no KV export/import"):
+        eng.submit([1, 2, 3], prefill_only=True)
+    with pytest.raises(ValueError, match="no KV export/import"):
+        eng.kv_graft(list(range(PAGE)), np.zeros(1), kv_len=PAGE)
+
+
+@pytest.mark.parametrize("kw,match", [
+    (dict(lora_slots=2, lora_rank=4), "no LoRA hooks"),
+    (dict(role="prefill", decode_deployment="decode"), "serve it unified"),
+    (dict(role="decode"), "serve it unified"),
+    (dict(prefix_cache=True), "radix prefix hit cannot restore"),
+])
+def test_the_server_refuses_at_construction(params, kw, match):
+    with pytest.raises(ValueError, match=match):
+        LLMServer(CFG, params=params, max_batch=2, max_len=64,
+                  page_size=PAGE, **kw)
+
+
+def test_the_server_serves_a_preset_by_name():
+    srv = LLMServer("lfm2-debug", max_batch=2, max_len=64, page_size=PAGE)
+    try:
+        out = srv.engine.generate([5, 6, 7], max_new_tokens=5)
+        assert len(out["tokens"]) == 5
+        assert srv._prefix_client is None       # no demotion either
+    finally:
+        srv.engine.stop()
