@@ -104,14 +104,17 @@ def engine_lowerings(cfg, eng_kw: dict, shapes, sharding=None) -> dict:
     eng = LLMEngine(cfg, params, **eng_kw)
     i32, f32 = jnp.int32, jnp.float32
     out = {}
+    def lora(rows):      # None without adapter banks (`lora_slots`)
+        return abstract(eng._lora_args([0] * rows))
+
     for w, p in shapes:
         out[f"prefill_w{w}_p{p}"] = eng._prefill_fwd.lower(
             params, sds((w, p), i32), sds((w,), i32), sds((w,), i32),
-            sds((w,), f32), sds((w,), i32), sds((w,), i32), None)
+            sds((w,), f32), sds((w,), i32), sds((w,), i32), lora(w))
     b, k = eng.max_batch, eng.steps_per_sync
     out[f"decode_k{k}"] = eng._decode_fns[k].lower(
         params, abstract(eng.cache), sds((b,), i32), sds((b,), f32),
-        sds((b, eng._maxp), i32), sds((b,), i32), sds((b,), i32), None)
+        sds((b, eng._maxp), i32), sds((b,), i32), sds((b,), i32), lora(b))
     return out
 
 

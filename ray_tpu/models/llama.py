@@ -33,7 +33,11 @@ from ray_tpu.parallel.sharding import with_sharding_constraint
 # `jax.named_scope` (embed, layer_weights, attn_qkv, rope, attn,
 # attn_out, mlp, norm, lm_head, kv_write), so XProf and the benchmark's
 # --dump-trace show which part an op belongs to.  Scopes change op
-# metadata only, never the compiled program.
+# metadata only, never the compiled program.  `layer_weights` (a layer's
+# slices of the stacked arrays) must hold no device time: each slice is a
+# view read inside its matmul.  It held 2.1 ms of an 18.5 ms step while
+# the decode paths reshaped the q/k/v products into heads (`_decode_qkv`
+# says why; PERF.md section 6, PR 29).
 rmsnorm = jax.named_call(rmsnorm, name="norm")
 apply_rope = jax.named_call(apply_rope, name="rope")
 attention = jax.named_call(attention, name="attn")
@@ -508,6 +512,24 @@ def _lora_layer_slice(lora, lid):
             lora["idx"])
 
 
+def _decode_qkv(h, lp, cfg: LlamaConfig, lb=None, idx=None):
+    """One decode token's q, k, v as heads ([b, 1, heads, head_dim]).
+
+    The three products are held FLAT behind an optimization barrier, so
+    the split into heads is a view of the [b, 1, heads*head_dim]
+    activations.  A reshape that touches the dot is folded into it: XLA
+    then wants wq/wk/wv as [heads, head_dim, in] and transposes all
+    three, every layer, on every step (768 MB a step at Mistral-7B-d16;
+    PERF.md section 6, PR 29; tests/test_chip_compile.py guards it)."""
+    b = h.shape[0]
+    lb = lb or {}
+    q, k, v = lax.optimization_barrier(tuple(
+        _lora_proj(h, lp[t], lb.get(t), idx) for t in ("wq", "wk", "wv")))
+    return (q.reshape(b, 1, cfg.n_heads, cfg.head_dim),
+            k.reshape(b, 1, cfg.n_kv_heads, cfg.head_dim),
+            v.reshape(b, 1, cfg.n_kv_heads, cfg.head_dim))
+
+
 # ---------------------------------------------------------------- decode
 def prefill(params: dict, tokens: jnp.ndarray, cfg: LlamaConfig,
             lora: dict | None = None,
@@ -575,9 +597,7 @@ def _decode_layer(x, lp, ck, cv, pos, cos, sin, mask, cfg: LlamaConfig):
 
     h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
     with jax.named_scope("attn_qkv"):
-        q = (h @ lp["wq"]).reshape(b, 1, cfg.n_heads, cfg.head_dim)
-        k = (h @ lp["wk"]).reshape(b, 1, cfg.n_kv_heads, cfg.head_dim)
-        v = (h @ lp["wv"]).reshape(b, 1, cfg.n_kv_heads, cfg.head_dim)
+        q, k, v = _decode_qkv(h, lp, cfg)
     q = apply_rope(q, cos, sin, positions=pos[:, None])
     k = apply_rope(k, cos, sin, positions=pos[:, None])
     with jax.named_scope("kv_write"):
@@ -827,7 +847,10 @@ def decode_step_paged(params: dict, pages: dict, tails: dict,
     {"k"/"v": [L x [B, kvh, kt, hd]]} at the shared in-block column
     `j` (a scalar: every slot's pos advances in lockstep, so
     pos - tail_start is uniform).  After the block, the engine merges
-    tails into pages with ops.paged_attention.merge_tail_pages."""
+    tails into pages with ops.paged_attention.merge_tail_pages.
+
+    q, k, v come from `_decode_qkv`: the products stay flat behind a
+    barrier, or the compiler re-lays-out wq/wk/wv every step (PR 29)."""
     from ray_tpu.ops.paged_attention import paged_decode_attention
 
     b = tokens.shape[0]
@@ -846,12 +869,7 @@ def decode_step_paged(params: dict, pages: dict, tails: dict,
         lb = lb or {}
         h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
         with jax.named_scope("attn_qkv"):
-            q = _lora_proj(h, lp["wq"], lb.get("wq"), lidx) \
-                .reshape(b, 1, cfg.n_heads, cfg.head_dim)
-            k = _lora_proj(h, lp["wk"], lb.get("wk"), lidx) \
-                .reshape(b, 1, cfg.n_kv_heads, cfg.head_dim)
-            v = _lora_proj(h, lp["wv"], lb.get("wv"), lidx) \
-                .reshape(b, 1, cfg.n_kv_heads, cfg.head_dim)
+            q, k, v = _decode_qkv(h, lp, cfg, lb, lidx)
         q = apply_rope(q, cos, sin, positions=pos[:, None])
         k = apply_rope(k, cos, sin, positions=pos[:, None])
         qg = q.reshape(b, cfg.n_kv_heads, n_rep, cfg.head_dim)

@@ -180,7 +180,16 @@ def merge_tail_pages(pages, tail, page_table, tail_start, n_rows):
     slot b lands at absolute position tail_start[b] + j for j < n_rows.
     Positions past a slot's allocation resolve to the trash page via
     the zeroed table columns.  Call ONCE per decode block with `pages`
-    donated — whole-pool traffic per K steps, not per step."""
+    donated: the rows are written in place.
+
+    The head is an index too, so each update is one contiguous row of
+    the pool as stored.  Indexed by (page, row) alone the update is a
+    [kvh, hd] window strided over the page: XLA:TPU then transposes the
+    WHOLE pool, scatters and transposes back, two copies of every pool
+    a block (27.4 ms a block of 8 steps at Mistral-7B-d16 against 4.8;
+    PERF.md section 6, PR 29).  Rows narrower than a lane tile
+    (head_dim 64) scatter slowly one by one (7.7 ms against 5.3 for
+    LFM2's four pools), so they keep the window."""
     B, kvh, kt, hd = tail.shape
     page = pages.shape[2]
     maxp = page_table.shape[1]
@@ -193,7 +202,10 @@ def merge_tail_pages(pages, tail, page_table, tail_start, n_rows):
     # short block can't clobber live data with stale tail columns.
     pids = jnp.where(j < n_rows, pids, 0)
     value = tail.transpose(0, 2, 1, 3)                 # [B, kt, kvh, hd]
-    return pages.at[pids, :, rows].set(value)
+    if hd % 128:
+        return pages.at[pids, :, rows].set(value)
+    heads = jnp.arange(kvh)[None, None, :]
+    return pages.at[pids[:, :, None], heads, rows[:, :, None]].set(value)
 
 
 def gather_pages(pages, page_table):

@@ -11,7 +11,9 @@ load the TPU library (see the on-chip-measurement guide, section 2).
 Interpret mode is steered off from here (the program's own gate sees the
 CPU backend), not through an option of ops/.
 """
+import math
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -171,6 +173,31 @@ def test_page_ops_compile_at_served_widths(one_chip, op):
     assert c.memory_analysis().temp_size_in_bytes < HBM_BYTES
 
 
+@pytest.mark.parametrize("B,maxp,n_pages", [(32, 4, 129), (8, 16, 161)],
+                         ids=["mistral7b", "codestral22b"])
+def test_merge_tail_pages_scatters_in_place(one_chip, B, maxp, n_pages):
+    """The block's rows go into the DONATED pool where it lies: no copy
+    of the pool beside it.  Indexed by (page, row) alone, XLA:TPU
+    transposed the whole 135 MB pool, scattered, and transposed back:
+    one pool of temporaries and 3.3 ms of a 15.7 ms decode step on the
+    chip (PERF.md section 6, PR 29)."""
+    from ray_tpu.ops import paged_attention
+
+    kvh, hd, page, kt = 8, 128, 512, 8
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    c = jax.jit(
+        lambda p, t, tb, ts: paged_attention.merge_tail_pages(
+            p, t, tb, ts, kt), donate_argnums=0).lower(
+        s((n_pages, kvh, page, hd)), s((B, kvh, kt, hd)),
+        s((B, maxp), jnp.int32), s((B,), jnp.int32)).compile()
+    pool_bytes = n_pages * kvh * page * hd * 2
+    assert c.memory_analysis().temp_size_in_bytes < pool_bytes // 8
+    assert _pool_copies(c.as_text(), pool_bytes // 2) == []
+
+
 def test_served_engine_fits_one_chip(topo, one_chip, compiled_kernels,
                                      monkeypatch):
     """chip_smoke.py's deployment (llama3-8b widths at the depth it
@@ -196,6 +223,181 @@ def test_served_engine_fits_one_chip(topo, one_chip, compiled_kernels,
         # 2 GiB of headroom for what the process keeps besides this
         # program (the other program's outputs, staging buffers).
         assert total < HBM_BYTES - 2 * 1024 ** 3, (name, total)
+
+
+# ----------------------------- what the decode program's step loop holds
+# Read from the compiled program's text: the static counter of PERF.md
+# section 6, PR 29.  A weight is read once, by the matmul that uses it;
+# an instruction of the K-step scan's body that WRITES something the
+# size of a weight (a re-layout, a slice copied out of the stacked
+# arrays, a page pool copied) is traffic the step does not need.
+_ARRAY = re.compile(r"\b[a-z]+\d+\w*\[([0-9,]*)\]\{([0-9,]*)")
+_INSTR = re.compile(r"\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\(")
+# views, control flow and the second half of an asynchronous copy
+_NO_WRITE = {"parameter", "get-tuple-element", "tuple", "bitcast",
+             "constant", "while", "conditional", "call", "copy-done",
+             "slice-done", "dynamic-slice-done", "async-done"}
+
+
+def _arrays(shape: str) -> list:
+    """(elements, minor-to-major layout) of each array in an
+    instruction's output shape (a tuple has several)."""
+    return [(math.prod(map(int, dims.split(","))), layout)
+            for dims, layout in _ARRAY.findall(shape) if dims]
+
+
+def _computations(hlo: str) -> dict:
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        if name is None:
+            m = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{\s*$", line)
+            if m:
+                name = m.group(1)
+                comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        else:
+            comps[name].append(line)
+    return comps
+
+
+def _holds_matmul(comps, name, seen) -> bool:
+    if name in seen or name not in comps:
+        return False
+    seen.add(name)
+    return any(re.search(r"\b(convolution|dot)\(", ln)
+               or any(_holds_matmul(comps, c, seen)
+                      for c in re.findall(r"calls=%([\w.\-]+)", ln))
+               for ln in comps[name])
+
+
+def weight_sized_writes(hlo: str, min_elems: int) -> list:
+    """(instruction, op, scope) of every instruction in a `while` body
+    (and what it calls) whose output has at least `min_elems` elements
+    and is not a matmul fusion, a view, or an asynchronous prefetch that
+    keeps the layout (a DMA of the stored bytes: the one read)."""
+    comps = _computations(hlo)
+    todo = [b for lines in comps.values() for ln in lines
+            for b in re.findall(r"\bwhile\(.*body=%([\w.\-]+)", ln)]
+    seen, found = set(), []
+    while todo:
+        body = todo.pop()
+        if body in seen or body not in comps:
+            continue
+        seen.add(body)
+        for ln in comps[body]:
+            m = _INSTR.match(ln)
+            if not m:
+                continue
+            name, out, op = m.groups()
+            todo += re.findall(
+                r"(?:to_apply|body|true_computation|false_computation)"
+                r"=%([\w.\-]+)", ln)
+            if op in _NO_WRITE or "ConcatBitcast" in ln:
+                continue
+            arrays = _arrays(out)
+            if op.endswith("-start"):
+                # ((operands), output, context): same layout = prefetch
+                big = [a for a in arrays if a[0] >= min_elems]
+                if len({layout for _, layout in big}) <= 1:
+                    continue
+            if not any(n >= min_elems for n, _ in arrays):
+                continue
+            if op == "fusion" and _holds_matmul(
+                    comps, re.search(r"calls=%([\w.\-]+)", ln).group(1),
+                    set()):
+                continue
+            scope = re.search(r'op_name="([^"]*)"', ln)
+            found.append((name, op, scope.group(1) if scope else ""))
+    return found
+
+
+def _pool_copies(hlo: str, min_elems: int) -> list:
+    """Names of the `copy` instructions of at least `min_elems` elements
+    anywhere outside a fusion: a layout change of something resident."""
+    found = []
+    for name, lines in _computations(hlo).items():
+        if "fused_computation" in name:
+            continue
+        for ln in lines:
+            m = _INSTR.match(ln)
+            if m and m.group(3) == "copy" and any(
+                    n >= min_elems for n, _ in _arrays(m.group(2))):
+                found.append(m.group(1))
+    return found
+
+
+def test_weight_sized_writes_reads_a_program_text():
+    """The reader itself, on four instructions of a real program: the
+    re-layout and the copy are found, the matmul fusion, the view and the
+    layout-keeping prefetch are not."""
+    hlo = """HloModule m
+%fused_mm (p0: bf16[32,4096], p1: bf16[2,4096,4096]) -> bf16[32,4096] {
+  %p0 = bf16[32,4096]{1,0} parameter(0)
+  %p1 = bf16[2,4096,4096]{2,1,0} parameter(1)
+  ROOT %convolution.1 = bf16[32,4096]{1,0} convolution(%p0, %p1)
+}
+%fused_slice (p0: bf16[2,4096,4096]) -> bf16[1,4096,4096] {
+  %p0 = bf16[2,4096,4096]{1,2,0} parameter(0)
+  ROOT %slice.1 = bf16[1,4096,4096]{1,2,0} slice(%p0), slice={[0:1]}
+}
+%body (arg: (s32[], bf16[2,4096,4096])) -> (s32[], bf16[2,4096,4096]) {
+  %arg = (s32[], bf16[2,4096,4096]{2,1,0}) parameter(0)
+  %w = bf16[2,4096,4096]{2,1,0:T(8,128)(2,1)} get-tuple-element(%arg), index=1
+  %relayout = bf16[1,4096,4096]{1,2,0:T(8,128)(2,1)} fusion(%w), kind=kLoop, calls=%fused_slice, metadata={op_name="jit(f)/while/body/layer_weights/slice"}
+  %copy.1 = bf16[2,4096,4096]{1,2,0:T(8,128)(2,1)} copy(%w)
+  %slice-start = ((bf16[2,4096,4096]{2,1,0:T(8,128)(2,1)}), bf16[1,4096,4096]{2,1,0:T(8,128)(2,1)S(1)}, s32[]{:S(2)}) slice-start(%w), slice={[0:1]}
+  %mm = bf16[32,4096,128]{2,1,0} fusion(%w, %w), kind=kOutput, calls=%fused_mm
+  ROOT %t = (s32[], bf16[2,4096,4096]{2,1,0}) tuple(%w)
+}
+ENTRY %main (p: bf16[2,4096,4096]) -> bf16[2,4096,4096] {
+  %p = bf16[2,4096,4096]{2,1,0} parameter(0)
+  %while.1 = (s32[], bf16[2,4096,4096]{2,1,0}) while(%p), condition=%cond, body=%body
+}
+"""
+    found = weight_sized_writes(hlo, 4096 * 1024)
+    assert [(n, op) for n, op, _ in found] == [("relayout", "fusion"),
+                                                ("copy.1", "copy")]
+    assert found[0][2].endswith("layer_weights/slice")
+
+
+@pytest.mark.parametrize("widths,engine,lora_slots", [
+    # Mistral-7B-v0.3 (benchmarks/configs/mistral-7b-v0.3-d16.json)
+    (dict(dim=4096, n_heads=32, n_kv_heads=8, ffn_dim=14336),
+     dict(max_batch=32, max_len=2048, kv_pages=129), 0),
+    # Codestral-22B-v0.1: 48 query heads over 8, a GQA group of 6
+    (dict(dim=6144, n_heads=48, n_kv_heads=8, ffn_dim=16384),
+     dict(max_batch=8, max_len=8192, kv_pages=161), 0),
+    # Mistral widths with adapter banks on all four projections
+    (dict(dim=4096, n_heads=32, n_kv_heads=8, ffn_dim=14336),
+     dict(max_batch=32, max_len=2048, kv_pages=129), 4),
+], ids=["mistral7b", "codestral22b", "mistral7b-lora"])
+def test_decode_step_loop_writes_nothing_the_size_of_a_weight(
+        topo, one_chip, compiled_kernels, monkeypatch, widths, engine,
+        lora_slots):
+    """The engine's K-step paged decode program at the benchmark's widths
+    (2 layers: the body is per layer): every weight byte is read once, by
+    its matmul.  Written with the reshape into heads ON the q/k/v
+    products, the step transposed wq, wk and wv of every layer
+    (`{1,2,0}` copies under `layer_weights`, 2.1 ms of an 18.5 ms step
+    on the chip; PERF.md section 6, PR 29)."""
+    import chip_smoke
+    from ray_tpu.models import llama
+
+    monkeypatch.setattr(jax, "devices", lambda *a: list(topo.devices))
+    cfg = llama.LlamaConfig(vocab_size=32768, n_layers=2,
+                            max_seq=engine["max_len"], **widths)
+    eng_kw = dict(engine, paged=True, page_size=512, steps_per_sync=8,
+                  lora_slots=lora_slots, lora_rank=16 if lora_slots else 0)
+    hlo = chip_smoke.engine_lowerings(
+        cfg, eng_kw, [], sharding=one_chip)["decode_k8"].compile().as_text()
+    assert "while(" in hlo and "paged_attn" in hlo
+    smallest_weight = cfg.dim * cfg.n_kv_heads * cfg.head_dim
+    assert weight_sized_writes(hlo, smallest_weight) == []
+    # ... and once a window, outside the loop, `merge_tail_pages` writes
+    # the block's rows into the pools in place
+    pool = engine["kv_pages"] * cfg.n_kv_heads * 512 * cfg.head_dim
+    assert _pool_copies(hlo, pool) == []
 
 
 def test_sharded_train_step_lowers_for_four_chips(topo, compiled_kernels,

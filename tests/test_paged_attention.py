@@ -73,6 +73,35 @@ def test_merge_tail_roundtrip():
                                np.asarray(o_next), atol=1e-5)
 
 
+@pytest.mark.parametrize("n_rows", [6, 4], ids=["whole", "short"])
+@pytest.mark.parametrize("hd", [128, 64], ids=["hd128", "hd64"])
+def test_merge_tail_pages_writes_the_block_rows_and_no_other(hd, n_rows):
+    """Both scatters of merge_tail_pages (rows of a whole lane tile go in
+    place, one contiguous row an update; narrower ones as a [kvh, hd]
+    window) against a loop in numpy: a block that crosses a page edge,
+    one that ends its page, a short block whose stale columns must land
+    on the trash page, and a slot without pages."""
+    from ray_tpu.ops.paged_attention import merge_tail_pages
+
+    rng = np.random.default_rng(hd + n_rows)
+    B, kvh, kt, page, maxp, n_pages = 4, 2, 6, 8, 3, 8
+    pages = rng.normal(size=(n_pages, kvh, page, hd)).astype(np.float32)
+    tail = rng.normal(size=(B, kvh, kt, hd)).astype(np.float32)
+    table = np.asarray([[1, 2, 0], [3, 4, 5], [6, 7, 0], [0, 0, 0]],
+                       np.int32)
+    ts = np.asarray([5, 10, 2, 0], np.int32)
+    want = pages.copy()
+    for b in range(B):
+        for j in range(n_rows):
+            apos = min(ts[b] + j, maxp * page - 1)
+            want[table[b, apos // page], :, apos % page] = tail[b, :, j]
+    got = np.asarray(jax.jit(merge_tail_pages, static_argnums=4)(
+        jnp.asarray(pages), jnp.asarray(tail), jnp.asarray(table),
+        jnp.asarray(ts), n_rows))
+    np.testing.assert_array_equal(got[1:], want[1:])   # page 0: trash
+    assert not np.array_equal(got[1:], pages[1:])
+
+
 def test_kernel_clamps_runaway_idle_pos():
     """An idle slot's pos keeps advancing between reuses; the kernel must
     clamp rather than index past the table."""
@@ -159,3 +188,76 @@ def test_long_context_engine_no_dense_prealloc():
         assert out["tokens"] == ref["tokens"]
     finally:
         eng.stop()
+
+
+@pytest.mark.parametrize("lora", [False, True], ids=["base", "lora"])
+@pytest.mark.parametrize("n_heads,n_kv_heads", [(4, 4), (8, 2), (12, 2)],
+                         ids=["rep1", "rep4", "rep6"])
+def test_decode_step_paged_equals_the_reshape_on_the_product(
+        monkeypatch, n_heads, n_kv_heads, lora):
+    """`llama._decode_qkv` holds the q/k/v products flat behind a barrier
+    (so that the compiler leaves the weights' layout alone: PERF.md
+    section 6, PR 29).  The arithmetic is what it was: written as before,
+    the reshape into heads on each product, the step gives the same
+    logits and the same new K/V rows, with and without adapter banks."""
+    from ray_tpu.models import llama
+
+    cfg = llama.LlamaConfig(vocab_size=256, dim=32 * n_heads, n_layers=2,
+                            n_heads=n_heads, n_kv_heads=n_kv_heads,
+                            ffn_dim=256, max_seq=128, remat=False)
+    keys = iter(jax.random.split(jax.random.PRNGKey(n_heads), 32))
+    params = llama.init_params(next(keys), cfg)
+    B, page, maxp, kt, j = 3, 16, 4, 4, 1
+    kvh, hd = cfg.n_kv_heads, cfg.head_dim
+    n_pages = 1 + B * maxp
+
+    def rand(shape):
+        return jax.random.normal(next(keys), shape, jnp.float32) \
+            .astype(cfg.dtype)
+
+    pages = {kv: [rand((n_pages, kvh, page, hd))
+                  for _ in range(cfg.n_layers)] for kv in "kv"}
+    tails = {kv: [rand((B, kvh, kt, hd)) for _ in range(cfg.n_layers)]
+             for kv in "kv"}
+    table = jnp.arange(1, n_pages, dtype=jnp.int32).reshape(B, maxp)
+    tail_start = jnp.asarray([5, 17, 40], jnp.int32)
+    args = (jnp.asarray([7, 99, 200], jnp.int32), tail_start + j,
+            tail_start, j, table, cfg)
+    bank = None
+    if lora:
+        slots, rank = 3, 4                       # slot 0: the base model
+        bank = {"idx": jnp.asarray([0, 2, 1], jnp.int32), "banks": {
+            t: {"a": rand((cfg.n_layers, slots, din, rank)).at[:, 0].set(0),
+                "b": rand((cfg.n_layers, slots, rank, dout)).at[:, 0].set(0)}
+            for t, (din, dout) in llama.lora_target_dims(cfg).items()}}
+
+    def as_before(h, lp, cfg, lb=None, idx=None):
+        b, lb = h.shape[0], lb or {}
+        return (llama._lora_proj(h, lp["wq"], lb.get("wq"), idx)
+                .reshape(b, 1, cfg.n_heads, cfg.head_dim),
+                llama._lora_proj(h, lp["wk"], lb.get("wk"), idx)
+                .reshape(b, 1, cfg.n_kv_heads, cfg.head_dim),
+                llama._lora_proj(h, lp["wv"], lb.get("wv"), idx)
+                .reshape(b, 1, cfg.n_kv_heads, cfg.head_dim))
+
+    # traced anew for each side: the second runs the patched projection
+    logits, new_tails = jax.jit(
+        lambda p, pg, tl, lo: llama.decode_step_paged(
+            p, pg, tl, *args, lo))(params, pages, tails, bank)
+    monkeypatch.setattr(llama, "_decode_qkv", as_before)
+    want, want_tails = jax.jit(
+        lambda p, pg, tl, lo: llama.decode_step_paged(
+            p, pg, tl, *args, lo))(params, pages, tails, bank)
+    assert float(jnp.abs(want).max()) > 0.1
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(want))
+    for kv in "kv":
+        for got, ref in zip(new_tails[kv], want_tails[kv]):
+            np.testing.assert_array_equal(
+                np.asarray(got, np.float32), np.asarray(ref, np.float32))
+    if lora:        # the banks do something: lane 0 rides slot 0, the base
+        base, _ = jax.jit(
+            lambda p, pg, tl: llama.decode_step_paged(
+                p, pg, tl, *args))(params, pages, tails)
+        np.testing.assert_array_equal(np.asarray(base[0]),
+                                      np.asarray(want[0]))
+        assert float(jnp.abs(base[1:] - want[1:]).max()) > 1e-3
