@@ -29,8 +29,7 @@ from ray_tpu.models.vit import ViTConfig, vit_configs
 #     assignments computed (0 rows: nothing is counted);
 # and `SERVING_CAPS`: the optional capabilities it has, under their own
 # names ("prefix": prefill_with_prefix; "lora": the adapter hooks;
-# "kv_transfer": KV export/import/graft; "dense": the paged=False layout:
-# prefill, decode_step_unrolled, init_kv_cache_leaves).
+# "kv_transfer": KV export/import/graft).
 _SERVING = {"LlamaConfig": "ray_tpu.models.llama",
             "Lfm2MoeConfig": "ray_tpu.models.lfm2"}
 

@@ -7,8 +7,8 @@ runs: `init_params`, `init_paged_cache`, `prefill`,
 `scatter_prefill_pages`, `decode_step_paged`.  It has none of the
 optional capabilities (`SERVING_CAPS` is empty): a lane carries
 convolution state that no KV page holds, so a radix prefix hit cannot
-restore it (no `prefill_with_prefix`), and there are no LoRA hooks, no KV
-export/import and no dense (unpaged) layout.
+restore it (no `prefill_with_prefix`), and there are no LoRA hooks and
+no KV export/import.
 
 The equations (transformers' `modeling_lfm2_moe.py`, from the model's
 `config.json`).  `x_0 = Embed[t]`; for layer l

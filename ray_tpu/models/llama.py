@@ -36,7 +36,7 @@ from ray_tpu.parallel.sharding import with_sharding_constraint
 # metadata only, never the compiled program.  `layer_weights` (a layer's
 # slices of the stacked arrays) must hold no device time: each slice is a
 # view read inside its matmul.  It held 2.1 ms of an 18.5 ms step while
-# the decode paths reshaped the q/k/v products into heads (`_decode_qkv`
+# the decode step reshaped the q/k/v products into heads (`_decode_qkv`
 # says why; PERF.md section 6, PR 29).
 rmsnorm = jax.named_call(rmsnorm, name="norm")
 apply_rope = jax.named_call(apply_rope, name="rope")
@@ -110,7 +110,7 @@ def llama_configs() -> dict[str, LlamaConfig]:
 # What this module gives the serving seam (models/__init__.py) beyond
 # the required functions (at the end of the file): every optional
 # capability.
-SERVING_CAPS = frozenset({"prefix", "lora", "kv_transfer", "dense"})
+SERVING_CAPS = frozenset({"prefix", "lora", "kv_transfer"})
 serving_configs = llama_configs
 
 
@@ -543,7 +543,7 @@ def prefill(params: dict, tokens: jnp.ndarray, cfg: LlamaConfig,
     k [L, b, P, n_kv, hd], v likewise), RoPE already applied.  Padding
     rows produce garbage K/V that decode never attends to: the decode
     mask admits only kpos <= pos and each decode step overwrites its own
-    position before reading it (see decode_step).
+    position before reading it (see decode_step_paged).
 
     lora: None/{} (base model) or {"idx": [b] int32 slots, "banks":
     {target: {"a": [L, n_slots, din, r], "b": [L, n_slots, r, dout]}}}
@@ -583,96 +583,10 @@ def prefill(params: dict, tokens: jnp.ndarray, cfg: LlamaConfig,
     return x, ks, vs
 
 
-def _decode_layer(x, lp, ck, cv, pos, cos, sin, mask, cfg: LlamaConfig):
-    """One decoder layer of a single decode step — SHARED by decode_step
-    (scanned layers) and decode_step_unrolled (per-layer cache leaves),
-    so the two paths cannot diverge.  ck/cv: [b, max_len, kvh, hd];
-    returns (x, ck, cv) with the current token's K/V written at pos."""
-    b = x.shape[0]
-    n_rep = cfg.n_heads // cfg.n_kv_heads
-
-    def write_row(c, kv, p):
-        # c [max_len, kvh, hd], kv [1, kvh, hd]: write one position.
-        return lax.dynamic_update_slice(c, kv, (p, 0, 0))
-
-    h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-    with jax.named_scope("attn_qkv"):
-        q, k, v = _decode_qkv(h, lp, cfg)
-    q = apply_rope(q, cos, sin, positions=pos[:, None])
-    k = apply_rope(k, cos, sin, positions=pos[:, None])
-    with jax.named_scope("kv_write"):
-        ck = jax.vmap(write_row)(ck, k.astype(cfg.dtype), pos)
-        cv = jax.vmap(write_row)(cv, v.astype(cfg.dtype), pos)
-    with jax.named_scope("attn"):
-        # Grouped-query attention without materializing repeated K/V:
-        # queries fold into [kv-group, rep] and share the group's cache.
-        qg = q.reshape(b, 1, cfg.n_kv_heads, n_rep, cfg.head_dim)
-        a = jnp.einsum("bqgrd,bkgd->bgrqk", qg, ck,
-                       preferred_element_type=jnp.float32)
-        a *= cfg.head_dim ** -0.5
-        a = jnp.where(mask[:, None, None, None, :], a, -1e30)
-        probs = jax.nn.softmax(a, axis=-1).astype(cfg.dtype)
-        o = jnp.einsum("bgrqk,bkgd->bqgrd", probs, cv)
-        o = o.reshape(b, 1, cfg.n_heads * cfg.head_dim)
-    with jax.named_scope("attn_out"):
-        x = x + (o @ lp["wo"])
-    with jax.named_scope("mlp"):
-        h2 = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
-        gg = jax.nn.silu((h2 @ lp["w_gate"]).astype(jnp.float32))
-        x = x + ((gg.astype(cfg.dtype) * (h2 @ lp["w_up"]))
-                 @ lp["w_down"])
-    return x, ck, cv
-
-
-def init_kv_cache_leaves(cfg: LlamaConfig, batch: int,
-                         max_len: int) -> dict:
-    """Per-layer cache leaves for decode_step_unrolled: separate [b, S,
-    kvh, hd] arrays per layer (a pytree of 2L leaves) instead of one
-    stacked [L, ...] array.  The stacked form forces `lax.scan` to carry
-    the cache as xs/ys, which XLA cannot alias — every decode step copied
-    the ENTIRE cache (measured 25.8ms vs 13.8ms per step at b64 x S512 on
-    v5e).  Separate donated leaves update in place."""
-    shape = (batch, max_len, cfg.n_kv_heads, cfg.head_dim)
-    return {"k": [jnp.zeros(shape, cfg.dtype)
-                  for _ in range(cfg.n_layers)],
-            "v": [jnp.zeros(shape, cfg.dtype)
-                  for _ in range(cfg.n_layers)],
-            "pos": jnp.zeros((batch,), jnp.int32)}
-
-
-def decode_step_unrolled(params: dict, cache: dict, tokens: jnp.ndarray,
-                         cfg: LlamaConfig) -> tuple[jnp.ndarray, dict]:
-    """One decode step with layers UNROLLED over per-layer cache leaves
-    (see init_kv_cache_leaves).  Compiles one body per layer — fine for
-    a serving engine that jits exactly one decode program — in exchange
-    for in-place cache updates (no per-step whole-cache copy)."""
-    b = tokens.shape[0]
-    max_len = cache["k"][0].shape[1]
-    pos = cache["pos"]
-    x = embed_lookup(params["embed"], tokens[:, None], cfg.dtype)
-    cos, sin = rope_frequencies(cfg.head_dim, max_len, cfg.rope_theta)
-    n_rep = cfg.n_heads // cfg.n_kv_heads
-    kpos = jnp.arange(max_len)[None, :]
-    mask = kpos <= pos[:, None]
-
-    new_k, new_v = [], []
-    for lid in range(cfg.n_layers):
-        with jax.named_scope("layer_weights"):
-            lp = jax.tree.map(lambda a: a[lid], params["layers"])
-        x, ck, cv = _decode_layer(x, lp, cache["k"][lid], cache["v"][lid],
-                                  pos, cos, sin, mask, cfg)
-        new_k.append(ck)
-        new_v.append(cv)
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    with jax.named_scope("lm_head"):
-        logits = (x[:, 0] @ params["lm_head"]).astype(jnp.float32)
-    return logits, {"k": new_k, "v": new_v, "pos": pos + 1}
-
-
 def init_paged_kv_cache(cfg: LlamaConfig, batch: int, n_pages: int,
                         page: int) -> dict:
     """Shared page-pool KV cache (ops/paged_attention.py): per-layer
-    [n_pages, kvh, page, hd] leaves instead of dense per-slot windows.
+    [n_pages, kvh, page, hd] leaves, not a [max_len] window per slot.
     Page 0 is the TRASH page — inactive slots' table rows point at it,
     so their (ignored) decode writes land somewhere harmless.  HBM cost
     scales with the page budget, not max_len x slots — the long-context
@@ -697,7 +611,7 @@ def scatter_prefill_pages(cache: dict, ks, vs, page_ids: jnp.ndarray,
     (page id + in-page row per token position; positions past a slot's
     allocation point at the trash page).  Returns the updated cache.
     Duplicate wave-padding rows write identical data, so scatter order
-    is irrelevant (same rule as the dense _prefill_wave).
+    is irrelevant.
 
     Fast paths write PAGE-ALIGNED BLOCKS with a single [n] advanced
     index on the pool's page axis: the original [W, P] per-token
@@ -895,45 +809,6 @@ def decode_step_paged(params: dict, pages: dict, tails: dict,
     with jax.named_scope("lm_head"):
         logits = (x[:, 0] @ params["lm_head"]).astype(jnp.float32)
     return logits, {"k": new_tk, "v": new_tv}
-
-
-def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int) -> dict:
-    shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
-    return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype),
-            "pos": jnp.zeros((batch,), jnp.int32)}
-
-
-def decode_step(params: dict, cache: dict, tokens: jnp.ndarray,
-                cfg: LlamaConfig) -> tuple[jnp.ndarray, dict]:
-    """One decode step for continuous-batched serving.
-
-    tokens [b] int32 (current token per sequence); cache positions advance
-    per sequence.  Returns (logits [b, vocab], new cache).
-
-    TPU shape: layers ride a `lax.scan` (one compiled body), and the K/V
-    write is a per-sequence `dynamic_update_slice` (vmapped over the
-    batch) — it touches ONE cache row per sequence instead of a full
-    one-hot read-modify-write of the cache (which is what makes naive
-    decode HBM-bound: 2×cache traffic per layer per token).
-    """
-    max_len = cache["k"].shape[2]
-    pos = cache["pos"]                                  # [b]
-    x = embed_lookup(params["embed"], tokens[:, None], cfg.dtype)  # [b,1,d]
-    cos, sin = rope_frequencies(cfg.head_dim, max_len, cfg.rope_theta)
-    kpos = jnp.arange(max_len)[None, :]                 # [1, max]
-    mask = kpos <= pos[:, None]                         # [b, max]
-
-    def layer(x, inputs):
-        lp, ck, cv = inputs        # ck/cv [b, max_len, kvh, hd]
-        x, ck, cv = _decode_layer(x, lp, ck, cv, pos, cos, sin, mask, cfg)
-        return x, (ck, cv)
-
-    x, (nk, nv) = lax.scan(layer, x,
-                           (params["layers"], cache["k"], cache["v"]))
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    with jax.named_scope("lm_head"):
-        logits = (x[:, 0] @ params["lm_head"]).astype(jnp.float32)
-    return logits, {"k": nk, "v": nv, "pos": pos + 1}
 
 
 # ------------------------------------------------------ the serving seam

@@ -1,9 +1,10 @@
 """Continuous-batched LLM inference engine + Serve deployment.
 
-The judged serve configuration (BASELINE.json north star: "Ray Serve's
-replica scheduler runs continuous-batched LLM inference on TPU";
-reference analog: serve LLM workloads under ray: release/serve_tests/ and
-the vLLM-on-Serve pattern — rebuilt TPU-first rather than ported).
+`LLMEngine` decodes a fixed array of lanes over one paged KV pool, for
+whichever model `ray_tpu.models.serving_model` knows; `LLMServer` is the
+Serve replica around it (streaming, KV migration between pools, adapters,
+live weight sync).  The analog is vLLM on Ray Serve, rebuilt TPU-first
+rather than ported.
 
 TPU-native shape (SURVEY §7 "Serve continuous batching on TPU"):
   - ONE jitted decode program over a fixed [max_batch] slot array —
@@ -90,6 +91,15 @@ def _check_pool_role(role: str, decode_deployment) -> None:
             f"decode_deployment only applies to role='prefill' (got "
             f"role={role!r}) — a dangling decode target would "
             "silently serve unified")
+
+
+def _check_paged(paged: bool) -> None:
+    """`paged` is kept for the callers that still pass `paged=True`
+    (ROADMAP D13); the page pool is the only KV layout."""
+    if not paged:
+        raise ValueError(
+            "paged=False: the dense KV layout was removed; the engine "
+            "is always paged (drop the argument)")
 
 
 def _pow2(n: int) -> int:
@@ -308,6 +318,7 @@ class LLMEngine:
 
         from ray_tpu.models import serving_model
 
+        _check_paged(paged)
         model = self._model = serving_model(cfg)
         caps = model.SERVING_CAPS
         # Per-lane state beside the page pool (a convolution's last
@@ -317,10 +328,6 @@ class LLMEngine:
         self._lane_layers = int(model.lane_state_layers(cfg))
         self._moe_layers = int(model.routed_layers(cfg))
         stateful = self._lane_layers > 0
-        if not paged and "dense" not in caps:
-            raise ValueError(
-                f"{model.__name__} has no dense (paged=False) cache "
-                "layout")
         if lora_slots and "lora" not in caps:
             raise ValueError(
                 f"{model.__name__} has no LoRA hooks: lora_slots must "
@@ -347,38 +354,29 @@ class LLMEngine:
         self.steps_per_sync = max(1, steps_per_sync)
         self.params = params if params is not None else model.init_params(
             jax.random.PRNGKey(seed), cfg)
-        self.paged = paged
-        self._prefix_cache = paged and (
+        self._prefix_cache = (
             prefix_cache if prefix_cache is not None
             else _env_on("RAY_TPU_PREFIX_CACHE"))
-        self._preempt_on = paged and (
+        self._preempt_on = (
             kv_preempt if kv_preempt is not None
             else _env_on("RAY_TPU_KV_PREEMPT"))
-        if paged:
-            # Shared page pool (ops/paged_attention.py): HBM holds the
-            # page budget, NOT max_len x slots — max_len can be 32k+
-            # while the pool is sized to the expected live footprint.
-            # Page 0 is the trash page (idle slots point at it).
-            self.page = page_size
-            self._maxp = -(-self.max_len // page_size)
-            if kv_pages is None:
-                kv_pages = 1 + max_batch * (
-                    -(-min(self.max_len, 4096) // page_size))
-            self.n_pages = kv_pages
-            self.cache = model.init_paged_cache(cfg, max_batch,
-                                                kv_pages, page_size)
-            # Host-side accounting: refcounted blocks + radix prefix
-            # index over pool ids 1..n_pages-1 (serve/kv_blocks.py).
-            self._mgr = BlockManager(kv_pages - 1, page_size,
-                                     prefix_cache=self._prefix_cache)
-            self._table = np.zeros((max_batch, self._maxp), np.int32)
-        else:
-            # Dense per-layer cache leaves: the stacked [L, ...] cache
-            # rode a lax.scan as xs/ys, which XLA cannot alias — every
-            # decode step copied the whole cache.
-            self.cache = model.init_kv_cache_leaves(cfg, max_batch,
-                                                    self.max_len)
-            self._mgr = None
+        # Shared page pool (ops/paged_attention.py): HBM holds the
+        # page budget, NOT max_len x slots — max_len can be 32k+
+        # while the pool is sized to the expected live footprint.
+        # Page 0 is the trash page (idle slots point at it).
+        self.page = page_size
+        self._maxp = -(-self.max_len // page_size)
+        if kv_pages is None:
+            kv_pages = 1 + max_batch * (
+                -(-min(self.max_len, 4096) // page_size))
+        self.n_pages = kv_pages
+        self.cache = model.init_paged_cache(cfg, max_batch,
+                                            kv_pages, page_size)
+        # Host-side accounting: refcounted blocks + radix prefix
+        # index over pool ids 1..n_pages-1 (serve/kv_blocks.py).
+        self._mgr = BlockManager(kv_pages - 1, page_size,
+                                 prefix_cache=self._prefix_cache)
+        self._table = np.zeros((max_batch, self._maxp), np.int32)
         self._buckets = _buckets_for(self.max_len)
         # Prefill sub-wave cap: a full-width wave serializes the whole
         # burst's forward in front of EVERY first-token fetch (64x128
@@ -401,10 +399,6 @@ class LLMEngine:
         self.lora_slots = max(0, int(lora_slots))
         self.lora_rank = int(lora_rank) if self.lora_slots else 0
         if self.lora_slots:
-            if not paged:
-                raise ValueError(
-                    "lora_slots > 0 requires a paged engine (adapter "
-                    "KV identity is radix/page-granular)")
             if self.lora_rank < 1:
                 raise ValueError(
                     "lora_slots > 0 requires lora_rank >= 1 (bank "
@@ -464,25 +458,6 @@ class LLMEngine:
         # overload ladder's "shrink the sync window" knob needs a
         # factory: each window size compiles once and stays cached.
         def _make_decode(K):
-            def _decode_k_dense(params, cache, tokens, temps, table,
-                                seeds, starts, lora):
-                lane_keys = jax.vmap(
-                    lambda s: jax.random.fold_in(self._base_key,
-                                                 s))(seeds)
-
-                def step(carry, j):
-                    cache, toks = carry
-                    logits, cache = model.decode_step_unrolled(
-                        params, cache, toks, cfg)
-                    keys = jax.vmap(jax.random.fold_in)(lane_keys,
-                                                        starts + j)
-                    nxt = _sample_rows(logits, temps, keys)
-                    return (cache, nxt), nxt
-
-                (cache, last), seq = jax.lax.scan(
-                    step, (cache, tokens), jnp.arange(K))
-                return seq, last, cache   # seq [K, B]
-
             def _decode_k_paged(params, cache, tokens, temps, table,
                                 seeds, starts, lora):
                 """Pages stay OUT of the scan carry (read-only during
@@ -532,8 +507,7 @@ class LLMEngine:
                 return seq, last, {"k": new_k, "v": new_v, "pos": pos,
                                    "state": state}, counts
 
-            return jax.jit(_decode_k_paged if paged else _decode_k_dense,
-                           donate_argnums=(1,))
+            return jax.jit(_decode_k_paged, donate_argnums=(1,))
 
         self._make_decode = _make_decode
         self._decode_fns = {self.steps_per_sync:
@@ -545,46 +519,15 @@ class LLMEngine:
         self._k_live = self.steps_per_sync
         self.sync_window_shrinks = 0
 
-        # Wave prefill: ONE compiled program admits a whole wave of
-        # requests — computes all their prompt KV and scatter-writes each
-        # into its slot.  Per-request prefill calls would each round-trip
-        # the (donated) cache through the runtime; one call per wave pays
-        # that cost once.  Waves are padded by duplicating the last row
-        # (same slot written twice with identical data — harmless), so
-        # there is one compile per prompt-length bucket, not per wave
-        # size.
-        def _prefill_wave(params, cache, tokens, true_lens, slots, temps,
-                          seeds, starts):
-            W = tokens.shape[0]
-            hidden, ks, vs = model.prefill(params, tokens, cfg)
-
-            # Scatter each wave member's prompt KV into its slot with ONE
-            # batched indexed write per layer leaf (duplicate padded slots
-            # carry identical rows, so scatter order is irrelevant; leaves
-            # update in place under donation — see init_kv_cache_leaves).
-            P = tokens.shape[1]
-            k = [cache["k"][li].at[slots, :P].set(ks[li])
-                 for li in range(cfg.n_layers)]
-            v = [cache["v"][li].at[slots, :P].set(vs[li])
-                 for li in range(cfg.n_layers)]
-            pos = cache["pos"].at[slots].set(true_lens)
-            # Project only the W last-position rows through lm_head (the
-            # full [W, P, vocab] logits tensor would be GBs at serving
-            # shapes).  Duplicate padding rows carry the same
-            # (seed, start), so they draw the SAME sample — cur-token
-            # and recorded token can't diverge under temperature.
-            last_h = hidden[jnp.arange(W), true_lens - 1]    # [W, dim]
-            nxt = _first_token(params, last_h, temps, seeds, starts)
-            return nxt, {"k": k, "v": v, "pos": pos}
-
-        self._prefill = jax.jit(_prefill_wave, donate_argnums=(1,))
-
-        # Paged prefill is SPLIT into two programs: (A) forward +
-        # first-token sample, (B) the KV page scatter.  The first-token
-        # fetch depends only on A, so its device→host sync overlaps B's
-        # per-layer page writes AND later chunks' forwards instead of
-        # queueing behind them (round-5 serve-TTFT rework; unmeasured
-        # on today's chip).
+        # Wave prefill: ONE forward admits a whole wave of requests.
+        # Waves are padded by duplicating the last row (same slot written
+        # twice with identical data — harmless), so there is one compile
+        # per (width, prompt-length) bucket, not per wave size.  It is
+        # SPLIT into two programs: (A) forward + first-token sample,
+        # (B) the KV page scatter.  The first-token fetch depends only
+        # on A, so its device→host sync overlaps B's per-layer page
+        # writes AND later chunks' forwards instead of queueing behind
+        # them (round-5 serve-TTFT rework; unmeasured on today's chip).
         def _prefill_fwd_only(params, tokens, true_lens, slots, temps,
                               seeds, starts, lora):
             W = tokens.shape[0]
@@ -592,6 +535,11 @@ class LLMEngine:
             # padded to a length bucket) and the routed layers' counts
             hidden, ks, vs, state, counts = model.serve_prefill(
                 params, tokens, cfg, true_lens, lora)
+            # Project only the W last-position rows through lm_head (the
+            # full [W, P, vocab] logits tensor would be GBs at serving
+            # shapes).  Duplicate padding rows carry the same
+            # (seed, start), so they draw the SAME sample — cur-token
+            # and recorded token can't diverge under temperature.
             last_h = hidden[jnp.arange(W), true_lens - 1]
             nxt = _first_token(params, last_h, temps, seeds, starts)
             return nxt, ks, vs, state, counts
@@ -685,9 +633,9 @@ class LLMEngine:
         self._temps = np.zeros((max_batch,), np.float32)
         self._seeds = np.zeros((max_batch,), np.int32)
         # Device copy of the page table, refreshed only when admission or
-        # completion changed it (dense mode passes a constant dummy).
-        self._table_dev = jnp.zeros((1, 1), jnp.int32)
-        self._table_dirty = paged
+        # completion changed it.
+        self._table_dev = jnp.asarray(self._table)
+        self._table_dirty = False
         # Admission order: new submissions drain from the thread-safe
         # queue into this deque; preempted requests re-enter at the
         # FRONT (they keep their place — recompute, not starvation).
@@ -803,7 +751,7 @@ class LLMEngine:
         """Thread-safe; resolves to {tokens, ttft_s, total_s}.  With
         `token_queue`, every decoded token is ALSO pushed to the queue as
         produced (None = end) — the token-streaming hook.  With
-        `prefill_only` (paged engines), the result additionally carries
+        `prefill_only`, the result additionally carries
         `kv_export`: the request's KV pages as one host array plus the
         metadata kv_import() needs to resume decoding on ANOTHER engine
         (the prefill half of disaggregated serving).  With `model_id`,
@@ -811,10 +759,6 @@ class LLMEngine:
         must be resident — load_adapter — by ADMISSION time, or the
         future fails with AdapterLoadError) and its KV cache entries
         key on the adapter's salt."""
-        if prefill_only and not self.paged:
-            raise ValueError(
-                "prefill_only requires a paged engine (KV export is "
-                "page-granular)")
         if prefill_only:
             self._need_kv_transfer("prefill_only")
         if model_id is not None and self._lora_banks is None:
@@ -830,13 +774,12 @@ class LLMEngine:
                 f"prompt ({len(prompt)}) + max_new_tokens "
                 f"({max_new_tokens}) exceeds max_len {self.max_len}; "
                 "decode past the cache end would corrupt output")
-        if self.paged:
-            need = -(-(len(prompt) + max_new_tokens) // self.page)
-            if need > self.n_pages - 1:
-                raise ValueError(
-                    f"request needs {need} KV pages but the pool holds "
-                    f"{self.n_pages - 1}; raise kv_pages (admission "
-                    "would otherwise block forever)")
+        need = -(-(len(prompt) + max_new_tokens) // self.page)
+        if need > self.n_pages - 1:
+            raise ValueError(
+                f"request needs {need} KV pages but the pool holds "
+                f"{self.n_pages - 1}; raise kv_pages (admission "
+                "would otherwise block forever)")
         if self._error is not None:
             raise RuntimeError(
                 "LLM engine is dead after an earlier failure") \
@@ -891,8 +834,6 @@ class LLMEngine:
 
         if failpoints.ACTIVE:
             failpoints.fire("serve.kv_import")
-        if not self.paged:
-            raise ValueError("kv_import requires a paged engine")
         self._need_kv_transfer("kv_import")
         if not tokens:
             raise ValueError("kv_import needs at least the first "
@@ -969,7 +910,7 @@ class LLMEngine:
             min_idle=max(0, int(min_idle)),
             period_s=max(0.01, float(period_s)),
             watermark=int(max(0.0, float(watermark_frac))
-                          * (self.n_pages - 1)) if self.paged else 0,
+                          * (self.n_pages - 1)),
             limit=max(1, int(limit)),
             max_inflight=max(1, int(max_inflight)))
         with self._demote_lock:
@@ -992,8 +933,6 @@ class LLMEngine:
         version) — see serve/lora.adapter_salt; 0 = base model."""
         import numpy as np
 
-        if not self.paged:
-            raise ValueError("kv_graft requires a paged engine")
         self._need_kv_transfer("kv_graft")
         if kv_len <= 0 or kv_len % self.page != 0:
             raise ValueError(
@@ -1337,16 +1276,15 @@ class LLMEngine:
             self.params = new_params
             self.weight_version = version
         self.weight_updates += 1
-        if self._mgr is not None:
-            # Cached KV belongs to the OLD policy: flush the radix tree
-            # (refcount-0 pages free now; in-flight readers finish under
-            # the documented staleness) and gate pending commits behind
-            # a fresh generation.
-            self._cache_gen += 1
-            self._mgr.flush()
-            with self._demote_lock:
-                # Declined-leaf memory belongs to the flushed tree.
-                self._demote_skip.clear()
+        # Cached KV belongs to the OLD policy: flush the radix tree
+        # (refcount-0 pages free now; in-flight readers finish under
+        # the documented staleness) and gate pending commits behind
+        # a fresh generation.
+        self._cache_gen += 1
+        self._mgr.flush()
+        with self._demote_lock:
+            # Declined-leaf memory belongs to the flushed tree.
+            self._demote_skip.clear()
         self.last_weight_sync_ms = (time.perf_counter()
                                     - staged_t) * 1000.0
 
@@ -1408,7 +1346,7 @@ class LLMEngine:
                 "llm.engine", now, now, ctx=(tracing.new_id(), ""),
                 attrs={"engine": self.name, "max_batch": self.max_batch,
                        "steps_per_sync": self.steps_per_sync,
-                       "page_size": self.page if self.paged else 0})
+                       "page_size": self.page})
         return self._loop_trace
 
     @contextlib.contextmanager
@@ -1449,8 +1387,6 @@ class LLMEngine:
         memory harvest (tier "hbm" rows next to the arena tiers): used
         bytes = non-free pool blocks x bytes per page, from the same
         BlockManager accounting the radix cache runs on."""
-        if self._mgr is None:
-            return
         import jax
 
         try:
@@ -1562,7 +1498,7 @@ class LLMEngine:
             try:
                 if failpoints.ACTIVE:
                     failpoints.fire("serve.prefix_graft")
-                if self._mgr is None or not self._prefix_cache:
+                if not self._prefix_cache:
                     out = {"grafted": 0, "reason": "no_prefix_cache"}
                 elif wv is not None and wv != self.weight_version:
                     # Stored KV from another policy version: grafting
@@ -1617,8 +1553,7 @@ class LLMEngine:
         installs the callback, and gated per scan by the
         RAY_TPU_PREFIX_STORE kill switch."""
         cb = self._demote_cb
-        if (cb is None or self._mgr is None or not self._prefix_cache
-                or not self.paged):
+        if cb is None or not self._prefix_cache:
             return
         knobs = self._demote_knobs
         now = time.monotonic()
@@ -1811,20 +1746,19 @@ class LLMEngine:
                 # head-of-line barrier.
                 self._pending.popleft()
                 continue
-            if self.paged:
-                # The block pool is the admission control: the FRONT
-                # request blocks FIFO when free + evictable can't cover
-                # it (vLLM-style KV backpressure; nothing skips past).
-                if not self._reserve_blocks(req, copies):
-                    if req.lora_slot:
-                        # Undo the lane's slot mark — the request is
-                        # NOT decoding; its adapter stays evictable.
-                        with self._lora_lock:
-                            self._adapters[free] = 0
-                    break
-                self._table[free, :] = 0
-                self._table[free, :len(req.pages)] = req.pages
-                self._table_dirty = True
+            # The block pool is the admission control: the FRONT
+            # request blocks FIFO when free + evictable can't cover
+            # it (vLLM-style KV backpressure; nothing skips past).
+            if not self._reserve_blocks(req, copies):
+                if req.lora_slot:
+                    # Undo the lane's slot mark — the request is
+                    # NOT decoding; its adapter stays evictable.
+                    with self._lora_lock:
+                        self._adapters[free] = 0
+                break
+            self._table[free, :] = 0
+            self._table[free, :len(req.pages)] = req.pages
+            self._table_dirty = True
             self._pending.popleft()
             req.slot = free
             req.admitted_at = time.perf_counter()
@@ -1875,7 +1809,7 @@ class LLMEngine:
         """Prefill a whole wave of admitted requests through the cheapest
         (width, length) programs the engine has (serve/prefill_plan.py);
         one batched fetch materializes their first tokens."""
-        # Dispatch every program's forward (and, paged, its separate
+        # Dispatch every program's forward (and its separate
         # scatter program) back-to-back, shortest first, THEN fetch
         # first tokens — program 1's round trip overlaps program 2's
         # compute, so a big burst's p50 TTFT tracks one RTT plus HALF
@@ -1891,8 +1825,7 @@ class LLMEngine:
             for rows, w, b in plan:
                 chunk = [wave[i] for i in rows]
                 t_disp = time.time()
-                if self.paged and any(r.prefill_from > 0
-                                      for _, r in chunk):
+                if any(r.prefill_from > 0 for _, r in chunk):
                     nxt = self._prefill_chunk_suffix(chunk, w, b)
                 else:
                     nxt = self._prefill_chunk_full(chunk, w, b)
@@ -1993,27 +1926,21 @@ class LLMEngine:
             self.prefill_tokens += len(req.prompt) + len(req.tokens)
         slots_dev = jnp.asarray(slots)
         lens_dev = jnp.asarray(true_lens)
-        if self.paged:
-            cols = np.arange(bucket) // self.page
-            page_ids = self._table[slots][:, cols]  # [padded_w, bkt]
-            rows = np.tile(
-                np.arange(bucket, dtype=np.int32) % self.page,
-                (padded_w, 1))
-            nxt, ks, vs, state, counts = self._prefill_fwd(
-                self.params, jnp.asarray(tokens), lens_dev,
-                slots_dev, jnp.asarray(temps), jnp.asarray(seeds),
-                jnp.asarray(starts), self._lora_args(lidx))
-            self.cache = self._scatter_pages(
-                self.cache, ks, vs, state, jnp.asarray(page_ids),
-                jnp.asarray(rows), slots_dev, lens_dev)
-            if self._moe_layers:
-                # fetched with the wave's first tokens
-                self._prefill_counts.append(counts)
-        else:
-            nxt, self.cache = self._prefill(
-                self.params, self.cache, jnp.asarray(tokens),
-                lens_dev, slots_dev, jnp.asarray(temps),
-                jnp.asarray(seeds), jnp.asarray(starts))
+        cols = np.arange(bucket) // self.page
+        page_ids = self._table[slots][:, cols]  # [padded_w, bkt]
+        rows = np.tile(
+            np.arange(bucket, dtype=np.int32) % self.page,
+            (padded_w, 1))
+        nxt, ks, vs, state, counts = self._prefill_fwd(
+            self.params, jnp.asarray(tokens), lens_dev,
+            slots_dev, jnp.asarray(temps), jnp.asarray(seeds),
+            jnp.asarray(starts), self._lora_args(lidx))
+        self.cache = self._scatter_pages(
+            self.cache, ks, vs, state, jnp.asarray(page_ids),
+            jnp.asarray(rows), slots_dev, lens_dev)
+        if self._moe_layers:
+            # fetched with the wave's first tokens
+            self._prefill_counts.append(counts)
         # Duplicate padding rows target the same slot + same token.
         self._cur_dev = self._cur_dev.at[slots_dev].set(nxt)
         return nxt
@@ -2222,7 +2149,7 @@ class LLMEngine:
         but evictable; private ones free).  KV is valid only below
         prompt+tokens-1: the newest token's K/V hasn't been written,
         and rows past a lane's early finish hold trimmed overshoot."""
-        if not (self.paged and req.pages):
+        if not req.pages:
             return
         kv_valid = len(req.prompt) + len(req.tokens) - 1
         if req.cache_ok and req.cache_gen == self._cache_gen:
@@ -2246,7 +2173,7 @@ class LLMEngine:
         self._slots[slot] = None
         self._adapters[slot] = 0      # the lane's adapter is evictable
         self.completed += 1
-        if req.prefill_only and self.paged and req.pages \
+        if req.prefill_only and req.pages \
                 and not (req.eos_id is not None and req.tokens
                          and req.tokens[-1] == req.eos_id):
             # Export path: block release + table scrub happen here (the
@@ -2347,7 +2274,7 @@ class LLMEngine:
         if k_win is None:
             k_win = self._k_live
         active = [i for i, s in enumerate(self._slots) if s is not None]
-        if not self.paged or not active:
+        if not active:
             return active
         for slot in sorted(active,
                            key=lambda i: self._slots[i].sample_seed):
@@ -2422,8 +2349,7 @@ class LLMEngine:
             self._maybe_demote()
             self._flush_metrics()
             if active and self._table_dirty:
-                self._table_dev = jnp.asarray(self._table) if self.paged \
-                    else jnp.zeros((1, 1), jnp.int32)
+                self._table_dev = jnp.asarray(self._table)
                 self._table_dirty = False
             ph.update(blocks=self._funded_blocks - blocks0,
                       preempted=self.preemptions - pre0,
@@ -2550,10 +2476,9 @@ class LLMEngine:
                "lane_steps_live": self.lane_steps_live,
                "preemptions": self.preemptions,
                "completed": self.completed,
-               "weight_updates": self.weight_updates}
-        if self._mgr is not None:
-            cur["prefix_hit_tokens"] = self._mgr.hit_tokens
-            cur["evictions"] = self._mgr.evictions
+               "weight_updates": self.weight_updates,
+               "prefix_hit_tokens": self._mgr.hit_tokens,
+               "evictions": self._mgr.evictions}
         if self._moe_layers:
             cur.update({k: self.moe[k] for k in (
                 "moe_layer_steps", "moe_experts_hit", "moe_assignments")})
@@ -2570,19 +2495,16 @@ class LLMEngine:
         m["queue_depth"].set(
             self._waiting.qsize() + len(self._pending), tags)
         m["weight_version"].set(float(self.weight_version), tags)
-        if self._mgr is not None:
-            m["free_blocks"].set(self._mgr.free_count(), tags)
-            seen = self._mgr.hit_tokens + self.prefill_tokens
-            m["hit_rate"].set(
-                self._mgr.hit_tokens / seen if seen else 0.0, tags)
+        m["free_blocks"].set(self._mgr.free_count(), tags)
+        seen = self._mgr.hit_tokens + self.prefill_tokens
+        m["hit_rate"].set(
+            self._mgr.hit_tokens / seen if seen else 0.0, tags)
 
     def kv_check(self) -> dict:
         """Assert the block-state partition (test/ops probe): raises if
         any KV block is leaked or double-booked.  Shared by the serve
         replica's kv_check RPC and the RLHF rollout workers' post-chaos
         leak checks."""
-        if self._mgr is None:
-            return {"ok": True, "paged": False}
         self._mgr.check()
         return {"ok": True, "free": self._mgr.free_count(),
                 "available": self._mgr.available()}
@@ -2653,14 +2575,13 @@ class LLMEngine:
                               "age": round(now - m["last_used"], 3)}
                         for mid, m in self._lora_meta.items()},
                 }
-        if self._mgr is not None:
-            kv = self._mgr.stats()
-            out["kv"] = kv
-            out["prefix_hits"] = kv["hits"]
-            out["prefix_misses"] = kv["misses"]
-            out["prefix_hit_tokens"] = kv["hit_tokens"]
-            out["evictions"] = kv["evictions"]
-            out["cow_copies"] = kv["cow_copies"]
+        kv = self._mgr.stats()
+        out["kv"] = kv
+        out["prefix_hits"] = kv["hits"]
+        out["prefix_misses"] = kv["misses"]
+        out["prefix_hit_tokens"] = kv["hit_tokens"]
+        out["evictions"] = kv["evictions"]
+        out["cow_copies"] = kv["cow_copies"]
         self._flush_metrics(force=True)
         return out
 
@@ -2713,10 +2634,7 @@ class LLMServer:
         from ray_tpu.models import named_config, serving_model
 
         _check_pool_role(role, decode_deployment)
-        if role == "prefill" and not paged:
-            raise ValueError(
-                "role='prefill' requires a paged engine (KV migration "
-                "is page-granular)")
+        _check_paged(paged)
         cfg = named_config(model) if isinstance(model, str) else model
         served_by = serving_model(cfg)
         if role != "unified" and "kv_transfer" not in served_by.SERVING_CAPS:
@@ -2802,8 +2720,8 @@ class LLMServer:
     def _install_prefix_store(self) -> None:
         """(Re)attach the prefix-store client + demotion hook to the
         current engine (constructor and every reconfigure rebuild).
-        Disabled for dense engines, prefix_cache=0 engines, and
-        explicitly via prefix_store={"enabled": False}."""
+        Disabled for prefix_cache=0 engines and explicitly via
+        prefix_store={"enabled": False}."""
         from ray_tpu.serve import prefix_store as pstore
 
         if self._prefix_client is not None:
@@ -2811,9 +2729,7 @@ class LLMServer:
             self._prefix_client = None
         eng = self.engine
         cfg = self._prefix_store_cfg
-        if (not eng.paged or eng._mgr is None
-                or not eng._prefix_cache
-                or cfg.get("enabled", True) is False):
+        if not eng._prefix_cache or cfg.get("enabled", True) is False:
             eng.set_prefix_store(None)
             return
         rid = None
@@ -2957,7 +2873,7 @@ class LLMServer:
         eng = self.engine
         if (self._prefix_client is None or not isinstance(request, dict)
                 or not request.get("prefix_store", True)
-                or eng._mgr is None or not eng._prefix_cache):
+                or not eng._prefix_cache):
             return False
         prompt = request.get("prompt")
         if not isinstance(prompt, (list, tuple)) \
@@ -3032,7 +2948,6 @@ class LLMServer:
 
         return (self._role == "prefill"
                 and self._decode_dep is not None
-                and self.engine.paged
                 and kv_router.pd_disagg_on()
                 and request.get("disagg", True)
                 and request.get("max_new_tokens", 32) > 1)
@@ -3375,12 +3290,8 @@ class LLMServer:
         if "kv_blocks" in cfg:
             cfg["kv_pages"] = cfg.pop("kv_blocks")
         kwargs = {**self._engine_kwargs, **cfg}
-        if new_role == "prefill" and not kwargs.get("paged", True):
-            # Mirror the constructor's check: this combination must
-            # fail at (re)configuration, not silently serve unified.
-            raise ValueError(
-                "role='prefill' requires a paged engine (KV migration "
-                "is page-granular)")
+        _check_paged(kwargs["paged"])
+
         def commit_roles():
             self._role = new_role
             if new_dd is not self._decode_dep:

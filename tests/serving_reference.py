@@ -1,0 +1,76 @@
+"""What the serving tests compare with, and the driver that walks a
+model's serving seam (`ray_tpu.models.serving_model`) by hand: shared by
+the llama and LFM2 tests (imported rootdir-relative, like the other
+helpers in this directory)."""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+
+def reference_greedy(params, cfg, prompt, n_new):
+    """Greedy tokens of `llama.forward`, the plain full forward the train
+    step differentiates, on the whole context at every step — the
+    slow-but-sure decoder, independent of every serving program.  The
+    context sits in one buffer of its final length (one compile): the
+    forward is causal, so what lies past a position cannot reach it."""
+    from ray_tpu.models import llama
+
+    n = len(prompt)
+    toks = np.zeros((1, n + n_new), np.int32)
+    toks[0, :n] = prompt
+    fwd = jax.jit(lambda p, t: llama.forward(p, t, cfg))
+    for i in range(n, n + n_new):
+        toks[0, i] = int(jnp.argmax(fwd(params, jnp.asarray(toks))[0, i - 1]))
+    return toks[0, n:].tolist()
+
+
+def served_logits(model, params, cfg, prompt, follow, bucket, *,
+                  page=16, k=4):
+    """Logits of the served path at every position from the prompt's last
+    on: the prompt padded to `bucket` in a wave of two rows (the other a
+    longer prompt), scattered into a page pool and lane 1, then
+    teacher-forced paged decode in windows of `k` over `follow`, the
+    tails merged into the pages between windows as the engine does."""
+    from ray_tpu.ops.paged_attention import merge_tail_pages
+
+    n = len(prompt)
+    other = np.random.default_rng(99).integers(0, cfg.vocab_size, bucket)
+    toks = np.zeros((2, bucket), np.int32)
+    toks[0], toks[1, :n] = other, prompt
+    true_lens = jnp.asarray([bucket, n], jnp.int32)
+    h, ks, vs, state, _ = model.serve_prefill(params, jnp.asarray(toks),
+                                              cfg, true_lens)
+    out = [model.project_logits(params, h[1, n - 1])]
+    maxp = 4
+    cache = model.init_paged_cache(cfg, 2, 1 + 2 * maxp, page)
+    table = np.arange(1, 1 + 2 * maxp, dtype=np.int32).reshape(2, maxp)
+    cols = np.arange(bucket) // page
+    cache = model.serve_scatter(
+        cache, ks, vs, state, jnp.asarray(table[:, cols]),
+        jnp.tile(jnp.arange(bucket) % page, (2, 1)), jnp.arange(2),
+        true_lens)
+    table = jnp.asarray(table)
+    follow = list(follow)
+    # traced anew in every call: a control patches what it calls
+    step = jax.jit(lambda *a: model.serve_decode_step(*a, cfg))
+    for w0 in range(0, len(follow), k):
+        ts = cache["pos"]
+        pages = {"k": cache["k"], "v": cache["v"]}
+        kvh, hd = cfg.n_kv_heads, cfg.head_dim
+        tails = {kv: [jnp.zeros((2, kvh, k, hd), cfg.dtype)
+                      for _ in pages["k"]] for kv in "kv"}
+        st, pos = cache["state"], ts
+        for j, t in enumerate(follow[w0:w0 + k]):
+            lg, tails, st, _ = step(
+                params, pages, tails, st, jnp.asarray([1, t], jnp.int32),
+                pos, ts, j, table)
+            out.append(lg[1])
+            pos = pos + 1
+        cache = {"k": [merge_tail_pages(p, t, table, ts, k)
+                       for p, t in zip(pages["k"], tails["k"])],
+                 "v": [merge_tail_pages(p, t, table, ts, k)
+                       for p, t in zip(pages["v"], tails["v"])],
+                 "pos": ts + k, "state": st}
+    return jnp.stack(out)

@@ -26,15 +26,14 @@ def small():
     return cfg, llama.init_params(jax.random.PRNGKey(7), cfg)
 
 
-def _engine(small, paged=True, **kw):
+def _engine(small, **kw):
     from ray_tpu.serve.llm import LLMEngine
 
     kw.setdefault("max_batch", 4)
     kw.setdefault("max_len", 256)
-    if paged:
-        kw.setdefault("page_size", 16)
+    kw.setdefault("page_size", 16)
     kw.setdefault("steps_per_sync", 4)
-    return LLMEngine(small[0], small[1], seed=0, paged=paged, **kw)
+    return LLMEngine(small[0], small[1], seed=0, **kw)
 
 
 def _one_wave(eng, prompts, max_new_tokens, cache_ok=()):
@@ -234,17 +233,16 @@ def test_prefill_counters_by_hand(small):
         "true_tokens": 320, "padded_tokens": 768}
 
 
-@pytest.mark.parametrize("kind", ["paged", "dense", "cached_prefix"])
+@pytest.mark.parametrize("kind", ["paged", "cached_prefix"])
 def test_mixed_wave_gives_the_tokens_of_one_at_a_time(small, kind):
-    paged = kind != "dense"
     kw = {"prefix_cache": True} if kind == "cached_prefix" else {}
     # arrival order is not length order; three length buckets and more
     prompts = [_prompt(200, 1), _prompt(20, 2), _prompt(100, 3),
                _prompt(50, 4)]
     plan, cached = "8x64,1x128,1x256", ()
-    ref_eng = _engine(small, paged, max_batch=8)
+    ref_eng = _engine(small, max_batch=8)
     ref_eng.start()
-    eng = _engine(small, paged, max_batch=8, **kw)
+    eng = _engine(small, max_batch=8, **kw)
     eng.start()
     try:
         if kind == "cached_prefix":
@@ -323,25 +321,6 @@ def test_lane_steps_live_by_hand(small):
     assert set(s1["phase_s"]) == set(PHASES)
     assert all(s1["phase_s"][p] >= s0["phase_s"][p] for p in PHASES)
     assert s1["phase_s"]["decode_sync"] > s0["phase_s"]["decode_sync"]
-
-
-def test_dense_engine_has_a_timeline_too(small):
-    from ray_tpu import tracing
-    from ray_tpu.serve.llm import LLMEngine
-
-    eng = LLMEngine(small[0], small[1], max_batch=2, max_len=64,
-                    paged=False, steps_per_sync=4)
-    eng.start()
-    try:
-        eng.generate(_prompt(12), max_new_tokens=9)
-        loop = eng.stats()["loop"]
-    finally:
-        eng.stop()
-    assert loop["decode_steps"] == 8 and loop["lane_steps_live"] == 8
-    assert loop["prefill_padded_tokens"] == 32      # 1 row x bucket 32
-    root = next(r for r in tracing.snapshot() if r["name"] == "llm.engine"
-                and r["tid"] == eng._loop_trace[0])
-    assert root["attrs"]["page_size"] == 0
 
 
 def test_counters_advance_with_tracing_off(small):

@@ -16,6 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from serving_reference import served_logits  # rootdir-relative (no pkg)
+
 from benchmarks.harness.refs import lfm2_moe as ref
 from ray_tpu.models import lfm2, serving_model
 from ray_tpu.ops import grouped_matmul
@@ -48,57 +50,10 @@ def _tokens(n, seed=0):
     return np.random.default_rng(seed).integers(0, 256, n).astype(np.int32)
 
 
-def served_logits(params, prompt, follow, bucket, cfg=CFG):
-    """Logits of the served path at every position from the prompt's last
-    on: the prompt padded to `bucket` in a wave of two rows (the other a
-    longer prompt), scattered into a page pool and lane 1, then
-    teacher-forced paged decode in windows of K over `follow`."""
-    n = len(prompt)
-    other = _tokens(bucket, 99)
-    toks = np.zeros((2, bucket), np.int32)
-    toks[0], toks[1, :n] = other, prompt
-    true_lens = jnp.asarray([bucket, n], jnp.int32)
-    h, ks, vs, state, _ = lfm2.prefill(params, jnp.asarray(toks), cfg,
-                                       true_lens)
-    out = [lfm2.project_logits(params, h[1, n - 1])]
-    maxp = 4
-    cache = lfm2.init_paged_cache(cfg, 2, 1 + 2 * maxp, PAGE)
-    table = np.arange(1, 1 + 2 * maxp, dtype=np.int32).reshape(2, maxp)
-    cols = np.arange(bucket) // PAGE
-    cache = lfm2.scatter_prefill_pages(
-        cache, ks, vs, state, jnp.asarray(table[:, cols]),
-        jnp.tile(jnp.arange(bucket) % PAGE, (2, 1)), jnp.arange(2),
-        true_lens)
-    from ray_tpu.ops.paged_attention import merge_tail_pages
-
-    table = jnp.asarray(table)
-    follow = list(follow)
-    # traced anew in every call: a control patches what it calls
-    step = jax.jit(lambda *a: lfm2.decode_step_paged(*a, cfg))
-    for w0 in range(0, len(follow), K):
-        ts = cache["pos"]
-        pages = {"k": cache["k"], "v": cache["v"]}
-        kvh, hd = cfg.n_kv_heads, cfg.head_dim
-        tails = {kv: [jnp.zeros((2, kvh, K, hd), cfg.dtype)
-                      for _ in pages["k"]] for kv in "kv"}
-        st, pos = cache["state"], ts
-        for j, t in enumerate(follow[w0:w0 + K]):
-            lg, tails, st, _ = step(
-                params, pages, tails, st, jnp.asarray([1, t], jnp.int32),
-                pos, ts, j, table)
-            out.append(lg[1])
-            pos = pos + 1
-        cache = {"k": [merge_tail_pages(p, t, table, ts, K)
-                       for p, t in zip(pages["k"], tails["k"])],
-                 "v": [merge_tail_pages(p, t, table, ts, K)
-                       for p, t in zip(pages["v"], tails["v"])],
-                 "pos": ts + K, "state": st}
-    return jnp.stack(out)
-
-
 def _worst(params_served, params_ref, n=21, bucket=32, follow=2 * K):
     prompt, nxt = _tokens(n, 1), _tokens(follow, 2)
-    got = served_logits(params_served, prompt, nxt, bucket)
+    got = served_logits(lfm2, params_served, CFG, prompt, nxt, bucket,
+                        page=PAGE, k=K)
     want = ref.logits(params_ref, list(prompt) + list(nxt), MODEL,
                       last=follow + 1)
     return float(jnp.max(jnp.abs(got - want)))
@@ -310,8 +265,7 @@ def test_every_control_exceeds_the_tolerance(params, monkeypatch, control):
     elif control == "expert_bias_in_the_weights":
         monkeypatch.setattr(lfm2, "route", _route_bias_in_weights)
     elif control == "lane_state_zeroed_at_admission":
-        monkeypatch.setattr(lfm2, "scatter_prefill_pages",
-                            _scatter_zero_state)
+        monkeypatch.setattr(lfm2, "serve_scatter", _scatter_zero_state)
     worst = _worst(served, params)
     if control == "sound":
         assert worst < TOL
@@ -328,8 +282,6 @@ def test_a_model_with_lane_state_is_served_without_the_prefix_cache(params):
     with pytest.raises(ValueError, match="no LoRA hooks"):
         LLMEngine(CFG, params, max_batch=2, max_len=64, page_size=PAGE,
                   lora_slots=2, lora_rank=4)
-    with pytest.raises(ValueError, match="no dense"):
-        LLMEngine(CFG, params, max_batch=2, max_len=64, paged=False)
     eng = LLMEngine(CFG, params, max_batch=2, max_len=64, page_size=PAGE)
     assert eng.stats()["prefix_cache"] is False
     assert eng.stats()["lane_state"]["prefix_cache"] == "off: lane state"

@@ -1,6 +1,6 @@
 """Llama-3-8B train step traces abstractly over a v5e-64-shaped mesh.
 
-The north-star config (BASELINE.json: 8B pretrain on v5e-64) can't run on
+The north-star config (Llama-3-8B pretrain on a v5e-64) can't run on
 CI hardware; what CAN be verified is that the FULL-SIZE model's sharded
 step is well-formed: parameter shapes/shardings, the loss/grad/optimizer
 program, and the dp×fsdp×tp layout all trace without materializing a

@@ -10,6 +10,8 @@ import concurrent.futures
 import numpy as np
 import pytest
 
+from serving_reference import reference_greedy, served_logits
+
 
 @pytest.fixture(scope="module")
 def small():
@@ -25,43 +27,65 @@ def small():
     return cfg, params
 
 
-def _reference_greedy(params, cfg, prompt, n_new):
-    """Full-context forward per step — the slow-but-sure decoder."""
-    import jax.numpy as jnp
-
-    from ray_tpu.models import llama
-
-    toks = list(prompt)
-    for _ in range(n_new):
-        logits = llama.forward(params, jnp.asarray([toks]), cfg)
-        toks.append(int(jnp.argmax(logits[0, -1])))
-    return toks[len(prompt):]
-
-
-def test_decode_paths_agree(small):
-    """The scanned (compile-flat) and unrolled (in-place cache) decode
-    paths share one layer body and must produce identical logits and
-    cache states step for step."""
+@pytest.mark.parametrize("n_heads,n_kv_heads", [(4, 4), (8, 2)],
+                         ids=["rep1", "rep4"])
+def test_padded_prefill_then_paged_decode_equals_the_full_forward(
+        n_heads, n_kv_heads):
+    """The seam's three programs against `llama.forward` on the whole
+    sequence: a prompt of 21 at a bucket of 32 through serve_prefill and
+    serve_scatter, then two windows of K teacher-forced steps of
+    serve_decode_step with the tails merged between them, give the full
+    forward's logits at every position (float32: summation order)."""
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.models import llama
 
+    cfg = llama.LlamaConfig(
+        vocab_size=256, dim=16 * n_heads, n_layers=2, n_heads=n_heads,
+        n_kv_heads=n_kv_heads, ffn_dim=128, max_seq=128, remat=False,
+        dtype=jnp.float32)
+    params = llama.init_params(jax.random.PRNGKey(n_heads), cfg)
+    rng = np.random.default_rng(1)
+    prompt, follow = rng.integers(0, 256, 21), rng.integers(0, 256, 8)
+    got = served_logits(llama, params, cfg, prompt, follow, 32)
+    seq = jnp.asarray([list(prompt) + list(follow)])
+    want = llama.forward(params, seq, cfg)[0, len(prompt) - 1:]
+    assert got.shape == want.shape and float(jnp.abs(want).max()) > 0.1
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-4, rtol=0)
+
+
+@pytest.mark.parametrize("how", ["engine", "server", "reconfigure"])
+def test_paged_false_is_refused(small, how):
+    """The dense layout is gone; `paged` stays accepted for the callers
+    that pass True.  A refused reconfigure leaves the server as it was."""
+    from ray_tpu.serve.llm import LLMEngine, LLMServer
+
     cfg, params = small
-    b, S = 2, 32
-    c_scan = llama.init_kv_cache(cfg, b, S)
-    c_unr = llama.init_kv_cache_leaves(cfg, b, S)
-    toks = jnp.asarray([3, 7], jnp.int32)
-    for _ in range(4):
-        l1, c_scan = llama.decode_step(params, c_scan, toks, cfg)
-        l2, c_unr = llama.decode_step_unrolled(params, c_unr, toks, cfg)
-        np.testing.assert_allclose(np.asarray(l1), np.asarray(l2),
-                                   atol=1e-5, rtol=1e-5)
-        for li in range(cfg.n_layers):
-            np.testing.assert_allclose(np.asarray(c_scan["k"][li]),
-                                       np.asarray(c_unr["k"][li]),
-                                       atol=1e-5, rtol=1e-5)
-        toks = jnp.argmax(l1, axis=-1).astype(jnp.int32)
+    gone = "dense KV layout was removed"
+    if how == "engine":
+        with pytest.raises(ValueError, match=gone):
+            LLMEngine(cfg, params, max_batch=2, max_len=64, paged=False)
+        return
+    if how == "server":
+        with pytest.raises(ValueError, match=gone):
+            LLMServer(cfg, params=params, max_batch=2, max_len=64,
+                      paged=False)
+        return
+    server = LLMServer(cfg, params=params, max_batch=2, max_len=64,
+                       kv_pages=9, page_size=16, paged=True)
+    try:
+        eng, kwargs = server.engine, dict(server._engine_kwargs)
+        with pytest.raises(ValueError, match=gone):
+            server.reconfigure({"paged": False, "role": "decode",
+                                "kv_blocks": 17})
+        assert server.engine is eng and eng._thread.is_alive()
+        assert server._role == "unified"
+        assert server._engine_kwargs == kwargs and eng.n_pages == 9
+        assert len(eng.generate([3, 1, 4], max_new_tokens=4)["tokens"]) == 4
+    finally:
+        server.engine.stop()
 
 
 def test_engine_matches_full_forward_greedy(small):
@@ -72,7 +96,7 @@ def test_engine_matches_full_forward_greedy(small):
     try:
         for prompt in ([5, 9, 2], [17, 3, 44, 8, 11, 23, 6]):
             got = eng.generate(prompt, max_new_tokens=8)
-            assert got["tokens"] == _reference_greedy(
+            assert got["tokens"] == reference_greedy(
                 params, cfg, prompt, 8), prompt
             assert got["ttft_s"] > 0 and got["total_s"] >= got["ttft_s"]
     finally:
@@ -93,7 +117,7 @@ def test_continuous_batching_oversubscribed(small):
         results = [f.result(timeout=120) for f in futs]
         assert eng.completed == 5
         for p, r in zip(prompts, results):
-            assert r["tokens"] == _reference_greedy(params, cfg, p, 6), p
+            assert r["tokens"] == reference_greedy(params, cfg, p, 6), p
     finally:
         eng.stop()
 

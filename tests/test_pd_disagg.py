@@ -144,18 +144,6 @@ def test_kv_import_validation(small):
         eng.stop()
 
 
-def test_prefill_only_requires_paged(small):
-    from ray_tpu.serve.llm import LLMEngine
-
-    cfg, params = small
-    eng = LLMEngine(cfg, params, paged=False, max_batch=2, max_len=64)
-    try:
-        with pytest.raises(ValueError, match="paged"):
-            eng.submit([1, 2, 3], prefill_only=True)
-    finally:
-        eng.stop()
-
-
 def test_kv_export_failpoint_releases_blocks(small):
     """serve.kv_export=error: the export window faults AFTER prefill —
     the future fails (the server's cue to fall back to unified local
@@ -249,9 +237,6 @@ def test_llmserver_role_validation(small):
         LLMServer(cfg, params=params, role="shard")
     with pytest.raises(ValueError, match="decode pool"):
         LLMServer(cfg, params=params, role="prefill")
-    with pytest.raises(ValueError, match="paged"):
-        LLMServer(cfg, params=params, role="prefill",
-                  decode_deployment="d", paged=False)
     # A dangling decode target (role not prefill) would silently serve
     # unified forever — rejected at construction.
     with pytest.raises(ValueError, match="only applies"):
@@ -261,13 +246,9 @@ def test_llmserver_role_validation(small):
     srv = LLMServer(cfg, params=params, max_batch=2, max_len=64,
                     page_size=8)
     try:
-        with pytest.raises(ValueError, match="paged"):
-            srv.reconfigure({"role": "prefill",
-                             "decode_deployment": "d", "paged": False})
-        assert srv._role == "unified" and srv._decode_dep is None
         with pytest.raises(ValueError, match="decode pool"):
             srv.reconfigure({"role": "prefill"})
-        assert srv._role == "unified"
+        assert srv._role == "unified" and srv._decode_dep is None
     finally:
         srv.shutdown()
 
