@@ -132,11 +132,14 @@ def install(role: str) -> None:
             pass
 
 
-def collect(timeout_s: float = 3.0) -> str:
+def collect(timeout_s: float = 3.0, only=None) -> str:
     """Signal every REGISTERED runtime process and return the fresh
     dumps (driver side of `ray-tpu stack`).  Only pids with a stack file
     are signalled — a process that has not registered its handler yet
-    would be KILLED by SIGUSR1's default disposition."""
+    would be KILLED by SIGUSR1's default disposition.  `only` narrows
+    that to the registered processes among them: STACK_DIR is one per
+    machine, and a caller that shares the machine with other clusters
+    (a test beside other tests) must leave theirs alone."""
     t_signal = time.time()
     try:
         names = sorted(os.listdir(STACK_DIR))
@@ -147,6 +150,8 @@ def collect(timeout_s: float = 3.0) -> str:
         try:
             pid = int(name.split("_", 1)[0])
         except ValueError:
+            continue
+        if only is not None and pid not in only:
             continue
         # Send SIGUSR2 only to processes ADVERTISING a handler for it:
         # the default disposition is Term, and a leftover process from

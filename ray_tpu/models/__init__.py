@@ -21,8 +21,9 @@ from ray_tpu.models.vit import ViTConfig, vit_configs
 #   serve_scatter(cache, ks, vs, state, page_ids, rows, slots, true_lens,
 #     aligned=True) -> cache;
 #   serve_decode_step(params, pages, tails, state, tokens, pos,
-#     tail_start, j, page_table, cfg, lora) -> (logits, tails, state,
-#     counts);
+#     tail_start, j, page_table, cfg, lora, plan) -> (logits, tails,
+#     state, counts); `plan` is the window's
+#     ops.paged_attention.attention_plan, built once by the engine;
 #   project_logits(params, h); lane_state_layers(cfg) (0: the prefix
 #     cache may stay on); routed_layers(cfg): the rows of `counts`, int32
 #     [routed layers, 3] = experts that held a row, the largest load,

@@ -385,20 +385,22 @@ def scatter_prefill_pages(cache: dict, ks, vs, state, page_ids, rows,
 def decode_step_paged(params: dict, pages: dict, tails: dict, state: list,
                       tokens: jnp.ndarray, pos: jnp.ndarray,
                       tail_start: jnp.ndarray, j, page_table: jnp.ndarray,
-                      cfg: Lfm2MoeConfig, lora=None):
+                      cfg: Lfm2MoeConfig, lora=None, plan=None):
     """One decode step over the paged cache, the in-block tail (see
     llama.decode_step_paged: pages are read-only, new K/V rows land in
     the tails at column j) and the lanes' convolution state (carried:
     each convolution layer shifts its lane rows by one).  A lane whose
-    table row starts at the trash page holds no request and is routed
-    nowhere.  Returns (logits [B, vocab] float32, tails, state, counts
-    int32 [routed layers, 3])."""
-    from ray_tpu.ops.paged_attention import paged_decode_attention
+    table row starts at the trash page holds no request: it is routed
+    nowhere and attends nothing (`plan`: the block's `attention_plan`,
+    as in llama.decode_step_paged).  Returns (logits [B, vocab]
+    float32, tails, state, counts int32 [routed layers, 3])."""
+    from ray_tpu.ops.paged_attention import (lanes_live,
+                                             paged_decode_attention)
 
     B = tokens.shape[0]
     hd = cfg.head_dim
     n_rep = cfg.n_heads // cfg.n_kv_heads
-    live = page_table[:, 0] > 0
+    live = lanes_live(page_table)
     x = embed_lookup(params["embed"], tokens[:, None], cfg.dtype)[:, 0]
     max_len = page_table.shape[1] * pages["k"][0].shape[2]
     cos, sin = rope_frequencies(hd, max_len, cfg.rope_theta)
@@ -425,7 +427,7 @@ def decode_step_paged(params: dict, pages: dict, tails: dict, state: list,
             with jax.named_scope("attn"):
                 o = paged_decode_attention(
                     qg.astype(cfg.dtype), pages["k"][ai], pages["v"][ai],
-                    tk, tv, page_table, pos, tail_start)
+                    tk, tv, page_table, pos, tail_start, plan=plan)
             new_tk.append(tk)
             new_tv.append(tv)
             with jax.named_scope("attn_out"):

@@ -750,8 +750,8 @@ def prefill_with_prefix(params: dict, tokens: jnp.ndarray,
 def decode_step_paged(params: dict, pages: dict, tails: dict,
                       tokens: jnp.ndarray, pos: jnp.ndarray,
                       tail_start: jnp.ndarray, j, page_table: jnp.ndarray,
-                      cfg: LlamaConfig,
-                      lora: dict | None = None) -> tuple[jnp.ndarray, dict]:
+                      cfg: LlamaConfig, lora: dict | None = None,
+                      plan: dict | None = None) -> tuple[jnp.ndarray, dict]:
     """One decode step over the paged cache + in-block tail.
 
     pages {"k"/"v": [L x [n_pages, kvh, page, hd]]} are READ-ONLY here
@@ -761,7 +761,10 @@ def decode_step_paged(params: dict, pages: dict, tails: dict,
     {"k"/"v": [L x [B, kvh, kt, hd]]} at the shared in-block column
     `j` (a scalar: every slot's pos advances in lockstep, so
     pos - tail_start is uniform).  After the block, the engine merges
-    tails into pages with ops.paged_attention.merge_tail_pages.
+    tails into pages with ops.paged_attention.merge_tail_pages.  `plan`
+    is the block's `attention_plan`, the same for every layer and step
+    (the engine builds it once a block; each kernel call builds its own
+    if none is given).
 
     q, k, v come from `_decode_qkv`: the products stay flat behind a
     barrier, or the compiler re-lays-out wq/wk/wv every step (PR 29)."""
@@ -797,7 +800,7 @@ def decode_step_paged(params: dict, pages: dict, tails: dict,
         with jax.named_scope("attn"):
             o = paged_decode_attention(
                 qg.astype(cfg.dtype), pages["k"][lid], pages["v"][lid],
-                tk, tv, page_table, pos, tail_start)
+                tk, tv, page_table, pos, tail_start, plan=plan)
         new_tk.append(tk)
         new_tv.append(tv)
         with jax.named_scope("attn_out"):
@@ -850,7 +853,8 @@ def serve_scatter(cache, ks, vs, state, page_ids, rows, slots, true_lens,
 
 
 def serve_decode_step(params, pages, tails, state, tokens, pos, tail_start,
-                      j, page_table, cfg, lora=None):
+                      j, page_table, cfg, lora=None, plan=None):
     logits, tails = decode_step_paged(params, pages, tails, tokens, pos,
-                                      tail_start, j, page_table, cfg, lora)
+                                      tail_start, j, page_table, cfg, lora,
+                                      plan)
     return logits, tails, state, _no_counts()

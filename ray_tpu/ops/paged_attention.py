@@ -14,9 +14,8 @@ buffer (measured: the row write alone cost more than the attention —
 organised to never write the pools inside the scan:
 
   - PAGES are loop-invariant during a K-step decode block: the kernel
-    only READS them (BlockSpec index_map follows the page table;
-    Pallas pipelines page loads across grid steps and elides copies
-    when the clamped block index repeats).
+    only READS them, one whole page a grid step, by a DMA that Pallas
+    issues while the step before computes.
   - New K/V rows accumulate in a small dense TAIL [B, kvh, K, hd]
     (one dynamic_update_slice per step at the shared in-block column —
     every slot's pos advances in lockstep, so the column index is a
@@ -25,6 +24,18 @@ organised to never write the pools inside the scan:
     (their live values are in the tail).
   - After the block, ONE scatter merges the tail into the pages —
     whole-pool traffic once per K steps instead of per step.
+
+The kernel's grid is a WORK LIST, not lanes x table columns
+(`attention_plan`): one step for each (lane, page) pair that holds
+rows below the block-start snapshot, lanes in order and each lane's
+pages ascending, the lane's tail attended in the step of its last
+page.  A lane whose table row starts at the trash page holds no
+request: it gets no step, no DMA and no compute, and its output rows
+are 0.  The table and the snapshot do not change inside a block, so
+the engine builds the list once a block and every layer's call of
+every step reads it; the grid's bound is the list's length, a value
+the device holds.  What the kernel still wastes: it copies WHOLE pages
+(512 rows) however few rows of the last one are below the snapshot.
 
 No reference analog (ray delegates attention entirely to user
 libraries); the serving role matches what vLLM's paged_attention CUDA
@@ -44,21 +55,57 @@ from ray_tpu.ops.flash_attention import _interpret
 NEG_INF = -1e30
 
 
-def _kernel(table_ref, pos_ref, ts_ref,       # scalar prefetch
+def lanes_live(page_table):
+    """[B] bool: the lanes that hold a request.  The engine zeroes a
+    finished lane's table row, so a row that STARTS at the trash page
+    (page 0) is an idle lane, whatever its position has run to."""
+    return page_table[:, 0] != 0
+
+
+def attention_plan(page_table, tail_start, page: int) -> dict:
+    """The steps `paged_decode_attention` walks for one decode block.
+
+    page_table [B, maxp] int32 (`lanes_live` says which rows hold a
+    request), tail_start [B] int32 (the block-start snapshot; an idle
+    lane's may have run away), page = rows a page.
+
+    A live lane takes max(pages, 1) steps, pages = its pages that hold
+    rows < tail_start: one a page, ascending, the tail attended in the
+    last (a lane with no row below the snapshot takes one step for its
+    tail alone).  Returns int32 arrays of the static length B * maxp —
+    `lane` [N], `col` [N] (the table column; 0 opens a lane), `page` [N]
+    (the pool page to copy) — and `count`, the scalar number of steps
+    that are work.  Entries from `count` on repeat the last one: valid
+    indices that no step visits."""
+    B, maxp = page_table.shape
+    pages = -(-jnp.minimum(tail_start, maxp * page) // page)
+    n = jnp.where(lanes_live(page_table), jnp.maximum(pages, 1), 0)
+    end = jnp.cumsum(n)
+    count = end[-1]
+    i = jnp.minimum(jnp.arange(B * maxp), jnp.maximum(count - 1, 0))
+    # the first lane whose steps end after i (B - 1, col 0, if none is live)
+    lane = jnp.minimum(jnp.sum(end[None, :] <= i[:, None], axis=1), B - 1)
+    col = i - (end - n)[lane]
+    return {"lane": lane, "col": col, "page": page_table[lane, col],
+            "count": count}
+
+
+def _kernel(lane_ref, col_ref, page_ref, pos_ref, ts_ref,   # scalar prefetch
             q_ref, kp_ref, vp_ref, kt_ref, vt_ref,   # blocked inputs
             o_ref,                            # output
             acc_ref, m_ref, l_ref,            # scratch
-            *, page: int, kvh: int, rep: int, hd: int, kt: int,
+            *, page: int, maxp: int, kvh: int, rep: int, hd: int, kt: int,
             sm_scale: float):
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-    maxp = pl.num_programs(1) - 1             # last iteration = tail
+    del page_ref                              # the index maps read it
+    i = pl.program_id(0)
+    b = lane_ref[i]
+    col = col_ref[i]
     pos = pos_ref[b]
     ts = jnp.minimum(ts_ref[b], maxp * page)  # block-start snapshot
     # Pages hold rows < ts; the tail holds rows ts..pos.
     npages = (ts + page - 1) // page
 
-    @pl.when(i == 0)
+    @pl.when(col == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -80,10 +127,10 @@ def _kernel(table_ref, pos_ref, ts_ref,       # scalar prefetch
         acc_ref[...] = acc_ref[...] * alpha[..., None] + pv
         m_ref[:, :, 0] = m_cur
 
-    @pl.when(i < npages)
+    @pl.when(col < npages)
     def _pages():
         q = q_ref[0].astype(jnp.float32)     # [kvh, rep, hd]
-        kpos = i * page + jax.lax.broadcasted_iota(
+        kpos = col * page + jax.lax.broadcasted_iota(
             jnp.int32, (1, rep, page), 2)
         admit = kpos < ts                    # tail owns rows >= ts
         k = kp_ref[0].astype(jnp.float32)    # [kvh, page, hd]
@@ -92,7 +139,7 @@ def _kernel(table_ref, pos_ref, ts_ref,       # scalar prefetch
             preferred_element_type=jnp.float32) * sm_scale
         flash_update(jnp.where(admit, s, NEG_INF), vp_ref[0])
 
-    @pl.when(i == maxp)
+    @pl.when(col >= npages - 1)              # the lane's last step
     def _tail():
         q = q_ref[0].astype(jnp.float32)
         jpos = ts + jax.lax.broadcasted_iota(jnp.int32, (1, rep, kt), 2)
@@ -109,6 +156,7 @@ def _kernel(table_ref, pos_ref, ts_ref,       # scalar prefetch
 
 def paged_decode_attention(q, k_pages, v_pages, k_tail, v_tail,
                            page_table, pos, tail_start, *,
+                           plan: dict | None = None,
                            sm_scale: float | None = None):
     """Paged + tail decode attention (READ-only on every input).
 
@@ -118,11 +166,16 @@ def paged_decode_attention(q, k_pages, v_pages, k_tail, v_tail,
     k_tail/v_tail:   [B, kvh, kt, hd]  current block's accumulated rows
                 (row j = absolute position tail_start + j; the CURRENT
                 token's K/V must already be written at pos - tail_start)
-    page_table: [B, maxp] int32     page ids per slot (page 0 = trash)
+    page_table: [B, maxp] int32     page ids per slot (page 0 = trash;
+                a row that starts there is an idle lane)
     pos:        [B] int32           current attend position
     tail_start: [B] int32           pos snapshot at block start
+    plan:       `attention_plan(page_table, tail_start, page)`, which a
+                caller with many calls on one table and snapshot (a
+                block's layers x steps) builds once; built here if not
+                given
 
-    Returns o [B, kvh, rep, hd].
+    Returns o [B, kvh, rep, hd]; an idle lane's rows are 0.
     """
     B, kvh, rep, hd = q.shape
     page = k_pages.shape[2]
@@ -130,47 +183,51 @@ def paged_decode_attention(q, k_pages, v_pages, k_tail, v_tail,
     maxp = page_table.shape[1]
     if sm_scale is None:
         sm_scale = hd ** -0.5
+    if plan is None:
+        plan = attention_plan(page_table, tail_start, page)
 
-    def page_map(b, i, table, pos_a, ts_a):
-        # Out-of-range iterations clamp to the slot's LAST page: the
-        # block index is unchanged, so Pallas skips the copy and the
-        # masked compute is free.  (Also keeps a runaway idle slot's
-        # ts from indexing past the table.)
-        ts = jnp.minimum(ts_a[b], maxp * page)
-        last = jnp.maximum((ts + page - 1) // page - 1, 0)
-        return (table[b, jnp.minimum(i, last)], 0, 0, 0)
+    # Consecutive steps name different pages (a lane's next page, then
+    # the next live lane's first), so the pipeline copies step i + 1's
+    # page while step i computes.
+    def page_map(i, lane, col, pages, *_):
+        return (pages[i], 0, 0, 0)
 
-    def tail_map(b, i, *_):
-        return (b, 0, 0, 0)
+    def lane_map(i, lane, *_):
+        return (lane[i], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, maxp + 1),
+        num_scalar_prefetch=5,
+        grid=(plan["count"],),                # the device's own number
         in_specs=[
-            pl.BlockSpec((1, kvh, rep, hd), tail_map),
+            pl.BlockSpec((1, kvh, rep, hd), lane_map),
             pl.BlockSpec((1, kvh, page, hd), page_map),
             pl.BlockSpec((1, kvh, page, hd), page_map),
-            pl.BlockSpec((1, kvh, kt, hd), tail_map),
-            pl.BlockSpec((1, kvh, kt, hd), tail_map),
+            pl.BlockSpec((1, kvh, kt, hd), lane_map),
+            pl.BlockSpec((1, kvh, kt, hd), lane_map),
         ],
-        out_specs=pl.BlockSpec((1, kvh, rep, hd), tail_map),
+        out_specs=pl.BlockSpec((1, kvh, rep, hd), lane_map),
         scratch_shapes=[
             pltpu.VMEM((kvh, rep, hd), jnp.float32),
             pltpu.VMEM((kvh, rep, 128), jnp.float32),
             pltpu.VMEM((kvh, rep, 128), jnp.float32),
         ],
     )
-    kernel = functools.partial(_kernel, page=page, kvh=kvh, rep=rep,
-                               hd=hd, kt=kt, sm_scale=sm_scale)
-    return pl.pallas_call(
+    kernel = functools.partial(_kernel, page=page, maxp=maxp, kvh=kvh,
+                               rep=rep, hd=hd, kt=kt, sm_scale=sm_scale)
+    o = pl.pallas_call(
         kernel,
         name="paged_attn",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, kvh, rep, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
-    )(page_table, pos, tail_start, q, k_pages, v_pages, k_tail, v_tail)
+    )(plan["lane"], plan["col"], plan["page"], pos, tail_start,
+      q, k_pages, v_pages, k_tail, v_tail)
+    # No step wrote an idle lane's rows: they hold whatever the buffer
+    # did (NaN in interpret mode).
+    live = lanes_live(page_table)
+    return jnp.where(live[:, None, None, None], o, jnp.zeros_like(o))
 
 
 def merge_tail_pages(pages, tail, page_table, tail_start, n_rows):
@@ -226,7 +283,8 @@ def gather_pages(pages, page_table):
 def paged_decode_reference(q, k_pages, v_pages, k_tail, v_tail,
                            page_table, pos, tail_start, *,
                            sm_scale: float | None = None):
-    """Pure-jax oracle: materializes gathered KV (test-scale only)."""
+    """Pure-jax oracle: materializes gathered KV (test-scale only).  An
+    idle lane (table row starting at the trash page) reads 0."""
     B, kvh, rep, hd = q.shape
     page = k_pages.shape[2]
     kt = k_tail.shape[2]
@@ -250,4 +308,5 @@ def paged_decode_reference(q, k_pages, v_pages, k_tail, v_tail,
     p = jax.nn.softmax(s, axis=-1)
     vals = jnp.concatenate([vs, v_tail.astype(jnp.float32)], axis=2)
     o = jnp.einsum("bhrk,bhkd->bhrd", p, vals.astype(jnp.float32))
-    return o.astype(q.dtype)
+    live = lanes_live(page_table)
+    return jnp.where(live[:, None, None, None], o, 0.0).astype(q.dtype)

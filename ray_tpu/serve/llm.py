@@ -163,6 +163,17 @@ def _engine_metrics():
                 "decode_steps": um.get_or_create(
                     um.Counter, "serve_llm_decode_steps",
                     "Decode steps dispatched (K per sync window)", tk),
+                # attn_steps / attn_steps_dense = the share of a grid
+                # over every lane and table column that held work
+                "attn_steps": um.get_or_create(
+                    um.Counter, "serve_llm_attn_steps",
+                    "Grid steps of a paged_attn call, summed over sync "
+                    "windows: a step a page of a lane holding a "
+                    "request", tk),
+                "attn_steps_dense": um.get_or_create(
+                    um.Counter, "serve_llm_attn_steps_dense",
+                    "Lanes x (table columns + 1), summed over sync "
+                    "windows", tk),
                 # a routed model's decode: experts hit a layer-step =
                 # moe_experts_hit / moe_layer_steps
                 "moe_layer_steps": um.get_or_create(
@@ -469,11 +480,17 @@ class LLMEngine:
                 nothing) rides the carry, and the routed layers' counts
                 ([routed layers, 3]: experts hit, largest load,
                 assignments; summed over the K steps) come back beside
-                `seq`, fetched in the same sync."""
-                from ray_tpu.ops.paged_attention import merge_tail_pages
+                `seq`, fetched in the same sync.  The table and the
+                block-start positions stand still for the K steps, so
+                the attention kernel's work list is built here, once,
+                for every layer's call of every step."""
+                from ray_tpu.ops.paged_attention import (attention_plan,
+                                                         merge_tail_pages)
 
                 ts = cache["pos"]
                 pages = {"k": cache["k"], "v": cache["v"]}
+                with jax.named_scope("attn_plan"):
+                    plan = attention_plan(table, ts, self.page)
                 n_kv = len(pages["k"])
                 tshape = (max_batch, cfg.n_kv_heads, K, cfg.head_dim)
                 tails = {"k": [jnp.zeros(tshape, cfg.dtype)
@@ -488,7 +505,7 @@ class LLMEngine:
                     tails, state, pos, toks, counts = carry
                     logits, tails, state, cnt = model.serve_decode_step(
                         params, pages, tails, state, toks, pos, ts, j,
-                        table, cfg, lora)
+                        table, cfg, lora, plan)
                     keys = jax.vmap(jax.random.fold_in)(lane_keys,
                                                         starts + j)
                     nxt = _sample_rows(logits, temps, keys)
@@ -676,6 +693,11 @@ class LLMEngine:
         self._iter = 0                 # loop iterations: the spans' `iter`
         self.decode_steps = 0          # sum of K over the windows
         self.lane_steps_live = 0       # sum of live lanes x K
+        # The attention kernel's grid a window (every layer's call of
+        # every step walks the same): steps that were work, and what a
+        # grid of every lane x (every table column + the tail) was.
+        self.attn_steps = 0
+        self.attn_steps_dense = 0
         self.phase_s = dict.fromkeys(_LOOP_PHASES, 0.0)
         self.prefill_padded_tokens = 0  # width bucket x length bucket
         self.prefill_programs = 0      # (width, length) programs dispatched
@@ -2358,10 +2380,19 @@ class LLMEngine:
             self._idle_wait()
             return
         with self._phase("decode_dispatch", iter=it, lanes=len(active),
-                         steps=k_win):
+                         steps=k_win) as ph:
             starts = np.zeros((self.max_batch,), np.int32)
+            attn_steps = 0
             for i in active:
-                starts[i] = len(self._slots[i].tokens)
+                req = self._slots[i]
+                starts[i] = len(req.tokens)
+                # the lane's share of the attention kernel's grid
+                # (ops/paged_attention.attention_plan): a step a page
+                # holding rows below its block-start position
+                rows = min(len(req.prompt) + len(req.tokens) - 1,
+                           self._maxp * self.page)
+                attn_steps += max(-(-rows // self.page), 1)
+            ph.update(attn_steps=attn_steps)
             win_traced = tracing.ENABLED and any(
                 self._slots[i] is not None
                 and self._slots[i].trace is not None for i in active)
@@ -2381,6 +2412,8 @@ class LLMEngine:
                 out[3].copy_to_host_async()
             self.decode_steps += k_win
             self.lane_steps_live += len(active) * k_win
+            self.attn_steps += attn_steps
+            self.attn_steps_dense += self.max_batch * (self._maxp + 1)
         with self._phase("decode_sync", iter=it):
             seq = np.asarray(seq)               # the ONE sync per block
             # the routed layers' counts: a few hundred bytes of the same
@@ -2474,6 +2507,8 @@ class LLMEngine:
                "decode_tokens": self.decode_tokens,
                "decode_steps": self.decode_steps,
                "lane_steps_live": self.lane_steps_live,
+               "attn_steps": self.attn_steps,
+               "attn_steps_dense": self.attn_steps_dense,
                "preemptions": self.preemptions,
                "completed": self.completed,
                "weight_updates": self.weight_updates,
@@ -2538,10 +2573,14 @@ class LLMEngine:
                "sync_window_shrinks": self.sync_window_shrinks,
                # The engine thread's timeline, cumulative: pad factor =
                # prefill_padded_tokens / prefill_true_tokens, live lanes
-               # per decode step = lane_steps_live / decode_steps.
+               # per decode step = lane_steps_live / decode_steps, the
+               # share of a lanes x columns attention grid that is work
+               # = attn_steps / attn_steps_dense.
                "loop": {
                    "decode_steps": self.decode_steps,
                    "lane_steps_live": self.lane_steps_live,
+                   "attn_steps": self.attn_steps,
+                   "attn_steps_dense": self.attn_steps_dense,
                    "phase_s": dict(self.phase_s),
                    "prefill_true_tokens": self.prefill_tokens,
                    "prefill_padded_tokens": self.prefill_padded_tokens,

@@ -69,9 +69,14 @@ def _compile(fn, *args):
     (8, 8, 4, 128, 512, 8, 4),        # llama3-8b widths, chip_smoke's
     (8, 8, 4, 128, 512, 63, 4),       # unaligned tail length
     (8, 8, 4, 64, 512, 8, 4),         # head_dim 64
+    (32, 8, 4, 128, 512, 8, 4),       # Mistral-7B as the benchmark serves it
+    (8, 8, 6, 128, 512, 8, 16),       # Codestral-22B: a group of 6, 16 columns
+    (64, 8, 4, 64, 512, 8, 4),        # LFM2-24B-A2B: 64 lanes, head_dim 64
 ])
 def test_paged_decode_attention_compiles(one_chip, compiled_kernels,
                                          B, kvh, rep, hd, page, kt, maxp):
+    """The grid's bound is the plan's count, a value on the device: the
+    chip's compiler takes a traced grid dimension."""
     from ray_tpu.ops.paged_attention import paged_decode_attention
 
     def s(shape, dtype=jnp.bfloat16):
@@ -271,14 +276,36 @@ def _holds_matmul(comps, name, seen) -> bool:
                for ln in comps[name])
 
 
+def _while_bodies(comps: dict) -> list:
+    return [b for lines in comps.values() for ln in lines
+            for b in re.findall(r"\bwhile\(.*body=%([\w.\-]+)", ln)]
+
+
+def _loop_lines(hlo: str) -> list:
+    """Every instruction line of the `while` bodies and of whatever they
+    call, fusions included."""
+    comps = _computations(hlo)
+    todo = _while_bodies(comps)
+    seen, lines = set(), []
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        lines += comps[name]
+        todo += [c for ln in comps[name] for c in re.findall(
+            r"(?:calls|to_apply|body|condition|true_computation"
+            r"|false_computation)=%([\w.\-]+)", ln)]
+    return lines
+
+
 def weight_sized_writes(hlo: str, min_elems: int) -> list:
     """(instruction, op, scope) of every instruction in a `while` body
     (and what it calls) whose output has at least `min_elems` elements
     and is not a matmul fusion, a view, or an asynchronous prefetch that
     keeps the layout (a DMA of the stored bytes: the one read)."""
     comps = _computations(hlo)
-    todo = [b for lines in comps.values() for ln in lines
-            for b in re.findall(r"\bwhile\(.*body=%([\w.\-]+)", ln)]
+    todo = _while_bodies(comps)
     seen, found = set(), []
     while todo:
         body = todo.pop()
@@ -398,6 +425,37 @@ def test_decode_step_loop_writes_nothing_the_size_of_a_weight(
     # the block's rows into the pools in place
     pool = engine["kv_pages"] * cfg.n_kv_heads * 512 * cfg.head_dim
     assert _pool_copies(hlo, pool) == []
+
+
+def test_decode_step_loop_reads_the_attention_plan_and_builds_none(
+        topo, one_chip, compiled_kernels, monkeypatch):
+    """Mistral-7B-d16 as the benchmark serves it: the window's work list
+    (`attention_plan`, under the scope `attn_plan`) is built before the
+    K-step loop and nowhere in it, and the loop's body still holds one
+    `paged_attn` kernel a layer, each bounded by the plan's count (its
+    first operand, a scalar)."""
+    import chip_smoke
+    from ray_tpu.models import llama
+
+    monkeypatch.setattr(jax, "devices", lambda *a: list(topo.devices))
+    cfg = llama.LlamaConfig(vocab_size=32768, n_layers=16, max_seq=2048,
+                            dim=4096, n_heads=32, n_kv_heads=8,
+                            ffn_dim=14336)
+    eng_kw = dict(max_batch=32, max_len=2048, kv_pages=129, paged=True,
+                  page_size=512, steps_per_sync=8)
+    hlo = chip_smoke.engine_lowerings(
+        cfg, eng_kw, [], sharding=one_chip)["decode_k8"].compile().as_text()
+    assert hlo.count("attn_plan") > 0
+    loop = _loop_lines(hlo)
+    assert [ln for ln in loop if "attn_plan" in ln] == []
+    calls = [ln for ln in loop if "custom-call(" in ln and "paged_attn" in ln]
+    assert len(calls) == cfg.n_layers
+    operands = {re.search(r"custom-call\(%([\w.\-]+)", ln).group(1)
+                for ln in calls}
+    count, = operands                   # one count for all sixteen
+    bound = next(ln for ln in loop
+                 if re.match(rf"\s*%{re.escape(count)} = ", ln))
+    assert re.match(r"\s*%[\w.\-]+ = s32\[\]", bound), bound
 
 
 def test_sharded_train_step_lowers_for_four_chips(topo, compiled_kernels,

@@ -323,6 +323,28 @@ def test_lane_steps_live_by_hand(small):
     assert s1["phase_s"]["decode_sync"] > s0["phase_s"]["decode_sync"]
 
 
+def test_attn_steps_by_hand(small):
+    """Pages of 16 rows, 16 table columns, 4 lanes: three requests of 20
+    rows hold two pages each in both of their windows (block starts 20
+    and 24), so the attention kernel's grid is 3 x 2 steps a window of
+    the 4 x (16 + 1) a grid over lanes and columns would walk; the
+    dispatch span carries the window's steps."""
+    eng = _engine(small)                # K = 4
+    eng.start()
+    try:
+        s0 = eng.stats()["loop"]
+        _one_wave(eng, [_prompt(20, i) for i in range(3)], 9)
+        s1 = eng.stats()["loop"]
+    finally:
+        eng.stop()
+    assert s1["attn_steps"] - s0["attn_steps"] == 2 * 3 * 2
+    assert s1["attn_steps_dense"] - s0["attn_steps_dense"] == 2 * 4 * 17
+    spans = [s for s in _loop_spans(eng._loop_trace[0])
+             if s["name"] == "llm.loop.decode_dispatch"]
+    assert [s["attrs"]["attn_steps"] for s in spans] == [6, 6]
+    assert [s["attrs"]["lanes"] for s in spans] == [3, 3]
+
+
 def test_counters_advance_with_tracing_off(small):
     from ray_tpu import tracing
 
@@ -429,6 +451,7 @@ def test_operator_metrics(small):
     assert value("prefill_programs") == 1
     assert value("prefill_tokens") == 60
     assert value("lane_steps_live") == 24 and value("decode_steps") == 8
+    assert value("attn_steps") == 12 and value("attn_steps_dense") == 136
     assert m["tpot"].boundaries == [1, 2, 5, 10, 15, 20, 25, 30, 40, 50,
                                     75, 100, 250, 1000]
     # every TPOT the benchmark has read (17-28 ms) no longer shares a bucket

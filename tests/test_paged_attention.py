@@ -123,6 +123,147 @@ def test_kernel_clamps_runaway_idle_pos():
     assert np.all(np.isfinite(np.asarray(o)))
 
 
+# lanes that hold a request, by name; the rest are idle (a table row
+# of zeros, the trash page, and a `pos` that ran away)
+_LIVE_MIXES = {
+    "idle_first": [0, 0, 1, 1, 1, 1],
+    "idle_last": [1, 1, 1, 1, 0, 0],
+    "interleaved": [1, 0, 1, 0, 0, 1],
+    "all_idle": [0, 0, 0, 0, 0, 0],
+    "all_live": [1, 1, 1, 1, 1, 1],
+}
+
+
+def _lanes(live, page, maxp, seed):
+    """A table and block starts for `live`: each live lane gets the pages
+    its rows need (one at least: its row must not start at the trash
+    page) from a shuffled pool, block starts of 0, one row short of a
+    page, exactly a page, every page full, and two in between, rotated by
+    the seed; an idle lane's start ran away."""
+    rng = np.random.default_rng(seed)
+    starts = np.roll([0, page - 1, page, maxp * page, page + 3, 2 * page],
+                     seed)
+    B = len(live)
+    n_pages = 1 + B * maxp
+    free = list(rng.permutation(np.arange(1, n_pages)))
+    table = np.zeros((B, maxp), np.int32)
+    ts = np.full((B,), 10_000, np.int32)
+    for b in np.flatnonzero(live):
+        ts[b] = starts[b]
+        for c in range(max(-(-ts[b] // page), 1)):
+            table[b, c] = free.pop()
+    return table, ts, n_pages
+
+
+@pytest.mark.parametrize("hd,rep", [(128, 4), (64, 6), (128, 6), (64, 4)],
+                         ids=["hd128-rep4", "hd64-rep6", "hd128-rep6",
+                              "hd64-rep4"])
+@pytest.mark.parametrize("mix", list(_LIVE_MIXES))
+def test_kernel_walks_live_lanes_only(mix, hd, rep):
+    """The grid is the live (lane, page) pairs: with the trash page and
+    every page no live lane lists holding NaN, live lanes give the
+    reference's rows and idle lanes exactly 0, wherever the idle lanes
+    sit and however far their positions ran."""
+    from ray_tpu.ops.paged_attention import (paged_decode_attention,
+                                             paged_decode_reference)
+
+    live = np.asarray(_LIVE_MIXES[mix], bool)
+    B, kvh, kt, page, maxp = len(live), 2, 4, 8, 3
+    seed = list(_LIVE_MIXES).index(mix)
+    table, ts, n_pages = _lanes(live, page, maxp, seed)
+    rng = np.random.default_rng(100 + seed)
+
+    def rand(*shape):
+        return rng.normal(size=shape).astype(np.float32)
+
+    q, kp, vp = rand(B, kvh, rep, hd), rand(n_pages, kvh, page, hd), \
+        rand(n_pages, kvh, page, hd)
+    ktail, vtail = rand(B, kvh, kt, hd), rand(B, kvh, kt, hd)
+    pos = ts + 2
+    dead = np.setdiff1d(np.arange(n_pages), table[table > 0])
+    assert 0 in dead and len(dead) > 1
+    poisoned = [jnp.asarray(x).at[dead].set(jnp.nan) for x in (kp, vp)]
+    rest = [jnp.asarray(x) for x in (ktail, vtail, table, pos, ts)]
+    got = np.asarray(jax.jit(paged_decode_attention)(
+        jnp.asarray(q), *poisoned, *rest))
+    # the oracle gathers every table column, the zeroed ones too, and
+    # 0 x NaN is NaN: it reads the pools as they were
+    want = np.asarray(paged_decode_reference(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), *rest))
+    np.testing.assert_array_equal(got[~live], 0.0)
+    np.testing.assert_array_equal(want[~live], 0.0)
+    np.testing.assert_allclose(got[live], want[live], atol=1e-5)
+    if live.any():
+        assert np.abs(want[live]).max() > 0.1
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_attention_plan_lists_each_live_page_once_in_order(seed):
+    """The plan alone, no kernel: for random tables and block starts its
+    count is the live lanes' pages (one step at least a live lane, the
+    tail's), and its first `count` entries are every live (lane, column)
+    once, lanes ascending and a lane's columns ascending, each with the
+    table's page; what follows repeats the last entry."""
+    from ray_tpu.ops.paged_attention import attention_plan
+
+    rng = np.random.default_rng(seed)
+    B, maxp, page = int(rng.integers(1, 12)), int(rng.integers(1, 6)), 16
+    table = rng.integers(1, 500, size=(B, maxp)).astype(np.int32)
+    live = rng.random(B) < (0.0, 0.3, 0.6, 0.9, 1.0, 0.5)[seed]
+    table[~live, 0] = 0
+    ts = rng.integers(0, maxp * page + 1, size=B).astype(np.int32)
+    ts[rng.random(B) < 0.2] = 0
+    ts[~live] = 1_000_000
+    plan = jax.jit(attention_plan, static_argnums=2)(
+        jnp.asarray(table), jnp.asarray(ts), page)
+    want = [(b, c) for b in range(B) if live[b]
+            for c in range(max(-(-int(ts[b]) // page), 1))]
+    count = int(plan["count"])
+    assert count == len(want)
+    lane, col, pg = (np.asarray(plan[k]) for k in ("lane", "col", "page"))
+    assert lane.shape == col.shape == pg.shape == (B * maxp,)
+    assert list(zip(lane[:count], col[:count])) == want
+    np.testing.assert_array_equal(pg, table[lane, col])
+    last = max(count - 1, 0)
+    assert (lane[last:] == lane[last]).all() and \
+        (col[last:] == col[last]).all()
+
+
+def test_engine_counts_the_plans_steps():
+    """`stats()["loop"]["attn_steps"]`, which the engine adds up from the
+    lengths it holds on the host, is the sum of the counts of the plans
+    its decode windows built on the device; `attn_steps_dense` is the
+    lanes x (columns + 1) grid a window."""
+    from ray_tpu.models import llama
+    from ray_tpu.ops.paged_attention import attention_plan
+
+    eng = _engine(llama.llama_configs()["debug"], page_size=16)
+    counts = []
+    k = eng.steps_per_sync
+    decode = eng._decode_fns[k]
+
+    def counting(params, cache, tokens, temps, table, *rest):
+        counts.append(int(attention_plan(table, cache["pos"],
+                                         eng.page)["count"]))
+        return decode(params, cache, tokens, temps, table, *rest)
+
+    eng._decode_fns[k] = counting
+    try:
+        # prompts under a page, over one and over two; the longest
+        # decode crosses a page edge; lanes finish at different windows
+        futs = [eng.submit(list(range(1, 1 + n)), max_new_tokens=m)
+                for n, m in ((5, 3), (20, 30), (40, 9))]
+        for f in futs:
+            f.result(timeout=180)
+        loop = eng.stats()["loop"]
+    finally:
+        eng.stop()
+    assert len(counts) >= 4 and len(set(counts)) > 1
+    assert loop["attn_steps"] == sum(counts)
+    assert loop["attn_steps_dense"] == len(counts) * 4 * (eng._maxp + 1)
+    assert 0 < loop["attn_steps"] < loop["attn_steps_dense"]
+
+
 def _debug_f32():
     """The debug model in float32: greedy tokens then follow the
     arithmetic and not bf16 near-ties, so `llama.forward` can referee."""

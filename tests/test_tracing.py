@@ -81,10 +81,16 @@ def test_timeline_events_carry_trace_id(cluster):
 
 def test_stack_dump_collects_runtime_stacks(cluster):
     """`ray-tpu stack`: every runtime process dumps all-thread stacks on
-    SIGUSR1 and the collector gathers them."""
+    SIGUSR1 and the collector gathers them.  Only THIS cluster's
+    processes are signalled: the stack directory is the machine's, and a
+    signal into another xdist worker's cluster has cost that worker's
+    actors their connection (a lost task: its test waits for ever)."""
+    import psutil
+
     from ray_tpu._private.stack_dump import collect
 
-    out = collect()
+    out = collect(only={p.pid for p in
+                        psutil.Process().children(recursive=True)})
     assert "signalled" in out
     # At least the controller/agent/worker processes responded with a
     # thread dump.
