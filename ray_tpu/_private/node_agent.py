@@ -36,6 +36,7 @@ from ray_tpu._private import failpoints
 from ray_tpu._private import memledger
 from ray_tpu._private import scheduler as sched
 from ray_tpu._private import spans
+from ray_tpu._private import stack_dump
 from ray_tpu._private.config import Config
 from ray_tpu._private.ids import NodeID
 from ray_tpu._private.rpc import ClientPool, RpcServer, Subscriber
@@ -784,6 +785,8 @@ class NodeAgent:
                 pass
         self.workers.pop(w.worker_id, None)
         self._prune_worker_logs(w.worker_id)
+        if w.proc is not None:
+            stack_dump.unregister(w.proc.pid)
         self._try_grant_pending()
 
     # -------------------------------------------------------------- leasing

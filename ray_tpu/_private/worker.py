@@ -3566,7 +3566,19 @@ class CoreWorker:
                 for task, _ in batch:
                     self._fail_actor_call(task, err)
                 return
-            addr = await self._resolve_actor_addr(st)
+            try:
+                addr = await self._resolve_actor_addr(st)
+            except Exception as e:  # noqa: BLE001 - controller gone/refusing
+                # These calls have no other owner: an error that left
+                # this task here (the resolve's own 150 s deadline on a
+                # controller that died, a refusal) used to end the task
+                # and leave their refs pending for ever.
+                err = ActorError(
+                    st.actor_id,
+                    f"actor address could not be resolved: {e!r}")
+                for task, _ in batch:
+                    self._fail_actor_call(task, err)
+                return
             if addr is None:
                 continue    # loops back; st.dead set or address refreshed
             if addr in self._dead_worker_addrs:

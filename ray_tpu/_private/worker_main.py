@@ -20,17 +20,22 @@ def _watch_parent() -> None:
     import threading
     import time
 
+    from ray_tpu._private.stack_dump import proc_stat
+
     agent_pid = int(os.environ.get("RAY_TPU_AGENT_PID") or 0)
 
     def _alive() -> bool:
         if agent_pid:
             try:
                 os.kill(agent_pid, 0)
-                return True
             except ProcessLookupError:
                 return False
             except PermissionError:
                 return True
+            # An agent that died under a parent that has not reaped it
+            # (a driver holding its Popen) still answers kill(pid, 0).
+            stat = proc_stat(agent_pid)
+            return not stat or stat[0] != "Z"
         return os.getppid() > 1
 
     def _loop():
@@ -105,12 +110,6 @@ def main() -> None:
     _watch_parent()
     _extend_sys_path()
     _mark("pre")
-    # `kill -USR1 <pid>` dumps all thread stacks to stderr — the per-process
-    # half of the `ray stack` debugging story (ray: py-spy attach).
-    import faulthandler
-    import signal
-
-    faulthandler.register(signal.SIGUSR1, all_threads=True)
     logging.basicConfig(
         level=logging.INFO,
         format="%(asctime)s %(levelname)s worker[%(process)d]: %(message)s")

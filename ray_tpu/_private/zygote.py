@@ -290,7 +290,18 @@ def main() -> None:
     signal.signal(signal.SIGCHLD, _on_chld)
     print("READY", flush=True)
 
-    conn, _ = listener.accept()
+    # An agent that died before it connected must not leave this
+    # process waiting in accept() for ever.
+    listener.settimeout(1.0)
+    while True:
+        if os.getppid() != agent_pid:
+            os._exit(0)
+        try:
+            conn, _ = listener.accept()
+            break
+        except socket.timeout:
+            continue
+    conn.settimeout(None)
     import select
 
     children: set[int] = set()

@@ -26,20 +26,32 @@ _initialized = False
 
 
 def _read_json_line(proc: subprocess.Popen, timeout: float = 30.0) -> dict:
-    """Read the child's one-line JSON address announcement from stdout."""
+    """Read the child's one-line JSON address announcement from stdout.
+    Bounded by `timeout` whatever the child does: a readline() waits for
+    ever on a child that died after handing the pipe to a child of its
+    own (the agent's zygote), which never writes and never closes it."""
+    import os
+    import select
+
+    fd = proc.stdout.fileno()
     deadline = time.monotonic() + timeout
-    while time.monotonic() < deadline:
-        line = proc.stdout.readline()
-        if not line:
+    buf = b""
+    while True:
+        while b"\n" in buf:
+            line, buf = buf.split(b"\n", 1)
+            line = line.strip()
+            if line.startswith(b"{"):
+                return json.loads(line)
+        left = deadline - time.monotonic()
+        if left <= 0 or not select.select([fd], [], [], left)[0]:
+            raise TimeoutError("head process did not announce its address")
+        chunk = os.read(fd, 65536)
+        if not chunk:
             if proc.poll() is not None:
                 raise RuntimeError(
                     f"head process exited with {proc.returncode}")
             time.sleep(0.01)
-            continue
-        line = line.strip()
-        if line.startswith(b"{"):
-            return json.loads(line)
-    raise TimeoutError("head process did not announce its address")
+        buf += chunk
 
 
 def _spawn(args: list[str]) -> tuple[subprocess.Popen, dict]:
@@ -47,7 +59,11 @@ def _spawn(args: list[str]) -> tuple[subprocess.Popen, dict]:
         [sys.executable, "-m", *args], stdout=subprocess.PIPE,
         stderr=subprocess.DEVNULL if not __import__("os").environ.get(
             "RAY_TPU_HEAD_LOGS") else None)
-    info = _read_json_line(proc)
+    try:
+        info = _read_json_line(proc)
+    except BaseException:
+        proc.kill()
+        raise
     _head_processes.append(proc)
     return proc, info
 

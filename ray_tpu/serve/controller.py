@@ -249,8 +249,17 @@ class ServeController:
     # replicas publish/withdraw demoted subtrees, the miss path looks
     # up the deepest stored prefix, and handles poll the summary for
     # store-aware routing.  All logic lives in the directory.
-    def prefix_store_publish(self, app: str, meta: dict, ref) -> bool:
-        return self._prefix_store.publish(app, meta, ref)
+    def prefix_store_publish(self, app: str, meta: dict, ref) -> dict:
+        # A replica of a deleted app still drains and may demote: what
+        # it published after delete_app's scrub, nothing would scrub.
+        # Under the lock, so a publish lands before the app is marked
+        # (and is scrubbed with it) or after (and is refused).
+        with self._lock:
+            rec = self._apps.get(app)
+            if rec is None or all(
+                    st.deleting for st in rec["deployments"].values()):
+                return {"ok": False, "live": []}
+            return self._prefix_store.publish(app, meta, ref)
 
     def prefix_store_lookup(self, app: str, hashes: list, page: int,
                             seed, weight_version: int | None = None,

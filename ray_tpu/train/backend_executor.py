@@ -280,6 +280,16 @@ class BackendExecutor:
                 progressed = True
                 if msg["type"] == "done":
                     done[i] = True
+                    if msg.get("error"):
+                        # Not once every rank is done: its peers may
+                        # sit inside a collective it will never join.
+                        # Reports already drained still reach the
+                        # caller (a fresher resume point).
+                        while on_report is not None and any(pending):
+                            on_report([p.pop(0) for p in pending if p])
+                        raise TrainingFailedError(
+                            f"train fn failed on rank {i}:\n"
+                            f"{msg['error']}")
                 elif msg["type"] == "report":
                     pending[i].append(msg)
             # lock-step: emit a round once every live worker reported
@@ -294,11 +304,5 @@ class BackendExecutor:
             if not progressed:
                 time.sleep(0.05)
 
-        statuses = wg.execute("get_status")
-        errors = [(i, s["error"]) for i, s in enumerate(statuses)
-                  if s["error"]]
-        if errors:
-            rank, tb = errors[0]
-            raise TrainingFailedError(
-                f"train fn failed on rank {rank}:\n{tb}")
+        # Every rank's done message came without an error.
         return wg.execute("get_result")

@@ -167,8 +167,9 @@ class ElasticRun:
                 # Same failure-budget contract as the legacy loop: a
                 # train-fn error burns one max_failures round, then the
                 # LIVE gang retries at the next epoch from the newest
-                # checkpoint (all workers answered get_status to get
-                # here — no respawn needed).
+                # checkpoint (the transition parks the failed rank's
+                # peers, some of them inside a collective it left, and
+                # drops whoever does not answer — no respawn needed).
                 fail(_be.TrainingFailedError(payload))
                 survivors = self._transition(self.active)
                 if not survivors:
@@ -310,6 +311,16 @@ class ElasticRun:
                 progressed = True
                 if msg["type"] == "done":
                     done[r] = True
+                    if msg.get("error"):
+                        # Its peers may sit inside a collective that
+                        # this rank will never join: hand the error up
+                        # now, and the transition parks and frees them
+                        # as it does after a kill.
+                        self._flush_pending(pending, on_report)
+                        return ("fn_error",
+                                f"train fn failed on rank {r} "
+                                f"(epoch {self.epoch}):\n{msg['error']}",
+                                None)
                 elif msg["type"] == "report":
                     pending[r].append(msg)
             if all(p or done[i] for i, p in enumerate(pending)) and \
@@ -331,22 +342,14 @@ class ElasticRun:
                     return ("regrow", joiners, None)
             if not progressed:
                 time.sleep(0.05)
-        statuses = []
-        for r, slot in enumerate(roster):
+        # Every rank's done message came without an error.
+        results = []
+        for slot in roster:
             try:
-                statuses.append(ray_tpu.get(
-                    wg.workers[slot].get_status.remote(), timeout=30.0))
+                results.append(ray_tpu.get(
+                    wg.workers[slot].get_result.remote(), timeout=30.0))
             except Exception as e:  # noqa: BLE001 - died while finishing
                 return ("dead", [slot], e)
-        errors = [(r, s["error"]) for r, s in enumerate(statuses)
-                  if s["error"]]
-        if errors:
-            rank, tb = errors[0]
-            return ("fn_error",
-                    f"train fn failed on rank {rank} "
-                    f"(epoch {self.epoch}):\n{tb}", None)
-        results = [ray_tpu.get(wg.workers[slot].get_result.remote(),
-                               timeout=30.0) for slot in roster]
         return ("done", results, None)
 
     # ------------------------------------------------------------- regrow

@@ -114,7 +114,10 @@ class TrainWorker:
                     if self._error is None and not sess.epoch_abort:
                         self._error = traceback.format_exc()
                 self._finished = True
-                sess.out.put({"type": "done"})
+                # The error rides the done message: the driver parks
+                # this rank's peers when the FIRST rank fails, not when
+                # the last one gives up waiting for it in a collective.
+                sess.out.put({"type": "done", "error": self._error})
 
         self._thread = threading.Thread(target=run, daemon=True)
         self._thread.start()
@@ -125,12 +128,12 @@ class TrainWorker:
         import queue as q
 
         if self._session is None:
-            return {"type": "done"}
+            return {"type": "done", "error": None}
         try:
             msg = self._session.out.get(timeout=timeout)
         except q.Empty:
             if self._finished:
-                return {"type": "done"}
+                return {"type": "done", "error": self._error}
             return None
         return msg
 

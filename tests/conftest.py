@@ -22,43 +22,166 @@ ensure_cpu_devices(8)
 import pytest  # noqa: E402
 
 
+# One limit for every phase (setup, call, teardown) of every test.  The
+# slowest test of a loaded six-worker run takes under a third of it
+# (CHANGES.md PR 32 has the table), and six wedged workers still cost a
+# 1,470 s run an eighth of its clock.  A test that needs longer says so
+# on itself: @pytest.mark.time_limit(seconds), with the reason beside it.
+TEST_LIMIT_S = 180
+# The backstop ends the process this long after the alarm should have
+# fired: room for the scrub below (ray_tpu.shutdown() is bounded at 16 s).
+_BACKSTOP_GRACE_S = 30
+# A test that swallowed the watchdog's error is hit again this often.
+_REFIRE_S = 10
+
+_real_stderr_fd = None
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers",
         "chaos: process-killing fault-injection suites (test_chaos*, "
         "test_failpoints) — each test runs its own cluster and kills "
         "pieces of it; deselect with -m 'not chaos' for a quiet pass")
+    config.addinivalue_line(
+        "markers",
+        f"time_limit(seconds): this test's own watchdog limit for each "
+        f"phase, in place of the suite's {TEST_LIMIT_S} s")
+    _route_worker_logs_through_logging()
+
+
+def _route_worker_logs_through_logging():
+    """Worker log lines forwarded to this driver go through `logging`,
+    where pytest's capture holds them with the test that caused them,
+    and not to a stderr that between tests is pytest's progress line
+    (the driver's fallback count reads whole lines of dots)."""
+    import logging
+
+    from ray_tpu._private.worker import CoreWorker
+
+    log = logging.getLogger("ray_tpu.worker_logs")
+
+    async def _on_log_lines(self, _topic, payload):
+        node = payload.get("node_id", "?")
+        for src, line in payload.get("lines", []):
+            log.info("(%s, node=%s) %s", src, node, line)
+
+    CoreWorker._on_log_lines = _on_log_lines
+
+
+def _stderr_outside_capture(config) -> int:
+    """A descriptor of the stderr this process was started with: the
+    backstop's dump must outlive the process, and pytest's capture file
+    does not."""
+    global _real_stderr_fd
+    if _real_stderr_fd is None:
+        capman = config.pluginmanager.getplugin("capturemanager")
+        if capman is None:
+            _real_stderr_fd = os.dup(2)
+        else:
+            with capman.global_and_fixture_disabled():
+                _real_stderr_fd = os.dup(2)
+    return _real_stderr_fd
+
+
+def _runtime_descendants():
+    import psutil
+
+    out = []
+    for p in psutil.Process().children(recursive=True):
+        try:
+            if "ray_tpu._private" in " ".join(p.cmdline()):
+                out.append(p)
+        except psutil.Error:
+            continue
+    return out
+
+
+def _scrub_runtime():
+    """After a timeout the worker must be clean for the next test:
+    shut the runtime down (bounded) and SIGKILL every runtime process
+    this pytest process started, so `ray_shared` and every fresh
+    cluster of the tests that follow start from nothing."""
+    import psutil
+
+    import ray_tpu
+
+    procs = _runtime_descendants()
+    if ray_tpu.is_initialized():
+        try:
+            ray_tpu.shutdown()
+        except Exception:  # noqa: BLE001 - the kill below is the floor
+            pass
+    for p in procs:
+        try:
+            p.kill()
+        except psutil.Error:
+            pass
+    psutil.wait_procs(procs, timeout=5.0)
+
+
+def _limited(item, when):
+    """Watchdog around one phase of one test (pytest-timeout isn't in
+    this image).  SIGALRM interrupts a wedged main-thread wait and fails
+    THAT test with every thread's stack; behind it faulthandler's own
+    timer, which needs nothing of the main thread, dumps the stacks and
+    ends the process when the main thread sits in a native call that
+    never returns (xdist then fails the test by name and replaces the
+    worker)."""
+    import faulthandler
+    import signal
+    import sys
+
+    marker = item.get_closest_marker("time_limit")
+    limit = int(marker.args[0]) if marker else TEST_LIMIT_S
+    fired = []
+
+    def _fire(signum, frame):
+        # All-thread dump first: the main-thread frame usually shows only
+        # a queue/future wait — the interesting stack (executor threads,
+        # IO loop) is elsewhere.
+        faulthandler.dump_traceback(file=sys.stderr, all_threads=True)
+        fired.append(f"{frame.f_code.co_filename}:{frame.f_lineno}")
+        signal.alarm(_REFIRE_S)
+        raise TimeoutError(
+            f"watchdog: {item.nodeid} exceeded {limit}s in {when} "
+            f"(frame: {fired[-1]})")
+
+    old = signal.signal(signal.SIGALRM, _fire)
+    faulthandler.dump_traceback_later(
+        limit + _BACKSTOP_GRACE_S, exit=True,
+        file=_stderr_outside_capture(item.config))
+    signal.alarm(limit)
+    try:
+        result = yield
+        if fired:
+            raise TimeoutError(
+                f"watchdog: {item.nodeid} exceeded {limit}s in {when} "
+                f"and swallowed the error (frame: {fired[0]})")
+        return result
+    finally:
+        signal.alarm(0)
+        signal.signal(signal.SIGALRM, old)
+        if fired:
+            _scrub_runtime()
+        faulthandler.cancel_dump_traceback_later()
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_setup(item):
+    return (yield from _limited(item, "setup"))
 
 
 @pytest.hookimpl(wrapper=True)
 def pytest_runtest_call(item):
-    """Per-test watchdog (pytest-timeout isn't in this image): SIGALRM
-    interrupts a wedged main-thread wait, failing THAT test with a live
-    stack instead of hanging the whole suite — distributed-runtime bugs
-    here historically manifest as infinite gets."""
-    import signal
+    return (yield from _limited(item, "call"))
 
-    budget = int(os.environ.get("RAY_TPU_TEST_TIMEOUT_S", "900"))
 
-    def _fire(signum, frame):
-        # All-thread dump first: the main-thread frame usually shows only
-        # a queue/future wait — the THE interesting stack (executor
-        # threads, IO loop) is elsewhere.
-        import faulthandler
-        import sys
-
-        faulthandler.dump_traceback(file=sys.stderr, all_threads=True)
-        raise TimeoutError(
-            f"watchdog: {item.nodeid} exceeded {budget}s "
-            f"(frame: {frame.f_code.co_filename}:{frame.f_lineno})")
-
-    old = signal.signal(signal.SIGALRM, _fire)
-    signal.alarm(budget)
-    try:
-        return (yield)
-    finally:
-        signal.alarm(0)
-        signal.signal(signal.SIGALRM, old)
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_teardown(item):
+    # Module- and session-scoped finalisers run in the teardown of the
+    # last test that used them, so they are under this limit too.
+    return (yield from _limited(item, "teardown"))
 
 
 @pytest.fixture

@@ -108,3 +108,45 @@ def test_tasks_survive_random_worker_kills():
         assert killed, "chaos thread never killed a worker"
     finally:
         ray_tpu.shutdown()
+
+
+def test_call_behind_a_failed_address_resolve_raises(monkeypatch):
+    """A call queued behind an actor-address resolve that FAILS had no
+    owner: the error ended the sender task and the call's ref stayed
+    pending for ever.  Found under a 1 Hz `stack_dump.collect()`, which
+    (before one GIL-holding handler replaced the two signals) ended the
+    controller with SIGSEGV while a fresh actor's first call waited for
+    its address; `get_actor_info` then ran into its own 150 s deadline.
+    Here the resolve fails at once (the 150 s are not the point): the
+    caller gets the runtime's error, inside 30 s."""
+    from ray_tpu._private.worker import global_worker
+    from ray_tpu.exceptions import ActorError
+
+    if ray_tpu.is_initialized():
+        ray_tpu.shutdown()
+    ray_tpu.init(resources={"CPU": 2})
+    try:
+        @ray_tpu.remote
+        class Echo:
+            def pid(self):
+                return os.getpid()
+
+        async def _no_answer(_st):
+            raise TimeoutError("controller did not answer")
+
+        monkeypatch.setattr(global_worker(), "_do_resolve", _no_answer)
+        a = Echo.remote()
+        t0 = time.monotonic()
+        with pytest.raises(ActorError, match="could not be resolved"):
+            ray_tpu.get(a.pid.remote(), timeout=60)
+        assert time.monotonic() - t0 < 30
+        # The worker that holds the actor vanishes too: nothing waits.
+        monkeypatch.undo()
+        pid = ray_tpu.get(a.pid.remote(), timeout=60)
+        os.kill(pid, signal.SIGKILL)
+        t0 = time.monotonic()
+        with pytest.raises(ActorError):
+            ray_tpu.get(a.pid.remote(), timeout=60)
+        assert time.monotonic() - t0 < 30
+    finally:
+        ray_tpu.shutdown()

@@ -578,6 +578,16 @@ def test_store_through_serve_controller_directory(serve_ray, small):
     # App delete scrubbed the directory (controller-side refs too).
     st = ray_tpu.get(ctrl.prefix_store_stats.remote(), timeout=30.0)
     assert st["entries"] == 0, st
+    # A replica that demotes while it drains publishes AFTER that
+    # scrub (the `assert 1 == 0` this test showed under load): the
+    # directory refuses what nothing would scrub again.
+    late = ray_tpu.get(ctrl.prefix_store_publish.remote(
+        "ps_app", {"hashes": [1], "page": 16, "nbytes": 8,
+                   "replica": "gone", "deployment": "llm"},
+        [ray_tpu.put(b"late")]), timeout=30.0)
+    assert late == {"ok": False, "live": []}, late
+    st = ray_tpu.get(ctrl.prefix_store_stats.remote(), timeout=30.0)
+    assert st["entries"] == 0, st
 
 
 @pytest.mark.chaos

@@ -98,6 +98,66 @@ def test_stack_dump_collects_runtime_stacks(cluster):
     assert "Thread 0x" in out or "Current thread" in out, out[:2000]
 
 
+def test_stack_dump_leaves_a_reused_pid_alone():
+    """A pid file names the process it was written for.  A stale file
+    whose header start time is not the live process's is unlinked and
+    the process that drew the pid is NOT signalled: here a `sleep` with
+    default dispositions, which SIGUSR1 would terminate."""
+    import os
+    import subprocess
+    import time
+
+    from ray_tpu._private import stack_dump
+
+    child = subprocess.Popen(["sleep", "60"])
+    path = os.path.join(stack_dump.STACK_DIR, f"{child.pid}_worker.txt")
+    try:
+        os.makedirs(stack_dump.STACK_DIR, exist_ok=True)
+        with open(path, "w") as f:
+            f.write(f"# worker pid={child.pid} start=1 argv=[]\n")
+        out = stack_dump.collect(only={child.pid})
+        assert "signalled 0 runtime processes" in out, out
+        assert not os.path.exists(path)
+        time.sleep(0.3)
+        assert child.poll() is None, "collect() signalled a reused pid"
+    finally:
+        child.kill()
+        child.wait()
+        if os.path.exists(path):
+            os.unlink(path)
+
+
+def test_worker_sigusr1_dump_lands_in_its_pid_file(cluster):
+    """One SIGUSR1 registration per process, to the pid file: `kill
+    -USR1 <worker pid>` shows up where `ray-tpu stack` reads."""
+    import os
+    import signal
+    import time
+
+    from ray_tpu._private import stack_dump
+
+    @ray_tpu.remote
+    class Pid:
+        def pid(self):
+            return os.getpid()
+
+    a = Pid.remote()
+    pid = ray_tpu.get(a.pid.remote(), timeout=60)
+    path = os.path.join(stack_dump.STACK_DIR, f"{pid}_worker.txt")
+    with open(path) as f:
+        assert f"start={stack_dump._start_time(pid)} " in f.readline()
+    os.kill(pid, signal.SIGUSR1)
+    deadline = time.monotonic() + 10
+    text = ""
+    while time.monotonic() < deadline and "most recent call" not in text:
+        time.sleep(0.1)
+        with open(path) as f:
+            text = f.read()
+    assert "most recent call first" in text, text[:2000]
+    assert ray_tpu.get(a.pid.remote(), timeout=60) == pid
+    ray_tpu.kill(a)
+
+
 def test_otlp_export_file(cluster, tmp_path):
     """VERDICT round-4 item 9 (ray: util/tracing/tracing_helper.py:1):
     task spans export as an OTLP/JSON document with trace ids propagated

@@ -285,14 +285,20 @@ def test_elastic_transient_error_retries_live_gang(ray_shared, tmp_path):
     """A train-fn error on the elastic path burns one max_failures
     round (same budget contract as the legacy loop) and retries the
     LIVE gang at the next epoch — pid-stable, no respawn."""
+    t0 = time.monotonic()
     executor, history, _, error = _drive(
         _sgd_loop,
         {"total_steps": 4, "error_marker": str(tmp_path / "err_once"),
          "error_at": 2},
         num_workers=2, storage=tmp_path / "store", trial="el_retry",
         max_failures=1)
+    took = time.monotonic() - t0
     assert (tmp_path / "err_once").exists(), "error never armed"
     assert error is None, error
+    # Rank 0 sat in host_allreduce when rank 1 raised: it is parked and
+    # freed when the error is REPORTED, not when the collective's own
+    # 120 s deadline gives up on the missing contribution.
+    assert took < 30, f"peers of the failed rank waited: {took:.1f}s"
     kinds = [t["kind"] for t in executor.elastic.stats["transitions"]]
     assert kinds == ["retry"], kinds
     assert len({m["pid"] for m in history}) == 1, history
@@ -305,14 +311,17 @@ def test_legacy_transient_error_reuses_live_group(ray_shared, tmp_path,
     every worker still ALIVE retries on the live gang — same worker
     pids after the retry, no respawn."""
     monkeypatch.setenv("RAY_TPU_ELASTIC", "0")
+    t0 = time.monotonic()
     executor, history, _, error = _drive(
         _sgd_loop,
         {"total_steps": 4, "error_marker": str(tmp_path / "err_once"),
          "error_at": 2},
         num_workers=2, storage=tmp_path / "store", trial="el_legacy",
         max_failures=1)
+    took = time.monotonic() - t0
     assert (tmp_path / "err_once").exists(), "error never armed"
     assert error is None, error
+    assert took < 30, f"peers of the failed rank waited: {took:.1f}s"
     assert executor.elastic is None     # legacy path ran
     # One pid per rank across the WHOLE run including the retry: the
     # group was reused, not respawned.  rank0 history only carries
