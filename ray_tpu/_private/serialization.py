@@ -62,6 +62,8 @@ class _Pickler(cloudpickle.CloudPickler):
         if isinstance(obj, ObjectRef):
             _note_ref(obj)
             return (ObjectRef._from_serialized, (obj.binary(), obj.owner_addr))
+        if obj.__class__ is _ndarray and _unbuffered_dtype(obj):
+            return _reduce_unbuffered_ndarray(obj)
         custom = _custom_serializers.get(obj.__class__)
         if custom is not None:
             ser, deser = custom
@@ -89,6 +91,34 @@ except Exception:  # noqa: BLE001
     _np = None
 
 
+_ndarray = _np.ndarray if _np is not None else None
+
+
+def _unbuffered_dtype(a) -> bool:
+    """A dtype numpy registered for somebody else (ml_dtypes' bfloat16,
+    the float8s): such an array exports no buffer ("cannot include
+    dtype 'E' in a buffer"), so numpy pickles it IN the stream: a
+    `tobytes()` copy and the pickler's own, both under the GIL.  A
+    34 MB page of bfloat16 KV held the GIL ~100 ms that way and stalled
+    the serving engine's thread for as long (PERF.md section 6,
+    PR 33)."""
+    return a.dtype.isbuiltin == 2
+
+
+def _ndarray_from_buffer(buf, dtype, shape):
+    return _np.frombuffer(buf, dtype=_np.uint8).view(dtype).reshape(shape)
+
+
+def _reduce_unbuffered_ndarray(a):
+    """Out of band like any other array: the bytes ride as a raw frame
+    (a view of the array, no copy) and come back as a view of the frame,
+    read-only like every array that crosses the object plane."""
+    if not a.flags.c_contiguous:
+        a = _np.ascontiguousarray(a)
+    raw = pickle.PickleBuffer(a.reshape(-1).view(_np.uint8))
+    return (_ndarray_from_buffer, (raw, a.dtype, a.shape))
+
+
 def _stdlib_picklable(v: Any) -> bool:
     """True when the C pickler provably produces the SAME result cloudpickle
     would: exact builtin scalar/container types, object-free numpy arrays,
@@ -104,8 +134,10 @@ def _stdlib_picklable(v: Any) -> bool:
                    for k, x in v.items())
     if t in _SAFE_CONTAINERS:
         return all(_stdlib_picklable(x) for x in v)
-    if _np is not None and t is _np.ndarray:
-        return not v.dtype.hasobject
+    if t is _ndarray:
+        # (a registered dtype goes out of band through
+        # _Pickler.reducer_override)
+        return not v.dtype.hasobject and not _unbuffered_dtype(v)
     from ray_tpu.object_ref import ObjectRef
 
     return t is ObjectRef

@@ -186,6 +186,13 @@ def _engine_metrics():
                 "moe_assignments": um.get_or_create(
                     um.Counter, "serve_llm_moe_assignments",
                     "Token-expert assignments computed in decode", tk),
+                # beside phase_s["decode_sync"]: bytes a second the
+                # prefix store's demotion fetched off the device
+                "demote_bytes": um.get_or_create(
+                    um.Counter, "serve_llm_demote_bytes",
+                    "Host bytes of KV pages demoted to the prefix "
+                    "store (fetched off the device, 2 x layers pieces "
+                    "a page)", tk),
                 "preemptions": um.get_or_create(
                     um.Counter, "serve_llm_preemptions",
                     "Requests preempted for KV blocks", tk),
@@ -606,6 +613,22 @@ class LLMEngine:
 
         self._gather_kv = jax.jit(_gather_kv_fn)
 
+        # Prefix-store demotion reads ONE page a call, whatever the
+        # path's depth (a path of depth n is n calls): the page's K and
+        # V of every layer as 2·L separate [kvh, page, hd] arrays, so
+        # the export thread can fetch them one piece at a time and
+        # nothing the size of a path ever stands between the decode
+        # window's tokens and the host.  ONE program, one shape,
+        # compiled when a demotion callback is installed
+        # (set_prefix_store) and never after: `_gather_page` is the
+        # compiled executable, which cannot meet a new shape.
+        def _gather_page_fn(ks, vs, pid):
+            return (tuple(k[pid] for k in ks)
+                    + tuple(v[pid] for v in vs))
+
+        self._gather_page_jit = jax.jit(_gather_page_fn)
+        self._gather_page = None
+
         def _import_kv_fn(cache, cur, kv, ids, slot, kvlen, tok):
             k = [cache["k"][li].at[ids].set(kv[0, li])
                  for li in range(cfg.n_layers)]
@@ -680,8 +703,8 @@ class LLMEngine:
         self.kv_exports = 0            # prefill-side page migrations out
         self.kv_imports = 0            # decode-side page migrations in
         # Export side-channel (created lazily by the loop thread on the
-        # first prefill_only finish): the device→host fetch of migrated
-        # KV runs here so the decode loop never blocks on it.
+        # first prefill_only finish or demotion): the device→host fetch
+        # of migrated or demoted KV runs on this thread (_export_loop).
         self._export_q: queue.Queue | None = None
         self._export_thread: threading.Thread | None = None
         self.prefill_tokens = 0        # tokens actually prefilled
@@ -714,7 +737,13 @@ class LLMEngine:
              for k in ("moe_layer_steps", "moe_assignments",
                        "moe_experts_hit", "moe_max_load")], 0)
         self._funded_blocks = 0        # pages _ensure_decode_blocks got
-        self._demote_dispatched = 0    # gathers _maybe_demote dispatched
+        self._demote_dispatched = 0    # candidates _maybe_demote took
+        # What demotion moved off the device, cumulative, bumped on the
+        # export thread: pages and host bytes fetched, and the seconds
+        # that thread spent in the piece fetches.
+        self.demote_pages = 0
+        self.demote_bytes = 0
+        self.demote_fetch_s = 0.0
         # Live weight sync (online RLHF): update_weights() stages a
         # fresh param tree here; the loop swaps it in BETWEEN decode
         # sync windows (never mid-block — the compiled program must see
@@ -734,14 +763,19 @@ class LLMEngine:
         # Tier-2 prefix store (serve/prefix_store.py): the owning
         # server installs a demotion callback via set_prefix_store;
         # the loop then demotes cold radix leaves into sealed arena
-        # objects (gather dispatched on the loop, host fetch + publish
-        # on the export thread) and applies queued grafts.  All
-        # no-ops until a callback is installed.
+        # objects (scan in `fund`, one page gather a page dispatched
+        # BEHIND the decode window, piecewise host fetch + publish on
+        # the export thread) and applies queued grafts.  All no-ops
+        # until a callback is installed.
         self._demote_cb = None
         self._demote_knobs: dict = {}
         self._demote_lock = threading.Lock()
         self._demote_inflight = 0
         self._demote_t = 0.0
+        # Scanned (pinned) candidates whose gathers are not dispatched
+        # yet: filled by _maybe_demote, emptied by _dispatch_demotes
+        # later in the same iteration.
+        self._demote_pending: list[dict] = []
         # Leaf hashes the store declined — skipped on rescans so a
         # disabled/full store doesn't re-gather the same leaves every
         # period.  Cleared on weight swaps with the tree flush.
@@ -926,7 +960,13 @@ class LLMEngine:
         ticks of disuse, or immediately when the free pool falls under
         `watermark_frac` (demote-before-evict: plain eviction would
         destroy KV the cluster could reuse); at most `limit` leaves per
-        `period_s` scan and `max_inflight` unfinished demotions."""
+        `period_s` scan and `max_inflight` unfinished demotions.
+        Installing a callback compiles the one page-gather program
+        demotion runs (start-up, never a decode window); an engine
+        that never gets one, or has no prefix cache, builds none."""
+        if (publish_cb is not None and self._prefix_cache
+                and self._gather_page is None):
+            self._gather_page = self._lower_page_gather().compile()
         self._demote_cb = publish_cb
         self._demote_knobs = dict(
             min_idle=max(0, int(min_idle)),
@@ -937,6 +977,24 @@ class LLMEngine:
             max_inflight=max(1, int(max_inflight)))
         with self._demote_lock:
             self._demote_skip.clear()
+
+    def _lower_page_gather(self, sharding=None):
+        """The page gather lowered for this engine's pools: from their
+        shapes alone, so it is safe beside a running loop (the decode
+        program donates the arrays themselves).  `sharding` places the
+        arguments elsewhere than the pools lie (tests/
+        test_chip_compile.py: a described chip)."""
+        import jax
+        import jax.numpy as jnp
+
+        def spec(a):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                        sharding=sharding or a.sharding)
+
+        return self._gather_page_jit.lower(
+            [spec(a) for a in self.cache["k"]],
+            [spec(a) for a in self.cache["v"]],
+            jax.ShapeDtypeStruct((), jnp.int32, sharding=sharding))
 
     def kv_graft(self, tokens: list[int], kv, *, kv_len: int,
                  weight_version: int | None = None, salt: int = 0,
@@ -1328,7 +1386,9 @@ class LLMEngine:
         bypasses the prefix cache (_cache_ok=False): each bucket's
         ramp prompt is a prefix of the next one's, and matching it
         would compile the suffix programs instead of the full-prefill
-        bucket programs warmup exists to build."""
+        bucket programs warmup exists to build.  The prefix store's
+        page gather is not built here: set_prefix_store compiles it
+        when a callback is installed, traffic or no traffic."""
         cap = self.max_len - 1
         if getattr(self, "page", None):
             cap = min(cap, (self.n_pages - 1) * self.page - 1)
@@ -1566,14 +1626,14 @@ class LLMEngine:
         return self._export_q
 
     def _maybe_demote(self) -> None:
-        """Loop-side demotion scan (tier-1 → tier-2): pick cold
-        refcount-0 radix leaves (BlockManager.demote_scan), dispatch
-        ONE device gather per candidate covering the whole path
-        root..leaf, and hand the host fetch + publish to the export
-        thread — the loop never blocks on the device→host fetch.
-        Throttled by period and in-flight cap; no-op until a server
-        installs the callback, and gated per scan by the
-        RAY_TPU_PREFIX_STORE kill switch."""
+        """Loop-side demotion scan (tier-1 → tier-2), host arithmetic
+        only: pick cold refcount-0 radix leaves
+        (BlockManager.demote_scan pins each path root..leaf) and leave
+        them in `_demote_pending`.  The device work is left to
+        _dispatch_demotes, which the caller runs BEHIND the decode
+        window of the same iteration.  Throttled by period and
+        in-flight cap; no-op until a server installs the callback, and
+        gated per scan by the RAY_TPU_PREFIX_STORE kill switch."""
         cb = self._demote_cb
         if cb is None or not self._prefix_cache:
             return
@@ -1595,52 +1655,91 @@ class LLMEngine:
             limit=min(knobs["limit"], budget),
             min_idle=knobs["min_idle"], watermark=knobs["watermark"],
             exclude=exclude)
-        if not cands:
-            return
-        import jax.numpy as jnp
+        with self._demote_lock:
+            self._demote_inflight += len(cands)
+        self._demote_dispatched += len(cands)
+        self._demote_pending += cands
 
+    def _dispatch_demotes(self) -> int:
+        """Dispatch the pending candidates' device work: one call of
+        the warmed page gather a page of each path, reading the cache
+        the last program RETURNED (the pages are pinned and sealed: the
+        same bytes before or after a window), and hand the pieces to
+        the export thread.  Nothing is fetched here.  Returns the pages
+        gathered."""
+        if not self._demote_pending:
+            return 0
         q = self._ensure_export_thread()
         gen, wv = self._cache_gen, self.weight_version
-        for c in cands:
-            n = c["depth"]
-            ids_p = list(c["blocks"]) + [0] * (_pow2(n) - n)
-            arr = self._gather_kv(self.cache["k"], self.cache["v"],
-                                  jnp.asarray(ids_p, jnp.int32))
-            try:
-                arr.copy_to_host_async()
-            except AttributeError:
-                pass
-            with self._demote_lock:
-                self._demote_inflight += 1
-            self._demote_dispatched += 1
-            q.put(("demote", c, arr, gen, wv))
+        k, v = self.cache["k"], self.cache["v"]
+        pages = 0
+        for c in self._demote_pending:
+            q.put(("demote", c,
+                   [self._gather_page(k, v, np.int32(b))
+                    for b in c["blocks"]], gen, wv))
+            pages += c["depth"]
+        self._demote_pending.clear()
+        return pages
 
-    def _demote_one(self, c: dict, arr, gen: int, wv: int) -> None:
-        """Export-thread half of one demotion: materialize the host KV,
-        publish to the store, then finish the manager-side accounting
-        (pins released either way; the tier-1 leaf drops only when
-        tier 2 really holds the entry AND no weight swap invalidated
-        the KV mid-flight)."""
+    def _fetch_pages(self, pages: list) -> "np.ndarray":
+        """Export-thread fetch of one demoted path: `pages` holds, a
+        page, the gather's 2·L device arrays [kvh, page, hd] (every
+        layer's K, then every layer's V).  The entry's host array
+        [2, L, depth, kvh, page, hd] is allocated once and filled piece
+        by piece, each piece awaited before the next is asked for: at
+        most ONE piece (1 MB at Mistral widths, 32 a page) is on its
+        way at any time, so that is the most a decode window's token
+        fetch can queue behind, and the GIL is held for one piece's
+        copy."""
+        n_l = len(pages[0]) // 2
+        host = np.empty((2, n_l, len(pages)) + tuple(pages[0][0].shape),
+                        pages[0][0].dtype)
+        for p in range(len(pages)):
+            for i, piece in enumerate(pages[p]):
+                host[i // n_l, i % n_l, p] = self._fetch_piece(piece)
+            pages[p] = None          # the page's device copy goes now
+        return host
+
+    @staticmethod
+    def _fetch_piece(piece) -> "np.ndarray":
+        """One blocking device→host fetch (the seam tier-1 stubs)."""
+        return np.asarray(piece)
+
+    def _demote_one(self, c: dict, pages: list, gen: int,
+                    wv: int) -> None:
+        """Export-thread half of one demotion: fetch the path's pages
+        to the host (_fetch_pages), publish to the store, then finish
+        the manager-side accounting (pins released either way; the
+        tier-1 leaf drops only when tier 2 really holds the entry AND
+        no weight swap invalidated the KV mid-flight)."""
         published = False
+        n_pieces = sum(len(p) for p in pages)
+        t0 = time.perf_counter()
         try:
-            host = np.ascontiguousarray(
-                np.asarray(arr)[:, :, :c["depth"]])
+            host = self._fetch_pages(pages)
         except BaseException:  # noqa: BLE001 - device fault
             self.demote_failures += 1
             host = None
+        fetch_s = time.perf_counter() - t0
+        self.demote_fetch_s += fetch_s
+        if host is not None:
+            self.demote_pages += c["depth"]
+            self.demote_bytes += host.nbytes
         if host is not None and gen == self._cache_gen:
             from ray_tpu import failpoints
 
             try:
                 if failpoints.ACTIVE:
                     # The mid-demotion fault window: a crash here dies
-                    # BETWEEN the KV gather and the store registration
+                    # BETWEEN the KV fetch and the store registration
                     # — the chaos shape the accounting must survive.
                     failpoints.fire("serve.prefix_demote")
                 published = bool(self._demote_cb(dict(
                     tokens=c["tokens"], kv=host, hashes=c["hashes"],
                     depth=c["depth"], page=self.page,
-                    weight_version=wv, salt=c.get("salt", 0))))
+                    weight_version=wv, salt=c.get("salt", 0),
+                    pieces=n_pieces,
+                    fetch_ms=round(fetch_s * 1e3, 3))))
             except BaseException:  # noqa: BLE001 - injected faults
                 self.demote_failures += 1
             if not published:
@@ -2110,7 +2209,14 @@ class LLMEngine:
     def _export_loop(self) -> None:
         """Materializes device→host payloads off the engine loop: KV
         migrations (kv_export) and prefix-store demotions both fetch
-        here so the decode loop never blocks on a device→host fetch."""
+        here, so the engine thread never CALLS a page fetch.  That
+        alone does not keep it running: whatever this thread does
+        under the GIL for the length of a page (a copy, a pickle) the
+        engine thread stands for, with the device idle behind it
+        (39–160 ms a demotion before PR 33, PERF.md section 6).
+        Demotion fetches piece by piece (_fetch_pages) and publishes
+        an array the serializer carries out of band; kv_export still
+        fetches one stacked array."""
         while True:
             item = self._export_q.get()
             if item is None:
@@ -2410,6 +2516,9 @@ class LLMEngine:
             self._cur_dev = last                # stays on device
             if self._moe_layers:
                 out[3].copy_to_host_async()
+            # the scan's page gathers queue BEHIND the window the lanes
+            # wait for, on the cache it returned
+            ph.update(demote_pages=self._dispatch_demotes())
             self.decode_steps += k_win
             self.lane_steps_live += len(active) * k_win
             self.attn_steps += attn_steps
@@ -2473,6 +2582,7 @@ class LLMEngine:
         admit phase takes it from there; until then only the periodic
         housekeeping runs, inside the phase."""
         with self._phase("idle", pending=len(self._pending)):
+            self._dispatch_demotes()    # no window to queue behind
             while not self._stop.is_set():
                 # With a head-of-line request waiting on blocks and no
                 # active decode to free them, only finished-and-cached
@@ -2486,6 +2596,7 @@ class LLMEngine:
                         or self._staged_weights is not None):
                     return
                 self._maybe_demote()
+                self._dispatch_demotes()
                 self._flush_metrics()
 
     def _flush_metrics(self, force: bool = False) -> None:
@@ -2509,6 +2620,7 @@ class LLMEngine:
                "lane_steps_live": self.lane_steps_live,
                "attn_steps": self.attn_steps,
                "attn_steps_dense": self.attn_steps_dense,
+               "demote_bytes": self.demote_bytes,
                "preemptions": self.preemptions,
                "completed": self.completed,
                "weight_updates": self.weight_updates,
@@ -2582,6 +2694,13 @@ class LLMEngine:
                    "attn_steps": self.attn_steps,
                    "attn_steps_dense": self.attn_steps_dense,
                    "phase_s": dict(self.phase_s),
+                   # the prefix store's demotion: pages and host bytes
+                   # fetched off the device, and the export thread's
+                   # seconds in those fetches (bytes a second demoted,
+                   # beside phase_s["decode_sync"])
+                   "demote_pages": self.demote_pages,
+                   "demote_bytes": self.demote_bytes,
+                   "demote_fetch_s": round(self.demote_fetch_s, 6),
                    "prefill_true_tokens": self.prefill_tokens,
                    "prefill_padded_tokens": self.prefill_padded_tokens,
                    "prefill_programs": self.prefill_programs,

@@ -489,7 +489,8 @@ class PrefixStoreClient:
         seal the subtree's host KV into an arena object and register it
         with the directory.  Returns True when tier 2 holds the entry —
         the engine's cue that evicting the tier-1 leaf loses nothing.
-        entry: {tokens, kv, hashes, depth, page, weight_version}.
+        entry: {tokens, kv, hashes, depth, page, weight_version, and
+        the fetch's `pieces` / `fetch_ms` for the span}.
         (The serve.prefix_demote failpoint fires on the ENGINE side of
         this callback — llm.py _demote_one — so the fault window covers
         any publisher.)"""
@@ -539,7 +540,10 @@ class PrefixStoreClient:
         if tracing.ENABLED:
             tracing.emit("serve.prefix_demote", t0, attrs={
                 "bytes": nbytes, "depth": int(entry["depth"]),
-                "weight_version": version, "ok": ok})
+                "weight_version": version, "ok": ok,
+                # how the engine's export thread fetched the KV
+                "pieces": entry.get("pieces"),
+                "fetch_ms": entry.get("fetch_ms")})
         if not ok:
             del ref
             with self._lock:

@@ -427,6 +427,50 @@ def test_decode_step_loop_writes_nothing_the_size_of_a_weight(
     assert _pool_copies(hlo, pool) == []
 
 
+@pytest.mark.parametrize("widths,engine,n_layers", [
+    (dict(dim=4096, n_heads=32, n_kv_heads=8, ffn_dim=14336),
+     dict(max_batch=32, max_len=2048, kv_pages=129), 16),
+    (dict(dim=6144, n_heads=48, n_kv_heads=8, ffn_dim=16384),
+     dict(max_batch=8, max_len=8192, kv_pages=161), 8),
+], ids=["mistral7b", "codestral22b"])
+def test_demotion_page_gather_reads_one_page_a_layer(topo, one_chip, widths,
+                                                     engine, n_layers):
+    """The ONE program the prefix store's demotion runs (a call a page,
+    PERF.md section 6, PR 33), at the benchmark's widths and depths: it
+    writes 2·L arrays of [kvh, page, hd] and nothing else; no page pool
+    is copied, re-laid-out or even matched in size by anything it
+    produces.  An engine nobody installed a callback on has none."""
+    from ray_tpu.models import llama
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg = llama.LlamaConfig(vocab_size=32768, n_layers=n_layers,
+                            max_seq=engine["max_len"], **widths)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: llama.init_params(jax.random.PRNGKey(0),
+                                                 cfg)))
+    eng = LLMEngine(cfg, params, paged=True, page_size=512,
+                    steps_per_sync=8, **engine)
+    assert eng._gather_page is None
+    c = eng._lower_page_gather(sharding=one_chip).compile()
+    piece = (cfg.n_kv_heads, 512, cfg.head_dim)
+    outs = jax.tree.leaves(c.out_info)
+    assert [o.shape for o in outs] == [piece] * (2 * n_layers)
+    piece_bytes = math.prod(piece) * 2
+    pool = engine["kv_pages"] * math.prod(piece)
+    mem = c.memory_analysis()
+    assert mem.output_size_in_bytes <= 2 * n_layers * (piece_bytes + 1024)
+    assert mem.temp_size_in_bytes <= 2 * n_layers * piece_bytes
+    hlo = c.as_text()
+    assert _pool_copies(hlo, pool // 2) == []
+    big = [(m.group(1), m.group(3))
+           for lines in _computations(hlo).values() for ln in lines
+           for m in [_INSTR.match(ln)]
+           if m and m.group(3) not in _NO_WRITE
+           and any(n >= pool // 2 for n, _ in _arrays(m.group(2)))]
+    assert big == []
+
+
 def test_decode_step_loop_reads_the_attention_plan_and_builds_none(
         topo, one_chip, compiled_kernels, monkeypatch):
     """Mistral-7B-d16 as the benchmark serves it: the window's work list

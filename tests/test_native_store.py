@@ -45,6 +45,50 @@ def test_zero_copy_numpy(arena):
     assert len(frames) >= 2
 
 
+@pytest.mark.parametrize("dtype", ["bfloat16", "float8_e4m3fn",
+                                   "float8_e5m2"])
+@pytest.mark.parametrize("nested", [False, True], ids=["bare", "nested"])
+def test_zero_copy_numpy_of_a_registered_dtype(arena, dtype, nested):
+    """ml_dtypes' arrays export no buffer, so numpy pickles them IN the
+    stream (two copies under the GIL: 34 MB of bfloat16 KV stalled the
+    serving engine's thread ~100 ms a put, PERF.md section 6, PR 33).
+    The serializer carries them out of band like any other array: the
+    stream stays small, the payload is a view, the bytes come back
+    equal and alias the arena."""
+    import ml_dtypes
+
+    from ray_tpu._private.serialization import deserialize, serialize
+
+    dt = np.dtype(getattr(ml_dtypes, dtype))
+    arr = (np.arange(3 * 512 * 1024) % 251).astype(np.float32).astype(
+        dt).reshape(3, 512, 1024)
+    sv = serialize({"kv": [arr, 7]} if nested else arr)
+    assert len(sv.frames) == 2 and len(sv.frames[0]) < 1024
+    assert isinstance(sv.frames[1], memoryview)      # no copy on the way in
+    assert sv.frames[1].nbytes == arr.nbytes
+    assert arena.put_frames(b"D" * 16, sv.frames)
+    out = deserialize(arena.get_frames(b"D" * 16))
+    out = out["kv"][0] if nested else out
+    assert out.dtype == dt and out.shape == arr.shape
+    assert out.tobytes() == arr.tobytes()
+    assert not out.flags.writeable and not out.flags.owndata
+
+
+@pytest.mark.parametrize("shape", ["scalar", "empty", "transposed",
+                                   "strided"])
+def test_registered_dtype_odd_shapes_round_trip(shape):
+    import ml_dtypes
+
+    from ray_tpu._private.serialization import deserialize, serialize
+
+    base = np.arange(12).astype(ml_dtypes.bfloat16)
+    arr = {"scalar": base[3].reshape(()), "empty": base[:0].reshape(0, 3),
+           "transposed": base.reshape(3, 4).T, "strided": base[::2]}[shape]
+    out = deserialize(serialize(arr).frames)
+    assert out.dtype == arr.dtype and out.shape == arr.shape
+    assert out.tobytes() == arr.tobytes()
+
+
 def test_no_implicit_eviction_when_full(arena):
     """A full arena refuses new puts instead of silently dropping sealed
     (referenced) objects — the StoreRunner spills to disk on failure

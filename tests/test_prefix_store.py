@@ -272,6 +272,324 @@ def test_demote_failpoint_releases_pins(small):
         eng.stop()
 
 
+# ---------------------------------- how a demoted path leaves the device
+def _prompt(depth: int, salt: int = 0) -> list:
+    """A prompt whose finished request leaves `depth` sealed pages."""
+    return [(i * 11 + 5 + 17 * salt) % 127 + 1 for i in range(depth * 8 + 3)]
+
+
+def _record_scans(eng) -> dict:
+    """{leaf hash: blocks} of every candidate the engine's scans take."""
+    scanned, scan = {}, eng._mgr.demote_scan
+
+    def demote_scan(**kw):
+        cands = scan(**kw)
+        scanned.update({c["hash"]: list(c["blocks"]) for c in cands})
+        return cands
+
+    eng._mgr.demote_scan = demote_scan
+    return scanned
+
+
+def _wait(cond, timeout=30.0) -> bool:
+    deadline = time.time() + timeout
+    while not cond() and time.time() < deadline:
+        time.sleep(0.01)
+    return bool(cond())
+
+
+@pytest.mark.parametrize("depth", [1, 2, 3])
+def test_demoted_entry_bit_equal_to_stacked_gather(small, depth):
+    """The entry the callback receives, fetched a page a call and a
+    piece a fetch, is bit-equal to ONE stacked `_gather_kv` of the same
+    blocks ([2, L, depth, kvh, page, hd], what demotion fetched before
+    PR 33), and a graft of it serves the same tokens."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    prompt = _prompt(depth)
+    a = _engine(small, name="a")
+    try:
+        ref = a.generate(prompt, max_new_tokens=4)
+        scanned = _record_scans(a)
+        store = _demote_all(a)
+        assert _wait(lambda: a._demote_inflight == 0)
+        (h, entry), = store.items()
+        assert entry["depth"] == depth
+        assert entry["pieces"] == depth * 2 * 2      # 2 layers, K and V
+        # the loop is idle and nothing was admitted since: the pool
+        # still holds what the scan pinned
+        want = np.asarray(a._gather_kv(
+            a.cache["k"], a.cache["v"],
+            jnp.asarray(scanned[h], jnp.int32)))
+        assert entry["kv"].shape == want.shape == (2, 2, depth, 2, 8, 16)
+        assert entry["kv"].dtype == want.dtype
+        assert entry["kv"].flags["C_CONTIGUOUS"]
+        assert entry["kv"].tobytes() == want.tobytes()
+        loop = a.stats()["loop"]
+        assert loop["demote_pages"] == depth
+        assert loop["demote_bytes"] == want.nbytes
+        assert loop["demote_fetch_s"] > 0.0
+        a._mgr.check()
+    finally:
+        a.stop()
+    b = _engine(small, name="b")
+    try:
+        out = b.kv_graft(entry["tokens"], entry["kv"], kv_len=depth * 8,
+                         weight_version=0).result(timeout=120)
+        assert out["grafted"] == depth
+        assert b.generate(prompt, max_new_tokens=4)["tokens"] \
+            == ref["tokens"]
+        assert b._mgr.hit_tokens >= depth * 8
+        b._mgr.check()
+    finally:
+        b.stop()
+
+
+@pytest.fixture
+def backend_compiles():
+    """Counts this process's XLA backend compiles, on any thread."""
+    from jax._src import monitoring
+
+    seen = []
+
+    def listen(event, duration, **kw):
+        if event.endswith("backend_compile_duration"):
+            seen.append(event)
+
+    monitoring.register_event_duration_secs_listener(listen)
+    yield seen
+    monitoring.unregister_event_duration_listener(listen)
+
+
+@pytest.mark.parametrize("depth", [1, 2, 3])
+def test_demotion_meets_no_new_program_after_warmup(small, depth,
+                                                    backend_compiles):
+    """ONE page gather, compiled when the callback is installed: after
+    warmup() a demotion of any depth compiles nothing (before PR 33 each
+    power-of-two depth was a new `_gather_kv` program, compiled on the
+    engine thread inside a decode window)."""
+    eng = _engine(small)
+    try:
+        assert eng._gather_page is None
+        n0 = len(backend_compiles)
+        # installed, but nothing is cold yet
+        eng.set_prefix_store(lambda e: True, min_idle=1 << 30,
+                             watermark_frac=0.0)
+        assert len(backend_compiles) == n0 + 1
+        program = eng._gather_page
+        eng.warmup(buckets=[16, 32])
+        eng.generate(_prompt(depth), max_new_tokens=4)
+        n1 = len(backend_compiles)
+        store = _demote_all(eng)
+        assert _wait(lambda: eng._demote_inflight == 0)
+        assert [e["depth"] for e in store.values()] == [depth]
+        assert len(backend_compiles) == n1
+        assert eng._gather_page is program
+        assert eng._gather_kv._cache_size() == 0    # demotion's old program
+    finally:
+        eng.stop()
+
+
+@pytest.mark.parametrize("case", ["no_callback", "prefix_cache_off"])
+def test_engine_without_demotion_builds_no_page_gather(small, case,
+                                                       backend_compiles):
+    eng = _engine(small, prefix_cache=(case != "prefix_cache_off"))
+    try:
+        n0 = len(backend_compiles)
+        eng.set_prefix_store(
+            None if case == "no_callback" else (lambda e: True))
+        eng.warmup(buckets=[16])
+        assert eng._gather_page is None
+        assert eng._gather_page_jit._cache_size() == 0
+        eng.set_prefix_store(None)
+        assert len(backend_compiles) > n0           # warmup's programs
+        assert eng.stats()["loop"]["demote_pages"] == 0
+    finally:
+        eng.stop()
+
+
+class _Piece:
+    """Stands for one gathered [kvh, page, hd] device array and records
+    every way of asking for its bytes."""
+
+    def __init__(self, arr, log):
+        self.arr, self.log = arr, log
+        self.shape, self.dtype = arr.shape, arr.dtype
+
+    def copy_to_host_async(self):
+        self.log.append("async")
+
+    def __array__(self, *a, **kw):
+        self.log.append("array")
+        raise AssertionError("a piece was fetched around _fetch_piece")
+
+
+def _stub_fetch(eng, gate=None, window_s=0.0):
+    """Route the engine's gathers and fetches through recorders: returns
+    (events, state) where events holds ("window" | "gather", iter) in
+    dispatch order and state["max"] the most pieces ever outstanding.
+    `gate` holds every fetch until set; a window takes `window_s` more."""
+    import threading
+
+    import numpy as np
+
+    events, log = [], []
+    state = {"out": 0, "max": 0, "fetched": 0, "log": log,
+             "window": threading.Event()}
+    gather = eng._gather_page
+
+    def gather_page(k, v, pid):
+        events.append(("gather", eng._iter))
+        return tuple(_Piece(x, log) for x in gather(k, v, pid))
+
+    def fetch_piece(piece):
+        state["out"] += 1
+        state["max"] = max(state["max"], state["out"])
+        if gate is not None:
+            assert gate.wait(30.0)
+        out = np.asarray(piece.arr)
+        state["fetched"] += 1
+        state["out"] -= 1
+        return out
+
+    eng._gather_page, eng._fetch_piece = gather_page, fetch_piece
+    for k, decode in list(eng._decode_fns.items()):
+        def window(*a, _decode=decode):
+            events.append(("window", eng._iter))
+            state["window"].set()
+            time.sleep(window_s)
+            return _decode(*a)
+        eng._decode_fns[k] = window
+    return events, state
+
+
+def test_gathers_queue_behind_the_window_one_piece_on_its_way(small):
+    """A scan that finds candidates while lanes decode dispatches their
+    page gathers AFTER that iteration's decode window, and the export
+    thread asks for one piece at a time: never two outstanding, never a
+    whole-path `copy_to_host_async`."""
+    eng = _engine(small)
+    try:
+        for d in (1, 2, 3):
+            eng.generate(_prompt(d, salt=d), max_new_tokens=4)
+        store = {}
+        eng.set_prefix_store(lambda e: True, min_idle=1 << 30,
+                             watermark_frac=0.0)     # compiled, idle
+        events, state = _stub_fetch(eng, window_s=0.02)
+        # 28 windows of 20 ms and more: live lanes until the scans are done
+        fut = eng.submit(_prompt(1, salt=9)[:7], max_new_tokens=110)
+        assert state["window"].wait(30.0)
+
+        def cb(entry):
+            store[entry["hashes"][-1]] = entry
+            return True
+
+        eng.set_prefix_store(cb, min_idle=0, period_s=0.001,
+                             watermark_frac=0.0, limit=4, max_inflight=4)
+        fut.result(timeout=120)
+        assert _wait(lambda: len(store) >= 3)
+        eng.set_prefix_store(cb, min_idle=1 << 30, watermark_frac=0.0)
+        assert _wait(lambda: not eng._demote_inflight)
+        # the three cold paths, and perhaps the finished request's own
+        depths = sorted(e["depth"] for e in store.values())
+        assert depths[:3] == [1, 2, 3]
+        assert state["max"] == 1 and state["log"] == []
+        assert state["fetched"] == sum(depths) * 2 * 2
+        by_iter: dict = {}
+        for what, it in events:
+            by_iter.setdefault(it, []).append(what)
+        behind = [seq for seq in by_iter.values()
+                  if "gather" in seq and "window" in seq]
+        assert behind, "no scan met a live window: " + repr(by_iter)
+        for seq in behind:
+            assert seq[0] == "window" and seq.count("window") == 1, seq
+        eng._mgr.check()
+    finally:
+        eng.stop()
+
+
+@pytest.mark.parametrize("case", ["weight_swap", "publish_refusal",
+                                  "failpoint"])
+def test_demotion_cut_mid_fetch_finishes_once_and_unpins(small, case):
+    """Whatever ends a demotion between its first piece and its publish
+    (the policy swapped under it; the store refuses the entry; the
+    serve.prefix_demote failpoint, which still fires AFTER the fetch and
+    BEFORE the publish), demote_finish runs exactly once for the path,
+    nothing is dropped from tier 1 and every pin is released."""
+    import threading
+
+    import jax
+
+    from ray_tpu._private import failpoints
+    from ray_tpu.models import llama
+
+    cfg, _params = small
+    eng = _engine(small)
+    try:
+        eng.generate(_prompt(2), max_new_tokens=4)
+        cached = eng._mgr.cached_count()
+        assert cached == 2
+        eng.set_prefix_store(lambda e: True, min_idle=1 << 30,
+                             watermark_frac=0.0)
+        gate = threading.Event()
+        _events, state = _stub_fetch(eng, gate)
+        finished, finish = [], eng._mgr.demote_finish
+
+        def demote_finish(leaf, blocks, drop):
+            finished.append((list(blocks), drop))
+            return finish(leaf, blocks, drop=drop)
+
+        eng._mgr.demote_finish = demote_finish
+        published, fired_mid_fetch = [], None
+        if case == "failpoint":
+            failpoints.configure("serve.prefix_demote=nth:1+error")
+
+        def cb(entry):
+            published.append(entry)
+            return case != "publish_refusal"
+
+        eng.set_prefix_store(cb, min_idle=0, period_s=0.01,
+                             watermark_frac=0.0, limit=1, max_inflight=1)
+        eng._wake.set()
+        # the export thread holds the first piece: mid-fetch
+        assert _wait(lambda: state["out"] == 1)
+        assert eng._demote_inflight == 1 and not finished
+        assert eng._mgr.evictable_count() == 0          # pinned
+        if case == "weight_swap":
+            eng.update_weights(
+                llama.init_params(jax.random.PRNGKey(99), cfg), version=1)
+            eng._wake.set()
+            assert _wait(lambda: eng.weight_version == 1)
+        if case == "failpoint":
+            fired_mid_fetch = failpoints.counters()[
+                "serve.prefix_demote"]["fired"]
+        gate.set()
+        assert _wait(lambda: len(finished) >= 1
+                     and eng._demote_inflight == 0)
+        # no later scan takes the path again before we look
+        eng.set_prefix_store(None)
+        assert state["fetched"] == 2 * 2 * 2             # the whole path
+        assert len(finished) == 1 and finished[0][1] is False
+        assert len(published) == (1 if case == "publish_refusal" else 0)
+        assert eng.demote_published == 0 and eng._mgr.demotions == 0
+        assert eng.demote_failures == (1 if case == "failpoint" else 0)
+        if case == "failpoint":
+            assert fired_mid_fetch == 0    # not before the fetch ended
+            assert failpoints.counters()[
+                "serve.prefix_demote"]["fired"] == 1
+        eng._mgr.check()
+        if case == "weight_swap":          # the swap flushed the tree
+            assert eng._mgr.available() == eng._mgr.n_blocks
+        else:
+            assert eng._mgr.cached_count() == cached
+            assert eng._mgr.evictable_count() == cached
+        assert len(eng.generate(PROMPT, max_new_tokens=3)["tokens"]) == 3
+    finally:
+        failpoints.reset()
+        eng.stop()
+
+
 # ------------------------------------------------------------- server
 def _server(small, directory, seed=3, **extra):
     from ray_tpu.serve.llm import LLMServer
