@@ -13,11 +13,17 @@ from ray_tpu.models.vit import ViTConfig, vit_configs
 # asks here and names no model itself.  Every serving module gives, under
 # ONE signature each:
 #   init_params(key, cfg); init_paged_cache(cfg, batch, n_pages, page) ->
-#     {"k", "v", "pos", "state"}: the page pool of the layers that keep KV
-#     and `state`, a pytree of whatever a lane carries that no page holds
-#     (an empty list if nothing), which the engine never looks inside;
+#     {"pos", "state", and the page pool under names of the model's own}:
+#     each pool entry a list of [n_pages, heads, page, width] leaves, one
+#     a layer that keeps rows ({"k", "v"}: a K and a V pool; {"latent"}:
+#     one row a token that every head shares), from which the engine
+#     takes its tails' and merges' shapes; and `state`, a pytree of
+#     whatever a lane carries that no page holds (an empty list if
+#     nothing), which the engine never looks inside;
 #   serve_prefill(params, tokens, cfg, true_lens, lora) -> (hidden, ks,
-#     vs, state taken at each row's TRUE length, counts);
+#     vs, state taken at each row's TRUE length, counts); ks and vs are
+#     the rows for the pool, handed unopened to serve_scatter (a latent
+#     pool's rows and an empty list);
 #   serve_scatter(cache, ks, vs, state, page_ids, rows, slots, true_lens,
 #     aligned=True) -> cache;
 #   serve_decode_step(params, pages, tails, state, tokens, pos,
@@ -27,12 +33,15 @@ from ray_tpu.models.vit import ViTConfig, vit_configs
 #   project_logits(params, h); lane_state_layers(cfg) (0: the prefix
 #     cache may stay on); routed_layers(cfg): the rows of `counts`, int32
 #     [routed layers, 3] = experts that held a row, the largest load,
-#     assignments computed (0 rows: nothing is counted);
+#     assignments computed (0 rows: nothing is counted; a config with
+#     routed layers has `top_k`); `CACHE_KIND`, the word
+#     stats()["cache"]["kind"] gives for the pool ("kv": K and V rows);
 # and `SERVING_CAPS`: the optional capabilities it has, under their own
 # names ("prefix": prefill_with_prefix; "lora": the adapter hooks;
 # "kv_transfer": KV export/import/graft).
 _SERVING = {"LlamaConfig": "ray_tpu.models.llama",
-            "Lfm2MoeConfig": "ray_tpu.models.lfm2"}
+            "Lfm2MoeConfig": "ray_tpu.models.lfm2",
+            "MlaMoeConfig": "ray_tpu.models.mla_moe"}
 
 
 def serving_model(cfg):

@@ -66,12 +66,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models import llama
+from ray_tpu.models import llama, routed
 from ray_tpu.models.llama import apply_rope, attention, embed_lookup, rmsnorm
-from ray_tpu.ops.grouped_matmul import gmm
+from ray_tpu.models.routed import route
 from ray_tpu.ops.rope import rope_frequencies
 
 SERVING_CAPS: frozenset = frozenset()
+CACHE_KIND = "kv"
 ATTN = "full_attention"
 
 
@@ -189,67 +190,11 @@ def project_logits(params: dict, h: jnp.ndarray) -> jnp.ndarray:
 
 
 # ------------------------------------------------------------ the layers
-def route(h2, lp, cfg: Lfm2MoeConfig):
-    """h2 [T, d] -> (experts [T, k] int32, weights [T, k] float32)."""
-    with jax.named_scope("moe_router"):
-        s = jax.nn.sigmoid(jnp.dot(
-            h2.astype(jnp.float32), lp["router"].astype(jnp.float32),
-            precision=lax.Precision.HIGHEST))
-        sel = s + lp["expert_bias"] if cfg.use_expert_bias else s
-        _, idx = lax.top_k(sel, cfg.top_k)
-        # s at the selected (a masked sum: the gather form takes the
-        # TPU compiler seconds a program)
-        chosen = idx[..., None] == jnp.arange(cfg.n_experts)
-        wts = jnp.sum(jnp.where(chosen, s[:, None, :], 0.0), axis=-1)
-        if cfg.norm_topk_prob:
-            wts = wts / (jnp.sum(wts, axis=-1, keepdims=True) + 1e-6)
-        return idx.astype(jnp.int32), wts * cfg.routed_scaling
-
-
 def routed_ffn(h2, lp, cfg: Lfm2MoeConfig, live=None,
                experts: tuple[int, int] | None = None):
-    """The routed experts' part of FF for rows h2 [T, d].
-
-    `experts` = (lo, hi): the range of experts whose weights `lp` holds
-    (`w13` [hi-lo, d, 2f], `w2` [hi-lo, f, d]); default all.  The result
-    is THEIR part of the sum, so the parts of disjoint ranges add up to
-    the layer.  `live` [T] bool: rows that hold a request; the others are
-    routed nowhere.  Returns (y [T, d], counts int32 [3]: experts of the
-    range that hold a row, the largest load, assignments computed)."""
-    T, d = h2.shape
-    k, f = cfg.top_k, cfg.moe_ffn_dim
-    lo, hi = experts or (0, cfg.n_experts)
-    G = hi - lo
-    idx, wts = route(h2, lp, cfg)
-    with jax.named_scope("moe_experts"):
-        flat = idx.reshape(T * k)
-        held = (flat >= lo) & (flat < hi)
-        if live is not None:
-            held &= jnp.repeat(live, k)
-        group = jnp.where(held, flat - lo, G)     # G: nobody's, goes last
-        # A counting sort, by group and then by row (a TPU `sort` of
-        # 65,536 keys is a bitonic network that takes the compiler 10 s):
-        # an assignment's place is its group's offset plus how many of
-        # the group came before it.
-        mine = (group[:, None] == jnp.arange(G + 1)[None, :]).astype(
-            jnp.int32)
-        before = jnp.cumsum(mine, axis=0)                  # [T*k, G+1]
-        n_all = before[-1]
-        place = (jnp.cumsum(n_all) - n_all)[group] + jnp.take_along_axis(
-            before, group[:, None], axis=1)[:, 0] - 1
-        order = jnp.zeros((T * k,), jnp.int32).at[place].set(
-            jnp.arange(T * k, dtype=jnp.int32))
-        sizes = n_all[:G]
-        rows = h2[order // k]                     # [T*k, d] by group
-        h13 = gmm(rows, lp["w13"], sizes)
-        act = (jax.nn.silu(h13[:, :f].astype(jnp.float32))
-               .astype(h2.dtype) * h13[:, f:])
-        y = gmm(act, lp["w2"], sizes)             # rows of nobody: 0
-        y = y[place].reshape(T, k, d).astype(jnp.float32)
-        out = jnp.sum(y * wts[..., None], axis=1).astype(h2.dtype)
-        counts = jnp.stack([jnp.sum(sizes > 0, dtype=jnp.int32),
-                            jnp.max(sizes), jnp.sum(sizes)])
-    return out, counts
+    """`routed.routed_ffn` under THIS module's `route` (looked up at the
+    call, so a test's control can stand in for it)."""
+    return routed.routed_ffn(h2, lp, cfg, live, experts, route_fn=route)
 
 
 def ffn(x, lp, lid: int, cfg: Lfm2MoeConfig, live=None):
@@ -277,11 +222,6 @@ def _conv_taps(zs, conv_w):
 def _qk_norm(q, k, lp, cfg: Lfm2MoeConfig):
     return (rmsnorm(q, lp["q_norm"], cfg.norm_eps),
             rmsnorm(k, lp["k_norm"], cfg.norm_eps))
-
-
-def _counts(per_layer: list) -> jnp.ndarray:
-    return (jnp.stack(per_layer) if per_layer
-            else jnp.zeros((0, 3), jnp.int32))
 
 
 # ---------------------------------------------------------------- prefill
@@ -347,7 +287,7 @@ def prefill(params: dict, tokens: jnp.ndarray, cfg: Lfm2MoeConfig,
         if cnt is not None:
             counts.append(cnt)
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    return x, ks, vs, state, _counts(counts)
+    return x, ks, vs, state, routed.stack_counts(counts)
 
 
 # ------------------------------------------------------------ paged cache
@@ -448,7 +388,7 @@ def decode_step_paged(params: dict, pages: dict, tails: dict, state: list,
             counts.append(cnt)
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = project_logits(params, x).astype(jnp.float32)
-    return logits, {"k": new_tk, "v": new_tv}, new_state, _counts(counts)
+    return logits, {"k": new_tk, "v": new_tv}, new_state, routed.stack_counts(counts)
 
 
 # the serving seam's names (models/__init__.py)

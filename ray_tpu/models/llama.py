@@ -111,6 +111,7 @@ def llama_configs() -> dict[str, LlamaConfig]:
 # the required functions (at the end of the file): every optional
 # capability.
 SERVING_CAPS = frozenset({"prefix", "lora", "kv_transfer"})
+CACHE_KIND = "kv"
 serving_configs = llama_configs
 
 
@@ -600,18 +601,14 @@ def init_paged_kv_cache(cfg: LlamaConfig, batch: int, n_pages: int,
             "pos": jnp.zeros((batch,), jnp.int32)}
 
 
-@functools.partial(jax.named_call, name="kv_write")
-def scatter_prefill_pages(cache: dict, ks, vs, page_ids: jnp.ndarray,
-                          rows: jnp.ndarray, slots: jnp.ndarray,
-                          true_lens: jnp.ndarray,
-                          aligned: bool = True) -> dict:
-    """Write a prefill wave's K/V into the page pool.
+def scatter_rows(pool, new, page_ids: jnp.ndarray, rows: jnp.ndarray,
+                 aligned: bool = True):
+    """Write a prefill wave's rows into ONE leaf of a page pool.
 
-    ks/vs: [L, W, P, kvh, hd] from prefill(); page_ids/rows: [W, P]
-    (page id + in-page row per token position; positions past a slot's
-    allocation point at the trash page).  Returns the updated cache.
-    Duplicate wave-padding rows write identical data, so scatter order
-    is irrelevant.
+    pool [n_pages, kvh, page, w]; new [W, P, kvh, w]; page_ids/rows:
+    [W, P] (page id + in-page row per token position; positions past a
+    slot's allocation point at the trash page).  Duplicate wave-padding
+    rows write identical data, so scatter order is irrelevant.
 
     Fast paths write PAGE-ALIGNED BLOCKS with a single [n] advanced
     index on the pool's page axis: the original [W, P] per-token
@@ -623,41 +620,38 @@ def scatter_prefill_pages(cache: dict, ks, vs, page_ids: jnp.ndarray,
     general fallback — and is FORCED with aligned=False (prefix-cache
     suffix waves start mid-span at per-request offsets, so rows don't
     begin at 0)."""
-    nk = len(cache["k"])
     W, P = page_ids.shape
-    page = cache["k"][0].shape[2]
+    page = pool.shape[2]
     if not aligned:
-        k = [cache["k"][li].at[page_ids, :, rows].set(ks[li])
-             for li in range(nk)]
-        v = [cache["v"][li].at[page_ids, :, rows].set(vs[li])
-             for li in range(nk)]
-    elif P <= page:
+        return pool.at[page_ids, :, rows].set(new)
+    if P <= page:
         # One (partial) page per wave member: block-write rows [0, P).
-        pids0 = page_ids[:, 0]
-        k = [cache["k"][li].at[pids0, :, :P, :].set(
-                 ks[li].transpose(0, 2, 1, 3)) for li in range(nk)]
-        v = [cache["v"][li].at[pids0, :, :P, :].set(
-                 vs[li].transpose(0, 2, 1, 3)) for li in range(nk)]
-    elif P % page == 0:
+        return pool.at[page_ids[:, 0], :, :P, :].set(
+            new.transpose(0, 2, 1, 3))
+    if P % page == 0:
         # m whole pages per wave member: flatten to W*m full-page writes.
         m = P // page
         flat = page_ids[:, ::page].reshape(W * m)
+        kvh, w = new.shape[2], new.shape[3]
+        return pool.at[flat].set(
+            new.reshape(W, m, page, kvh, w).transpose(0, 1, 3, 2, 4)
+            .reshape(W * m, kvh, page, w))
+    return pool.at[page_ids, :, rows].set(new)
 
-        def blockify(a):
-            kvh, hd = a.shape[2], a.shape[3]
-            return a.reshape(W, m, page, kvh, hd) \
-                    .transpose(0, 1, 3, 2, 4) \
-                    .reshape(W * m, kvh, page, hd)
 
-        k = [cache["k"][li].at[flat].set(blockify(ks[li]))
-             for li in range(nk)]
-        v = [cache["v"][li].at[flat].set(blockify(vs[li]))
-             for li in range(nk)]
-    else:
-        k = [cache["k"][li].at[page_ids, :, rows].set(ks[li])
-             for li in range(nk)]
-        v = [cache["v"][li].at[page_ids, :, rows].set(vs[li])
-             for li in range(nk)]
+@functools.partial(jax.named_call, name="kv_write")
+def scatter_prefill_pages(cache: dict, ks, vs, page_ids: jnp.ndarray,
+                          rows: jnp.ndarray, slots: jnp.ndarray,
+                          true_lens: jnp.ndarray,
+                          aligned: bool = True) -> dict:
+    """Write a prefill wave's K/V into the page pool, a leaf at a time
+    (`scatter_rows`): ks/vs [L, W, P, kvh, hd] from prefill().  Returns
+    the updated cache."""
+    nk = len(cache["k"])
+    k = [scatter_rows(cache["k"][li], ks[li], page_ids, rows, aligned)
+         for li in range(nk)]
+    v = [scatter_rows(cache["v"][li], vs[li], page_ids, rows, aligned)
+         for li in range(nk)]
     pos = cache["pos"].at[slots].set(true_lens)
     return {"k": k, "v": v, "pos": pos}
 

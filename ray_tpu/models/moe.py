@@ -2,7 +2,7 @@
 capacity-DROPPING routed layer for TRAINING (`forward`, `loss_fn`; no
 prefill, decode or cache).  Tokens past an expert's capacity are dropped,
 so its logits depend on the batch.  The SERVED routed layer, which drops
-nothing, is `models/lfm2.routed_ffn` over `ops/grouped_matmul.gmm`.
+nothing, is `models/routed.routed_ffn` over `ops/grouped_matmul.gmm`.
 
 The reference has no MoE anywhere (SURVEY §2.4: expert parallelism ABSENT
 — greenfield for this framework).  Design follows the GShard/Switch TPU

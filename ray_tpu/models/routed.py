@@ -1,0 +1,109 @@
+"""The served routed-expert layer, shared by every serving module that
+has one (`models/lfm2.py`, `models/mla_moe.py`): the router, the experts'
+part over the dropless grouped matmul (`ops/grouped_matmul.gmm`) for the
+range of experts a chip holds, and the shared expert beside them.
+
+`cfg` is the serving module's config; it gives `n_experts` (the ROUTER's
+width, every expert of the deployment), `top_k`, `moe_ffn_dim`,
+`use_expert_bias`, `norm_topk_prob` and `routed_scaling`.  The router
+scores every expert and selects and normalises over all of them whatever
+range is held: a chip that holds experts lo..hi computes THEIR part of
+the sum, and the parts of disjoint ranges add up to the layer.
+
+Device-side names: `moe_router`, `moe_experts`, `shared_expert`; the
+grouped matmul's kernel is `moe_gmm`.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ray_tpu.ops.grouped_matmul import gmm
+
+
+def route(h2, lp, cfg):
+    """h2 [T, d] -> (experts [T, k] int32, weights [T, k] float32)."""
+    with jax.named_scope("moe_router"):
+        s = jax.nn.sigmoid(jnp.dot(
+            h2.astype(jnp.float32), lp["router"].astype(jnp.float32),
+            precision=lax.Precision.HIGHEST))
+        sel = s + lp["expert_bias"] if cfg.use_expert_bias else s
+        _, idx = lax.top_k(sel, cfg.top_k)
+        # s at the selected (a masked sum: the gather form takes the
+        # TPU compiler seconds a program)
+        chosen = idx[..., None] == jnp.arange(cfg.n_experts)
+        wts = jnp.sum(jnp.where(chosen, s[:, None, :], 0.0), axis=-1)
+        if cfg.norm_topk_prob:
+            wts = wts / (jnp.sum(wts, axis=-1, keepdims=True) + 1e-6)
+        return idx.astype(jnp.int32), wts * cfg.routed_scaling
+
+
+def routed_ffn(h2, lp, cfg, live=None,
+               experts: tuple[int, int] | None = None, route_fn=None):
+    """The routed experts' part of FF for rows h2 [T, d].
+
+    `experts` = (lo, hi): the range of experts whose weights `lp` holds
+    (`w13` [hi-lo, d, 2f], `w2` [hi-lo, f, d]); default all.  The result
+    is THEIR part of the sum, so the parts of disjoint ranges add up to
+    the layer.  `live` [T] bool: rows that hold a request; the others are
+    routed nowhere.  `route_fn`: the caller's router (default `route`;
+    a serving module passes its own name for it, so that a test's
+    control can stand in for that module's router alone).  Returns
+    (y [T, d], counts int32 [3]: experts of the range that hold a row,
+    the largest load, assignments computed)."""
+    T, d = h2.shape
+    k, f = cfg.top_k, cfg.moe_ffn_dim
+    lo, hi = experts or (0, cfg.n_experts)
+    G = hi - lo
+    idx, wts = (route_fn or route)(h2, lp, cfg)
+    with jax.named_scope("moe_experts"):
+        flat = idx.reshape(T * k)
+        held = (flat >= lo) & (flat < hi)
+        if live is not None:
+            held &= jnp.repeat(live, k)
+        group = jnp.where(held, flat - lo, G)     # G: nobody's, goes last
+        # A counting sort, by group and then by row (a TPU `sort` of
+        # 65,536 keys is a bitonic network that takes the compiler 10 s):
+        # an assignment's place is its group's offset plus how many of
+        # the group came before it.
+        mine = (group[:, None] == jnp.arange(G + 1)[None, :]).astype(
+            jnp.int32)
+        before = jnp.cumsum(mine, axis=0)                  # [T*k, G+1]
+        n_all = before[-1]
+        place = (jnp.cumsum(n_all) - n_all)[group] + jnp.take_along_axis(
+            before, group[:, None], axis=1)[:, 0] - 1
+        order = jnp.zeros((T * k,), jnp.int32).at[place].set(
+            jnp.arange(T * k, dtype=jnp.int32))
+        sizes = n_all[:G]
+        rows = h2[order // k]                     # [T*k, d] by group
+        h13 = gmm(rows, lp["w13"], sizes)
+        act = (jax.nn.silu(h13[:, :f].astype(jnp.float32))
+               .astype(h2.dtype) * h13[:, f:])
+        y = gmm(act, lp["w2"], sizes)             # rows of nobody: 0
+        y = y[place].reshape(T, k, d).astype(jnp.float32)
+        out = jnp.sum(y * wts[..., None], axis=1).astype(h2.dtype)
+        counts = jnp.stack([jnp.sum(sizes > 0, dtype=jnp.int32),
+                            jnp.max(sizes), jnp.sum(sizes)])
+    return out, counts
+
+
+def swiglu(h, w1, w3, w2, dtype):
+    """W_2(silu(W_1 h) * W_3 h), the gate's activation in float32."""
+    g = jax.nn.silu((h @ w1).astype(jnp.float32))
+    return (g.astype(dtype) * (h @ w3)) @ w2
+
+
+def shared_ffn(h2, lp, dtype):
+    """The shared expert: a SwiGLU every row passes through, whatever
+    the router chose (`sw1`, `sw3` [d, f], `sw2` [f, d]); every chip of
+    an expert-parallel layer computes it alike for its own rows."""
+    with jax.named_scope("shared_expert"):
+        return swiglu(h2, lp["sw1"], lp["sw3"], lp["sw2"], dtype)
+
+
+def stack_counts(per_layer: list) -> jnp.ndarray:
+    """The routed layers' counts of one program, int32 [layers, 3] (no
+    rows for a program without a routed layer)."""
+    return (jnp.stack(per_layer) if per_layer
+            else jnp.zeros((0, 3), jnp.int32))

@@ -38,16 +38,18 @@ def _repeat_kv(k: jnp.ndarray, n_rep: int) -> jnp.ndarray:
 
 def xla_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                   causal: bool = True,
-                  q_offset: int | jnp.ndarray = 0) -> jnp.ndarray:
+                  q_offset: int | jnp.ndarray = 0,
+                  sm_scale: float | None = None) -> jnp.ndarray:
     """Reference implementation: fp32 softmax, GQA, causal mask.
 
-    q: [b, sq, hq, d]; k/v: [b, skv, hkv, d].  q_offset shifts query
-    positions relative to kv positions (decode with a cache).
+    q: [b, sq, hq, d]; k/v: [b, skv, hkv, d] (v may be narrower).
+    q_offset shifts query positions relative to kv positions (decode
+    with a cache); sm_scale defaults to d**-0.5.
     """
     n_rep = q.shape[2] // k.shape[2]
     k = _repeat_kv(k, n_rep)
     v = _repeat_kv(v, n_rep)
-    scale = q.shape[-1] ** -0.5
+    scale = q.shape[-1] ** -0.5 if sm_scale is None else sm_scale
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
     if causal:
@@ -62,14 +64,17 @@ def xla_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return out.astype(q.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "impl"))
+@functools.partial(jax.jit, static_argnames=("causal", "impl", "sm_scale"))
 def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
               causal: bool = True, impl: str = "auto",
-              q_offset: int | jnp.ndarray = 0) -> jnp.ndarray:
+              q_offset: int | jnp.ndarray = 0,
+              sm_scale: float | None = None) -> jnp.ndarray:
     """Multi-head attention with GQA.
 
     impl: "auto" picks the Pallas flash kernel on TPU for long-enough
     sequences, XLA otherwise (short sequences / CPU tests / decode).
+    v may be narrower than q and k (latent attention's expanded path:
+    192 / 128); sm_scale defaults to head_dim**-0.5.
     """
     use_flash = False
     if impl == "flash":
@@ -77,7 +82,7 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     elif impl == "auto":
         on_tpu = any(d.platform == "tpu" for d in jax.devices())
         # Flash kernel requires seq multiple of its block size; a
-        # head_dim that is no multiple of 128 lanes is zero-padded.
+        # head_dim under 128 lanes is zero-padded.
         use_flash = (on_tpu and causal and q.shape[1] == k.shape[1]
                      and q.shape[1] % 128 == 0)
         if on_tpu and not use_flash:
@@ -89,23 +94,27 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                             "tokens)", *key)
             _XLA_FALLBACKS[key] = _XLA_FALLBACKS.get(key, 0) + 1
     if use_flash:
-        return _flash_padded(q, k, v, causal)
-    return xla_attention(q, k, v, causal=causal, q_offset=q_offset)
+        return _flash_padded(q, k, v, causal, sm_scale)
+    return xla_attention(q, k, v, causal=causal, q_offset=q_offset,
+                         sm_scale=sm_scale)
 
 
-def _flash_padded(q, k, v, causal: bool):
-    """The flash kernel at any head_dim: q, k and v zero-padded to the
-    next multiple of 128 lanes, the scale given as the TRUE head_dim's.
-    Exact: the padded columns add 0 to every score and the padded
-    columns of the output are cut off."""
-    d = q.shape[-1]
-    pad = -d % 128
-    if not pad:
-        return _flash_per_shard(q, k, v, causal)
-    widths = ((0, 0), (0, 0), (0, 0), (0, pad))
-    o = _flash_per_shard(jnp.pad(q, widths), jnp.pad(k, widths),
-                         jnp.pad(v, widths), causal, sm_scale=d ** -0.5)
-    return o[..., :d]
+def _flash_padded(q, k, v, causal: bool, sm_scale: float | None = None):
+    """The flash kernel at any head_dim: a width (q and k's, or v's)
+    under 128 lanes is zero-padded to 128, the scale given as the TRUE
+    head_dim's; wider ones go as they are (192 / 128 compiles:
+    tests/test_chip_compile.py).  Exact: the padded columns add 0 to
+    every score and the padded columns of the output are cut off."""
+    d, dv = q.shape[-1], v.shape[-1]
+    if min(d, dv) >= 128:
+        return _flash_per_shard(q, k, v, causal, sm_scale)
+
+    def pad(a):
+        return jnp.pad(a, ((0, 0),) * 3 + ((0, max(128 - a.shape[-1], 0)),))
+
+    o = _flash_per_shard(pad(q), pad(k), pad(v), causal,
+                         sm_scale=d ** -0.5 if sm_scale is None else sm_scale)
+    return o[..., :dv]
 
 
 def _flash_per_shard(q, k, v, causal: bool, sm_scale: float | None = None):

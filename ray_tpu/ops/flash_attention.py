@@ -12,8 +12,10 @@ inside the kernel.  Default blocks are block_q=512 / block_k=1024
 the dispatcher halves them until they divide the sequence, so any
 seq % 128 == 0 works.
 
-Constraints: seq % 128 == 0, head_dim % 128 == 0 (the dispatcher in
-ray_tpu.ops.attention falls back to XLA otherwise).
+Constraints: seq % 128 == 0 (the dispatcher in ray_tpu.ops.attention
+falls back to XLA otherwise, and zero-pads a head_dim that is no multiple
+of 128 lanes).  The forward takes values narrower than the keys (latent
+attention's expanded path: q/k 192, v 128); the backward one width.
 """
 from __future__ import annotations
 
@@ -99,9 +101,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k):
-    """q: [b, hq, sq, d]; k/v: [b, hkv, skv, d] → (o, lse[b, hq, sq])."""
+    """q: [b, hq, sq, d]; k: [b, hkv, skv, d]; v: [b, hkv, skv, dv]
+    (dv = d everywhere but latent attention's expanded path, whose keys
+    are wider than its values) → (o [b, hq, sq, dv], lse [b, hq, sq])."""
     b, hq, sq, d = q.shape
     hkv, skv = k.shape[1], k.shape[2]
+    dv = v.shape[3]
     n_rep = hq // hkv
     grid = (b, hq, sq // block_q)
 
@@ -115,22 +120,22 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k):
             pl.BlockSpec((None, None, block_k, d),
                          lambda bi, hi, qi, ki,
                          n_rep=n_rep: (bi, hi // n_rep, ki, 0)),
-            pl.BlockSpec((None, None, block_k, d),
+            pl.BlockSpec((None, None, block_k, dv),
                          lambda bi, hi, qi, ki,
                          n_rep=n_rep: (bi, hi // n_rep, ki, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((None, None, block_q, d),
+            pl.BlockSpec((None, None, block_q, dv),
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             pl.BlockSpec((None, None, block_q, 128),
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((b, hq, sq, dv), q.dtype),
             jax.ShapeDtypeStruct((b, hq, sq, 128), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
@@ -376,7 +381,9 @@ def flash_attention(q, k, v, causal: bool = True, sm_scale: float | None = None,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_k: int = DEFAULT_BLOCK_K):
     """Flash attention with GQA.  q: [b, sq, hq, d]; k/v: [b, skv, hkv, d];
-    returns [b, sq, hq, d] (layout matches ray_tpu.ops.attention)."""
+    returns [b, sq, hq, d] (layout matches ray_tpu.ops.attention).  v may
+    be [b, skv, hkv, dv] with dv != d (forward only: the backward kernels
+    take one width); the result is then [b, sq, hq, dv]."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     qt = q.transpose(0, 2, 1, 3)
@@ -397,5 +404,8 @@ def flash_attention(q, k, v, causal: bool = True, sm_scale: float | None = None,
         raise ValueError(
             f"block sizes ({block_q}, {block_k}) do not divide seq "
             f"({qt.shape[2]}, {kt.shape[2]}); use power-of-two blocks")
-    o = _flash(qt, kt, vt, sm_scale, causal, block_q, block_k)
+    if vt.shape[3] != qt.shape[3]:
+        o, _ = _flash_fwd(qt, kt, vt, sm_scale, causal, block_q, block_k)
+    else:
+        o = _flash(qt, kt, vt, sm_scale, causal, block_q, block_k)
     return o.transpose(0, 2, 1, 3)

@@ -230,6 +230,149 @@ def paged_decode_attention(q, k_pages, v_pages, k_tail, v_tail,
     return jnp.where(live[:, None, None, None], o, jnp.zeros_like(o))
 
 
+def _mla_kernel(lane_ref, col_ref, page_ref, pos_ref, ts_ref,  # prefetch
+                q_ref, rp_ref, rt_ref,        # blocked inputs
+                o_ref,                        # output
+                acc_ref, m_ref, l_ref,        # scratch
+                *, page: int, maxp: int, kt: int, dv: int, sm_scale: float):
+    """One step of `mla_decode_attention`: the walk of `_kernel`, over
+    rows that are key AND value.  q_ref [H, dk]; rp_ref [page, dk] (one
+    page of one layer's pool, copied once for all H heads); rt_ref
+    [kt, dk]; o_ref [H, dv]."""
+    del page_ref
+    i = pl.program_id(0)
+    b = lane_ref[i]
+    col = col_ref[i]
+    pos = pos_ref[b]
+    ts = jnp.minimum(ts_ref[b], maxp * page)
+    npages = (ts + page - 1) // page
+
+    @pl.when(col == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def flash_update(rows, admit):
+        """rows [n, dk]: scores over all dk columns, values = the first
+        dv of the same rows."""
+        s = jax.lax.dot_general(
+            q_ref[...], rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale   # [H, n]
+        s = jnp.where(admit, s, NEG_INF)
+        m_prev = m_ref[:, 0]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        alpha = jnp.exp(m_prev - m_cur)
+        p = jnp.exp(s - m_cur[:, None])
+        l_ref[:, 0] = l_ref[:, 0] * alpha + jnp.sum(p, axis=1)
+        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+            p.astype(rows.dtype), rows[:, :dv], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[:, 0] = m_cur
+
+    @pl.when(col < npages)
+    def _pages():
+        kpos = col * page + jax.lax.broadcasted_iota(
+            jnp.int32, (1, page), 1)
+        flash_update(rp_ref[...], kpos < ts)   # the tail owns rows >= ts
+
+    @pl.when(col >= npages - 1)                # the lane's last step
+    def _tail():
+        jpos = ts + jax.lax.broadcasted_iota(jnp.int32, (1, kt), 1)
+        flash_update(rt_ref[...], jpos <= pos)
+        l = l_ref[:, 0]
+        l = jnp.where(l == 0.0, 1.0, l)
+        o_ref[...] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+
+
+def mla_decode_attention(q, row_pages, row_tail, page_table, pos,
+                         tail_start, *, dv: int, sm_scale: float,
+                         plan: dict | None = None):
+    """Paged + tail decode attention over a LATENT cache (multi-head
+    latent attention, absorbed form): every head attends the SAME cached
+    rows, and a row is key and value at once.
+
+    q:         [B, H, dk]  absorbed queries, dk = latent + rotary width
+    row_pages: [n_pages, 1, page, dk]  one layer's pool of cached rows
+               [latent | rotary key] (rows < tail_start)
+    row_tail:  [B, 1, kt, dk]  the block's rows (the current token's
+               already written at pos - tail_start)
+    dv:        the leading columns of a row that are its VALUE (the
+               latent); scores run over all dk
+    page_table, pos, tail_start, plan: as `paged_decode_attention`, whose
+    work list this walks: one step a live (lane, page) pair, ONE page
+    copy serving all H heads, the tail attended in the lane's last step.
+
+    Returns o [B, H, dv] (to be expanded by the value up-projection); an
+    idle lane's rows are 0."""
+    B, H, dk = q.shape
+    page = row_pages.shape[2]
+    kt = row_tail.shape[2]
+    maxp = page_table.shape[1]
+    if plan is None:
+        plan = attention_plan(page_table, tail_start, page)
+
+    def page_map(i, lane, col, pages, *_):
+        return (pages[i], 0, 0, 0)
+
+    def lane_map3(i, lane, *_):
+        return (lane[i], 0, 0)
+
+    def lane_map4(i, lane, *_):
+        return (lane[i], 0, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(plan["count"],),
+        in_specs=[
+            pl.BlockSpec((None, H, dk), lane_map3),
+            pl.BlockSpec((None, None, page, dk), page_map),
+            pl.BlockSpec((None, None, kt, dk), lane_map4),
+        ],
+        out_specs=pl.BlockSpec((None, H, dv), lane_map3),
+        scratch_shapes=[
+            pltpu.VMEM((H, dv), jnp.float32),
+            pltpu.VMEM((H, 128), jnp.float32),
+            pltpu.VMEM((H, 128), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(_mla_kernel, page=page, maxp=maxp, kt=kt,
+                               dv=dv, sm_scale=sm_scale)
+    o = pl.pallas_call(
+        kernel,
+        name="mla_attn",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=_interpret(),
+    )(plan["lane"], plan["col"], plan["page"], pos, tail_start,
+      q, row_pages, row_tail)
+    live = lanes_live(page_table)
+    return jnp.where(live[:, None, None], o, jnp.zeros_like(o))
+
+
+def mla_decode_reference(q, row_pages, row_tail, page_table, pos,
+                         tail_start, *, dv: int, sm_scale: float):
+    """Pure-jax oracle of `mla_decode_attention`: materializes each
+    lane's gathered rows (test-scale only)."""
+    B, H, dk = q.shape
+    page = row_pages.shape[2]
+    kt = row_tail.shape[2]
+    maxp = page_table.shape[1]
+    rows = row_pages[page_table][:, :, 0].reshape(B, maxp * page, dk)
+    rows = jnp.concatenate([rows, row_tail[:, 0]], axis=1).astype(
+        jnp.float32)                                  # [B, n + kt, dk]
+    s = jnp.einsum("bhd,bkd->bhk", q.astype(jnp.float32), rows) * sm_scale
+    kpos = jnp.arange(maxp * page)[None, :] < tail_start[:, None]
+    jpos = (tail_start[:, None] + jnp.arange(kt)[None, :]) <= pos[:, None]
+    admit = jnp.concatenate([kpos, jpos], axis=1)[:, None, :]
+    p = jax.nn.softmax(jnp.where(admit, s, NEG_INF), axis=-1)
+    o = jnp.einsum("bhk,bkd->bhd", p, rows[..., :dv])
+    live = lanes_live(page_table)
+    return jnp.where(live[:, None, None], o, 0.0).astype(q.dtype)
+
+
 def merge_tail_pages(pages, tail, page_table, tail_start, n_rows):
     """Scatter a finished block's tail rows into the page pool.
 

@@ -71,6 +71,16 @@ def _buckets_for(max_len: int, smallest: int = 32) -> list[int]:
 from ray_tpu.serve.kv_router import env_on as _env_on
 
 
+def _pool(cache: dict) -> dict:
+    """The page pool of a model's cache (`init_paged_cache`): every
+    entry but the lanes' positions and their state, each a list of
+    [n_pages, heads, page, width] leaves, one a layer that keeps rows.
+    The engine takes the leaves' names and shapes from here and from
+    nowhere else."""
+    return {name: leaves for name, leaves in cache.items()
+            if name not in ("pos", "state")}
+
+
 def _check_pool_role(role: str, decode_deployment) -> None:
     """The pool-role combination rules, shared by LLMServer.__init__
     and reconfigure (the declarative schema enforces the same rules at
@@ -186,6 +196,19 @@ def _engine_metrics():
                 "moe_assignments": um.get_or_create(
                     um.Counter, "serve_llm_moe_assignments",
                     "Token-expert assignments computed in decode", tk),
+                "moe_assignments_absent": um.get_or_create(
+                    um.Counter, "serve_llm_moe_assignments_absent",
+                    "Selected experts this chip does not hold (an "
+                    "expert-parallel share), in decode", tk),
+                "attn_ctx_rows": um.get_or_create(
+                    um.Counter, "serve_llm_attn_ctx_rows",
+                    "Cached rows the decode attention kernel had to "
+                    "attend, summed over live lanes, steps and sync "
+                    "windows", tk),
+                "prefill_programs_capped": um.get_or_create(
+                    um.Counter, "serve_llm_prefill_programs_capped",
+                    "Prefill waves the planner's token ceiling split "
+                    "(serve/prefill_plan.PREFILL_MAX_TOKENS)", tk),
                 # beside phase_s["decode_sync"]: bytes a second the
                 # prefix store's demotion fetched off the device
                 "demote_bytes": um.get_or_create(
@@ -320,7 +343,9 @@ class LLMEngine:
     capabilities (`SERVING_CAPS`) the engine refuses at construction; a
     model whose lanes carry state no KV page holds (`lane_state_layers`)
     is served with the prefix cache, suffix prefill and the prefix
-    store's demotion off, and `stats()["lane_state"]` says so."""
+    store's demotion off, and `stats()["lane_state"]` says so.  The page
+    pool is whatever leaves the model's `init_paged_cache` returns
+    (`_pool`): `stats()["cache"]` says what it holds."""
 
     def __init__(self, cfg, params=None, *, max_batch: int = 8,
                  max_len: int | None = None, seed: int = 0,
@@ -390,6 +415,7 @@ class LLMEngine:
         self.n_pages = kv_pages
         self.cache = model.init_paged_cache(cfg, max_batch,
                                             kv_pages, page_size)
+        self._cache_info = self._cache_stats()   # shapes: fixed from here
         # Host-side accounting: refcounted blocks + radix prefix
         # index over pool ids 1..n_pages-1 (serve/kv_blocks.py).
         self._mgr = BlockManager(kv_pages - 1, page_size,
@@ -495,15 +521,18 @@ class LLMEngine:
                                                          merge_tail_pages)
 
                 ts = cache["pos"]
-                pages = {"k": cache["k"], "v": cache["v"]}
+                # the page pool, by whatever leaves the model's
+                # init_paged_cache gave it (a K and a V pool a layer,
+                # one latent pool a layer, ...): every leaf is
+                # [n_pages, heads, page, width]; its tail is the same
+                # with K rows a lane
+                pages = _pool(cache)
                 with jax.named_scope("attn_plan"):
                     plan = attention_plan(table, ts, self.page)
-                n_kv = len(pages["k"])
-                tshape = (max_batch, cfg.n_kv_heads, K, cfg.head_dim)
-                tails = {"k": [jnp.zeros(tshape, cfg.dtype)
-                               for _ in range(n_kv)],
-                         "v": [jnp.zeros(tshape, cfg.dtype)
-                               for _ in range(n_kv)]}
+                tails = jax.tree.map(
+                    lambda pool: jnp.zeros(
+                        (max_batch, pool.shape[1], K, pool.shape[3]),
+                        pool.dtype), pages)
                 lane_keys = jax.vmap(
                     lambda s: jax.random.fold_in(self._base_key,
                                                  s))(seeds)
@@ -522,13 +551,11 @@ class LLMEngine:
                 (tails, state, pos, last, counts), seq = jax.lax.scan(
                     step, (tails, cache["state"], ts, tokens, counts0),
                     jnp.arange(K))
-                new_k = [merge_tail_pages(pages["k"][li],
-                                          tails["k"][li], table, ts, K)
-                         for li in range(n_kv)]
-                new_v = [merge_tail_pages(pages["v"][li],
-                                          tails["v"][li], table, ts, K)
-                         for li in range(n_kv)]
-                return seq, last, {"k": new_k, "v": new_v, "pos": pos,
+                merged = jax.tree.map(
+                    lambda pool, tail: merge_tail_pages(pool, tail, table,
+                                                        ts, K),
+                    pages, tails)
+                return seq, last, {**merged, "pos": pos,
                                    "state": state}, counts
 
             return jax.jit(_decode_k_paged, donate_argnums=(1,))
@@ -661,8 +688,8 @@ class LLMEngine:
         self._copy_pages = jax.jit(
             lambda cache, src, dst: {
                 **cache,
-                "k": [l.at[dst].set(l[src]) for l in cache["k"]],
-                "v": [l.at[dst].set(l[src]) for l in cache["v"]]},
+                **{name: [l.at[dst].set(l[src]) for l in leaves]
+                   for name, leaves in _pool(cache).items()}},
             donate_argnums=(0,))
 
         # Slot state.  Current tokens live ON DEVICE between blocks: the
@@ -721,21 +748,28 @@ class LLMEngine:
         # grid of every lane x (every table column + the tail) was.
         self.attn_steps = 0
         self.attn_steps_dense = 0
+        # Rows the attention kernel had to attend: a live lane's context
+        # at each of a window's K steps (block-start rows + the tail's
+        # j + 1), summed over lanes, steps and windows.
+        self.attn_ctx_rows = 0
         self.phase_s = dict.fromkeys(_LOOP_PHASES, 0.0)
         self.prefill_padded_tokens = 0  # width bucket x length bucket
         self.prefill_programs = 0      # (width, length) programs dispatched
         self.prefill_waves = 0
         self.prefill_waves_split = 0   # plans of more programs than chunks
+        # waves the planner's token ceiling split (PREFILL_MAX_TOKENS)
+        self.prefill_programs_capped = 0
         # Routed layers (a model that declares `routed_layers`): layer
         # x steps run, assignments computed, experts that held a row and
         # the largest expert load, each summed over layer-steps; decode
         # and prefill apart.  The device counts; the numbers ride the
         # token fetch of the window (wave) they belong to.
-        self._prefill_counts: list = []    # device arrays, a wave's
+        self._prefill_counts: list = []    # (device array, rows) a program
         self.moe = dict.fromkeys(
             [p + k for p in ("", "prefill_")
              for k in ("moe_layer_steps", "moe_assignments",
-                       "moe_experts_hit", "moe_max_load")], 0)
+                       "moe_assignments_absent", "moe_experts_hit",
+                       "moe_max_load")], 0)
         self._funded_blocks = 0        # pages _ensure_decode_blocks got
         self._demote_dispatched = 0    # candidates _maybe_demote took
         # What demotion moved off the device, cumulative, bumped on the
@@ -1939,10 +1973,10 @@ class LLMEngine:
         with self._phase("prefill_dispatch", iter=it,
                          rows=len(wave)) as ph:
             true0, padded0 = self.prefill_tokens, self.prefill_padded_tokens
-            plan = plan_wave(
-                [len(r.prompt) + len(r.tokens) - r.prefill_from
-                 for _, r in wave],
-                self._width_buckets, self._buckets, self._chunk)
+            lengths = [len(r.prompt) + len(r.tokens) - r.prefill_from
+                       for _, r in wave]
+            plan, capped = plan_wave(lengths, self._width_buckets,
+                                     self._buckets, self._chunk)
             for rows, w, b in plan:
                 chunk = [wave[i] for i in rows]
                 t_disp = time.time()
@@ -1957,6 +1991,7 @@ class LLMEngine:
             # more programs than arrival-order chunks of _chunk rows
             self.prefill_waves_split += \
                 len(plan) > -(-len(wave) // self._chunk)
+            self.prefill_programs_capped += capped
             # the buckets are those of the widest / longest program
             ph.update(width_bucket=max(w for _, w, _ in plan),
                       len_bucket=max(b for _, _, b in plan),
@@ -1966,7 +2001,8 @@ class LLMEngine:
                       plan=",".join(f"{w}x{b}" for _, w, b in plan))
         with self._phase("prefill_sync", iter=it, rows=len(wave)):
             counts, self._prefill_counts = self._prefill_counts, []
-            for a in [nxt for _, nxt, _t in pending_waves] + counts:
+            for a in ([nxt for _, nxt, _t in pending_waves]
+                      + [c for c, _ in counts]):
                 try:
                     a.copy_to_host_async()
                 except AttributeError:
@@ -2009,8 +2045,8 @@ class LLMEngine:
                         attrs={"ttft_ms": round(
                             (req.first_token_at - req.submitted_at)
                             * 1000, 1)})
-            for c in counts:        # on the host since the tokens are
-                self._count_moe("prefill_", np.asarray(c), 1)
+            for c, rows in counts:  # on the host since the tokens are
+                self._count_moe("prefill_", np.asarray(c), 1, rows)
 
     def _prefill_chunk_full(self, chunk, padded_w: int, bucket: int):
         """Full-prompt prefill (no cached prefix anywhere in the chunk)
@@ -2060,8 +2096,9 @@ class LLMEngine:
             self.cache, ks, vs, state, jnp.asarray(page_ids),
             jnp.asarray(rows), slots_dev, lens_dev)
         if self._moe_layers:
-            # fetched with the wave's first tokens
-            self._prefill_counts.append(counts)
+            # fetched with the wave's first tokens; beside them the rows
+            # the program routed (padding rows of the width repeat one)
+            self._prefill_counts.append((counts, int(true_lens.sum())))
         # Duplicate padding rows target the same slot + same token.
         self._cur_dev = self._cur_dev.at[slots_dev].set(nxt)
         return nxt
@@ -2488,7 +2525,7 @@ class LLMEngine:
         with self._phase("decode_dispatch", iter=it, lanes=len(active),
                          steps=k_win) as ph:
             starts = np.zeros((self.max_batch,), np.int32)
-            attn_steps = 0
+            attn_steps = attn_rows = 0
             for i in active:
                 req = self._slots[i]
                 starts[i] = len(req.tokens)
@@ -2498,6 +2535,7 @@ class LLMEngine:
                 rows = min(len(req.prompt) + len(req.tokens) - 1,
                            self._maxp * self.page)
                 attn_steps += max(-(-rows // self.page), 1)
+                attn_rows += k_win * rows + k_win * (k_win + 1) // 2
             ph.update(attn_steps=attn_steps)
             win_traced = tracing.ENABLED and any(
                 self._slots[i] is not None
@@ -2523,6 +2561,7 @@ class LLMEngine:
             self.lane_steps_live += len(active) * k_win
             self.attn_steps += attn_steps
             self.attn_steps_dense += self.max_batch * (self._maxp + 1)
+            self.attn_ctx_rows += attn_rows
         with self._phase("decode_sync", iter=it):
             seq = np.asarray(seq)               # the ONE sync per block
             # the routed layers' counts: a few hundred bytes of the same
@@ -2532,7 +2571,8 @@ class LLMEngine:
         with self._phase("deliver", iter=it) as ph:
             tokens0, done0 = self.decode_tokens, self.completed
             if moe is not None:
-                hit, load = self._count_moe("", moe, k_win)
+                hit, load = self._count_moe("", moe, k_win,
+                                            len(active) * k_win)
                 ph.update(experts_hit=hit, max_load=load)
             if win_traced:
                 # One K-step decode window per traced co-resident
@@ -2562,16 +2602,24 @@ class LLMEngine:
             ph.update(tokens=self.decode_tokens - tokens0,
                       finished=self.completed - done0)
 
-    def _count_moe(self, prefix: str, counts, steps: int) -> tuple:
+    def _count_moe(self, prefix: str, counts, steps: int,
+                   rows: int) -> tuple:
         """Add one program's routed-layer counts ([layers, 3]: experts
         hit, largest load, assignments; each summed over the program's
-        `steps`) to the `prefix`ed counters; returns (experts hit,
-        largest load) as means a layer-step."""
+        `steps`) to the `prefix`ed counters.  `rows`: the rows it routed
+        in each layer, summed over the steps; each selected
+        `cfg.top_k` experts (a config with routed layers has it), and
+        the selections that were not computed went to experts this chip
+        does not hold.  Returns (experts hit, largest load) as means a
+        layer-step."""
         m, n = self.moe, counts.shape[0] * steps
+        computed = int(counts[:, 2].sum())
         m[prefix + "moe_layer_steps"] += n
         m[prefix + "moe_experts_hit"] += int(counts[:, 0].sum())
         m[prefix + "moe_max_load"] += int(counts[:, 1].sum())
-        m[prefix + "moe_assignments"] += int(counts[:, 2].sum())
+        m[prefix + "moe_assignments"] += computed
+        m[prefix + "moe_assignments_absent"] += (
+            rows * self.cfg.top_k * counts.shape[0] - computed)
         return (round(float(counts[:, 0].sum()) / n, 2),
                 round(float(counts[:, 1].sum()) / n, 2))
 
@@ -2620,6 +2668,8 @@ class LLMEngine:
                "lane_steps_live": self.lane_steps_live,
                "attn_steps": self.attn_steps,
                "attn_steps_dense": self.attn_steps_dense,
+               "attn_ctx_rows": self.attn_ctx_rows,
+               "prefill_programs_capped": self.prefill_programs_capped,
                "demote_bytes": self.demote_bytes,
                "preemptions": self.preemptions,
                "completed": self.completed,
@@ -2628,7 +2678,8 @@ class LLMEngine:
                "evictions": self._mgr.evictions}
         if self._moe_layers:
             cur.update({k: self.moe[k] for k in (
-                "moe_layer_steps", "moe_experts_hit", "moe_assignments")})
+                "moe_layer_steps", "moe_experts_hit", "moe_assignments",
+                "moe_assignments_absent")})
         with self._metrics_lock:
             self._metrics_t = now
             for key, val in cur.items():
@@ -2655,6 +2706,19 @@ class LLMEngine:
         self._mgr.check()
         return {"ok": True, "free": self._mgr.free_count(),
                 "available": self._mgr.available()}
+
+    def _cache_stats(self) -> dict:
+        """The page pool by what it holds: the model's word for it
+        (`CACHE_KIND`), the bytes a token's rows take in one layer as
+        stored, the layers that keep any, and the pool's bytes."""
+        pool = _pool(self.cache)
+        return {"kind": self._model.CACHE_KIND,
+                "row_bytes": int(sum(
+                    v[0].shape[1] * v[0].shape[3] * v[0].dtype.itemsize
+                    for v in pool.values())),
+                "layers": max(len(v) for v in pool.values()),
+                "pool_bytes": int(sum(a.size * a.dtype.itemsize
+                                      for v in pool.values() for a in v))}
 
     def stats(self) -> dict:
         out = {"completed": self.completed,
@@ -2693,6 +2757,7 @@ class LLMEngine:
                    "lane_steps_live": self.lane_steps_live,
                    "attn_steps": self.attn_steps,
                    "attn_steps_dense": self.attn_steps_dense,
+                   "attn_ctx_rows": self.attn_ctx_rows,
                    "phase_s": dict(self.phase_s),
                    # the prefix store's demotion: pages and host bytes
                    # fetched off the device, and the export thread's
@@ -2705,7 +2770,10 @@ class LLMEngine:
                    "prefill_padded_tokens": self.prefill_padded_tokens,
                    "prefill_programs": self.prefill_programs,
                    "prefill_waves": self.prefill_waves,
-                   "prefill_waves_split": self.prefill_waves_split}}
+                   "prefill_waves_split": self.prefill_waves_split,
+                   "prefill_programs_capped":
+                   self.prefill_programs_capped},
+               "cache": dict(self._cache_info)}
         if self._moe_layers:
             out["loop"].update(self.moe)
         if self._lane_layers:
@@ -2798,8 +2866,8 @@ class LLMServer:
         if role != "unified" and "kv_transfer" not in served_by.SERVING_CAPS:
             raise ValueError(
                 f"role={role!r} needs KV export/import, which "
-                f"{served_by.__name__} lacks (its lanes hold state that "
-                "no page carries): serve it unified")
+                f"{served_by.__name__} lacks (what its lanes or its "
+                "pool hold is no K and V page): serve it unified")
         name = "llm"
         self._app_name = None
         try:

@@ -11,6 +11,15 @@ from __future__ import annotations
 # bytes/s), not the model's size.
 FLOOR_TOKENS = 256
 
+# Token positions over which no program goes unless it has ONE row: a
+# program's temporaries grow with its positions (a routed layer gathers
+# [positions x experts per token, dim] rows: 4.3 GB at 16 x 8192 x 8 x
+# 4096), and past a few tens of thousands a wider program buys nothing
+# (the floor above is long amortised).  At the widest programs the
+# benchmark's dense cells reach (8 x 4096, 16 x 2048), so no plan of
+# theirs changes.
+PREFILL_MAX_TOKENS = 32768
+
 
 def program_cost(width: int, bucket: int) -> int:
     """Token positions a (width, bucket) prefill program is charged."""
@@ -18,7 +27,7 @@ def program_cost(width: int, bucket: int) -> int:
 
 
 def plan_wave(lengths: list[int], widths: list[int], buckets: list[int],
-              chunk: int) -> list[tuple[list[int], int, int]]:
+              chunk: int) -> tuple[list[tuple[list[int], int, int]], bool]:
     """Partition a wave's rows into prefill programs of least total cost.
 
     `lengths[i]` is the token count row i's prefill pads (prompt, or the
@@ -27,24 +36,33 @@ def plan_wave(lengths: list[int], widths: list[int], buckets: list[int],
     ordered by length and cut into contiguous groups; a group runs at
     the smallest width that holds it and the length bucket of its
     longest row, so no program lies outside widths x buckets or outside
-    the span of the rows' own buckets.  Ties go to fewer programs: `w`
-    equal rows stay ONE w-wide program (what a warm-up that submits
-    exactly that relies on).  Returns (row indices, width, bucket) per
-    program, shortest first."""
+    the span of the rows' own buckets; a group of more than one row whose
+    program would hold more than PREFILL_MAX_TOKENS positions is not
+    formed.  Ties go to fewer programs: `w` equal rows stay ONE w-wide
+    program (what a warm-up that submits exactly that relies on).
+    Returns the plan, (row indices, width, bucket) per program, shortest
+    first, and whether the ceiling shaped it: whether a group of the plan
+    stands where a forbidden one would have been taken."""
     order = sorted(range(len(lengths)), key=lengths.__getitem__)
     bucket_of = [next(b for b in buckets if b >= lengths[i]) for i in order]
     width_of = [0] + [next(w for w in widths if w >= g)
                       for g in range(1, chunk + 1)]
-    # best[i] = (cost, programs, size of the last group) over rows [0, i)
-    best = [(0, 0, 0)]
+    # best[i] = (cost, programs, size of the last group) over rows [0, i);
+    # beaten[i]: a group the ceiling forbids would have been taken there
+    best, beaten = [(0, 0, 0)], [False]
     for i in range(1, len(order) + 1):
-        best.append(min(
-            (best[i - g][0] + program_cost(width_of[g], bucket_of[i - 1]),
-             best[i - g][1] + 1, g)
-            for g in range(1, min(chunk, i) + 1)))
-    plan, i = [], len(order)
+        allowed, forbidden = [], []
+        for g in range(1, min(chunk, i) + 1):
+            over = g > 1 and width_of[g] * bucket_of[i - 1] > PREFILL_MAX_TOKENS
+            (forbidden if over else allowed).append(
+                (best[i - g][0] + program_cost(width_of[g], bucket_of[i - 1]),
+                 best[i - g][1] + 1, g))
+        best.append(min(allowed))
+        beaten.append(any(c < best[i] for c in forbidden))
+    plan, capped, i = [], False, len(order)
     while i:
         g = best[i][2]
         plan.append((order[i - g:i], width_of[g], bucket_of[i - 1]))
+        capped |= beaten[i]
         i -= g
-    return plan[::-1]
+    return plan[::-1], capped

@@ -1,6 +1,6 @@
 """What the serving tests compare with, and the driver that walks a
 model's serving seam (`ray_tpu.models.serving_model`) by hand: shared by
-the llama and LFM2 tests (imported rootdir-relative, like the other
+the llama, LFM2 and latent-attention tests (imported rootdir-relative, like the other
 helpers in this directory)."""
 from __future__ import annotations
 
@@ -57,10 +57,13 @@ def served_logits(model, params, cfg, prompt, follow, bucket, *,
     step = jax.jit(lambda *a: model.serve_decode_step(*a, cfg))
     for w0 in range(0, len(follow), k):
         ts = cache["pos"]
-        pages = {"k": cache["k"], "v": cache["v"]}
-        kvh, hd = cfg.n_kv_heads, cfg.head_dim
-        tails = {kv: [jnp.zeros((2, kvh, k, hd), cfg.dtype)
-                      for _ in pages["k"]] for kv in "kv"}
+        # the pool by the leaves the model gave it, as the engine's
+        # decode program takes it (serve/llm.py `_pool`)
+        pages = {name: leaves for name, leaves in cache.items()
+                 if name not in ("pos", "state")}
+        tails = jax.tree.map(
+            lambda p: jnp.zeros((2, p.shape[1], k, p.shape[3]), p.dtype),
+            pages)
         st, pos = cache["state"], ts
         for j, t in enumerate(follow[w0:w0 + k]):
             lg, tails, st, _ = step(
@@ -68,9 +71,7 @@ def served_logits(model, params, cfg, prompt, follow, bucket, *,
                 pos, ts, j, table)
             out.append(lg[1])
             pos = pos + 1
-        cache = {"k": [merge_tail_pages(p, t, table, ts, k)
-                       for p, t in zip(pages["k"], tails["k"])],
-                 "v": [merge_tail_pages(p, t, table, ts, k)
-                       for p, t in zip(pages["v"], tails["v"])],
-                 "pos": ts + k, "state": st}
+        cache = {**jax.tree.map(
+            lambda p, t: merge_tail_pages(p, t, table, ts, k), pages, tails),
+            "pos": ts + k, "state": st}
     return jnp.stack(out)

@@ -501,6 +501,146 @@ def test_decode_step_loop_reads_the_attention_plan_and_builds_none(
                  if re.match(rf"\s*%{re.escape(count)} = ", ln))
     assert re.match(r"\s*%[\w.\-]+ = s32\[\]", bound), bound
 
+# ------------------------------------ the latent-attention model (PR 34)
+def _sarvam(n_layers=None):
+    """benchmarks/configs/sarvam-105b-ep4.json as the benchmark builds
+    it: (program config, engine keywords, the family)."""
+    from benchmarks.harness import spec
+
+    cfg = spec.load_json(os.path.join(
+        spec.BENCH_DIR, "configs", "sarvam-105b-ep4.json"))
+    if n_layers is not None:
+        cfg["num_hidden_layers"] = n_layers
+    fam = spec.config_family(cfg)
+    eng_kw = dict(cfg["engine"], paged=True)
+    return (fam.program_config(fam.published(cfg),
+                               max_seq=eng_kw["max_len"]), eng_kw, fam)
+
+
+def _sarvam_lowerings(one_chip, n_layers, shapes):
+    from ray_tpu.models import mla_moe
+    from ray_tpu.serve.llm import LLMEngine
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def abstract(tree):
+        return jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
+
+    cfg, eng_kw, _ = _sarvam(n_layers)
+    params = abstract(jax.eval_shape(
+        lambda: mla_moe.init_params(jax.random.PRNGKey(0), cfg)))
+    eng = LLMEngine(cfg, params, **eng_kw)
+    i32, f32 = jnp.int32, jnp.float32
+    b, k = eng.max_batch, eng.steps_per_sync
+    out = {f"decode_k{k}": eng._decode_fns[k].lower(
+        params, abstract(eng.cache), sds((b,), i32), sds((b,), f32),
+        sds((b, eng._maxp), i32), sds((b,), i32), sds((b,), i32), None)}
+    for w, p in shapes:
+        out[f"prefill_w{w}_p{p}"] = eng._prefill_fwd.lower(
+            params, sds((w, p), i32), sds((w,), i32), sds((w,), i32),
+            sds((w,), f32), sds((w,), i32), sds((w,), i32), None)
+    return cfg, eng, out
+
+
+def test_mla_attn_compiles_and_copies_no_pool(one_chip, compiled_kernels):
+    """`mla_attn` at sarvam-105b's served widths: 32 lanes, 64 heads over
+    ONE 640-wide row a token (576 used), pages of 512, 18 table columns.
+    The pool is read where it lies: a leaf declared 576 wide was laid
+    out page-minor and copied whole (340 MB) before the call."""
+    from ray_tpu.ops.paged_attention import mla_decode_attention
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    B, H, dk, dv, page, kt, maxp, n_pages = 32, 64, 640, 512, 512, 8, 18, 577
+    low, c = _compile(
+        lambda q, rp, rt, t, p, ts: mla_decode_attention(
+            q, rp, rt, t, p, ts, dv=dv, sm_scale=0.135),
+        s((B, H, dk)), s((n_pages, 1, page, dk)), s((B, 1, kt, dk)),
+        s((B, maxp), jnp.int32), s((B,), jnp.int32), s((B,), jnp.int32))
+    assert low.as_text().count("tpu_custom_call") == 1
+    assert "mla_attn" in low.as_text()
+    pool = n_pages * page * dk
+    assert c.memory_analysis().temp_size_in_bytes < 16 * 1024 ** 2
+    assert _pool_copies(c.as_text(), pool // 2) == []
+
+
+def test_flash_forward_compiles_at_keys_wider_than_values(one_chip,
+                                                          compiled_kernels):
+    """The expanded path's prefill attention: 64 heads, q/k 192 wide, v
+    and the output 128, one row of 8,192 positions, under the name the
+    rooflines read (`flash_fwd`)."""
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    def s(d):
+        return jax.ShapeDtypeStruct((1, 8192, 64, d), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    low, c = _compile(lambda q, k, v: flash_attention(q, k, v, sm_scale=0.1),
+                      s(192), s(192), s(128))
+    assert low.as_text().count("tpu_custom_call") == 1
+    assert "flash_fwd" in low.as_text()
+    assert jax.tree.leaves(c.out_info)[0].shape == (1, 8192, 64, 128)
+
+
+@pytest.mark.time_limit(600)
+def test_served_sarvam_engine_fits_one_chip(topo, one_chip, compiled_kernels,
+                                            monkeypatch):
+    """sarvam-105b-ep4 as the benchmark serves it (6 layers, 32 of 128
+    experts, a quarter of the vocabulary, 32 lanes over 577 latent
+    pages): the decode program and the ONE prefill program its traffic
+    reaches (1 x 8192: the planner's ceiling forms no wider one) compile
+    for one chip and leave 0.8 GB beside weights + pool."""
+    monkeypatch.setattr(jax, "devices", lambda *a: list(topo.devices))
+    cfg, eng, lows = _sarvam_lowerings(one_chip, None, [(1, 8192)])
+    st = eng._cache_stats()
+    assert st == {"kind": "latent", "row_bytes": 1280, "layers": 6,
+                  "pool_bytes": 577 * 512 * 1280 * 6}
+    resident = st["pool_bytes"] + 2 * sum(
+        math.prod(a.shape) for a in jax.tree.leaves(eng.params))
+    assert resident > 0.75 * HBM_BYTES       # a deployment's fill
+    kernels = {"decode_k8": ("mla_attn", "moe_gmm"),
+               "prefill_w1_p8192": ("flash_fwd", "moe_gmm")}
+    for name, low in lows.items():
+        txt = low.as_text()
+        for kern in kernels[name]:
+            assert kern in txt, (name, kern)
+        mem = low.compile().memory_analysis()
+        held = resident + mem.temp_size_in_bytes + (
+            mem.output_size_in_bytes - mem.alias_size_in_bytes)
+        print(f"{name}: temps {mem.temp_size_in_bytes / 1e9:.2f} GB, "
+              f"resident {resident / 1e9:.2f} GB")
+        assert held < 16.9e9 - 0.8e9, (name, held)
+
+
+def test_sarvam_decode_step_loop_writes_no_weight_and_builds_no_plan(
+        topo, one_chip, compiled_kernels, monkeypatch):
+    """The absorbed decode program at the served widths (the dense layer
+    and one routed layer: the body is per layer): W_UK and W_UV are held
+    as the step reads them, so nothing the size of a weight is written
+    inside the K-step loop (the smallest matrix a step multiplies is
+    W_kva, 4096 x 576); the latent pool is merged in place once a
+    window; the loop holds one `mla_attn` a layer, bounded by the plan
+    built before it."""
+    monkeypatch.setattr(jax, "devices", lambda *a: list(topo.devices))
+    cfg, eng, lows = _sarvam_lowerings(one_chip, 2, [])
+    hlo = lows["decode_k8"].compile().as_text()
+    assert "while(" in hlo and "mla_attn" in hlo and "moe_gmm" in hlo
+    assert weight_sized_writes(hlo, cfg.dim * cfg.row_used) == []
+    assert _pool_copies(hlo, eng.n_pages * 512 * cfg.row_width) == []
+    loop = _loop_lines(hlo)
+    assert hlo.count("attn_plan") > 0
+    # the plan's arrays are carried into the loop (views of the loop's
+    # argument); no instruction of the loop computes one
+    built = [m.group(1) for ln in loop if "attn_plan" in ln
+             for m in [_INSTR.match(ln)] if m and m.group(3) not in _NO_WRITE]
+    assert built == []
+    calls = [ln for ln in loop if "custom-call(" in ln and "mla_attn" in ln]
+    assert len(calls) == cfg.n_layers
+    assert len({re.search(r"custom-call\(%([\w.\-]+)", ln).group(1)
+                for ln in calls}) == 1
+
 
 def test_sharded_train_step_lowers_for_four_chips(topo, compiled_kernels,
                                                   monkeypatch):

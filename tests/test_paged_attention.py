@@ -407,3 +407,78 @@ def test_decode_step_paged_equals_the_reshape_on_the_product(
         np.testing.assert_array_equal(np.asarray(base[0]),
                                       np.asarray(want[0]))
         assert float(jnp.abs(base[1:] - want[1:]).max()) > 1e-3
+
+
+# ------------------------------------------- latent rows: `mla_attn`
+def _mla_case(seed, B=5, H=4, dk=48, page=8, maxp=4, kt=4, dtype=jnp.float32):
+    rng = np.random.default_rng(seed)
+    n_pages = 1 + B * maxp
+    q = jnp.asarray(rng.normal(size=(B, H, dk)), dtype)
+    rows = jnp.asarray(rng.normal(size=(n_pages, 1, page, dk)), dtype)
+    tail = jnp.asarray(rng.normal(size=(B, 1, kt, dk)), dtype)
+    table = np.arange(1, n_pages, dtype=np.int32).reshape(B, maxp)
+    return q, rows, tail, table
+
+
+@pytest.mark.parametrize("ts", [
+    [0, 7, 8, 27, 32],          # no page yet, a partial last page, whole
+    [5, 13, 21, 29, 3],         # every lane ends inside a page
+], ids=["boundaries", "partial_last_pages"])
+def test_mla_attn_matches_a_plain_gather(ts):
+    """One cached row a token serves every head as key AND value: scores
+    over the row's dk columns, values = its first dv.  Against the
+    oracle that gathers each lane's rows."""
+    from ray_tpu.ops.paged_attention import (mla_decode_attention,
+                                             mla_decode_reference)
+
+    q, rows, tail, table = _mla_case(0)
+    ts = jnp.asarray(ts, jnp.int32)
+    for j in (0, 3):
+        args = (q, rows, tail, jnp.asarray(table), ts + j, ts)
+        got = mla_decode_attention(*args, dv=32, sm_scale=0.3)
+        want = mla_decode_reference(*args, dv=32, sm_scale=0.3)
+        assert got.shape == (5, 4, 32)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-5)
+
+
+def test_mla_attn_leaves_idle_lanes_exactly_zero_and_reads_no_trash():
+    """Lanes 1 and 3 hold no request (their table rows start at the trash
+    page, their positions have run away): they get no step and read
+    exactly 0, and NaNs in the trash page and in their tails reach
+    nobody."""
+    from ray_tpu.ops.paged_attention import (attention_plan,
+                                             mla_decode_attention,
+                                             mla_decode_reference)
+
+    q, rows, tail, table = _mla_case(1)
+    table[1] = table[3] = 0
+    rows = rows.at[0].set(jnp.nan)
+    tail = tail.at[1].set(jnp.nan).at[3].set(jnp.nan)
+    ts = jnp.asarray([9, 10 ** 6, 17, 99, 30], jnp.int32)
+    plan = attention_plan(jnp.asarray(table), ts, 8)
+    assert int(plan["count"]) == 2 + 3 + 4
+    args = (q, rows, tail, jnp.asarray(table), ts + 1, ts)
+    got = mla_decode_attention(*args, dv=32, sm_scale=0.3, plan=plan)
+    want = mla_decode_reference(
+        q, rows.at[0].set(0.0), tail.at[1].set(0.0).at[3].set(0.0),
+        *args[3:], dv=32, sm_scale=0.3)
+    assert (np.asarray(got[1]) == 0).all() and (np.asarray(got[3]) == 0).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+
+def test_mla_attn_in_bfloat16_at_a_padded_row_width():
+    """The served form: bfloat16 rows stored a lane tile wider than they
+    are used (zeros), q padded alike: the padding adds nothing."""
+    from ray_tpu.ops.paged_attention import (mla_decode_attention,
+                                             mla_decode_reference)
+
+    q, rows, tail, table = _mla_case(2, dk=40, dtype=jnp.bfloat16)
+    pad = lambda a: jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, 24)])  # noqa
+    ts = jnp.asarray([4, 12, 20, 28, 31], jnp.int32)
+    args = (jnp.asarray(table), ts + 2, ts)
+    got = mla_decode_attention(pad(q), pad(rows), pad(tail), *args,
+                               dv=32, sm_scale=0.2)
+    want = mla_decode_reference(q, rows, tail, *args, dv=32, sm_scale=0.2)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=2e-2)

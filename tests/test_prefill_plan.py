@@ -32,7 +32,7 @@ def _arrival_order(lengths, widths, chunk):
 def _check(lengths, shape):
     """Every invariant of a plan; returns it as the span's `plan` string."""
     widths, chunk = SHAPES[shape]
-    plan = plan_wave(lengths, widths, BUCKETS, chunk)
+    plan, _ = plan_wave(lengths, widths, BUCKETS, chunk)
     # every row in exactly one group
     assert sorted(i for rows, _, _ in plan for i in rows) \
         == list(range(len(lengths)))
@@ -94,3 +94,69 @@ def test_plan(lengths, shape, expect):
     got = _check(lengths, shape)
     if expect is not None:
         assert got == expect
+
+
+# ----------------------------------------------- the ceiling on positions
+from ray_tpu.serve import prefill_plan  # noqa: E402
+from ray_tpu.serve.prefill_plan import PREFILL_MAX_TOKENS  # noqa: E402
+
+LONG_BUCKETS = [32, 64, 128, 256, 512, 1024, 2048, 4096, 8192, 9216]
+
+
+def _shapes(plan):
+    return [(w, b) for _, w, b in plan]
+
+
+def _without_the_ceiling(monkeypatch, *args):
+    with monkeypatch.context() as m:
+        m.setattr(prefill_plan, "PREFILL_MAX_TOKENS", 1 << 40)
+        plan, capped = plan_wave(*args)
+    assert not capped
+    return plan
+
+
+@pytest.mark.parametrize("lengths,expect", [
+    ([8000] * 16, [(1, 8192)] * 16),          # 16 x 8192 = 131,072: split
+    ([5000, 8192, 6000], [(1, 8192)] * 3),
+    ([4000] * 8, [(8, 4096)]),                # 32,768 positions: allowed
+    ([4000] * 9, [(1, 4096), (8, 4096)]),     # 16 x 4096 is over it
+    ([9000], [(1, 9216)]),                    # one row, whatever its length
+    ([300] * 16, [(16, 512)]),
+], ids=["sixteen-long", "three-long", "eight-at-the-ceiling",
+        "nine-at-4096", "one-row-over-it", "short-rows-untouched"])
+def test_no_program_of_more_rows_than_one_holds_more_than_the_ceiling(
+        lengths, expect, monkeypatch):
+    plan, capped = plan_wave(lengths, [1, 8, 16], LONG_BUCKETS, 16)
+    assert sorted(i for rows, _, _ in plan for i in rows) \
+        == list(range(len(lengths)))
+    assert sorted(_shapes(plan)) == sorted(expect)
+    for _, w, b in plan:
+        assert w == 1 or w * b <= PREFILL_MAX_TOKENS
+    unbounded = _without_the_ceiling(monkeypatch, lengths, [1, 8, 16],
+                                     LONG_BUCKETS, 16)
+    assert capped == (_shapes(plan) != _shapes(unbounded))
+
+
+@pytest.mark.parametrize("widths,chunk,max_len", [
+    ([1, 8, 16], 16, 2048),       # mistral-7b-v0.3-d16: 32 lanes
+    ([1, 8], 8, 8192),            # codestral-22b-v0.1-d8: 8 lanes, 8 x 4096
+    ([1, 8, 16], 16, 2048),       # lfm2-24b-a2b-d9: 64 lanes
+    ([1, 8], 8, 2048),            # chip_smoke
+], ids=["mistral7b", "codestral22b", "lfm2moe", "chip_smoke"])
+def test_the_ceiling_changes_no_plan_of_the_existing_cells(widths, chunk,
+                                                            max_len,
+                                                            monkeypatch):
+    """Every (width, bucket) program those engines can form holds at most
+    PREFILL_MAX_TOKENS positions, so their plans are what they were:
+    random waves of their traffic's lengths plan the same with and
+    without the ceiling."""
+    buckets = [b for b in LONG_BUCKETS if b < max_len] + [max_len]
+    top = min(max_len, 4096)      # Codestral's prompts end at 4,096
+    assert max(widths) * top <= PREFILL_MAX_TOKENS
+    rng = random.Random(34)
+    for _ in range(300):
+        lengths = [rng.randint(1, top) for _ in range(rng.randint(1, 2 * chunk))]
+        plan, capped = plan_wave(lengths, widths, buckets, chunk)
+        assert not capped
+        assert plan == _without_the_ceiling(monkeypatch, lengths, widths,
+                                            buckets, chunk)
