@@ -32,9 +32,12 @@ from ray_tpu.models.vit import ViTConfig, vit_configs
 #     ops.paged_attention.attention_plan, built once by the engine;
 #   project_logits(params, h); lane_state_layers(cfg) (0: the prefix
 #     cache may stay on); routed_layers(cfg): the rows of `counts`, int32
-#     [routed layers, 3] = experts that held a row, the largest load,
-#     assignments computed (0 rows: nothing is counted; a config with
-#     routed layers has `top_k`); `CACHE_KIND`, the word
+#     [routed layers, 4] = experts that held a row, the largest load,
+#     assignments computed, visits of the grouped matmul that were work
+#     (0 rows: nothing is counted; a config with routed layers has
+#     `top_k`, and its module `routed_visits(cfg, rows)`: the length of
+#     the visit list a routed layer pads for a program of `rows` rows);
+#     `CACHE_KIND`, the word
 #     stats()["cache"]["kind"] gives for the pool ("kv": K and V rows);
 # and `SERVING_CAPS`: the optional capabilities it has, under their own
 # names ("prefix": prefill_with_prefix; "lora": the adapter hooks;

@@ -138,6 +138,10 @@ def routed_layers(cfg: Lfm2MoeConfig) -> int:
     return max(0, cfg.n_layers - cfg.n_dense_layers)
 
 
+def routed_visits(cfg: Lfm2MoeConfig, rows: int) -> int:
+    return routed.routed_visits(cfg, rows)
+
+
 # ---------------------------------------------------------------- params
 def init_params(key: jax.Array, cfg: Lfm2MoeConfig,
                 expert_bias_std: float = 0.02) -> dict:

@@ -812,7 +812,7 @@ def decode_step_paged(params: dict, pages: dict, tails: dict,
 # The functions above under the ONE signature serve/llm.LLMEngine calls
 # for every model (models/__init__.py): a dense decoder's lanes keep no
 # state beside the page pool (an empty list: no leaf in any program) and
-# it has no routed layers to count (a [0, 3] array).
+# it has no routed layers to count (a [0, 4] array).
 def lane_state_layers(cfg: LlamaConfig) -> int:
     return 0
 
@@ -826,7 +826,7 @@ def project_logits(params: dict, h: jnp.ndarray) -> jnp.ndarray:
 
 
 def _no_counts() -> jnp.ndarray:
-    return jnp.zeros((0, 3), jnp.int32)
+    return jnp.zeros((0, 4), jnp.int32)
 
 
 def init_paged_cache(cfg: LlamaConfig, batch: int, n_pages: int,

@@ -142,6 +142,10 @@ def routed_layers(cfg: MlaMoeConfig) -> int:
     return max(0, cfg.n_layers - cfg.n_dense_layers)
 
 
+def routed_visits(cfg: MlaMoeConfig, rows: int) -> int:
+    return routed.routed_visits(cfg, rows, cfg.experts_held)
+
+
 # ---------------------------------------------------------------- params
 def init_params(key: jax.Array, cfg: MlaMoeConfig,
                 expert_bias_std: float = 0.02) -> dict:

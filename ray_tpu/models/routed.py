@@ -19,7 +19,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.ops.grouped_matmul import gmm
+from ray_tpu.ops.grouped_matmul import gmm, n_visits, visits_static
+
+COUNTS = 4      # entries of a routed layer's counts (`routed_ffn`)
 
 
 def route(h2, lp, cfg):
@@ -50,8 +52,10 @@ def routed_ffn(h2, lp, cfg, live=None,
     routed nowhere.  `route_fn`: the caller's router (default `route`;
     a serving module passes its own name for it, so that a test's
     control can stand in for that module's router alone).  Returns
-    (y [T, d], counts int32 [3]: experts of the range that hold a row,
-    the largest load, assignments computed)."""
+    (y [T, d], counts int32 [4]: experts of the range that hold a row,
+    the largest load, assignments computed, and the visits of ONE `gmm`
+    call that were work (both calls walk the same list; of
+    `visits_static` at most, the length the list is padded to))."""
     T, d = h2.shape
     k, f = cfg.top_k, cfg.moe_ffn_dim
     lo, hi = experts or (0, cfg.n_experts)
@@ -84,8 +88,18 @@ def routed_ffn(h2, lp, cfg, live=None,
         y = y[place].reshape(T, k, d).astype(jnp.float32)
         out = jnp.sum(y * wts[..., None], axis=1).astype(h2.dtype)
         counts = jnp.stack([jnp.sum(sizes > 0, dtype=jnp.int32),
-                            jnp.max(sizes), jnp.sum(sizes)])
+                            jnp.max(sizes), jnp.sum(sizes),
+                            n_visits(sizes, T * k)])
     return out, counts
+
+
+def routed_visits(cfg, rows: int,
+                  experts: tuple[int, int] | None = None) -> int:
+    """The length the visit list of `routed_ffn`'s grouped matmul is
+    padded to for `rows` rows over the range `experts` (default all):
+    what `counts[3]` is a share of."""
+    lo, hi = experts or (0, cfg.n_experts)
+    return visits_static(rows * cfg.top_k, hi - lo)
 
 
 def swiglu(h, w1, w3, w2, dtype):
@@ -103,7 +117,11 @@ def shared_ffn(h2, lp, dtype):
 
 
 def stack_counts(per_layer: list) -> jnp.ndarray:
-    """The routed layers' counts of one program, int32 [layers, 3] (no
-    rows for a program without a routed layer)."""
-    return (jnp.stack(per_layer) if per_layer
-            else jnp.zeros((0, 3), jnp.int32))
+    """The routed layers' counts of one program, int32 [layers, COUNTS]
+    (no rows for a program without a routed layer).  A row handed in
+    shorter (the benchmark's `layer_skipped` control stands in for a
+    layer with three zeros, and its file is a `benchmark` PR's to edit)
+    reads 0 in what it lacks."""
+    return (jnp.stack([jnp.pad(c, (0, COUNTS - c.shape[0]))
+                       for c in per_layer]) if per_layer
+            else jnp.zeros((0, COUNTS), jnp.int32))

@@ -16,9 +16,17 @@ that holds a row is streamed once a column tile, and a group that holds
 none has no visit: its weights are never read.  A row tile that two
 groups share is visited by each in turn and stored under a row mask
 (the technique of jax's megablox `gmm`; this one keeps the whole
-contraction in one block, pads the visit list to a static length with
-repeats of the last visit, which fetch nothing, and so needs neither a
-dynamic grid nor an accumulator).
+contraction in one block and so needs no accumulator).
+
+The visit axis of the grid is bounded by the number of visits that are
+work, a value the device holds (`visits`' `total`, as `paged_attn`'s
+grid is bounded by its plan's count): the list has the static length
+`visits_static`, the most visits there can be, and the entries past
+`total` pad it and are never walked.  A layer that holds a quarter of
+the experts, or whose rows are mostly padding, pays for the visits its
+rows make and not for the list; with no row at all no step runs, the
+output is left unwritten, and `gmm` zeroes it as it zeroes every row
+past `sum(group_sizes)`.
 
 Elsewhere (the CPU tests) it is `jax.lax.ragged_dot`, as `xla_attention`
 stands in for the flash kernel.
@@ -52,12 +60,25 @@ def _interpret() -> bool:
     return flash_attention._interpret()
 
 
+def row_tile(m: int) -> int:
+    """The row tile `gmm` walks `m` rows by."""
+    return ROW_TILE_SMALL if m <= 64 * ROW_TILE_SMALL else ROW_TILE_LARGE
+
+
+def visits_static(m: int, G: int) -> int:
+    """The length of the visit list `gmm` builds for `m` rows over `G`
+    groups, the most visits there can be: every row tile once, and once
+    more for each group boundary inside a tile."""
+    return -(-m // row_tile(m)) + G - 1
+
+
 def visits(group_sizes: jnp.ndarray, m: int, tm: int):
     """The kernel's visit list for rows tiled by `tm`.
 
-    Returns (group_of_visit [V], tile_of_visit [V], group_offsets [G+1])
-    with V = m/tm + G - 1, the most visits there can be; the list is
-    padded with repeats of its last real visit."""
+    Returns (group_of_visit [V], tile_of_visit [V], group_offsets [G+1],
+    total) with V = m/tm + G - 1, the most visits there can be, and
+    `total` (a scalar) the visits that are work: the kernel walks the
+    first `total` entries; the others repeat the last of them."""
     G = group_sizes.shape[0]
     ends = jnp.cumsum(group_sizes)
     starts = ends - group_sizes
@@ -75,7 +96,14 @@ def visits(group_sizes: jnp.ndarray, m: int, tm: int):
     tile = jnp.clip(tile, 0, m // tm - 1).astype(jnp.int32)
     offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32),
                                ends.astype(jnp.int32)])
-    return g, tile, offsets
+    return g, tile, offsets, total
+
+
+def n_visits(group_sizes: jnp.ndarray, m: int):
+    """The visits `gmm` walks for `m` rows in groups of `group_sizes`: a
+    scalar the device holds, `visits_static(m, G)` at most."""
+    tm = row_tile(m)
+    return visits(group_sizes.astype(jnp.int32), m + -m % tm, tm)[3]
 
 
 def _kernel(g_ref, t_ref, off_ref, x_ref, w_ref, o_ref, *, tm: int):
@@ -95,10 +123,10 @@ def _kernel(g_ref, t_ref, off_ref, x_ref, w_ref, o_ref, *, tm: int):
 def _gmm_pallas(rows, weights, group_sizes, tm: int, tn: int):
     m, k = rows.shape
     G, _, n = weights.shape
-    g, tile, offsets = visits(group_sizes, m, tm)
+    g, tile, offsets, total = visits(group_sizes, m, tm)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(n // tn, g.shape[0]),
+        grid=(n // tn, total),                # the device's own number
         in_specs=[
             pl.BlockSpec((tm, k), lambda j, v, g, t, off: (t[v], 0)),
             pl.BlockSpec((None, k, tn),
@@ -134,7 +162,7 @@ def gmm(rows: jnp.ndarray, weights: jnp.ndarray, group_sizes: jnp.ndarray,
                                  preferred_element_type=jnp.float32)
         out = out.astype(rows.dtype)
     else:
-        tm = ROW_TILE_SMALL if m <= 64 * ROW_TILE_SMALL else ROW_TILE_LARGE
+        tm = row_tile(m)
         tn = COL_TILE if n % COL_TILE == 0 else 128
         pad = -m % tm
         x = jnp.pad(rows, ((0, pad), (0, 0))) if pad else rows

@@ -200,6 +200,17 @@ def _engine_metrics():
                     um.Counter, "serve_llm_moe_assignments_absent",
                     "Selected experts this chip does not hold (an "
                     "expert-parallel share), in decode", tk),
+                # moe_visits / moe_visits_static = the share of the
+                # grouped matmul's visit list that was work
+                "moe_visits": um.get_or_create(
+                    um.Counter, "serve_llm_moe_visits",
+                    "Visits (a group's row tile) a moe_gmm call "
+                    "walked, summed over routed layer-steps of "
+                    "decode", tk),
+                "moe_visits_static": um.get_or_create(
+                    um.Counter, "serve_llm_moe_visits_static",
+                    "The length its visit list is padded to (row "
+                    "tiles + experts held - 1), summed likewise", tk),
                 "attn_ctx_rows": um.get_or_create(
                     um.Counter, "serve_llm_attn_ctx_rows",
                     "Cached rows the decode attention kernel had to "
@@ -511,9 +522,9 @@ class LLMEngine:
                 (ops/paged_attention.py).  The lanes' state (whatever
                 the model keeps beside the pool: a few rows a lane, or
                 nothing) rides the carry, and the routed layers' counts
-                ([routed layers, 3]: experts hit, largest load,
-                assignments; summed over the K steps) come back beside
-                `seq`, fetched in the same sync.  The table and the
+                ([routed layers, 4]: experts hit, largest load,
+                assignments, visits; summed over the K steps) come back
+                beside `seq`, fetched in the same sync.  The table and the
                 block-start positions stand still for the K steps, so
                 the attention kernel's work list is built here, once,
                 for every layer's call of every step."""
@@ -547,7 +558,7 @@ class LLMEngine:
                     nxt = _sample_rows(logits, temps, keys)
                     return (tails, state, pos + 1, nxt, counts + cnt), nxt
 
-                counts0 = jnp.zeros((self._moe_layers, 3), jnp.int32)
+                counts0 = jnp.zeros((self._moe_layers, 4), jnp.int32)
                 (tails, state, pos, last, counts), seq = jax.lax.scan(
                     step, (tails, cache["state"], ts, tokens, counts0),
                     jnp.arange(K))
@@ -760,16 +771,21 @@ class LLMEngine:
         # waves the planner's token ceiling split (PREFILL_MAX_TOKENS)
         self.prefill_programs_capped = 0
         # Routed layers (a model that declares `routed_layers`): layer
-        # x steps run, assignments computed, experts that held a row and
-        # the largest expert load, each summed over layer-steps; decode
-        # and prefill apart.  The device counts; the numbers ride the
-        # token fetch of the window (wave) they belong to.
-        self._prefill_counts: list = []    # (device array, rows) a program
+        # x steps run, assignments computed, experts that held a row,
+        # the largest expert load and the grouped matmul's visits that
+        # were work, each summed over layer-steps; decode and prefill
+        # apart.  The device counts; the numbers ride the token fetch
+        # of the window (wave) they belong to.  `moe_visits_static` is
+        # the length the visit lists were padded to: the ratio is the
+        # share of the list the kernel walked.
+        # (device array, rows routed, rows of its shape) a program
+        self._prefill_counts: list = []
         self.moe = dict.fromkeys(
             [p + k for p in ("", "prefill_")
              for k in ("moe_layer_steps", "moe_assignments",
                        "moe_assignments_absent", "moe_experts_hit",
-                       "moe_max_load")], 0)
+                       "moe_max_load", "moe_visits",
+                       "moe_visits_static")], 0)
         self._funded_blocks = 0        # pages _ensure_decode_blocks got
         self._demote_dispatched = 0    # candidates _maybe_demote took
         # What demotion moved off the device, cumulative, bumped on the
@@ -2002,7 +2018,7 @@ class LLMEngine:
         with self._phase("prefill_sync", iter=it, rows=len(wave)):
             counts, self._prefill_counts = self._prefill_counts, []
             for a in ([nxt for _, nxt, _t in pending_waves]
-                      + [c for c, _ in counts]):
+                      + [c for c, *_ in counts]):
                 try:
                     a.copy_to_host_async()
                 except AttributeError:
@@ -2045,8 +2061,8 @@ class LLMEngine:
                         attrs={"ttft_ms": round(
                             (req.first_token_at - req.submitted_at)
                             * 1000, 1)})
-            for c, rows in counts:  # on the host since the tokens are
-                self._count_moe("prefill_", np.asarray(c), 1, rows)
+            for c, rows, shape in counts:  # on the host since the tokens
+                self._count_moe("prefill_", np.asarray(c), 1, rows, shape)
 
     def _prefill_chunk_full(self, chunk, padded_w: int, bucket: int):
         """Full-prompt prefill (no cached prefix anywhere in the chunk)
@@ -2098,7 +2114,8 @@ class LLMEngine:
         if self._moe_layers:
             # fetched with the wave's first tokens; beside them the rows
             # the program routed (padding rows of the width repeat one)
-            self._prefill_counts.append((counts, int(true_lens.sum())))
+            self._prefill_counts.append(
+                (counts, int(true_lens.sum()), padded_w * bucket))
         # Duplicate padding rows target the same slot + same token.
         self._cur_dev = self._cur_dev.at[slots_dev].set(nxt)
         return nxt
@@ -2572,7 +2589,8 @@ class LLMEngine:
             tokens0, done0 = self.decode_tokens, self.completed
             if moe is not None:
                 hit, load = self._count_moe("", moe, k_win,
-                                            len(active) * k_win)
+                                            len(active) * k_win,
+                                            self.max_batch)
                 ph.update(experts_hit=hit, max_load=load)
             if win_traced:
                 # One K-step decode window per traced co-resident
@@ -2603,15 +2621,17 @@ class LLMEngine:
                       finished=self.completed - done0)
 
     def _count_moe(self, prefix: str, counts, steps: int,
-                   rows: int) -> tuple:
-        """Add one program's routed-layer counts ([layers, 3]: experts
-        hit, largest load, assignments; each summed over the program's
-        `steps`) to the `prefix`ed counters.  `rows`: the rows it routed
-        in each layer, summed over the steps; each selected
-        `cfg.top_k` experts (a config with routed layers has it), and
-        the selections that were not computed went to experts this chip
-        does not hold.  Returns (experts hit, largest load) as means a
-        layer-step."""
+                   rows: int, shape_rows: int) -> tuple:
+        """Add one program's routed-layer counts ([layers, 4]: experts
+        hit, largest load, assignments, visits that were work; each
+        summed over the program's `steps`) to the `prefix`ed counters.
+        `rows`: the rows it routed in each layer, summed over the steps;
+        each selected `cfg.top_k` experts (a config with routed layers
+        has it), and the selections that were not computed went to
+        experts this chip does not hold.  `shape_rows`: the rows a step
+        of the program is shaped for, which set the length its visit
+        lists are padded to.  Returns (experts hit, largest load) as
+        means a layer-step."""
         m, n = self.moe, counts.shape[0] * steps
         computed = int(counts[:, 2].sum())
         m[prefix + "moe_layer_steps"] += n
@@ -2620,6 +2640,9 @@ class LLMEngine:
         m[prefix + "moe_assignments"] += computed
         m[prefix + "moe_assignments_absent"] += (
             rows * self.cfg.top_k * counts.shape[0] - computed)
+        m[prefix + "moe_visits"] += int(counts[:, 3].sum())
+        m[prefix + "moe_visits_static"] += n * self._model.routed_visits(
+            self.cfg, shape_rows)
         return (round(float(counts[:, 0].sum()) / n, 2),
                 round(float(counts[:, 1].sum()) / n, 2))
 
@@ -2679,7 +2702,8 @@ class LLMEngine:
         if self._moe_layers:
             cur.update({k: self.moe[k] for k in (
                 "moe_layer_steps", "moe_experts_hit", "moe_assignments",
-                "moe_assignments_absent")})
+                "moe_assignments_absent", "moe_visits",
+                "moe_visits_static")})
         with self._metrics_lock:
             self._metrics_t = now
             for key, val in cur.items():

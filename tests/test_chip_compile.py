@@ -118,22 +118,28 @@ def test_flash_attention_compiles(one_chip, compiled_kernels,
     assert low.as_text().count("tpu_custom_call") == (3 if grad else 1)
 
 
-@pytest.mark.parametrize("m,k,n", [
-    (256, 2048, 3072),        # LFM2-24B-A2B decode: 64 lanes x 4 -> w13
-    (256, 1536, 2048),        # ... -> w2
-    (65536, 2048, 3072),      # its widest prefill wave: 16 x 1024 x 4
-    (4096, 1536, 2048),
+@pytest.mark.parametrize("G,m,k,n", [
+    (64, 256, 2048, 3072),    # LFM2-24B-A2B decode: 64 lanes x 4 -> w13
+    (64, 256, 1536, 2048),    # ... -> w2
+    (64, 65536, 2048, 3072),  # its widest prefill wave: 16 x 1024 x 4
+    (64, 4096, 1536, 2048),
+    (32, 65536, 4096, 4096),  # sarvam-105b, 32 of 128 held: 1 x 8192 x 8
+    (32, 65536, 2048, 4096),
+    (32, 256, 4096, 4096),    # its decode: 32 lanes x 8
+    (32, 256, 2048, 4096),
 ])
-def test_grouped_matmul_compiles(one_chip, compiled_kernels, m, k, n):
-    """ops/grouped_matmul.py's `moe_gmm` at the served widths: 64 experts,
-    a 16-row tile for decode and a 256-row one for prefill."""
+def test_grouped_matmul_compiles(one_chip, compiled_kernels, G, m, k, n):
+    """ops/grouped_matmul.py's `moe_gmm` at the served widths, a 16-row
+    tile for decode and a 256-row one for prefill: the visit axis of its
+    grid is bounded by a count the device holds, beside the static
+    column axis, and the chip's compiler takes it."""
     from ray_tpu.ops.grouped_matmul import gmm
 
     def s(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     low, c = _compile(lambda x, w, g: gmm(x, w, g, impl="pallas"),
-                      s((m, k)), s((64, k, n)), s((64,), jnp.int32))
+                      s((m, k)), s((G, k, n)), s((G,), jnp.int32))
     assert low.as_text().count("tpu_custom_call") == 1
     assert "moe_gmm" in low.as_text()
     assert c.memory_analysis().temp_size_in_bytes < 2 * 1024 ** 3

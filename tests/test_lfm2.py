@@ -153,7 +153,7 @@ def test_the_routed_layer_drops_nothing_under_skew(params, case):
     y, counts = lfm2.routed_ffn(h2, lp, CFG)
     assert float(np.abs(np.asarray(y) - _loop_ffn(h2, lp, idx, wts)).max()) \
         < TOL
-    hit, load, n = (int(c) for c in counts)
+    hit, load, n, _ = (int(c) for c in counts)
     assert n == 40 * 4                      # every assignment computed
     if case == "all_to_one":
         assert (hit, load) == (4, 40)
@@ -194,10 +194,111 @@ def test_gmm_visits_no_empty_group():
     """The kernel's visit list names only groups that hold a row, so an
     expert nobody was sent to is never fetched."""
     sizes = jnp.asarray([0, 0, 10, 0, 20, 1, 0, 5], jnp.int32)
-    g, tile, _ = grouped_matmul.visits(sizes, 48, 16)
+    g, tile, _, total = grouped_matmul.visits(sizes, 48, 16)
     assert set(np.asarray(g).tolist()) == {2, 4, 5, 7}
-    assert g.shape[0] == 48 // 16 + 8 - 1
+    assert g.shape[0] == 48 // 16 + 8 - 1 == grouped_matmul.visits_static(
+        48, 8)
     assert (np.diff(np.asarray(tile)) >= 0).all()
+    # rows 0-9 | 10-29 | 30 | 31-35: tiles 0 | 0, 1 | 1 | 1, 2
+    assert int(total) == 6
+    assert len(set(zip(np.asarray(g).tolist(),
+                       np.asarray(tile).tolist()))) == 6
+
+
+def _gmm_over_the_padded_list(rows, weights, group_sizes, tm, tn):
+    """The form `moe_gmm` had before its grid was bounded by the visits
+    that are work, kept as the reference: a STATIC grid over the whole
+    padded list, every padded visit multiplying and storing again what
+    the last real one stored."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    m, k = rows.shape
+    n = weights.shape[2]
+    g, tile, offsets, _ = grouped_matmul.visits(group_sizes, m, tm)
+
+    def kernel(g_ref, t_ref, off_ref, x_ref, w_ref, o_ref):
+        v = pl.program_id(1)
+        acc = jnp.dot(x_ref[...], w_ref[...],
+                      preferred_element_type=jnp.float32)
+        r = t_ref[v] * tm + jax.lax.broadcasted_iota(jnp.int32, acc.shape, 0)
+        mine = (r >= off_ref[g_ref[v]]) & (r < off_ref[g_ref[v] + 1])
+        o_ref[...] = jnp.where(mine, acc, o_ref[...].astype(jnp.float32)
+                               ).astype(o_ref.dtype)
+
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(n // tn, g.shape[0]),
+            in_specs=[
+                pl.BlockSpec((tm, k), lambda j, v, g, t, off: (t[v], 0)),
+                pl.BlockSpec((None, k, tn),
+                             lambda j, v, g, t, off: (g[v], 0, j))],
+            out_specs=pl.BlockSpec((tm, tn),
+                                   lambda j, v, g, t, off: (t[v], j))),
+        out_shape=jax.ShapeDtypeStruct((m, n), rows.dtype),
+        interpret=True)(g, tile, offsets, rows, weights)
+    held = jnp.arange(m)[:, None] < jnp.sum(group_sizes)
+    return jnp.where(held, out, jnp.zeros((), out.dtype))
+
+
+@pytest.mark.parametrize("m", [48, 2048], ids=["tile16", "tile256"])
+@pytest.mark.parametrize("sizes", [
+    lambda m: [m // 8] * 8,                     # the list is full
+    lambda m: [m // 16, 0, m // 8, 0, 0, m // 16, 0, 0],  # 3/4: nobody's
+    lambda m: [0, 0, 0, 0, 0, 0, 1, 0],         # one row
+    lambda m: [0] * 8,                          # total = 0: no step runs
+], ids=["full", "a_quarter", "one_row", "none"])
+def test_gmm_walks_the_visits_that_are_work_bit_for_bit(m, sizes):
+    """Bounding the grid by `total` changes no bit: a padded visit only
+    stored again what the last real one had stored.  With no visit at
+    all the kernel writes nothing, and every row is exactly 0."""
+    sizes = jnp.asarray(sizes(m), jnp.int32)
+    x = jax.random.normal(jax.random.PRNGKey(0), (m, 128), jnp.bfloat16)
+    w = jax.random.normal(jax.random.PRNGKey(1), (8, 128, 256), jnp.bfloat16)
+    got = grouped_matmul.gmm(x, w, sizes, impl="pallas")
+    tm = grouped_matmul.row_tile(m)
+    want = _gmm_over_the_padded_list(x, w, sizes, tm, 128)
+    got, want = np.asarray(got), np.asarray(want)
+    assert not np.isnan(got.astype(np.float32)).any()
+    assert (got.view(np.uint16) == want.view(np.uint16)).all()
+    assert (got[int(sizes.sum()):].astype(np.float32) == 0).all()
+
+
+@pytest.mark.parametrize("rows,experts,live", [
+    (40, (0, 8), "all"), (40, (2, 4), "all"), (40, (0, 8), "none"),
+    (40, (6, 8), "half"), (512, (0, 8), "all"), (512, (4, 6), "half"),
+], ids=lambda v: "-".join(map(str, v)) if isinstance(v, tuple) else str(v))
+def test_routed_ffn_counts_the_visits_that_are_work(params, rows, experts,
+                                                    live):
+    """counts[3] is the number of REAL entries of the list `gmm` builds
+    for the layer's sorted rows (one for each row tile a group that
+    holds a row lies in), and never more than the padded length."""
+    lp = params["layers"][2]
+    lo, hi = experts
+    held = dict(lp, w13=lp["w13"][lo:hi], w2=lp["w2"][lo:hi])
+    h2 = jax.random.normal(jax.random.PRNGKey(8), (rows, CFG.dim))
+    mask = {"all": None, "none": jnp.zeros(rows, bool),
+            "half": jnp.arange(rows) % 2 == 0}[live]
+    _, counts = lfm2.routed_ffn(h2, held, CFG, live=mask, experts=experts)
+    idx = np.asarray(lfm2.route(h2, lp, CFG)[0])
+    if mask is not None:
+        idx = idx[np.asarray(mask)]
+    sizes = np.bincount(idx.ravel(), minlength=8)[lo:hi]
+    m = rows * CFG.top_k
+    tm = grouped_matmul.row_tile(m)
+    off = np.concatenate([[0], np.cumsum(sizes)])
+    real = sum((off[i + 1] - 1) // tm - off[i] // tm + 1
+               for i in range(hi - lo) if sizes[i])
+    g, tile, _, total = grouped_matmul.visits(
+        jnp.asarray(sizes, jnp.int32), m, tm)
+    assert int(counts[3]) == int(total) == real
+    if real:
+        assert real == len(set(zip(np.asarray(g).tolist(),
+                                   np.asarray(tile).tolist())))
+    assert int(counts[2]) == sizes.sum()
+    static = m // tm + (hi - lo) - 1
+    assert real <= static == lfm2.routed.routed_visits(CFG, rows, experts)
 
 
 # ------------------------------------------------- (5) ranges of experts

@@ -151,6 +151,11 @@ def test_engine_generates_the_reference_tokens(params):
     assert (loop["moe_assignments"] + loop["moe_assignments_absent"]
             == loop["lane_steps_live"] * CFG.top_k * routed_layers)
     assert 0 < loop["moe_experts_hit"] <= 4 * loop["moe_layer_steps"]
+    # a decode step routes 2 lanes x 4 selections: one row tile and, at
+    # most, a visit more for each of the 3 boundaries between 4 experts
+    assert 0 < loop["moe_visits"] <= loop["moe_visits_static"] \
+        == 4 * loop["moe_layer_steps"]
+    assert 0 < loop["prefill_moe_visits"] <= loop["prefill_moe_visits_static"]
     assert (loop["prefill_moe_assignments"]
             + loop["prefill_moe_assignments_absent"]
             >= 4 * sum(map(len, prompts)) * routed_layers)
@@ -158,6 +163,38 @@ def test_engine_generates_the_reference_tokens(params):
         "kind": "latent", "row_bytes": CFG.row_width * 4, "layers": 3,
         "pool_bytes": 3 * 6 * PAGE * CFG.row_width * 4}
     assert "lane_state" not in st and st["prefix_cache"] is False
+
+
+@pytest.mark.parametrize("held", [(0, 4), (0, 2), (6, 8)],
+                         ids=["a_half", "a_quarter", "the_last_quarter"])
+def test_the_share_of_the_visit_list_that_is_work(held):
+    """`moe_visits / moe_visits_static`, decode and prefill apart: the
+    visits the grouped matmul walked over the length its lists were
+    padded to.  A prompt of 40 in a bucket of 64 routes 160 selections
+    of the list's 256 rows; a chip that holds a quarter of the experts
+    computes about 40 of them, so most of its list pads it."""
+    cfg = dataclasses.replace(CFG, experts_held=held)
+    eng = LLMEngine(cfg, mla_moe.init_params(jax.random.PRNGKey(7), cfg),
+                    max_batch=2, max_len=96, page_size=PAGE,
+                    steps_per_sync=K)
+    eng.start()
+    try:
+        eng.generate(_tokens(40, 5).tolist(), max_new_tokens=5)
+        loop = eng.stats()["loop"]
+    finally:
+        eng.stop()
+    G, layers = held[1] - held[0], mla_moe.routed_layers(cfg)
+    assert loop["prefill_moe_layer_steps"] == layers
+    assert loop["prefill_moe_visits_static"] == layers * (
+        64 * cfg.top_k // 16 + G - 1)
+    assert loop["moe_visits_static"] == loop["moe_layer_steps"] * (1 + G - 1)
+    for p in ("", "prefill_"):
+        assert 0 < loop[p + "moe_visits"] <= loop[p + "moe_visits_static"]
+        # a visit holds a row, and a row tile 16 of them at most
+        assert loop[p + "moe_visits"] >= loop[p + "moe_assignments"] / 16
+    if G == 2:
+        assert loop["prefill_moe_visits"] < \
+            0.5 * loop["prefill_moe_visits_static"]
 
 
 def test_attn_ctx_rows_counts_what_the_kernel_admits(params):
