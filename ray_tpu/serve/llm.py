@@ -125,6 +125,137 @@ def _pow2(n: int) -> int:
 _METRICS = None
 _METRICS_LOCK = threading.Lock()
 
+# Every program JAX builds in this process, whoever asked for it: the
+# three stages JAX itself times, by the events it reports them under.
+# PROCESS-WIDE (one replica holds one engine), cumulative since the
+# first LLMEngine was constructed, on with RAY_TPU_TRACE=0 too.
+_BUILD_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile"}
+_CACHE_ASKED = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_BUILDS = {"program_builds": 0,         # compile stages
+           "program_build_s": 0.0,      # outermost stages' seconds
+           "program_cache_misses": 0}   # compile stages the cache missed
+_BUILDS_LOCK = threading.Lock()
+_BUILD_OPEN = threading.local()     # .depth: stages open on this thread
+_build_listening = False            # under _BUILDS_LOCK
+_build_root = None                  # under _BUILDS_LOCK: see _build_ctx
+# a stage that runs INSIDE another (a jitted helper traced while its
+# caller is) and ends under this gets no span: one program's build must
+# not flood the ring.  Its seconds are its caller's already.
+_BUILD_SPAN_MIN_S = 1e-3
+
+
+def _on_build_stage_start(event: str, _start: float, **_kw) -> None:
+    if event in _BUILD_STAGES:
+        _BUILD_OPEN.depth = getattr(_BUILD_OPEN, "depth", 0) + 1
+        _BUILD_OPEN.cache = "off"
+
+
+def _on_cache_event(event: str, **_kw) -> None:
+    # inside the compile stage, on its thread: asked, then maybe hit
+    if event == _CACHE_ASKED:
+        _BUILD_OPEN.cache = "miss"
+    elif event == _CACHE_HIT:
+        _BUILD_OPEN.cache = "hit"
+
+
+def _on_build_stage_end(event: str, t0: float, t1: float,
+                        fun_name: str = "", **_kw) -> None:
+    """One stage of one program's build ended: JAX's own start and end,
+    on `time.time()`, the flight recorder's clock."""
+    stage = _BUILD_STAGES.get(event)
+    if stage is None:
+        return
+    depth = _BUILD_OPEN.depth = max(0, getattr(_BUILD_OPEN, "depth", 1) - 1)
+    attrs = {"fun": fun_name, "stage": stage, "depth": depth,
+             "thread": threading.current_thread().name}
+    with _BUILDS_LOCK:
+        if depth == 0:      # nested stages' seconds lie inside this one's
+            _BUILDS["program_build_s"] += t1 - t0
+        if stage == "compile":
+            attrs["cache"] = getattr(_BUILD_OPEN, "cache", "off")
+            _BUILDS["program_builds"] += 1
+            _BUILDS["program_cache_misses"] += attrs["cache"] == "miss"
+    if tracing.ENABLED and (depth == 0 or stage == "compile"
+                            or t1 - t0 >= _BUILD_SPAN_MIN_S):
+        tracing.emit("llm.program_build", t0, t1, ctx=_build_ctx(),
+                     attrs=attrs)
+
+
+def _build_ctx() -> tuple | None:
+    """What a build's span hangs off: the traced request it was built
+    inside, if any, else this process's one zero-length `llm.programs`
+    root (as the loop's phases hang off `llm.engine`).  Never a trace of
+    its own: `tracing.slowest` and `attribution` would rank every stage
+    of every build as a request."""
+    global _build_root
+    ctx = tracing.current()
+    if ctx is not None:
+        return ctx
+    with _BUILDS_LOCK:
+        if _build_root is None:
+            now = time.time()
+            _build_root = tracing.emit("llm.programs", now, now,
+                                       ctx=(tracing.new_id(), ""))
+        return _build_root
+
+
+def _listen_for_program_builds() -> None:
+    """Register the listeners, once a process.  Called by LLMEngine's
+    constructor and not at import: this module imports no JAX, and a
+    driver that imports it stays off JAX."""
+    global _build_listening
+    from jax import monitoring
+
+    with _BUILDS_LOCK:
+        if _build_listening:
+            return
+        _build_listening = True
+        monitoring.register_scalar_listener(_on_build_stage_start)
+        monitoring.register_event_listener(_on_cache_event)
+        monitoring.register_event_time_span_listener(_on_build_stage_end)
+
+
+# The ledger's rows.  A thread is filed under the first of these its
+# name starts with (a pool's threads are "<prefix>_<n>", an actor's
+# executors "actor-<id>[-<group>]_<n>") and any other thread under
+# "other": the rows, and with them the labels of
+# serve_llm_thread_cpu_seconds, are a fixed set however many
+# connections, actors and default-named threads come and go.
+_THREAD_ROWS = ("llm-engine", "llm-kv-export", "serve-call", "actor",
+                "task-exec", "raytpu-io", "raytpu-putcopy", "asyncio",
+                "MainThread")
+
+
+def _thread_cpu_ledger() -> dict:
+    """CPU seconds of this PROCESS's live Python threads by row
+    (`_THREAD_ROWS`), beside the process's and the wall clock at the
+    reading (two readings give a rate).  Over an interval, what the rows
+    other than `llm-engine` gained is what could have held the GIL while
+    the engine thread stood; `process_cpu_s` less the sum is the native
+    threads (the XLA runtime, transfers) and Python threads that have
+    ended."""
+    by: dict = {}
+    for t in threading.enumerate():
+        if t.native_id is None:
+            continue
+        try:
+            # the kernel's CPU clock of thread `native_id`, the id glibc's
+            # pthread_getcpuclockid computes, without handing glibc the
+            # pthread_t of a thread that may have ended meanwhile
+            cpu = time.clock_gettime((~t.native_id << 3) | 6)
+        except OSError:     # ended since enumerate()
+            continue
+        row = next((r for r in _THREAD_ROWS if t.name.startswith(r)),
+                   "other")
+        by[row] = by.get(row, 0.0) + cpu
+    return {"wall_s": time.time(), "process_cpu_s": time.process_time(),
+            "by_name": by}
+
+
 # Latency-histogram bucket upper bounds in ms: sub-ms router picks
 # through prefills up to pathological multi-second p99s the flight
 # recorder exists to attribute.
@@ -227,6 +358,28 @@ def _engine_metrics():
                     "Host bytes of KV pages demoted to the prefix "
                     "store (fetched off the device, 2 x layers pieces "
                     "a page)", tk),
+                # a phase's wall seconds are stats()["loop"]["phase_s"];
+                # what they have more than these is the time the engine
+                # thread stood, and thread_cpu_s says who ran meanwhile
+                "phase_cpu_s": um.get_or_create(
+                    um.Counter, "serve_llm_phase_cpu_seconds",
+                    "CPU seconds of the engine thread in each phase of "
+                    "its loop (time.thread_time)", ("engine", "phase")),
+                "thread_cpu_s": um.get_or_create(
+                    um.Counter, "serve_llm_thread_cpu_seconds",
+                    "CPU seconds of the replica process's live Python "
+                    "threads by a fixed set of rows (a pool's prefix, "
+                    "or other); read when stats() is",
+                    ("engine", "thread")),
+                "program_builds": um.get_or_create(
+                    um.Counter, "serve_llm_program_builds",
+                    "Programs the process compiled or loaded from the "
+                    "compile cache: rising after warm-up is a "
+                    "recompile", tk),
+                "program_build_s": um.get_or_create(
+                    um.Counter, "serve_llm_program_build_seconds",
+                    "Seconds the process spent tracing, lowering and "
+                    "compiling (or loading) programs", tk),
                 "preemptions": um.get_or_create(
                     um.Counter, "serve_llm_preemptions",
                     "Requests preempted for KV blocks", tk),
@@ -373,6 +526,7 @@ class LLMEngine:
         from ray_tpu.models import serving_model
 
         _check_paged(paged)
+        _listen_for_program_builds()    # before the engine's own programs
         model = self._model = serving_model(cfg)
         caps = model.SERVING_CAPS
         # Per-lane state beside the page pool (a convolution's last
@@ -764,6 +918,7 @@ class LLMEngine:
         # j + 1), summed over lanes, steps and windows.
         self.attn_ctx_rows = 0
         self.phase_s = dict.fromkeys(_LOOP_PHASES, 0.0)
+        self.phase_cpu_s = dict.fromkeys(_LOOP_PHASES, 0.0)
         self.prefill_padded_tokens = 0  # width bucket x length bucket
         self.prefill_programs = 0      # (width, length) programs dispatched
         self.prefill_waves = 0
@@ -839,7 +994,7 @@ class LLMEngine:
         # — the controller's SLO loop consumes this via stats() →
         # replica_metrics; the histograms quantize, this doesn't).
         self._slo_window = slo.LatencyWindow()
-        self._metrics_last: dict[str, float] = {}
+        self._metrics_last: dict = {}
         self._metrics_t = 0.0
         # stats() flushes from replica threads while the loop flushes on
         # its own cadence; the delta bookkeeping must not double-count.
@@ -1486,8 +1641,12 @@ class LLMEngine:
         """One phase of the engine thread's timeline, and the only way
         one is recorded: `with self._phase("fund", iter=it) as ph:`.
         The block (1) adds its perf_counter duration to the cumulative
-        `phase_s` counter, always; (2) becomes one flight-recorder span
-        `llm.loop.<key>` when tracing is on; (3) runs under a
+        `phase_s` counter and the thread's own CPU time in it
+        (`time.thread_time`) to `phase_cpu_s`, always: the difference is
+        the time the thread STOOD there, off the processor (waiting for
+        the GIL, a lock, the device); (2) becomes one flight-recorder
+        span `llm.loop.<key>`, carrying `cpu_ms`, when tracing is on;
+        (3) runs under a
         `jax.profiler.TraceAnnotation`, so it is a host event, on the
         profiler's clock, in any profiler trace that is running.  `with`
         yields the span's attrs: the block adds what it learns (the
@@ -1497,11 +1656,15 @@ class LLMEngine:
         with TraceAnnotation("llm.loop." + key, **attrs):
             w0 = time.time() if tracing.ENABLED else 0.0
             t0 = time.perf_counter()
+            c0 = time.thread_time()
             try:
                 yield attrs
             finally:
+                cpu = time.thread_time() - c0
                 self.phase_s[key] += time.perf_counter() - t0
+                self.phase_cpu_s[key] += cpu
                 if w0 and tracing.ENABLED:
+                    attrs["cpu_ms"] = round(cpu * 1e3, 3)
                     tracing.emit("llm.loop." + key, w0, time.time(),
                                  ctx=self._loop_ctx(), attrs=attrs)
 
@@ -2670,11 +2833,13 @@ class LLMEngine:
                 self._dispatch_demotes()
                 self._flush_metrics()
 
-    def _flush_metrics(self, force: bool = False) -> None:
+    def _flush_metrics(self, force: bool = False,
+                       threads: dict | None = None) -> None:
         """Export engine/cache counters as process metrics (→ controller
         KV → dashboard /metrics).  Counters flush as deltas against the
         last snapshot; throttled to ~1 Hz so the loop never stalls on
-        the registry lock."""
+        the registry lock.  `threads`: the CPU ledger's rows, where the
+        caller (`stats`) has just read them."""
         now = time.monotonic()
         if not force and now - self._metrics_t < 1.0:
             return
@@ -2704,6 +2869,12 @@ class LLMEngine:
                 "moe_layer_steps", "moe_experts_hit", "moe_assignments",
                 "moe_assignments_absent", "moe_visits",
                 "moe_visits_static")})
+        with _BUILDS_LOCK:
+            cur["program_builds"] = _BUILDS["program_builds"]
+            cur["program_build_s"] = _BUILDS["program_build_s"]
+        # (counter, tag it is split by, its rows)
+        split = [("phase_cpu_s", "phase", dict(self.phase_cpu_s)),
+                 ("thread_cpu_s", "thread", threads or {})]
         with self._metrics_lock:
             self._metrics_t = now
             for key, val in cur.items():
@@ -2711,6 +2882,12 @@ class LLMEngine:
                 if delta > 0:
                     m[key].inc(delta, tags)
                 self._metrics_last[key] = val
+            for key, tag, rows in split:
+                for row, val in rows.items():
+                    delta = val - self._metrics_last.get((key, row), 0.0)
+                    if delta > 0:
+                        m[key].inc(delta, {**tags, tag: row})
+                    self._metrics_last[key, row] = val
         m["occupancy"].set(
             sum(s is not None for s in self._slots) / self.max_batch,
             tags)
@@ -2783,6 +2960,9 @@ class LLMEngine:
                    "attn_steps_dense": self.attn_steps_dense,
                    "attn_ctx_rows": self.attn_ctx_rows,
                    "phase_s": dict(self.phase_s),
+                   # the thread's own CPU seconds in each phase: what
+                   # phase_s has more is the time it stood there
+                   "phase_cpu_s": dict(self.phase_cpu_s),
                    # the prefix store's demotion: pages and host bytes
                    # fetched off the device, and the export thread's
                    # seconds in those fetches (bytes a second demoted,
@@ -2798,6 +2978,12 @@ class LLMEngine:
                    "prefill_programs_capped":
                    self.prefill_programs_capped},
                "cache": dict(self._cache_info)}
+        with _BUILDS_LOCK:
+            # every program this PROCESS built since its first engine
+            # was made; the ledger of threads is the process's too
+            out["loop"].update(
+                _BUILDS, program_build_s=round(_BUILDS["program_build_s"], 6))
+        out["threads"] = _thread_cpu_ledger()
         if self._moe_layers:
             out["loop"].update(self.moe)
         if self._lane_layers:
@@ -2832,7 +3018,7 @@ class LLMEngine:
         out["prefix_hit_tokens"] = kv["hit_tokens"]
         out["evictions"] = kv["evictions"]
         out["cow_copies"] = kv["cow_copies"]
-        self._flush_metrics(force=True)
+        self._flush_metrics(force=True, threads=out["threads"]["by_name"])
         return out
 
 

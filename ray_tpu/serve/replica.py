@@ -113,7 +113,8 @@ class Replica:
         # well — the thread pool only bounds sync ones.
         self._slots = asyncio.Semaphore(max_ongoing_requests)
         self._pool = concurrent.futures.ThreadPoolExecutor(
-            max_workers=max(2, max_ongoing_requests))
+            max_workers=max(2, max_ongoing_requests),
+            thread_name_prefix="serve-call")
         import ray_tpu
 
         global _current_context

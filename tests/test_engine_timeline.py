@@ -143,7 +143,7 @@ def test_phases_hang_off_the_engine_root_and_iter_is_monotone(traced_run):
     assert disp[-1]["attrs"]["rows"] == 1
     assert set(disp[-1]["attrs"]) == {
         "iter", "rows", "width_bucket", "len_bucket", "true_tokens",
-        "padded_tokens", "chunks", "plan"}
+        "padded_tokens", "chunks", "plan", "cpu_ms"}
     dec = [s for s in spans if s["name"] == "llm.loop.decode_dispatch"]
     assert {s["attrs"]["steps"] for s in dec} == {4}
     assert max(s["attrs"]["lanes"] for s in dec) == 4
@@ -203,7 +203,7 @@ def test_prefill_counters_by_hand(small):
 
     def delta(a, b):
         return {k: b["loop"][k] - a["loop"][k] for k in b["loop"]
-                if k != "phase_s"}
+                if k not in ("phase_s", "phase_cpu_s")}
 
     # three equal rows: 4 x 128 = 512 positions, under three 1 x 128 at
     # the floor each (768): one program, as arrival order gave
@@ -222,7 +222,8 @@ def test_prefill_counters_by_hand(small):
     assert d["prefill_true_tokens"] == 320
     assert (d["prefill_programs"], d["prefill_waves"],
             d["prefill_waves_split"]) == (2, 1, 1)
-    first, second = [s["attrs"] for s in _dispatch_spans(eng)]
+    first, second = [{k: v for k, v in s["attrs"].items() if k != "cpu_ms"}
+                     for s in _dispatch_spans(eng)]
     assert first == {
         "iter": first["iter"], "rows": 3, "chunks": 1, "plan": "4x128",
         "width_bucket": 4, "len_bucket": 128, "true_tokens": 300,
@@ -299,6 +300,8 @@ def test_no_compile_after_a_warmup_of_equal_rows(small):
     finally:
         eng.stop()
     assert [f._cache_size() for f in programs] == sizes
+    assert s1["program_builds"] == s0["program_builds"]
+    assert s1["program_build_s"] == s0["program_build_s"]
     assert s1["prefill_waves"] - s0["prefill_waves"] == 10
     assert s1["prefill_waves_split"] > s0["prefill_waves_split"]
     plans = {p for s in _dispatch_spans(eng)
@@ -355,18 +358,256 @@ def test_counters_advance_with_tracing_off(small):
         eng.start()
         try:
             _one_wave(eng, [_prompt(20, i) for i in range(2)], 9)
-            loop = eng.stats()["loop"]
+            st = eng.stats()
+            loop = st["loop"]
         finally:
             eng.stop()
         assert loop["decode_steps"] == 8 and loop["lane_steps_live"] == 16
         assert loop["prefill_padded_tokens"] == 4 * 32
         assert loop["phase_s"]["decode_dispatch"] > 0
         assert loop["phase_s"]["prefill_dispatch"] > 0
+        # and what PR 36 records beside them: no recorder needed
+        assert loop["phase_cpu_s"]["decode_dispatch"] > 0
+        assert loop["phase_cpu_s"]["prefill_dispatch"] > 0
+        # this engine's own programs, at least (the counters are the
+        # process's: an earlier test's engine may have built the rest)
+        assert loop["program_builds"] >= 1 and loop["program_build_s"] > 0
+        assert st["threads"]["by_name"]["llm-engine"] > 0
         assert eng._loop_trace is None
         assert not [r for r in tracing.snapshot()
                     if r["name"].startswith("llm.")]
     finally:
         tracing.set_enabled(True)
+
+
+# ------------------------- PR 36: did the thread RUN where it was, who
+# ------------------------- ran beside it, and what was built
+HOST_PHASES = ("admit", "prefill_dispatch", "fund", "decode_dispatch",
+               "deliver")
+
+
+def test_phase_cpu_beside_phase_wall(traced_run):
+    eng, spans, _, _ = traced_run
+    loop = eng.stats()["loop"]
+    assert set(loop["phase_cpu_s"]) == set(loop["phase_s"]) == set(PHASES)
+    for p in PHASES:
+        # a thread cannot run longer than the wall (1 ms: the two clocks
+        # are read one after the other)
+        assert 0.0 <= loop["phase_cpu_s"][p] <= loop["phase_s"][p] + 1e-3, p
+    # the waits burn no CPU: the engine thread sleeps in them
+    assert loop["phase_cpu_s"]["idle"] < 0.5 * loop["phase_s"]["idle"]
+    assert spans and all("cpu_ms" in s["attrs"] for s in spans)
+    for s in spans:
+        assert 0.0 <= s["attrs"]["cpu_ms"] \
+            <= (s["t1"] - s["t0"]) * 1e3 + 1.0, s
+
+
+def _stood_and_rival(eng, windows=20):
+    """Seconds the engine thread stood in its host phases, and CPU
+    seconds the ledger's row `serve-call` gained (the rival is named as
+    a caller's thread is), over `windows` decode windows of one
+    request."""
+    s0 = eng.stats()
+    eng.generate(_prompt(40, 3), max_new_tokens=4 * windows + 1,
+                 _cache_ok=False)
+    s1 = eng.stats()
+
+    def stood(s):
+        return sum(s["loop"]["phase_s"][p] - s["loop"]["phase_cpu_s"][p]
+                   for p in HOST_PHASES)
+
+    def rival(s):
+        return s["threads"]["by_name"].get("serve-call", 0.0)
+
+    return stood(s1) - stood(s0), rival(s1) - rival(s0)
+
+
+@pytest.mark.parametrize("rival", ["spins", "sleeps"])
+def test_a_rival_thread_shows_in_stood_time_and_in_the_ledger(small, rival):
+    """A thread that spins in pure Python holds the GIL a switch
+    interval at a time: the engine thread stands in its host phases
+    each time it comes back from native code, and the ledger shows the
+    rival's CPU.  One that sleeps raises neither."""
+    import sys
+    import threading
+
+    stop = threading.Event()
+
+    def spin():
+        n = 0
+        while not stop.is_set():
+            n += 1
+
+    def sleep():
+        while not stop.wait(0.01):
+            pass
+
+    t = threading.Thread(target=spin if rival == "spins" else sleep,
+                         name="serve-call_0", daemon=True)
+    interval = sys.getswitchinterval()
+    eng = _engine(small)
+    eng.start()
+    try:
+        _one_wave(eng, [_prompt(40)], 9)        # warm
+        alone, none = _stood_and_rival(eng)
+        # a handoff costs the engine thread one interval: long enough to
+        # stand out from a busy box's scheduling
+        sys.setswitchinterval(0.05)
+        t.start()
+        beside, rival_cpu = _stood_and_rival(eng)
+    finally:
+        sys.setswitchinterval(interval)
+        stop.set()
+        t.join(timeout=30.0)
+        eng.stop()
+    assert not t.is_alive() and none == 0.0
+    if rival == "spins":
+        # twenty windows, at least one handoff each
+        assert beside > alone + 0.4, (alone, beside)
+        assert rival_cpu > 0.4, rival_cpu
+    else:
+        # under what the spinner must pass, not under a scheduler's
+        # whim: both are sums of wall less CPU over twenty windows, and
+        # a busy box or a CPU clock that ticks moves either by tenths
+        assert beside < alone + 0.4, (alone, beside)
+        assert rival_cpu < 0.1, rival_cpu
+
+
+def _burn(seconds):
+    t_end = time.thread_time() + seconds
+    while time.thread_time() < t_end:
+        pass
+
+
+def test_the_ledger_sums_a_pool_under_its_prefix(small):
+    import concurrent.futures
+    import threading
+
+    def burn(barrier):
+        barrier.wait(timeout=30.0)      # one task a thread of the pool
+        _burn(0.03)
+
+    eng = _engine(small)
+    pool = concurrent.futures.ThreadPoolExecutor(
+        max_workers=3, thread_name_prefix="serve-call")
+    try:
+        barrier = threading.Barrier(3)
+        for f in [pool.submit(burn, barrier) for _ in range(3)]:
+            f.result(timeout=60.0)
+        led = eng.stats()["threads"]
+    finally:
+        pool.shutdown()
+    by = led["by_name"]
+    assert set(led) == {"wall_s", "process_cpu_s", "by_name"}
+    assert led["wall_s"] <= time.time()
+    assert by["serve-call"] >= 3 * 0.03
+    assert by["MainThread"] > 0
+    # the process's clock also holds the native threads and the ended
+    assert led["process_cpu_s"] >= sum(by.values())
+
+
+@pytest.mark.parametrize("name,row", [
+    ("actor-0000000375f5_0", "actor"),          # an actor's executor
+    ("actor-0000000375f5-io_1", "actor"),       # and its named group's
+    (None, "other"),            # "Thread-<n> (serve)": a connection's
+    ("kv-store", "other"),
+])
+def test_the_ledger_rows_are_a_fixed_set(name, row):
+    """However threads are named and however many come and go, the
+    ledger's rows (and with them the labels of
+    `serve_llm_thread_cpu_seconds` and the engine's `_metrics_last`) are
+    `_THREAD_ROWS` and `other`; a thread that has ended is left out, not
+    asked for its clock."""
+    import threading
+
+    from ray_tpu.serve import llm
+
+    burnt, leave = threading.Event(), threading.Event()
+
+    def serve():
+        _burn(0.03)
+        burnt.set()
+        leave.wait(30.0)
+
+    t = threading.Thread(target=serve, name=name, daemon=True)
+    before = llm._thread_cpu_ledger()["by_name"].get(row, 0.0)
+    t.start()
+    try:
+        assert burnt.wait(30.0)
+        by = llm._thread_cpu_ledger()["by_name"]
+    finally:
+        leave.set()
+        t.join(timeout=30.0)
+    assert set(by) <= set(llm._THREAD_ROWS) | {"other"}, by
+    assert by[row] >= before + 0.03 - 1e-6
+    assert not t.is_alive()
+    # its CPU went with it: the row is what the live threads hold
+    gone = llm._thread_cpu_ledger()["by_name"]
+    assert gone.get(row, 0.0) <= by[row] - 0.03 + 0.02, (by, gone)
+
+
+def _build_spans():
+    from ray_tpu import tracing
+
+    return [r for r in tracing.snapshot() if r["name"] == "llm.program_build"]
+
+
+def test_an_unwarmed_shape_is_three_build_spans_and_a_count(small):
+    """A prompt whose length bucket the warm-up did not cover builds the
+    prefill program of that shape (and the page scatter of its pages):
+    three `llm.program_build` spans a program, on the recorder's clock,
+    and `program_builds` up by the compile stages; a second wave of the
+    shape builds nothing."""
+    from ray_tpu import tracing
+
+    eng = _engine(small)
+    eng.start()
+    try:
+        _one_wave(eng, [_prompt(40)], 9)        # 1 x 64, and the decode
+        tracing.clear()
+        s0, t0 = eng.stats()["loop"], time.time()
+        _one_wave(eng, [_prompt(100, 1)], 9)    # 1 x 128: not covered
+        s1, t1 = eng.stats()["loop"], time.time()
+        built = _build_spans()
+        tracing.clear()
+        _one_wave(eng, [_prompt(100, 2)], 9)
+        s2 = eng.stats()["loop"]
+        again = _build_spans()
+    finally:
+        eng.stop()
+    compiles = [b for b in built if b["attrs"]["stage"] == "compile"]
+    prefill = [b for b in built if "_prefill_fwd_only" in b["attrs"]["fun"]
+               and b["attrs"]["depth"] == 0]
+    assert [b["attrs"]["stage"] for b in prefill] == [
+        "trace", "lower", "compile"]
+    assert all(b["attrs"]["thread"] == "llm-engine" for b in prefill)
+    assert prefill[2]["attrs"]["cache"] in ("hit", "miss", "off")
+    assert all("cache" not in b["attrs"] for b in prefill[:2])
+    # JAX's own stamps: inside the stretch, one stage after the other
+    assert t0 <= prefill[0]["t0"] and prefill[2]["t1"] <= t1
+    assert all(a["t1"] <= b["t0"] + 1e-6
+               for a, b in zip(prefill, prefill[1:]))
+    # the prefill program and the scatter of its pages, nothing else
+    assert len(compiles) == 2
+    assert s1["program_builds"] - s0["program_builds"] == len(compiles)
+    assert s1["program_cache_misses"] - s0["program_cache_misses"] == sum(
+        b["attrs"]["cache"] == "miss" for b in compiles)
+    # outermost stages only: a helper traced inside is in its caller's
+    outer_s = sum(b["t1"] - b["t0"] for b in built
+                  if b["attrs"]["depth"] == 0)
+    assert s1["program_build_s"] - s0["program_build_s"] \
+        == pytest.approx(outer_s, abs=1e-4)
+    # (a helper an earlier test traced at this shape is not traced again)
+    assert all(b["t1"] - b["t0"] >= 1e-3 for b in built
+               if b["attrs"]["depth"] and b["attrs"]["stage"] != "compile")
+    # no build roots a trace of its own (`tracing.slowest` would rank
+    # each stage as a request): all hang off the process's one root
+    from ray_tpu.serve import llm
+
+    assert {(b["tid"], b["par"]) for b in built} == {llm._build_root}
+    assert not again
+    assert (s2["program_builds"], s2["program_build_s"]) == (
+        s1["program_builds"], s1["program_build_s"])
 
 
 def test_request_scoped_spans_are_what_they_were(small):
@@ -458,6 +699,23 @@ def test_operator_metrics(small):
     assert len({sum(x > b for b in m["tpot"].boundaries)
                 for x in (17.0, 22.5, 28.0)}) == 3
     assert m["ttft"].boundaries[:4] == [1.0, 5.0, 10.0, 25.0]
+    # PR 36: the engine thread's CPU by phase, the process's threads' by
+    # name, and the programs built, each under its Prometheus name
+    assert [m[k].name for k in ("phase_cpu_s", "thread_cpu_s",
+                                "program_builds", "program_build_s")] == [
+        "serve_llm_phase_cpu_seconds", "serve_llm_thread_cpu_seconds",
+        "serve_llm_program_builds", "serve_llm_program_build_seconds"]
+
+    def rows(key, tag):
+        return {v["tags"][tag]: v["value"]
+                for v in m[key].snapshot()["values"]
+                if v["tags"]["engine"] == "timeline-metrics"}
+
+    by_phase = rows("phase_cpu_s", "phase")
+    assert set(by_phase) <= set(PHASES) and by_phase["decode_dispatch"] > 0
+    assert 0 < sum(by_phase.values()) <= sum(eng.phase_cpu_s.values()) + 1e-6
+    assert rows("thread_cpu_s", "thread")["llm-engine"] > 0
+    assert value("program_builds") >= 1 and value("program_build_s") > 0
 
 
 # ------------------------------------------------- the kernels' names
@@ -535,22 +793,36 @@ def _span(phase, t0, t1, **attrs):
             "attrs": attrs}
 
 
-def _synthetic_run():
+def _build(fun, stage, t0, t1, depth=0, **attrs):
+    return {"name": "llm.program_build", "t0": t0, "t1": t1, "tid": "b",
+            "attrs": {"fun": fun, "stage": stage, "depth": depth,
+                      "thread": "llm-engine", **attrs}}
+
+
+def _synthetic_run(parent=False):
     """Twelve iterations of 100 ms starting at t = 1000: 2 ms admit, 1 ms
     fund, 3 ms decode_dispatch, 90 ms decode_sync, 4 ms deliver; then
     idle.  The chip is idle in the first 10 ms of each iteration (admit +
-    fund + dispatch + 4 ms of the sync) and all through the idle phase."""
+    fund + dispatch + 4 ms of the sync) and all through the idle phase.
+    Since PR 36 the engine thread ran 0.5 of admit's 2 ms, all of fund,
+    1 of dispatch's 3 and 0.5 of deliver's 4 (it stood 7 of the 10); the
+    replica built 7.5 s of programs before the window and one of 250 ms
+    inside it; between the readings of `stats`, 4 s apart, its other
+    threads gained 1.0 CPU second.  `parent`: the run of a program from
+    before PR 36, which records none of that."""
     spans, gaps = [], []
     for i in range(12):
         t = 1000.0 + 0.1 * i
-        spans += [_span("admit", t, t + .002, iter=i, admitted=0),
-                  _span("fund", t + .002, t + .003, iter=i),
+        spans += [_span("admit", t, t + .002, iter=i, admitted=0,
+                        cpu_ms=0.5),
+                  _span("fund", t + .002, t + .003, iter=i, cpu_ms=1.0),
                   _span("decode_dispatch", t + .003, t + .006, iter=i,
-                        lanes=3, steps=8),
-                  _span("decode_sync", t + .006, t + .096, iter=i),
-                  _span("deliver", t + .096, t + .1, iter=i)]
+                        lanes=3, steps=8, cpu_ms=1.0),
+                  _span("decode_sync", t + .006, t + .096, iter=i,
+                        cpu_ms=0.1),
+                  _span("deliver", t + .096, t + .1, iter=i, cpu_ms=0.5)]
         gaps.append((0.010, 0.1 * i, 0.1 * i + 0.010))
-    spans.append(_span("idle", 1001.2, 1001.7, pending=0))
+    spans.append(_span("idle", 1001.2, 1001.7, pending=0, cpu_ms=0.0))
     gaps.append((0.5, 1.2, 1.7))
     spans.append({"name": "llm.queue", "t0": 1000.0, "t1": 1002.0,
                   "tid": "r", "attrs": {}})
@@ -558,9 +830,37 @@ def _synthetic_run():
              "lane_steps_live": 50, "decode_steps": 10}
     loop1 = {"prefill_padded_tokens": 9000, "prefill_true_tokens": 2400,
              "lane_steps_live": 2450, "decode_steps": 106}
+    stats0, stats1 = {"loop": loop0}, {"loop": loop1}
+    if parent:
+        for s in spans:
+            s["attrs"].pop("cpu_ms", None)
+    else:
+        spans += [
+            _build("_prefill_fwd_only", "trace", 990.0, 991.0),
+            _build("attention", "trace", 990.2, 990.7, depth=1),
+            _build("jit(_prefill_fwd_only)", "lower", 991.0, 991.5),
+            _build("jit(_prefill_fwd_only)", "compile", 991.5, 997.5,
+                   cache="hit"),
+            _build("jit(_decode_k)", "compile", 1000.5, 1000.75,
+                   cache="miss")]
+        loop0.update(program_builds=1, program_build_s=7.5,
+                     program_cache_misses=0,
+                     phase_s={"admit": 1.0}, phase_cpu_s={"admit": 0.5})
+        loop1.update(program_builds=2, program_build_s=7.75,
+                     program_cache_misses=1,
+                     phase_s={"admit": 1.024}, phase_cpu_s={"admit": 0.506})
+        stats0["threads"] = {
+            "wall_s": 999.0, "process_cpu_s": 20.0,
+            "by_name": {"llm-engine": 10.0, "serve-call": 5.0,
+                        "raytpu-io": 1.0}}
+        stats1["threads"] = {
+            "wall_s": 1003.0, "process_cpu_s": 23.0,
+            "by_name": {"llm-engine": 11.0, "serve-call": 5.6,
+                        "raytpu-io": 1.3, "llm-kv-export": 0.1}}
     return {
         "spans": spans, "window_wall": (1000.0, 1002.0),
-        "stats": ({"loop": loop0}, {"loop": loop1}),
+        "setup": {"serve_run_s": 20.0, "warmup_s": 9.0},
+        "stats": (stats0, stats1),
         "trace": {"start_wall_s": 1000.0, "t_lo": 0.0, "t_hi": 2.0,
                   "window_s": 2.0, "busy_s": 1.38,
                   "devices": [{"busy_s": 1.38, "modules": [], "by_op": [],
@@ -577,17 +877,81 @@ EMPTY_RUN = {"spans": [], "window_wall": (0.0, 1.0), "stats": ({}, {}),
     ("lanes_live", 25.0),                # 2400 / 96
     # 12 gaps x (2 + 1 + 3 + 4 ms of the sync) = 120 ms of 2 s
     ("device_idle_with_work_pct", 6.0),
+    # PR 36's five, by metric file: 1.5 + 0 + 2 + 3.5 ms stood
+    ("engine.stood_ms_per_window.open", 7.0),
+    ("engine.stood_ms_per_window.closed", 7.0),
+    ("engine.program_build_ms_in_window.open", 250.0),
+    ("engine.program_build_ms_in_window.closed", 250.0),
+    ("setup.program_build_s", 7.5),
 ])
 def test_timeline_readers_on_a_synthetic_run(fn, want, capsys):
-    from benchmarks.harness import timeline
+    import json
 
-    assert getattr(timeline, fn)(_synthetic_run()) == pytest.approx(want)
-    assert getattr(timeline, fn)(dict(EMPTY_RUN)) is None
+    from benchmarks.harness import spec, timeline
+
+    # a name with a dot is a metric's file, read as the harness reads it
+    read = spec.load_reader(fn).read if "." in fn else getattr(timeline, fn)
+    assert read(_synthetic_run()) == pytest.approx(want)
+    assert read(dict(EMPTY_RUN)) is None
+    lines = [json.loads(ln) for ln in
+             capsys.readouterr().out.splitlines() if ln.startswith("{")]
+    if "." in fn:
+        # a parent's run (no cpu_ms, no threads, no program_build_s):
+        # nothing to read, no exception
+        assert read(_synthetic_run(parent=True)) is None
+        part = lines[0]
+        if "stood_ms" in fn:
+            assert part["step"] == "stood_by_phase"
+            assert part["iterations"] == 12
+            assert part["mean_ms"] == pytest.approx(
+                {"wall": 10.0, "ran": 3.0, "stood": 7.0})
+            by = part["by_phase_mean_ms"]
+            assert by["deliver"]["stood"] == pytest.approx(3.5)
+            assert by["fund"]["ran"] == pytest.approx(1.0)
+            assert by["decode_dispatch"]["wall_p50_p90_max"] \
+                == pytest.approx([3.0] * 3)
+            assert "prefill_dispatch" not in by             # no wave
+            assert part["sync_cpu_mean_ms"] == pytest.approx(
+                {"prefill_sync": 0.0, "decode_sync": 0.1})
+            # under ten iterations: too few
+            run = _synthetic_run()
+            run["spans"] = [s for s in run["spans"]
+                            if s["attrs"].get("iter", 0) < 9]
+            assert read(run) is None
+            # a thread clock that ticks (the chip machine's: 10 ms): a
+            # phase reads 0 or 10 ms, and only the sums mean anything
+            run = _synthetic_run()
+            for sp in run["spans"]:
+                if "cpu_ms" in sp["attrs"]:
+                    sp["attrs"]["cpu_ms"] = 10.0 * (
+                        sp["name"] == "llm.loop.decode_dispatch"
+                        and sp["attrs"]["iter"] % 4 == 0)
+            capsys.readouterr()
+            # 12 x 10 ms of wall less 3 ticks, over 12 windows
+            assert read(run) == pytest.approx(7.5)
+            part = json.loads(capsys.readouterr().out.splitlines()[0])
+            assert set(part["by_phase_mean_ms"]["admit"]) == set(
+                by["admit"])                # one shape, whatever the clock
+            assert part["by_phase_mean_ms"]["decode_dispatch"]["stood"] \
+                == pytest.approx(3.0 - 2.5)
+        elif "in_window" in fn:
+            assert part["step"] == "builds_in_window"
+            assert (part["program_builds"],
+                    part["program_cache_misses"]) == (1, 1)
+            assert part["spans"] == [["jit(_decode_k)", "compile", "miss",
+                                      pytest.approx(250.0), "llm-engine", 0]]
+        else:
+            assert part["step"] == "setup_builds"
+            assert (part["serve_run_s"], part["warmup_s"]) == (20.0, 9.0)
+            assert part["by_stage_n_s"] == {
+                "trace": [1, pytest.approx(1.0)],       # not the nested one
+                "lower": [1, pytest.approx(0.5)],
+                "compile": [1, pytest.approx(6.0)]}
+            assert part["compile_by_cache_n_s"]["hit"] == [
+                1, pytest.approx(6.0)]
+            assert part["longest_s"][0][:2] == [
+                "jit(_prefill_fwd_only)", pytest.approx(6.5)]
     if fn == "device_idle_with_work_pct":
-        import json
-
-        lines = [json.loads(ln) for ln in
-                 capsys.readouterr().out.splitlines() if ln.startswith("{")]
         by = next(ln for ln in lines if ln["step"] == "idle_by_phase")
         assert by["by_phase_s"]["idle"] == pytest.approx(0.5)
         assert by["by_phase_s"]["decode_sync"] == pytest.approx(0.048)
@@ -602,6 +966,37 @@ def test_timeline_readers_on_a_synthetic_run(fn, want, capsys):
                         if not s["name"].startswith("llm.loop.")]
         assert timeline.device_idle_with_work_pct(run) is None
         assert timeline.host_ms_per_window(run) is None
+
+
+@pytest.mark.parametrize("traced", [True, False])
+def test_thread_cpu_line_says_when_it_holds_the_profiler(traced, capsys):
+    """The thread ledger between the two readings of `stats` is printed
+    beside the stood time and is no metric: where a device trace was
+    taken and stopped between the readings, the rivals' CPU is the
+    profiler's, and the line says so."""
+    import json
+
+    from benchmarks.harness import stood
+
+    run = _synthetic_run()
+    if not traced:
+        run["trace"] = None
+    stood._log_thread_cpu(run)
+    part = json.loads(capsys.readouterr().out.splitlines()[0])
+    assert part["step"] == "thread_cpu" and part["wall_s"] == 4.0
+    assert part["holds_profiler"] is traced
+    # (0.6 + 0.3 + 0.1 CPU s of the rows that are not the engine's) over
+    # the 4 s between the readings
+    assert part["rivals_pct_of_a_core"] == pytest.approx(25.0)
+    assert [n for n, _ in part["top5_cpu_s"]] == [
+        "serve-call", "raytpu-io", "llm-kv-export"]
+    assert part["engine_cpu_s"] == pytest.approx(1.0)
+    # 3.0 s of the process less 1.0 + 0.6 + 0.3 + 0.1
+    assert part["unaccounted_cpu_s"] == pytest.approx(1.0)
+    assert part["engine_phase_cpu_s"]["admit"] == pytest.approx(.006)
+    # a parent's run holds no ledger: no line, no exception
+    stood._log_thread_cpu(_synthetic_run(parent=True))
+    assert not capsys.readouterr().out
 
 
 def test_flash_bwd_only_roofline_reads_the_named_kernels():
