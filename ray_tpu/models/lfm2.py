@@ -248,7 +248,7 @@ def prefill_op(x, lp, lid: int, cfg: Lfm2MoeConfig, true_lens):
             q, k = _qk_norm(q, k, lp, cfg)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-        o = attention(q, k, v, causal=True)
+        o = attention(q, k, v, causal=True, lengths=true_lens)
         with jax.named_scope("attn_out"):
             d = o.reshape(b, P, -1) @ lp["wo"]
         return d, k.astype(cfg.dtype), v.astype(cfg.dtype), None

@@ -533,7 +533,7 @@ def _decode_qkv(h, lp, cfg: LlamaConfig, lb=None, idx=None):
 
 # ---------------------------------------------------------------- decode
 def prefill(params: dict, tokens: jnp.ndarray, cfg: LlamaConfig,
-            lora: dict | None = None,
+            lora: dict | None = None, true_lens: jnp.ndarray | None = None,
             ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Prompt pass for serving: final hidden states plus the per-layer
     K/V to seed a decode cache.
@@ -544,7 +544,9 @@ def prefill(params: dict, tokens: jnp.ndarray, cfg: LlamaConfig,
     k [L, b, P, n_kv, hd], v likewise), RoPE already applied.  Padding
     rows produce garbage K/V that decode never attends to: the decode
     mask admits only kpos <= pos and each decode step overwrites its own
-    position before reading it (see decode_step_paged).
+    position before reading it (see decode_step_paged).  true_lens [b]
+    (absent: every row is P long) lets the attention kernel pass over
+    the blocks of padding.
 
     lora: None/{} (base model) or {"idx": [b] int32 slots, "banks":
     {target: {"a": [L, n_slots, din, r], "b": [L, n_slots, r, dout]}}}
@@ -570,7 +572,7 @@ def prefill(params: dict, tokens: jnp.ndarray, cfg: LlamaConfig,
                 .reshape(b, P, cfg.n_kv_heads, cfg.head_dim)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-        o = attention(q, k, v, causal=True)
+        o = attention(q, k, v, causal=True, lengths=true_lens)
         with jax.named_scope("attn_out"):
             x = x + _lora_proj(o.reshape(b, P, -1), lp["wo"],
                                lb.get("wo"), idx)
@@ -835,7 +837,7 @@ def init_paged_cache(cfg: LlamaConfig, batch: int, n_pages: int,
 
 
 def serve_prefill(params, tokens, cfg, true_lens, lora=None):
-    hidden, ks, vs = prefill(params, tokens, cfg, lora)
+    hidden, ks, vs = prefill(params, tokens, cfg, lora, true_lens)
     return hidden, ks, vs, [], _no_counts()
 
 

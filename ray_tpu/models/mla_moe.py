@@ -279,10 +279,11 @@ def decode_attention(q, pages, tail, page_table, pos, tail_start,
         dv=cfg.kv_lora_rank, sm_scale=softmax_scale(cfg), plan=plan)
 
 
-def prefill_op(x, lp, lid: int, cfg: MlaMoeConfig):
+def prefill_op(x, lp, lid: int, cfg: MlaMoeConfig, true_lens=None):
     """The attention half of layer `lid` over whole rows, EXPANDED: what
     it adds to x [b, P, d], and the rows' cache rows [b, P, 1,
-    row_width]."""
+    row_width].  true_lens [b] (absent: every row is P long) lets the
+    attention kernel pass over the blocks of padding."""
     b, P, _ = x.shape
     cos, sin = yarn_frequencies(cfg, P)
     h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
@@ -297,7 +298,7 @@ def prefill_op(x, lp, lid: int, cfg: MlaMoeConfig):
         axis=-1)
     with jax.named_scope("mla_attn"):
         o = attention(q, k.astype(q.dtype), v.astype(q.dtype),
-                      sm_scale=softmax_scale(cfg))
+                      sm_scale=softmax_scale(cfg), lengths=true_lens)
     with jax.named_scope("mla_out"):
         d = o.reshape(b, P, -1) @ lp["wo"]
     return d, cache_row(c, k_r, cfg)[:, :, None, :]
@@ -342,7 +343,7 @@ def prefill(params: dict, tokens: jnp.ndarray, cfg: MlaMoeConfig,
     x = embed_lookup(params["embed"], tokens, cfg.dtype)
     rows, counts = [], []
     for lid, lp in enumerate(params["layers"]):
-        d, row = prefill_op(x, lp, lid, cfg)
+        d, row = prefill_op(x, lp, lid, cfg, true_lens)
         x = x + d
         rows.append(row)
         y, cnt = ffn(x, lp, lid, cfg, live)

@@ -11,6 +11,7 @@ import logging
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
 logger = logging.getLogger(__name__)
 
@@ -68,13 +69,19 @@ def xla_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
               causal: bool = True, impl: str = "auto",
               q_offset: int | jnp.ndarray = 0,
-              sm_scale: float | None = None) -> jnp.ndarray:
+              sm_scale: float | None = None,
+              lengths: jnp.ndarray | None = None) -> jnp.ndarray:
     """Multi-head attention with GQA.
 
     impl: "auto" picks the Pallas flash kernel on TPU for long-enough
     sequences, XLA otherwise (short sequences / CPU tests / decode).
     v may be narrower than q and k (latent attention's expanded path:
     192 / 128); sm_scale defaults to head_dim**-0.5.
+    lengths: int32 [b], the true lengths of right-padded rows (causal
+    only, forward only).  The flash kernel copies and multiplies nothing
+    for the query blocks wholly past a length and leaves zeros there; the
+    XLA path ignores it, and what either gives in a padded row is no
+    one's to read: causal true rows need no length.
     """
     use_flash = False
     if impl == "flash":
@@ -94,34 +101,40 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                             "tokens)", *key)
             _XLA_FALLBACKS[key] = _XLA_FALLBACKS.get(key, 0) + 1
     if use_flash:
-        return _flash_padded(q, k, v, causal, sm_scale)
+        return _flash_padded(q, k, v, causal, sm_scale, lengths)
     return xla_attention(q, k, v, causal=causal, q_offset=q_offset,
                          sm_scale=sm_scale)
 
 
-def _flash_padded(q, k, v, causal: bool, sm_scale: float | None = None):
+def _flash_padded(q, k, v, causal: bool, sm_scale: float | None = None,
+                  lengths=None):
     """The flash kernel at any head_dim: a width (q and k's, or v's)
     under 128 lanes is zero-padded to 128, the scale given as the TRUE
     head_dim's; wider ones go as they are (192 / 128 compiles:
-    tests/test_chip_compile.py).  Exact: the padded columns add 0 to
-    every score and the padded columns of the output are cut off."""
+    tests/test_chip_compile.py; behind the model's transposes it runs as
+    fast as padded to 256: PERF.md section 6, PR 38).  Exact: the padded
+    columns add 0 to every score and the padded columns of the output
+    are cut off."""
     d, dv = q.shape[-1], v.shape[-1]
     if min(d, dv) >= 128:
-        return _flash_per_shard(q, k, v, causal, sm_scale)
+        return _flash_per_shard(q, k, v, causal, sm_scale, lengths)
 
     def pad(a):
         return jnp.pad(a, ((0, 0),) * 3 + ((0, max(128 - a.shape[-1], 0)),))
 
     o = _flash_per_shard(pad(q), pad(k), pad(v), causal,
-                         sm_scale=d ** -0.5 if sm_scale is None else sm_scale)
+                         d ** -0.5 if sm_scale is None else sm_scale,
+                         lengths)
     return o[..., :dv]
 
 
-def _flash_per_shard(q, k, v, causal: bool, sm_scale: float | None = None):
+def _flash_per_shard(q, k, v, causal: bool, sm_scale: float | None = None,
+                     lengths=None):
     """The Pallas kernel; under an ambient multi-device mesh, one call
     per shard (jax refuses a Mosaic kernel under GSPMD at lowering:
     "wrap the call in a shard_map").  The layout — what splits over
-    which mesh axes, and the GQA rule — is parallel/sharding's."""
+    which mesh axes, and the GQA rule — is parallel/sharding's; lengths
+    split as q's rows do."""
     from ray_tpu.ops.flash_attention import flash_attention
     from ray_tpu.parallel.sharding import attention_shard_specs
 
@@ -129,8 +142,10 @@ def _flash_per_shard(q, k, v, causal: bool, sm_scale: float | None = None):
                            sm_scale=sm_scale)
     specs = attention_shard_specs(q.shape, k.shape)
     if specs is None:
-        return fn(q, k, v)
+        return fn(q, k, v, lengths=lengths)
     mesh, axis_names, q_spec, kv_spec = specs
-    return jax.shard_map(
-        fn, mesh=mesh, axis_names=axis_names, check_vma=False,
-        in_specs=(q_spec, kv_spec, kv_spec), out_specs=q_spec)(q, k, v)
+    return jax.shard_map(       # lengths None: a tree of no leaves
+        lambda q, k, v, n: fn(q, k, v, lengths=n), mesh=mesh,
+        axis_names=axis_names, check_vma=False,
+        in_specs=(q_spec, kv_spec, kv_spec, PartitionSpec(q_spec[0])),
+        out_specs=q_spec)(q, k, v, lengths)

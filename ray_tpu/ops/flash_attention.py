@@ -5,6 +5,14 @@ streams KV blocks through VMEM, keeping a running (max, sum, acc) per query
 block; the backward is two kernels (dq; dkv) recomputing P from the saved
 log-sum-exp, FlashAttention-2 style.
 
+The forward walks the (row, query block, key block) triples that are WORK
+and no others (`key_blocks`, `_walk`): nothing above the diagonal and, with
+`lengths` (the true lengths of right-padded rows), nothing in a query block
+that lies wholly past its row's length, which comes out as zeros.  The
+minor grid axis is the walk, bounded by its longest row's count; the causal
+mask is applied only in the blocks the diagonal crosses; the log-sum-exp is
+an output only of the call that keeps it for the backward.
+
 This is the framework's own kernel (the reference delegates attention to
 user libraries entirely — ray has no attention op); layout is [b, h, s, d]
 inside the kernel.  Default blocks are block_q=512 / block_k=1024
@@ -23,61 +31,142 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Measured on v5e (bench-350m, b8 x s2048): fwd is flat across block
-# sizes (~8 TF/s — the kernel beats jax's splash at 5.2 TF/s on the same
-# shape), but the BACKWARD kernels run ~1.8x faster at bq=512/bk=1024
-# than at 128/128 (12.5ms vs 22.7ms fwd+bwd per layer-call).
+# Measured on v5e: the BACKWARD kernels run ~1.8x faster at bq=512/bk=1024
+# than at 128/128 (bench-350m, b8 x s2048: 12.5 ms vs 22.7 ms fwd+bwd per
+# layer-call).  The forward at these blocks (PR 38, PERF.md section 6):
+# 1 x 64 heads x 8192 at q/k 192, v 128 in sarvam's prefill program,
+# lengths 4,097-8,192: 8.43 ms a call (17.53 on the static grid before
+# the walk); kernel alone 14.34 ms at the full length, 9.37 at 6,144,
+# 6.51 at 4,097 (19.43 before; each 1.47 over what the program pays, the
+# re-layout of a bare 192-wide argument); 1 x 32 x 1024: 0.21 ms (0.39).
+# A step that does nothing costs 0.16 us, which is why the walk's axis
+# is bounded by a count and not only clamped; the mask outside the
+# diagonal's blocks was 0.8 % and the scale over the scores 1 % (left
+# where it is: the bytes stay).
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 1024
 NEG_INF = -1e30
 
 
 # ------------------------------------------------------------------ forward
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref,
-                *, sm_scale: float, causal: bool):
-    """One (batch, head, q-block, KV-block) program.  KV is the MINOR
-    grid dimension, so each program sees one [block_k, d] slice — VMEM
-    stays bounded at ANY sequence length (whole-KV residency OOMed
-    scoped vmem at 32k).  The running (max, sum, acc) live in scratch,
-    which persists across the sequential kv iterations; o/lse write out
-    on the last one.
+# What one step of the forward walk does, a bit each (`_walk`'s `flag`).
+_FIRST, _LAST, _INSIDE, _EDGE = 1, 2, 4, 8
 
-    q_ref: [block_q, d]; k_ref/v_ref: [block_k, d]; o_ref: [block_q, d];
-    lse_ref: [block_q, 128] (value broadcast across lanes — TPU tiles
-    need a 128 minor dim).
+
+def fit_blocks(sq: int, skv: int, block_q: int = DEFAULT_BLOCK_Q,
+               block_k: int = DEFAULT_BLOCK_K) -> tuple[int, int]:
+    """The blocks a call runs at: the power-of-two defaults halved until
+    they divide the sequence, never below the 128 MXU tile."""
+    block_q = min(block_q, sq)
+    while sq % block_q and block_q > 128:
+        block_q //= 2
+    block_k = min(block_k, skv)
+    while skv % block_k and block_k > 128:
+        block_k //= 2
+    return block_q, block_k
+
+
+def key_blocks(sq: int, skv: int, lengths, block_q: int, block_k: int,
+               causal: bool = True, xp=np):
+    """[rows, query blocks]: how many key blocks are WORK for each query
+    block.  Causal: those up to the one the block's last row ends in;
+    none for a query block that lies wholly past its row's length
+    (`lengths` [rows]; absent: one row, `sq` long, for all).  A block the
+    length crosses counts whole: the causal mask keeps its true rows
+    from the padded keys."""
+    q_start = xp.arange(-(-sq // block_q)) * block_q
+    last_key = xp.minimum(q_start + block_q, skv) - 1 if causal \
+        else xp.full_like(q_start, skv - 1)
+    n = (last_key // block_k + 1)[None, :]
+    if lengths is None:
+        return n
+    return xp.where(q_start[None, :] < lengths[:, None], n, 0)
+
+
+def attn_blocks(sq: int, lengths, block_q: int, block_k: int) -> int:
+    """The (row, query block, key block) triples that causal
+    self-attention over right-padded rows of `lengths` (host integers)
+    multiplies: what `flash_fwd` walks of a dense grid's rows x
+    `sq // block_q` x `sq // block_k`."""
+    return int(key_blocks(sq, sq, np.asarray(lengths), block_q,
+                          block_k).sum())
+
+
+def _walk(n_keys, steps: int, block_q: int, block_k: int, causal: bool, xp):
+    """The forward kernel's steps: `n_keys` [rows, query blocks]
+    (`key_blocks`) -> qi, ki, flag int32 [rows * steps] (`steps` a row,
+    the count a full-length row takes) and the steps each row needs
+    [rows].  A query block takes one step a key block that is work, or
+    ONE step that only writes its zeros.  The steps past a row's count
+    name the blocks already resident, so nothing is copied for them, and
+    do nothing."""
+    per_q = xp.maximum(n_keys, 1)
+    ends = xp.cumsum(per_q, axis=1)
+    total = ends[:, -1]
+    p = xp.arange(steps)[None, :]
+    at = xp.minimum(p, total[:, None] - 1)
+    qi = (at[:, :, None] >= ends[:, None, :]).sum(-1)
+
+    def of(a):
+        return xp.take_along_axis(a, qi, axis=1)
+
+    mine, live = of(per_q), of(n_keys) > 0
+    ki = at - (of(ends) - mine)
+    crosses = (ki + 1) * block_k - 1 > qi * block_q if causal \
+        else xp.zeros_like(ki, dtype=bool)
+    flag = xp.where(
+        p < total[:, None],
+        (ki == 0) * _FIRST + (ki == mine - 1) * _LAST
+        + live * xp.where(crosses, _EDGE, _INSIDE), 0)
+    # a block of zeros names the keys its row multiplied last
+    resident = xp.maximum(n_keys.max(axis=1) - 1, 0)[:, None]
+    ki = xp.where(live, ki, resident)
+    return tuple(a.astype(xp.int32).reshape(-1)
+                 for a in (qi, ki, flag)) + (total,)
+
+
+def _fwd_kernel(qi_ref, ki_ref, flag_ref, q_ref, k_ref, v_ref, o_ref, *refs,
+                sm_scale: float, stride: int):
+    """One step of the walk of one (batch row, head): `_walk` names its
+    query block, its key block and what to do with them.  The key blocks
+    of a query block are consecutive steps of the MINOR grid dimension,
+    so each step sees one [block_k, d] slice — VMEM stays bounded at ANY
+    sequence length (whole-KV residency OOMed scoped vmem at 32k).  The
+    running (max, sum, acc) live in scratch, which persists across the
+    steps; o (and lse, for the call that keeps it) write out on a query
+    block's last.
+
+    q_ref: [block_q, d]; k_ref: [block_k, d]; v_ref: [block_k, dv];
+    o_ref: [block_q, dv]; lse_ref: [block_q, 128] (value broadcast across
+    lanes — TPU tiles need a 128 minor dim).
     """
-    block_q, d = q_ref.shape
-    block_k = k_ref.shape[0]
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
-    num_kv = pl.num_programs(3)
-    q_start = qi * block_q
-    k_start = ki * block_k
+    *lse_ref, acc_ref, m_ref, l_ref = refs
+    block_q, block_k = q_ref.shape[0], k_ref.shape[0]
+    at = pl.program_id(0) * stride + pl.program_id(2)
+    flag = flag_ref[at]
 
-    @pl.when(ki == 0)
+    @pl.when(flag & _FIRST != 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(jnp.logical_or(not causal,
-                            k_start <= q_start + block_q - 1))
-    def _compute():
+    def update(edge: bool):
         q = q_ref[...]
         k = k_ref[...]
         v = v_ref[...]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            qpos = q_start + jax.lax.broadcasted_iota(
+        if edge:                  # the diagonal crosses this block
+            qpos = qi_ref[at] * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
-            kpos = k_start + jax.lax.broadcasted_iota(
+            kpos = ki_ref[at] * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(qpos >= kpos, s, NEG_INF)
         m_prev = m_ref[:, 0]                      # [bq]
@@ -92,56 +181,76 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                             preferred_element_type=jnp.float32))
         m_ref[:, 0] = m_cur
 
-    @pl.when(ki == num_kv - 1)
+    pl.when(flag & _EDGE != 0)(functools.partial(update, True))
+    pl.when(flag & _INSIDE != 0)(functools.partial(update, False))
+
+    @pl.when(flag & _LAST != 0)
     def _write():
         l = l_ref[:, 0]
-        l = jnp.where(l == 0.0, 1.0, l)   # fully-masked rows: zeros, no NaN
+        l = jnp.where(l == 0.0, 1.0, l)   # nothing admitted: zeros, no NaN
         o_ref[...] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[:, 0] = m_ref[:, 0] + jnp.log(l)
+        for ref in lse_ref:
+            ref[:, 0] = m_ref[:, 0] + jnp.log(l)
 
 
-def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k):
+def _flash_fwd(q, k, v, lengths, sm_scale, causal, block_q, block_k,
+               keep_lse: bool):
     """q: [b, hq, sq, d]; k: [b, hkv, skv, d]; v: [b, hkv, skv, dv]
     (dv = d everywhere but latent attention's expanded path, whose keys
-    are wider than its values) → (o [b, hq, sq, dv], lse [b, hq, sq])."""
+    are wider than its values); lengths: int32 [b] or None (every row
+    `sq` long) -> o [b, hq, sq, dv], zeros in the query blocks wholly
+    past a row's length, and lse [b, hq, sq] if `keep_lse` (the backward
+    kernels' residual), else None."""
     b, hq, sq, d = q.shape
     hkv, skv = k.shape[1], k.shape[2]
     dv = v.shape[3]
     n_rep = hq // hkv
-    grid = (b, hq, sq // block_q)
+    n_keys, xp = key_blocks(sq, skv, None, block_q, block_k, causal), np
+    steps = int(n_keys.sum())         # what a row of the full length takes
+    if lengths is not None:
+        if not causal:
+            raise ValueError("lengths take causal attention: without the "
+                             "mask a true row sees the padded keys")
+        n_keys, xp = key_blocks(sq, skv, lengths.astype(jnp.int32), block_q,
+                                block_k, causal, jnp), jnp
+    *tables, total = _walk(n_keys, steps, block_q, block_k, causal, xp)
+    # no lengths: one row of tables for every row, all of it static
+    stride, n_steps = (0, steps) if lengths is None \
+        else (steps, jnp.max(total))
 
-    out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal),
-        name="flash_fwd",
-        grid=(*grid, skv // block_k),
+    def q_map(bi, hi, p, qi, ki, flag):
+        return (bi, hi, qi[bi * stride + p], 0)
+
+    def kv_map(bi, hi, p, qi, ki, flag):
+        return (bi, hi // n_rep, ki[bi * stride + p], 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b, hq, n_steps),        # with lengths, the device's number
         in_specs=[
-            pl.BlockSpec((None, None, block_q, d),
-                         lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            pl.BlockSpec((None, None, block_k, d),
-                         lambda bi, hi, qi, ki,
-                         n_rep=n_rep: (bi, hi // n_rep, ki, 0)),
-            pl.BlockSpec((None, None, block_k, dv),
-                         lambda bi, hi, qi, ki,
-                         n_rep=n_rep: (bi, hi // n_rep, ki, 0)),
+            pl.BlockSpec((None, None, block_q, d), q_map),
+            pl.BlockSpec((None, None, block_k, d), kv_map),
+            pl.BlockSpec((None, None, block_k, dv), kv_map),
         ],
-        out_specs=[
-            pl.BlockSpec((None, None, block_q, dv),
-                         lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            pl.BlockSpec((None, None, block_q, 128),
-                         lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hq, sq, dv), q.dtype),
-            jax.ShapeDtypeStruct((b, hq, sq, 128), jnp.float32),
-        ],
+        out_specs=[pl.BlockSpec((None, None, block_q, dv), q_map)]
+        + [pl.BlockSpec((None, None, block_q, 128), q_map)] * keep_lse,
         scratch_shapes=[
             pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
+    )
+    out, *lse = pl.pallas_call(
+        functools.partial(_fwd_kernel, sm_scale=sm_scale, stride=stride),
+        name="flash_fwd",
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((b, hq, sq, dv), q.dtype)]
+        + [jax.ShapeDtypeStruct((b, hq, sq, 128), jnp.float32)] * keep_lse,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
-    )(q, k, v)
-    return out, lse[..., 0]
+    )(*tables, q, k, v)
+    return out, (lse[0][..., 0] if keep_lse else None)
 
 
 # ----------------------------------------------------------------- backward
@@ -358,12 +467,13 @@ def _interpret() -> bool:
 # ---------------------------------------------------------------- dispatch
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash(q, k, v, sm_scale, causal, block_q, block_k):
-    o, _ = _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k)
-    return o
+    return _flash_fwd(q, k, v, None, sm_scale, causal, block_q, block_k,
+                      keep_lse=False)[0]
 
 
 def _flash_vjp_fwd(q, k, v, sm_scale, causal, block_q, block_k):
-    o, lse = _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k)
+    o, lse = _flash_fwd(q, k, v, None, sm_scale, causal, block_q, block_k,
+                        keep_lse=True)
     # Name the residuals so a remat policy can SAVE them: under
     # jax.checkpoint with nothing_saveable, the backward re-runs this
     # whole forward kernel just to regenerate (o, lse) — per-layer
@@ -377,35 +487,51 @@ def _flash_vjp_fwd(q, k, v, sm_scale, causal, block_q, block_k):
 _flash.defvjp(_flash_vjp_fwd, _flash_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash_forward_only(q, k, v, lengths, sm_scale, causal, block_q,
+                        block_k):
+    return _flash_fwd(q, k, v, lengths, sm_scale, causal, block_q, block_k,
+                      keep_lse=False)[0]
+
+
+def _no_backward(q, k, v, lengths, *_):
+    raise TypeError(
+        "flash attention's backward kernels take one width and no lengths: "
+        f"q {q.shape}, v {v.shape}, lengths "
+        f"{None if lengths is None else lengths.shape}")
+
+
+_flash_forward_only.defvjp(_no_backward, _no_backward)
+
+
 def flash_attention(q, k, v, causal: bool = True, sm_scale: float | None = None,
                     block_q: int = DEFAULT_BLOCK_Q,
-                    block_k: int = DEFAULT_BLOCK_K):
+                    block_k: int = DEFAULT_BLOCK_K, lengths=None):
     """Flash attention with GQA.  q: [b, sq, hq, d]; k/v: [b, skv, hkv, d];
     returns [b, sq, hq, d] (layout matches ray_tpu.ops.attention).  v may
-    be [b, skv, hkv, dv] with dv != d (forward only: the backward kernels
-    take one width); the result is then [b, sq, hq, dv]."""
+    be [b, skv, hkv, dv] with dv != d; the result is then [b, sq, hq, dv].
+    lengths: int32 [b], the true length of each right-padded row (causal
+    only): true rows come out as without it, the query blocks wholly past
+    a length as zeros, and nothing is copied or multiplied for those.
+    Both are forward only (the backward kernels take one width and whole
+    rows): differentiating such a call raises."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    # Blocks must DIVIDE the sequence (the grids floor-divide): halve the
-    # power-of-two defaults until they do, never below the 128 MXU tile.
+    # Blocks must DIVIDE the sequence (the walk floor-divides).
     # seq % 128 == 0 is the dispatcher's entry gate, so power-of-two
     # blocks always land; a non-power-of-two caller block that can't
     # divide is an error rather than a silent degenerate grid.
-    block_q = min(block_q, qt.shape[2])
-    while qt.shape[2] % block_q and block_q > 128:
-        block_q //= 2
-    block_k = min(block_k, kt.shape[2])
-    while kt.shape[2] % block_k and block_k > 128:
-        block_k //= 2
+    block_q, block_k = fit_blocks(qt.shape[2], kt.shape[2], block_q, block_k)
     if qt.shape[2] % block_q or kt.shape[2] % block_k:
         raise ValueError(
             f"block sizes ({block_q}, {block_k}) do not divide seq "
             f"({qt.shape[2]}, {kt.shape[2]}); use power-of-two blocks")
-    if vt.shape[3] != qt.shape[3]:
-        o, _ = _flash_fwd(qt, kt, vt, sm_scale, causal, block_q, block_k)
-    else:
+    if lengths is None and vt.shape[3] == qt.shape[3]:
         o = _flash(qt, kt, vt, sm_scale, causal, block_q, block_k)
+    else:
+        o = _flash_forward_only(qt, kt, vt, lengths, sm_scale, causal,
+                                block_q, block_k)
     return o.transpose(0, 2, 1, 3)

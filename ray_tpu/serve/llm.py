@@ -315,6 +315,17 @@ def _engine_metrics():
                     um.Counter, "serve_llm_attn_steps_dense",
                     "Lanes x (table columns + 1), summed over sync "
                     "windows", tk),
+                # prefill_attn_blocks / prefill_attn_blocks_dense = the
+                # share of a prefill program's attention grid that is
+                # under the diagonal and inside its rows' true lengths
+                "prefill_attn_blocks": um.get_or_create(
+                    um.Counter, "serve_llm_prefill_attn_blocks",
+                    "(row, query block, key block) triples flash_fwd "
+                    "multiplies, a full-prompt prefill program", tk),
+                "prefill_attn_blocks_dense": um.get_or_create(
+                    um.Counter, "serve_llm_prefill_attn_blocks_dense",
+                    "Rows x query blocks x key blocks of the same "
+                    "programs", tk),
                 # a routed model's decode: experts hit a layer-step =
                 # moe_experts_hit / moe_layer_steps
                 "moe_layer_steps": um.get_or_create(
@@ -913,6 +924,11 @@ class LLMEngine:
         # grid of every lane x (every table column + the tail) was.
         self.attn_steps = 0
         self.attn_steps_dense = 0
+        # The prefill kernel's walk a full-prompt program (every layer's
+        # call walks the same), from the lengths sent with it: the (row,
+        # query block, key block) triples that are work, and all of them.
+        self.prefill_attn_blocks = 0
+        self.prefill_attn_blocks_dense = 0
         # Rows the attention kernel had to attend: a live lane's context
         # at each of a window's K steps (block-start rows + the tail's
         # j + 1), summed over lanes, steps and windows.
@@ -2233,6 +2249,8 @@ class LLMEngine:
         Returns the first tokens (on device)."""
         import jax.numpy as jnp
 
+        from ray_tpu.ops.flash_attention import attn_blocks, fit_blocks
+
         W = len(chunk)
         # Pad by duplicating the last row: the duplicate writes the
         # same slot with the same data, so correctness is
@@ -2260,6 +2278,10 @@ class LLMEngine:
             lidx[j] = req.lora_slot
         for _, req in chunk:
             self.prefill_tokens += len(req.prompt) + len(req.tokens)
+        bq, bk = fit_blocks(bucket, bucket)
+        self.prefill_attn_blocks += attn_blocks(bucket, true_lens, bq, bk)
+        self.prefill_attn_blocks_dense += \
+            padded_w * -(-bucket // bq) * -(-bucket // bk)
         slots_dev = jnp.asarray(slots)
         lens_dev = jnp.asarray(true_lens)
         cols = np.arange(bucket) // self.page
@@ -2857,6 +2879,8 @@ class LLMEngine:
                "attn_steps": self.attn_steps,
                "attn_steps_dense": self.attn_steps_dense,
                "attn_ctx_rows": self.attn_ctx_rows,
+               "prefill_attn_blocks": self.prefill_attn_blocks,
+               "prefill_attn_blocks_dense": self.prefill_attn_blocks_dense,
                "prefill_programs_capped": self.prefill_programs_capped,
                "demote_bytes": self.demote_bytes,
                "preemptions": self.preemptions,
@@ -2952,13 +2976,19 @@ class LLMEngine:
                # prefill_padded_tokens / prefill_true_tokens, live lanes
                # per decode step = lane_steps_live / decode_steps, the
                # share of a lanes x columns attention grid that is work
-               # = attn_steps / attn_steps_dense.
+               # = attn_steps / attn_steps_dense, the share of a prefill
+               # program's rows x query blocks x key blocks that the
+               # diagonal and the true lengths leave as work =
+               # prefill_attn_blocks / prefill_attn_blocks_dense.
                "loop": {
                    "decode_steps": self.decode_steps,
                    "lane_steps_live": self.lane_steps_live,
                    "attn_steps": self.attn_steps,
                    "attn_steps_dense": self.attn_steps_dense,
                    "attn_ctx_rows": self.attn_ctx_rows,
+                   "prefill_attn_blocks": self.prefill_attn_blocks,
+                   "prefill_attn_blocks_dense":
+                   self.prefill_attn_blocks_dense,
                    "phase_s": dict(self.phase_s),
                    # the thread's own CPU seconds in each phase: what
                    # phase_s has more is the time it stood there

@@ -590,6 +590,55 @@ def test_flash_forward_compiles_at_keys_wider_than_values(one_chip,
     assert jax.tree.leaves(c.out_info)[0].shape == (1, 8192, 64, 128)
 
 
+@pytest.mark.parametrize("b,s,h,kvh,d,dv", [
+    (1, 8192, 64, 64, 192, 128),      # sarvam-105b's expanded prefill
+    (8, 1024, 32, 8, 128, 128),       # Mistral-7B's widest wave
+])
+def test_flash_forward_with_lengths_compiles(one_chip, compiled_kernels,
+                                             b, s, h, kvh, d, dv):
+    """`flash_fwd` over right-padded rows: the walk's tables and the
+    lengths' count ride as scalar prefetch, the minor grid axis is bounded
+    by a number the device holds, and a forward-only call has no
+    log-sum-exp among its results (f32 [b, h, s, 128]: 268 MB a layer at
+    sarvam's widths)."""
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    def sds(heads, width):
+        return jax.ShapeDtypeStruct((b, s, heads, width), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    low, c = _compile(
+        lambda q, k, v, n: flash_attention(q, k, v, sm_scale=0.1, lengths=n),
+        sds(h, d), sds(kvh, d), sds(kvh, dv),
+        jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one_chip))
+    assert low.as_text().count("tpu_custom_call") == 1
+    assert "flash_fwd" in low.as_text()
+    assert f"f32[{b},{h},{s},128]" not in c.as_text()
+    assert jax.tree.leaves(c.out_info)[0].shape == (b, s, h, dv)
+
+
+def test_flash_train_shard_keeps_lse_and_its_backward(one_chip,
+                                                      compiled_kernels):
+    """The train cell's per-shard call (2 rows x 16 heads x 4,096, no
+    lengths) through `jax.grad`: the vjp's forward is `flash_fwd` WITH
+    the log-sum-exp, beside the two backward kernels that read it."""
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    def sds(heads):
+        return jax.ShapeDtypeStruct((2, 4096, heads, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    low, c = _compile(
+        lambda q, k, v: jax.grad(
+            lambda *a: flash_attention(*a).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v), sds(16), sds(4), sds(4))
+    txt = low.as_text()
+    assert txt.count("tpu_custom_call") == 3
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert name in txt
+    assert "f32[2,16,4096,128]" in c.as_text()
+
+
 @pytest.mark.time_limit(600)
 def test_served_sarvam_engine_fits_one_chip(topo, one_chip, compiled_kernels,
                                             monkeypatch):

@@ -326,6 +326,73 @@ def test_lane_steps_live_by_hand(small):
     assert s1["phase_s"]["decode_sync"] > s0["phase_s"]["decode_sync"]
 
 
+def _blocks_by_brute_force(sq, lengths, bq, bk):
+    """Reduce the causal-and-length mask [row, q, k] by blocks: a triple
+    is work if its query block holds a true row and any of its (q, k)
+    is under the diagonal."""
+    import numpy as np
+
+    pos = np.arange(sq)
+    causal = pos[:, None] >= pos[None, :]
+    n = 0
+    for length in lengths:
+        for q0 in range(0, sq, bq):
+            if q0 >= length:
+                continue
+            n += sum(bool(causal[q0:q0 + bq, k0:k0 + bk].any())
+                     for k0 in range(0, sq, bk))
+    return n
+
+
+@pytest.mark.parametrize("sq,bq,bk,lengths", [
+    (512, 128, 256, (1,)), (512, 128, 256, (127,)), (512, 128, 256, (128,)),
+    (512, 128, 256, (129,)), (512, 128, 256, (512,)),
+    (512, 128, 256, (1, 128, 129)), (512, 128, 256, (127, 512, 300)),
+    (1024, 512, 1024, (33, 600, 1024)), (96, 96, 96, (5, 96)),
+    (2048, 512, 1024, (1536,)), (8192, 512, 1024, (4097, 6144, 8192)),
+], ids=str)
+def test_attn_blocks_against_the_mask_reduced_by_blocks(sq, bq, bk, lengths):
+    from ray_tpu.ops.flash_attention import attn_blocks
+
+    assert attn_blocks(sq, lengths, bq, bk) == \
+        _blocks_by_brute_force(sq, lengths, bq, bk)
+
+
+def test_prefill_attn_blocks_are_the_programs_the_engine_dispatched(small):
+    """`loop.prefill_attn_blocks` is `attn_blocks` summed over the
+    full-prompt prefill programs, from the lengths sent with each (a
+    program's padding rows repeat its last); `_dense` is rows x query
+    blocks x key blocks, the grid `flash_fwd` walked before it took
+    lengths."""
+    from ray_tpu.ops.flash_attention import attn_blocks, fit_blocks
+
+    eng = _engine(small)
+    sent = []
+    fwd = eng._prefill_fwd
+
+    def recording(params, tokens, lens, *a):
+        sent.append((tokens.shape, [int(n) for n in lens]))
+        return fwd(params, tokens, lens, *a)
+
+    eng._prefill_fwd = recording
+    eng.start()
+    try:
+        _one_wave(eng, [_prompt(100, 1), _prompt(20, 2), _prompt(200, 3)], 3)
+        _one_wave(eng, [_prompt(40)], 3)
+        loop = eng.stats()["loop"]
+    finally:
+        eng.stop()
+    assert len(sent) >= 2
+    want = dense = 0
+    for (w, b), lens in sent:
+        bq, bk = fit_blocks(b, b)
+        want += attn_blocks(b, lens, bq, bk)
+        dense += w * (b // bq) * (b // bk)
+    assert loop["prefill_attn_blocks"] == want
+    assert loop["prefill_attn_blocks_dense"] == dense
+    assert 0 < want <= dense
+
+
 def test_attn_steps_by_hand(small):
     """Pages of 16 rows, 16 table columns, 4 lanes: three requests of 20
     rows hold two pages each in both of their windows (block starts 20
@@ -693,6 +760,11 @@ def test_operator_metrics(small):
     assert value("prefill_tokens") == 60
     assert value("lane_steps_live") == 24 and value("decode_steps") == 8
     assert value("attn_steps") == 12 and value("attn_steps_dense") == 136
+    # one 4 x 32 program, one block a row: nothing to pass over
+    assert value("prefill_attn_blocks") == 4
+    assert value("prefill_attn_blocks_dense") == 4
+    for key in ("prefill_attn_blocks", "prefill_attn_blocks_dense"):
+        assert m[key].snapshot()["name"] == "serve_llm_" + key
     assert m["tpot"].boundaries == [1, 2, 5, 10, 15, 20, 25, 30, 40, 50,
                                     75, 100, 250, 1000]
     # every TPOT the benchmark has read (17-28 ms) no longer shares a bucket

@@ -2,6 +2,8 @@
 ring attention (8-device virtual mesh) against the XLA reference
 implementation.  Mirrors the reference's fake-backend testing trick
 (ray: MockNcclGroup, python/ray/experimental/channel/conftest.py:58)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -161,6 +163,116 @@ class TestFlashAttention:
         n_flash = txt_flash.count("pallas_call")
         n_nothing = txt_nothing.count("pallas_call")
         assert 0 < n_flash < n_nothing, (n_flash, n_nothing)
+
+
+def _jaxprs_in(jaxpr):
+    """jaxpr and every jaxpr nested in its equations' params."""
+    yield jaxpr
+    for eqn in jaxpr.eqns:
+        for val in eqn.params.values():
+            for sub in val if isinstance(val, (list, tuple)) else [val]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _jaxprs_in(sub)
+
+
+def _flash_fwd_calls(fn, *args):
+    return [e for j in _jaxprs_in(jax.make_jaxpr(fn)(*args).jaxpr)
+            for e in j.eqns if e.primitive.name == "pallas_call"
+            and e.params["name"] == "flash_fwd"]
+
+
+# a length by where it falls around a query block's edge
+_LENGTHS = {"one": lambda bq, s: 1, "under": lambda bq, s: bq - 1,
+            "on": lambda bq, s: bq, "over": lambda bq, s: bq + 1,
+            "full": lambda bq, s: s}
+
+
+class TestFlashLengths:
+    """`lengths`: the true lengths of right-padded rows.  The forward
+    kernel walks only the (row, query block, key block) triples that are
+    under the diagonal and inside a row's length."""
+
+    @pytest.mark.parametrize("lens", [
+        ("one",), ("under",), ("on",), ("over",), ("full",),
+        ("one", "on", "over"), ("under", "full", "over")],
+        ids="-".join)
+    @pytest.mark.parametrize("n_rep", [1, 4], ids=["mha", "gqa4"])
+    @pytest.mark.parametrize("width", ["128", "64-padded", "192-128"])
+    def test_true_rows_stay_and_padded_blocks_are_zero(self, width, n_rep,
+                                                       lens):
+        b, hq = len(lens), 4
+        d, dv = {"128": (128, 128), "64-padded": (64, 64),
+                 "192-128": (192, 128)}[width]
+        if width == "64-padded":
+            # through the dispatcher, which pads to 128 lanes and takes
+            # the default blocks: 512 / 512 at 1536 rows
+            s, bq = 1536, 512
+            run = functools.partial(attention, impl="flash")
+        else:
+            s, bq = 512, 128
+            run = functools.partial(flash_attention, block_q=bq,
+                                    block_k=256)
+        key = jax.random.PRNGKey(3)
+        q = jax.random.normal(jax.random.fold_in(key, 1), (b, s, hq, d))
+        k = jax.random.normal(jax.random.fold_in(key, 2),
+                              (b, s, hq // n_rep, d))
+        v = jax.random.normal(jax.random.fold_in(key, 3),
+                              (b, s, hq // n_rep, dv))
+        lengths = np.array([_LENGTHS[n](bq, s) for n in lens], np.int32)
+        o = np.asarray(run(q, k, v, lengths=jnp.asarray(lengths)))
+        whole = np.asarray(run(q, k, v))
+        ref = np.asarray(xla_attention(q, k, v))
+        assert o.shape == (b, s, hq, dv) and np.isfinite(o).all()
+        for r, n in enumerate(lengths):
+            # the scale is where it was: the same bytes as without lengths
+            np.testing.assert_array_equal(o[r, :n], whole[r, :n])
+            np.testing.assert_allclose(o[r, :n], ref[r, :n], atol=2e-2,
+                                       rtol=1e-2)
+            assert not o[r, -(-n // bq) * bq:].any()
+
+    def test_lengths_split_with_the_rows_under_a_mesh(self):
+        from ray_tpu.parallel.mesh import MeshConfig, create_mesh
+
+        mesh = create_mesh(MeshConfig(data=2, fsdp=2, tensor=2),
+                           devices=jax.devices()[:8])
+        q, k, v = _qkv(b=4, s=1024, hq=4, hkv=2)
+        lengths = jnp.array([1024, 1, 513, 512], jnp.int32)
+        with jax.set_mesh(mesh):
+            o = jax.jit(lambda *a: attention(*a[:3], impl="flash",
+                                             lengths=a[3]))(q, k, v, lengths)
+        ref = xla_attention(q, k, v)
+        for r, n in enumerate(np.asarray(lengths)):
+            np.testing.assert_allclose(o[r, :n], ref[r, :n], atol=2e-2,
+                                       rtol=1e-2)
+        assert not np.asarray(o[1, 512:]).any()     # blocks of 512 rows
+
+    def test_a_differentiated_call_with_lengths_raises(self):
+        q, k, v = _qkv(s=128)
+        lengths = jnp.array([128, 5], jnp.int32)
+        with pytest.raises(TypeError, match="no lengths"):
+            jax.grad(lambda q: flash_attention(
+                q, k, v, lengths=lengths).sum())(q)
+        with pytest.raises(ValueError, match="causal"):
+            flash_attention(q, k, v, causal=False, lengths=lengths)
+
+    def test_lse_is_an_output_only_of_the_call_that_keeps_it(self):
+        """The forward-only kernel writes o alone; the vjp's forward
+        writes the log-sum-exp the backward kernels read beside it.
+        Both are named `flash_fwd`."""
+        q, k, v = _qkv(s=128)
+        lengths = jnp.array([128, 5], jnp.int32)
+        for fn, args in [
+                (flash_attention, (q, k, v)),
+                (lambda q, k, v, n: flash_attention(q, k, v, lengths=n),
+                 (q, k, v, lengths)),
+                (flash_attention, (q, k, v[..., :64]))]:
+            (call,) = _flash_fwd_calls(fn, *args)
+            assert len(call.outvars) == 1
+        (call,) = _flash_fwd_calls(
+            lambda *a: jax.vjp(flash_attention, *a)[0], q, k, v)
+        assert len(call.outvars) == 2
+        assert call.outvars[1].aval.dtype == jnp.float32
 
 
 class TestRingAttention:
