@@ -19,7 +19,13 @@ from ray_tpu.models.vit import ViTConfig, vit_configs
 #     one row a token that every head shares), from which the engine
 #     takes its tails' and merges' shapes; and `state`, a pytree of
 #     whatever a lane carries that no page holds (an empty list if
-#     nothing), which the engine never looks inside;
+#     nothing; a few rows a lane; or gigabytes: a state-space layer's
+#     matrices, every lane's in one array), which the engine never looks
+#     inside, allocates once, donates through the scatter and decode
+#     programs and never copies or selects over: the module's scatter
+#     writes a row's state where the lanes' state lies, and its decode
+#     step updates it in place (a kernel that aliases it).  A dict's keys
+#     are the kinds `stats()["lane_state"]["by_kind"]` reports;
 #   serve_prefill(params, tokens, cfg, true_lens, lora) -> (hidden, ks,
 #     vs, state taken at each row's TRUE length, counts); ks and vs are
 #     the rows for the pool, handed unopened to serve_scatter (a latent
@@ -31,7 +37,12 @@ from ray_tpu.models.vit import ViTConfig, vit_configs
 #     state, counts); `plan` is the window's
 #     ops.paged_attention.attention_plan, built once by the engine;
 #   project_logits(params, h); lane_state_layers(cfg) (0: the prefix
-#     cache may stay on); routed_layers(cfg): the rows of `counts`, int32
+#     cache may stay on); optionally, for state that a chunked scan
+#     fills and a one-step kernel updates: scan_chunk(cfg), the scan's
+#     chunk (the engine's `ssm_lane_steps` and `prefill_scan_chunks`
+#     counters) and prefill_state_bytes(cfg) (the state ONE prefill row
+#     hands the scatter: the wave planner bounds a program's width by
+#     it); routed_layers(cfg): the rows of `counts`, int32
 #     [routed layers, 4] = experts that held a row, the largest load,
 #     assignments computed, visits of the grouped matmul that were work
 #     (0 rows: nothing is counted; a config with routed layers has
@@ -44,7 +55,8 @@ from ray_tpu.models.vit import ViTConfig, vit_configs
 # "kv_transfer": KV export/import/graft).
 _SERVING = {"LlamaConfig": "ray_tpu.models.llama",
             "Lfm2MoeConfig": "ray_tpu.models.lfm2",
-            "MlaMoeConfig": "ray_tpu.models.mla_moe"}
+            "MlaMoeConfig": "ray_tpu.models.mla_moe",
+            "SsmHybridConfig": "ray_tpu.models.ssm_hybrid"}
 
 
 def serving_model(cfg):

@@ -360,8 +360,27 @@ def _engine_metrics():
                     "windows", tk),
                 "prefill_programs_capped": um.get_or_create(
                     um.Counter, "serve_llm_prefill_programs_capped",
-                    "Prefill waves the planner's token ceiling split "
-                    "(serve/prefill_plan.PREFILL_MAX_TOKENS)", tk),
+                    "Prefill waves a ceiling of the planner split "
+                    "(serve/prefill_plan.PREFILL_MAX_TOKENS, "
+                    "PREFILL_MAX_STATE_BYTES)", tk),
+                # a state-space model's kernels: the one-step update's
+                # work list, and prefill_scan_chunks /
+                # prefill_scan_chunks_dense = the share of the chunked
+                # scan's chunks that lie below the rows' true lengths
+                "ssm_lane_steps": um.get_or_create(
+                    um.Counter, "serve_llm_ssm_lane_steps",
+                    "Lane states the one-step state-space update read "
+                    "and wrote: live lanes x decode steps x state-space "
+                    "layers", tk),
+                "prefill_scan_chunks": um.get_or_create(
+                    um.Counter, "serve_llm_prefill_scan_chunks",
+                    "Chunks of the chunked state-space scan below the "
+                    "rows' true lengths, summed over prefill programs "
+                    "and state-space layers", tk),
+                "prefill_scan_chunks_dense": um.get_or_create(
+                    um.Counter, "serve_llm_prefill_scan_chunks_dense",
+                    "Chunks of the padded prefill programs the chunked "
+                    "state-space scan walked, summed likewise", tk),
                 # beside phase_s["decode_sync"]: bytes a second the
                 # prefix store's demotion fetched off the device
                 "demote_bytes": um.get_or_create(
@@ -518,9 +537,14 @@ class LLMEngine:
     capabilities (`SERVING_CAPS`) the engine refuses at construction; a
     model whose lanes carry state no KV page holds (`lane_state_layers`)
     is served with the prefix cache, suffix prefill and the prefix
-    store's demotion off, and `stats()["lane_state"]` says so.  The page
-    pool is whatever leaves the model's `init_paged_cache` returns
-    (`_pool`): `stats()["cache"]` says what it holds."""
+    store's demotion off, and `stats()["lane_state"]` says so.  That
+    state may be a few rows a lane or gigabytes (a state-space layer
+    keeps a matrix a head a lane): it is allocated once, donated through
+    the scatter and decode programs, updated in place by the model's own
+    kernel, and never copied or selected over whole; the engine never
+    looks inside.  The page pool is whatever leaves the model's
+    `init_paged_cache` returns (`_pool`): `stats()["cache"]` says what
+    it holds."""
 
     def __init__(self, cfg, params=None, *, max_batch: int = 8,
                  max_len: int | None = None, seed: int = 0,
@@ -541,11 +565,25 @@ class LLMEngine:
         model = self._model = serving_model(cfg)
         caps = model.SERVING_CAPS
         # Per-lane state beside the page pool (a convolution's last
-        # rows): the prefill program returns it taken at each row's
-        # TRUE length, the scatter program writes it into the lane, the
-        # decode scan carries it; the engine never looks inside.
+        # rows, a state-space layer's matrices): the prefill program
+        # returns it taken at each row's TRUE length, the scatter
+        # program writes it into the lane where the lanes' state lies
+        # (the cache is donated), the decode scan carries it and the
+        # model's step updates it in place; the engine never looks
+        # inside.  What a model may declare beside the layer count: that
+        # those layers keep a state matrix which a chunked scan fills
+        # and a one-step kernel updates (`scan_chunk`, the scan's chunk:
+        # the engine then counts both kernels' work), and the bytes of
+        # state one prefill row hands the scatter
+        # (`prefill_state_bytes`: the planner bounds a program's width
+        # by it).
         self._lane_layers = int(model.lane_state_layers(cfg))
         self._moe_layers = int(model.routed_layers(cfg))
+        self._scan_chunk = int(getattr(
+            model, "scan_chunk", lambda _cfg: 0)(cfg))
+        self._scan_layers = self._lane_layers if self._scan_chunk else 0
+        self._row_state_bytes = int(getattr(
+            model, "prefill_state_bytes", lambda _cfg: 0)(cfg))
         stateful = self._lane_layers > 0
         if lora_slots and "lora" not in caps:
             raise ValueError(
@@ -592,6 +630,7 @@ class LLMEngine:
         self.cache = model.init_paged_cache(cfg, max_batch,
                                             kv_pages, page_size)
         self._cache_info = self._cache_stats()   # shapes: fixed from here
+        self._lane_info = self._lane_state_stats()
         # Host-side accounting: refcounted blocks + radix prefix
         # index over pool ids 1..n_pages-1 (serve/kv_blocks.py).
         self._mgr = BlockManager(kv_pages - 1, page_size,
@@ -685,8 +724,14 @@ class LLMEngine:
                 every step); new rows ride a small dense tail, merged
                 into the pages once at block end
                 (ops/paged_attention.py).  The lanes' state (whatever
-                the model keeps beside the pool: a few rows a lane, or
-                nothing) rides the carry, and the routed layers' counts
+                the model keeps beside the pool: nothing, a few rows a
+                lane, or gigabytes of state matrices) rides the carry:
+                the cache is donated, the scan's carry is updated where
+                it lies, and a model with large state writes it through
+                a kernel that aliases its input (`ops/ssm.ssm_update`),
+                so no step copies or selects over a leaf of it
+                (tests/test_chip_compile.py reads the compiled
+                program).  The routed layers' counts
                 ([routed layers, 4]: experts hit, largest load,
                 assignments, visits; summed over the K steps) come back
                 beside `seq`, fetched in the same sync.  The table and the
@@ -929,6 +974,14 @@ class LLMEngine:
         # query block, key block) triples that are work, and all of them.
         self.prefill_attn_blocks = 0
         self.prefill_attn_blocks_dense = 0
+        # State-space layers (a model that declares `scan_chunk`):
+        # the one-step kernel's work list a window, live lanes x K x
+        # those layers; and the chunked scan's walk a full-prompt
+        # program, chunks below the rows' true lengths and chunks of the
+        # padded program, each x those layers.
+        self.ssm_lane_steps = 0
+        self.prefill_scan_chunks = 0
+        self.prefill_scan_chunks_dense = 0
         # Rows the attention kernel had to attend: a live lane's context
         # at each of a window's K steps (block-start rows + the tail's
         # j + 1), summed over lanes, steps and windows.
@@ -939,7 +992,8 @@ class LLMEngine:
         self.prefill_programs = 0      # (width, length) programs dispatched
         self.prefill_waves = 0
         self.prefill_waves_split = 0   # plans of more programs than chunks
-        # waves the planner's token ceiling split (PREFILL_MAX_TOKENS)
+        # waves a ceiling of the planner split (PREFILL_MAX_TOKENS
+        # positions, PREFILL_MAX_STATE_BYTES of lane state handed over)
         self.prefill_programs_capped = 0
         # Routed layers (a model that declares `routed_layers`): layer
         # x steps run, assignments computed, experts that held a row,
@@ -2170,8 +2224,10 @@ class LLMEngine:
             true0, padded0 = self.prefill_tokens, self.prefill_padded_tokens
             lengths = [len(r.prompt) + len(r.tokens) - r.prefill_from
                        for _, r in wave]
+            scan0 = self.prefill_scan_chunks
             plan, capped = plan_wave(lengths, self._width_buckets,
-                                     self._buckets, self._chunk)
+                                     self._buckets, self._chunk,
+                                     self._row_state_bytes)
             for rows, w, b in plan:
                 chunk = [wave[i] for i in rows]
                 t_disp = time.time()
@@ -2194,6 +2250,8 @@ class LLMEngine:
                       padded_tokens=self.prefill_padded_tokens - padded0,
                       chunks=len(plan),
                       plan=",".join(f"{w}x{b}" for _, w, b in plan))
+            if self._scan_layers:
+                ph.update(scan_chunks=self.prefill_scan_chunks - scan0)
         with self._phase("prefill_sync", iter=it, rows=len(wave)):
             counts, self._prefill_counts = self._prefill_counts, []
             for a in ([nxt for _, nxt, _t in pending_waves]
@@ -2282,6 +2340,14 @@ class LLMEngine:
         self.prefill_attn_blocks += attn_blocks(bucket, true_lens, bq, bk)
         self.prefill_attn_blocks_dense += \
             padded_w * -(-bucket // bq) * -(-bucket // bk)
+        if self._scan_layers:
+            # the chunked scan walks every chunk of the padded program;
+            # those below a row's true length are work
+            q = self._scan_chunk
+            self.prefill_scan_chunks += self._scan_layers * sum(
+                -(-int(n) // q) for n in true_lens)
+            self.prefill_scan_chunks_dense += \
+                self._scan_layers * len(true_lens) * -(-bucket // q)
         slots_dev = jnp.asarray(slots)
         lens_dev = jnp.asarray(true_lens)
         cols = np.arange(bucket) // self.page
@@ -2739,6 +2805,9 @@ class LLMEngine:
                 attn_steps += max(-(-rows // self.page), 1)
                 attn_rows += k_win * rows + k_win * (k_win + 1) // 2
             ph.update(attn_steps=attn_steps)
+            if self._scan_layers:
+                ph.update(ssm_lane_steps=len(active) * k_win
+                          * self._scan_layers)
             win_traced = tracing.ENABLED and any(
                 self._slots[i] is not None
                 and self._slots[i].trace is not None for i in active)
@@ -2761,6 +2830,7 @@ class LLMEngine:
             ph.update(demote_pages=self._dispatch_demotes())
             self.decode_steps += k_win
             self.lane_steps_live += len(active) * k_win
+            self.ssm_lane_steps += len(active) * k_win * self._scan_layers
             self.attn_steps += attn_steps
             self.attn_steps_dense += self.max_batch * (self._maxp + 1)
             self.attn_ctx_rows += attn_rows
@@ -2882,6 +2952,9 @@ class LLMEngine:
                "prefill_attn_blocks": self.prefill_attn_blocks,
                "prefill_attn_blocks_dense": self.prefill_attn_blocks_dense,
                "prefill_programs_capped": self.prefill_programs_capped,
+               "ssm_lane_steps": self.ssm_lane_steps,
+               "prefill_scan_chunks": self.prefill_scan_chunks,
+               "prefill_scan_chunks_dense": self.prefill_scan_chunks_dense,
                "demote_bytes": self.demote_bytes,
                "preemptions": self.preemptions,
                "completed": self.completed,
@@ -2944,6 +3017,22 @@ class LLMEngine:
                 "layers": max(len(v) for v in pool.values()),
                 "pool_bytes": int(sum(a.size * a.dtype.itemsize
                                       for v in pool.values() for a in v))}
+
+    def _lane_state_stats(self) -> dict:
+        """The lanes' state beside the pool, from its own leaves: bytes
+        in all and by kind (the keys of a state that is a dict: memory
+        divided between the pool, `stats()["cache"]`, and these)."""
+        import jax
+
+        def nbytes(tree):
+            return int(sum(a.size * a.dtype.itemsize
+                           for a in jax.tree.leaves(tree)))
+
+        state = self.cache["state"]
+        kinds = state if isinstance(state, dict) else {"rows": state}
+        return {"layers": self._lane_layers, "bytes": nbytes(state),
+                "by_kind": {k: nbytes(v) for k, v in kinds.items()},
+                "prefix_cache": "off: lane state"}
 
     def stats(self) -> dict:
         out = {"completed": self.completed,
@@ -3008,6 +3097,11 @@ class LLMEngine:
                    "prefill_programs_capped":
                    self.prefill_programs_capped},
                "cache": dict(self._cache_info)}
+        if self._scan_layers:
+            out["loop"].update(
+                ssm_lane_steps=self.ssm_lane_steps,
+                prefill_scan_chunks=self.prefill_scan_chunks,
+                prefill_scan_chunks_dense=self.prefill_scan_chunks_dense)
         with _BUILDS_LOCK:
             # every program this PROCESS built since its first engine
             # was made; the ledger of threads is the process's too
@@ -3017,11 +3111,7 @@ class LLMEngine:
         if self._moe_layers:
             out["loop"].update(self.moe)
         if self._lane_layers:
-            out["lane_state"] = {
-                "layers": self._lane_layers,
-                "bytes": int(sum(a.size * a.dtype.itemsize
-                                 for a in self.cache["state"])),
-                "prefix_cache": "off: lane state"}
+            out["lane_state"] = dict(self._lane_info)
         if self._lora_banks is not None:
             with self._lora_lock:
                 now = time.monotonic()

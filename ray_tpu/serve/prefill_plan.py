@@ -20,6 +20,23 @@ FLOOR_TOKENS = 256
 # theirs changes.
 PREFILL_MAX_TOKENS = 32768
 
+# Bytes of lane state over which no program's rows hand the scatter
+# program unless it has ONE row: a model whose lanes carry state beside
+# the page pool returns each row's state from the prefill program, a
+# temporary that lives until the scatter has written it into the lane
+# (a state-space model's matrices: 76.4 MB a ROW at 36 layers x 64 heads
+# x 64 x 128 float32).  What it buys, for that model whole on a 16.9 GB
+# chip with 12.35 GB resident (sandbox compile for the chip, PR 39): an
+# 8 x 1024 program holds 0.67 GB of temporaries and hands over 0.68 GB,
+# 13.70 GB in all, and the chip's peak reads 14.07-14.68 GB with the
+# decode program's own beside it; a 16 x 1024 program holds 1.32 + 1.36
+# GB, 15.03 GB in all, which the same 0.4-1.0 GB on top puts at
+# 15.4-16.0 GB: over the 15.5 GB a deployment is sized to.  So that
+# model's widest program is 8 rows (and it builds 5 programs fewer, ~10 s
+# each at 40 layers).  A model without such state gives 0 bytes a row and
+# no plan of its changes.
+PREFILL_MAX_STATE_BYTES = 1024 * 1024 * 1024
+
 
 def program_cost(width: int, bucket: int) -> int:
     """Token positions a (width, bucket) prefill program is charged."""
@@ -27,7 +44,8 @@ def program_cost(width: int, bucket: int) -> int:
 
 
 def plan_wave(lengths: list[int], widths: list[int], buckets: list[int],
-              chunk: int) -> tuple[list[tuple[list[int], int, int]], bool]:
+              chunk: int, row_state_bytes: int = 0
+              ) -> tuple[list[tuple[list[int], int, int]], bool]:
     """Partition a wave's rows into prefill programs of least total cost.
 
     `lengths[i]` is the token count row i's prefill pads (prompt, or the
@@ -37,11 +55,14 @@ def plan_wave(lengths: list[int], widths: list[int], buckets: list[int],
     the smallest width that holds it and the length bucket of its
     longest row, so no program lies outside widths x buckets or outside
     the span of the rows' own buckets; a group of more than one row whose
-    program would hold more than PREFILL_MAX_TOKENS positions is not
-    formed.  Ties go to fewer programs: `w` equal rows stay ONE w-wide
-    program (what a warm-up that submits exactly that relies on).
+    program would hold more than PREFILL_MAX_TOKENS positions, or whose
+    rows would hand over more than PREFILL_MAX_STATE_BYTES of lane state
+    (`row_state_bytes` a row, padding rows counted: the program returns
+    theirs too), is not formed.  Ties go to fewer programs: `w` equal
+    rows stay ONE w-wide program (what a warm-up that submits exactly
+    that relies on).
     Returns the plan, (row indices, width, bucket) per program, shortest
-    first, and whether the ceiling shaped it: whether a group of the plan
+    first, and whether a ceiling shaped it: whether a group of the plan
     stands where a forbidden one would have been taken."""
     order = sorted(range(len(lengths)), key=lengths.__getitem__)
     bucket_of = [next(b for b in buckets if b >= lengths[i]) for i in order]
@@ -53,7 +74,9 @@ def plan_wave(lengths: list[int], widths: list[int], buckets: list[int],
     for i in range(1, len(order) + 1):
         allowed, forbidden = [], []
         for g in range(1, min(chunk, i) + 1):
-            over = g > 1 and width_of[g] * bucket_of[i - 1] > PREFILL_MAX_TOKENS
+            over = g > 1 and (
+                width_of[g] * bucket_of[i - 1] > PREFILL_MAX_TOKENS
+                or width_of[g] * row_state_bytes > PREFILL_MAX_STATE_BYTES)
             (forbidden if over else allowed).append(
                 (best[i - g][0] + program_cost(width_of[g], bucket_of[i - 1]),
                  best[i - g][1] + 1, g))

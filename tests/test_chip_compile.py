@@ -731,3 +731,142 @@ def test_sharded_train_step_lowers_for_four_chips(topo, compiled_kernels,
     with jax.set_mesh(mesh):
         low = step.lower(state, {"inputs": tok, "targets": tok})
     assert low.as_text().count("tpu_custom_call") == 3
+
+
+# ------------------------------- the state-space hybrid model (PR 39)
+def _granite_lowerings(one_chip, shapes, layer_types=None):
+    """benchmarks/configs/granite-4.0-h-micro.json as the benchmark
+    builds it (or with other `layer_types`: the bodies are per run)."""
+    from benchmarks.harness import spec
+    from ray_tpu.serve.llm import LLMEngine
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def abstract(tree):
+        return jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
+
+    conf = spec.load_json(os.path.join(
+        spec.BENCH_DIR, "configs", "granite-4.0-h-micro.json"))
+    if layer_types is not None:
+        conf.update(layer_types=layer_types,
+                    num_hidden_layers=len(layer_types))
+    fam = spec.config_family(conf)
+    eng_kw = dict(conf["engine"], paged=True)
+    cfg = fam.program_config(fam.published(conf), max_seq=eng_kw["max_len"])
+    params = abstract(jax.eval_shape(
+        lambda: fam.init_params(jax.random.PRNGKey(0), cfg)))
+    eng = LLMEngine(cfg, params, **eng_kw)
+    i32, f32 = jnp.int32, jnp.float32
+    b, k = eng.max_batch, eng.steps_per_sync
+    out = {f"decode_k{k}": eng._decode_fns[k].lower(
+        params, abstract(eng.cache), sds((b,), i32), sds((b,), f32),
+        sds((b, eng._maxp), i32), sds((b,), i32), sds((b,), i32), None)}
+    for w, p in shapes:
+        out[f"prefill_w{w}_p{p}"] = eng._prefill_fwd.lower(
+            params, sds((w, p), i32), sds((w,), i32), sds((w,), i32),
+            sds((w,), f32), sds((w,), i32), sds((w,), i32), None)
+    return cfg, eng, out
+
+
+@pytest.mark.parametrize("lanes,layers", [(64, 36), (64, 9), (8, 36)])
+def test_ssm_update_compiles_at_the_served_widths(one_chip, compiled_kernels,
+                                                  lanes, layers):
+    """granite-4.0-h-micro's lanes: 36 layers x [128, 4096] float32 a
+    lane, one 2 MB block a grid step, read and written through the alias;
+    nothing the size of the state is a temporary."""
+    from ray_tpu.ops import ssm
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    L, N, HP = layers, 128, 4096
+    low = jax.jit(ssm.ssm_update, donate_argnums=(0,)).lower(
+        s((L, lanes, N, HP), jnp.float32), s((), jnp.int32),
+        s((lanes,), jnp.int32), s((), jnp.int32), s((lanes, HP)),
+        s((lanes, HP), jnp.float32), s((lanes, N)), s((lanes, N)),
+        s((HP,), jnp.float32), s((HP,), jnp.float32))
+    assert low.as_text().count("tpu_custom_call") == 1
+    c = low.compile()
+    mem = c.memory_analysis()
+    state_bytes = L * lanes * N * HP * 4
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.temp_size_in_bytes < state_bytes // 64
+    assert _pool_copies(c.as_text(), lanes * N * HP) == []
+
+
+@pytest.mark.time_limit(600)
+def test_served_granite_engine_fits_one_chip(topo, one_chip, compiled_kernels,
+                                             monkeypatch):
+    """granite-4.0-h-micro as the benchmark serves it, WHOLE (40 layers,
+    64 lanes, 257 pages): the decode program and the widest prefill
+    program its traffic reaches (8 x 1024: the planner's state ceiling
+    keeps 16 rows apart) compile for one chip and
+    leave 1.5 GB beside weights + lane state + pool."""
+    monkeypatch.setattr(jax, "devices", lambda *a: list(topo.devices))
+    cfg, eng, lows = _granite_lowerings(one_chip, [(8, 1024)])
+    lane = eng.stats()["lane_state"]
+    assert lane["by_kind"] == {"conv": 36 * 64 * 3 * 4352 * 2,
+                               "ssm": 36 * 64 * 128 * 4096 * 4}
+    assert eng._cache_stats() == {"kind": "kv", "row_bytes": 2 * 8 * 64 * 2,
+                                  "layers": 4,
+                                  "pool_bytes": 257 * 512 * 2048 * 4}
+    weights = sum(math.prod(a.shape) * a.dtype.itemsize
+                  for a in jax.tree.leaves(eng.params))
+    resident = weights + lane["bytes"] + eng._cache_stats()["pool_bytes"]
+    assert 12.3e9 < resident < 12.4e9        # 73 % of the chip
+    kernels = {"decode_k8": ("ssm_update", "paged_attn"),
+               "prefill_w8_p1024": ("flash_fwd",)}
+    for name, low in lows.items():
+        txt = low.as_text()
+        for kern in kernels[name]:
+            assert kern in txt, (name, kern)
+        mem = low.compile().memory_analysis()
+        held = resident + mem.temp_size_in_bytes + (
+            mem.output_size_in_bytes - mem.alias_size_in_bytes)
+        print(f"{name}: temps {mem.temp_size_in_bytes / 1e9:.2f} GB, "
+              f"resident {resident / 1e9:.2f} GB, held {held / 1e9:.2f} GB")
+        assert held < 16.9e9 - 1.5e9, (name, held)
+
+
+def test_granite_decode_step_loop_copies_no_lane_state_and_no_weight(
+        topo, one_chip, compiled_kernels, monkeypatch):
+    """The decode program at the served widths, one period of the
+    published pattern (the bodies are per run): inside the K-step loop
+    the lanes' state matrices (4.8 GB at 36 layers; 1.2 GB here) are
+    touched by `ssm_update` alone, which aliases them: no copy, select,
+    broadcast or scatter the size of ONE LAYER's lanes (64 x 128 x 4096)
+    exists in the compiled program, inside the loop or outside it; no
+    weight is re-laid-out in the loop; and the state is donated through
+    the program."""
+    monkeypatch.setattr(jax, "devices", lambda *a: list(topo.devices))
+    period = ["mamba"] * 5 + ["attention"] + ["mamba"] * 4
+    cfg, eng, lows = _granite_lowerings(one_chip, [], period)
+    c = lows["decode_k8"].compile()
+    hlo = c.as_text()
+    assert "while(" in hlo and "ssm_update" in hlo and "paged_attn" in hlo
+    layer_state = 64 * 128 * 4096
+    found = weight_sized_writes(hlo, layer_state)
+    assert found and all(op == "custom-call" and "ssm_update" in scope
+                         for _, op, scope in found), found
+    # (the bfloat16 page pools of head_dim 64 are copied to the layout
+    # the kernels read, once a window: PERF.md section 7; the state is
+    # the float32 array)
+    lines = {m.group(1): ln for ln in hlo.splitlines()
+             for m in [_INSTR.match(ln)] if m}
+    assert [n for n in _pool_copies(hlo, layer_state)
+            if " f32[" in lines[n].split("copy(")[0]] == []
+    # the smallest matrix a step multiplies is wk / wv, 2048 x 512; what
+    # the loop does write of that size are the convolution rows (64 x 3
+    # x 4352 a layer, shifted and stacked by run), under no matmul's name
+    small = [f for f in weight_sized_writes(hlo, cfg.dim * 512)
+             if "ssm_update" not in f[2]]
+    assert [f for f in small if any(scope in f[2] for scope in (
+        "ssm_in_proj", "ssm_out", "mlp", "attn_qkv", "attn_out",
+        "lm_head"))] == []
+    # the whole state is an argument the result aliases
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= 9 * layer_state * 4
+    loop = _loop_lines(hlo)
+    calls = [ln for ln in loop if "custom-call(" in ln and "ssm_update" in ln]
+    assert len(calls) == 2                  # a body a Mamba run

@@ -123,6 +123,7 @@ def test_engine_generates_the_reference_tokens(params):
         * lfm2.routed_layers(CFG)
     assert st["lane_state"] == {
         "layers": 2, "bytes": 2 * 2 * 2 * 64 * 4,
+        "by_kind": {"rows": 2 * 2 * 2 * 64 * 4},
         "prefix_cache": "off: lane state"}
     assert st["prefix_cache"] is False
 

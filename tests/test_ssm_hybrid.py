@@ -1,0 +1,476 @@
+"""models/ssm_hybrid.py (Mamba-2 state-space layers beside NoPE GQA
+attention, Granite's scalars) and ops/ssm.py against the plain float32
+reference the benchmark holds them to (`benchmarks/harness/refs/
+ssm_hybrid.py`: the token-by-token recurrence, importing nothing of the
+program): the chunked scan at lengths that are no multiple of the chunk,
+the one-step kernel with idle lanes, the prompt pass at a padded bucket
+followed by paged decode through the pool and the lane state, the ENGINE's
+own logits with lanes reused and more requests than lanes, the controls a
+sound comparison must fail, and the counters."""
+from __future__ import annotations
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from serving_reference import served_logits  # rootdir-relative (no pkg)
+
+from benchmarks.harness.refs import ssm_hybrid as ref
+from ray_tpu.models import named_config, serving_model, ssm_hybrid
+from ray_tpu.ops import paged_attention, ssm
+from ray_tpu.serve.llm import LLMEngine, LLMServer
+from ray_tpu.serve.prefill_plan import PREFILL_MAX_STATE_BYTES, plan_wave
+
+# float32 weights: the served path and the reference then differ by
+# summation order alone, so the bound is tight and every control stands
+# far outside it
+# (and scores at head_dim**-0.5, a given number all the same: at the
+# preset's 1/64 the softmax over 30 keys is flat and no control of the
+# attention layer could part from the reference)
+CFG = dataclasses.replace(named_config("ssm-hybrid-debug"),
+                          dtype=jnp.float32, attn_scale=0.25)
+MODEL = dict(num_attention_heads=4, num_key_value_heads=2,
+             rms_norm_eps=1e-5, attention_multiplier=CFG.attn_scale,
+             embedding_multiplier=12, residual_multiplier=0.22,
+             logits_scaling=8, mamba_n_heads=4, mamba_d_head=16,
+             mamba_d_state=16, mamba_d_conv=4,
+             layer_types=list(CFG.layer_types))
+TOL = 2e-5          # float32 against float32, of the logits' scale
+CONTROL = 2e-3      # what every control must exceed, 100 x TOL
+
+
+def _gap(got, want) -> float:
+    """The largest difference of two arrays of logits as a share of the
+    reference's largest logit (the embedding is drawn small, so the
+    logits are of scale 1e-2: `ssm_hybrid.init_params`)."""
+    got, want = np.asarray(got), np.asarray(want)
+    return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+PAGE, K = 16, 4
+N_MAMBA = CFG.layer_types.count("mamba")
+
+
+@pytest.fixture(scope="module")
+def params():
+    return ssm_hybrid.init_params(jax.random.PRNGKey(7), CFG)
+
+
+def _tokens(n, seed=0):
+    return np.random.default_rng(seed).integers(0, 256, n).astype(np.int32)
+
+
+def _scan_inputs(b, T, H=4, P=16, N=16, lens=None, seed=0):
+    k = jax.random.split(jax.random.PRNGKey(seed), 5)
+    x = jax.random.normal(k[0], (b, T, H, P))
+    dt = jax.nn.softplus(jax.random.normal(k[1], (b, T, H)) - 2)
+    if lens is not None:
+        dt = jnp.where(jnp.arange(T)[None, :, None]
+                       < jnp.asarray(lens)[:, None, None], dt, 0.0)
+    A = -jnp.exp(jax.random.normal(k[2], (H,)))
+    B = jax.random.normal(k[3], (b, T, N))
+    C = jax.random.normal(k[4], (b, T, N))
+    return x, dt, A, B, C
+
+
+def _recurrence(x, dt, A, B, C):
+    """The recurrence token by token for ONE row (the plain reference's
+    `lax.scan` over positions), in `ssd_scan`'s shapes."""
+    y, h = ref.recurrence(x[0], dt[0], A, B[0], C[0])
+    return y[None], h[None]
+
+
+# ------------------------------------------ (a) the chunked scan, ops/ssm
+@pytest.mark.parametrize("T,chunk,lens", [
+    (20, 8, [13, 20]),      # no multiple of the chunk, right padding
+    (32, 8, [1, 31]),       # a row of one token
+    (5, 8, [5, 3]),         # a bucket under one chunk: one short chunk
+    (64, 16, [64, 17]),
+])
+def test_ssd_scan_equals_the_recurrence_at_the_true_length(T, chunk, lens):
+    x, dt, A, B, C = _scan_inputs(2, T, lens=lens)
+    y, h = ssm.ssd_scan(x, dt, A, B, C, chunk)
+    for row, n in enumerate(lens):
+        cut = [a[row:row + 1, :n] for a in (x, dt)] + [A] \
+            + [a[row:row + 1, :n] for a in (B, C)]
+        want_y, want_h = _recurrence(*cut)
+        scale = float(jnp.max(jnp.abs(want_y)))
+        assert float(jnp.max(jnp.abs(y[row, :n] - want_y[0]))) < 1e-5 * scale
+        assert float(jnp.max(jnp.abs(h[row] - want_h[0]))) \
+            < 1e-5 * float(jnp.max(jnp.abs(want_h)))
+
+
+def test_ssd_scan_carries_the_state_between_chunks():
+    """Three chunks of 8 against one chunk of 24: what a chunk hands the
+    next (the decayed state, and its share of the next chunk's y) is the
+    only difference between the two programs."""
+    x, dt, A, B, C = _scan_inputs(1, 24)
+    y, h = ssm.ssd_scan(x, dt, A, B, C, 8)
+    y1, h1 = ssm.ssd_scan(x, dt, A, B, C, 24)
+    want_y, want_h = _recurrence(x, dt, A, B, C)
+    for got_y, got_h in ((y, h), (y1, h1)):
+        assert float(jnp.max(jnp.abs(got_y - want_y))) < 1e-4
+        assert float(jnp.max(jnp.abs(got_h - want_h))) < 1e-4
+
+
+# -------------------------------------- (b) the one-step kernel, ops/ssm
+def _update_inputs(nb=6, L=3, H=4, P=16, N=16, seed=1):
+    k = jax.random.split(jax.random.PRNGKey(seed), 6)
+    state = jax.random.normal(k[0], (L, nb, N, H * P))
+    x = jax.random.normal(k[1], (nb, H * P))
+    dt = jnp.repeat(jax.random.normal(k[2], (nb, H)), P, axis=1)
+    A_log = jnp.repeat(jnp.log(jnp.arange(1.0, H + 1)), P)
+    D = jnp.repeat(jax.random.normal(k[3], (H,)), P)
+    return (state, x, dt, jax.random.normal(k[4], (nb, N)),
+            jax.random.normal(k[5], (nb, N)), A_log, D)
+
+
+@pytest.mark.parametrize("live,heads", [
+    ([0, 1, 1, 0, 1, 0], 16), ([1, 1, 1, 1, 1, 1], 4),
+    ([0, 0, 0, 0, 0, 1], 16), ([0, 0, 0, 0, 0, 0], 4)])
+def test_ssm_update_is_one_recurrence_step_and_leaves_idle_lanes(
+        live, heads):
+    # 16 heads of 16: a lane's block is two register-wide column tiles;
+    # 4 heads: one narrower tile
+    state, x, dt, B, C, A_log, D = _update_inputs(H=heads)
+    live = jnp.asarray(live, bool)
+    lanes, count = ssm.live_lanes(live)
+    assert int(count) == int(live.sum())
+    assert lanes[:int(count)].tolist() == np.flatnonzero(live).tolist()
+    new, y = ssm.ssm_update(state, jnp.int32(1), lanes, count, x, dt, B, C,
+                            A_log, D)
+    d = jax.nn.softplus(dt)
+    want = (jnp.exp(d * -jnp.exp(A_log))[:, None] * state[1]
+            + B[:, :, None] * (d * x)[:, None])
+    want_y = jnp.einsum("bn,bnc->bc", C, want) + D * x
+    on, off = np.flatnonzero(live), np.flatnonzero(~live)
+    assert float(jnp.max(jnp.abs(new[1][on] - want[on]), initial=0)) < 1e-5
+    assert float(jnp.max(jnp.abs(y[on] - want_y[on]), initial=0)) < 1e-4
+    # an idle lane's state is bit-unchanged, its y rows are 0, and no
+    # other layer is touched
+    assert bool(jnp.all(new[1][off] == state[1][off]))
+    assert bool(jnp.all(y[off] == 0))
+    assert bool(jnp.all(new[0] == state[0])) \
+        and bool(jnp.all(new[2] == state[2]))
+
+
+# ------------------------- (c) prefill, then decode, against the forward
+def _worst(params_served, params_ref, n=21, bucket=32, follow=2 * K,
+           cfg=CFG):
+    prompt, nxt = _tokens(n, 1), _tokens(follow, 2)
+    got = served_logits(ssm_hybrid, params_served, cfg, prompt, nxt, bucket,
+                        page=PAGE, k=K)
+    want = ref.logits(params_ref, list(prompt) + list(nxt), MODEL,
+                      last=follow + 1)
+    return _gap(got, want)
+
+
+@pytest.mark.parametrize("n", [1, 2, 17, 32])
+def test_prefill_logits_equal_the_reference(params, n):
+    toks = _tokens(32, 3)[None]
+    h, *_ = ssm_hybrid.prefill(params, jnp.asarray(toks), CFG,
+                               jnp.asarray([n], jnp.int32))
+    got = ssm_hybrid.project_logits(params, h[0, :n])
+    want = ref.logits(params, toks[0, :n], MODEL)
+    assert _gap(got, want) < TOL
+
+
+@pytest.mark.parametrize("n,bucket", [(21, 32), (1, 32), (2, 32), (3, 32),
+                                      (33, 64), (9, 32)])
+def test_padded_prefill_then_paged_decode_equals_the_reference(
+        params, n, bucket):
+    """true_len a multiple of nothing (not of the chunk of 8 either): the
+    lane state must be the state and the convolution rows at the TRUE
+    length (zeros where the prompt is shorter than three), and two
+    windows of K steps carry them on."""
+    assert _worst(params, params, n=n, bucket=bucket) < TOL
+
+
+def test_the_prefill_hands_the_state_at_the_true_length(params):
+    toks = _tokens(32, 5)
+    _, _, _, state, _ = ssm_hybrid.prefill(
+        params, jnp.asarray(toks[None]), CFG, jnp.asarray([13], jnp.int32))
+    got = jnp.concatenate(state["ssm"])[:, 0]           # [Mamba layers, ...]
+    x = ref.embed(params, toks[:13], MODEL)
+    want = []
+    for kind, lp in ref.layers(params, MODEL):
+        x, h = ref.mixer_half(x, lp, kind, MODEL)
+        x = ref.mlp_half(x, lp, MODEL)
+        if h is not None:
+            want.append(h)
+    assert got.shape == (N_MAMBA, 16, 64)
+    assert float(jnp.max(jnp.abs(got - jnp.stack(want)))) < 1e-5
+
+
+# ------------------------------------------------ (c) through the engine
+def _record_engine_logits(monkeypatch):
+    """Every logit the engine's programs compute, as they compute it:
+    (input token, position, logits) of each live lane's decode step and of
+    each prefill row's last position."""
+    seen = []
+
+    def note(toks, pos, live, logits):
+        for t, p, ok, lg in zip(*map(np.asarray, (toks, pos, live, logits))):
+            if ok:
+                seen.append((int(t), int(p), lg))
+
+    step, prefill = ssm_hybrid.serve_decode_step, ssm_hybrid.serve_prefill
+
+    def decode_step(params, pages, tails, state, tokens, pos, ts, j, table,
+                    cfg, lora=None, plan=None):
+        out = step(params, pages, tails, state, tokens, pos, ts, j, table,
+                   cfg, lora, plan)
+        jax.debug.callback(note, tokens, pos,
+                           paged_attention.lanes_live(table), out[0])
+        return out
+
+    def prefill_rows(params, tokens, cfg, true_lens, lora=None):
+        out = prefill(params, tokens, cfg, true_lens, lora)
+        rows = jnp.arange(tokens.shape[0])
+        last = out[0][rows, true_lens - 1]
+        jax.debug.callback(
+            note, tokens[rows, true_lens - 1], true_lens - 1,
+            jnp.ones_like(true_lens, bool),
+            ssm_hybrid.project_logits(params, last).astype(jnp.float32))
+        return out
+
+    monkeypatch.setattr(ssm_hybrid, "serve_decode_step", decode_step)
+    monkeypatch.setattr(ssm_hybrid, "serve_prefill", prefill_rows)
+    return seen
+
+
+def test_engine_logits_equal_the_reference_across_lane_reuse(
+        params, monkeypatch):
+    """Two lanes, five prompts of other lengths: more requests than lanes,
+    so a lane that served one request serves another, and no state may
+    leak.  The LOGITS the engine's own programs computed at every served
+    position equal the reference's full forward, and the new counters
+    equal what the kernel's work list admits."""
+    seen = _record_engine_logits(monkeypatch)
+    eng = LLMEngine(CFG, params, max_batch=2, max_len=96, page_size=PAGE,
+                    kv_pages=12, steps_per_sync=K)
+    eng.start()
+    try:
+        prompts = [_tokens(n, 10 + n).tolist() for n in (40, 3, 17, 1, 29)]
+        futs = [eng.submit(p, max_new_tokens=14) for p in prompts]
+        outs = [f.result(timeout=300) for f in futs]
+        jax.effects_barrier()
+        st = eng.stats()
+    finally:
+        eng.stop()
+    assert st["completed"] == 5 and st["preemptions"] == 0
+    by_key = {}
+    for t, p, lg in seen:
+        by_key.setdefault((t, p), []).append(lg)
+    checked = 0
+    for prompt, out in zip(prompts, outs):
+        seq = prompt + out["tokens"]
+        want = np.asarray(ref.logits(params, seq[:-1], MODEL,
+                                     last=len(out["tokens"])))
+        for i, row in enumerate(want):
+            p = len(prompt) - 1 + i
+            got = by_key.get((seq[p], p), [])
+            assert got, (len(prompt), i)
+            assert min(_gap(g, row) for g in got) < TOL
+            checked += 1
+    assert checked == 5 * 14
+    # the counters: live lanes x K x Mamba layers a window; the chunks of
+    # 8 positions below the true lengths and of the padded programs
+    loop = st["loop"]
+    assert loop["ssm_lane_steps"] == loop["lane_steps_live"] * N_MAMBA
+    assert loop["prefill_scan_chunks"] == N_MAMBA * sum(
+        -(-len(p) // 8) for p in prompts)
+    assert loop["prefill_scan_chunks"] <= loop["prefill_scan_chunks_dense"]
+    assert loop["prefill_scan_chunks_dense"] % N_MAMBA == 0
+    lane = st["lane_state"]
+    assert lane["layers"] == N_MAMBA
+    assert lane["by_kind"] == {"conv": N_MAMBA * 2 * 3 * 96 * 4,
+                               "ssm": N_MAMBA * 2 * 16 * 64 * 4}
+    assert lane["bytes"] == sum(lane["by_kind"].values())
+    assert lane["prefix_cache"] == "off: lane state"
+    assert st["prefix_cache"] is False
+
+
+@pytest.mark.parametrize("live", [[1, 0, 1, 1], [0, 0, 0, 0], [1, 1, 1, 1]])
+def test_the_host_counts_what_the_kernels_work_list_admits(live):
+    """`loop.ssm_lane_steps` adds len(active) x K x layers a window; the
+    kernel's grid is `count` steps a layer-step, from the table the engine
+    sends: a lane is live where its table row does not start at the trash
+    page."""
+    table = jnp.asarray([[3 if on else 0, 0] for on in live], jnp.int32)
+    lanes, count = ssm.live_lanes(paged_attention.lanes_live(table))
+    assert int(count) == sum(live)
+    assert sorted(lanes[:int(count)].tolist()) == \
+        [i for i, on in enumerate(live) if on]
+
+
+def test_an_idle_lanes_state_is_bit_unchanged_by_a_decode_window(params):
+    """One of two lanes holds a request: the window's K steps update its
+    state matrices and leave the other lane's as they were."""
+    eng = LLMEngine(CFG, params, max_batch=2, max_len=64, page_size=PAGE,
+                    steps_per_sync=K)
+    mark = jnp.full_like(eng.cache["state"]["ssm"], 0.375)
+    eng.cache["state"]["ssm"] = mark
+    eng.start()
+    try:
+        eng.generate(_tokens(9, 4).tolist(), max_new_tokens=2 * K)
+    finally:
+        eng.stop()
+    after = np.asarray(eng.cache["state"]["ssm"])
+    assert (after[:, 1] == 0.375).all()          # the lane nobody held
+    assert not (after[:, 0] == 0.375).any(axis=(1, 2)).any()
+
+
+# ------------------------------------- (d) each scalar, each order: controls
+def _no(name):
+    return lambda cfg: dataclasses.replace(cfg, **{name: 1.0})
+
+
+def _rope_applied(x, lp, cfg, true_lens):
+    """Attention with a rotary embedding on q and k (prefill only: the
+    control needs one path to part from the reference)."""
+    from ray_tpu.models.llama import apply_rope
+    from ray_tpu.ops.rope import rope_frequencies
+
+    cos, sin = rope_frequencies(cfg.head_dim, x.shape[1], 10000.0)
+    real = ssm_hybrid.attention
+
+    def attention(q, k, v, **kw):
+        return real(apply_rope(q, cos, sin), apply_rope(k, cos, sin), v, **kw)
+
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(ssm_hybrid, "attention", attention)
+        return _ATTN_PREFILL(x, lp, cfg, true_lens)
+
+
+def _norm_before_gate(y, z, lp, cfg):
+    g = ssm_hybrid.rmsnorm(y, lp["gate_norm"], cfg.norm_eps) \
+        * jax.nn.silu(z.astype(jnp.float32))
+    return g.astype(cfg.dtype) @ lp["out_proj"]
+
+
+def _dt_unmasked(h, lp, cfg, true_lens):
+    z, x, _, B, C, rows = _SCAN_INPUTS(h, lp, cfg, true_lens)
+    full = jnp.full_like(true_lens, h.shape[1])
+    return (z, x, _SCAN_INPUTS(h, lp, cfg, full)[2], B, C, rows)
+
+
+def _conv_rows_at_the_padded_length(h, lp, cfg, true_lens):
+    full = jnp.full_like(true_lens, h.shape[1])
+    return _SCAN_INPUTS(h, lp, cfg, true_lens)[:5] \
+        + (_SCAN_INPUTS(h, lp, cfg, full)[5],)
+
+
+def _scatter_zero_state(cache, ks, vs, state, *a, **kw):
+    return _SCATTER(cache, ks, vs, jax.tree.map(jnp.zeros_like, state),
+                    *a, **kw)
+
+
+def _state_through_bf16(*a, **kw):
+    new, y = _UPDATE(*a, **kw)
+    return new.astype(jnp.bfloat16).astype(new.dtype), y
+
+
+_ATTN_PREFILL, _SCAN_INPUTS = ssm_hybrid.attn_prefill, ssm_hybrid.scan_inputs
+_SCATTER, _UPDATE = ssm_hybrid.scatter_prefill_pages, ssm.ssm_update
+
+
+@pytest.mark.parametrize("control", [
+    "sound", "attention_scale_1_over_8", "rope_applied",
+    "residual_multiplier_left_out", "embedding_multiplier_left_out",
+    "logits_scaling_left_out", "norm_before_gate", "D_left_out",
+    "dt_unmasked_past_the_true_length",
+    "conv_rows_at_the_padded_length", "lane_state_zeroed_at_admission",
+    "a_mamba_layer_skipped", "state_through_bfloat16"])
+def test_every_control_exceeds_the_tolerance(params, monkeypatch, control):
+    served, cfg = params, CFG
+    if control == "attention_scale_1_over_8":
+        cfg = dataclasses.replace(CFG, attn_scale=0.125)
+    elif control == "rope_applied":
+        monkeypatch.setattr(ssm_hybrid, "attn_prefill", _rope_applied)
+    elif control == "residual_multiplier_left_out":
+        cfg = _no("residual_scale")(CFG)
+    elif control == "embedding_multiplier_left_out":
+        cfg = _no("embed_scale")(CFG)
+    elif control == "logits_scaling_left_out":
+        cfg = _no("logits_scale")(CFG)
+    elif control == "norm_before_gate":
+        monkeypatch.setattr(ssm_hybrid, "_gate_out", _norm_before_gate)
+    elif control == "D_left_out":
+        served = dict(params, mamba=dict(
+            params["mamba"], D=jnp.zeros_like(params["mamba"]["D"])))
+    elif control == "dt_unmasked_past_the_true_length":
+        monkeypatch.setattr(ssm_hybrid, "scan_inputs", _dt_unmasked)
+    elif control == "conv_rows_at_the_padded_length":
+        monkeypatch.setattr(ssm_hybrid, "scan_inputs",
+                            _conv_rows_at_the_padded_length)
+    elif control == "lane_state_zeroed_at_admission":
+        monkeypatch.setattr(ssm_hybrid, "serve_scatter", _scatter_zero_state)
+    elif control == "a_mamba_layer_skipped":
+        served = dict(params, mamba=dict(
+            params["mamba"],
+            out_proj=params["mamba"]["out_proj"].at[1].set(0.0)))
+    elif control == "state_through_bfloat16":
+        monkeypatch.setattr(ssm, "ssm_update", _state_through_bf16)
+    worst = _worst(served, params, cfg=cfg)
+    if control == "sound":
+        assert worst < TOL
+    elif control == "state_through_bfloat16":
+        # a rounding of 2**-9 of the state a step moves these logits by
+        # 7e-4 of their scale over the eight steps walked: 400 x the
+        # sound reading (1.5e-6) and 30 x the tolerance, where every
+        # other control stands 5,000 x outside it (the benchmark's judge
+        # reads the state itself: families/ssm_hybrid.STATE_ERR_TOL)
+        assert worst > 10 * TOL
+    else:
+        assert worst > CONTROL
+
+
+# ---------------------------------------------- the planner's state ceiling
+@pytest.mark.parametrize("row_bytes,widest", [
+    (0, 16), (PREFILL_MAX_STATE_BYTES // 16, 16),
+    (PREFILL_MAX_STATE_BYTES // 16 + 1, 8),
+    (PREFILL_MAX_STATE_BYTES // 8 + 1, 1)])
+def test_the_planner_bounds_a_programs_width_by_the_state_handed_over(
+        row_bytes, widest):
+    lengths = [100] * 16
+    plan, capped = plan_wave(lengths, [1, 8, 16], [32, 64, 128], 16,
+                             row_bytes)
+    assert sorted(i for rows, _, _ in plan for i in rows) == list(range(16))
+    assert max(w for _, w, _ in plan) == widest
+    assert capped == (widest < 16)
+    assert all(w * row_bytes <= PREFILL_MAX_STATE_BYTES or w == 1
+               for _, w, _ in plan)
+
+
+def test_the_published_state_fits_eight_rows_a_program():
+    cfg = named_config("granite-4.0-h-micro")
+    row = ssm_hybrid.prefill_state_bytes(cfg)
+    assert row == 36 * (128 * 4096 * 4 + 3 * 4352 * 2) == 76_437_504
+    assert 8 * row <= PREFILL_MAX_STATE_BYTES < 16 * row
+
+
+# --------------------------------------------- what the engine refuses
+def test_a_state_space_model_is_served_without_the_prefix_cache(params):
+    assert serving_model(CFG) is ssm_hybrid
+    with pytest.raises(ValueError, match="radix prefix hit cannot restore"):
+        LLMEngine(CFG, params, max_batch=2, max_len=64, page_size=PAGE,
+                  prefix_cache=True)
+    with pytest.raises(ValueError, match="no LoRA hooks"):
+        LLMEngine(CFG, params, max_batch=2, max_len=64, page_size=PAGE,
+                  lora_slots=2, lora_rank=4)
+    eng = LLMEngine(CFG, params, max_batch=2, max_len=64, page_size=PAGE)
+    with pytest.raises(ValueError, match="no KV export/import"):
+        eng.submit([1, 2, 3], prefill_only=True)
+
+
+def test_the_server_serves_the_preset_by_name():
+    srv = LLMServer("ssm-hybrid-debug", max_batch=2, max_len=64,
+                    page_size=PAGE)
+    try:
+        out = srv.engine.generate([5, 6, 7], max_new_tokens=5)
+        assert len(out["tokens"]) == 5
+        assert srv._prefix_client is None       # no demotion either
+    finally:
+        srv.engine.stop()
