@@ -42,7 +42,12 @@ from ray_tpu.models.vit import ViTConfig, vit_configs
 #     chunk (the engine's `ssm_lane_steps` and `prefill_scan_chunks`
 #     counters) and prefill_state_bytes(cfg) (the state ONE prefill row
 #     hands the scatter: the wave planner bounds a program's width by
-#     it); routed_layers(cfg): the rows of `counts`, int32
+#     it); prefill_params(cfg) (a model whose prefill program reads
+#     weights a position does not multiply, a routed layer's experts:
+#     the matmul parameters a program STREAMS whatever it holds and
+#     those ONE position multiplies; the planner's floor and the
+#     programs the engine builds follow their ratio; without it the
+#     ratio is 1); routed_layers(cfg): the rows of `counts`, int32
 #     [routed layers, 4] = experts that held a row, the largest load,
 #     assignments computed, visits of the grouped matmul that were work
 #     (0 rows: nothing is counted; a config with routed layers has

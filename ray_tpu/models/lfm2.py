@@ -399,3 +399,16 @@ def decode_step_paged(params: dict, pages: dict, tails: dict, state: list,
 serve_prefill = prefill
 serve_scatter = scatter_prefill_pages
 serve_decode_step = decode_step_paged
+
+
+def prefill_params(cfg: Lfm2MoeConfig) -> tuple[int, int]:
+    """Matmul parameters a prefill program STREAMS whatever it holds and
+    those ONE position multiplies (the seam's declaration: the wave
+    planner's floor and the programs the engine builds follow their
+    ratio, `routed.prefill_params`)."""
+    d, hd = cfg.dim, cfg.head_dim
+    n_attn = attn_layers(cfg)
+    rest = (n_attn * d * hd * 2 * (cfg.n_heads + cfg.n_kv_heads)
+            + (cfg.n_layers - n_attn) * 4 * d * d
+            + (cfg.n_layers - routed_layers(cfg)) * 3 * d * cfg.ffn_dim)
+    return routed.prefill_params(cfg, rest, routed_layers(cfg))

@@ -432,3 +432,20 @@ def decode_step_paged(params: dict, pages: dict, tails: dict, state: list,
 serve_prefill = prefill
 serve_scatter = scatter_prefill_pages
 serve_decode_step = decode_step_paged
+
+
+def prefill_params(cfg: MlaMoeConfig) -> tuple[int, int]:
+    """Matmul parameters a prefill program STREAMS whatever it holds and
+    those ONE position multiplies (the seam's declaration: the wave
+    planner's floor and the programs the engine builds follow their
+    ratio, `routed.prefill_params`): of a position's `top_k` experts this
+    chip multiplies the share it holds."""
+    d, H, r = cfg.dim, cfg.n_heads, cfg.kv_lora_rank
+    attn = (d * H * cfg.qk_head_dim + d * cfg.row_used
+            + H * r * (cfg.qk_nope_dim + cfg.v_head_dim)
+            + H * cfg.v_head_dim * d)
+    shared = 3 * d * cfg.moe_ffn_dim * cfg.n_shared_experts
+    rest = (cfg.n_layers * attn + routed_layers(cfg) * shared
+            + (cfg.n_layers - routed_layers(cfg)) * 3 * d * cfg.ffn_dim)
+    return routed.prefill_params(cfg, rest, routed_layers(cfg),
+                                 cfg.experts_held)

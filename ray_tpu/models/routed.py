@@ -125,3 +125,22 @@ def stack_counts(per_layer: list) -> jnp.ndarray:
     return (jnp.stack([jnp.pad(c, (0, COUNTS - c.shape[0]))
                        for c in per_layer]) if per_layer
             else jnp.zeros((0, COUNTS), jnp.int32))
+
+
+def prefill_params(cfg, rest: int, layers: int,
+                   experts: tuple[int, int] | None = None
+                   ) -> tuple[int, int]:
+    """(streamed, multiplied) matmul parameters of a model with `layers`
+    routed layers over the range `experts` (default all) and `rest`
+    parameters that every position multiplies (attention, convolutions,
+    dense and shared SwiGLUs; the routers are added here).  STREAMED: what
+    a prefill program reads whatever it holds: every held expert, since
+    the grouped matmul reads a hit expert whole and ~100 positions hit
+    them all.  MULTIPLIED: what ONE position multiplies: of its `top_k`
+    experts those this chip holds.  Neither counts the embedding (a
+    lookup) or the head (one position a row)."""
+    lo, hi = experts or (0, cfg.n_experts)
+    one = 3 * cfg.dim * cfg.moe_ffn_dim
+    rest += layers * cfg.dim * cfg.n_experts
+    return (rest + layers * (hi - lo) * one,
+            rest + layers * cfg.top_k * (hi - lo) * one // cfg.n_experts)

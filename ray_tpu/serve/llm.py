@@ -56,7 +56,8 @@ from ray_tpu import tracing
 from ray_tpu.exceptions import AdapterLoadError
 from ray_tpu.serve import slo
 from ray_tpu.serve.kv_blocks import BlockManager
-from ray_tpu.serve.prefill_plan import plan_wave
+from ray_tpu.serve.prefill_plan import (FLOOR_TOKENS, floor_positions,
+                                        plan_wave, programs_under)
 
 
 def _buckets_for(max_len: int, smallest: int = 32) -> list[int]:
@@ -298,6 +299,15 @@ def _engine_metrics():
                     um.Counter, "serve_llm_prefill_programs",
                     "Prefill programs dispatched (one or more a wave: "
                     "serve/prefill_plan.py)", tk),
+                "prefill_programs_at_floor": um.get_or_create(
+                    um.Counter, "serve_llm_prefill_programs_at_floor",
+                    "Prefill programs charged the planner's floor: a "
+                    "pass over the weights and no more", tk),
+                "prefill_floor_positions": um.get_or_create(
+                    um.Gauge, "serve_llm_prefill_floor_positions",
+                    "Token positions the planner charges a prefill "
+                    "program at least (serve/prefill_plan.FLOOR_TOKENS "
+                    "x parameters streamed / multiplied a position)", tk),
                 "lane_steps_live": um.get_or_create(
                     um.Counter, "serve_llm_lane_steps_live",
                     "Decode steps x lanes holding a request", tk),
@@ -576,7 +586,11 @@ class LLMEngine:
         # the engine then counts both kernels' work), and the bytes of
         # state one prefill row hands the scatter
         # (`prefill_state_bytes`: the planner bounds a program's width
-        # by it).
+        # by it).  And, where a prefill program reads weights that a
+        # position does not multiply (a routed layer's experts), the
+        # matmul parameters a program streams whatever it holds and
+        # those ONE position multiplies (`prefill_params`: the planner's
+        # floor and the programs built follow their ratio).
         self._lane_layers = int(model.lane_state_layers(cfg))
         self._moe_layers = int(model.routed_layers(cfg))
         self._scan_chunk = int(getattr(
@@ -643,8 +657,23 @@ class LLMEngine:
         # first chunk's tokens reach the host while later chunks are
         # still computing (the fetches overlap via copy_to_host_async).
         self._chunk = min(16, max_batch)
-        self._width_buckets = sorted({w for w in (1, 8, self._chunk)
-                                      if w <= max_batch})
+        wide = {1, 8, self._chunk}
+        # What the planner charges a program at least, and the programs
+        # it plans over (serve/prefill_plan.py).  A model whose every
+        # weight multiplies every position: FLOOR_TOKENS and widths
+        # {1, 8, chunk} x every bucket.  One that says its programs
+        # stream more than a position multiplies: a floor that many
+        # times further out, under which two rows cost what one does, so
+        # widths 2 and 4 beside them and only the programs that floor
+        # leaves distinct.
+        streams = getattr(model, "prefill_params", None)
+        self._prefill_floor = (FLOOR_TOKENS if streams is None
+                               else floor_positions(*streams(cfg)))
+        narrow = frozenset() if streams is None else frozenset({2, 4}) - wide
+        self._width_buckets = sorted(w for w in wide | narrow
+                                     if w <= max_batch)
+        self._prefill_programs = None if streams is None else programs_under(
+            self._prefill_floor, self._width_buckets, self._buckets, narrow)
         # Per-request sampling base key (see _Request.sample_seed).
         self._base_key = jax.random.PRNGKey(seed + 1)
 
@@ -990,6 +1019,7 @@ class LLMEngine:
         self.phase_cpu_s = dict.fromkeys(_LOOP_PHASES, 0.0)
         self.prefill_padded_tokens = 0  # width bucket x length bucket
         self.prefill_programs = 0      # (width, length) programs dispatched
+        self.prefill_programs_at_floor = 0
         self.prefill_waves = 0
         self.prefill_waves_split = 0   # plans of more programs than chunks
         # waves a ceiling of the planner split (PREFILL_MAX_TOKENS
@@ -2227,7 +2257,9 @@ class LLMEngine:
             scan0 = self.prefill_scan_chunks
             plan, capped = plan_wave(lengths, self._width_buckets,
                                      self._buckets, self._chunk,
-                                     self._row_state_bytes)
+                                     self._row_state_bytes,
+                                     self._prefill_floor,
+                                     self._prefill_programs)
             for rows, w, b in plan:
                 chunk = [wave[i] for i in rows]
                 t_disp = time.time()
@@ -2239,6 +2271,9 @@ class LLMEngine:
                 pending_waves.append((chunk, nxt, t_disp))
             self.prefill_waves += 1
             self.prefill_programs += len(plan)
+            # charged the floor: a pass over the weights and no more
+            self.prefill_programs_at_floor += sum(
+                w * b <= self._prefill_floor for _, w, b in plan)
             # more programs than arrival-order chunks of _chunk rows
             self.prefill_waves_split += \
                 len(plan) > -(-len(wave) // self._chunk)
@@ -2943,6 +2978,7 @@ class LLMEngine:
         cur = {"prefill_tokens": self.prefill_tokens,
                "prefill_padded_tokens": self.prefill_padded_tokens,
                "prefill_programs": self.prefill_programs,
+               "prefill_programs_at_floor": self.prefill_programs_at_floor,
                "decode_tokens": self.decode_tokens,
                "decode_steps": self.decode_steps,
                "lane_steps_live": self.lane_steps_live,
@@ -2992,6 +3028,7 @@ class LLMEngine:
             self._waiting.qsize() + len(self._pending), tags)
         m["weight_version"].set(float(self.weight_version), tags)
         m["free_blocks"].set(self._mgr.free_count(), tags)
+        m["prefill_floor_positions"].set(self._prefill_floor, tags)
         seen = self._mgr.hit_tokens + self.prefill_tokens
         m["hit_rate"].set(
             self._mgr.hit_tokens / seen if seen else 0.0, tags)
@@ -3092,6 +3129,9 @@ class LLMEngine:
                    "prefill_true_tokens": self.prefill_tokens,
                    "prefill_padded_tokens": self.prefill_padded_tokens,
                    "prefill_programs": self.prefill_programs,
+                   "prefill_programs_at_floor":
+                   self.prefill_programs_at_floor,
+                   "prefill_floor_positions": self._prefill_floor,
                    "prefill_waves": self.prefill_waves,
                    "prefill_waves_split": self.prefill_waves_split,
                    "prefill_programs_capped":

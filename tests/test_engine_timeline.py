@@ -309,6 +309,66 @@ def test_no_compile_after_a_warmup_of_equal_rows(small):
     assert plans <= {f"{w}x{b}" for w in eng._width_buckets for b in buckets}
 
 
+def test_a_routed_engine_plans_over_its_own_programs_and_builds_none_after():
+    """A model that says its programs stream more than a position
+    multiplies (`prefill_params`): the floor and the programs follow the
+    ratio, `bench_warmup`'s walk over `_width_buckets` x buckets runs
+    every built program the range reaches, and mixed waves after it
+    build nothing; `prefill_programs_at_floor` and
+    `prefill_floor_positions` by hand."""
+    from ray_tpu.models import lfm2, named_config
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg = named_config("lfm2-debug")
+    # 2 routed layers x 8 experts x 3 x 128 x 128 = 786,432 of the
+    # 1,067,008 matmul parameters of the layers; a position multiplies
+    # 2 of 8: 477,184.  256 x 1,067,008 // 477,184 = 572 positions.
+    assert lfm2.prefill_params(cfg) == (1_067_008, 477_184)
+    eng = LLMEngine(cfg, seed=0, max_batch=8, max_len=128, page_size=16,
+                    steps_per_sync=4)
+    assert eng._width_buckets == [1, 2, 4, 8]
+    built = {"1x128", "2x128", "4x64", "4x128", "8x32", "8x64", "8x128"}
+    assert {f"{w}x{b}" for w, b in eng._prefill_programs} == built
+    lo, hi = 33, 120        # a prompt is shorter than max_len
+    eng.start()
+    try:
+        for b in (64, 128):
+            for w in eng._width_buckets:
+                _one_wave(eng, [[1 + (i + j) % 97 for j in range(min(b, hi))]
+                                for i in range(w)], 1)
+        warm = {p for s in _dispatch_spans(eng)
+                for p in s["attrs"]["plan"].split(",")}
+        assert warm == built - {"8x32"}     # no prompt maps to bucket 32
+        programs = (eng._prefill_fwd, eng._scatter_pages)
+        sizes = [f._cache_size() for f in programs]
+        assert sizes[0] == len(warm)
+        s0 = eng.stats()["loop"]
+        assert s0["prefill_floor_positions"] == 572
+        assert s0["prefill_programs"] == 8      # one a (width, bucket) pair
+        assert s0["prefill_programs_at_floor"] == 7     # 8 x 128 is past it
+        _one_wave(eng, [_prompt(40, 1), _prompt(100, 2)], 1)    # 2 x 128
+        _one_wave(eng, [_prompt(100, i) for i in range(8)], 1)  # 8 x 128
+        _one_wave(eng, [_prompt(n, n) for n in (40, 50, 60)], 1)  # 4 x 64
+        s1 = eng.stats()["loop"]
+        rng = random.Random(40)
+        for n in (1, 2, 3, 4, 5, 8, 8, 6, 2, 7):
+            _one_wave(eng, [_prompt(rng.randint(lo, hi), rng.randint(0, 90))
+                            for _ in range(n)], 1)
+        s2 = eng.stats()["loop"]
+    finally:
+        eng.stop()
+    assert [s["attrs"]["plan"] for s in _dispatch_spans(eng)[8:11]] \
+        == ["2x128", "8x128", "4x64"]
+    assert (s1["prefill_programs"] - s0["prefill_programs"],
+            s1["prefill_programs_at_floor"]
+            - s0["prefill_programs_at_floor"]) == (3, 2)
+    assert [f._cache_size() for f in programs] == sizes
+    assert s2["program_builds"] == s0["program_builds"]
+    assert s2["prefill_waves"] - s1["prefill_waves"] == 10
+    assert {p for s in _dispatch_spans(eng)
+            for p in s["attrs"]["plan"].split(",")} == warm
+
+
 def test_lane_steps_live_by_hand(small):
     eng = _engine(small)                # K = 4, 4 lanes
     eng.start()

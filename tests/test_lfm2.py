@@ -413,3 +413,47 @@ def test_the_server_serves_a_preset_by_name():
         assert srv._prefix_client is None       # no demotion either
     finally:
         srv.engine.stop()
+
+
+# ------------------- (8) what a prefill program streams and multiplies
+def test_prefill_params_equal_a_count_over_the_tree(params):
+    """`prefill_params`: every matmul leaf of the layers is streamed (no
+    embedding: a lookup, and the tied head keeps one position a row);
+    a position multiplies them all but the experts, of which its
+    `top_k` of `n_experts`.  The engine's floor follows the ratio; a
+    module without the function reads ratio 1."""
+    from ray_tpu.models import llama, ssm_hybrid
+    from ray_tpu.serve.prefill_plan import FLOOR_TOKENS, programs_under
+
+    experts = sum(lp[k].size for lp in params["layers"] if "w13" in lp
+                  for k in ("w13", "w2"))
+    matmul = sum(a.size for lp in params["layers"]
+                 for k, a in lp.items() if a.ndim >= 2 and k != "conv_w")
+    assert experts == lfm2.routed_layers(CFG) * CFG.n_experts \
+        * 3 * CFG.dim * CFG.moe_ffn_dim
+    streamed, multiplied = lfm2.prefill_params(CFG)
+    assert streamed == matmul
+    assert multiplied == matmul - experts + experts * CFG.top_k \
+        // CFG.n_experts
+    eng = LLMEngine(CFG, params, max_batch=16, max_len=128, page_size=PAGE)
+    floor = FLOOR_TOKENS * streamed // multiplied
+    assert FLOOR_TOKENS < floor == eng._prefill_floor \
+        == eng.stats()["loop"]["prefill_floor_positions"]
+    assert eng._width_buckets == [1, 2, 4, 8, 16]
+    assert eng._prefill_programs == programs_under(
+        floor, [1, 2, 4, 8, 16], eng._buckets, frozenset({2, 4}))
+    assert all(w * b <= floor for w, b in eng._prefill_programs
+               if w in (2, 4))
+    # four lanes: width 4 is the chunk, and holds every bucket
+    small = LLMEngine(CFG, params, max_batch=4, max_len=128, page_size=PAGE)
+    assert small._width_buckets == [1, 2, 4]
+    assert {b for w, b in small._prefill_programs if w == 4} \
+        >= {small._buckets[-1]}
+    for mod, name in ((llama, "debug"), (ssm_hybrid, "ssm-hybrid-debug")):
+        assert not hasattr(mod, "prefill_params")
+        dense = LLMEngine(mod.serving_configs()[name], max_batch=16,
+                          max_len=64, page_size=PAGE)
+        assert dense._prefill_floor == FLOOR_TOKENS
+        assert dense._width_buckets == [1, 8, 16]
+        assert dense._prefill_programs is None
+        assert dense.stats()["loop"]["prefill_floor_positions"] == 256

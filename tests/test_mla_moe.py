@@ -172,7 +172,9 @@ def test_the_share_of_the_visit_list_that_is_work(held):
     visits the grouped matmul walked over the length its lists were
     padded to.  A prompt of 40 in a bucket of 64 routes 160 selections
     of the list's 256 rows; a chip that holds a quarter of the experts
-    computes about 40 of them, so most of its list pads it."""
+    computes about 40 of them, so most of its list pads it.  (Under this
+    model's floor the one-row program of the longest bucket holds the
+    row where the 64-bucket's is not built: serve/prefill_plan.py.)"""
     cfg = dataclasses.replace(CFG, experts_held=held)
     eng = LLMEngine(cfg, mla_moe.init_params(jax.random.PRNGKey(7), cfg),
                     max_batch=2, max_len=96, page_size=PAGE,
@@ -185,8 +187,10 @@ def test_the_share_of_the_visit_list_that_is_work(held):
         eng.stop()
     G, layers = held[1] - held[0], mla_moe.routed_layers(cfg)
     assert loop["prefill_moe_layer_steps"] == layers
+    bucket = min(b for w, b in eng._prefill_programs if w == 1 and b >= 64)
+    assert loop["prefill_padded_tokens"] == bucket
     assert loop["prefill_moe_visits_static"] == layers * (
-        64 * cfg.top_k // 16 + G - 1)
+        bucket * cfg.top_k // 16 + G - 1)
     assert loop["moe_visits_static"] == loop["moe_layer_steps"] * (1 + G - 1)
     for p in ("", "prefill_"):
         assert 0 < loop[p + "moe_visits"] <= loop[p + "moe_visits_static"]
@@ -396,3 +400,35 @@ def test_the_server_serves_a_preset_by_name():
         assert srv.stats()["cache"]["kind"] == "latent"
     finally:
         srv.engine.stop()
+
+
+def test_prefill_params_equal_a_count_over_the_tree(params):
+    """`prefill_params`: every matmul leaf of the layers is streamed, the
+    experts HELD and the shared one among them (no embedding, no head:
+    one position a row); a position multiplies them all but the held
+    experts, of which the share of its `top_k` that this chip holds."""
+    from ray_tpu.serve.prefill_plan import FLOOR_TOKENS
+
+    held = CFG.experts_held[1] - CFG.experts_held[0]
+    experts = sum(lp[k].size for lp in params["layers"] if "w13" in lp
+                  for k in ("w13", "w2"))
+    shared = sum(lp[k].size for lp in params["layers"] if "sw1" in lp
+                 for k in ("sw1", "sw2", "sw3"))
+    matmul = sum(a.size for lp in params["layers"] for a in lp.values()
+                 if a.ndim >= 2)
+    assert experts == mla_moe.routed_layers(CFG) * held \
+        * 3 * CFG.dim * CFG.moe_ffn_dim
+    assert shared == mla_moe.routed_layers(CFG) * 3 * CFG.dim \
+        * CFG.moe_ffn_dim * CFG.n_shared_experts
+    streamed, multiplied = mla_moe.prefill_params(CFG)
+    assert streamed == matmul
+    assert multiplied == matmul - experts + experts * CFG.top_k \
+        // CFG.n_experts
+    eng = LLMEngine(CFG, params, max_batch=16, max_len=128, page_size=PAGE)
+    assert FLOOR_TOKENS < eng._prefill_floor \
+        == FLOOR_TOKENS * streamed // multiplied \
+        == eng.stats()["loop"]["prefill_floor_positions"]
+    assert eng._width_buckets == [1, 2, 4, 8, 16]
+    # widths 2 and 4 only where they are free: never past the floor
+    assert all(w * b <= eng._prefill_floor
+               for w, b in eng._prefill_programs if w in (2, 4))
