@@ -14,10 +14,15 @@ from ray_tpu.models.vit import ViTConfig, vit_configs
 # ONE signature each:
 #   init_params(key, cfg); init_paged_cache(cfg, batch, n_pages, page) ->
 #     {"pos", "state", and the page pool under names of the model's own}:
-#     each pool entry a list of [n_pages, heads, page, width] leaves, one
+#     each pool entry a list of [n_pages, heads, rows, width] leaves, one
 #     a layer that keeps rows ({"k", "v"}: a K and a V pool; {"latent"}:
-#     one row a token that every head shares), from which the engine
-#     takes its tails' and merges' shapes; and `state`, a pytree of
+#     one row a token that every head shares; {"latent", "index"}: that
+#     beside a pooled index key a GROUP of positions), from which the
+#     engine takes its tails' and merges' shapes: `rows` is the page
+#     size where a leaf holds a row a token, and page size / g where a
+#     row covers g positions (its tail then holds the rows a window's
+#     positions COMPLETE, and the model's step writes a row when its
+#     token completes one); and `state`, a pytree of
 #     whatever a lane carries that no page holds (an empty list if
 #     nothing; a few rows a lane; or gigabytes: a state-space layer's
 #     matrices, every lane's in one array), which the engine never looks
@@ -42,7 +47,11 @@ from ray_tpu.models.vit import ViTConfig, vit_configs
 #     chunk (the engine's `ssm_lane_steps` and `prefill_scan_chunks`
 #     counters) and prefill_state_bytes(cfg) (the state ONE prefill row
 #     hands the scatter: the wave planner bounds a program's width by
-#     it); prefill_params(cfg) (a model whose prefill program reads
+#     it); selection(cfg) (a model whose attention reads its pool through
+#     a learned selection: (layers that do, positions a pooled index key,
+#     the selection's size in tokens), from which the engine counts
+#     `dsa_rows_context`, `dsa_groups_scored`, `dsa_rows_selected`);
+#     prefill_params(cfg) (a model whose prefill program reads
 #     weights a position does not multiply, a routed layer's experts:
 #     the matmul parameters a program STREAMS whatever it holds and
 #     those ONE position multiplies; the planner's floor and the
@@ -61,7 +70,8 @@ from ray_tpu.models.vit import ViTConfig, vit_configs
 _SERVING = {"LlamaConfig": "ray_tpu.models.llama",
             "Lfm2MoeConfig": "ray_tpu.models.lfm2",
             "MlaMoeConfig": "ray_tpu.models.mla_moe",
-            "SsmHybridConfig": "ray_tpu.models.ssm_hybrid"}
+            "SsmHybridConfig": "ray_tpu.models.ssm_hybrid",
+            "Glm5NextConfig": "ray_tpu.models.glm5_next"}
 
 
 def serving_model(cfg):

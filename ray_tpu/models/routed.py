@@ -5,7 +5,9 @@ range of experts a chip holds, and the shared expert beside them.
 
 `cfg` is the serving module's config; it gives `n_experts` (the ROUTER's
 width, every expert of the deployment), `top_k`, `moe_ffn_dim`,
-`use_expert_bias`, `norm_topk_prob` and `routed_scaling`.  The router
+`use_expert_bias`, `norm_topk_prob` and `routed_scaling`, and may give
+`swiglu_limit` (a clamp on every expert's SwiGLU, `clamp`; absent or 0:
+none).  The router
 scores every expert and selects and normalises over all of them whatever
 range is held: a chip that holds experts lo..hi computes THEIR part of
 the sum, and the parts of disjoint ranges add up to the layer.
@@ -82,8 +84,9 @@ def routed_ffn(h2, lp, cfg, live=None,
         sizes = n_all[:G]
         rows = h2[order // k]                     # [T*k, d] by group
         h13 = gmm(rows, lp["w13"], sizes)
-        act = (jax.nn.silu(h13[:, :f].astype(jnp.float32))
-               .astype(h2.dtype) * h13[:, f:])
+        gate, up = clamp(h13[:, :f], h13[:, f:],
+                         getattr(cfg, "swiglu_limit", 0.0))
+        act = jax.nn.silu(gate.astype(jnp.float32)).astype(h2.dtype) * up
         y = gmm(act, lp["w2"], sizes)             # rows of nobody: 0
         y = y[place].reshape(T, k, d).astype(jnp.float32)
         out = jnp.sum(y * wts[..., None], axis=1).astype(h2.dtype)
@@ -102,18 +105,29 @@ def routed_visits(cfg, rows: int,
     return visits_static(rows * cfg.top_k, hi - lo)
 
 
-def swiglu(h, w1, w3, w2, dtype):
+def clamp(gate, up, limit: float):
+    """A clamped SwiGLU's two inputs (`swiglu_limit`; the form is an
+    assumption the configurations that use it list: the gate bounded
+    above, the other input on both sides): (min(gate, limit), clip(up,
+    -limit, limit)); as they came where `limit` is 0 or absent."""
+    if not limit:
+        return gate, up
+    return jnp.minimum(gate, limit), jnp.clip(up, -limit, limit)
+
+
+def swiglu(h, w1, w3, w2, dtype, limit: float = 0.0):
     """W_2(silu(W_1 h) * W_3 h), the gate's activation in float32."""
-    g = jax.nn.silu((h @ w1).astype(jnp.float32))
-    return (g.astype(dtype) * (h @ w3)) @ w2
+    gate, up = clamp(h @ w1, h @ w3, limit)
+    g = jax.nn.silu(gate.astype(jnp.float32))
+    return (g.astype(dtype) * up) @ w2
 
 
-def shared_ffn(h2, lp, dtype):
+def shared_ffn(h2, lp, dtype, limit: float = 0.0):
     """The shared expert: a SwiGLU every row passes through, whatever
     the router chose (`sw1`, `sw3` [d, f], `sw2` [f, d]); every chip of
     an expert-parallel layer computes it alike for its own rows."""
     with jax.named_scope("shared_expert"):
-        return swiglu(h2, lp["sw1"], lp["sw3"], lp["sw2"], dtype)
+        return swiglu(h2, lp["sw1"], lp["sw3"], lp["sw2"], dtype, limit)
 
 
 def stack_counts(per_layer: list) -> jnp.ndarray:

@@ -373,14 +373,20 @@ def mla_decode_reference(q, row_pages, row_tail, page_table, pos,
     return jnp.where(live[:, None, None], o, 0.0).astype(q.dtype)
 
 
-def merge_tail_pages(pages, tail, page_table, tail_start, n_rows):
+def merge_tail_pages(pages, tail, page_table, tail_start, n_rows,
+                     per: int = 1):
     """Scatter a finished block's tail rows into the page pool.
 
-    pages [n_pages, kvh, page, hd]; tail [B, kvh, kt, hd]; row j of
-    slot b lands at absolute position tail_start[b] + j for j < n_rows.
-    Positions past a slot's allocation resolve to the trash page via
-    the zeroed table columns.  Call ONCE per decode block with `pages`
-    donated: the rows are written in place.
+    pages [n_pages, kvh, page_rows, hd]; tail [B, kvh, kt, hd].  `per`
+    positions share a row of this leaf (1: a row a token, the K, V and
+    latent pools; an index pool keeps one pooled key a group of `per`
+    positions, and a page of it `page_rows` = page / per rows): row j of
+    slot b is row tail_start[b] // per + j of the lane, for the rows
+    that the `n_rows` positions from tail_start[b] on COMPLETED
+    ((tail_start + n_rows) // per - tail_start // per of them; with per
+    = 1, j < n_rows).  Positions past a slot's allocation resolve to the
+    trash page via the zeroed table columns.  Call ONCE per decode block
+    with `pages` donated: the rows are written in place.
 
     The head is an index too, so each update is one contiguous row of
     the pool as stored.  Indexed by (page, row) alone the update is a
@@ -394,13 +400,15 @@ def merge_tail_pages(pages, tail, page_table, tail_start, n_rows):
     page = pages.shape[2]
     maxp = page_table.shape[1]
     j = jnp.arange(kt)[None, :]                       # [1, kt]
-    apos = jnp.minimum(tail_start[:, None] + j, maxp * page - 1)
+    first = tail_start[:, None] // per
+    apos = jnp.minimum(first + j, maxp * page - 1)
     cols = apos // page                                # [B, kt]
     rows = apos % page
     pids = jnp.take_along_axis(page_table, cols, axis=1)   # [B, kt]
     # Rows beyond the block's actual length go to the trash page so a
     # short block can't clobber live data with stale tail columns.
-    pids = jnp.where(j < n_rows, pids, 0)
+    done = (tail_start[:, None] + n_rows) // per - first
+    pids = jnp.where(j < done, pids, 0)
     value = tail.transpose(0, 2, 1, 3)                 # [B, kt, kvh, hd]
     if hd % 128:
         return pages.at[pids, :, rows].set(value)

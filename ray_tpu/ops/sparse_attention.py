@@ -1,0 +1,378 @@
+"""Learned sparse attention over a latent pool (DeepSeek-V3.2's indexer
+with pooled index keys), for serving: the indexer's scores, the top-k
+selection, and the decode kernel that attends the latent rows a
+SELECTION names.
+
+The mechanism.  Beside a token's cached latent row the layer keeps an
+INDEX KEY, pooled `group` positions to a row: the index pool holds ONE
+key a COMPLETE group (`pool_index_keys`: the mean of the group's keys).
+A query scores every complete group below it,
+
+    I_{t,g} = sum_j w_{t,j} relu(q^I_{t,j} . kbar_g)        (`index_scores`)
+
+keeps the `top` best (`select_groups`) and attends the positions of
+those groups and of its own incomplete group (`selected_mask`: the one
+reading of which rows a selection means, for whole rows of queries;
+`select_rows`: the same for one decode step, as rows gathered from the
+pool).  Below `top` complete groups the selection keeps everything and
+the layer is dense latent attention.
+
+A decode step (`decode_select` + `dsa_decode_attention`): the index
+keys of the lane's pages are gathered through its table (256 B a group:
+37 MB a step at 64 lanes x 9,216 positions), scored, the top groups'
+latent rows are gathered `group` rows at a time (contiguous in a page)
+and the kernel `dsa_attn` attends them for every head at once, one grid
+step a LIVE lane: `attention_plan`'s work list names pages, a selection
+names rows, so this kernel walks lanes and reads rows that were
+gathered for it.  Rows of the running decode block are not in the pool
+yet (`ops/paged_attention`): they ride behind the gathered rows, each
+admitted if the selection holds its group.
+
+Device-side names: `dsa_index`, `dsa_select`, `dsa_attn` (the kernel's
+`pallas_call` name too).
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops import flash_attention
+
+F32 = jnp.float32
+NEG_INF = -1e30
+LANE = 128
+
+
+def _interpret() -> bool:
+    # the flash kernel's rule, asked where it lives (as ops/ssm.py does)
+    return flash_attention._interpret()
+
+
+def pool_index_keys(keys, group: int):
+    """keys [..., T, w] -> [..., T // group, w]: ONE key a complete group
+    of `group` positions, their mean (float32; the config's
+    `index_kpool_compress`: only this pooled key is kept)."""
+    *lead, T, w = keys.shape
+    n = T // group
+    return jnp.mean(keys[..., :n * group, :].astype(F32).reshape(
+        *lead, n, group, w), axis=-2)
+
+
+def index_scores(q, w, kbar):
+    """q [..., t, J, w] index queries, w [..., t, J] their weights, kbar
+    [..., G, w] pooled keys -> [..., t, G] float32: sum over the J index
+    heads of w_j relu(q_j . kbar_g)."""
+    s = jnp.einsum("...tjw,...gw->...tjg", q, kbar.astype(q.dtype),
+                   preferred_element_type=F32)
+    return jnp.sum(jax.nn.relu(s) * w.astype(F32)[..., None], axis=-2)
+
+
+def select_groups(scores, n_complete, top: int):
+    """The `top` best of the first `n_complete` groups of each row (of
+    equal scores the lower group first, `lax.top_k`'s order).  scores
+    [..., G] float32, n_complete [...] int32.  Returns (idx [..., top]
+    int32, ok [..., top]: False where fewer than `top` groups are
+    complete, (kth [...], last [...]): the smallest selected score and
+    the highest group selected at that score, from which `selected_mask`
+    rebuilds the same set without a scatter)."""
+    G = scores.shape[-1]
+    valid = jnp.arange(G) < n_complete[..., None]
+    masked = jnp.where(valid, scores, NEG_INF)
+    if G < top:
+        masked = jnp.pad(masked, [(0, 0)] * (masked.ndim - 1)
+                         + [(0, top - G)], constant_values=NEG_INF)
+    val, idx = lax.top_k(masked, top)
+    ok = val > 0.5 * NEG_INF
+    kth = jnp.min(jnp.where(ok, val, -NEG_INF), axis=-1)
+    last = jnp.max(jnp.where(ok & (val == kth[..., None]), idx, -1), axis=-1)
+    return idx.astype(jnp.int32), ok, (kth, last)
+
+
+def selected_mask(scores, pos, n_keys: int, group: int, top: int):
+    """Which of `n_keys` key positions each query attends.  scores
+    [..., t, G] (`index_scores`), pos [t] the queries' positions.
+    Returns (mask [..., t, n_keys] bool: s <= t's position, and s in one
+    of the `top // group` best complete groups (group g + group - 1 <=
+    position) or in the query's own incomplete group (the config's
+    `index_kpool_always_select_tail`); chosen [..., t, G] bool: the groups
+    the scores chose)."""
+    n_complete = (pos + 1) // group
+    _, _, (kth, last) = select_groups(scores, jnp.broadcast_to(
+        n_complete, scores.shape[:-1]), top // group)
+    g = jnp.arange(scores.shape[-1])
+    chosen = ((scores > kth[..., None])
+              | ((scores == kth[..., None]) & (g <= last[..., None]))) \
+        & (g < n_complete[:, None])
+    keys = jnp.arange(n_keys)
+    by_group = jnp.repeat(chosen, group, axis=-1)[..., :n_keys]
+    if by_group.shape[-1] < n_keys:
+        by_group = jnp.pad(by_group, [(0, 0)] * (by_group.ndim - 1)
+                           + [(0, n_keys - by_group.shape[-1])])
+    tail = keys[None, :] >= (n_complete * group)[:, None]
+    return (by_group | tail) & (keys[None, :] <= pos[:, None]), chosen
+
+
+def decode_select(q, w, idx_pages, idx_tail, page_table, pos, tail_start,
+                  group: int, top: int):
+    """One decode step's selection for every lane.
+
+    q [B, J, w], w [B, J]; idx_pages [n_pages, 1, page // group, w] the
+    index pool (groups complete below `tail_start`); idx_tail [B, 1, R,
+    w] the groups the running block completed (row r = group
+    tail_start // group + r).  Returns (groups [B, top // group] int32
+    absolute group numbers, ok [B, top // group])."""
+    B, maxp = page_table.shape
+    with jax.named_scope("dsa_index"):
+        kbar = idx_pages[page_table][:, :, 0]         # [B, maxp, rows, w]
+        kbar = kbar.reshape(B, -1, kbar.shape[-1])
+        G = kbar.shape[1]
+        kbar = jnp.concatenate([kbar, idx_tail[:, 0]], axis=1)
+        s = index_scores(q[:, None], w[:, None], kbar)[:, 0]   # [B, G + R]
+        g0 = tail_start // group
+        r = jnp.arange(idx_tail.shape[2])
+        done = (g0[:, None] + r[None, :] + 1) * group - 1 <= pos[:, None]
+        valid = jnp.concatenate(
+            [jnp.arange(G)[None, :] < g0[:, None], done], axis=1)
+        s = jnp.where(valid, s, NEG_INF)
+    with jax.named_scope("dsa_select"):
+        idx, ok, _ = select_groups(
+            s, jnp.full((B,), s.shape[1], jnp.int32), top // group)
+        groups = jnp.where(idx < G, idx, g0[:, None] + idx - G)
+    return groups, ok
+
+
+def select_rows(latent_pages, latent_tail, page_table, pos, tail_start,
+                groups, ok, group: int):
+    """The rows a selection names, gathered for `dsa_decode_attention`.
+
+    latent_pages [n_pages, 1, page, w] (rows below `tail_start`),
+    latent_tail [B, 1, K, w] (the running block's rows), groups, ok
+    [B, n] (`decode_select`).  Returns (rows [B, S, w] gathered from the
+    pool, bias [B, S], tail_bias [B, K]: float32, 0 where the row is
+    attended and -1e30 elsewhere; rpos [B, S + K], admit [B, S + K]: every
+    row's position and whether it is attended, the tail's last), S = (n +
+    1) * group rounded up to whole lane tiles (groups nobody chose fill
+    it).  A selected group's rows at or past `tail_start` are not in the
+    pool: they are admitted in the tail, as are the rows of the query's
+    own incomplete group."""
+    n_pages, _, page, w = latent_pages.shape
+    B = groups.shape[0]
+    K = latent_tail.shape[2]
+    with jax.named_scope("dsa_select"):
+        # the query's own incomplete group rides behind the chosen ones:
+        # its rows below `tail_start` are in the pool like any other's
+        n = -(-(groups.shape[1] + 1) * group // LANE) * LANE // group
+        fill = n - groups.shape[1] - 1
+        groups = jnp.concatenate(
+            [groups, ((pos + 1) // group)[:, None],
+             jnp.zeros((B, fill), groups.dtype)], axis=1)
+        ok = jnp.concatenate([ok, jnp.ones((B, 1), bool),
+                              jnp.zeros((B, fill), bool)], axis=1)
+        # a row at a time: the pool seen as [pages x rows, w] is the
+        # same bytes, while `group` rows side by side are another layout
+        # of a tiled array (the whole pool copied, 604 MB a window); the
+        # page is looked up a GROUP at a time (a group lies in one page)
+        first = groups * group                             # [B, n]
+        col = jnp.minimum(first // page, page_table.shape[1] - 1)
+        at = jnp.take_along_axis(page_table, col, axis=1) * page \
+            + first % page
+        step = jnp.arange(group)[None, None, :]
+        rows = latent_pages.reshape(n_pages * page, w)[
+            (at[:, :, None] + step).reshape(B, n * group)]
+        rpos = (first[:, :, None] + step).reshape(B, n * group)
+        admit = (jnp.repeat(ok, group, axis=1)
+                 & (rpos < tail_start[:, None]))
+        tpos = tail_start[:, None] + jnp.arange(K)[None, :]      # [B, K]
+        own = tpos >= ((pos + 1) // group * group)[:, None]
+        held = jnp.any((tpos // group)[:, :, None] == jnp.where(
+            ok, groups, -1)[:, None, :], axis=-1)
+        t_admit = (tpos <= pos[:, None]) & (own | held)
+
+        def bias(a):
+            return jnp.where(a, 0.0, NEG_INF).astype(F32)
+
+        return (rows, bias(admit), bias(t_admit),
+                jnp.concatenate([rpos, tpos], axis=1),
+                jnp.concatenate([admit, t_admit], axis=1))
+
+
+def _dsa_kernel(lanes_ref, q_ref, rows_ref, bias_ref, tail_ref, tbias_ref,
+                o_ref, *, dv: int, sm_scale: float):
+    """One lane: q_ref [H, dk]; rows_ref [S, dk] gathered from the pool
+    and tail_ref [K, dk] the running block's (key AND value: the first dv
+    columns); bias_ref [1, S], tbias_ref [1, K]; o_ref [H, dv]."""
+    del lanes_ref
+
+    def scores(rows, bias):
+        return lax.dot_general(
+            q_ref[...], rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=F32) * sm_scale + bias
+
+    def weigh(p, rows):
+        return lax.dot_general(p.astype(rows.dtype), rows[:, :dv],
+                               (((1,), (0,)), ((), ())),
+                               preferred_element_type=F32)
+
+    rows, tail = rows_ref[...], tail_ref[...]
+    s, st = scores(rows, bias_ref[...]), scores(tail, tbias_ref[...])
+    m = jnp.maximum(jnp.max(s, axis=1, keepdims=True),
+                    jnp.max(st, axis=1, keepdims=True))
+    # (a lane with no row admitted: every weight 0, not exp(0))
+    p = jnp.where(s > 0.5 * NEG_INF, jnp.exp(s - m), 0.0)
+    pt = jnp.where(st > 0.5 * NEG_INF, jnp.exp(st - m), 0.0)
+    l = jnp.sum(p, axis=1, keepdims=True) + jnp.sum(pt, axis=1,
+                                                    keepdims=True)
+    o = weigh(p, rows) + weigh(pt, tail)
+    o_ref[...] = (o / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+def dsa_decode_attention(q, rows, bias, tail, tail_bias, lanes, count, *,
+                         dv: int, sm_scale: float):
+    """Attention of every head over the rows a selection named.
+
+    q [B, H, dk] absorbed queries; rows [B, S, dk], bias [B, S], tail_bias
+    [B, K] (`select_rows`); tail [B, K, dk] the running block's rows;
+    lanes, count: the work list of the live lanes (`ops/ssm.live_lanes`).
+    Returns o [B, H, dv]; a lane outside the list reads 0."""
+    B, H, dk = q.shape
+    S, K = rows.shape[1], tail.shape[1]
+
+    def lane3(i, lanes):
+        return (lanes[i], 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(count,),
+        in_specs=[pl.BlockSpec((None, H, dk), lane3),
+                  pl.BlockSpec((None, S, dk), lane3),
+                  pl.BlockSpec((None, 1, S), lane3),
+                  pl.BlockSpec((None, K, dk), lane3),
+                  pl.BlockSpec((None, 1, K), lane3)],
+        out_specs=pl.BlockSpec((None, H, dv), lane3),
+    )
+    o = pl.pallas_call(
+        functools.partial(_dsa_kernel, dv=dv, sm_scale=sm_scale),
+        name="dsa_attn",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=max(32 << 20, 6 * S * dk * 2)),
+        interpret=_interpret(),
+    )(lanes, q, rows, bias[:, None, :], tail, tail_bias[:, None, :])
+    listed = jnp.any((lanes[None, :] == jnp.arange(B)[:, None])
+                     & (jnp.arange(B)[None, :] < count), axis=1)
+    return jnp.where(listed[:, None, None], o, jnp.zeros_like(o))
+
+
+def selection_counts(context: int, group: int, top: int
+                     ) -> tuple[int, int]:
+    """Host arithmetic for a query with `context` rows below and at it
+    (its position + 1): (complete groups it scores, rows it attends:
+    those of the best `top // group` groups and of its own incomplete
+    group)."""
+    complete = context // group
+    return complete, (min(complete, top // group) * group
+                      + context % group)
+
+
+def attn_cost(H: int, dk: int, dv: int, rows: float
+              ) -> tuple[float, float]:
+    """(flops, bytes) `dsa_attn` NEEDS to attend `rows` selected rows in
+    all (summed over lanes and steps): each row read once at its width
+    (bfloat16) and scored and weighed for every head."""
+    return 2.0 * H * (dk + dv) * rows, 2.0 * dk * rows
+
+
+# ------------------------------------------------- prefill: masked flash
+def _prefill_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, acc_ref, m_ref,
+                    l_ref, *, sm_scale: float):
+    """One (query block i, key block j <= i) pair of one head: the flash
+    accumulation of `ops/flash_attention`, with the pairs a query attends
+    given as a mask block."""
+    i, j = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j <= i)
+    def _pair():
+        keep = mask_ref[...] != 0
+        s = lax.dot_general(q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
+                            preferred_element_type=F32) * sm_scale
+        s = jnp.where(keep, s, NEG_INF)
+        m_prev = m_ref[:, :1]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_cur)
+        p = jnp.where(keep, jnp.exp(s - m_cur), 0.0)
+        l_ref[:, :1] = l_ref[:, :1] * alpha + jnp.sum(p, axis=1,
+                                                      keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())),
+            preferred_element_type=F32)
+        m_ref[:, :1] = m_cur
+
+    @pl.when(j == i)
+    def _done():
+        l = l_ref[:, :1]
+        o_ref[...] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(
+            o_ref.dtype)
+
+
+def prefill_block(T: int) -> int:
+    """Queries (and keys) a block of `masked_prefill_attention` for rows
+    of T positions: the largest of 512, 256, 128 that divides T; 0 where
+    none does (the caller then runs the attention in XLA)."""
+    return next((n for n in (512, 256, 128) if T % n == 0), 0)
+
+
+def masked_prefill_attention(q, k, v, mask, *, sm_scale: float):
+    """softmax(sm_scale q k^T over the pairs `mask` admits) v, causal
+    block pairs only (a pair of blocks above the diagonal is neither
+    copied nor computed).
+
+    q, k [b, T, H, dq]; v [b, T, H, dv]; mask [b, T, T] int8 (1: the
+    query attends the key; nothing above the diagonal is read).  T a
+    multiple of `prefill_block(T)`.  Returns o [b, T, H, dv]; a query that
+    attends nothing reads 0."""
+    b, T, H, dq = q.shape
+    dv = v.shape[-1]
+    n = prefill_block(T)
+    nb = T // n
+
+    def qmap(bi, h, i, j):
+        return (bi, h, i, 0)
+
+    def kmap(bi, h, i, j):
+        return (bi, h, jnp.minimum(j, i), 0)    # above the diagonal: no copy
+
+    def mmap(bi, h, i, j):
+        return (bi, i, jnp.minimum(j, i))
+
+    qh, kh, vh = (jnp.swapaxes(a, 1, 2) for a in (q, k, v))   # [b, H, T, w]
+    o = pl.pallas_call(
+        functools.partial(_prefill_kernel, sm_scale=sm_scale),
+        name="dsa_prefill",
+        grid=(b, H, nb, nb),
+        in_specs=[pl.BlockSpec((None, None, n, dq), qmap),
+                  pl.BlockSpec((None, None, n, dq), kmap),
+                  pl.BlockSpec((None, None, n, dv), kmap),
+                  pl.BlockSpec((None, n, n), mmap)],
+        out_specs=pl.BlockSpec((None, None, n, dv), qmap),
+        out_shape=jax.ShapeDtypeStruct((b, H, T, dv), q.dtype),
+        scratch_shapes=[pltpu.VMEM((n, dv), F32), pltpu.VMEM((n, LANE), F32),
+                        pltpu.VMEM((n, LANE), F32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary")),
+        interpret=_interpret(),
+    )(qh, kh, vh, mask)
+    return jnp.swapaxes(o, 1, 2)

@@ -75,11 +75,20 @@ from ray_tpu.serve.kv_router import env_on as _env_on
 def _pool(cache: dict) -> dict:
     """The page pool of a model's cache (`init_paged_cache`): every
     entry but the lanes' positions and their state, each a list of
-    [n_pages, heads, page, width] leaves, one a layer that keeps rows.
-    The engine takes the leaves' names and shapes from here and from
-    nowhere else."""
+    [n_pages, heads, rows, width] leaves, one a layer that keeps rows.
+    A leaf holds a row a token (`rows` = the page size: K, V, a latent
+    row) or a row a GROUP of positions (`rows` = page size / positions a
+    row: a pooled index key): `_per_row` reads which from the shape, and
+    the leaf's tail and its merge follow.  The engine takes the leaves'
+    names and shapes from here and from nowhere else."""
     return {name: leaves for name, leaves in cache.items()
             if name not in ("pos", "state")}
+
+
+def _per_row(leaf, page: int) -> int:
+    """Positions that share ONE row of a pool leaf whose pages cover
+    `page` positions (1: a row a token)."""
+    return page // leaf.shape[2]
 
 
 def _check_pool_role(role: str, decode_deployment) -> None:
@@ -598,6 +607,16 @@ class LLMEngine:
         self._scan_layers = self._lane_layers if self._scan_chunk else 0
         self._row_state_bytes = int(getattr(
             model, "prefill_state_bytes", lambda _cfg: 0)(cfg))
+        # A model whose attention reads its pool through a learned
+        # selection (`selection`: layers that do, positions a pooled
+        # index key, the selection's size in tokens): the engine counts
+        # the rows in context, the groups scored and the rows attended.
+        self._sel_layers, self._sel_group, self._sel_top = getattr(
+            model, "selection", lambda _cfg: (0, 1, 0))(cfg)
+        # (host arithmetic; imported here, where jax already is: the
+        # module itself loads without it)
+        from ray_tpu.ops.sparse_attention import selection_counts
+        self._selection_counts = selection_counts
         stateful = self._lane_layers > 0
         if lora_slots and "lora" not in caps:
             raise ValueError(
@@ -774,15 +793,17 @@ class LLMEngine:
                 # the page pool, by whatever leaves the model's
                 # init_paged_cache gave it (a K and a V pool a layer,
                 # one latent pool a layer, ...): every leaf is
-                # [n_pages, heads, page, width]; its tail is the same
-                # with K rows a lane
+                # [n_pages, heads, rows, width]; its tail is the same
+                # with the rows K positions can complete a lane (K where
+                # a leaf holds a row a token)
                 pages = _pool(cache)
                 with jax.named_scope("attn_plan"):
                     plan = attention_plan(table, ts, self.page)
                 tails = jax.tree.map(
                     lambda pool: jnp.zeros(
-                        (max_batch, pool.shape[1], K, pool.shape[3]),
-                        pool.dtype), pages)
+                        (max_batch, pool.shape[1],
+                         -(-K // _per_row(pool, self.page)),
+                         pool.shape[3]), pool.dtype), pages)
                 lane_keys = jax.vmap(
                     lambda s: jax.random.fold_in(self._base_key,
                                                  s))(seeds)
@@ -802,8 +823,9 @@ class LLMEngine:
                     step, (tails, cache["state"], ts, tokens, counts0),
                     jnp.arange(K))
                 merged = jax.tree.map(
-                    lambda pool, tail: merge_tail_pages(pool, tail, table,
-                                                        ts, K),
+                    lambda pool, tail: merge_tail_pages(
+                        pool, tail, table, ts, K,
+                        _per_row(pool, self.page)),
                     pages, tails)
                 return seq, last, {**merged, "pos": pos,
                                    "state": state}, counts
@@ -1011,6 +1033,14 @@ class LLMEngine:
         self.ssm_lane_steps = 0
         self.prefill_scan_chunks = 0
         self.prefill_scan_chunks_dense = 0
+        # Learned sparse attention (a model that declares `selection`),
+        # a live lane's every decode step, x those layers: the rows in
+        # its context, the complete groups its indexer scored and the
+        # rows its selection attended (all host arithmetic,
+        # ops/sparse_attention.selection_counts).
+        self.dsa_rows_context = 0
+        self.dsa_groups_scored = 0
+        self.dsa_rows_selected = 0
         # Rows the attention kernel had to attend: a live lane's context
         # at each of a window's K steps (block-start rows + the tail's
         # j + 1), summed over lanes, steps and windows.
@@ -2829,6 +2859,7 @@ class LLMEngine:
                          steps=k_win) as ph:
             starts = np.zeros((self.max_batch,), np.int32)
             attn_steps = attn_rows = 0
+            sel = [0, 0, 0]         # rows in context, groups, rows selected
             for i in active:
                 req = self._slots[i]
                 starts[i] = len(req.tokens)
@@ -2839,7 +2870,18 @@ class LLMEngine:
                            self._maxp * self.page)
                 attn_steps += max(-(-rows // self.page), 1)
                 attn_rows += k_win * rows + k_win * (k_win + 1) // 2
+                for ctx in range(rows + 1, rows + 1 + k_win
+                                 if self._sel_layers else 0):
+                    scored, kept = self._selection_counts(
+                        ctx, self._sel_group, self._sel_top)
+                    sel[0] += ctx
+                    sel[1] += scored
+                    sel[2] += kept
             ph.update(attn_steps=attn_steps)
+            if self._sel_layers:
+                sel = [n * self._sel_layers for n in sel]
+                ph.update(dsa_rows_context=sel[0], dsa_groups_scored=sel[1],
+                          dsa_rows_selected=sel[2])
             if self._scan_layers:
                 ph.update(ssm_lane_steps=len(active) * k_win
                           * self._scan_layers)
@@ -2869,6 +2911,9 @@ class LLMEngine:
             self.attn_steps += attn_steps
             self.attn_steps_dense += self.max_batch * (self._maxp + 1)
             self.attn_ctx_rows += attn_rows
+            self.dsa_rows_context += sel[0]
+            self.dsa_groups_scored += sel[1]
+            self.dsa_rows_selected += sel[2]
         with self._phase("decode_sync", iter=it):
             seq = np.asarray(seq)               # the ONE sync per block
             # the routed layers' counts: a few hundred bytes of the same
@@ -3047,13 +3092,23 @@ class LLMEngine:
         (`CACHE_KIND`), the bytes a token's rows take in one layer as
         stored, the layers that keep any, and the pool's bytes."""
         pool = _pool(self.cache)
+        by_leaf = {
+            name: {"row_bytes": int(v[0].shape[1] * v[0].shape[3]
+                                    * v[0].dtype.itemsize),
+                   "positions_per_row": _per_row(v[0], self.page),
+                   "layers": len(v),
+                   "pool_bytes": int(sum(a.size * a.dtype.itemsize
+                                         for a in v))}
+            for name, v in pool.items()}
         return {"kind": self._model.CACHE_KIND,
+                # a TOKEN's bytes: a row shared by g positions counts 1/g
                 "row_bytes": int(sum(
-                    v[0].shape[1] * v[0].shape[3] * v[0].dtype.itemsize
-                    for v in pool.values())),
-                "layers": max(len(v) for v in pool.values()),
-                "pool_bytes": int(sum(a.size * a.dtype.itemsize
-                                      for v in pool.values() for a in v))}
+                    b["row_bytes"] // b["positions_per_row"]
+                    for b in by_leaf.values())),
+                "layers": max(b["layers"] for b in by_leaf.values()),
+                "pool_bytes": sum(b["pool_bytes"]
+                                  for b in by_leaf.values()),
+                "by_leaf": by_leaf}
 
     def _lane_state_stats(self) -> dict:
         """The lanes' state beside the pool, from its own leaves: bytes
@@ -3142,6 +3197,11 @@ class LLMEngine:
                 ssm_lane_steps=self.ssm_lane_steps,
                 prefill_scan_chunks=self.prefill_scan_chunks,
                 prefill_scan_chunks_dense=self.prefill_scan_chunks_dense)
+        if self._sel_layers:
+            out["loop"].update(
+                dsa_rows_context=self.dsa_rows_context,
+                dsa_groups_scored=self.dsa_groups_scored,
+                dsa_rows_selected=self.dsa_rows_selected)
         with _BUILDS_LOCK:
             # every program this PROCESS built since its first engine
             # was made; the ledger of threads is the process's too

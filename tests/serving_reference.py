@@ -61,9 +61,12 @@ def served_logits(model, params, cfg, prompt, follow, bucket, *,
         # decode program takes it (serve/llm.py `_pool`)
         pages = {name: leaves for name, leaves in cache.items()
                  if name not in ("pos", "state")}
+        # a leaf's rows cover `page // rows` positions each (1 but for
+        # a pooled index key): its tail holds what k positions complete
         tails = jax.tree.map(
-            lambda p: jnp.zeros((2, p.shape[1], k, p.shape[3]), p.dtype),
-            pages)
+            lambda p: jnp.zeros((2, p.shape[1],
+                                 -(-k // (page // p.shape[2])), p.shape[3]),
+                                p.dtype), pages)
         st, pos = cache["state"], ts
         for j, t in enumerate(follow[w0:w0 + k]):
             lg, tails, st, _ = step(
@@ -72,6 +75,8 @@ def served_logits(model, params, cfg, prompt, follow, bucket, *,
             out.append(lg[1])
             pos = pos + 1
         cache = {**jax.tree.map(
-            lambda p, t: merge_tail_pages(p, t, table, ts, k), pages, tails),
+            lambda p, t: merge_tail_pages(p, t, table, ts, k,
+                                          page // p.shape[2]),
+            pages, tails),
             "pos": ts + k, "state": st}
     return jnp.stack(out)

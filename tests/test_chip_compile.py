@@ -650,8 +650,11 @@ def test_served_sarvam_engine_fits_one_chip(topo, one_chip, compiled_kernels,
     monkeypatch.setattr(jax, "devices", lambda *a: list(topo.devices))
     cfg, eng, lows = _sarvam_lowerings(one_chip, None, [(1, 8192)])
     st = eng._cache_stats()
+    pool = 577 * 512 * 1280 * 6
     assert st == {"kind": "latent", "row_bytes": 1280, "layers": 6,
-                  "pool_bytes": 577 * 512 * 1280 * 6}
+                  "pool_bytes": pool, "by_leaf": {"latent": {
+                      "row_bytes": 1280, "positions_per_row": 1,
+                      "layers": 6, "pool_bytes": pool}}}
     resident = st["pool_bytes"] + 2 * sum(
         math.prod(a.shape) for a in jax.tree.leaves(eng.params))
     assert resident > 0.75 * HBM_BYTES       # a deployment's fill
@@ -808,9 +811,12 @@ def test_served_granite_engine_fits_one_chip(topo, one_chip, compiled_kernels,
     lane = eng.stats()["lane_state"]
     assert lane["by_kind"] == {"conv": 36 * 64 * 3 * 4352 * 2,
                                "ssm": 36 * 64 * 128 * 4096 * 4}
-    assert eng._cache_stats() == {"kind": "kv", "row_bytes": 2 * 8 * 64 * 2,
-                                  "layers": 4,
-                                  "pool_bytes": 257 * 512 * 2048 * 4}
+    cache = eng._cache_stats()
+    leaf = {"row_bytes": 8 * 64 * 2, "positions_per_row": 1, "layers": 4,
+            "pool_bytes": 257 * 512 * 2048 * 2}
+    assert cache == {"kind": "kv", "row_bytes": 2 * 8 * 64 * 2,
+                     "layers": 4, "pool_bytes": 257 * 512 * 2048 * 4,
+                     "by_leaf": {"k": leaf, "v": leaf}}
     weights = sum(math.prod(a.shape) * a.dtype.itemsize
                   for a in jax.tree.leaves(eng.params))
     resident = weights + lane["bytes"] + eng._cache_stats()["pool_bytes"]
@@ -870,3 +876,184 @@ def test_granite_decode_step_loop_copies_no_lane_state_and_no_weight(
     loop = _loop_lines(hlo)
     calls = [ln for ln in loop if "custom-call(" in ln and "ssm_update" in ln]
     assert len(calls) == 2                  # a body a Mamba run
+
+
+# ------------------------------------------------- GLM-5.3-Flash (PR 41)
+def _glm_lowerings(one_chip, shapes):
+    """benchmarks/configs/glm-5.3-flash-ep8.json as the benchmark builds
+    it."""
+    from benchmarks.harness import spec
+    from ray_tpu.serve.llm import LLMEngine
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def abstract(tree):
+        return jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
+
+    conf = spec.load_json(os.path.join(
+        spec.BENCH_DIR, "configs", "glm-5.3-flash-ep8.json"))
+    fam = spec.config_family(conf)
+    eng_kw = dict(conf["engine"], paged=True)
+    cfg = fam.program_config(fam.published(conf), max_seq=eng_kw["max_len"])
+    params = abstract(jax.eval_shape(
+        lambda: fam.init_params(jax.random.PRNGKey(0), cfg)))
+    eng = LLMEngine(cfg, params, **eng_kw)
+    i32, f32 = jnp.int32, jnp.float32
+    b, k = eng.max_batch, eng.steps_per_sync
+    out = {f"decode_k{k}": eng._decode_fns[k].lower(
+        params, abstract(eng.cache), sds((b,), i32), sds((b,), f32),
+        sds((b, eng._maxp), i32), sds((b,), i32), sds((b,), i32), None)}
+    for w, p in shapes:
+        out[f"prefill_w{w}_p{p}"] = eng._prefill_fwd.lower(
+            params, sds((w, p), i32), sds((w,), i32), sds((w,), i32),
+            sds((w,), f32), sds((w,), i32), sds((w,), i32), None)
+    return cfg, eng, out
+
+
+@pytest.mark.parametrize("lanes,layers,dtype", [
+    (64, 4, jnp.float32), (8, 34, jnp.float32), (64, 4, jnp.bfloat16)])
+def test_kda_update_compiles_at_the_served_widths(one_chip, compiled_kernels,
+                                                  lanes, layers, dtype):
+    """GLM-5.3-Flash's lanes: 64 heads x [128, 128] float32 a lane a
+    layer, one 4 MB block a grid step, read and written through the alias
+    (a head's vectors cut out of [dk, H] as static columns); nothing the
+    size of the state is a temporary."""
+    from ray_tpu.ops import kda
+
+    def s(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    H, dk = 64, 128
+    low = jax.jit(kda.kda_update, donate_argnums=(0,)).lower(
+        s((layers, lanes, H, dk, dk), dtype), s((), jnp.int32),
+        s((lanes,), jnp.int32), s((), jnp.int32), s((lanes, H, dk)),
+        s((lanes, H, dk)), s((lanes, H, dk)), s((lanes, H, dk)),
+        s((lanes, H)))
+    assert low.as_text().count("tpu_custom_call") == 1
+    c = low.compile()
+    mem = c.memory_analysis()
+    state_bytes = layers * lanes * H * dk * dk * jnp.dtype(dtype).itemsize
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.temp_size_in_bytes < state_bytes // 64
+    assert _pool_copies(c.as_text(), lanes * H * dk * dk) == []
+
+
+def test_dsa_attn_compiles_and_copies_no_pool(one_chip, compiled_kernels):
+    """The sparse step's gather and kernel at the served widths: 513
+    groups of 4 rows a lane gathered out of the latent pool into 2,176
+    rows (a row at a time: the pool is never copied or re-laid-out),
+    attended with the block's 8 tail rows by 64 heads in one grid step."""
+    from ray_tpu.ops import sparse_attention as dsa
+
+    def s(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    B, H, w, page, maxp, n_pages = 64, 64, 512, 512, 18, 1153
+
+    def step(q, pages, tail, table, pos, ts, groups, ok, lanes, count):
+        rows, bias, tail_bias, _, _ = dsa.select_rows(
+            pages, tail, table, pos, ts, groups, ok, 4)
+        return dsa.dsa_decode_attention(q, rows, bias, tail[:, 0], tail_bias,
+                                        lanes, count, dv=w, sm_scale=0.0625)
+
+    i32 = jnp.int32
+    low, c = _compile(
+        step, s((B, H, w)), s((n_pages, 1, page, w)), s((B, 1, 8, w)),
+        s((B, maxp), i32), s((B,), i32), s((B,), i32), s((B, 512), i32),
+        s((B, 512), jnp.bool_), s((B,), i32), s((), i32))
+    assert low.as_text().count("tpu_custom_call") == 1
+    hlo = c.as_text()
+    assert _pool_copies(hlo, n_pages * page * w // 2) == []
+    mem = c.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 * B * 2176 * w * 2 + (64 << 20)
+
+
+def test_dsa_prefill_kernel_compiles_at_the_served_widths(one_chip,
+                                                          compiled_kernels):
+    """The masked flash kernel of the 1 x 8192 prefill: 64 heads, q / k /
+    v 256 wide, blocks of 512 with the mask a byte a pair."""
+    from ray_tpu.ops import sparse_attention as dsa
+
+    def s(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    T, H, w = 8192, 64, 256
+    low, c = _compile(
+        lambda q, k, v, m: dsa.masked_prefill_attention(
+            q, k, v, m, sm_scale=0.0625),
+        s((1, T, H, w)), s((1, T, H, w)), s((1, T, H, w)),
+        s((1, T, T), jnp.int8))
+    assert low.as_text().count("tpu_custom_call") == 1
+    # the three transposes to head-major and the one back, nothing else
+    assert c.memory_analysis().temp_size_in_bytes < 5 * T * H * w * 2
+
+
+@pytest.mark.time_limit(900)
+def test_served_glm_engine_fits_one_chip_and_copies_no_state_or_pool(
+        topo, one_chip, compiled_kernels, monkeypatch):
+    """glm-5.3-flash-ep8 as the benchmark serves it (5 layers, 64 lanes,
+    1,153 pages): the decode program and the 1 x 8192 prefill program its
+    traffic runs compile for one chip beside weights + lane state + both
+    pool leaves.  Inside the K-step loop the lanes' KDA state (1.07 GB)
+    is touched by `kda_update` alone, which aliases it, and neither pool
+    leaf is copied, selected over or re-laid-out, in the loop or outside
+    it: the merges scatter in place and the sparse step gathers rows."""
+    monkeypatch.setattr(jax, "devices", lambda *a: list(topo.devices))
+    cfg, eng, lows = _glm_lowerings(one_chip, [(1, 8192)])
+    lane = eng.stats()["lane_state"]
+    assert lane["by_kind"] == {"conv": 4 * 64 * 3 * 24576 * 2,
+                               "kda": 4 * 64 * 64 * 128 * 128 * 4,
+                               "ipart": 64 * 128 * 4}
+    cache = eng._cache_stats()
+    assert cache["by_leaf"]["latent"] == {
+        "row_bytes": 1024, "positions_per_row": 1, "layers": 1,
+        "pool_bytes": 1153 * 512 * 1024}
+    assert cache["by_leaf"]["index"] == {
+        "row_bytes": 256, "positions_per_row": 4, "layers": 1,
+        "pool_bytes": 1153 * 128 * 256}
+    assert cache["row_bytes"] == 1024 + 64
+    weights = sum(math.prod(a.shape) * a.dtype.itemsize
+                  for a in jax.tree.leaves(eng.params))
+    resident = weights + lane["bytes"] + cache["pool_bytes"]
+    assert 11.1e9 < resident < 11.3e9        # 66 % of the chip
+    assert eng._prefill_floor > 1000 and (1, 8192) in eng._prefill_programs
+    kernels = {"decode_k8": ("kda_update", "dsa_attn", "moe_gmm"),
+               "prefill_w1_p8192": ("moe_gmm", "dsa_prefill")}
+    compiled = {}
+    for name, low in lows.items():
+        txt = low.as_text()
+        for kern in kernels[name]:
+            assert kern in txt, (name, kern)
+        compiled[name] = c = low.compile()
+        mem = c.memory_analysis()
+        held = resident + mem.temp_size_in_bytes + (
+            mem.output_size_in_bytes - mem.alias_size_in_bytes)
+        print(f"{name}: temps {mem.temp_size_in_bytes / 1e9:.2f} GB, "
+              f"resident {resident / 1e9:.2f} GB, held {held / 1e9:.2f} GB")
+        assert held < 16.9e9 - 1.5e9, (name, held)
+    c = compiled["decode_k8"]
+    hlo = c.as_text()
+    assert "while(" in hlo
+    layer_state = 64 * 64 * 128 * 128        # one KDA layer's lanes
+    # (the one other array of that size is the sparse step's gathered
+    # rows, 64 lanes x 2,176 rows x 512: what the selection reads, 0.14 GB)
+    found = [f for f in weight_sized_writes(hlo, layer_state)
+             if "dsa_select" not in f[2]]
+    assert found and all(op == "custom-call" and "kda_update" in scope
+                         for _, op, scope in found), found
+    # no copy of anything with a pool leaf's 1,153 pages (W_qb [1536,
+    # 16384] IS copied, to the layout the 64-row matmul reads, once a
+    # window outside the loop: 50 MB, PERF.md section 7)
+    lines = {m.group(1): ln for ln in hlo.splitlines()
+             for m in [_INSTR.match(ln)] if m}
+    assert [n for n in _pool_copies(hlo, 1153 * 128 * 128)
+            if "[1153," in lines[n].split("copy(")[0]] == []
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= 4 * layer_state * 4 + cache[
+        "pool_bytes"]
+    loop = _loop_lines(hlo)
+    assert len([ln for ln in loop if "custom-call(" in ln
+                and "kda_update" in ln]) == 4          # a call a KDA layer
+    assert len([ln for ln in loop if "custom-call(" in ln
+                and "dsa_attn" in ln]) == 1

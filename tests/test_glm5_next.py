@@ -1,0 +1,519 @@
+"""models/glm5_next.py (KDA linear-attention layers beside latent
+attention read through a learned selection, an mHC residual, routed
+experts) against the plain float32 reference the benchmark holds it to
+(`benchmarks/harness/refs/glm5_next.py`, which imports nothing of the
+program): the prompt pass, paged decode through both pool leaves and the
+lane state past the point where the selection starts to drop rows, the
+ENGINE's own logits with lanes reused, the pooled index key written once,
+the expert shares, the residual maps, the counters and the controls a
+sound comparison must fail."""
+from __future__ import annotations
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from serving_reference import served_logits  # rootdir-relative (no pkg)
+
+from benchmarks.harness.refs import glm5_next as ref
+from ray_tpu.models import glm5_next, named_config, serving_model
+from ray_tpu.ops import paged_attention, sparse_attention as dsa, ssm
+from ray_tpu.serve.llm import LLMEngine, LLMServer
+
+# float32 weights: the served path and the reference then differ by
+# summation order alone
+CFG = dataclasses.replace(named_config("glm5-next-debug"),
+                          dtype=jnp.float32)
+PAGE, K = 16, 4
+TOL = 5e-5
+CONTROL = 2e-3
+N_KDA = CFG.count(glm5_next.KDA)
+
+
+def model_of(cfg) -> dict:
+    return dict(
+        hc_mult=cfg.hc_mult, hc_eps=cfg.hc_eps,
+        hc_sinkhorn_iters=cfg.hc_iters, rms_norm_eps=cfg.norm_eps,
+        linear_attn_config=dict(
+            num_heads=cfg.n_heads, head_dim=cfg.kda_head_dim,
+            short_conv_kernel_size=cfg.conv_kernel,
+            gate_lower_bound=cfg.gate_lower_bound),
+        num_attention_heads=cfg.n_heads, qk_nope_head_dim=cfg.qk_head_dim,
+        index_kpool=cfg.index_pool, index_topk=cfg.index_topk,
+        index_n_heads=cfg.index_heads, index_head_dim=cfg.index_dim,
+        num_experts_per_tok=cfg.top_k, norm_topk_prob=True,
+        routed_scaling_factor=cfg.routed_scaling,
+        swiglu_limit=cfg.swiglu_limit, layer_types=list(cfg.layer_types),
+        mlp_layer_types=list(cfg.ffn_types),
+        num_hidden_layers=cfg.n_layers,
+        experts_held=list(cfg.experts_held))
+
+
+MODEL = model_of(CFG)
+
+
+def _gap(got, want) -> float:
+    got, want = np.asarray(got), np.asarray(want)
+    return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+
+
+@pytest.fixture(scope="module")
+def params():
+    return glm5_next.init_params(jax.random.PRNGKey(7), CFG)
+
+
+def _tokens(n, seed=0):
+    return np.random.default_rng(seed).integers(0, 256, n).astype(np.int32)
+
+
+# --------------------------------------------------- (a) the prompt pass
+@pytest.mark.parametrize("n", [5, 16, 37])
+def test_prefill_logits_equal_the_reference(params, n):
+    """37 positions: nine complete groups of which four are kept, so the
+    selection drops rows in the second half of the prompt."""
+    tok = _tokens(n, n)
+    h = glm5_next.prefill(params, jnp.asarray(tok[None]), CFG)[0]
+    got = glm5_next.project_logits(params, h[0])
+    assert _gap(got, ref.logits(params, tok, MODEL)) < TOL
+
+
+@pytest.mark.parametrize("n,bucket,new", [(21, 32, 11), (3, 16, 9)])
+def test_padded_prefill_then_paged_decode_equals_the_reference(
+        params, n, bucket, new):
+    """The prompt padded to a bucket beside a longer row, scattered into
+    both pool leaves and lane 1, then decode in windows of four: a group
+    of index keys completes mid-window, groups straddle the windows'
+    edges, and the context passes the selection's size."""
+    tok = _tokens(n + new, 3 * n)
+    got = served_logits(glm5_next, params, CFG, tok[:n], tok[n:], bucket,
+                        page=PAGE, k=K)
+    want = ref.logits(params, tok, MODEL, last=new + 1)
+    assert _gap(got, want) < TOL
+
+
+def test_the_prefill_hands_the_state_at_the_true_length(params):
+    tok = _tokens(32, 5)
+    lens = jnp.asarray([32, 13], jnp.int32)
+    toks = jnp.asarray(np.stack([tok, tok]))
+    _, latent, index, state, _ = glm5_next.prefill(params, toks, CFG, lens)
+    X = ref.embed(params, tok[:13], MODEL)
+    kda_i = 0
+    for lid, lp in enumerate(params["layers"]):
+        X, _, info, _ = ref.layer(X, lp, lid, MODEL)
+        if CFG.layer_types[lid] == glm5_next.KDA:
+            assert _gap(state["kda"][kda_i][1], info["state"]) < TOL
+            assert _gap(state["conv"][kda_i][1], info["conv"]) < TOL
+            kda_i += 1
+        else:
+            assert _gap(latent[0][1, :13, 0], info["latent"]) < TOL
+            assert _gap(index[0][1, :3, 0], info["index"]) < TOL
+            assert _gap(state["ipart"][0][1], info["ipart"]) < TOL
+
+
+# ----------------------------------------- (b) the selection's mechanism
+def test_sparse_decode_is_dense_latent_decode_below_the_selections_size():
+    """While no more groups are complete than the selection keeps, the
+    gathered rows are the whole context: `dsa_attn` over them equals the
+    dense latent kernel's oracle."""
+    B, H, w, page, maxp, g, top, kt = 3, 4, 32, 16, 4, 4, 64, 4
+    ks = jax.random.split(jax.random.PRNGKey(2), 5)
+    pages = jax.random.normal(ks[0], (1 + B * maxp, 1, page, w))
+    tail = jax.random.normal(ks[1], (B, 1, kt, w))
+    q = jax.random.normal(ks[2], (B, H, w))
+    table = jnp.arange(1, 1 + B * maxp, dtype=jnp.int32).reshape(B, maxp)
+    table = table.at[1].set(0)                   # lane 1 holds no request
+    ts = jnp.asarray([37, 0, 22], jnp.int32)
+    pos = ts + 2
+    n_complete = (pos + 1) // g
+    scores = jax.random.normal(ks[3], (B, top // g + 2))
+    idx, ok, _ = dsa.select_groups(
+        jnp.where(jnp.arange(scores.shape[1])[None] < n_complete[:, None],
+                  scores, dsa.NEG_INF),
+        jnp.full((B,), scores.shape[1], jnp.int32), top // g)
+    rows, bias, tail_bias, _, _ = dsa.select_rows(pages, tail, table, pos,
+                                                  ts, idx, ok, g)
+    lanes, count = ssm.live_lanes(paged_attention.lanes_live(table))
+    got = dsa.dsa_decode_attention(q, rows, bias, tail[:, 0], tail_bias,
+                                   lanes, count, dv=w, sm_scale=0.3)
+    want = paged_attention.mla_decode_reference(
+        q, pages, tail, table, pos, ts, dv=w, sm_scale=0.3)
+    assert float(jnp.abs(got - want).max()) < 1e-5
+    assert float(jnp.abs(got[1]).max()) == 0.0
+
+
+@pytest.mark.parametrize("T", [128, 384])
+def test_the_masked_prefill_kernel_equals_a_masked_softmax(T):
+    """`dsa_prefill` (interpret mode): one block, and three blocks of 128
+    with the pairs above the diagonal never walked; a query that attends
+    nothing reads 0."""
+    b, H, dq = 1, 2, 32
+    ks = jax.random.split(jax.random.PRNGKey(T), 4)
+    q, k, v = (jax.random.normal(ks[i], (b, T, H, dq)) for i in range(3))
+    mask = ((jax.random.uniform(ks[3], (b, T, T)) < 0.3)
+            | jnp.eye(T, dtype=bool)[None]) & jnp.tril(
+                jnp.ones((T, T), bool))[None]
+    mask = mask.at[:, 5].set(False)
+    got = dsa.masked_prefill_attention(q, k, v, mask.astype(jnp.int8),
+                                       sm_scale=0.2)
+    s = jnp.where(mask[:, None],
+                  jnp.einsum("bthd,bshd->bhts", q, k) * 0.2, -1e30)
+    want = jnp.einsum("bhts,bshd->bthd",
+                      jax.nn.softmax(s, -1) * mask[:, None], v)
+    assert float(jnp.abs(got - want).max()) < 1e-5
+    assert float(jnp.abs(got[:, 5]).max()) == 0.0
+    assert dsa.prefill_block(8192) == 512 and dsa.prefill_block(37) == 0
+
+
+def test_a_prompt_of_a_kernel_bucket_equals_the_reference(params):
+    """128 positions: the bucket at which the prefill's sparse attention
+    runs in the kernel and not in XLA."""
+    tok = _tokens(128, 9)
+    h = glm5_next.prefill(params, jnp.asarray(tok[None]), CFG)[0]
+    got = glm5_next.project_logits(params, h[0])
+    assert _gap(got, ref.logits(params, tok, MODEL)) < TOL
+
+
+def test_the_selection_keeps_the_best_groups_and_the_own_group():
+    """Six complete groups, two kept: the query attends their eight rows
+    and the rows of its own incomplete group, nothing else."""
+    g, top = 4, 8
+    pos = jnp.asarray([26])                     # groups 0..5 complete
+    scores = jnp.asarray([[0.1, 0.9, -0.3, 0.5, 0.2, 0.0, 7.0]])
+    mask, chosen = dsa.selected_mask(scores, pos, 28, g, top)
+    assert chosen[0].tolist() == [False, True, False, True, False, False,
+                                  False]
+    assert np.flatnonzero(np.asarray(mask[0])).tolist() == [
+        4, 5, 6, 7, 12, 13, 14, 15, 24, 25, 26]
+    assert dsa.selection_counts(27, g, top) == (6, 8 + 3)
+    assert dsa.selection_counts(7, g, top) == (1, 4 + 3)
+
+
+def test_a_group_completing_mid_window_writes_its_pooled_key_once(params):
+    """Decode four steps from position 13: position 15 completes group 3,
+    whose pooled key lands in the index tail's row 0 at that step and is
+    not touched again; the merge writes it, and only it, to the pool."""
+    n = 13
+    tok = _tokens(n + K, 17)
+    toks = jnp.asarray(np.stack([tok[:16], tok[:16]]))
+    lens = jnp.asarray([16, n], jnp.int32)
+    h, latent, index, state, _ = glm5_next.prefill(params, toks, CFG, lens)
+    cache = glm5_next.init_paged_cache(CFG, 2, 9, PAGE)
+    table = np.arange(1, 9, dtype=np.int32).reshape(2, 4)
+    cols = np.arange(16) // PAGE
+    cache = glm5_next.scatter_prefill_pages(
+        cache, latent, index, state, jnp.asarray(table[:, cols]),
+        jnp.tile(jnp.arange(16) % PAGE, (2, 1)), jnp.arange(2), lens)
+    before = cache["index"][0]
+    pages = {"latent": cache["latent"], "index": cache["index"]}
+    tails = {"latent": [jnp.zeros((2, 1, K, CFG.kv_lora_rank))],
+             "index": [jnp.zeros((2, 1, 1, CFG.index_dim))]}
+    st, ts = cache["state"], cache["pos"]
+    seen = []
+    for j in range(K):
+        _, tails, st, _ = glm5_next.decode_step_paged(
+            params, pages, tails, st, jnp.asarray([1, int(tok[n + j])]),
+            ts + j, ts, j, jnp.asarray(table), CFG)
+        seen.append(np.asarray(tails["index"][0][1, 0, 0]))
+    assert not seen[0].any() and not seen[1].any()      # positions 13, 14
+    assert seen[2].any() and (seen[3] == seen[2]).all()  # 15 completes it
+    X = ref.embed(params, tok, MODEL)
+    X = ref.layer(X, params["layers"][0], 0, MODEL)[0]
+    info = ref.layer(X, params["layers"][1], 1, MODEL)[2]
+    assert _gap(seen[2], info["index"][3]) < TOL
+    merged = paged_attention.merge_tail_pages(
+        before, tails["index"][0], jnp.asarray(table), ts, K, per=4)
+    changed = np.argwhere(np.asarray(merged != before).any(-1))
+    # lane 1's group 3 (its first page); lane 0, at positions 16..19,
+    # completed group 4 (the first row of its second page); nothing else
+    assert changed.tolist() == [[2, 0, 0], [5, 0, 3]]
+
+
+# ------------------------------------------------ (c) through the engine
+def _record_engine_logits(monkeypatch):
+    """Every logit the engine's programs compute, as they compute it."""
+    seen = []
+
+    def note(toks, pos, live, logits):
+        for t, p, ok, lg in zip(*map(np.asarray, (toks, pos, live, logits))):
+            if ok:
+                seen.append((int(t), int(p), lg))
+
+    step, prefill = glm5_next.serve_decode_step, glm5_next.serve_prefill
+
+    def decode_step(params, pages, tails, state, tokens, pos, ts, j, table,
+                    cfg, lora=None, plan=None):
+        out = step(params, pages, tails, state, tokens, pos, ts, j, table,
+                   cfg, lora, plan)
+        jax.debug.callback(note, tokens, pos,
+                           paged_attention.lanes_live(table), out[0])
+        return out
+
+    def prefill_rows(params, tokens, cfg, true_lens, lora=None):
+        out = prefill(params, tokens, cfg, true_lens, lora)
+        rows = jnp.arange(tokens.shape[0])
+        last = out[0][rows, true_lens - 1]
+        jax.debug.callback(
+            note, tokens[rows, true_lens - 1], true_lens - 1,
+            jnp.ones_like(true_lens, bool),
+            glm5_next.project_logits(params, last).astype(jnp.float32))
+        return out
+
+    monkeypatch.setattr(glm5_next, "serve_decode_step", decode_step)
+    monkeypatch.setattr(glm5_next, "serve_prefill", prefill_rows)
+    return seen
+
+
+def test_engine_logits_equal_the_reference_across_lane_reuse(
+        params, monkeypatch):
+    """Two lanes, five prompts: a lane that served one request serves
+    another, and neither the KDA state, the incomplete group's sum nor a
+    pool row may leak.  The LOGITS the engine's own programs computed at
+    every served position equal the reference's full forward; the
+    counters equal the host arithmetic they stand for."""
+    seen = _record_engine_logits(monkeypatch)
+    eng = LLMEngine(CFG, params, max_batch=2, max_len=96, page_size=PAGE,
+                    kv_pages=13, steps_per_sync=K)
+    eng.start()
+    try:
+        prompts = [_tokens(n, 10 + n).tolist() for n in (40, 3, 17, 1, 29)]
+        futs = [eng.submit(p, max_new_tokens=14) for p in prompts]
+        outs = [f.result(timeout=300) for f in futs]
+        jax.effects_barrier()
+        st = eng.stats()
+    finally:
+        eng.stop()
+    assert st["completed"] == 5 and st["preemptions"] == 0
+    by_key = {}
+    for t, p, lg in seen:
+        by_key.setdefault((t, p), []).append(lg)
+    checked = 0
+    for prompt, out in zip(prompts, outs):
+        seq = prompt + out["tokens"]
+        want = np.asarray(ref.logits(params, seq[:-1], MODEL,
+                                     last=len(out["tokens"])))
+        for i, row in enumerate(want):
+            p = len(prompt) - 1 + i
+            got = by_key.get((seq[p], p), [])
+            assert got, (len(prompt), i)
+            assert min(_gap(g, row) for g in got) < TOL
+            checked += 1
+    assert checked == 5 * 14
+    loop = st["loop"]
+    assert loop["ssm_lane_steps"] == loop["lane_steps_live"] * N_KDA
+    assert loop["prefill_scan_chunks"] == N_KDA * sum(
+        -(-len(p) // CFG.kda_chunk) for p in prompts)
+    # every live lane-step of the sparse layer: its context, the complete
+    # groups it scored, the rows it attended (under 100 %: it was sparse)
+    assert loop["dsa_groups_scored"] <= loop["dsa_rows_context"] // 4
+    assert loop["dsa_rows_selected"] < loop["dsa_rows_context"]
+    assert loop["dsa_rows_selected"] <= loop["lane_steps_live"] * (
+        CFG.index_topk + 3)
+    cache = st["cache"]
+    assert cache["kind"] == "latent" and set(cache["by_leaf"]) == {
+        "latent", "index"}
+    assert cache["by_leaf"]["index"]["positions_per_row"] == 4
+    assert cache["by_leaf"]["latent"]["positions_per_row"] == 1
+    assert cache["row_bytes"] == 4 * (CFG.kv_lora_rank + CFG.index_dim // 4)
+    lane = st["lane_state"]
+    assert lane["layers"] == N_KDA and set(lane["by_kind"]) == {
+        "conv", "kda", "ipart"}
+    assert lane["by_kind"]["kda"] == N_KDA * 2 * 4 * 16 * 16 * 4
+    assert lane["prefix_cache"] == "off: lane state"
+
+
+def test_an_idle_lanes_state_is_bit_unchanged_by_a_decode_window(params):
+    eng = LLMEngine(CFG, params, max_batch=3, max_len=64, page_size=PAGE,
+                    kv_pages=13, steps_per_sync=K)
+    marked = jax.tree.map(lambda a: a + 1.0, eng.cache["state"])
+    eng.cache = {**eng.cache, "state": marked}
+    want = jax.tree.map(np.asarray, marked)
+    eng.start()
+    try:
+        eng.generate(_tokens(9, 1).tolist(), max_new_tokens=9)
+        got = jax.tree.map(np.asarray, eng.cache["state"])
+    finally:
+        eng.stop()
+    used = [i for i in range(3)
+            if not (got["kda"][:, i] == want["kda"][:, i]).all()]
+    assert len(used) == 1
+    for name in ("kda", "ipart"):
+        idle = [i for i in range(3) if i not in used]
+        assert (got[name][:, idle] == want[name][:, idle]).all()
+
+
+# ------------------------------------------------------ (d) the residual
+def test_the_residual_map_is_doubly_stochastic(params):
+    X = jax.random.normal(jax.random.PRNGKey(4), (2, 9, CFG.hc_mult,
+                                                  CFG.dim))
+    pre, post, res = glm5_next.mhc_maps(X, params["layers"][0]["hc_mix"],
+                                        CFG)
+    assert float(jnp.abs(res.sum(-1) - 1).max()) < 1e-3
+    assert float(jnp.abs(res.sum(-2) - 1).max()) < 1e-3
+    assert bool(jnp.all((pre > 0) & (pre < 1) & (post > 0) & (post < 2)))
+    want = ref.mhc_maps(X[0], params["layers"][0]["hc_mix"], MODEL)
+    for got, w in zip((pre, post, res), want):
+        assert _gap(got[0], w) < TOL
+
+
+def test_identity_maps_make_the_plain_residual(monkeypatch):
+    """H_res = I, H_pre = H_post = e_0: stream 0 is x + F(x), the others
+    stand still."""
+    n = CFG.hc_mult
+    e0 = jnp.zeros((n,)).at[0].set(1.0)
+    monkeypatch.setattr(glm5_next, "mhc_maps", lambda X, hp, cfg: (
+        jnp.broadcast_to(e0, X.shape[:-1]),
+        jnp.broadcast_to(e0, X.shape[:-1]),
+        jnp.broadcast_to(jnp.eye(n), X.shape[:-2] + (n, n))))
+    X = jax.random.normal(jax.random.PRNGKey(8), (5, n, CFG.dim))
+    out, _ = glm5_next.sublayer(X, None, CFG, lambda x: (jnp.tanh(x), None))
+    assert float(jnp.abs(out[:, 0] - (X[:, 0] + jnp.tanh(X[:, 0]))).max()) \
+        < 1e-6
+    assert bool(jnp.all(out[:, 1:] == X[:, 1:]))
+
+
+# ------------------------------------------------- (e) ranges of experts
+def test_the_eight_shares_and_the_shared_expert_once_add_up():
+    """Eight chips each hold one of the router's eight experts; every one
+    computes the shared expert alike.  Their routed parts plus the shared
+    expert counted ONCE are the uncut layer of the reference."""
+    lp = glm5_next.init_params(jax.random.PRNGKey(3), CFG)["layers"][1]
+    x = jax.random.normal(jax.random.PRNGKey(5), (24, CFG.dim))
+    want, _ = ref.ff(x, lp, 1, MODEL)
+    h2 = glm5_next.rmsnorm(x, lp["norm2"], CFG.norm_eps)
+    parts = glm5_next.shared_ffn(h2, lp, CFG.dtype, CFG.swiglu_limit)
+    n = 0
+    for lo in range(8):
+        chip = dataclasses.replace(CFG, experts_held=(lo, lo + 1))
+        held = dict(lp, w13=lp["w13"][lo:lo + 1], w2=lp["w2"][lo:lo + 1])
+        y, c = glm5_next.routed_ffn(h2, held, chip)
+        parts, n = parts + y, n + int(c[2])
+    assert float(jnp.abs(parts - want).max()) < TOL
+    assert n == 24 * CFG.top_k
+    chip = dataclasses.replace(CFG, experts_held=(2, 5))
+    held = dict(lp, w13=lp["w13"][2:5], w2=lp["w2"][2:5])
+    got, _ = glm5_next.ffn(x, held, 1, chip)
+    want, _ = ref.ff(x, held, 1, model_of(chip))
+    assert float(jnp.abs(got - want).max()) < TOL
+
+
+def test_the_clamp_bounds_a_swiglu():
+    from ray_tpu.models import routed
+
+    gate = jnp.asarray([-30.0, 3.0, 30.0])
+    up = jnp.asarray([-30.0, 3.0, 30.0])
+    g, u = routed.clamp(gate, up, 10.0)
+    assert g.tolist() == [-30.0, 3.0, 10.0] and u.tolist() == [-10.0, 3.0,
+                                                              10.0]
+    assert routed.clamp(gate, up, 0.0) == (gate, up)
+
+
+# ------------------------------------------------------- (f) the controls
+def _sound(params, cfg=CFG, model=MODEL):
+    """The served path (a padded prompt pass, the scatter, eleven decode
+    steps in windows of four) against the reference's full forward."""
+    tok = _tokens(32, 41)
+    got = served_logits(glm5_next, params, cfg, tok[:21], tok[21:], 32,
+                        page=PAGE, k=K)
+    return _gap(got, ref.logits(params, tok, model, last=12))
+
+
+def _no_tail(scores, pos, n_keys, group, top, _f=dsa.selected_mask):
+    mask, chosen = _f(scores, pos, n_keys, group, top)
+    own = jnp.arange(n_keys)[None, :] >= ((pos + 1) // group * group)[:, None]
+    return mask & ~own, chosen
+
+
+def _last_groups(q, w, kbar):
+    return jnp.broadcast_to(jnp.arange(kbar.shape[-2], dtype=jnp.float32),
+                            q.shape[:-2] + (kbar.shape[-2],))
+
+
+CONTROLS = {
+    "no_decay_gate": lambda mp: mp.setattr(
+        glm5_next, "kda_gate", lambda h, lp, cfg, _f=glm5_next.kda_gate: (
+            jnp.zeros_like(_f(h, lp, cfg)[0]), _f(h, lp, cfg)[1])),
+    "sinkhorn_once": lambda mp: mp.setattr(
+        glm5_next, "sinkhorn",
+        lambda m, iters, _f=glm5_next.sinkhorn: _f(m, 1)),
+    "last_rows_selected": lambda mp: mp.setattr(dsa, "index_scores",
+                                                _last_groups),
+    "tail_not_selected": lambda mp: mp.setattr(dsa, "selected_mask",
+                                               _no_tail),
+    "index_key_is_the_groups_last": lambda mp: mp.setattr(
+        dsa, "pool_index_keys", lambda keys, group: keys[
+            ..., group - 1:keys.shape[-2] // group * group:group, :]),
+    "streams_not_summed": lambda mp: mp.setattr(
+        glm5_next, "final_hidden",
+        lambda params, X, cfg: glm5_next.rmsnorm(
+            X[..., 0, :], params["final_norm"], cfg.norm_eps)),
+}
+
+
+def test_the_sound_program_is_inside_the_tolerance(params):
+    assert _sound(params) < TOL
+
+
+@pytest.mark.parametrize("control", sorted(CONTROLS))
+def test_every_control_exceeds_the_tolerance(params, monkeypatch, control):
+    CONTROLS[control](monkeypatch)
+    assert _sound(params) > CONTROL
+
+
+def test_a_bfloat16_state_exceeds_the_tolerance(params):
+    """The lanes' state matrices kept in bfloat16: handed over rounded,
+    re-rounded by every decode step."""
+    assert _sound(params, dataclasses.replace(
+        CFG, state_dtype=jnp.bfloat16)) > 10 * TOL
+
+
+def test_an_unclamped_swiglu_exceeds_the_tolerance(params, monkeypatch):
+    """At fan-in scaled weights no SwiGLU input reaches the published
+    limit (|gate| ~ 1 against 10), so the control runs at a limit that
+    binds, under which the sound program still equals the reference."""
+    cfg = dataclasses.replace(CFG, swiglu_limit=0.5)
+    model = dict(MODEL, swiglu_limit=0.5)
+    assert _sound(params, cfg, model) < TOL
+    monkeypatch.setattr(glm5_next.routed, "clamp",
+                        lambda gate, up, limit: (gate, up))
+    assert _sound(params, cfg, model) > CONTROL
+
+
+# ------------------------------------------------------------ (g) serving
+def test_the_seam_declares_what_the_engine_counts():
+    model = serving_model(CFG)
+    assert model is glm5_next
+    assert model.lane_state_layers(CFG) == 3 and model.CACHE_KIND == "latent"
+    assert model.selection(CFG) == (1, 4, 16)
+    assert model.scan_chunk(CFG) == 8 and model.routed_layers(CFG) == 3
+    streamed, multiplied = model.prefill_params(CFG)
+    assert streamed > multiplied > 0
+    big = glm5_next.Glm5NextConfig(
+        vocab_size=19456, layer_types=(glm5_next.KDA, glm5_next.DSA)
+        + (glm5_next.KDA,) * 3, ffn_types=("dense",) + ("sparse",) * 4,
+        experts_held=(0, 36))
+    # the ISSUE's arithmetic: 17.37 MB of lane state a row
+    assert glm5_next.prefill_state_bytes(big) == 4 * (4194304 + 147456) + 512
+    assert glm5_next.kda.max_chunk(big.gate_lower_bound) == big.kda_chunk
+
+
+def test_a_latent_pool_with_lane_state_is_served_without_the_prefix_cache(
+        params):
+    with pytest.raises(ValueError, match="prefix_cache=True refused"):
+        LLMEngine(CFG, params, max_batch=2, max_len=64, page_size=PAGE,
+                  kv_pages=9, prefix_cache=True)
+    with pytest.raises(ValueError, match="no multiple of index_pool"):
+        glm5_next.init_paged_cache(CFG, 2, 9, 6)
+
+
+def test_the_server_serves_the_preset_by_name():
+    srv = LLMServer("glm5-next-debug", max_batch=2, max_len=64,
+                    page_size=PAGE, kv_pages=9, steps_per_sync=K)
+    try:
+        out = srv.engine.generate([5, 6, 7, 8, 9], max_new_tokens=6)
+        assert len(out["tokens"]) == 6
+        assert srv.engine.stats()["cache"]["kind"] == "latent"
+    finally:
+        srv.shutdown()

@@ -161,7 +161,10 @@ def test_engine_generates_the_reference_tokens(params):
             >= 4 * sum(map(len, prompts)) * routed_layers)
     assert st["cache"] == {
         "kind": "latent", "row_bytes": CFG.row_width * 4, "layers": 3,
-        "pool_bytes": 3 * 6 * PAGE * CFG.row_width * 4}
+        "pool_bytes": 3 * 6 * PAGE * CFG.row_width * 4,
+        "by_leaf": {"latent": {
+            "row_bytes": CFG.row_width * 4, "positions_per_row": 1,
+            "layers": 3, "pool_bytes": 3 * 6 * PAGE * CFG.row_width * 4}}}
     assert "lane_state" not in st and st["prefix_cache"] is False
 
 

@@ -482,3 +482,47 @@ def test_mla_attn_in_bfloat16_at_a_padded_row_width():
     want = mla_decode_reference(q, rows, tail, *args, dv=32, sm_scale=0.2)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=2e-2)
+
+
+@pytest.mark.parametrize("per,kt,ts,n_rows,want", [
+    # positions 13..16 complete group 3 (position 15): one row
+    (4, 1, 13, 4, [3]),
+    # positions 8..15: groups 2 and 3, the block started on an edge
+    (4, 2, 8, 8, [2, 3]),
+    # positions 10..17: groups 2 (ends at 11) and 3 (15); 16, 17 open one
+    (4, 2, 10, 8, [2, 3]),
+    # positions 9..10 complete nothing
+    (4, 1, 9, 2, []),
+    # a window of three steps from 5: position 7 completes group 1
+    (4, 1, 5, 3, [1]),
+    # two positions a row, a page of 4 rows: rows 3, 4 (a page's edge)
+    (2, 2, 6, 4, [3, 4]),
+])
+def test_merge_tail_pages_of_a_leaf_with_a_row_a_group(per, kt, ts, n_rows,
+                                                       want):
+    """A leaf that holds ONE row a group of `per` positions (a pooled
+    index key): tail row j is row ts // per + j of the lane, and only the
+    rows that the block's positions COMPLETED are written, each exactly
+    once, into the page that holds the group."""
+    from ray_tpu.ops.paged_attention import merge_tail_pages
+
+    page_rows, w = 8 // per if per == 2 else 4, 128
+    pages = jnp.zeros((7, 1, page_rows, w), jnp.float32)
+    tail = 1.0 + jnp.arange(2 * kt, dtype=jnp.float32).reshape(
+        2, 1, kt, 1) * jnp.ones((w,), jnp.float32)
+    table = jnp.asarray([[1, 2, 3], [4, 5, 6]], jnp.int32)
+    tstart = jnp.asarray([ts, 0], jnp.int32)
+    out = merge_tail_pages(pages, tail, table, tstart, n_rows, per=per)
+    got = {}
+    for p, _, r in np.argwhere(np.asarray(out != 0).any(-1)):
+        if p == 0:
+            continue        # the trash page takes the rows not completed
+        lane, col = divmod(int(p) - 1, 3)
+        got.setdefault(lane, []).append(
+            (col * page_rows + int(r), float(out[p, 0, r, 0])))
+    rows0 = sorted(got.get(0, []))
+    assert [g for g, _ in rows0] == want
+    assert [v for _, v in rows0] == [1.0 + j for j in range(len(want))]
+    # lane 1 started at 0: its block completed n_rows // per groups
+    assert [g for g, _ in sorted(got.get(1, []))] == list(
+        range(n_rows // per))
