@@ -77,8 +77,10 @@ positions, and the engine takes its tail and its merge from that shape.
 Not served: the multi-token-prediction layer (`num_nextn_predict_layers`)
 and the vision tower.
 
-Device-side names: `kda_in_proj`, `kda_conv`, `kda_scan` (prefill) /
-`kda_update` (the decode kernel), `kda_out`, `dsa_index`, `dsa_select`,
+Device-side names: `kda_in_proj`, `kda_conv`, `kda_scan` (the prefill
+kernel: a head's state stays in VMEM across a row's chunks, position
+blocks past the row's true length get no step) / `kda_update` (the
+decode kernel), `kda_out`, `dsa_index`, `dsa_select`,
 `dsa_attn`, `mla_q`, `mla_kv_down`, `mla_absorb`, `mla_out`, `mhc_mix`,
 `state_write`, beside `moe_router`, `moe_experts`, `shared_expert`,
 `embed`, `mlp`, `lm_head`, `kv_write`.
@@ -459,7 +461,7 @@ def kda_prefill(x, lp, cfg: Glm5NextConfig, true_lens):
     [b, H, dk, dv] in `state_dtype`))."""
     h = rmsnorm(x, lp["norm1"], cfg.norm_eps)
     q, k, v, g, beta, rows = kda_inputs(h, lp, cfg, true_lens)
-    o, state = kda.kda_scan(q, k, v, g, beta, cfg.kda_chunk)
+    o, state = kda.kda_scan(q, k, v, g, beta, cfg.kda_chunk, true_lens)
     return kda_out(o, h, lp, cfg), (rows, state.astype(cfg.state_dtype))
 
 

@@ -27,7 +27,7 @@ write and `q^T S`), dk down the sublanes and dv along the lanes, so both
 sums are adds of whole registers; the per-channel vectors (a, k, q)
 arrive as [dk, H] so that a head's is a column.
 
-`kda_scan` (XLA, under `jax.named_scope("kda_scan")`): the same
+`kda_scan` (Pallas, `pallas_call(name="kda_scan")`): the same
 recurrence over whole rows in the chunked WY form.  Within a chunk of C
 positions, with G_t = g_1 + ... + g_t (per channel, from the chunk's
 start) and S_0 the state at its start,
@@ -44,16 +44,35 @@ diagonal the exponents add up to at most 0): either factor grows with
 half the chunk, so the chunk is bounded by the gate's lower bound (C / 2
 * |bound| <= 80, float32's range: `max_chunk`; 32 positions at the
 published -5; pairs above the diagonal may overflow and are masked).
-The inverse of the unit lower-triangular I + A is the finite product
-(I - A)(I + A^2)(I + A^4)... (A is nilpotent), log2(C) small matmuls.
-Everything that does not need S_0 is computed for `SUPER` positions at
-once; only `U = U0 - W S_0`, the output and the state's step run a chunk
-after the other.  The caller sets g = 0 and beta = 0 past a row's true
-length: the state returned IS the state at the true length.  All in
-float32 at `Precision.HIGHEST`: a lane keeps that state for hundreds of
-steps.
+With T = (I + A)^-1 (`_unit_lower_inverse`: A is nilpotent), W = T (beta
+K exp(G)) and U0 = T (beta V): U = U0 - W S_0.
+
+Where the bytes live.  q, k, v, g are read as [b, T, H dk]: a head's
+rows are a column block of one lane tile, no transpose is made, o
+leaves the same way.  The grid is (row, `HEADS` heads, a block of
+`POSITIONS` positions), the positions minor and sequential; the heads'
+states [dk, dv] live in a VMEM scratch from the row's first block to its
+last and reach HBM once, at the end.  Inside a grid step a `fori_loop`
+walks GROUPS of `ROWS` positions: everything that does not need the
+state is made for the group's chunks at once (`_group_parts`), as
+products with [ROWS, ROWS] matrices that are zero outside the diagonal
+blocks of C (one tile of the MXU, where a chunk alone fills a sixteenth
+of it); then the chunks one after the other, two products with the state
+each.  Every chain of products is a chain of MXU latencies, and the
+compiler issues them in the order they are written: so each stage is
+written for all the step's heads, side by side, before the next (9.5 ms
+a layer at two heads, 8.4 at four; a head after the other: 13.6).  A
+block wholly at or past a row's `lengths` gets no step: its inputs are
+not fetched, its o is zeros, the state passes through (the caller's g = 0
+and beta = 0 there make it the identity anyway; a block the length
+crosses is computed whole).  The state returned IS the state at the true
+length.  All in float32 at `Precision.HIGHEST`: a lane keeps that state
+for hundreds of steps.  Interpret mode runs any shape; the chip takes
+what it can tile (`scan_tiles`).
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -65,7 +84,9 @@ from ray_tpu.ops import flash_attention
 
 F32 = jnp.float32
 _HI = lax.Precision.HIGHEST
-SUPER = 1024        # positions whose chunk-local parts are made at once
+POSITIONS = 512     # a grid step's block of positions
+ROWS = 128          # a group: the chunks whose local parts are made at once
+HEADS = 4           # heads a grid step takes
 
 
 def _interpret() -> bool:
@@ -99,92 +120,262 @@ def kda_recurrence(q, k, v, g, beta, state=None):
     return o, state
 
 
-def _unit_lower_inverse(A):
-    """(I + A)^-1 for A [..., C, C] strictly lower triangular."""
-    C = A.shape[-1]
-    eye = jnp.eye(C, dtype=F32)
-    X = -A
-    inv = eye + X
-    n = 2
-    while n < C:
-        X = jnp.matmul(X, X, precision=_HI)
-        inv = jnp.matmul(inv, eye + X, precision=_HI)
-        n *= 2
-    return inv
+def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+    return lax.dot_general(a, b, dims, precision=_HI,
+                           preferred_element_type=F32)
 
 
-def _chunk_parts(q, k, v, g, beta, C: int):
-    """What a stretch's chunks need that does not depend on the carried
-    state.  q, k, g [b, H, n, C, dk]; v [b, H, n, C, dv]; beta [b, H, n,
-    C].  Returns (QW [.., 2 C, dk]: Qe over W, what multiplies the carried
-    state in ONE pass over it; U0 [.., C, dv]; O0 [.., C, dv]; Ke [.., C,
-    dk]; dec [.., dk])."""
-    G = jnp.cumsum(g, axis=-2)
-    rel = G - G[..., (C - 1) // 2:(C - 1) // 2 + 1, :]  # from the middle
-    up, down = jnp.exp(rel), jnp.exp(-rel)
-    low = jnp.tril(jnp.ones((C, C), bool), -1)
-    A = jnp.where(low, jnp.einsum("...td,...jd->...tj", k * up, k * down,
-                                  precision=_HI), 0.0) * beta[..., None]
-    Bm = jnp.where(low | jnp.eye(C, dtype=bool),
-                   jnp.einsum("...td,...jd->...tj", q * up, k * down,
-                              precision=_HI), 0.0)
-    T = _unit_lower_inverse(A)
-    decay = jnp.exp(G)                    # from the chunk's start: <= 1
-    W = jnp.matmul(T, k * decay * beta[..., None], precision=_HI)
-    U0 = jnp.matmul(T, v * beta[..., None], precision=_HI)
-    Qe = q * decay - jnp.matmul(Bm, W, precision=_HI)
-    O0 = jnp.matmul(Bm, U0, precision=_HI)
-    Ke = k * jnp.exp(G[..., -1:, :] - G)
-    return (jnp.concatenate([Qe, W], axis=-2), U0, O0, Ke,
-            decay[..., -1, :])
+def _unit_lower_inverse(As, C: int):
+    """(I + A)^-1 for every A [R, R] of the list `As`, each strictly
+    lower triangular inside its diagonal blocks of C and zero outside
+    them (nilpotent of index C): the finite product (I - A)(I + A^2)
+    (I + A^4)...  The R / C blocks are held SIDE BY SIDE, [C, R], and
+    multiplied from the right by the block-diagonal [R, R] form: C rows
+    pushed through the MXU's whole width where [R, R] by [R, R] pushes R
+    for the same blocks; and a power's square and the running product's
+    next factor share that right-hand side, so they are ONE product.
+    The list's chains are independent and are written level by level,
+    side by side: the order the products are issued in."""
+    R = As[0].shape[0]
+    n = R // C
+    own = (lax.broadcasted_iota(jnp.int32, (R, R), 0) // C
+           == lax.broadcasted_iota(jnp.int32, (R, R), 1) // C)
+    eye = (lax.broadcasted_iota(jnp.int32, (C, R), 0)
+           == lax.broadcasted_iota(jnp.int32, (C, R), 1) % C)
+
+    def blocks(side):             # [C, R] -> block-diagonal [R, R]
+        return jnp.where(own, jnp.concatenate([side] * n), 0.0)
+
+    Xs = [-sum(A[i * C:(i + 1) * C] for i in range(n)) for A in As]
+    invs = [X + eye for X in Xs]
+    m = 2
+    if m < C:
+        Xs = [_dot(X, blocks(X)) for X in Xs]
+    while m < C:
+        m *= 2
+        if m < C:                 # X <- X^2 beside inv <- inv (I + X)
+            both = [_dot(jnp.concatenate([X, inv]), blocks(X))
+                    for X, inv in zip(Xs, invs)]
+            Xs = [x[:C] for x in both]
+            invs = [inv + x[C:] for inv, x in zip(invs, both)]
+        else:
+            invs = [inv + _dot(inv, blocks(X)) for X, inv in zip(Xs, invs)]
+    return [blocks(inv) for inv in invs]
 
 
-def kda_scan(q, k, v, g, beta, chunk: int):
+def _chunk_sums(g, C: int):
+    """The running sum of g [R, dk] down the rows, restarted at every
+    chunk of C rows: log2(C) shifted adds (no product: the sum is exact
+    float32 adds, and the MXU is what the kernel waits on)."""
+    at = lax.broadcasted_iota(jnp.int32, g.shape, 0) % C
+    s = 1
+    while s < C:
+        g = g + jnp.where(at >= s, pltpu.roll(g, s, 0), 0.0)
+        s *= 2
+    return g
+
+
+def _column(row):
+    """A row [1, n] as a column [n, 1] (a masked sum along the lanes)."""
+    n = row.shape[-1]
+    eye = (lax.broadcasted_iota(jnp.int32, (n, n), 0)
+           == lax.broadcasted_iota(jnp.int32, (n, n), 1))
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+
+def _group_parts(ins, C: int):
+    """What a GROUP of R = n C positions needs that does not depend on
+    the carried state, for every head of the list `ins` = [(q, k, v, g,
+    beta)] (q, k, g [R, dk]; v [R, dv]; beta [R, 1]), made for the n
+    chunks at once as products with [R, R] matrices that are zero
+    outside the diagonal blocks of C (at R = 128 one tile of the MXU,
+    where a chunk alone would fill a sixteenth).  A head's: (QW: a
+    chunk's [Qd; W] [2 C, dk], what multiplies the state in one pass
+    over it; U0 [R, dv]; Ke [R, dk]; dec: a chunk's decay [dk, 1]; B
+    [R, R]), returned as five lists over the heads.  The heads are
+    independent: every stage is written for all of them before the
+    next."""
+    R, dk = ins[0][1].shape
+    n = R // C
+    row = lax.broadcasted_iota(jnp.int32, (R, R), 0)
+    col = lax.broadcasted_iota(jnp.int32, (R, R), 1)
+    own = row // C == col // C                        # the same chunk
+
+    def of_chunk(x, at):          # row `at` of every chunk, over its rows
+        x = x.reshape(n, C, x.shape[-1])[:, at:at + 1]
+        return jnp.broadcast_to(x, (n, C, x.shape[-1])).reshape(R, -1)
+
+    Gs = [_chunk_sums(g, C) for _, _, _, g, _ in ins]
+    ABs = []
+    for (q, k, _, _, _), G in zip(ins, Gs):
+        rel = G - of_chunk(G, (C - 1) // 2)           # from the middle
+        up = jnp.exp(rel)
+        ABs.append(_dot(jnp.concatenate([k * up, q * up]),
+                        k * jnp.exp(-rel), (((1,), (1,)), ((), ()))))
+    Ts = _unit_lower_inverse(
+        [jnp.where(own & (col < row), AB[:R], 0.0) * beta
+         for AB, (_, _, _, _, beta) in zip(ABs, ins)], C)
+    parts = []
+    for (q, k, v, _, beta), G, AB, T in zip(ins, Gs, ABs, Ts):
+        decay = jnp.exp(G)                # from the chunk's start: <= 1
+        last = of_chunk(G, C - 1)
+        WU = _dot(T, jnp.concatenate([k * decay * beta, v * beta], axis=1))
+        Qd = q * decay
+        QW = [jnp.concatenate([Qd[i * C:(i + 1) * C],
+                               WU[i * C:(i + 1) * C, :dk]])
+              for i in range(n)]
+        parts.append((QW, WU[:, dk:], k * jnp.exp(last - G),
+                      [_column(jnp.exp(G[(i + 1) * C - 1:(i + 1) * C]))
+                       for i in range(n)],
+                      jnp.where(own & (col <= row), AB[R:], 0.0)))
+    return [list(x) for x in zip(*parts)]             # a list a part
+
+
+def _scan_kernel(lens_ref,                            # scalar prefetch
+                 q_ref, k_ref, v_ref, g_ref, beta_ref,
+                 o_ref, s_ref, state, *, C: int, R: int, heads: int):
+    """One position block of `heads` heads of one row.  q_ref, k_ref,
+    g_ref [1, P, heads dk]; v_ref, o_ref [1, P, heads dv]; beta_ref
+    [1, P, H]; s_ref [1, heads, dk, dv]; `state` the heads' [dk, dv] in
+    VMEM, which lives across the row's position blocks."""
+    i, j = pl.program_id(0), pl.program_id(2)
+    P, H = beta_ref.shape[1], beta_ref.shape[2]
+    dk, dv = state.shape[1], state.shape[2]
+    head0 = pl.program_id(1) * heads
+
+    @pl.when(j == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+
+    live = j * P < lens_ref[i]
+
+    @pl.when(live)
+    def _():
+        lane = lax.broadcasted_iota(jnp.int32, (R, H), 1)
+
+        def group(r, S):
+            rows = pl.ds(pl.multiple_of(r * R, R), R)
+            betas = beta_ref[0, rows, :]
+            ins = []
+            for h in range(heads):
+                kk = slice(h * dk, (h + 1) * dk)
+                vv = slice(h * dv, (h + 1) * dv)
+                ins.append((
+                    q_ref[0, rows, kk], k_ref[0, rows, kk],
+                    v_ref[0, rows, vv], g_ref[0, rows, kk],
+                    jnp.sum(jnp.where(lane == head0 + h, betas, 0.0),
+                            axis=1, keepdims=True)))
+            QW, U0, Ke, dec, Bm = _group_parts(ins, C)
+            # the chunks, from their state: `U = U0 - W S` beside the
+            # rows `Qd S`, then `S <- dec S + Ke^T U`
+            S = list(S)
+            qs, us = ([[] for _ in range(heads)] for _ in range(2))
+            for c in range(R // C):
+                at = slice(c * C, (c + 1) * C)
+                xs = [_dot(QW[h][c], S[h]) for h in range(heads)]
+                for h in range(heads):                # x [2 C, dv]
+                    qs[h].append(xs[h][:C])
+                    us[h].append(U0[h][at] - xs[h][C:])
+                ups = [_dot(Ke[h][at], us[h][-1], (((0,), (0,)), ((), ())))
+                       for h in range(heads)]
+                S = [dec[h][c] * S[h] + ups[h] for h in range(heads)]
+            for h in range(heads):                    # o = Qd S + B U
+                o_ref[0, rows, h * dv:(h + 1) * dv] = jnp.concatenate(
+                    qs[h]) + _dot(Bm[h], jnp.concatenate(us[h]))
+            return tuple(S)
+
+        S = lax.fori_loop(0, P // R, group,
+                          tuple(state[h] for h in range(heads)))
+        for h in range(heads):
+            state[h] = S[h]
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        s_ref[0] = state[...]
+
+
+def heads_a_step(H: int) -> int:
+    """The heads a grid step takes: the largest divisor of H that is at
+    most `HEADS`."""
+    return max(h for h in range(1, HEADS + 1) if H % h == 0)
+
+
+def scan_tiles(dk: int, dv: int, chunk: int) -> None:
+    """What the compiled kernel can tile: a head's block of the [b, T,
+    H dk] views is a column block of whole lane tiles, a chunk whole
+    sublane tiles.  (Interpret mode runs any shape.)"""
+    if dk % 128 or dv % 128 or chunk % 8:
+        raise ValueError(
+            f"kda_scan on the chip takes dk and dv multiples of 128 and "
+            f"a chunk a multiple of 8; got dk={dk}, dv={dv}, "
+            f"chunk={chunk}")
+
+
+def kda_scan(q, k, v, g, beta, chunk: int, lengths=None):
     """The recurrence over whole rows, chunked.
 
     q, k [b, T, H, dk] (normalised; q scaled); v [b, T, H, dv]; g
     [b, T, H, dk] float32 (the log decay, <= 0, ZERO past a row's true
     length); beta [b, T, H] float32 (ZERO past it); `chunk` positions a
-    chunk (`max_chunk` bounds it).  Returns (o [b, T, H, dv] float32,
-    the state after the last position [b, H, dk, dv] float32)."""
+    chunk (`max_chunk` bounds it); `lengths` [b] int32 or None: a
+    position block wholly at or past a row's length gets no step (its o
+    is 0, the state passes through: what g = 0 and beta = 0 there give
+    anyway).  Returns (o [b, T, H, dv] float32, the state after the last
+    position [b, H, dk, dv] float32)."""
     b, T, H, dk = k.shape
     dv = v.shape[-1]
     C = min(chunk, T)
-    big = min(SUPER, -(-T // C) * C)          # a stretch: whole chunks
-    big -= big % C
-    pad = -T % big
-    if pad:
-        q, k, v, g, beta = (
-            jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
-            for a in (q, k, v, g, beta))
-    ns, n = (T + pad) // big, big // C
-    with jax.named_scope("kda_scan"):
-        def stretches(a):       # [b, T, H, ...] -> [ns, b, H, n, C, ...]
-            a = a.reshape(b, ns, n, C, H, *a.shape[3:])
-            return jnp.moveaxis(jnp.moveaxis(a, 4, 2), 1, 0)
+    interpret = _interpret()
+    if not interpret:
+        scan_tiles(dk, dv, C)
+    chunks = -(-T // C)
+    R = C * max(1, min(ROWS // C, POSITIONS // C, chunks))  # a group
+    P = R * max(1, min(POSITIONS // R, -(-T // R)))   # a position block
+    heads = heads_a_step(H)
+    pad = -T % P
+    q, k, v, g = (jnp.pad(a.astype(F32), ((0, 0), (0, pad), (0, 0), (0, 0))
+                          ).reshape(b, T + pad, -1) for a in (q, k, v, g))
+    beta = jnp.pad(beta.astype(F32), ((0, 0), (0, pad), (0, 0)))
+    if lengths is None:
+        lengths = jnp.full((b,), T, jnp.int32)
 
-        def stretch(S, xs):
-            qs, ks, vs, gs, bs = (a.astype(F32) for a in xs)
-            parts = _chunk_parts(qs, ks, vs, gs, bs, C)
+    def held(i, j, lens):         # past the length: the block it ends in
+        return jnp.minimum(j, jnp.maximum(lens[i] - 1, 0) // P)
 
-            def one(S, p):                    # a chunk, from its state S
-                QW, U0, O0, Ke, dec = p
-                qs = jnp.matmul(QW, S, precision=_HI)        # [.., 2 C, dv]
-                U = U0 - qs[..., C:, :]
-                S = dec[..., None] * S + jnp.einsum(
-                    "bhcd,bhcv->bhdv", Ke, U, precision=_HI)
-                return S, qs[..., :C, :] + O0
+    def block(i, h, j, lens):
+        return (i, held(i, j, lens), h)
 
-            S, o = lax.scan(one, S, tuple(jnp.moveaxis(p, 2, 0)
-                                          for p in parts))
-            return S, jnp.moveaxis(o, 0, 2)   # [b, H, n, C, dv]
-
-        S, o = lax.scan(stretch, jnp.zeros((b, H, dk, dv), F32),
-                        tuple(stretches(a) for a in (q, k, v, g, beta)))
-        # [ns, b, H, n, C, dv] -> [b, T, H, dv]
-        o = jnp.moveaxis(o, 0, 1).reshape(b, ns, H, n * C, dv)
-        o = jnp.moveaxis(o, 2, 3).reshape(b, ns * big, H, dv)
-    return o[:, :T], S
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, H // heads, (T + pad) // P),
+        in_specs=[pl.BlockSpec((1, P, heads * dk), block),
+                  pl.BlockSpec((1, P, heads * dk), block),
+                  pl.BlockSpec((1, P, heads * dv), block),
+                  pl.BlockSpec((1, P, heads * dk), block),
+                  pl.BlockSpec((1, P, H), lambda i, h, j, lens:
+                               (i, held(i, j, lens), 0))],
+        out_specs=[pl.BlockSpec((1, P, heads * dv),
+                                lambda i, h, j, lens: (i, j, h)),
+                   pl.BlockSpec((1, heads, dk, dv),
+                                lambda i, h, j, lens: (i, h, 0, 0))],
+        scratch_shapes=[pltpu.VMEM((heads, dk, dv), F32)],
+    )
+    o, S = pl.pallas_call(
+        functools.partial(_scan_kernel, C=C, R=R, heads=heads),
+        name="kda_scan",
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((b, T + pad, H * dv), F32),
+                   jax.ShapeDtypeStruct((b, H, dk, dv), F32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=64 << 20),
+        interpret=interpret,
+    )(lengths.astype(jnp.int32), q, k, v, g, beta)
+    return o[:, :T].reshape(b, T, H, dv), S
 
 
 def _update_kernel(lanes_ref, layer_ref,              # scalar prefetch
@@ -275,3 +466,25 @@ def update_cost(H: int, dk: int, dv: int, lane_steps: float
     two multiply-adds, a multiply-add)."""
     nbytes = 2 * 4 * H * dk * dv + 4 * H * (3 * dk + 2 * dv + 1)
     return 7.0 * H * dk * dv * lane_steps, float(nbytes) * lane_steps
+
+
+def scan_cost(H: int, dk: int, dv: int, chunk: int, positions: float,
+              rows: float = 0.0) -> tuple[float, float]:
+    """(flops, bytes) the `kda_scan` calls NEED for `positions` true
+    positions in `rows` rows: q, k, g, v in and o out once (float32),
+    beta, the state written a row; and a (head, chunk)'s products as the
+    kernel forms them (A and B; the 2 (log2(chunk) - 1) products of the
+    inverse; W and U0; `[Qd; W] S`; `B U`; `Ke^T U`), a multiply-add two
+    operations.  Six bfloat16 passes a float32 product are the chip's
+    price, not the algorithm's: not counted."""
+    C = chunk
+    inverse = 2 * max(0, (C - 1).bit_length() - 1)
+    a_chunk = (2 * C * C * (2 * dk)                   # A, B
+               + inverse * 2 * C ** 3
+               + 2 * C * C * (dk + dv)                # W, U0
+               + 2 * (2 * C) * dk * dv                # [Qd; W] S
+               + 2 * C * C * dv                       # B U
+               + 2 * C * dk * dv)                     # Ke^T U
+    nbytes = (4 * H * (3 * dk + 2 * dv + 1) * positions
+              + 4 * H * dk * dv * rows)
+    return float(a_chunk) * H * positions / C, float(nbytes)

@@ -1,7 +1,8 @@
 """ops/kda.py (the gated delta rule with a per-channel decay): the
-chunked scan and the one-step kernel (interpret mode) against the
+scan kernel and the one-step kernel (interpret mode) against the
 token-by-token recurrence, at lengths that end mid-chunk, across
-stretches, with lanes that hold no request."""
+position blocks, with blocks past a row's length that get no step, with
+lanes that hold no request."""
 from __future__ import annotations
 
 import jax
@@ -54,14 +55,69 @@ def test_kda_scan_equals_the_recurrence_at_the_true_length(T, chunk, lens):
 
 
 def test_kda_scan_carries_the_state_between_stretches(monkeypatch):
-    """The chunk-local parts are made a stretch at a time; the state
-    crosses the stretches' edges as it crosses the chunks'."""
-    monkeypatch.setattr(kda, "SUPER", 16)
+    """The state lives in the kernel's scratch from a position block to
+    the next (a block of 16, two chunks of 8): it crosses the blocks'
+    edges as it crosses the chunks'."""
+    monkeypatch.setattr(kda, "POSITIONS", 16)
     q, k, v, g, beta = _inputs(1, 56, lens=[50], seed=3)
     o, S = kda.kda_scan(q, k, v, g, beta, chunk=8)
     want_o, want_S = kda.kda_recurrence(q[0, :50], k[0, :50], v[0, :50],
                                         g[0, :50], beta[0, :50])
     assert _rel(o[0, :50], want_o) < 2e-5 and _rel(S[0], want_S) < 2e-5
+
+
+@pytest.mark.parametrize("T,lens", [
+    (56, [50, 56]),     # the last block crossed; a full row
+    (64, [32, 48]),     # lengths on block edges
+    (48, [1, 48]),      # a row of one token
+    (64, [9, 64]),      # a row shorter than one block beside a full one
+])
+def test_blocks_past_a_rows_length_get_no_step(monkeypatch, T, lens):
+    """With `lengths`: the same o at the true positions and the same
+    state as without, and zeros from the first block that starts at or
+    past the length (blocks of 16 positions)."""
+    monkeypatch.setattr(kda, "POSITIONS", 16)
+    q, k, v, g, beta = _inputs(2, T, lens=lens, seed=13)
+    o, S = kda.kda_scan(q, k, v, g, beta, chunk=8)
+    o_len, S_len = kda.kda_scan(q, k, v, g, beta, chunk=8,
+                                lengths=jnp.asarray(lens, jnp.int32))
+    assert _rel(S_len, S) < 1e-6
+    for i, n in enumerate(lens):
+        assert _rel(o_len[i, :n], o[i, :n]) < 1e-6
+        past = -(-n // 16) * 16
+        assert float(jnp.abs(o_len[i, past:]).sum()) == 0.0
+        want_o, want_S = kda.kda_recurrence(
+            q[i, :n], k[i, :n], v[i, :n], g[i, :n], beta[i, :n])
+        assert _rel(o_len[i, :n], want_o) < 2e-5
+        assert _rel(S_len[i], want_S) < 2e-5
+
+
+@pytest.mark.parametrize("H,heads", [(3, 1), (6, 3), (4, 2), (5, 5)])
+def test_a_grid_step_takes_a_divisor_of_the_heads(monkeypatch, H, heads):
+    """`HEADS` is an upper bound: H = 3 under 2 heads a step runs one a
+    step, and every head's state and output are the recurrence's."""
+    monkeypatch.setattr(kda, "HEADS", {3: 2, 6: 4, 4: 2, 5: 8}[H])
+    assert kda.heads_a_step(H) == heads
+    q, k, v, g, beta = _inputs(1, 24, H=H, seed=17)
+    o, S = kda.kda_scan(q, k, v, g, beta, chunk=8)
+    want_o, want_S = kda.kda_recurrence(q[0], k[0], v[0], g[0], beta[0])
+    assert _rel(o[0], want_o) < 2e-5 and _rel(S[0], want_S) < 2e-5
+
+
+def test_the_chip_takes_whole_tiles_and_names_the_shape_otherwise():
+    kda.scan_tiles(128, 128, 32)
+    kda.scan_tiles(256, 128, 8)
+    for dk, dv, chunk in [(16, 16, 8), (128, 64, 32), (128, 128, 4)]:
+        with pytest.raises(ValueError, match=f"dk={dk}, dv={dv}, "
+                                             f"chunk={chunk}"):
+            kda.scan_tiles(dk, dv, chunk)
+
+
+def test_off_the_interpreter_a_debug_width_is_refused(monkeypatch):
+    monkeypatch.setattr(kda, "_interpret", lambda: False)
+    q, k, v, g, beta = _inputs(1, 16)
+    with pytest.raises(ValueError, match="dk=16"):
+        kda.kda_scan(q, k, v, g, beta, chunk=8)
 
 
 def test_the_strongest_decay_the_gate_allows_stays_finite():
@@ -77,10 +133,15 @@ def test_the_strongest_decay_the_gate_allows_stays_finite():
     assert bool(jnp.all(jnp.isfinite(S))) and _rel(S[0], want_S) < 2e-5
 
 
-def test_the_unit_lower_inverse_is_the_inverse():
-    A = jnp.tril(jax.random.normal(jax.random.PRNGKey(1), (3, 16, 16)), -1)
-    inv = kda._unit_lower_inverse(A * 0.3)
-    eye = jnp.eye(16)
+@pytest.mark.parametrize("n,C", [(16, 16), (32, 8), (24, 1), (12, 6)])
+def test_the_unit_lower_inverse_is_the_inverse(n, C):
+    """A group's matrix: strictly lower triangular inside its diagonal
+    blocks of C, zero outside them."""
+    at = jnp.arange(n)
+    own = (at[:, None] // C == at[None, :] // C) & (at[None, :] < at[:, None])
+    A = jnp.where(own, jax.random.normal(jax.random.PRNGKey(1), (n, n)), 0)
+    inv, = kda._unit_lower_inverse([A * 0.3], C)
+    eye = jnp.eye(n)
     assert float(jnp.abs(inv @ (eye + A * 0.3) - eye).max()) < 1e-5
 
 
@@ -133,3 +194,25 @@ def test_update_cost_counts_the_state_twice():
     assert by == 10 * (2 * 4 * 64 * 128 * 128 + 4 * 64 * (3 * 128 + 257))
     assert fl == 10 * 7.0 * 64 * 128 * 128
     assert np.isclose(by / 10, 8.55e6, rtol=0.01)
+
+
+def test_scan_cost_is_the_rows_in_and_out_and_the_chunks_products():
+    """At the served widths: 5 x 4 bytes a channel a position beside
+    beta, the state a row; a (head, chunk) is ~5 MFLOP, two thirds of it
+    the two products with the [128, 128] state."""
+    fl, by = kda.scan_cost(64, 128, 128, 32, 8192.0, rows=1.0)
+    assert by == 8192 * 4 * 64 * (5 * 128 + 1) + 4 * 64 * 128 * 128
+    assert np.isclose(by, 1.35e9, rtol=0.01)
+    C, d = 32, 128
+    chunk = (2 * 2 * C * C * d            # A, B
+             + 8 * 2 * C ** 3             # the inverse's products
+             + 2 * 2 * C * C * d          # W, U0
+             + 2 * 2 * C * d * d          # [Qd; W] S
+             + 2 * C * C * d              # B U
+             + 2 * C * d * d)             # Ke^T U
+    assert fl == chunk * 64 * 8192 / 32
+    assert np.isclose(chunk, 4.98e6, rtol=0.01)
+    # a chunk of 8: the inverse is (I - A)(I + A^2)(I + A^4), 4 products
+    fl8, _ = kda.scan_cost(1, 16, 16, 8, 8.0)
+    assert fl8 == (4 * 2 * 8 * 8 * 16 + 4 * 2 * 8 ** 3
+                   + 2 * 8 * 8 * 16 + 3 * 2 * 8 * 16 * 16)
