@@ -18,6 +18,14 @@ groups share is visited by each in turn and stored under a row mask
 (the technique of jax's megablox `gmm`; this one keeps the whole
 contraction in one block and so needs no accumulator).
 
+Both tiles follow the call's shape and nothing else (`row_tile`,
+`col_tile`; the comment over the constants says why): a call of few
+rows walks 128-row tiles, a call of many rows 256-row tiles, and both
+fetch a hit expert's weights in blocks of up to 1,024 columns (8 MiB at
+`k` = 4096).  The kernel asks for the VMEM its blocks need
+(`vmem_bytes`).  An output element is one whole-`k` contraction inside
+one block, so no tile changes a bit of it.
+
 The visit axis of the grid is bounded by the number of visits that are
 work, a value the device holds (`visits`' `total`, as `paged_attn`'s
 grid is bounded by its plan's count): the list has the static length
@@ -42,12 +50,31 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops import flash_attention
 
-# Row tile: decode sends a few rows to each group (64 lanes x 4 over 64
-# experts), so a small tile wastes the least of the MXU's rows on other
-# groups' rows; 16 is bfloat16's sublane tile.  Prefill sends hundreds.
-ROW_TILE_SMALL = 16
+# The tiles follow the call's shape (`row_tile`, `col_tile`); the kernel
+# alone on a v5e picked them (PERF.md section 5, PR 43).
+#
+# Rows.  Pallas has ONE weight block in flight, and a group whose rows
+# cross a row-tile boundary is visited twice, the second visit
+# multiplying with no copy behind it.  Few rows (decode: 64 lanes x 4 over
+# 64 experts; a prefill wave of up to SMALL_ROWS rows) are bound by the
+# copy of the weights, so their row tile is the MXU's 128 rows: a 16-row
+# tile had thirteen such visits in LFM2's decode and fed the MXU no
+# faster, which loads a weight tile for 16 rows as for 128.  Many rows
+# are bound by the multiply and walk 256-row tiles.
+#
+# Columns.  1,024 read fastest or tied at every served shape.  Narrower
+# blocks pay a gap after each at `n` = 4096 (0.3-0.6 us a 512-column
+# block, pieces of 1 KB that lie 8 KB apart; none at 3072 or 2048), and
+# re-read the row tile `n / tn` times where the rows are many.  Wider ones pay their own copy bare once a call, the first
+# block's, which hides behind nothing (20 us of a 1.08 ms call at 16
+# MiB), and Mosaic compiles a whole-expert call in 3 s against 1.
+# WEIGHT_BLOCK_BYTES only keeps two buffers of a block inside VMEM where
+# `k` is far larger than any served.
+ROW_TILE_SMALL = 128
 ROW_TILE_LARGE = 256
-COL_TILE = 512
+SMALL_ROWS = 1024
+COL_TILE = 1024
+WEIGHT_BLOCK_BYTES = 16 << 20
 
 
 def _on_tpu() -> bool:
@@ -61,8 +88,29 @@ def _interpret() -> bool:
 
 
 def row_tile(m: int) -> int:
-    """The row tile `gmm` walks `m` rows by."""
-    return ROW_TILE_SMALL if m <= 64 * ROW_TILE_SMALL else ROW_TILE_LARGE
+    """The row tile `gmm` walks `m` rows by (fewer rows than a small
+    tile are ONE tile of whole sublane tiles)."""
+    if m > SMALL_ROWS:
+        return ROW_TILE_LARGE
+    return min(ROW_TILE_SMALL, m + -m % 16)
+
+
+def col_tile(k: int, n: int, itemsize: int) -> int:
+    """The column tile `gmm` walks [G, k, n] weights by: the widest
+    divisor of `n` in lane tiles (`n` itself where there is none) up to
+    COL_TILE whose weight block `k x tn x itemsize` stays within
+    WEIGHT_BLOCK_BYTES, the narrowest where none does."""
+    tiles = [t for t in range(128, n + 1, 128) if n % t == 0] or [n]
+    return max([t for t in tiles if t <= COL_TILE
+                and k * t * itemsize <= WEIGHT_BLOCK_BYTES] or tiles[:1])
+
+
+def vmem_bytes(tm: int, k: int, tn: int, itemsize: int) -> int:
+    """What `moe_gmm` asks of VMEM for its blocks: the weight block, the
+    row tile and the output tile, each double-buffered, the float32
+    product before it is stored, and room for the compiler's own."""
+    blocks = (k * tn + tm * k + tm * tn) * itemsize
+    return 2 * blocks + tm * tn * 4 + (8 << 20)
 
 
 def visits_static(m: int, G: int) -> int:
@@ -140,7 +188,8 @@ def _gmm_pallas(rows, weights, group_sizes, tm: int, tn: int):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), rows.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_bytes(tm, k, tn, weights.dtype.itemsize)),
         interpret=_interpret(),
     )(g, tile, offsets, rows, weights)
 
@@ -163,7 +212,7 @@ def gmm(rows: jnp.ndarray, weights: jnp.ndarray, group_sizes: jnp.ndarray,
         out = out.astype(rows.dtype)
     else:
         tm = row_tile(m)
-        tn = COL_TILE if n % COL_TILE == 0 else 128
+        tn = col_tile(k, n, weights.dtype.itemsize)
         pad = -m % tm
         x = jnp.pad(rows, ((0, pad), (0, 0))) if pad else rows
         out = _gmm_pallas(x, weights, group_sizes, tm, tn)[:m]

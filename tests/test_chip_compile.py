@@ -127,12 +127,18 @@ def test_flash_attention_compiles(one_chip, compiled_kernels,
     (32, 65536, 2048, 4096),
     (32, 256, 4096, 4096),    # its decode: 32 lanes x 8
     (32, 256, 2048, 4096),
+    (36, 512, 4096, 4096),    # GLM-5.3-Flash, 36 of 288 held: 64 lanes x 8
+    (36, 512, 2048, 4096),
 ])
 def test_grouped_matmul_compiles(one_chip, compiled_kernels, G, m, k, n):
-    """ops/grouped_matmul.py's `moe_gmm` at the served widths, a 16-row
+    """ops/grouped_matmul.py's `moe_gmm` at the served widths, a 128-row
     tile for decode and a 256-row one for prefill: the visit axis of its
     grid is bounded by a count the device holds, beside the static
-    column axis, and the chip's compiler takes it."""
+    column axis, and the chip's compiler takes it.  Through `gmm`
+    itself: the column tile is `col_tile`'s (1,024 columns: 8 MiB a
+    block of a `d` = 4096 model, past the compiler's default limit) and
+    the VMEM asked for `vmem_bytes`', so a block Mosaic has no room for
+    is refused here."""
     from ray_tpu.ops.grouped_matmul import gmm
 
     def s(shape, dtype=jnp.bfloat16):

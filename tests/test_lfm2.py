@@ -197,8 +197,10 @@ def test_gmm_visits_no_empty_group():
     sizes = jnp.asarray([0, 0, 10, 0, 20, 1, 0, 5], jnp.int32)
     g, tile, _, total = grouped_matmul.visits(sizes, 48, 16)
     assert set(np.asarray(g).tolist()) == {2, 4, 5, 7}
-    assert g.shape[0] == 48 // 16 + 8 - 1 == grouped_matmul.visits_static(
-        48, 8)
+    assert g.shape[0] == 48 // 16 + 8 - 1
+    # gmm itself walks 48 rows as one tile, 384 as three of 128
+    assert grouped_matmul.visits_static(48, 8) == 1 + 8 - 1
+    assert grouped_matmul.visits_static(384, 8) == 3 + 8 - 1
     assert (np.diff(np.asarray(tile)) >= 0).all()
     # rows 0-9 | 10-29 | 30 | 31-35: tiles 0 | 0, 1 | 1 | 1, 2
     assert int(total) == 6
@@ -243,7 +245,8 @@ def _gmm_over_the_padded_list(rows, weights, group_sizes, tm, tn):
     return jnp.where(held, out, jnp.zeros((), out.dtype))
 
 
-@pytest.mark.parametrize("m", [48, 2048], ids=["tile16", "tile256"])
+@pytest.mark.parametrize("m", [48, 384, 2048],
+                         ids=["one_tile", "tile128", "tile256"])
 @pytest.mark.parametrize("sizes", [
     lambda m: [m // 8] * 8,                     # the list is full
     lambda m: [m // 16, 0, m // 8, 0, 0, m // 16, 0, 0],  # 3/4: nobody's
@@ -260,6 +263,73 @@ def test_gmm_walks_the_visits_that_are_work_bit_for_bit(m, sizes):
     got = grouped_matmul.gmm(x, w, sizes, impl="pallas")
     tm = grouped_matmul.row_tile(m)
     want = _gmm_over_the_padded_list(x, w, sizes, tm, 128)
+    got, want = np.asarray(got), np.asarray(want)
+    assert not np.isnan(got.astype(np.float32)).any()
+    assert (got.view(np.uint16) == want.view(np.uint16)).all()
+    assert (got[int(sizes.sum()):].astype(np.float32) == 0).all()
+
+
+@pytest.mark.parametrize("G,m,k,n", [
+    (64, 256, 2048, 3072), (64, 256, 1536, 2048),     # LFM2 decode
+    (32, 256, 4096, 4096), (32, 256, 2048, 4096),     # sarvam decode
+    (36, 512, 4096, 4096), (36, 512, 2048, 4096),     # GLM decode
+    (64, 65536, 2048, 3072), (64, 4096, 1536, 2048),  # prefill waves
+    (32, 65536, 4096, 4096),
+])
+def test_gmm_col_tile_fits_and_divides(G, m, k, n):
+    """The column tile of every served shape divides `n` by lane tiles
+    and is the WIDEST such divisor up to COL_TILE whose weight block
+    stays within the byte budget: 1,024 columns at every served shape,
+    few rows or many (the kernel-alone table of PERF.md section 5), 128
+    rows a tile up to 1,024 rows and 256 above; and what the blocks ask
+    of VMEM leaves a v5e's 128 MiB room."""
+    tn = grouped_matmul.col_tile(k, n, 2)
+    assert n % tn == 0 and tn % 128 == 0
+    budget = grouped_matmul.WEIGHT_BLOCK_BYTES
+    assert k * tn * 2 <= budget
+    wider = [t for t in range(tn + 128, n + 1, 128) if n % t == 0]
+    assert all(t > grouped_matmul.COL_TILE or k * t * 2 > budget
+               for t in wider)
+    assert tn == 1024
+    tm = grouped_matmul.row_tile(m)
+    assert tm == (128 if m <= 1024 else 256)
+    assert 2 * k * tn * 2 < grouped_matmul.vmem_bytes(tm, k, tn, 2) \
+        < 48 << 20
+
+
+def test_gmm_col_tile_keeps_two_blocks_inside_vmem():
+    """Where `k` is far larger than any served, the byte budget and not
+    COL_TILE bounds the block; where nothing fits, the narrowest tile."""
+    assert grouped_matmul.col_tile(16384, 4096, 2) == 512
+    assert grouped_matmul.col_tile(1 << 20, 4096, 2) == 128
+    assert grouped_matmul.col_tile(128, 200, 2) == 200   # no lane tile
+
+
+@pytest.mark.parametrize("n,budget,tn", [(256, None, 256), (768, None, 768),
+                                         (768, 384 * 128 * 2, 384),
+                                         (2048, None, 1024)],
+                         ids=["n256", "n768", "n768_by_384", "n2048_by_1024"])
+@pytest.mark.parametrize("sizes", [
+    [6] * 8,                            # the list is full
+    [3, 0, 6, 0, 0, 3, 0, 0],           # 3/4 of the rows: nobody's
+    [0, 0, 0, 0, 0, 0, 1, 0],           # one row
+    [0] * 8,                            # total = 0: no step runs
+], ids=["full", "a_quarter", "one_row", "none"])
+def test_gmm_wide_column_tile_changes_no_bit(monkeypatch, sizes, n, budget,
+                                             tn):
+    """An output element is one whole-`k` contraction inside one block,
+    so the block's width changes no bit: `gmm` on the tile its rule
+    picks (a whole group's weights, what a small budget leaves, or
+    COL_TILE columns of a wider group) against the 128-wide form over
+    the padded list."""
+    if budget is not None:
+        monkeypatch.setattr(grouped_matmul, "WEIGHT_BLOCK_BYTES", budget)
+    assert grouped_matmul.col_tile(128, n, 2) == tn
+    sizes = jnp.asarray(sizes, jnp.int32)
+    x = jax.random.normal(jax.random.PRNGKey(0), (48, 128), jnp.bfloat16)
+    w = jax.random.normal(jax.random.PRNGKey(1), (8, 128, n), jnp.bfloat16)
+    got = grouped_matmul.gmm(x, w, sizes, impl="pallas")
+    want = _gmm_over_the_padded_list(x, w, sizes, 16, 128)
     got, want = np.asarray(got), np.asarray(want)
     assert not np.isnan(got.astype(np.float32)).any()
     assert (got.view(np.uint16) == want.view(np.uint16)).all()
@@ -292,13 +362,13 @@ def test_routed_ffn_counts_the_visits_that_are_work(params, rows, experts,
     real = sum((off[i + 1] - 1) // tm - off[i] // tm + 1
                for i in range(hi - lo) if sizes[i])
     g, tile, _, total = grouped_matmul.visits(
-        jnp.asarray(sizes, jnp.int32), m, tm)
+        jnp.asarray(sizes, jnp.int32), m + -m % tm, tm)
     assert int(counts[3]) == int(total) == real
     if real:
         assert real == len(set(zip(np.asarray(g).tolist(),
                                    np.asarray(tile).tolist())))
     assert int(counts[2]) == sizes.sum()
-    static = m // tm + (hi - lo) - 1
+    static = -(-m // tm) + (hi - lo) - 1
     assert real <= static == lfm2.routed.routed_visits(CFG, rows, experts)
 
 
