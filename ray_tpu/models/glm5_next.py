@@ -4,9 +4,9 @@ SELECTION, every sublayer wrapped in a multi-stream residual (mHC), with
 routed experts and a shared expert (`model_type` `glm5_next_text`, e.g.
 GLM-5.3-Flash), served.  This module gives the serving seam
 (`ray_tpu.models.serving_model`) what `serve/llm.LLMEngine` runs.  It has
-none of the optional capabilities (`SERVING_CAPS` is empty): a lane
-carries a state matrix a head a KDA layer that no page holds, and the
-pool is a latent row a token beside a pooled index key a group.
+none of the optional capabilities (`serving_spec`'s `caps` is empty): a
+lane carries a state matrix a head a KDA layer that no page holds, and
+the pool is a latent row a token beside a pooled index key a group.
 
 The equations (h = RMSNorm(.), eps `norm_eps`; what the published keys
 leave open is marked "assumed" and lives in ONE function here and ONE in
@@ -88,6 +88,7 @@ decode kernel), `kda_out`, `dsa_index`, `dsa_select`,
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -97,12 +98,11 @@ from jax import lax
 from ray_tpu.models import routed
 from ray_tpu.models.llama import embed_lookup, rmsnorm, scatter_rows
 from ray_tpu.models.routed import route, shared_ffn
+from ray_tpu.models.serving import ServingSpec, merged
 from ray_tpu.ops import kda, sparse_attention as dsa, ssm
 from ray_tpu.ops.norms import layernorm
 from ray_tpu.ops.paged_attention import lanes_live
 
-SERVING_CAPS: frozenset = frozenset()
-CACHE_KIND = "latent"
 KDA = "linear_attention"
 DSA = "deepseek_sparse_attention"
 DENSE, SPARSE = "dense", "sparse"
@@ -187,40 +187,63 @@ def serving_configs() -> dict[str, Glm5NextConfig]:
     }
 
 
-def lane_state_layers(cfg: Glm5NextConfig) -> int:
-    """Layers whose per-lane state no page holds (the KDA layers)."""
-    return cfg.count(KDA)
+# What the sparse layers report of their learned selection, a live
+# lane's every decode step, x those layers.
+DSA_COUNTERS = {
+    "dsa_rows_context": "Rows in a lane's context at a sparse layer's "
+                        "decode step, summed over live lanes, steps and "
+                        "sparse layers",
+    "dsa_groups_scored": "Complete groups the indexer scored, summed "
+                         "likewise",
+    "dsa_rows_selected": "Rows the selection attended, summed likewise",
+}
 
 
-def routed_layers(cfg: Glm5NextConfig) -> int:
-    return cfg.ffn_types.count(SPARSE)
+def _decode_work(cfg: Glm5NextConfig, rows, k: int) -> tuple[dict, dict]:
+    """One decode window of `k` steps over live lanes that start it on
+    `rows` cached rows each: `kda_update`'s lane-steps and what the
+    selection read (`ops/sparse_attention.selection_counts`)."""
+    sel = [0, 0, 0]         # in DSA_COUNTERS' order
+    for r in rows:
+        for ctx in range(r + 1, r + 1 + k):
+            scored, kept = dsa.selection_counts(ctx, cfg.index_pool,
+                                                cfg.index_topk)
+            sel[0] += ctx
+            sel[1] += scored
+            sel[2] += kept
+    work = dict(zip(DSA_COUNTERS, (n * cfg.count(DSA) for n in sel)))
+    return merged(ssm.update_work(cfg.count(KDA), len(rows), k),
+                  (work, work))
 
 
-def routed_visits(cfg: Glm5NextConfig, rows: int) -> int:
-    return routed.routed_visits(cfg, rows, cfg.experts_held)
-
-
-def scan_chunk(cfg: Glm5NextConfig) -> int:
-    """Positions a chunk of `kda_scan` (the seam's declaration: the
-    engine counts the scan's chunks and `kda_update`'s lane-steps)."""
-    return cfg.kda_chunk
-
-
-def selection(cfg: Glm5NextConfig) -> tuple[int, int, int]:
-    """(layers that read their pool through a selection, positions a
-    pooled index key, the selection's size in tokens): the seam's
-    declaration, from which the engine counts rows in context, groups
-    scored and rows selected (`ops/sparse_attention.selection_counts`)."""
-    return cfg.count(DSA), cfg.index_pool, cfg.index_topk
-
-
-def prefill_state_bytes(cfg: Glm5NextConfig) -> int:
-    """Bytes of lane state ONE prefill row hands the scatter program."""
+def serving_spec(cfg: Glm5NextConfig) -> ServingSpec:
+    """No optional capability.  The KDA layers keep a state matrix a
+    head, which `kda_scan` fills a prefill (in chunks of `kda_chunk`)
+    and `kda_update` updates a decode step, beside a convolution's last
+    rows; a sparse layer an index key in the making: the bytes of all
+    that ONE prefill row hands the scatter program.  The sparse
+    prefill attention is `dsa.masked_prefill_attention`, not
+    `flash_fwd`: no `prefill_attn_blocks`."""
+    n_kda = cfg.count(KDA)
     kda_layer = (cfg.kda_inner * cfg.kda_head_dim
                  * jnp.dtype(cfg.state_dtype).itemsize
                  + (cfg.conv_kernel - 1) * 3 * cfg.kda_inner
                  * jnp.dtype(cfg.dtype).itemsize)
-    return cfg.count(KDA) * kda_layer + cfg.count(DSA) * 4 * cfg.index_dim
+    return ServingSpec(
+        lane_state_layers=n_kda,
+        prefill_state_bytes=(n_kda * kda_layer
+                             + cfg.count(DSA) * 4 * cfg.index_dim),
+        prefill_params=prefill_params(cfg),
+        routed_layers=_routed_layers(cfg),
+        counters={**ssm.SCAN_COUNTERS, **DSA_COUNTERS, **routed.COUNTERS},
+        decode_work=functools.partial(_decode_work, cfg),
+        prefill_work=functools.partial(ssm.scan_work, n_kda, cfg.kda_chunk),
+        routed_work=functools.partial(routed.routed_work, cfg,
+                                      cfg.experts_held))
+
+
+def _routed_layers(cfg: Glm5NextConfig) -> int:
+    return cfg.ffn_types.count(SPARSE)
 
 
 def prefill_params(cfg: Glm5NextConfig) -> tuple[int, int]:
@@ -239,9 +262,9 @@ def prefill_params(cfg: Glm5NextConfig) -> tuple[int, int]:
     hc = 2 * cfg.hc_mult * d * (2 * cfg.hc_mult + cfg.hc_mult ** 2)
     shared = 3 * d * cfg.moe_ffn_dim * cfg.n_shared_experts
     rest = (cfg.count(KDA) * kda_p + cfg.count(DSA) * dsa_p
-            + cfg.n_layers * hc + routed_layers(cfg) * shared
+            + cfg.n_layers * hc + _routed_layers(cfg) * shared
             + cfg.ffn_types.count(DENSE) * 3 * d * cfg.ffn_dim)
-    return routed.prefill_params(cfg, rest, routed_layers(cfg),
+    return routed.prefill_params(cfg, rest, _routed_layers(cfg),
                                  cfg.experts_held)
 
 
@@ -831,7 +854,7 @@ def decode_step_paged(params: dict, pages: dict, tails: dict, state: dict,
             routed.stack_counts(counts))
 
 
-# the serving seam's names (models/__init__.py)
+# the serving seam's names (models/serving.py)
 serve_prefill = prefill
 serve_scatter = scatter_prefill_pages
 serve_decode_step = decode_step_paged

@@ -5,7 +5,7 @@ SwiGLU layers and routed experts after them.  This module gives the
 serving seam (`ray_tpu.models.serving_model`) what `serve/llm.LLMEngine`
 runs: `init_params`, `init_paged_cache`, `prefill`,
 `scatter_prefill_pages`, `decode_step_paged`.  It has none of the
-optional capabilities (`SERVING_CAPS` is empty): a lane carries
+optional capabilities (`serving_spec`'s `caps` is empty): a lane carries
 convolution state that no KV page holds, so a radix prefix hit cannot
 restore it (no `prefill_with_prefix`), and there are no LoRA hooks and
 no KV export/import.
@@ -60,6 +60,7 @@ ones `llama.py` uses (`embed`, `attn_qkv`, `rope`, `attn`, `attn_out`,
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -69,10 +70,9 @@ from jax import lax
 from ray_tpu.models import llama, routed
 from ray_tpu.models.llama import apply_rope, attention, embed_lookup, rmsnorm
 from ray_tpu.models.routed import route
+from ray_tpu.models.serving import ServingSpec
 from ray_tpu.ops.rope import rope_frequencies
 
-SERVING_CAPS: frozenset = frozenset()
-CACHE_KIND = "kv"
 ATTN = "full_attention"
 
 
@@ -128,18 +128,8 @@ def attn_layers(cfg: Lfm2MoeConfig) -> int:
     return sum(cfg.is_attn(i) for i in range(cfg.n_layers))
 
 
-def lane_state_layers(cfg: Lfm2MoeConfig) -> int:
-    """Layers whose per-lane state no KV page holds (the seam's
-    declaration: the engine then serves without the prefix cache)."""
-    return cfg.n_layers - attn_layers(cfg)
-
-
-def routed_layers(cfg: Lfm2MoeConfig) -> int:
+def _routed_layers(cfg: Lfm2MoeConfig) -> int:
     return max(0, cfg.n_layers - cfg.n_dense_layers)
-
-
-def routed_visits(cfg: Lfm2MoeConfig, rows: int) -> int:
-    return routed.routed_visits(cfg, rows)
 
 
 # ---------------------------------------------------------------- params
@@ -308,7 +298,7 @@ def init_paged_cache(cfg: Lfm2MoeConfig, batch: int, n_pages: int,
             "pos": jnp.zeros((batch,), jnp.int32),
             "state": [jnp.zeros((batch, cfg.conv_kernel - 1, cfg.dim),
                                 cfg.dtype)
-                      for _ in range(lane_state_layers(cfg))]}
+                      for _ in range(cfg.n_layers - n_attn)]}
 
 
 def scatter_prefill_pages(cache: dict, ks, vs, state, page_ids, rows,
@@ -395,7 +385,7 @@ def decode_step_paged(params: dict, pages: dict, tails: dict, state: list,
     return logits, {"k": new_tk, "v": new_tv}, new_state, routed.stack_counts(counts)
 
 
-# the serving seam's names (models/__init__.py)
+# the serving seam's names (models/serving.py)
 serve_prefill = prefill
 serve_scatter = scatter_prefill_pages
 serve_decode_step = decode_step_paged
@@ -403,12 +393,24 @@ serve_decode_step = decode_step_paged
 
 def prefill_params(cfg: Lfm2MoeConfig) -> tuple[int, int]:
     """Matmul parameters a prefill program STREAMS whatever it holds and
-    those ONE position multiplies (the seam's declaration: the wave
-    planner's floor and the programs the engine builds follow their
-    ratio, `routed.prefill_params`)."""
+    those ONE position multiplies (`routed.prefill_params`)."""
     d, hd = cfg.dim, cfg.head_dim
     n_attn = attn_layers(cfg)
     rest = (n_attn * d * hd * 2 * (cfg.n_heads + cfg.n_kv_heads)
             + (cfg.n_layers - n_attn) * 4 * d * d
-            + (cfg.n_layers - routed_layers(cfg)) * 3 * d * cfg.ffn_dim)
-    return routed.prefill_params(cfg, rest, routed_layers(cfg))
+            + (cfg.n_layers - _routed_layers(cfg)) * 3 * d * cfg.ffn_dim)
+    return routed.prefill_params(cfg, rest, _routed_layers(cfg))
+
+
+def serving_spec(cfg: Lfm2MoeConfig) -> ServingSpec:
+    """No optional capability: the convolution layers' last rows are
+    lane state no KV page holds."""
+    from ray_tpu.ops.flash_attention import PREFILL_COUNTERS, prefill_work
+
+    return ServingSpec(
+        lane_state_layers=cfg.n_layers - attn_layers(cfg),
+        prefill_params=prefill_params(cfg),
+        routed_layers=_routed_layers(cfg),
+        counters={**PREFILL_COUNTERS, **routed.COUNTERS},
+        prefill_work=prefill_work,
+        routed_work=functools.partial(routed.routed_work, cfg, None))

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.models.serving import ServingSpec
 from ray_tpu.ops.attention import attention
 from ray_tpu.ops.norms import rmsnorm
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
@@ -107,12 +108,7 @@ def llama_configs() -> dict[str, LlamaConfig]:
     }
 
 
-# What this module gives the serving seam (models/__init__.py) beyond
-# the required functions (at the end of the file): every optional
-# capability.
-SERVING_CAPS = frozenset({"prefix", "lora", "kv_transfer"})
-CACHE_KIND = "kv"
-serving_configs = llama_configs
+serving_configs = llama_configs      # the serving seam's presets
 
 
 # ---------------------------------------------------------------- params
@@ -812,15 +808,16 @@ def decode_step_paged(params: dict, pages: dict, tails: dict,
 
 # ------------------------------------------------------ the serving seam
 # The functions above under the ONE signature serve/llm.LLMEngine calls
-# for every model (models/__init__.py): a dense decoder's lanes keep no
+# for every model (models/serving.py): a dense decoder's lanes keep no
 # state beside the page pool (an empty list: no leaf in any program) and
 # it has no routed layers to count (a [0, 4] array).
-def lane_state_layers(cfg: LlamaConfig) -> int:
-    return 0
+def serving_spec(cfg: LlamaConfig) -> ServingSpec:
+    """Every optional capability, and of work counters the prefill
+    kernel's alone."""
+    from ray_tpu.ops.flash_attention import PREFILL_COUNTERS, prefill_work
 
-
-def routed_layers(cfg: LlamaConfig) -> int:
-    return 0
+    return ServingSpec(caps=frozenset({"prefix", "lora", "kv_transfer"}),
+                       counters=PREFILL_COUNTERS, prefill_work=prefill_work)
 
 
 def project_logits(params: dict, h: jnp.ndarray) -> jnp.ndarray:

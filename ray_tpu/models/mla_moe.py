@@ -4,9 +4,9 @@ served.
 
 This module gives the serving seam (`ray_tpu.models.serving_model`) what
 `serve/llm.LLMEngine` runs.  It has none of the optional capabilities
-(`SERVING_CAPS` is empty): the prefix cache's suffix prefill, LoRA and KV
-export/import all read a K pool and a V pool, and this model's pool is
-one LATENT row a token (ROADMAP M4 keeps them).
+(`serving_spec`'s `caps` is empty): the prefix cache's suffix prefill,
+LoRA and KV export/import all read a K pool and a V pool, and this
+model's pool is one LATENT row a token (ROADMAP M4 keeps them).
 
 The equations, as the published keys give them (h = RMSNorm(x), eps
 `rms_norm_eps`; i over the heads):
@@ -55,6 +55,7 @@ the prefill kernel `flash_fwd` (q/k 192 wide, v 128).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any
 
@@ -65,11 +66,10 @@ from jax import lax
 from ray_tpu.models import routed
 from ray_tpu.models.llama import apply_rope, embed_lookup, rmsnorm
 from ray_tpu.models.routed import route, shared_ffn
+from ray_tpu.models.serving import ServingSpec
 from ray_tpu.ops.attention import attention
 from ray_tpu.ops.paged_attention import lanes_live, mla_decode_attention
 
-SERVING_CAPS: frozenset = frozenset()
-CACHE_KIND = "latent"       # what stats()["cache"]["kind"] says
 LANE = 128                  # a bfloat16 tile's lanes
 
 
@@ -134,16 +134,8 @@ def serving_configs() -> dict[str, MlaMoeConfig]:
     }
 
 
-def lane_state_layers(cfg: MlaMoeConfig) -> int:
-    return 0
-
-
-def routed_layers(cfg: MlaMoeConfig) -> int:
+def _routed_layers(cfg: MlaMoeConfig) -> int:
     return max(0, cfg.n_layers - cfg.n_dense_layers)
-
-
-def routed_visits(cfg: MlaMoeConfig, rows: int) -> int:
-    return routed.routed_visits(cfg, rows, cfg.experts_held)
 
 
 # ---------------------------------------------------------------- params
@@ -428,7 +420,7 @@ def decode_step_paged(params: dict, pages: dict, tails: dict, state: list,
     return logits, {"latent": new_tails}, state, routed.stack_counts(counts)
 
 
-# the serving seam's names (models/__init__.py)
+# the serving seam's names (models/serving.py)
 serve_prefill = prefill
 serve_scatter = scatter_prefill_pages
 serve_decode_step = decode_step_paged
@@ -436,16 +428,28 @@ serve_decode_step = decode_step_paged
 
 def prefill_params(cfg: MlaMoeConfig) -> tuple[int, int]:
     """Matmul parameters a prefill program STREAMS whatever it holds and
-    those ONE position multiplies (the seam's declaration: the wave
-    planner's floor and the programs the engine builds follow their
-    ratio, `routed.prefill_params`): of a position's `top_k` experts this
-    chip multiplies the share it holds."""
+    those ONE position multiplies (`routed.prefill_params`): of a
+    position's `top_k` experts this chip multiplies the share it holds."""
     d, H, r = cfg.dim, cfg.n_heads, cfg.kv_lora_rank
     attn = (d * H * cfg.qk_head_dim + d * cfg.row_used
             + H * r * (cfg.qk_nope_dim + cfg.v_head_dim)
             + H * cfg.v_head_dim * d)
     shared = 3 * d * cfg.moe_ffn_dim * cfg.n_shared_experts
-    rest = (cfg.n_layers * attn + routed_layers(cfg) * shared
-            + (cfg.n_layers - routed_layers(cfg)) * 3 * d * cfg.ffn_dim)
-    return routed.prefill_params(cfg, rest, routed_layers(cfg),
+    rest = (cfg.n_layers * attn + _routed_layers(cfg) * shared
+            + (cfg.n_layers - _routed_layers(cfg)) * 3 * d * cfg.ffn_dim)
+    return routed.prefill_params(cfg, rest, _routed_layers(cfg),
                                  cfg.experts_held)
+
+
+def serving_spec(cfg: MlaMoeConfig) -> ServingSpec:
+    """No optional capability (its pool is no K and V page) and no lane
+    state."""
+    from ray_tpu.ops.flash_attention import PREFILL_COUNTERS, prefill_work
+
+    return ServingSpec(
+        prefill_params=prefill_params(cfg),
+        routed_layers=_routed_layers(cfg),
+        counters={**PREFILL_COUNTERS, **routed.COUNTERS},
+        prefill_work=prefill_work,
+        routed_work=functools.partial(routed.routed_work, cfg,
+                                      cfg.experts_held))

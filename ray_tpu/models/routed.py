@@ -105,6 +105,61 @@ def routed_visits(cfg, rows: int,
     return visits_static(rows * cfg.top_k, hi - lo)
 
 
+# What a serving module with routed layers reports of them
+# (models/serving.ServingSpec.counters): the decode windows' under these
+# names, the prefill programs' under "prefill_" + the same.  The device
+# counts (`routed_ffn`); the numbers ride the token fetch of the window
+# (wave) they belong to.  Experts hit a layer-step = moe_experts_hit /
+# moe_layer_steps; moe_visits / moe_visits_static = the share of the
+# grouped matmul's visit list that was work.
+_ROUTED = {
+    "moe_layer_steps": "Routed layers x steps run",
+    "moe_experts_hit": "Experts that held a row, summed over routed "
+                       "layer-steps",
+    "moe_max_load": "The largest expert's rows, summed over routed "
+                    "layer-steps",
+    "moe_assignments": "Token-expert assignments computed",
+    "moe_assignments_absent": "Selected experts this chip does not hold "
+                              "(an expert-parallel share)",
+    "moe_visits": "Visits (a group's row tile) a moe_gmm call walked, "
+                  "summed over routed layer-steps",
+    "moe_visits_static": "The length its visit list is padded to (row "
+                         "tiles + experts held - 1), summed likewise",
+}
+COUNTERS = {p + name: f"{text}, {where}"
+            for p, where in (("", "in decode"),
+                             ("prefill_", "in prefill programs"))
+            for name, text in _ROUTED.items()}
+
+
+def routed_work(cfg, experts: tuple[int, int] | None, counts, steps: int,
+                rows: int, shape_rows: int, prefill: bool
+                ) -> tuple[dict, dict]:
+    """`ServingSpec.routed_work` (after `functools.partial` over `cfg`
+    and the range of experts held, default all): one program's counts on
+    the host ([layers, COUNTS], each summed over the program's `steps`)
+    as COUNTERS' rows.  `rows`: the rows it routed in each layer, summed
+    over the steps; each selected `cfg.top_k` experts, and the
+    selections that were not computed went to experts this chip does not
+    hold.  `shape_rows`: the rows a step of the program is shaped for,
+    which set the length its visit lists are padded to.  A decode
+    window's span shows experts hit and the largest load as means a
+    layer-step."""
+    layers = counts.shape[0]
+    n = layers * steps
+    hit, load, computed, visits = (int(c) for c in counts.sum(axis=0))
+    work = {"moe_layer_steps": n, "moe_experts_hit": hit,
+            "moe_max_load": load, "moe_assignments": computed,
+            "moe_assignments_absent": rows * cfg.top_k * layers - computed,
+            "moe_visits": visits,
+            "moe_visits_static": n * routed_visits(cfg, shape_rows,
+                                                   experts)}
+    if prefill:
+        return {"prefill_" + name: v for name, v in work.items()}, {}
+    return work, {"experts_hit": round(hit / n, 2),
+                  "max_load": round(load / n, 2)}
+
+
 def clamp(gate, up, limit: float):
     """A clamped SwiGLU's two inputs (`swiglu_limit`; the form is an
     assumption the configurations that use it list: the gate bounded

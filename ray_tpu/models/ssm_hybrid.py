@@ -3,7 +3,8 @@ attention layers without position embedding (`model_type`
 `granitemoehybrid` with no routed layer, e.g. granite-4.0-h-micro),
 served.  This module gives the serving seam
 (`ray_tpu.models.serving_model`) what `serve/llm.LLMEngine` runs.  It
-has none of the optional capabilities (`SERVING_CAPS` is empty): a lane
+has none of the optional capabilities (`serving_spec`'s `caps` is
+empty): a lane
 carries a state matrix a head a layer that no KV page holds, so a radix
 prefix hit cannot restore it.
 
@@ -76,10 +77,9 @@ from jax import lax
 
 from ray_tpu.models import llama
 from ray_tpu.models.llama import attention, embed_lookup, rmsnorm
+from ray_tpu.models.serving import ServingSpec, merged
 from ray_tpu.ops import ssm
 
-SERVING_CAPS: frozenset = frozenset()
-CACHE_KIND = "kv"
 ATTN = "attention"
 MAMBA = "mamba"
 F32 = jnp.float32
@@ -147,33 +147,25 @@ def serving_configs() -> dict[str, SsmHybridConfig]:
     }
 
 
-def lane_state_layers(cfg: SsmHybridConfig) -> int:
-    """Layers whose per-lane state no KV page holds (the seam's
-    declaration: the engine then serves without the prefix cache)."""
-    return cfg.count(MAMBA)
+def serving_spec(cfg: SsmHybridConfig) -> ServingSpec:
+    """No optional capability.  The state-space layers keep a state
+    matrix, which `ssd_scan` fills a prefill and `ssm_update` updates a
+    decode step, and a convolution's last rows: the bytes of both that
+    ONE prefill row hands the scatter program."""
+    from ray_tpu.ops.flash_attention import PREFILL_COUNTERS, prefill_work
 
-
-def routed_layers(cfg: SsmHybridConfig) -> int:
-    return 0
-
-
-def scan_chunk(cfg: SsmHybridConfig) -> int:
-    """Positions a chunk of the scan that fills the lane state (the
-    seam's declaration: the layers of `lane_state_layers` keep a state
-    matrix, `ssd_scan` a prefill and `ssm_update` a decode step, and the
-    engine counts their work)."""
-    return cfg.ssm_chunk
-
-
-def prefill_state_bytes(cfg: SsmHybridConfig) -> int:
-    """Bytes of lane state ONE prefill row hands the scatter program
-    (the seam's declaration: the wave planner bounds a program's width
-    by it)."""
+    n = cfg.count(MAMBA)
     per_layer = (cfg.ssm_state * cfg.inner
                  * jnp.dtype(cfg.state_dtype).itemsize
                  + (cfg.conv_kernel - 1) * cfg.conv_dim
                  * jnp.dtype(cfg.dtype).itemsize)
-    return lane_state_layers(cfg) * per_layer
+    return ServingSpec(
+        lane_state_layers=n, prefill_state_bytes=n * per_layer,
+        counters={**PREFILL_COUNTERS, **ssm.SCAN_COUNTERS},
+        decode_work=lambda rows, k: ssm.update_work(n, len(rows), k),
+        prefill_work=lambda true_lens, bucket: merged(
+            prefill_work(true_lens, bucket),
+            ssm.scan_work(n, cfg.ssm_chunk, true_lens, bucket)))
 
 
 # ---------------------------------------------------------------- params
@@ -561,7 +553,7 @@ def decode_step_paged(params: dict, pages: dict, tails: dict, state: dict,
             {"conv": conv, "ssm": ssm_state}, llama._no_counts())
 
 
-# the serving seam's names (models/__init__.py)
+# the serving seam's names (models/serving.py)
 serve_prefill = prefill
 serve_scatter = scatter_prefill_pages
 serve_decode_step = decode_step_paged

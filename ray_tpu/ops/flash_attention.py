@@ -97,6 +97,28 @@ def attn_blocks(sq: int, lengths, block_q: int, block_k: int) -> int:
                           block_k).sum())
 
 
+# What a serving module whose prefill calls `flash_fwd` reports of it
+# (models/serving.ServingSpec.counters): prefill_attn_blocks /
+# prefill_attn_blocks_dense = the share of a prefill program's attention
+# grid that is under the diagonal and inside its rows' true lengths.
+PREFILL_COUNTERS = {
+    "prefill_attn_blocks": "(row, query block, key block) triples flash_fwd "
+                           "multiplies, a full-prompt prefill program",
+    "prefill_attn_blocks_dense": "Rows x query blocks x key blocks of the "
+                                 "same programs",
+}
+
+
+def prefill_work(true_lens, bucket: int) -> tuple[dict, dict]:
+    """`ServingSpec.prefill_work` of a program of len(true_lens) rows
+    padded to `bucket`, every layer's call of which walks the same: the
+    triples that are work, and all of them (host arithmetic)."""
+    bq, bk = fit_blocks(bucket, bucket)
+    return {"prefill_attn_blocks": attn_blocks(bucket, true_lens, bq, bk),
+            "prefill_attn_blocks_dense":
+            len(true_lens) * -(-bucket // bq) * -(-bucket // bk)}, {}
+
+
 def _walk(n_keys, steps: int, block_q: int, block_k: int, causal: bool, xp):
     """The forward kernel's steps: `n_keys` [rows, query blocks]
     (`key_blocks`) -> qi, ki, flag int32 [rows * steps] (`steps` a row,

@@ -56,6 +56,44 @@ def _interpret() -> bool:
     return flash_attention._interpret()
 
 
+# What a serving module whose lanes keep a state matrix reports of the
+# two kernels that fill and update it (models/serving.ServingSpec
+# .counters): the one-step update's work list, and prefill_scan_chunks /
+# prefill_scan_chunks_dense = the share of the chunked scan's chunks that
+# lie below the rows' true lengths.
+SCAN_COUNTERS = {
+    "ssm_lane_steps": "Lane states the one-step state-space update read "
+                      "and wrote: live lanes x decode steps x state-space "
+                      "layers",
+    "prefill_scan_chunks": "Chunks of the chunked state-space scan below "
+                           "the rows' true lengths, summed over prefill "
+                           "programs and state-space layers",
+    "prefill_scan_chunks_dense": "Chunks of the padded prefill programs "
+                                 "the chunked state-space scan walked, "
+                                 "summed likewise",
+}
+
+
+def update_work(layers: int, lanes: int, steps: int) -> tuple[dict, dict]:
+    """`ServingSpec.decode_work`'s part of `layers` such layers: the
+    lane states a window of `steps` steps over `lanes` live lanes
+    updates."""
+    work = {"ssm_lane_steps": lanes * steps * layers}
+    return work, work
+
+
+def scan_work(layers: int, chunk: int, true_lens, bucket: int
+              ) -> tuple[dict, dict]:
+    """`ServingSpec.prefill_work`'s part of `layers` such layers: the
+    scan walks every `chunk` positions of the padded program; the chunks
+    below a row's true length are work."""
+    below = layers * sum(-(-int(n) // chunk) for n in true_lens)
+    return {"prefill_scan_chunks": below,
+            "prefill_scan_chunks_dense":
+            layers * len(true_lens) * -(-bucket // chunk)}, \
+        {"scan_chunks": below}
+
+
 def live_lanes(live) -> tuple[jnp.ndarray, jnp.ndarray]:
     """The work list of `ssm_update` for lanes `live` [B] bool: (lanes
     [B] int32, the live lanes ascending and then the last of them

@@ -277,16 +277,52 @@ _TPOT_MS_BUCKETS = (1.0, 2.0, 5.0, 10.0, 15.0, 20.0, 25.0, 30.0, 40.0,
                     50.0, 75.0, 100.0, 250.0, 1000.0)
 
 
-def _engine_metrics():
+# The engine loop's own work counters, name -> help text.  A served
+# model's rows (`ServingSpec.counters`) join them in the engine's ONE
+# table (`LLMEngine.work`): every row is a key of stats()["loop"] and a
+# Prometheus counter `serve_llm_<name>`.  Ratios an operator reads: pad
+# factor = prefill_padded_tokens / prefill_tokens; live lanes per decode
+# step = lane_steps_live / decode_steps; attn_steps / attn_steps_dense =
+# the share of a grid over every lane and table column that held work.
+_LOOP_WORK = {
+    "decode_steps": "Decode steps dispatched (K per sync window)",
+    "lane_steps_live": "Decode steps x lanes holding a request",
+    "attn_steps": "Grid steps of a paged_attn call, summed over sync "
+                  "windows: a step a page of a lane holding a request",
+    "attn_steps_dense": "Lanes x (table columns + 1), summed over sync "
+                        "windows",
+    "attn_ctx_rows": "Cached rows the decode attention kernel had to "
+                     "attend, summed over live lanes, steps and sync "
+                     "windows",
+    "prefill_padded_tokens": "Token positions the dispatched prefill "
+                             "programs computed (width bucket x length "
+                             "bucket)",
+    "prefill_programs": "Prefill programs dispatched (one or more a wave: "
+                        "serve/prefill_plan.py)",
+    "prefill_programs_at_floor": "Prefill programs charged the planner's "
+                                 "floor: a pass over the weights and no "
+                                 "more",
+    "prefill_programs_capped": "Prefill waves a ceiling of the planner "
+                               "split (serve/prefill_plan."
+                               "PREFILL_MAX_TOKENS, "
+                               "PREFILL_MAX_STATE_BYTES)",
+    "prefill_waves": "Prefill waves dispatched (one an admission)",
+    "prefill_waves_split": "Prefill waves planned as more programs than "
+                           "arrival-order chunks",
+}
+
+
+def _engine_metrics(work=_LOOP_WORK):
     """Process-wide serve-LLM metrics (utils.metrics registry → flushed
-    to the controller KV → dashboard /metrics Prometheus endpoint).
-    Tagged per engine so replicas don't clobber each other."""
+    to the controller KV → dashboard /metrics Prometheus endpoint), with
+    a counter for every row of `work` (an engine's table).  Tagged per
+    engine so replicas don't clobber each other."""
     global _METRICS
     with _METRICS_LOCK:
-        if _METRICS is None:
-            from ray_tpu.utils import metrics as um
+        from ray_tpu.utils import metrics as um
 
-            tk = ("engine",)
+        tk = ("engine",)
+        if _METRICS is None:
             _METRICS = {
                 "prefill_tokens": um.get_or_create(
                     um.Counter, "serve_llm_prefill_tokens",
@@ -297,109 +333,11 @@ def _engine_metrics():
                 "decode_tokens": um.get_or_create(
                     um.Counter, "serve_llm_decode_tokens",
                     "Tokens decoded", tk),
-                # pad factor = prefill_padded_tokens / prefill_tokens;
-                # live lanes per decode step = lane_steps_live /
-                # decode_steps (the engine-loop counters of stats())
-                "prefill_padded_tokens": um.get_or_create(
-                    um.Counter, "serve_llm_prefill_padded_tokens",
-                    "Token positions the dispatched prefill programs "
-                    "computed (width bucket x length bucket)", tk),
-                "prefill_programs": um.get_or_create(
-                    um.Counter, "serve_llm_prefill_programs",
-                    "Prefill programs dispatched (one or more a wave: "
-                    "serve/prefill_plan.py)", tk),
-                "prefill_programs_at_floor": um.get_or_create(
-                    um.Counter, "serve_llm_prefill_programs_at_floor",
-                    "Prefill programs charged the planner's floor: a "
-                    "pass over the weights and no more", tk),
                 "prefill_floor_positions": um.get_or_create(
                     um.Gauge, "serve_llm_prefill_floor_positions",
                     "Token positions the planner charges a prefill "
                     "program at least (serve/prefill_plan.FLOOR_TOKENS "
                     "x parameters streamed / multiplied a position)", tk),
-                "lane_steps_live": um.get_or_create(
-                    um.Counter, "serve_llm_lane_steps_live",
-                    "Decode steps x lanes holding a request", tk),
-                "decode_steps": um.get_or_create(
-                    um.Counter, "serve_llm_decode_steps",
-                    "Decode steps dispatched (K per sync window)", tk),
-                # attn_steps / attn_steps_dense = the share of a grid
-                # over every lane and table column that held work
-                "attn_steps": um.get_or_create(
-                    um.Counter, "serve_llm_attn_steps",
-                    "Grid steps of a paged_attn call, summed over sync "
-                    "windows: a step a page of a lane holding a "
-                    "request", tk),
-                "attn_steps_dense": um.get_or_create(
-                    um.Counter, "serve_llm_attn_steps_dense",
-                    "Lanes x (table columns + 1), summed over sync "
-                    "windows", tk),
-                # prefill_attn_blocks / prefill_attn_blocks_dense = the
-                # share of a prefill program's attention grid that is
-                # under the diagonal and inside its rows' true lengths
-                "prefill_attn_blocks": um.get_or_create(
-                    um.Counter, "serve_llm_prefill_attn_blocks",
-                    "(row, query block, key block) triples flash_fwd "
-                    "multiplies, a full-prompt prefill program", tk),
-                "prefill_attn_blocks_dense": um.get_or_create(
-                    um.Counter, "serve_llm_prefill_attn_blocks_dense",
-                    "Rows x query blocks x key blocks of the same "
-                    "programs", tk),
-                # a routed model's decode: experts hit a layer-step =
-                # moe_experts_hit / moe_layer_steps
-                "moe_layer_steps": um.get_or_create(
-                    um.Counter, "serve_llm_moe_layer_steps",
-                    "Routed layers x decode steps run", tk),
-                "moe_experts_hit": um.get_or_create(
-                    um.Counter, "serve_llm_moe_experts_hit",
-                    "Experts that held a row, summed over routed "
-                    "layer-steps of decode", tk),
-                "moe_assignments": um.get_or_create(
-                    um.Counter, "serve_llm_moe_assignments",
-                    "Token-expert assignments computed in decode", tk),
-                "moe_assignments_absent": um.get_or_create(
-                    um.Counter, "serve_llm_moe_assignments_absent",
-                    "Selected experts this chip does not hold (an "
-                    "expert-parallel share), in decode", tk),
-                # moe_visits / moe_visits_static = the share of the
-                # grouped matmul's visit list that was work
-                "moe_visits": um.get_or_create(
-                    um.Counter, "serve_llm_moe_visits",
-                    "Visits (a group's row tile) a moe_gmm call "
-                    "walked, summed over routed layer-steps of "
-                    "decode", tk),
-                "moe_visits_static": um.get_or_create(
-                    um.Counter, "serve_llm_moe_visits_static",
-                    "The length its visit list is padded to (row "
-                    "tiles + experts held - 1), summed likewise", tk),
-                "attn_ctx_rows": um.get_or_create(
-                    um.Counter, "serve_llm_attn_ctx_rows",
-                    "Cached rows the decode attention kernel had to "
-                    "attend, summed over live lanes, steps and sync "
-                    "windows", tk),
-                "prefill_programs_capped": um.get_or_create(
-                    um.Counter, "serve_llm_prefill_programs_capped",
-                    "Prefill waves a ceiling of the planner split "
-                    "(serve/prefill_plan.PREFILL_MAX_TOKENS, "
-                    "PREFILL_MAX_STATE_BYTES)", tk),
-                # a state-space model's kernels: the one-step update's
-                # work list, and prefill_scan_chunks /
-                # prefill_scan_chunks_dense = the share of the chunked
-                # scan's chunks that lie below the rows' true lengths
-                "ssm_lane_steps": um.get_or_create(
-                    um.Counter, "serve_llm_ssm_lane_steps",
-                    "Lane states the one-step state-space update read "
-                    "and wrote: live lanes x decode steps x state-space "
-                    "layers", tk),
-                "prefill_scan_chunks": um.get_or_create(
-                    um.Counter, "serve_llm_prefill_scan_chunks",
-                    "Chunks of the chunked state-space scan below the "
-                    "rows' true lengths, summed over prefill programs "
-                    "and state-space layers", tk),
-                "prefill_scan_chunks_dense": um.get_or_create(
-                    um.Counter, "serve_llm_prefill_scan_chunks_dense",
-                    "Chunks of the padded prefill programs the chunked "
-                    "state-space scan walked, summed likewise", tk),
                 # beside phase_s["decode_sync"]: bytes a second the
                 # prefix store's demotion fetched off the device
                 "demote_bytes": um.get_or_create(
@@ -475,6 +413,10 @@ def _engine_metrics():
                     "(queue/prefill/decode, ms)", ("engine", "stage"),
                     boundaries=_MS_BUCKETS),
             }
+        for name, text in work.items():
+            if name not in _METRICS:
+                _METRICS[name] = um.get_or_create(
+                    um.Counter, "serve_llm_" + name, text, tk)
     return _METRICS
 
 
@@ -552,10 +494,11 @@ _LOOP_PHASES = ("admit", "prefill_dispatch", "prefill_sync", "fund",
 class LLMEngine:
     """Continuous-batching decode engine over the params of whichever
     model module serves `cfg` (`ray_tpu.models.serving_model`: the
-    engine names no model).  What a model lacks of the optional
-    capabilities (`SERVING_CAPS`) the engine refuses at construction; a
-    model whose lanes carry state no KV page holds (`lane_state_layers`)
-    is served with the prefix cache, suffix prefill and the prefix
+    engine names no model, and reads the module's ONE declaration,
+    `models/serving.ServingSpec`).  What a model lacks of the optional
+    capabilities (`caps`) the engine refuses at construction; a model
+    whose lanes carry state no KV page holds (`lane_state_layers`) is
+    served with the prefix cache, suffix prefill and the prefix
     store's demotion off, and `stats()["lane_state"]` says so.  That
     state may be a few rows a lane or gigabytes (a state-space layer
     keeps a matrix a head a lane): it is allocated once, donated through
@@ -582,42 +525,17 @@ class LLMEngine:
         _check_paged(paged)
         _listen_for_program_builds()    # before the engine's own programs
         model = self._model = serving_model(cfg)
-        caps = model.SERVING_CAPS
-        # Per-lane state beside the page pool (a convolution's last
-        # rows, a state-space layer's matrices): the prefill program
-        # returns it taken at each row's TRUE length, the scatter
-        # program writes it into the lane where the lanes' state lies
-        # (the cache is donated), the decode scan carries it and the
+        # The model's ONE declaration (models/serving.ServingSpec), read
+        # here and nowhere else.  Per-lane state beside the page pool (a
+        # convolution's last rows, a state-space layer's matrices): the
+        # prefill program returns it taken at each row's TRUE length, the
+        # scatter program writes it into the lane where the lanes' state
+        # lies (the cache is donated), the decode scan carries it and the
         # model's step updates it in place; the engine never looks
-        # inside.  What a model may declare beside the layer count: that
-        # those layers keep a state matrix which a chunked scan fills
-        # and a one-step kernel updates (`scan_chunk`, the scan's chunk:
-        # the engine then counts both kernels' work), and the bytes of
-        # state one prefill row hands the scatter
-        # (`prefill_state_bytes`: the planner bounds a program's width
-        # by it).  And, where a prefill program reads weights that a
-        # position does not multiply (a routed layer's experts), the
-        # matmul parameters a program streams whatever it holds and
-        # those ONE position multiplies (`prefill_params`: the planner's
-        # floor and the programs built follow their ratio).
-        self._lane_layers = int(model.lane_state_layers(cfg))
-        self._moe_layers = int(model.routed_layers(cfg))
-        self._scan_chunk = int(getattr(
-            model, "scan_chunk", lambda _cfg: 0)(cfg))
-        self._scan_layers = self._lane_layers if self._scan_chunk else 0
-        self._row_state_bytes = int(getattr(
-            model, "prefill_state_bytes", lambda _cfg: 0)(cfg))
-        # A model whose attention reads its pool through a learned
-        # selection (`selection`: layers that do, positions a pooled
-        # index key, the selection's size in tokens): the engine counts
-        # the rows in context, the groups scored and the rows attended.
-        self._sel_layers, self._sel_group, self._sel_top = getattr(
-            model, "selection", lambda _cfg: (0, 1, 0))(cfg)
-        # (host arithmetic; imported here, where jax already is: the
-        # module itself loads without it)
-        from ray_tpu.ops.sparse_attention import selection_counts
-        self._selection_counts = selection_counts
-        stateful = self._lane_layers > 0
+        # inside.
+        spec = self._spec = model.serving_spec(cfg)
+        caps = spec.caps
+        stateful = spec.lane_state_layers > 0
         if lora_slots and "lora" not in caps:
             raise ValueError(
                 f"{model.__name__} has no LoRA hooks: lora_slots must "
@@ -685,9 +603,9 @@ class LLMEngine:
         # times further out, under which two rows cost what one does, so
         # widths 2 and 4 beside them and only the programs that floor
         # leaves distinct.
-        streams = getattr(model, "prefill_params", None)
+        streams = spec.prefill_params
         self._prefill_floor = (FLOOR_TOKENS if streams is None
-                               else floor_positions(*streams(cfg)))
+                               else floor_positions(*streams))
         narrow = frozenset() if streams is None else frozenset({2, 4}) - wide
         self._width_buckets = sorted(w for w in wide | narrow
                                      if w <= max_batch)
@@ -818,7 +736,7 @@ class LLMEngine:
                     nxt = _sample_rows(logits, temps, keys)
                     return (tails, state, pos + 1, nxt, counts + cnt), nxt
 
-                counts0 = jnp.zeros((self._moe_layers, 4), jnp.int32)
+                counts0 = jnp.zeros((spec.routed_layers, 4), jnp.int32)
                 (tails, state, pos, last, counts), seq = jax.lax.scan(
                     step, (tails, cache["state"], ts, tokens, counts0),
                     jnp.arange(K))
@@ -1008,69 +926,23 @@ class LLMEngine:
         self._export_thread: threading.Thread | None = None
         self.prefill_tokens = 0        # tokens actually prefilled
         self.decode_tokens = 0
-        # The engine thread's own timeline (stats()["loop"]): plain
-        # attributes bumped on the loop thread, cumulative since the
-        # engine was made, on with RAY_TPU_TRACE=0 too.
+        # The engine thread's own timeline (stats()["loop"]): bumped on
+        # the loop thread, cumulative since the engine was made, on with
+        # RAY_TPU_TRACE=0 too.
         self._loop_trace: tuple | None = None  # (trace id, root span id)
         self._iter = 0                 # loop iterations: the spans' `iter`
-        self.decode_steps = 0          # sum of K over the windows
-        self.lane_steps_live = 0       # sum of live lanes x K
-        # The attention kernel's grid a window (every layer's call of
-        # every step walks the same): steps that were work, and what a
-        # grid of every lane x (every table column + the tail) was.
-        self.attn_steps = 0
-        self.attn_steps_dense = 0
-        # The prefill kernel's walk a full-prompt program (every layer's
-        # call walks the same), from the lengths sent with it: the (row,
-        # query block, key block) triples that are work, and all of them.
-        self.prefill_attn_blocks = 0
-        self.prefill_attn_blocks_dense = 0
-        # State-space layers (a model that declares `scan_chunk`):
-        # the one-step kernel's work list a window, live lanes x K x
-        # those layers; and the chunked scan's walk a full-prompt
-        # program, chunks below the rows' true lengths and chunks of the
-        # padded program, each x those layers.
-        self.ssm_lane_steps = 0
-        self.prefill_scan_chunks = 0
-        self.prefill_scan_chunks_dense = 0
-        # Learned sparse attention (a model that declares `selection`),
-        # a live lane's every decode step, x those layers: the rows in
-        # its context, the complete groups its indexer scored and the
-        # rows its selection attended (all host arithmetic,
-        # ops/sparse_attention.selection_counts).
-        self.dsa_rows_context = 0
-        self.dsa_groups_scored = 0
-        self.dsa_rows_selected = 0
-        # Rows the attention kernel had to attend: a live lane's context
-        # at each of a window's K steps (block-start rows + the tail's
-        # j + 1), summed over lanes, steps and windows.
-        self.attn_ctx_rows = 0
+        # The ONE table of work counters: the loop's own rows
+        # (_LOOP_WORK) beside the model's (spec.counters, whose numbers
+        # the model's own arithmetic returns: spec.decode_work a window,
+        # spec.prefill_work a full-prompt program, spec.routed_work a
+        # program's device-side counts).  stats()["loop"], the Prometheus
+        # counters and `_count` read and write it by name.
+        self.work = dict.fromkeys((*_LOOP_WORK, *spec.counters), 0)
         self.phase_s = dict.fromkeys(_LOOP_PHASES, 0.0)
         self.phase_cpu_s = dict.fromkeys(_LOOP_PHASES, 0.0)
-        self.prefill_padded_tokens = 0  # width bucket x length bucket
-        self.prefill_programs = 0      # (width, length) programs dispatched
-        self.prefill_programs_at_floor = 0
-        self.prefill_waves = 0
-        self.prefill_waves_split = 0   # plans of more programs than chunks
-        # waves a ceiling of the planner split (PREFILL_MAX_TOKENS
-        # positions, PREFILL_MAX_STATE_BYTES of lane state handed over)
-        self.prefill_programs_capped = 0
-        # Routed layers (a model that declares `routed_layers`): layer
-        # x steps run, assignments computed, experts that held a row,
-        # the largest expert load and the grouped matmul's visits that
-        # were work, each summed over layer-steps; decode and prefill
-        # apart.  The device counts; the numbers ride the token fetch
-        # of the window (wave) they belong to.  `moe_visits_static` is
-        # the length the visit lists were padded to: the ratio is the
-        # share of the list the kernel walked.
-        # (device array, rows routed, rows of its shape) a program
+        # (device array, rows routed, rows of its shape) a prefill
+        # program with routed layers: fetched with the wave's first tokens
         self._prefill_counts: list = []
-        self.moe = dict.fromkeys(
-            [p + k for p in ("", "prefill_")
-             for k in ("moe_layer_steps", "moe_assignments",
-                       "moe_assignments_absent", "moe_experts_hit",
-                       "moe_max_load", "moe_visits",
-                       "moe_visits_static")], 0)
         self._funded_blocks = 0        # pages _ensure_decode_blocks got
         self._demote_dispatched = 0    # candidates _maybe_demote took
         # What demotion moved off the device, cumulative, bumped on the
@@ -1277,7 +1149,7 @@ class LLMEngine:
         return req.future
 
     def _need_kv_transfer(self, what: str) -> None:
-        if "kv_transfer" not in self._model.SERVING_CAPS:
+        if "kv_transfer" not in self._spec.caps:
             raise ValueError(
                 f"{what}: {self._model.__name__} has no KV export/import "
                 "(its lanes hold state that no page carries)")
@@ -1798,6 +1670,16 @@ class LLMEngine:
                     tracing.emit("llm.loop." + key, w0, time.time(),
                                  ctx=self._loop_ctx(), attrs=attrs)
 
+    def _count(self, work: dict, shown: dict | None = None,
+               ph: dict | None = None) -> None:
+        """Add `work` ({row of the table: what to add}; a name the table
+        lacks is an error, the model's or the loop's) and sum what is
+        `shown` of it into the attributes `ph` of the phase's span."""
+        for name, n in work.items():
+            self.work[name] += n
+        for name, n in (shown or {}).items():
+            ph[name] = ph.get(name, 0) + n
+
     def start(self) -> None:
         if self._thread is None or not self._thread.is_alive():
             self._stop.clear()
@@ -2281,13 +2163,12 @@ class LLMEngine:
         pending_waves = []        # (chunk, nxt_device, dispatch wall t)
         with self._phase("prefill_dispatch", iter=it,
                          rows=len(wave)) as ph:
-            true0, padded0 = self.prefill_tokens, self.prefill_padded_tokens
+            true0 = self.prefill_tokens
             lengths = [len(r.prompt) + len(r.tokens) - r.prefill_from
                        for _, r in wave]
-            scan0 = self.prefill_scan_chunks
             plan, capped = plan_wave(lengths, self._width_buckets,
                                      self._buckets, self._chunk,
-                                     self._row_state_bytes,
+                                     self._spec.prefill_state_bytes,
                                      self._prefill_floor,
                                      self._prefill_programs)
             for rows, w, b in plan:
@@ -2296,27 +2177,26 @@ class LLMEngine:
                 if any(r.prefill_from > 0 for _, r in chunk):
                     nxt = self._prefill_chunk_suffix(chunk, w, b)
                 else:
-                    nxt = self._prefill_chunk_full(chunk, w, b)
-                self.prefill_padded_tokens += w * b
+                    nxt = self._prefill_chunk_full(chunk, w, b, ph)
                 pending_waves.append((chunk, nxt, t_disp))
-            self.prefill_waves += 1
-            self.prefill_programs += len(plan)
-            # charged the floor: a pass over the weights and no more
-            self.prefill_programs_at_floor += sum(
-                w * b <= self._prefill_floor for _, w, b in plan)
-            # more programs than arrival-order chunks of _chunk rows
-            self.prefill_waves_split += \
-                len(plan) > -(-len(wave) // self._chunk)
-            self.prefill_programs_capped += capped
+            padded = sum(w * b for _, w, b in plan)
+            self._count({
+                "prefill_padded_tokens": padded,
+                "prefill_waves": 1,
+                "prefill_programs": len(plan),
+                # charged the floor: a pass over the weights and no more
+                "prefill_programs_at_floor": sum(
+                    w * b <= self._prefill_floor for _, w, b in plan),
+                # more programs than arrival-order chunks of _chunk rows
+                "prefill_waves_split":
+                len(plan) > -(-len(wave) // self._chunk),
+                "prefill_programs_capped": capped})
             # the buckets are those of the widest / longest program
             ph.update(width_bucket=max(w for _, w, _ in plan),
                       len_bucket=max(b for _, _, b in plan),
                       true_tokens=self.prefill_tokens - true0,
-                      padded_tokens=self.prefill_padded_tokens - padded0,
-                      chunks=len(plan),
+                      padded_tokens=padded, chunks=len(plan),
                       plan=",".join(f"{w}x{b}" for _, w, b in plan))
-            if self._scan_layers:
-                ph.update(scan_chunks=self.prefill_scan_chunks - scan0)
         with self._phase("prefill_sync", iter=it, rows=len(wave)):
             counts, self._prefill_counts = self._prefill_counts, []
             for a in ([nxt for _, nxt, _t in pending_waves]
@@ -2364,15 +2244,16 @@ class LLMEngine:
                             (req.first_token_at - req.submitted_at)
                             * 1000, 1)})
             for c, rows, shape in counts:  # on the host since the tokens
-                self._count_moe("prefill_", np.asarray(c), 1, rows, shape)
+                self._count(*self._spec.routed_work(
+                    np.asarray(c), 1, rows, shape, True))
 
-    def _prefill_chunk_full(self, chunk, padded_w: int, bucket: int):
+    def _prefill_chunk_full(self, chunk, padded_w: int, bucket: int,
+                            ph: dict):
         """Full-prompt prefill (no cached prefix anywhere in the chunk)
-        in the (padded_w, bucket) program the wave's plan chose.
+        in the (padded_w, bucket) program the wave's plan chose; what
+        the model counts of it goes to the table and the span `ph`.
         Returns the first tokens (on device)."""
         import jax.numpy as jnp
-
-        from ray_tpu.ops.flash_attention import attn_blocks, fit_blocks
 
         W = len(chunk)
         # Pad by duplicating the last row: the duplicate writes the
@@ -2401,18 +2282,7 @@ class LLMEngine:
             lidx[j] = req.lora_slot
         for _, req in chunk:
             self.prefill_tokens += len(req.prompt) + len(req.tokens)
-        bq, bk = fit_blocks(bucket, bucket)
-        self.prefill_attn_blocks += attn_blocks(bucket, true_lens, bq, bk)
-        self.prefill_attn_blocks_dense += \
-            padded_w * -(-bucket // bq) * -(-bucket // bk)
-        if self._scan_layers:
-            # the chunked scan walks every chunk of the padded program;
-            # those below a row's true length are work
-            q = self._scan_chunk
-            self.prefill_scan_chunks += self._scan_layers * sum(
-                -(-int(n) // q) for n in true_lens)
-            self.prefill_scan_chunks_dense += \
-                self._scan_layers * len(true_lens) * -(-bucket // q)
+        self._count(*self._spec.prefill_work(true_lens, bucket), ph)
         slots_dev = jnp.asarray(slots)
         lens_dev = jnp.asarray(true_lens)
         cols = np.arange(bucket) // self.page
@@ -2427,7 +2297,7 @@ class LLMEngine:
         self.cache = self._scatter_pages(
             self.cache, ks, vs, state, jnp.asarray(page_ids),
             jnp.asarray(rows), slots_dev, lens_dev)
-        if self._moe_layers:
+        if self._spec.routed_layers:
             # fetched with the wave's first tokens; beside them the rows
             # the program routed (padding rows of the width repeat one)
             self._prefill_counts.append(
@@ -2858,33 +2728,19 @@ class LLMEngine:
         with self._phase("decode_dispatch", iter=it, lanes=len(active),
                          steps=k_win) as ph:
             starts = np.zeros((self.max_batch,), np.int32)
-            attn_steps = attn_rows = 0
-            sel = [0, 0, 0]         # rows in context, groups, rows selected
+            lane_rows = []      # cached rows each live lane starts on
             for i in active:
                 req = self._slots[i]
                 starts[i] = len(req.tokens)
-                # the lane's share of the attention kernel's grid
-                # (ops/paged_attention.attention_plan): a step a page
-                # holding rows below its block-start position
-                rows = min(len(req.prompt) + len(req.tokens) - 1,
-                           self._maxp * self.page)
-                attn_steps += max(-(-rows // self.page), 1)
-                attn_rows += k_win * rows + k_win * (k_win + 1) // 2
-                for ctx in range(rows + 1, rows + 1 + k_win
-                                 if self._sel_layers else 0):
-                    scored, kept = self._selection_counts(
-                        ctx, self._sel_group, self._sel_top)
-                    sel[0] += ctx
-                    sel[1] += scored
-                    sel[2] += kept
-            ph.update(attn_steps=attn_steps)
-            if self._sel_layers:
-                sel = [n * self._sel_layers for n in sel]
-                ph.update(dsa_rows_context=sel[0], dsa_groups_scored=sel[1],
-                          dsa_rows_selected=sel[2])
-            if self._scan_layers:
-                ph.update(ssm_lane_steps=len(active) * k_win
-                          * self._scan_layers)
+                lane_rows.append(min(len(req.prompt) + len(req.tokens) - 1,
+                                     self._maxp * self.page))
+            # the lanes' share of the attention kernel's grid
+            # (ops/paged_attention.attention_plan): a step a page holding
+            # rows below a lane's block-start position
+            attn_steps = sum(max(-(-rows // self.page), 1)
+                             for rows in lane_rows)
+            work, shown = self._spec.decode_work(lane_rows, k_win)
+            ph.update(shown, attn_steps=attn_steps)
             win_traced = tracing.ENABLED and any(
                 self._slots[i] is not None
                 and self._slots[i].trace is not None for i in active)
@@ -2900,33 +2756,33 @@ class LLMEngine:
                 self._lora_args(self._adapters))
             seq, last, self.cache = out[:3]
             self._cur_dev = last                # stays on device
-            if self._moe_layers:
+            if self._spec.routed_layers:
                 out[3].copy_to_host_async()
             # the scan's page gathers queue BEHIND the window the lanes
             # wait for, on the cache it returned
             ph.update(demote_pages=self._dispatch_demotes())
-            self.decode_steps += k_win
-            self.lane_steps_live += len(active) * k_win
-            self.ssm_lane_steps += len(active) * k_win * self._scan_layers
-            self.attn_steps += attn_steps
-            self.attn_steps_dense += self.max_batch * (self._maxp + 1)
-            self.attn_ctx_rows += attn_rows
-            self.dsa_rows_context += sel[0]
-            self.dsa_groups_scored += sel[1]
-            self.dsa_rows_selected += sel[2]
+            self._count({
+                "decode_steps": k_win,
+                "lane_steps_live": len(active) * k_win,
+                "attn_steps": attn_steps,
+                "attn_steps_dense": self.max_batch * (self._maxp + 1),
+                # a lane's context at each of the K steps: its
+                # block-start rows + the tail's j + 1
+                "attn_ctx_rows": k_win * sum(lane_rows)
+                + len(active) * k_win * (k_win + 1) // 2, **work})
         with self._phase("decode_sync", iter=it):
             seq = np.asarray(seq)               # the ONE sync per block
             # the routed layers' counts: a few hundred bytes of the same
             # program, on their way since dispatch; no second wait
-            moe = np.asarray(out[3]) if self._moe_layers else None
+            counts = (np.asarray(out[3]) if self._spec.routed_layers
+                      else None)
             t_win1 = time.time() if win_traced else 0.0
         with self._phase("deliver", iter=it) as ph:
             tokens0, done0 = self.decode_tokens, self.completed
-            if moe is not None:
-                hit, load = self._count_moe("", moe, k_win,
-                                            len(active) * k_win,
-                                            self.max_batch)
-                ph.update(experts_hit=hit, max_load=load)
+            if counts is not None:
+                self._count(*self._spec.routed_work(
+                    counts, k_win, len(active) * k_win, self.max_batch,
+                    False), ph)
             if win_traced:
                 # One K-step decode window per traced co-resident
                 # request: the window (dispatch → host sync) is the
@@ -2954,32 +2810,6 @@ class LLMEngine:
                         break
             ph.update(tokens=self.decode_tokens - tokens0,
                       finished=self.completed - done0)
-
-    def _count_moe(self, prefix: str, counts, steps: int,
-                   rows: int, shape_rows: int) -> tuple:
-        """Add one program's routed-layer counts ([layers, 4]: experts
-        hit, largest load, assignments, visits that were work; each
-        summed over the program's `steps`) to the `prefix`ed counters.
-        `rows`: the rows it routed in each layer, summed over the steps;
-        each selected `cfg.top_k` experts (a config with routed layers
-        has it), and the selections that were not computed went to
-        experts this chip does not hold.  `shape_rows`: the rows a step
-        of the program is shaped for, which set the length its visit
-        lists are padded to.  Returns (experts hit, largest load) as
-        means a layer-step."""
-        m, n = self.moe, counts.shape[0] * steps
-        computed = int(counts[:, 2].sum())
-        m[prefix + "moe_layer_steps"] += n
-        m[prefix + "moe_experts_hit"] += int(counts[:, 0].sum())
-        m[prefix + "moe_max_load"] += int(counts[:, 1].sum())
-        m[prefix + "moe_assignments"] += computed
-        m[prefix + "moe_assignments_absent"] += (
-            rows * self.cfg.top_k * counts.shape[0] - computed)
-        m[prefix + "moe_visits"] += int(counts[:, 3].sum())
-        m[prefix + "moe_visits_static"] += n * self._model.routed_visits(
-            self.cfg, shape_rows)
-        return (round(float(counts[:, 0].sum()) / n, 2),
-                round(float(counts[:, 1].sum()) / n, 2))
 
     def _idle_wait(self) -> None:
         """No lane is live: wait for work under ONE `idle` phase, however
@@ -3016,37 +2846,19 @@ class LLMEngine:
         if not force and now - self._metrics_t < 1.0:
             return
         try:
-            m = _engine_metrics()
+            m = _engine_metrics({**_LOOP_WORK, **self._spec.counters})
         except Exception:  # noqa: BLE001 - metrics must never stop decode
             return
         tags = {"engine": self.name}
-        cur = {"prefill_tokens": self.prefill_tokens,
-               "prefill_padded_tokens": self.prefill_padded_tokens,
-               "prefill_programs": self.prefill_programs,
-               "prefill_programs_at_floor": self.prefill_programs_at_floor,
+        cur = {**self.work,
+               "prefill_tokens": self.prefill_tokens,
                "decode_tokens": self.decode_tokens,
-               "decode_steps": self.decode_steps,
-               "lane_steps_live": self.lane_steps_live,
-               "attn_steps": self.attn_steps,
-               "attn_steps_dense": self.attn_steps_dense,
-               "attn_ctx_rows": self.attn_ctx_rows,
-               "prefill_attn_blocks": self.prefill_attn_blocks,
-               "prefill_attn_blocks_dense": self.prefill_attn_blocks_dense,
-               "prefill_programs_capped": self.prefill_programs_capped,
-               "ssm_lane_steps": self.ssm_lane_steps,
-               "prefill_scan_chunks": self.prefill_scan_chunks,
-               "prefill_scan_chunks_dense": self.prefill_scan_chunks_dense,
                "demote_bytes": self.demote_bytes,
                "preemptions": self.preemptions,
                "completed": self.completed,
                "weight_updates": self.weight_updates,
                "prefix_hit_tokens": self._mgr.hit_tokens,
                "evictions": self._mgr.evictions}
-        if self._moe_layers:
-            cur.update({k: self.moe[k] for k in (
-                "moe_layer_steps", "moe_experts_hit", "moe_assignments",
-                "moe_assignments_absent", "moe_visits",
-                "moe_visits_static")})
         with _BUILDS_LOCK:
             cur["program_builds"] = _BUILDS["program_builds"]
             cur["program_build_s"] = _BUILDS["program_build_s"]
@@ -3088,9 +2900,10 @@ class LLMEngine:
                 "available": self._mgr.available()}
 
     def _cache_stats(self) -> dict:
-        """The page pool by what it holds: the model's word for it
-        (`CACHE_KIND`), the bytes a token's rows take in one layer as
-        stored, the layers that keep any, and the pool's bytes."""
+        """The page pool by what it holds: a word for it ("kv": a K and
+        a V pool; else its first entry's name), the bytes a token's rows
+        take in one layer as stored, the layers that keep any, and the
+        pool's bytes."""
         pool = _pool(self.cache)
         by_leaf = {
             name: {"row_bytes": int(v[0].shape[1] * v[0].shape[3]
@@ -3100,7 +2913,8 @@ class LLMEngine:
                    "pool_bytes": int(sum(a.size * a.dtype.itemsize
                                          for a in v))}
             for name, v in pool.items()}
-        return {"kind": self._model.CACHE_KIND,
+        return {"kind": ("kv" if list(pool) == ["k", "v"]
+                         else next(iter(pool))),
                 # a TOKEN's bytes: a row shared by g positions counts 1/g
                 "row_bytes": int(sum(
                     b["row_bytes"] // b["positions_per_row"]
@@ -3122,7 +2936,8 @@ class LLMEngine:
 
         state = self.cache["state"]
         kinds = state if isinstance(state, dict) else {"rows": state}
-        return {"layers": self._lane_layers, "bytes": nbytes(state),
+        return {"layers": self._spec.lane_state_layers,
+                "bytes": nbytes(state),
                 "by_kind": {k: nbytes(v) for k, v in kinds.items()},
                 "prefix_cache": "off: lane state"}
 
@@ -3153,23 +2968,13 @@ class LLMEngine:
                "slo": self._slo_window.snapshot(),
                "sync_window": self._k_live,
                "sync_window_shrinks": self.sync_window_shrinks,
-               # The engine thread's timeline, cumulative: pad factor =
-               # prefill_padded_tokens / prefill_true_tokens, live lanes
-               # per decode step = lane_steps_live / decode_steps, the
-               # share of a lanes x columns attention grid that is work
-               # = attn_steps / attn_steps_dense, the share of a prefill
-               # program's rows x query blocks x key blocks that the
-               # diagonal and the true lengths leave as work =
-               # prefill_attn_blocks / prefill_attn_blocks_dense.
+               # The engine thread's timeline, cumulative: every row of
+               # the table of work counters (_LOOP_WORK for the ratios
+               # an operator reads off the loop's own; the model's rows
+               # are documented where it declares them) beside the
+               # phases' seconds.
                "loop": {
-                   "decode_steps": self.decode_steps,
-                   "lane_steps_live": self.lane_steps_live,
-                   "attn_steps": self.attn_steps,
-                   "attn_steps_dense": self.attn_steps_dense,
-                   "attn_ctx_rows": self.attn_ctx_rows,
-                   "prefill_attn_blocks": self.prefill_attn_blocks,
-                   "prefill_attn_blocks_dense":
-                   self.prefill_attn_blocks_dense,
+                   **self.work,
                    "phase_s": dict(self.phase_s),
                    # the thread's own CPU seconds in each phase: what
                    # phase_s has more is the time it stood there
@@ -3182,35 +2987,15 @@ class LLMEngine:
                    "demote_bytes": self.demote_bytes,
                    "demote_fetch_s": round(self.demote_fetch_s, 6),
                    "prefill_true_tokens": self.prefill_tokens,
-                   "prefill_padded_tokens": self.prefill_padded_tokens,
-                   "prefill_programs": self.prefill_programs,
-                   "prefill_programs_at_floor":
-                   self.prefill_programs_at_floor,
-                   "prefill_floor_positions": self._prefill_floor,
-                   "prefill_waves": self.prefill_waves,
-                   "prefill_waves_split": self.prefill_waves_split,
-                   "prefill_programs_capped":
-                   self.prefill_programs_capped},
+                   "prefill_floor_positions": self._prefill_floor},
                "cache": dict(self._cache_info)}
-        if self._scan_layers:
-            out["loop"].update(
-                ssm_lane_steps=self.ssm_lane_steps,
-                prefill_scan_chunks=self.prefill_scan_chunks,
-                prefill_scan_chunks_dense=self.prefill_scan_chunks_dense)
-        if self._sel_layers:
-            out["loop"].update(
-                dsa_rows_context=self.dsa_rows_context,
-                dsa_groups_scored=self.dsa_groups_scored,
-                dsa_rows_selected=self.dsa_rows_selected)
         with _BUILDS_LOCK:
             # every program this PROCESS built since its first engine
             # was made; the ledger of threads is the process's too
             out["loop"].update(
                 _BUILDS, program_build_s=round(_BUILDS["program_build_s"], 6))
         out["threads"] = _thread_cpu_ledger()
-        if self._moe_layers:
-            out["loop"].update(self.moe)
-        if self._lane_layers:
+        if self._spec.lane_state_layers:
             out["lane_state"] = dict(self._lane_info)
         if self._lora_banks is not None:
             with self._lora_lock:
@@ -3293,7 +3078,8 @@ class LLMServer:
         _check_paged(paged)
         cfg = named_config(model) if isinstance(model, str) else model
         served_by = serving_model(cfg)
-        if role != "unified" and "kv_transfer" not in served_by.SERVING_CAPS:
+        if (role != "unified"
+                and "kv_transfer" not in served_by.serving_spec(cfg).caps):
             raise ValueError(
                 f"role={role!r} needs KV export/import, which "
                 f"{served_by.__name__} lacks (what its lanes or its "

@@ -6,19 +6,10 @@ agent processes (SURVEY §4 "fakes" row).
 """
 import time
 
-import pytest
-
 import ray_tpu
 
 
-@pytest.fixture(scope="module")
-def rt():
-    if not ray_tpu.is_initialized():
-        ray_tpu.init(resources={"CPU": 4})
-    yield ray_tpu
-
-
-def test_autoscaler_scales_up_and_down(rt):
+def test_autoscaler_scales_up_and_down(ray_shared):
     from ray_tpu._private.worker import global_worker
     from ray_tpu.autoscaler import (AutoscalerConfig, LocalNodeProvider,
                                     StandardAutoscaler, request_resources)

@@ -14,14 +14,7 @@ import pytest
 import ray_tpu
 
 
-@pytest.fixture(scope="module")
-def rt():
-    if not ray_tpu.is_initialized():
-        ray_tpu.init(resources={"CPU": 4})
-    yield ray_tpu
-
-
-def test_state_api(rt):
+def test_state_api(ray_shared):
     from ray_tpu.utils import state
 
     @ray_tpu.remote
@@ -52,7 +45,7 @@ def test_state_api(rt):
     ray_tpu.kill(p)
 
 
-def test_metrics(rt):
+def test_metrics(ray_shared):
     from ray_tpu.utils import metrics as m
     from ray_tpu.utils import state
 
@@ -78,7 +71,7 @@ def test_metrics(rt):
     assert found, "metrics never reached the controller KV"
 
 
-def test_job_submission(rt):
+def test_job_submission(ray_shared):
     from ray_tpu.job_submission import JobSubmissionClient
 
     client = JobSubmissionClient()
@@ -92,7 +85,7 @@ def test_job_submission(rt):
     assert any(j["job_id"] == jid for j in jobs)
 
 
-def test_job_failure_status(rt):
+def test_job_failure_status(ray_shared):
     from ray_tpu.job_submission import JobSubmissionClient
 
     client = JobSubmissionClient()
@@ -101,7 +94,7 @@ def test_job_failure_status(rt):
     assert client.get_job_info(jid)["return_code"] == 3
 
 
-def test_workflow_run_and_resume(rt, tmp_path):
+def test_workflow_run_and_resume(ray_shared, tmp_path):
     from ray_tpu import workflow
 
     calls = {"n": 0}
@@ -131,7 +124,7 @@ def test_workflow_run_and_resume(rt, tmp_path):
     assert workflow.get_status("wf1", storage=storage) == "NOT_FOUND"
 
 
-def test_workflow_step_checkpoint_skips_done(rt, tmp_path):
+def test_workflow_step_checkpoint_skips_done(ray_shared, tmp_path):
     from ray_tpu import workflow
     from ray_tpu.dag import InputNode
 
@@ -154,7 +147,7 @@ def test_workflow_step_checkpoint_skips_done(rt, tmp_path):
     assert marker.read_text() == "1"   # step executed exactly once
 
 
-def test_runtime_env_env_vars(rt):
+def test_runtime_env_env_vars(ray_shared):
     @ray_tpu.remote
     def read_env():
         return os.environ.get("RAY_TPU_TEST_FLAG", "missing")
@@ -166,7 +159,7 @@ def test_runtime_env_env_vars(rt):
     assert ray_tpu.get(read_env.remote()) == "missing"
 
 
-def test_runtime_env_working_dir(rt, tmp_path):
+def test_runtime_env_working_dir(ray_shared, tmp_path):
     pkg = tmp_path / "mypkg"
     pkg.mkdir()
     (pkg / "mymod_rt_env.py").write_text("VALUE = 'from-working-dir'\n")
@@ -200,7 +193,7 @@ def _make_wheel(wheel_dir, name: str, version: str, source: str) -> None:
         zf.writestr(f"{tag}.dist-info/RECORD", "")
 
 
-def test_runtime_env_pip_offline(rt, tmp_path):
+def test_runtime_env_pip_offline(ray_shared, tmp_path):
     """pip runtime env from a local wheel dir (ray: runtime_env/pip.py
     minus the network): the env's task imports the package; a plain task
     on the same pooled worker must NOT see it."""
@@ -234,7 +227,7 @@ def test_runtime_env_pip_offline(rt, tmp_path):
     assert ray_tpu.get(with_pkg.options(runtime_env=env2).remote()) == 43
 
 
-def test_runtime_env_venv_isolated_interpreter(rt, tmp_path):
+def test_runtime_env_venv_isolated_interpreter(ray_shared, tmp_path):
     """venv runtime env = a DEDICATED worker on an isolated interpreter
     (the conda analog; ray: runtime_env/conda.py + the env-keyed
     WorkerPool).  The env's tasks run under the venv prefix with its
@@ -370,13 +363,10 @@ def test_venv_lease_evicts_idle_worker_at_cap(tmp_path):
         # ...and back: a plain task evicts the idle venv worker.
         assert ray_tpu.get(plain.remote(), timeout=60) == plain_prefix
     finally:
-        ray_tpu.shutdown()
-        # Restore the module-shared runtime (the module-scoped `rt`
-        # fixture only inits on first use; later tests expect it live).
-        ray_tpu.init(resources={"CPU": 4})
+        ray_tpu.shutdown()      # `ray_shared` starts the next test's
 
 
-def test_venv_rejected_for_tpu_tasks(rt):
+def test_venv_rejected_for_tpu_tasks(ray_shared):
     @ray_tpu.remote
     def f():
         return 1
@@ -385,7 +375,7 @@ def test_venv_rejected_for_tpu_tasks(rt):
         f.options(num_tpus=1, runtime_env={"venv": True}).remote()
 
 
-def test_cli_status_and_list(rt):
+def test_cli_status_and_list(ray_shared):
     """Smoke the CLI code paths in-process (full subprocess CLI covered by
     job submission)."""
     from ray_tpu._private.worker import global_worker
@@ -398,7 +388,7 @@ def test_cli_status_and_list(rt):
     assert cli._require_address(A) == A.address
 
 
-def test_cli_status_and_memory(rt):
+def test_cli_status_and_memory(ray_shared):
     """`ray-tpu status` and `ray-tpu memory` against a live cluster
     (ray: `ray status` / `ray memory` CLI)."""
     import subprocess
@@ -416,7 +406,7 @@ def test_cli_status_and_memory(rt):
         assert expect in out.stdout, out.stdout
 
 
-def test_workflow_retries_timeout_events(rt, tmp_path):
+def test_workflow_retries_timeout_events(ray_shared, tmp_path):
     """Workflow hardening (ray: workflow_executor.py): per-step retries
     with a durable event stream, step timeouts, and bounded concurrency."""
     from ray_tpu import workflow
@@ -464,7 +454,7 @@ def test_workflow_retries_timeout_events(rt, tmp_path):
                      step_timeout_s=1.0)
 
 
-def test_workflow_concurrency_limit(rt, tmp_path):
+def test_workflow_concurrency_limit(ray_shared, tmp_path):
     """max_concurrent_steps bounds in-flight steps: with limit 1, step
     wall-clocks never overlap."""
     import json as _json
@@ -496,7 +486,7 @@ def test_workflow_concurrency_limit(rt, tmp_path):
         assert s1 >= e0 - 0.05, f"steps overlapped: {spans}"
 
 
-def test_runtime_env_custom_plugin(rt):
+def test_runtime_env_custom_plugin(ray_shared):
     """The plugin seam (ray: runtime_env/plugin.py RuntimeEnvPlugin):
     a user-defined kind ships BY VALUE in the descriptor — prepare on
     the driver, fetch+activate/deactivate around execution on a pooled
@@ -545,7 +535,7 @@ def test_runtime_env_custom_plugin(rt):
     assert ray_tpu.get(read_stamp.remote(), timeout=120) is None
 
 
-def test_workflow_api_extras(rt, tmp_path):
+def test_workflow_api_extras(ray_shared, tmp_path):
     """Round-4 workflow parity: continuation, sleep, wait_for_event,
     metadata, resume_all, cancellation error (ray: workflow/__init__)."""
     import time as _time

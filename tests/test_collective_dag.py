@@ -5,16 +5,8 @@ send-recv across actors) and python/ray/dag/tests/ (bind/execute,
 compiled DAGs).
 """
 import numpy as np
-import pytest
 
 import ray_tpu
-
-
-@pytest.fixture(scope="module")
-def rt():
-    if not ray_tpu.is_initialized():
-        ray_tpu.init(resources={"CPU": 4})
-    yield ray_tpu
 
 
 @ray_tpu.remote
@@ -75,7 +67,7 @@ def _cleanup(workers, group_name):
         pass
 
 
-def test_collective_allreduce_allgather(rt):
+def test_collective_allreduce_allgather(ray_shared):
     from ray_tpu import collective as col
 
     workers = [CollectiveWorker.remote() for _ in range(2)]
@@ -98,7 +90,7 @@ def test_collective_allreduce_allgather(rt):
     _cleanup(workers, "g1")
 
 
-def test_collective_send_recv(rt):
+def test_collective_send_recv(ray_shared):
     from ray_tpu import collective as col
 
     workers = [CollectiveWorker.remote() for _ in range(2)]
@@ -110,7 +102,7 @@ def test_collective_send_recv(rt):
     _cleanup(workers, "g2")
 
 
-def test_dag_function_chain(rt):
+def test_dag_function_chain(ray_shared):
     from ray_tpu.dag import InputNode
 
     @ray_tpu.remote
@@ -128,7 +120,7 @@ def test_dag_function_chain(rt):
     assert ray_tpu.get(dag.execute(10)) == 22
 
 
-def test_dag_actor_methods_and_compile(rt):
+def test_dag_actor_methods_and_compile(ray_shared):
     from ray_tpu.dag import InputNode, MultiOutputNode
 
     @ray_tpu.remote
@@ -168,7 +160,7 @@ def test_dag_actor_methods_and_compile(rt):
     ray_tpu.kill(b)
 
 
-def test_dag_input_attribute(rt):
+def test_dag_input_attribute(ray_shared):
     from ray_tpu.dag import InputNode
 
     @ray_tpu.remote

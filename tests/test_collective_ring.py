@@ -13,18 +13,10 @@ import json
 import time
 
 import numpy as np
-import pytest
 
 import ray_tpu
 
 pytestmark = []
-
-
-@pytest.fixture(scope="module")
-def rt():
-    if not ray_tpu.is_initialized():
-        ray_tpu.init(resources={"CPU": 4})
-    yield ray_tpu
 
 
 def _set_path_env(path: str):
@@ -126,7 +118,7 @@ class Member:
             return repr(e)
 
 
-def _group(rt, n, name):
+def _group(ray_shared, n, name):
     from ray_tpu import collective as col
 
     ws = [Member.options(num_cpus=0.5).remote() for _ in range(n)]
@@ -152,10 +144,10 @@ except ImportError:  # pragma: no cover - jax always ships ml_dtypes
     pass
 
 
-def test_ring_parity_dtypes_ops(rt):
+def test_ring_parity_dtypes_ops(ray_shared):
     """Ring / tree / legacy produce identical results for every dtype
     and op (integer-valued data: exact under any reduction order)."""
-    ws = _group(rt, 3, "par")
+    ws = _group(ray_shared, 3, "par")
     try:
         for dtype in DTYPES:
             for op in ("sum", "min", "max"):
@@ -184,8 +176,8 @@ def test_ring_parity_dtypes_ops(rt):
         _cleanup(ws, "par")
 
 
-def test_ring_reducescatter_allgather_broadcast(rt):
-    ws = _group(rt, 3, "rsagbc")
+def test_ring_reducescatter_allgather_broadcast(ray_shared):
+    ws = _group(ray_shared, 3, "rsagbc")
     try:
         x = np.arange(10, dtype=np.float64)
         full = 3 * x
@@ -217,7 +209,7 @@ def test_ring_reducescatter_allgather_broadcast(rt):
         _cleanup(ws, "rsagbc")
 
 
-def test_async_ordering_concurrent_groups(rt):
+def test_async_ordering_concurrent_groups(ray_shared):
     """Async ops execute in submission (seq) order per group, and two
     groups sharing the same actors don't cross-talk."""
     from ray_tpu import collective as col
@@ -238,11 +230,11 @@ def test_async_ordering_concurrent_groups(rt):
         _cleanup(ws, "ga", "gb")
 
 
-def test_tracer_byte_schedule(rt):
+def test_tracer_byte_schedule(ray_shared):
     """The phase tracer's byte counters prove the schedule shape: ring
     moves 2*N*(world-1)/world bytes per rank; the legacy gather pulls
     O(world*N)."""
-    ws = _group(rt, 3, "tr")
+    ws = _group(ray_shared, 3, "tr")
     try:
         x = np.ones(1 << 20, np.float32)          # 4 MiB
         n = x.nbytes
@@ -267,7 +259,7 @@ def test_tracer_byte_schedule(rt):
         _cleanup(ws, "tr")
 
 
-def test_exchange_timeout_names_missing_ranks(rt):
+def test_exchange_timeout_names_missing_ranks(ray_shared):
     """A rank whose peers never arrive gets a diagnostic error naming
     the missing ranks — never a hang (satellite fix).  Only rank 0 ever
     joins, with a 5s deadline; the barrier (legacy exchange) and the
@@ -285,13 +277,13 @@ def test_exchange_timeout_names_missing_ranks(rt):
     _cleanup(ws, "lone")
 
 
-def test_destroy_cleans_up_from_driver(rt):
+def test_destroy_cleans_up_from_driver(ray_shared):
     """destroy_collective_group works from a process whose registry
     never saw the group (the driver that used create_collective_group):
     the detached rendezvous actor is drained and killed, not leaked."""
     from ray_tpu import collective as col
 
-    ws = _group(rt, 2, "dstr")
+    ws = _group(ray_shared, 2, "dstr")
     ray_tpu.get([w.allreduce.remote("dstr", np.ones(4), "sum", "ring")
                  for w in ws], timeout=120)
     col.destroy_collective_group("dstr")

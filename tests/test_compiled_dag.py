@@ -12,13 +12,6 @@ import ray_tpu
 from ray_tpu.dag import InputNode, MultiOutputNode
 
 
-@pytest.fixture(scope="module")
-def rt():
-    if not ray_tpu.is_initialized():
-        ray_tpu.init(resources={"CPU": 4})
-    yield ray_tpu
-
-
 @ray_tpu.remote
 class Adder:
     def __init__(self, inc):
@@ -42,7 +35,7 @@ def _owned_count():
     return len(global_worker().owned)
 
 
-def test_compiled_chain_zero_submissions(rt):
+def test_compiled_chain_zero_submissions(ray_shared):
     a, b, c = Adder.remote(1), Adder.remote(10), Adder.remote(100)
     with InputNode() as inp:
         dag = c.add.bind(b.add.bind(a.add.bind(inp)))
@@ -64,7 +57,7 @@ def test_compiled_chain_zero_submissions(rt):
         ray_tpu.kill(h)
 
 
-def test_compiled_latency_vs_remote_chain(rt):
+def test_compiled_latency_vs_remote_chain(ray_shared):
     a, b, c = Adder.remote(1), Adder.remote(10), Adder.remote(100)
     # Warm the actors through the normal path first.
     assert ray_tpu.get(c.add.remote(ray_tpu.get(
@@ -100,7 +93,7 @@ def test_compiled_latency_vs_remote_chain(rt):
         ray_tpu.kill(h)
 
 
-def test_compiled_error_propagation_and_recovery(rt):
+def test_compiled_error_propagation_and_recovery(ray_shared):
     a, b = Adder.remote(1), Adder.remote(10)
     with InputNode() as inp:
         dag = b.add.bind(a.add.bind(inp))
@@ -117,7 +110,7 @@ def test_compiled_error_propagation_and_recovery(rt):
     ray_tpu.kill(b)
 
 
-def test_compiled_multi_output_and_input_attrs(rt):
+def test_compiled_multi_output_and_input_attrs(ray_shared):
     a, b = Adder.remote(1), Adder.remote(10)
     with InputNode() as inp:
         dag = MultiOutputNode([a.add.bind(inp["x"]),
@@ -133,7 +126,7 @@ def test_compiled_multi_output_and_input_attrs(rt):
     ray_tpu.kill(b)
 
 
-def test_teardown_releases_actor_and_channels(rt):
+def test_teardown_releases_actor_and_channels(ray_shared):
     import glob
 
     a = Adder.remote(1)
@@ -151,7 +144,7 @@ def test_teardown_releases_actor_and_channels(rt):
     ray_tpu.kill(a)
 
 
-def test_uncompilable_graph_falls_back(rt):
+def test_uncompilable_graph_falls_back(ray_shared):
     @ray_tpu.remote
     def double(x):
         return x * 2

@@ -12,10 +12,8 @@ import pytest
 import ray_tpu
 
 
-@pytest.fixture(scope="module")
-def dash():
-    if not ray_tpu.is_initialized():
-        ray_tpu.init(resources={"CPU": 4})
+@pytest.fixture
+def dash(ray_shared):
     from ray_tpu.dashboard import start_dashboard
 
     head = start_dashboard(port=0)

@@ -8,15 +8,7 @@ semantics at block boundaries)."""
 import numpy as np
 import pytest
 
-import ray_tpu
 from ray_tpu.data import from_items, range as data_range
-
-
-@pytest.fixture(scope="module")
-def cluster():
-    if not ray_tpu.is_initialized():
-        ray_tpu.init(resources={"CPU": 4})
-    yield
 
 
 def _multiblock(n=100, blocks=7, seed=3):
@@ -30,7 +22,7 @@ def _multiblock(n=100, blocks=7, seed=3):
 
 
 class TestAggregationGroundTruth:
-    def test_global_aggregates(self, cluster):
+    def test_global_aggregates(self, ray_shared):
         ds, _, vals = _multiblock()
         assert ds.count() == 100
         assert np.isclose(ds.sum("v"), vals.sum())
@@ -39,7 +31,7 @@ class TestAggregationGroundTruth:
         assert np.isclose(ds.mean("v"), vals.mean())
         assert np.isclose(ds.std("v"), vals.std(ddof=1))
 
-    def test_aggregate_multi(self, cluster):
+    def test_aggregate_multi(self, ray_shared):
         ds, _, vals = _multiblock()
         out = ds.aggregate(lo=("v", "min"), hi=("v", "max"),
                            total=("v", "sum"))
@@ -47,14 +39,14 @@ class TestAggregationGroundTruth:
         assert np.isclose(out["hi"], vals.max())
         assert np.isclose(out["total"], vals.sum())
 
-    def test_groupby_ground_truth(self, cluster):
+    def test_groupby_ground_truth(self, ray_shared):
         ds, keys, vals = _multiblock()
         got = {r["k"]: r for r in ds.groupby("k").mean("v").take_all()}
         for k in np.unique(keys):
             expect = vals[keys == k].mean()
             assert np.isclose(got[int(k)]["mean(v)"], expect), (k, got)
 
-    def test_groupby_count_sums_to_total(self, cluster):
+    def test_groupby_count_sums_to_total(self, ray_shared):
         ds, keys, _ = _multiblock()
         rows = ds.groupby("k").count().take_all()
         cc = next(c for c in rows[0] if c.startswith("count"))
@@ -62,12 +54,12 @@ class TestAggregationGroundTruth:
         for r in rows:
             assert r[cc] == int((keys == r["k"]).sum())
 
-    def test_unique_multiblock(self, cluster):
+    def test_unique_multiblock(self, ray_shared):
         ds, keys, _ = _multiblock()
         assert sorted(ds.unique("k")) == sorted(
             int(x) for x in np.unique(keys))
 
-    def test_sort_ground_truth_across_blocks(self, cluster):
+    def test_sort_ground_truth_across_blocks(self, ray_shared):
         ds, _, vals = _multiblock()
         got = [r["v"] for r in ds.sort("v").take_all()]
         assert np.allclose(got, np.sort(vals))
@@ -77,7 +69,7 @@ class TestAggregationGroundTruth:
 
 
 class TestSplitSemantics:
-    def test_split_at_indices_row_exact(self, cluster):
+    def test_split_at_indices_row_exact(self, ray_shared):
         """Pieces hold EXACTLY their row ranges even when cuts land
         mid-block (blocks of ~15 rows, cuts at 7/23/88)."""
         ds = data_range(100, parallelism=7)
@@ -88,7 +80,7 @@ class TestSplitSemantics:
         assert rows[2] == list(range(23, 88))
         assert rows[3] == list(range(88, 100))
 
-    def test_split_at_indices_keeps_interior_blocks_by_ref(self, cluster):
+    def test_split_at_indices_keeps_interior_blocks_by_ref(self, ray_shared):
         """The round-5 redesign: interior blocks move by REFERENCE (no
         row rewrite).  A single piece covering whole blocks shares block
         count with the source."""
@@ -105,13 +97,13 @@ class TestSplitSemantics:
             for r in p._materialized:
                 assert r.hex() in src
 
-    def test_split_at_indices_empty_and_clamped(self, cluster):
+    def test_split_at_indices_empty_and_clamped(self, ray_shared):
         ds = data_range(10, parallelism=3)
         pieces = ds.split_at_indices([0, 5, 5, 50])
         counts = [p.count() for p in pieces]
         assert counts == [0, 5, 0, 5, 0]
 
-    def test_split_proportionately_ground_truth(self, cluster):
+    def test_split_proportionately_ground_truth(self, ray_shared):
         ds = data_range(100, parallelism=6)
         a, b, c = ds.split_proportionately([0.3, 0.5])
         assert (a.count(), b.count(), c.count()) == (30, 50, 20)
@@ -120,7 +112,7 @@ class TestSplitSemantics:
               [r["id"] for r in c.take_all()]
         assert got == list(range(100))
 
-    def test_train_test_split_partition(self, cluster):
+    def test_train_test_split_partition(self, ray_shared):
         ds = data_range(50, parallelism=4)
         train, test = ds.train_test_split(0.25)
         # floor semantics: the train cut lands at int(50 * 0.75) == 37
@@ -131,7 +123,7 @@ class TestSplitSemantics:
 
 
 class TestRandomSampleStatistics:
-    def test_seeded_sample_varies_across_blocks(self, cluster):
+    def test_seeded_sample_varies_across_blocks(self, ray_shared):
         """Round-4 advisor medium: with a seed, every block drew the
         IDENTICAL keep-mask.  Multi-block sampling must not keep the
         same row positions in each block."""
@@ -145,13 +137,13 @@ class TestRandomSampleStatistics:
         distinct = {frozenset(p) for p in positions}
         assert len(distinct) > 1, "identical keep-mask in every block"
 
-    def test_seeded_sample_deterministic(self, cluster):
+    def test_seeded_sample_deterministic(self, ray_shared):
         ds = data_range(200, parallelism=4)
         a = [r["id"] for r in ds.random_sample(0.4, seed=11).take_all()]
         b = [r["id"] for r in ds.random_sample(0.4, seed=11).take_all()]
         assert a == b
 
-    def test_sample_fraction_bounds(self, cluster):
+    def test_sample_fraction_bounds(self, ray_shared):
         ds = data_range(400, parallelism=4)
         kept = ds.random_sample(0.5, seed=3).count()
         assert 120 <= kept <= 280, kept       # ~Binomial(400, .5)

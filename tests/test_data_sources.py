@@ -9,16 +9,7 @@ import pytest
 from ray_tpu import data as rdata
 
 
-@pytest.fixture(scope="module")
-def cluster():
-    import ray_tpu
-
-    if not ray_tpu.is_initialized():
-        ray_tpu.init(resources={"CPU": 4})
-    yield
-
-
-def test_read_images(cluster, tmp_path):
+def test_read_images(ray_shared, tmp_path):
     from PIL import Image
 
     for i in range(3):
@@ -34,7 +25,7 @@ def test_read_images(cluster, tmp_path):
         assert int(img[0, 0, 0]) == i * 40
 
 
-def test_read_binary_files(cluster, tmp_path):
+def test_read_binary_files(ray_shared, tmp_path):
     payloads = {f"f{i}.bin": bytes([i]) * (100 + i) for i in range(3)}
     for name, data in payloads.items():
         (tmp_path / name).write_bytes(data)
@@ -45,7 +36,7 @@ def test_read_binary_files(cluster, tmp_path):
         assert r["bytes"] == payloads[name]
 
 
-def test_tfrecord_roundtrip(cluster, tmp_path):
+def test_tfrecord_roundtrip(ray_shared, tmp_path):
     records = [f"record-{i}".encode() * (i + 1) for i in range(7)]
     ds = rdata.from_items([{"record": r} for r in records])
     out = tmp_path / "tfr"
@@ -54,7 +45,7 @@ def test_tfrecord_roundtrip(cluster, tmp_path):
     assert sorted(r["record"] for r in back) == sorted(records)
 
 
-def test_tfrecord_corruption_detected(cluster, tmp_path):
+def test_tfrecord_corruption_detected(ray_shared, tmp_path):
     ds = rdata.from_items([{"record": b"x" * 64}])
     out = tmp_path / "tfr"
     ds.write_tfrecords(str(out))
@@ -66,7 +57,7 @@ def test_tfrecord_corruption_detected(cluster, tmp_path):
         rdata.read_tfrecords(str(out), verify=True).take_all()
 
 
-def test_dataset_stats(cluster):
+def test_dataset_stats(ray_shared):
     ds = rdata.range(1000, parallelism=4).map_batches(
         lambda b: {"id": b["id"] * 2}).filter(lambda r: r["id"] % 4 == 0)
     assert "not been executed" in ds.stats()
@@ -79,7 +70,7 @@ def test_dataset_stats(cluster):
 
 
 class TestRound4Connectors:
-    def test_read_sql_sqlite(self, cluster, tmp_path):
+    def test_read_sql_sqlite(self, ray_shared, tmp_path):
         import sqlite3
 
         db = str(tmp_path / "t.db")
@@ -96,7 +87,7 @@ class TestRound4Connectors:
         assert out == [{"k": "a", "v": 1}, {"k": "b", "v": 2},
                        {"k": "c", "v": 3}]
 
-    def test_avro_roundtrip(self, cluster, tmp_path):
+    def test_avro_roundtrip(self, ray_shared, tmp_path):
         from ray_tpu.data.datasource import write_avro
         import ray_tpu.data as rd
 
@@ -121,7 +112,7 @@ class TestRound4Connectors:
             assert list(g["tags"]) == r["tags"]     # arrow -> ndarray
             assert g["note"] == r["note"]
 
-    def test_read_webdataset(self, cluster, tmp_path):
+    def test_read_webdataset(self, ray_shared, tmp_path):
         import io
         import tarfile
 
@@ -140,7 +131,7 @@ class TestRound4Connectors:
         assert [r["__key__"] for r in rows] == ["s0", "s1"]
         assert rows[0]["jpg"] == b"IMGs0" and rows[1]["cls"] == b"1"
 
-    def test_from_huggingface_local(self, cluster):
+    def test_from_huggingface_local(self, ray_shared):
         import datasets as hfds
         import ray_tpu.data as rd
 

@@ -81,10 +81,10 @@ def traced_run(small):
         time.sleep(0.15)
         # the measured stretch starts here (stop/start above leaves the
         # thread's timeline with holes: there is no thread in them)
-        mark, steps0 = time.time(), eng.decode_steps
+        mark, steps0 = time.time(), eng.work["decode_steps"]
         first = [eng.submit(_prompt(100, i), max_new_tokens=81,
                             _cache_ok=False) for i in range(3)]
-        while eng.decode_steps < steps0 + 5 * 4:     # 5 windows
+        while eng.work["decode_steps"] < steps0 + 5 * 4:     # 5 windows
             time.sleep(0.002)
         second = eng.submit(_prompt(40, 9), max_new_tokens=9,
                             _cache_ok=False)

@@ -485,17 +485,28 @@ def test_an_unclamped_swiglu_exceeds_the_tolerance(params, monkeypatch):
 def test_the_seam_declares_what_the_engine_counts():
     model = serving_model(CFG)
     assert model is glm5_next
-    assert model.lane_state_layers(CFG) == 3 and model.CACHE_KIND == "latent"
-    assert model.selection(CFG) == (1, 4, 16)
-    assert model.scan_chunk(CFG) == 8 and model.routed_layers(CFG) == 3
-    streamed, multiplied = model.prefill_params(CFG)
+    spec = model.serving_spec(CFG)
+    assert spec.lane_state_layers == 3 and spec.routed_layers == 3
+    assert not spec.caps
+    # one sparse layer that selects 16 rows by groups of 4, three KDA
+    # layers whose scan walks chunks of 8: through what they count
+    assert spec.decode_work([40], 1)[0] == {
+        "ssm_lane_steps": 3, "dsa_rows_context": 41,
+        "dsa_groups_scored": 10, "dsa_rows_selected": 16 + 1}
+    assert spec.prefill_work([9, 17], 32) == (
+        {"prefill_scan_chunks": 3 * (2 + 3),
+         "prefill_scan_chunks_dense": 3 * 2 * 4}, {"scan_chunks": 15})
+    # its prefill attention is not `flash_fwd`
+    assert "prefill_attn_blocks" not in spec.counters
+    streamed, multiplied = spec.prefill_params
+    assert (streamed, multiplied) == model.prefill_params(CFG)
     assert streamed > multiplied > 0
     big = glm5_next.Glm5NextConfig(
         vocab_size=19456, layer_types=(glm5_next.KDA, glm5_next.DSA)
         + (glm5_next.KDA,) * 3, ffn_types=("dense",) + ("sparse",) * 4,
         experts_held=(0, 36))
     # the ISSUE's arithmetic: 17.37 MB of lane state a row
-    assert glm5_next.prefill_state_bytes(big) == 4 * (4194304 + 147456) + 512
+    assert glm5_next.serving_spec(big).prefill_state_bytes == 4 * (4194304 + 147456) + 512
     assert glm5_next.kda.max_chunk(big.gate_lower_bound) == big.kda_chunk
 
 

@@ -113,14 +113,14 @@ def test_engine_generates_the_reference_tokens(params):
                 for p, o in zip(prompts, outs))
     assert clear >= 5 * 14 - 3
     # the routed layers' counters, and the lane state's declaration
-    loop = st["loop"]
+    loop, routed_layers = st["loop"], lfm2.serving_spec(CFG).routed_layers
     assert loop["moe_layer_steps"] > 0
-    assert loop["moe_layer_steps"] % (lfm2.routed_layers(CFG) * K) == 0
+    assert loop["moe_layer_steps"] % (routed_layers * K) == 0
     assert 0 < loop["moe_experts_hit"] <= 8 * loop["moe_layer_steps"]
     assert loop["moe_max_load"] >= loop["moe_assignments"] \
         / (8 * loop["moe_layer_steps"])
     assert loop["prefill_moe_assignments"] >= 4 * sum(map(len, prompts)) \
-        * lfm2.routed_layers(CFG)
+        * routed_layers
     assert st["lane_state"] == {
         "layers": 2, "bytes": 2 * 2 * 2 * 64 * 4,
         "by_kind": {"rows": 2 * 2 * 2 * 64 * 4},
@@ -499,7 +499,7 @@ def test_prefill_params_equal_a_count_over_the_tree(params):
                   for k in ("w13", "w2"))
     matmul = sum(a.size for lp in params["layers"]
                  for k, a in lp.items() if a.ndim >= 2 and k != "conv_w")
-    assert experts == lfm2.routed_layers(CFG) * CFG.n_experts \
+    assert experts == lfm2.serving_spec(CFG).routed_layers * CFG.n_experts \
         * 3 * CFG.dim * CFG.moe_ffn_dim
     streamed, multiplied = lfm2.prefill_params(CFG)
     assert streamed == matmul
@@ -520,8 +520,9 @@ def test_prefill_params_equal_a_count_over_the_tree(params):
     assert {b for w, b in small._prefill_programs if w == 4} \
         >= {small._buckets[-1]}
     for mod, name in ((llama, "debug"), (ssm_hybrid, "ssm-hybrid-debug")):
-        assert not hasattr(mod, "prefill_params")
-        dense = LLMEngine(mod.serving_configs()[name], max_batch=16,
+        dense_cfg = mod.serving_configs()[name]
+        assert mod.serving_spec(dense_cfg).prefill_params is None
+        dense = LLMEngine(dense_cfg, max_batch=16,
                           max_len=64, page_size=PAGE)
         assert dense._prefill_floor == FLOOR_TOKENS
         assert dense._width_buckets == [1, 8, 16]

@@ -145,7 +145,7 @@ def test_engine_generates_the_reference_tokens(params):
     clear = sum(_reference_agrees(params, p, o["tokens"])
                 for p, o in zip(prompts, outs))
     assert clear >= 5 * 14 - 3
-    loop, routed_layers = st["loop"], mla_moe.routed_layers(CFG)
+    loop, routed_layers = st["loop"], mla_moe.serving_spec(CFG).routed_layers
     assert loop["moe_layer_steps"] % (routed_layers * K) == 0
     # every selection is either computed here or an absent chip's
     assert loop["moe_assignments"] > 0 and loop["moe_assignments_absent"] > 0
@@ -190,7 +190,7 @@ def test_the_share_of_the_visit_list_that_is_work(held):
         loop = eng.stats()["loop"]
     finally:
         eng.stop()
-    G, layers = held[1] - held[0], mla_moe.routed_layers(cfg)
+    G, layers = held[1] - held[0], mla_moe.serving_spec(cfg).routed_layers
     assert loop["prefill_moe_layer_steps"] == layers
     bucket = min(b for w, b in eng._prefill_programs if w == 1 and b >= 64)
     assert loop["prefill_padded_tokens"] == bucket
@@ -422,9 +422,9 @@ def test_prefill_params_equal_a_count_over_the_tree(params):
                  for k in ("sw1", "sw2", "sw3"))
     matmul = sum(a.size for lp in params["layers"] for a in lp.values()
                  if a.ndim >= 2)
-    assert experts == mla_moe.routed_layers(CFG) * held \
+    assert experts == mla_moe.serving_spec(CFG).routed_layers * held \
         * 3 * CFG.dim * CFG.moe_ffn_dim
-    assert shared == mla_moe.routed_layers(CFG) * 3 * CFG.dim \
+    assert shared == mla_moe.serving_spec(CFG).routed_layers * 3 * CFG.dim \
         * CFG.moe_ffn_dim * CFG.n_shared_experts
     streamed, multiplied = mla_moe.prefill_params(CFG)
     assert streamed == matmul

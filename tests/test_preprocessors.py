@@ -23,14 +23,7 @@ from ray_tpu.data.preprocessors import (Chain, Concatenator,
                                         UniformKBinsDiscretizer)
 
 
-@pytest.fixture(scope="module")
-def rt():
-    if not ray_tpu.is_initialized():
-        ray_tpu.init(resources={"CPU": 4})
-    yield ray_tpu
-
-
-def test_standard_scaler_distributed_fit(rt):
+def test_standard_scaler_distributed_fit(ray_shared):
     vals = np.arange(20, dtype=np.float64)
     ds = data.from_items([{"a": float(v), "b": 1.0} for v in vals])
     sc = StandardScaler(["a"]).fit(ds)
@@ -42,12 +35,12 @@ def test_standard_scaler_distributed_fit(rt):
     assert np.all(out["b"] == 1.0)          # untouched column
 
 
-def test_unfitted_raises(rt):
+def test_unfitted_raises(ray_shared):
     with pytest.raises(PreprocessorNotFittedException):
         StandardScaler(["a"]).transform_batch({"a": np.ones(3)})
 
 
-def test_minmax_maxabs_robust(rt):
+def test_minmax_maxabs_robust(ray_shared):
     ds = data.from_items([{"a": float(v)} for v in [-4, -2, 0, 2, 4, 6]])
     mm = MinMaxScaler(["a"]).fit(ds)
     out = mm.transform_batch({"a": np.array([-4.0, 6.0])})
@@ -59,7 +52,7 @@ def test_minmax_maxabs_robust(rt):
         {"a": np.array([rs.stats_["a"]["median"]])})["a"][0] == 0.0
 
 
-def test_encoders(rt):
+def test_encoders(ray_shared):
     rows = [{"color": c, "label": l}
             for c, l in [("red", "x"), ("blue", "y"), ("red", "x"),
                          ("green", "z")]]
@@ -82,7 +75,7 @@ def test_encoders(rt):
     assert b["color_green"].tolist() == [0, 0]
 
 
-def test_multihot_encoder(rt):
+def test_multihot_encoder(ray_shared):
     ds = data.from_items([{"tags": ["a", "b"]}, {"tags": ["b", "c", "b"]}])
     mh = MultiHotEncoder(["tags"]).fit(ds)
     out = mh.transform_batch(
@@ -92,7 +85,7 @@ def test_multihot_encoder(rt):
     assert out["tags"][1].tolist() == [0, 2, 1]
 
 
-def test_simple_imputer(rt):
+def test_simple_imputer(ray_shared):
     ds = data.from_items([{"a": 1.0}, {"a": 3.0}, {"a": float("nan")}])
     im = SimpleImputer(["a"], strategy="mean").fit(ds)
     out = im.transform_batch({"a": np.array([np.nan, 5.0])})
@@ -105,14 +98,14 @@ def test_simple_imputer(rt):
     assert mf.stats_["c"] == "x"
 
 
-def test_nan_is_not_a_category(rt):
+def test_nan_is_not_a_category(ray_shared):
     ds = data.from_items([{"a": 1.0}, {"a": float("nan")},
                           {"a": 2.0}, {"a": float("nan")}])
     oe = OrdinalEncoder(["a"]).fit(ds)
     assert len(oe.stats_["a"]) == 2          # 1.0 and 2.0 only
 
 
-def test_constant_imputer_fits_all_missing_column(rt):
+def test_constant_imputer_fits_all_missing_column(ray_shared):
     """Chain fits every stage; a constant imputer must not run (or
     crash in) the most_frequent aggregation."""
     ds = data.from_items([{"a": float("nan")}, {"a": float("nan")}])
@@ -123,7 +116,7 @@ def test_constant_imputer_fits_all_missing_column(rt):
         SimpleImputer(["a"], strategy="most_frequent").fit(ds)
 
 
-def test_discretizers(rt):
+def test_discretizers(ray_shared):
     ds = data.from_items([{"a": float(v)} for v in np.arange(0, 10)])
     ud = UniformKBinsDiscretizer(["a"], bins=3).fit(ds)
     out = ud.transform(ds).to_numpy()["a"]
@@ -133,7 +126,7 @@ def test_discretizers(rt):
     assert got["a"].tolist() == [0, 1, 2]
 
 
-def test_stateless_transforms(rt):
+def test_stateless_transforms(ray_shared):
     nm = Normalizer(["v"], norm="l2")
     out = nm.transform_batch({"v": np.array([[3.0, 4.0]])})
     assert out["v"][0].tolist() == [0.6, 0.8]
@@ -153,7 +146,7 @@ def test_stateless_transforms(rt):
     assert got["t"][0] == ["a", "b"]
 
 
-def test_vectorizers_and_hasher(rt):
+def test_vectorizers_and_hasher(ray_shared):
     ds = data.from_items([{"t": "red red blue"}, {"t": "green blue"}])
     cv = CountVectorizer(["t"]).fit(ds)
     out = cv.transform_batch({"t": np.array(["red blue blue"])})
@@ -172,7 +165,7 @@ def test_vectorizers_and_hasher(rt):
     assert out["hashed_features"].sum() == 3.0
 
 
-def test_chain_and_dataset_roundtrip(rt):
+def test_chain_and_dataset_roundtrip(ray_shared):
     ds = data.from_items([{"a": float(v), "c": "u" if v % 2 else "v"}
                           for v in np.arange(8)])
     chain = Chain(SimpleImputer(["a"], strategy="mean"),
@@ -187,7 +180,7 @@ def test_chain_and_dataset_roundtrip(rt):
     assert b["c"][0] == 0
 
 
-def test_preprocessor_pickles_through_tasks(rt):
+def test_preprocessor_pickles_through_tasks(ray_shared):
     """A fitted preprocessor ships to workers (AIR pattern: fit on the
     driver, transform inside map_batches tasks)."""
     ds = data.from_items([{"a": float(v)} for v in np.arange(10)])

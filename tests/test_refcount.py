@@ -9,16 +9,8 @@ import gc
 import time
 
 import numpy as np
-import pytest
 
 import ray_tpu
-
-
-@pytest.fixture(scope="module")
-def rt():
-    if not ray_tpu.is_initialized():
-        ray_tpu.init(resources={"CPU": 4})
-    yield ray_tpu
 
 
 def _wait(cond, timeout=10.0, msg=""):
@@ -30,7 +22,7 @@ def _wait(cond, timeout=10.0, msg=""):
     raise AssertionError(f"condition never held: {msg}")
 
 
-def test_borrow_released_after_task(rt):
+def test_borrow_released_after_task(ray_shared):
     from ray_tpu._private.worker import global_worker
 
     core = global_worker()
@@ -53,7 +45,7 @@ def test_borrow_released_after_task(rt):
     _wait(lambda: oid not in core.owned, msg="object not freed after del")
 
 
-def test_fire_and_forget_return_not_leaked(rt):
+def test_fire_and_forget_return_not_leaked(ray_shared):
     """A return ref dropped before the reply arrives must not resurrect
     the owned record, and the executor's contained pins must release
     (regression: _on_task_reply used setdefault and pinned forever)."""
@@ -83,7 +75,7 @@ def test_fire_and_forget_return_not_leaked(rt):
     _wait(lambda: inner_oid not in core.owned, msg="inner not freed")
 
 
-def test_executing_worker_cache_does_not_pin(rt):
+def test_executing_worker_cache_does_not_pin(ray_shared):
     """After a task completes, the executing worker's cached copies of
     its arg values must not keep pinning refs nested inside them
     (regression: borrower memory cache held nested ObjectRef instances
@@ -117,7 +109,7 @@ def test_executing_worker_cache_does_not_pin(rt):
     _wait(lambda: container_oid not in core.owned, msg="container leaked")
 
 
-def test_borrow_held_by_actor_pins_object(rt):
+def test_borrow_held_by_actor_pins_object(ray_shared):
     from ray_tpu._private.worker import global_worker
 
     core = global_worker()

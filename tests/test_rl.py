@@ -4,16 +4,6 @@ Mirrors ray: rllib/**/tests (learning tests assert reward improvement on
 CartPole with small budgets — e.g. rllib/algorithms/ppo/tests/test_ppo.py).
 """
 import numpy as np
-import pytest
-
-import ray_tpu
-
-
-@pytest.fixture(scope="module")
-def rt():
-    if not ray_tpu.is_initialized():
-        ray_tpu.init(resources={"CPU": 4})
-    yield ray_tpu
 
 
 def test_cartpole_env_dynamics():
@@ -33,7 +23,7 @@ def test_cartpole_env_dynamics():
     assert 1 <= steps <= 500
 
 
-def test_env_runner_sampling(rt):
+def test_env_runner_sampling(ray_shared):
     import jax
 
     from ray_tpu.rl import models
@@ -51,7 +41,7 @@ def test_env_runner_sampling(rt):
     group.stop()
 
 
-def test_ppo_learns_cartpole(rt):
+def test_ppo_learns_cartpole(ray_shared):
     from ray_tpu.rl import PPOConfig
 
     config = (PPOConfig()
@@ -79,7 +69,7 @@ def test_ppo_learns_cartpole(rt):
     assert best > first * 1.2 or best >= 100.0
 
 
-def test_dqn_machinery(rt):
+def test_dqn_machinery(ray_shared):
     from ray_tpu.rl import DQNConfig
 
     config = (DQNConfig()
@@ -97,7 +87,7 @@ def test_dqn_machinery(rt):
     algo.cleanup()
 
 
-def test_algorithm_checkpoint_roundtrip(rt, tmp_path):
+def test_algorithm_checkpoint_roundtrip(ray_shared, tmp_path):
     from ray_tpu.rl import PPOConfig
 
     algo = (PPOConfig().environment("CartPole-v1")
@@ -122,7 +112,7 @@ def test_algorithm_checkpoint_roundtrip(rt, tmp_path):
     algo2.cleanup()
 
 
-def test_impala_vtrace_learns(rt):
+def test_impala_vtrace_learns(ray_shared):
     from ray_tpu.rl import IMPALAConfig
 
     config = (IMPALAConfig()
@@ -147,7 +137,7 @@ def test_impala_vtrace_learns(rt):
     assert best >= 40.0, f"IMPALA failed to improve: best={best:.1f}"
 
 
-def test_sac_machinery(rt):
+def test_sac_machinery(ray_shared):
     from ray_tpu.rl import SACConfig
 
     config = (SACConfig()
@@ -168,7 +158,7 @@ def test_sac_machinery(rt):
     algo.cleanup()
 
 
-def test_bc_offline_cloning(rt):
+def test_bc_offline_cloning(ray_shared):
     """BC clones an expert policy from logged (obs, action) pairs without
     env interaction during updates (ray: rllib/algorithms/bc over
     offline data)."""

@@ -6,15 +6,6 @@ import numpy as np
 import pytest
 
 
-@pytest.fixture(scope="module")
-def rt():
-    import ray_tpu
-
-    if not ray_tpu.is_initialized():
-        ray_tpu.init(resources={"CPU": 4})
-    yield ray_tpu
-
-
 def _expert_transitions(n_steps: int, seed: int = 3) -> dict:
     """Logged transitions from the lean-direction expert (+ light
     exploration noise so Q-learning sees off-policy actions)."""
@@ -46,7 +37,7 @@ def _expert_transitions(n_steps: int, seed: int = 3) -> dict:
     }
 
 
-def test_cql_offline_learns(rt):
+def test_cql_offline_learns(ray_shared):
     """CQL learns a usable policy from logged transitions only: greedy
     eval return beats the random-policy baseline (~20 on CartPole)."""
     from ray_tpu.rl import CQLConfig
@@ -67,7 +58,7 @@ def test_cql_offline_learns(rt):
     assert ret > 45, f"CQL offline policy too weak: return={ret:.1f}"
 
 
-def test_multicartpole_env_protocol(rt):
+def test_multicartpole_env_protocol(ray_shared):
     from ray_tpu.rl import MultiCartPole
 
     env = MultiCartPole(seed=0, num_agents=3)
@@ -91,7 +82,7 @@ def test_multicartpole_env_protocol(rt):
         raise AssertionError("no episode ever ended")
 
 
-def test_multi_agent_ppo_learns(rt):
+def test_multi_agent_ppo_learns(ray_shared):
     """Shared-policy multi-agent PPO on MultiCartPole: pooled episode
     return improves well past the random baseline (~20)."""
     from ray_tpu.rl import MultiAgentPPOConfig
@@ -115,7 +106,7 @@ def test_multi_agent_ppo_learns(rt):
     assert best > 60, f"multi-agent PPO failed to learn: best={best:.1f}"
 
 
-def test_multi_agent_distinct_policies(rt):
+def test_multi_agent_distinct_policies(ray_shared):
     """Two policies, one per agent: batches route to the right learner
     and both policies update."""
     from ray_tpu.rl import MultiAgentPPOConfig
@@ -141,7 +132,7 @@ def test_multi_agent_distinct_policies(rt):
     algo.cleanup()
 
 
-def test_appo_vtrace_clip_learns(rt):
+def test_appo_vtrace_clip_learns(ray_shared):
     """APPO (rllib: algorithms/appo/appo.py:277): clipped surrogate on
     V-trace advantages + target-net KL.  Seeded threshold like IMPALA's."""
     from ray_tpu.rl import APPOConfig
@@ -170,7 +161,7 @@ def test_appo_vtrace_clip_learns(rt):
     assert best >= 40.0, f"APPO failed to improve: best={best:.1f}"
 
 
-def test_connector_pipeline_surgery(rt):
+def test_connector_pipeline_surgery(ray_shared):
     """ConnectorV2 pipelines (rllib: connectors/connector_v2.py:29):
     composition, list surgery, and the shared env->learner pieces."""
     from ray_tpu.rl.connectors import (ConcatFragments, ConnectorCtx,
@@ -213,7 +204,7 @@ def test_connector_pipeline_surgery(rt):
         pipe.insert_after("Missing", norm)
 
 
-def test_marwil_offline_learns(rt):
+def test_marwil_offline_learns(ray_shared):
     """MARWIL (rllib: algorithms/marwil/marwil.py): advantage-weighted
     cloning beats the random baseline from logged transitions only, and
     the exp-weights actually spread (beta>0 is not plain BC)."""
@@ -236,7 +227,7 @@ def test_marwil_offline_learns(rt):
     assert ret > 45, f"MARWIL offline policy too weak: return={ret:.1f}"
 
 
-def test_marwil_beta_zero_is_bc(rt):
+def test_marwil_beta_zero_is_bc(ray_shared):
     """beta=0 collapses the weight to 1: loss equals plain BC's NLL."""
     import jax.numpy as jnp
 
@@ -260,7 +251,7 @@ def test_marwil_beta_zero_is_bc(rt):
     assert abs(float(m_aux["mean_weight"]) - 1.0) < 1e-6
 
 
-def test_dreamerv3_machinery(rt):
+def test_dreamerv3_machinery(ray_shared):
     """DreamerV3 (rllib: algorithms/dreamerv3): RSSM world model +
     imagination-trained actor-critic.  Machinery test in the style of
     SAC/DQN's: the world model demonstrably learns (reconstruction +

@@ -52,7 +52,7 @@ def _classes():
     return ArmableWorker, ArmableLearner
 
 
-@pytest.fixture(scope="module")
+@pytest.fixture
 def rt():
     if not ray_tpu.is_initialized():
         ray_tpu.init(resources={"CPU": 6})

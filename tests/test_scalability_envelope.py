@@ -3,19 +3,11 @@ single-node envelope: many args to one task, many returns, deep task
 backlogs).  Scaled for the 1-core CI box; the full reference-scale
 points (10k args to one task, 3k returns from one) are not run here.
 """
-import pytest
 
 import ray_tpu
 
 
-@pytest.fixture(scope="module")
-def rt():
-    if not ray_tpu.is_initialized():
-        ray_tpu.init(resources={"CPU": 4})
-    yield ray_tpu
-
-
-def test_many_args_to_one_task(rt):
+def test_many_args_to_one_task(ray_shared):
     @ray_tpu.remote
     def count_args(*args):
         return len(args), args[0], args[-1]
@@ -25,7 +17,7 @@ def test_many_args_to_one_task(rt):
     assert (n, first, last) == (1000, 0, 999)
 
 
-def test_many_returns_from_one_task(rt):
+def test_many_returns_from_one_task(ray_shared):
     @ray_tpu.remote
     def fan_out(k):
         return tuple(range(k))
@@ -35,7 +27,7 @@ def test_many_returns_from_one_task(rt):
     assert len(out) == 500 and out[0] == 0 and out[499] == 499
 
 
-def test_deep_task_backlog(rt):
+def test_deep_task_backlog(ray_shared):
     """A backlog far deeper than the worker pool must queue, drain
     completely, and preserve results (ray: 1M queued tasks point)."""
     @ray_tpu.remote
@@ -48,7 +40,7 @@ def test_deep_task_backlog(rt):
     assert got == list(range(n))
 
 
-def test_repeated_10k_arg_bursts_no_reply_loss(rt):
+def test_repeated_10k_arg_bursts_no_reply_loss(ray_shared):
     """Regression: a task resolving 10k top-level arg refs fires 10k
     concurrent resolve_object RPCs at the owner; the owner's ROUTER at
     the default zmq SNDHWM (1000) silently DROPPED ~30 replies per

@@ -446,7 +446,7 @@ def test_the_planner_bounds_a_programs_width_by_the_state_handed_over(
 
 def test_the_published_state_fits_eight_rows_a_program():
     cfg = named_config("granite-4.0-h-micro")
-    row = ssm_hybrid.prefill_state_bytes(cfg)
+    row = ssm_hybrid.serving_spec(cfg).prefill_state_bytes
     assert row == 36 * (128 * 4096 * 4 + 3 * 4352 * 2) == 76_437_504
     assert 8 * row <= PREFILL_MAX_STATE_BYTES < 16 * row
 

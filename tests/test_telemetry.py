@@ -327,12 +327,8 @@ def test_summarize_tasks_durations(ray_shared):
 
 
 # -------------------------------------------------- dashboard surfaces
-@pytest.fixture(scope="module")
-def dash():
-    import ray_tpu
-
-    if not ray_tpu.is_initialized():
-        ray_tpu.init(resources={"CPU": 4})
+@pytest.fixture
+def dash(ray_shared):
     from ray_tpu.dashboard import start_dashboard
 
     head = start_dashboard(port=0)

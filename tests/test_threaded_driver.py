@@ -15,17 +15,13 @@ import pytest
 import ray_tpu
 
 
-@pytest.fixture(scope="module")
-def cluster():
-    if not ray_tpu.is_initialized():
-        ray_tpu.init(resources={"CPU": 4})
-
+@pytest.fixture
+def cluster(ray_shared):
     @ray_tpu.remote
     def warm():
         return 1
 
     ray_tpu.get([warm.remote() for _ in range(4)], timeout=120)
-    yield
 
 
 def test_concurrent_submit_get(cluster):

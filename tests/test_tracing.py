@@ -7,17 +7,13 @@ import pytest
 import ray_tpu
 
 
-@pytest.fixture(scope="module")
-def cluster():
-    if not ray_tpu.is_initialized():
-        ray_tpu.init(resources={"CPU": 4})
-
+@pytest.fixture
+def cluster(ray_shared):
     @ray_tpu.remote
     def warm():
         return 1
 
     ray_tpu.get([warm.remote() for _ in range(3)])
-    yield
 
 
 def test_trace_propagates_through_nested_tasks(cluster):

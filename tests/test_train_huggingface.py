@@ -11,16 +11,8 @@ import tempfile
 
 import pytest
 
-import ray_tpu
 
 transformers = pytest.importorskip("transformers")
-
-
-@pytest.fixture(scope="module")
-def rt():
-    if not ray_tpu.is_initialized():
-        ray_tpu.init(resources={"CPU": 4})
-    yield ray_tpu
 
 
 def _train_loop(config):
@@ -64,7 +56,7 @@ def _train_loop(config):
     trainer.train()
 
 
-def test_transformers_trainer_reports_and_checkpoints(rt, tmp_path):
+def test_transformers_trainer_reports_and_checkpoints(ray_shared, tmp_path):
     from ray_tpu import data
     from ray_tpu.train import ScalingConfig
     from ray_tpu.train.torch import TorchTrainer
@@ -106,7 +98,7 @@ def RayTrainReportCallbackName():
     return RayTrainReportCallback.CHECKPOINT_NAME
 
 
-def test_prepare_trainer_passthrough_for_torch_dataset(rt):
+def test_prepare_trainer_passthrough_for_torch_dataset(ray_shared):
     """A plain map-style torch dataset keeps the stock dataloaders."""
     import torch
 

@@ -4,19 +4,9 @@ Mirrors ray: python/ray/train/tests/test_torch_trainer.py (CPU/gloo
 configuration — the reference's tests run the same way on laptop CI).
 """
 import numpy as np
-import pytest
-
-import ray_tpu
 
 
-@pytest.fixture(scope="module")
-def rt():
-    if not ray_tpu.is_initialized():
-        ray_tpu.init(resources={"CPU": 4})
-    yield ray_tpu
-
-
-def test_torch_trainer_ddp_gloo(rt):
+def test_torch_trainer_ddp_gloo(ray_shared):
     from ray_tpu.train import ScalingConfig
     from ray_tpu.train.torch import TorchTrainer
 

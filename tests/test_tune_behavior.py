@@ -6,7 +6,6 @@ Reference analogs: ray python/ray/tune/tests/test_api.py (callback
 ordering), test_stopper.py."""
 import threading
 
-import pytest
 
 from ray_tpu import tune
 from ray_tpu.train import RunConfig
@@ -14,15 +13,6 @@ from ray_tpu.tune.callback import Callback
 from ray_tpu.tune.schedulers import (CONTINUE, PAUSE, STOP, FIFOScheduler,
                                      TrialScheduler)
 from ray_tpu.tune.stopper import Stopper
-
-
-@pytest.fixture(scope="module")
-def cluster():
-    import ray_tpu
-
-    if not ray_tpu.is_initialized():
-        ray_tpu.init(resources={"CPU": 4})
-    yield
 
 
 def _loop(config):
@@ -82,7 +72,7 @@ class TestCallbackOrdering:
     def _events_for(self, rec, tid):
         return [k for k, t in rec.events if t == tid]
 
-    def test_lifecycle_order_fifo(self, cluster, tmp_path):
+    def test_lifecycle_order_fifo(self, ray_shared, tmp_path):
         rec = _Recorder()
         tuner = tune.Tuner(
             _loop, param_space={"m": tune.grid_search([1, 2])},
@@ -106,7 +96,7 @@ class TestCallbackOrdering:
         assert rec.events[-1] == ("end", None)
         assert sum(1 for k, _ in rec.events if k == "end") == 1
 
-    def test_pause_resume_ordering(self, cluster, tmp_path):
+    def test_pause_resume_ordering(self, ray_shared, tmp_path):
         """A PAUSEd trial resumes: its events stay well-formed — the
         resume fires a SECOND on_trial_start (actor restart), results
         continue after it, and completion still comes exactly once."""
@@ -132,7 +122,7 @@ class TestCallbackOrdering:
         second_start = len(seq) - 1 - seq[::-1].index("start")
         assert second_start > first_result, seq
 
-    def test_error_path_fires_on_trial_error(self, cluster, tmp_path):
+    def test_error_path_fires_on_trial_error(self, ray_shared, tmp_path):
         def boom(config):
             tune.report({"v": 1, "training_iteration": 1})
             raise RuntimeError("tune-boom")
@@ -173,7 +163,7 @@ class _StopAt(Stopper):
 
 
 class TestStopperSemantics:
-    def test_per_trial_stopper_truncates(self, cluster, tmp_path):
+    def test_per_trial_stopper_truncates(self, ray_shared, tmp_path):
         stopper = _StopAt(bound=2)
         tuner = tune.Tuner(
             _loop, param_space={"m": tune.grid_search([1])},
@@ -189,7 +179,7 @@ class TestStopperSemantics:
         assert [v for _, v in stopper.calls] == [1, 2]
         assert all(tid for tid, _ in stopper.calls)
 
-    def test_stop_all_halts_other_trials(self, cluster, tmp_path):
+    def test_stop_all_halts_other_trials(self, ray_shared, tmp_path):
         stopper = _StopAt(bound=10**9, all_bound=4)
         tuner = tune.Tuner(
             _loop, param_space={"m": tune.grid_search([1, 1, 1])},
@@ -204,7 +194,7 @@ class TestStopperSemantics:
         total_results = len(stopper.calls)
         assert total_results < 12, stopper.calls
 
-    def test_stop_dict_bound(self, cluster, tmp_path):
+    def test_stop_dict_bound(self, ray_shared, tmp_path):
         tuner = tune.Tuner(
             _loop, param_space={"m": tune.grid_search([1])},
             tune_config=tune.TuneConfig(metric="v", mode="max"),

@@ -9,13 +9,6 @@ import pytest
 import ray_tpu
 
 
-@pytest.fixture(scope="module")
-def rt():
-    if not ray_tpu.is_initialized():
-        ray_tpu.init(resources={"CPU": 4})
-    yield ray_tpu
-
-
 def _sq(x):
     return x * x
 
@@ -24,7 +17,7 @@ def _add(a, b):
     return a + b
 
 
-def test_pool_map_apply(rt):
+def test_pool_map_apply(ray_shared):
     from ray_tpu.utils.multiprocessing import Pool
 
     with Pool(processes=2) as p:
@@ -33,7 +26,7 @@ def test_pool_map_apply(rt):
         assert p.starmap(_add, [(1, 2), (3, 4)]) == [3, 7]
 
 
-def test_pool_async_and_imap(rt):
+def test_pool_async_and_imap(ray_shared):
     from ray_tpu.utils.multiprocessing import Pool
 
     with Pool(processes=2) as p:
@@ -47,7 +40,7 @@ def test_pool_async_and_imap(rt):
         assert one.get(timeout=60) == 30
 
 
-def test_pool_closed_rejects(rt):
+def test_pool_closed_rejects(ray_shared):
     from ray_tpu.utils.multiprocessing import Pool
 
     p = Pool(processes=1)
@@ -56,7 +49,7 @@ def test_pool_closed_rejects(rt):
         p.map(_sq, [1])
 
 
-def test_joblib_backend(rt):
+def test_joblib_backend(ray_shared):
     joblib = pytest.importorskip("joblib")
     from ray_tpu.utils.joblib_backend import register_ray_tpu
 
@@ -67,7 +60,7 @@ def test_joblib_backend(rt):
     assert out == [i * i for i in range(8)]
 
 
-def test_dask_on_ray_tpu_scheduler(rt):
+def test_dask_on_ray_tpu_scheduler(ray_shared):
     """Raw dask-graph execution (ray: util/dask/scheduler.py ray_dask_get)
     — the graph format is plain data, so the scheduler tests without dask
     installed."""
@@ -89,7 +82,7 @@ def test_dask_on_ray_tpu_scheduler(rt):
     assert get({"x": "not-a-key"}, "x") == "not-a-key"
 
 
-def test_gbdt_trainer_gates_cleanly(rt):
+def test_gbdt_trainer_gates_cleanly(ray_shared):
     """XGBoostTrainer (ray: train/xgboost) builds the full data-parallel
     run; with xgboost absent from this image the workers surface a clear
     ImportError naming the runtime_env escape hatch."""
@@ -114,7 +107,7 @@ def test_gbdt_trainer_gates_cleanly(rt):
         assert "xgboost" in str(result.error)
 
 
-def test_train_dataset_shards(rt, tmp_path):
+def test_train_dataset_shards(ray_shared, tmp_path):
     """train.get_dataset_shard streams each worker its split (ray:
     DataParallelTrainer + streaming_split): together the two workers
     consume every row exactly once."""
