@@ -17,7 +17,8 @@ _SERVING = {"LlamaConfig": "ray_tpu.models.llama",
             "Lfm2MoeConfig": "ray_tpu.models.lfm2",
             "MlaMoeConfig": "ray_tpu.models.mla_moe",
             "SsmHybridConfig": "ray_tpu.models.ssm_hybrid",
-            "Glm5NextConfig": "ray_tpu.models.glm5_next"}
+            "Glm5NextConfig": "ray_tpu.models.glm5_next",
+            "Dots3NoteConfig": "ray_tpu.models.dots3_note"}
 
 
 def serving_model(cfg):

@@ -187,33 +187,16 @@ def serving_configs() -> dict[str, Glm5NextConfig]:
     }
 
 
-# What the sparse layers report of their learned selection, a live
-# lane's every decode step, x those layers.
-DSA_COUNTERS = {
-    "dsa_rows_context": "Rows in a lane's context at a sparse layer's "
-                        "decode step, summed over live lanes, steps and "
-                        "sparse layers",
-    "dsa_groups_scored": "Complete groups the indexer scored, summed "
-                         "likewise",
-    "dsa_rows_selected": "Rows the selection attended, summed likewise",
-}
+DSA_COUNTERS = dsa.COUNTERS
 
 
 def _decode_work(cfg: Glm5NextConfig, rows, k: int) -> tuple[dict, dict]:
     """One decode window of `k` steps over live lanes that start it on
     `rows` cached rows each: `kda_update`'s lane-steps and what the
-    selection read (`ops/sparse_attention.selection_counts`)."""
-    sel = [0, 0, 0]         # in DSA_COUNTERS' order
-    for r in rows:
-        for ctx in range(r + 1, r + 1 + k):
-            scored, kept = dsa.selection_counts(ctx, cfg.index_pool,
-                                                cfg.index_topk)
-            sel[0] += ctx
-            sel[1] += scored
-            sel[2] += kept
-    work = dict(zip(DSA_COUNTERS, (n * cfg.count(DSA) for n in sel)))
+    selection read (`ops/sparse_attention.decode_work`)."""
     return merged(ssm.update_work(cfg.count(KDA), len(rows), k),
-                  (work, work))
+                  dsa.decode_work(cfg.count(DSA), cfg.index_pool,
+                                  cfg.index_topk, rows, k))
 
 
 def serving_spec(cfg: Glm5NextConfig) -> ServingSpec:

@@ -40,12 +40,14 @@ def _repeat_kv(k: jnp.ndarray, n_rep: int) -> jnp.ndarray:
 def xla_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                   causal: bool = True,
                   q_offset: int | jnp.ndarray = 0,
-                  sm_scale: float | None = None) -> jnp.ndarray:
+                  sm_scale: float | None = None,
+                  window: int | None = None) -> jnp.ndarray:
     """Reference implementation: fp32 softmax, GQA, causal mask.
 
     q: [b, sq, hq, d]; k/v: [b, skv, hkv, d] (v may be narrower).
     q_offset shifts query positions relative to kv positions (decode
-    with a cache); sm_scale defaults to d**-0.5.
+    with a cache); sm_scale defaults to d**-0.5; window (causal only):
+    a query attends its own position and the window - 1 before it.
     """
     n_rep = q.shape[2] // k.shape[2]
     k = _repeat_kv(k, n_rep)
@@ -58,6 +60,8 @@ def xla_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         qpos = jnp.arange(sq)[:, None] + q_offset
         kpos = jnp.arange(skv)[None, :]
         mask = qpos >= kpos
+        if window is not None:
+            mask &= qpos - kpos < window
         logits = jnp.where(mask[None, None], logits, -1e30)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v,
@@ -65,12 +69,14 @@ def xla_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return out.astype(q.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "impl", "sm_scale"))
+@functools.partial(jax.jit, static_argnames=("causal", "impl", "sm_scale",
+                                             "window"))
 def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
               causal: bool = True, impl: str = "auto",
               q_offset: int | jnp.ndarray = 0,
               sm_scale: float | None = None,
-              lengths: jnp.ndarray | None = None) -> jnp.ndarray:
+              lengths: jnp.ndarray | None = None,
+              window: int | None = None) -> jnp.ndarray:
     """Multi-head attention with GQA.
 
     impl: "auto" picks the Pallas flash kernel on TPU for long-enough
@@ -82,7 +88,12 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     for the query blocks wholly past a length and leaves zeros there; the
     XLA path ignores it, and what either gives in a padded row is no
     one's to read: causal true rows need no length.
+    window: a query attends its own position and the window - 1 before
+    it (causal only, forward only); the flash kernel walks no key block
+    that lies wholly before a query block's band.
     """
+    if window is not None and not causal:
+        raise ValueError("a window takes causal attention")
     use_flash = False
     if impl == "flash":
         use_flash = True
@@ -101,13 +112,13 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                             "tokens)", *key)
             _XLA_FALLBACKS[key] = _XLA_FALLBACKS.get(key, 0) + 1
     if use_flash:
-        return _flash_padded(q, k, v, causal, sm_scale, lengths)
+        return _flash_padded(q, k, v, causal, sm_scale, lengths, window)
     return xla_attention(q, k, v, causal=causal, q_offset=q_offset,
-                         sm_scale=sm_scale)
+                         sm_scale=sm_scale, window=window)
 
 
 def _flash_padded(q, k, v, causal: bool, sm_scale: float | None = None,
-                  lengths=None):
+                  lengths=None, window: int | None = None):
     """The flash kernel at any head_dim: a width (q and k's, or v's)
     under 128 lanes is zero-padded to 128, the scale given as the TRUE
     head_dim's; wider ones go as they are (192 / 128 compiles:
@@ -117,19 +128,19 @@ def _flash_padded(q, k, v, causal: bool, sm_scale: float | None = None,
     are cut off."""
     d, dv = q.shape[-1], v.shape[-1]
     if min(d, dv) >= 128:
-        return _flash_per_shard(q, k, v, causal, sm_scale, lengths)
+        return _flash_per_shard(q, k, v, causal, sm_scale, lengths, window)
 
     def pad(a):
         return jnp.pad(a, ((0, 0),) * 3 + ((0, max(128 - a.shape[-1], 0)),))
 
     o = _flash_per_shard(pad(q), pad(k), pad(v), causal,
                          d ** -0.5 if sm_scale is None else sm_scale,
-                         lengths)
+                         lengths, window)
     return o[..., :dv]
 
 
 def _flash_per_shard(q, k, v, causal: bool, sm_scale: float | None = None,
-                     lengths=None):
+                     lengths=None, window: int | None = None):
     """The Pallas kernel; under an ambient multi-device mesh, one call
     per shard (jax refuses a Mosaic kernel under GSPMD at lowering:
     "wrap the call in a shard_map").  The layout — what splits over
@@ -139,7 +150,8 @@ def _flash_per_shard(q, k, v, causal: bool, sm_scale: float | None = None,
     from ray_tpu.parallel.sharding import attention_shard_specs
 
     fn = functools.partial(flash_attention, causal=causal,
-                           sm_scale=sm_scale)
+                           sm_scale=sm_scale,
+                           **({} if window is None else {"window": window}))
     specs = attention_shard_specs(q.shape, k.shape)
     if specs is None:
         return fn(q, k, v, lengths=lengths)

@@ -11,7 +11,11 @@ and no others (`key_blocks`, `_walk`): nothing above the diagonal and, with
 that lies wholly past its row's length, which comes out as zeros.  The
 minor grid axis is the walk, bounded by its longest row's count; the causal
 mask is applied only in the blocks the diagonal crosses; the log-sum-exp is
-an output only of the call that keeps it for the backward.
+an output only of the call that keeps it for the backward.  With `window`
+(a sliding-window layer: a query attends its own position and the window -
+1 before it) the walk of a query block starts at the key block its band
+starts in (`first_key_blocks`), and the blocks the band's lower edge
+crosses are masked like the diagonal's.
 
 This is the framework's own kernel (the reference delegates attention to
 user libraries entirely — ray has no attention op); layout is [b, h, s, d]
@@ -71,10 +75,20 @@ def fit_blocks(sq: int, skv: int, block_q: int = DEFAULT_BLOCK_Q,
     return block_q, block_k
 
 
+def first_key_blocks(sq: int, block_q: int, block_k: int, window: int,
+                     xp=np):
+    """[query blocks]: the first key block a query block reads under a
+    band of `window` positions (a query at t attends t - window + 1 ..
+    t): the one its FIRST row's band starts in."""
+    q_start = xp.arange(-(-sq // block_q)) * block_q
+    return xp.maximum(q_start - (window - 1), 0) // block_k
+
+
 def key_blocks(sq: int, skv: int, lengths, block_q: int, block_k: int,
-               causal: bool = True, xp=np):
+               causal: bool = True, xp=np, window: int | None = None):
     """[rows, query blocks]: how many key blocks are WORK for each query
-    block.  Causal: those up to the one the block's last row ends in;
+    block.  Causal: those up to the one the block's last row ends in
+    (with `window`: from `first_key_blocks` on);
     none for a query block that lies wholly past its row's length
     (`lengths` [rows]; absent: one row, `sq` long, for all).  A block the
     length crosses counts whole: the causal mask keeps its true rows
@@ -83,18 +97,22 @@ def key_blocks(sq: int, skv: int, lengths, block_q: int, block_k: int,
     last_key = xp.minimum(q_start + block_q, skv) - 1 if causal \
         else xp.full_like(q_start, skv - 1)
     n = (last_key // block_k + 1)[None, :]
+    if window is not None:
+        n = n - first_key_blocks(sq, block_q, block_k, window, xp)[None, :]
     if lengths is None:
         return n
     return xp.where(q_start[None, :] < lengths[:, None], n, 0)
 
 
-def attn_blocks(sq: int, lengths, block_q: int, block_k: int) -> int:
+def attn_blocks(sq: int, lengths, block_q: int, block_k: int,
+                window: int | None = None) -> int:
     """The (row, query block, key block) triples that causal
     self-attention over right-padded rows of `lengths` (host integers)
-    multiplies: what `flash_fwd` walks of a dense grid's rows x
-    `sq // block_q` x `sq // block_k`."""
+    multiplies (under a band of `window` positions, if given): what
+    `flash_fwd` walks of a dense grid's rows x `sq // block_q` x
+    `sq // block_k`."""
     return int(key_blocks(sq, sq, np.asarray(lengths), block_q,
-                          block_k).sum())
+                          block_k, window=window).sum())
 
 
 # What a serving module whose prefill calls `flash_fwd` reports of it
@@ -119,14 +137,55 @@ def prefill_work(true_lens, bucket: int) -> tuple[dict, dict]:
             len(true_lens) * -(-bucket // bq) * -(-bucket // bk)}, {}
 
 
-def _walk(n_keys, steps: int, block_q: int, block_k: int, causal: bool, xp):
+# What a serving module whose window layers' prefill is `flash_fwd` under
+# a band reports beside PREFILL_COUNTERS (which then count the banded
+# walk): prefill_swa_blocks / prefill_swa_blocks_dense = the share of the
+# causal walk inside the rows' true lengths that the band leaves.
+BAND_COUNTERS = {
+    "prefill_swa_blocks": "(row, query block, key block) triples flash_fwd "
+                          "multiplies under a window layer's band, a "
+                          "full-prompt prefill program",
+    "prefill_swa_blocks_dense": "The triples the same calls would multiply "
+                                "without the band (causal, inside the true "
+                                "lengths)",
+}
+
+
+def band_blocks(sq: int, block_q: int = DEFAULT_BLOCK_Q,
+                block_k: int = DEFAULT_BLOCK_K) -> tuple[int, int]:
+    """The blocks a BANDED call runs at: `fit_blocks`, the key block no
+    longer than the query block (a query block's band of ~block_q keys
+    then lies in two key blocks; at 1,024 keys a block it read three
+    halves of what it needs)."""
+    block_q, block_k = fit_blocks(sq, sq, block_q, block_k)
+    return block_q, min(block_q, block_k)
+
+
+def band_work(window: int, true_lens, bucket: int) -> tuple[dict, dict]:
+    """`prefill_work` of a program whose `flash_fwd` calls run under a
+    band of `window` positions."""
+    bq, bk = band_blocks(bucket)
+    walked = attn_blocks(bucket, true_lens, bq, bk, window)
+    return {"prefill_attn_blocks": walked,
+            "prefill_attn_blocks_dense":
+            len(true_lens) * -(-bucket // bq) * -(-bucket // bk),
+            "prefill_swa_blocks": walked,
+            "prefill_swa_blocks_dense":
+            attn_blocks(bucket, true_lens, bq, bk)}, {}
+
+
+def _walk(n_keys, steps: int, block_q: int, block_k: int, causal: bool, xp,
+          first=None, window: int | None = None):
     """The forward kernel's steps: `n_keys` [rows, query blocks]
     (`key_blocks`) -> qi, ki, flag int32 [rows * steps] (`steps` a row,
     the count a full-length row takes) and the steps each row needs
     [rows].  A query block takes one step a key block that is work, or
     ONE step that only writes its zeros.  The steps past a row's count
     name the blocks already resident, so nothing is copied for them, and
-    do nothing."""
+    do nothing.  Under a band (`window`, `first` [query blocks] =
+    `first_key_blocks`) a query block's key blocks start at its `first`,
+    and a block the band's lower edge crosses is masked like one the
+    diagonal crosses."""
     per_q = xp.maximum(n_keys, 1)
     ends = xp.cumsum(per_q, axis=1)
     total = ends[:, -1]
@@ -139,21 +198,29 @@ def _walk(n_keys, steps: int, block_q: int, block_k: int, causal: bool, xp):
 
     mine, live = of(per_q), of(n_keys) > 0
     ki = at - (of(ends) - mine)
-    crosses = (ki + 1) * block_k - 1 > qi * block_q if causal \
-        else xp.zeros_like(ki, dtype=bool)
+    if window is None:
+        kabs = ki
+        crosses = (ki + 1) * block_k - 1 > qi * block_q if causal \
+            else xp.zeros_like(ki, dtype=bool)
+    else:
+        first = xp.broadcast_to(first[None, :], n_keys.shape)
+        kabs = ki + of(first)
+        crosses = ((kabs + 1) * block_k - 1 > qi * block_q) \
+            | (kabs * block_k < (qi + 1) * block_q - window)
     flag = xp.where(
         p < total[:, None],
         (ki == 0) * _FIRST + (ki == mine - 1) * _LAST
         + live * xp.where(crosses, _EDGE, _INSIDE), 0)
     # a block of zeros names the keys its row multiplied last
-    resident = xp.maximum(n_keys.max(axis=1) - 1, 0)[:, None]
-    ki = xp.where(live, ki, resident)
+    resident = xp.maximum(n_keys.max(axis=1) - 1, 0) if window is None \
+        else xp.where(n_keys > 0, first + n_keys - 1, 0).max(axis=1)
+    ki = xp.where(live, kabs, resident[:, None])
     return tuple(a.astype(xp.int32).reshape(-1)
                  for a in (qi, ki, flag)) + (total,)
 
 
 def _fwd_kernel(qi_ref, ki_ref, flag_ref, q_ref, k_ref, v_ref, o_ref, *refs,
-                sm_scale: float, stride: int):
+                sm_scale: float, stride: int, window: int | None = None):
     """One step of the walk of one (batch row, head): `_walk` names its
     query block, its key block and what to do with them.  The key blocks
     of a query block are consecutive steps of the MINOR grid dimension,
@@ -190,7 +257,10 @@ def _fwd_kernel(qi_ref, ki_ref, flag_ref, q_ref, k_ref, v_ref, o_ref, *refs,
                 jnp.int32, (block_q, block_k), 0)
             kpos = ki_ref[at] * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(qpos >= kpos, s, NEG_INF)
+            keep = qpos >= kpos
+            if window is not None:      # the band: the last `window` keys
+                keep &= qpos - kpos < window
+            s = jnp.where(keep, s, NEG_INF)
         m_prev = m_ref[:, 0]                      # [bq]
         m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
         alpha = jnp.exp(m_prev - m_cur)           # [bq]
@@ -216,26 +286,35 @@ def _fwd_kernel(qi_ref, ki_ref, flag_ref, q_ref, k_ref, v_ref, o_ref, *refs,
 
 
 def _flash_fwd(q, k, v, lengths, sm_scale, causal, block_q, block_k,
-               keep_lse: bool):
+               keep_lse: bool, window: int | None = None):
     """q: [b, hq, sq, d]; k: [b, hkv, skv, d]; v: [b, hkv, skv, dv]
     (dv = d everywhere but latent attention's expanded path, whose keys
     are wider than its values); lengths: int32 [b] or None (every row
     `sq` long) -> o [b, hq, sq, dv], zeros in the query blocks wholly
     past a row's length, and lse [b, hq, sq] if `keep_lse` (the backward
-    kernels' residual), else None."""
+    kernels' residual), else None.  `window`: a query attends its own
+    position and the window - 1 before it, and the key blocks wholly
+    before that band are not walked."""
     b, hq, sq, d = q.shape
     hkv, skv = k.shape[1], k.shape[2]
     dv = v.shape[3]
     n_rep = hq // hkv
-    n_keys, xp = key_blocks(sq, skv, None, block_q, block_k, causal), np
+    if window is not None and not (causal and sq == skv):
+        raise ValueError("a window takes causal self-attention")
+    n_keys, xp = key_blocks(sq, skv, None, block_q, block_k, causal,
+                            window=window), np
     steps = int(n_keys.sum())         # what a row of the full length takes
     if lengths is not None:
         if not causal:
             raise ValueError("lengths take causal attention: without the "
                              "mask a true row sees the padded keys")
         n_keys, xp = key_blocks(sq, skv, lengths.astype(jnp.int32), block_q,
-                                block_k, causal, jnp), jnp
-    *tables, total = _walk(n_keys, steps, block_q, block_k, causal, xp)
+                                block_k, causal, jnp, window), jnp
+    band = {} if window is None else {
+        "first": first_key_blocks(sq, block_q, block_k, window, xp),
+        "window": window}
+    *tables, total = _walk(n_keys, steps, block_q, block_k, causal, xp,
+                           **band)
     # no lengths: one row of tables for every row, all of it static
     stride, n_steps = (0, steps) if lengths is None \
         else (steps, jnp.max(total))
@@ -263,7 +342,8 @@ def _flash_fwd(q, k, v, lengths, sm_scale, causal, block_q, block_k,
         ],
     )
     out, *lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, sm_scale=sm_scale, stride=stride),
+        functools.partial(_fwd_kernel, sm_scale=sm_scale, stride=stride,
+                          **({} if window is None else {"window": window})),
         name="flash_fwd",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((b, hq, sq, dv), q.dtype)]
@@ -509,16 +589,17 @@ def _flash_vjp_fwd(q, k, v, sm_scale, causal, block_q, block_k):
 _flash.defvjp(_flash_vjp_fwd, _flash_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def _flash_forward_only(q, k, v, lengths, sm_scale, causal, block_q,
-                        block_k):
+                        block_k, window=None):
     return _flash_fwd(q, k, v, lengths, sm_scale, causal, block_q, block_k,
-                      keep_lse=False)[0]
+                      keep_lse=False, window=window)[0]
 
 
 def _no_backward(q, k, v, lengths, *_):
     raise TypeError(
-        "flash attention's backward kernels take one width and no lengths: "
+        "flash attention's backward kernels take one width, no lengths and "
+        "no window: "
         f"q {q.shape}, v {v.shape}, lengths "
         f"{None if lengths is None else lengths.shape}")
 
@@ -528,15 +609,19 @@ _flash_forward_only.defvjp(_no_backward, _no_backward)
 
 def flash_attention(q, k, v, causal: bool = True, sm_scale: float | None = None,
                     block_q: int = DEFAULT_BLOCK_Q,
-                    block_k: int = DEFAULT_BLOCK_K, lengths=None):
+                    block_k: int = DEFAULT_BLOCK_K, lengths=None,
+                    window: int | None = None):
     """Flash attention with GQA.  q: [b, sq, hq, d]; k/v: [b, skv, hkv, d];
     returns [b, sq, hq, d] (layout matches ray_tpu.ops.attention).  v may
     be [b, skv, hkv, dv] with dv != d; the result is then [b, sq, hq, dv].
     lengths: int32 [b], the true length of each right-padded row (causal
     only): true rows come out as without it, the query blocks wholly past
     a length as zeros, and nothing is copied or multiplied for those.
-    Both are forward only (the backward kernels take one width and whole
-    rows): differentiating such a call raises."""
+    window: a query attends its own position and the window - 1 before
+    it (causal self-attention only; the key block is then no longer than
+    the query block, `band_blocks`).
+    All three are forward only (the backward kernels take one width and
+    whole rows): differentiating such a call raises."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     qt = q.transpose(0, 2, 1, 3)
@@ -547,11 +632,16 @@ def flash_attention(q, k, v, causal: bool = True, sm_scale: float | None = None,
     # blocks always land; a non-power-of-two caller block that can't
     # divide is an error rather than a silent degenerate grid.
     block_q, block_k = fit_blocks(qt.shape[2], kt.shape[2], block_q, block_k)
+    if window is not None:
+        block_q, block_k = band_blocks(qt.shape[2], block_q, block_k)
     if qt.shape[2] % block_q or kt.shape[2] % block_k:
         raise ValueError(
             f"block sizes ({block_q}, {block_k}) do not divide seq "
             f"({qt.shape[2]}, {kt.shape[2]}); use power-of-two blocks")
-    if lengths is None and vt.shape[3] == qt.shape[3]:
+    if window is not None:
+        o = _flash_forward_only(qt, kt, vt, lengths, sm_scale, causal,
+                                block_q, block_k, window)
+    elif lengths is None and vt.shape[3] == qt.shape[3]:
         o = _flash(qt, kt, vt, sm_scale, causal, block_q, block_k)
     else:
         o = _flash_forward_only(qt, kt, vt, lengths, sm_scale, causal,

@@ -15,7 +15,9 @@ those groups and of its own incomplete group (`selected_mask`: the one
 reading of which rows a selection means, for whole rows of queries;
 `select_rows`: the same for one decode step, as rows gathered from the
 pool).  Below `top` complete groups the selection keeps everything and
-the layer is dense latent attention.
+the layer is dense latent attention.  At `group` 1 (a key a TOKEN:
+`models/dots3_note.py`) no group is ever incomplete, and `own` keeps the
+query's own row whatever its score.
 
 A decode step (`decode_select` + `dsa_decode_attention`): the index
 keys of the lane's pages are gathered through its table (256 B a group:
@@ -93,15 +95,21 @@ def select_groups(scores, n_complete, top: int):
     return idx.astype(jnp.int32), ok, (kth, last)
 
 
-def selected_mask(scores, pos, n_keys: int, group: int, top: int):
+def selected_mask(scores, pos, n_keys: int, group: int, top: int,
+                  own: bool = False):
     """Which of `n_keys` key positions each query attends.  scores
     [..., t, G] (`index_scores`), pos [t] the queries' positions.
+    `own` (a key a TOKEN, `group` 1: no group is ever incomplete): the
+    query's own key is selected whatever its score.
     Returns (mask [..., t, n_keys] bool: s <= t's position, and s in one
     of the `top // group` best complete groups (group g + group - 1 <=
     position) or in the query's own incomplete group (the config's
     `index_kpool_always_select_tail`); chosen [..., t, G] bool: the groups
     the scores chose)."""
     n_complete = (pos + 1) // group
+    if own:
+        scores = jnp.where(jnp.arange(scores.shape[-1]) == pos[:, None],
+                           -NEG_INF, scores)
     _, _, (kth, last) = select_groups(scores, jnp.broadcast_to(
         n_complete, scores.shape[:-1]), top // group)
     g = jnp.arange(scores.shape[-1])
@@ -118,8 +126,9 @@ def selected_mask(scores, pos, n_keys: int, group: int, top: int):
 
 
 def decode_select(q, w, idx_pages, idx_tail, page_table, pos, tail_start,
-                  group: int, top: int):
-    """One decode step's selection for every lane.
+                  group: int, top: int, own: bool = False):
+    """One decode step's selection for every lane (`own`, at `group` 1:
+    the query's own key, in the tail, is selected whatever its score).
 
     q [B, J, w], w [B, J]; idx_pages [n_pages, 1, page // group, w] the
     index pool (groups complete below `tail_start`); idx_tail [B, 1, R,
@@ -139,6 +148,11 @@ def decode_select(q, w, idx_pages, idx_tail, page_table, pos, tail_start,
         valid = jnp.concatenate(
             [jnp.arange(G)[None, :] < g0[:, None], done], axis=1)
         s = jnp.where(valid, s, NEG_INF)
+        if own:
+            mine = jnp.concatenate(
+                [jnp.zeros((B, G), bool),
+                 (g0[:, None] + r[None, :]) * group == pos[:, None]], axis=1)
+            s = jnp.where(mine, -NEG_INF, s)
     with jax.named_scope("dsa_select"):
         idx, ok, _ = select_groups(
             s, jnp.full((B,), s.shape[1], jnp.int32), top // group)
@@ -376,3 +390,32 @@ def masked_prefill_attention(q, k, v, mask, *, sm_scale: float):
         interpret=_interpret(),
     )(qh, kh, vh, mask)
     return jnp.swapaxes(o, 1, 2)
+
+
+# What a serving module with learned sparse attention reports of its
+# selection, a live lane's every decode step, x those layers
+# (models/serving.ServingSpec.counters).
+COUNTERS = {
+    "dsa_rows_context": "Rows in a lane's context at a sparse layer's "
+                        "decode step, summed over live lanes, steps and "
+                        "sparse layers",
+    "dsa_groups_scored": "Complete groups the indexer scored, summed "
+                         "likewise",
+    "dsa_rows_selected": "Rows the selection attended, summed likewise",
+}
+
+
+def decode_work(layers: int, group: int, top: int, rows, k: int
+                ) -> tuple[dict, dict]:
+    """One decode window of `k` steps over live lanes that start it on
+    `rows` cached rows each, x `layers` sparse layers (host arithmetic,
+    `selection_counts`), as COUNTERS' rows; the span shows the same."""
+    sel = [0, 0, 0]         # in COUNTERS' order
+    for r in rows:
+        for ctx in range(r + 1, r + 1 + k):
+            scored, kept = selection_counts(ctx, group, top)
+            sel[0] += ctx
+            sel[1] += scored
+            sel[2] += kept
+    work = dict(zip(COUNTERS, (n * layers for n in sel)))
+    return work, work

@@ -885,9 +885,8 @@ def test_granite_decode_step_loop_copies_no_lane_state_and_no_weight(
 
 
 # ------------------------------------------------- GLM-5.3-Flash (PR 41)
-def _glm_lowerings(one_chip, shapes):
-    """benchmarks/configs/glm-5.3-flash-ep8.json as the benchmark builds
-    it."""
+def _glm_lowerings(one_chip, shapes, config="glm-5.3-flash-ep8"):
+    """benchmarks/configs/<config>.json as the benchmark builds it."""
     from benchmarks.harness import spec
     from ray_tpu.serve.llm import LLMEngine
 
@@ -898,7 +897,7 @@ def _glm_lowerings(one_chip, shapes):
         return jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
 
     conf = spec.load_json(os.path.join(
-        spec.BENCH_DIR, "configs", "glm-5.3-flash-ep8.json"))
+        spec.BENCH_DIR, "configs", config + ".json"))
     fam = spec.config_family(conf)
     eng_kw = dict(conf["engine"], paged=True)
     cfg = fam.program_config(fam.published(conf), max_seq=eng_kw["max_len"])
@@ -1105,3 +1104,110 @@ def test_served_glm_engine_fits_one_chip_and_copies_no_state_or_pool(
                 and "kda_update" in ln]) == 4          # a call a KDA layer
     assert len([ln for ln in loop if "custom-call(" in ln
                 and "dsa_attn" in ln]) == 1
+
+
+# ------------------------------------------------- dots3-note-prev (PR 45)
+@pytest.mark.parametrize("lanes,with_lengths", [(64, True), (8, False)])
+def test_swa_kernels_compile_at_the_served_widths(one_chip, compiled_kernels,
+                                                  lanes, with_lengths):
+    """dots3-note-prev's window layers: `swa_attn` over a lane's ring of
+    640 rows x 1,152 for 64 heads at once (the ring read where it lies:
+    no copy of it), and `flash_fwd` under the band of 513 at 64 heads of
+    256 / 128 over 8,192 positions."""
+    from ray_tpu.ops import flash_attention, window_attention as swa
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(q, ring, pos, lanes_, count):
+        return swa.swa_decode_attention(
+            q, ring, swa.ring_bias(pos, 640, 513), lanes_, count, dv=1024,
+            sm_scale=256 ** -0.5)
+
+    low, c = _compile(step, s((lanes, 64, 1152)), s((lanes, 640, 1152)),
+                      s((lanes,), jnp.int32), s((lanes,), jnp.int32),
+                      s((), jnp.int32))
+    assert low.as_text().count("tpu_custom_call") == 1
+    assert "swa_attn" in low.as_text()
+    assert _pool_copies(c.as_text(), lanes * 640 * 1152 // 2) == []
+
+    def band(q, k, v, n):
+        return flash_attention.flash_attention(
+            q, k, v, sm_scale=256 ** -0.5, window=513,
+            lengths=n if with_lengths else None)
+
+    low, _ = _compile(band, s((1, 8192, 64, 256)), s((1, 8192, 64, 256)),
+                      s((1, 8192, 64, 128)), s((1,), jnp.int32))
+    assert low.as_text().count("tpu_custom_call") == 1
+    assert "flash_fwd" in low.as_text()
+
+
+@pytest.mark.time_limit(900)
+def test_served_dots3_engine_fits_one_chip_and_copies_no_ring_or_pool(
+        topo, one_chip, compiled_kernels, monkeypatch):
+    """dots3-note-prev-ep8 as the benchmark serves it (5 layers, 64 lanes,
+    1,153 pages): the decode program and the 1 x 8192 prefill program its
+    traffic runs compile for one chip beside weights + rings + both pool
+    leaves of the two FULL layers.  The window layers hold no page: their
+    rows are the lanes' rings (0.28 GB), which the decode program hands
+    back in the buffers they came in, written a row a lane a step and
+    read by `swa_attn` where they lie; neither a ring nor a pool leaf is
+    copied, in the loop or outside it."""
+    monkeypatch.setattr(jax, "devices", lambda *a: list(topo.devices))
+    cfg, eng, lows = _glm_lowerings(one_chip, [(1, 8192)],
+                                    "dots3-note-prev-ep8")
+    lane = eng.stats()["lane_state"]
+    ring = 64 * 640 * 1152                  # one window layer's lanes
+    assert lane["layers"] == 3
+    assert lane["by_kind"] == {"window": 3 * ring * 2}
+    cache = eng._cache_stats()
+    assert cache["by_leaf"]["latent"] == {
+        "row_bytes": 1280, "positions_per_row": 1, "layers": 2,
+        "pool_bytes": 2 * 1153 * 512 * 1280}
+    assert cache["by_leaf"]["index"] == {
+        "row_bytes": 256, "positions_per_row": 1, "layers": 2,
+        "pool_bytes": 2 * 1153 * 512 * 256}
+    weights = sum(math.prod(a.shape) * a.dtype.itemsize
+                  for a in jax.tree.leaves(eng.params))
+    resident = weights + lane["bytes"] + cache["pool_bytes"]
+    assert 10.2e9 < resident < 10.35e9       # 61 % of the chip
+    assert eng._prefill_floor > 1000 and (1, 8192) in eng._prefill_programs
+    assert eng._spec.prefill_state_bytes == 3 * 640 * 1152 * 2
+    kernels = {"decode_k8": ("swa_attn", "dsa_attn", "moe_gmm"),
+               "prefill_w1_p8192": ("moe_gmm", "dsa_prefill", "flash_fwd")}
+    compiled = {}
+    for name, low in lows.items():
+        txt = low.as_text()
+        for kern in kernels[name]:
+            assert kern in txt, (name, kern)
+        compiled[name] = c = low.compile()
+        mem = c.memory_analysis()
+        held = resident + mem.temp_size_in_bytes + (
+            mem.output_size_in_bytes - mem.alias_size_in_bytes)
+        print(f"{name}: temps {mem.temp_size_in_bytes / 1e9:.2f} GB, "
+              f"resident {resident / 1e9:.2f} GB, held {held / 1e9:.2f} GB")
+        assert held < 16.9e9 - 1.5e9, (name, held)
+    c = compiled["decode_k8"]
+    hlo = c.as_text()
+    assert "while(" in hlo
+    lines = {m.group(1): ln for ln in hlo.splitlines()
+             for m in [_INSTR.match(ln)] if m}
+    # no copy of a ring (64 lanes x 640 rows) or of a pool leaf (1,153
+    # pages), anywhere in the program
+    copies = _pool_copies(hlo, ring // 2)
+    assert [n for n in copies
+            if "[64,640," in lines[n].split("copy(")[0]
+            or "[1153," in lines[n].split("copy(")[0]] == []
+    # inside the loop the only writers of something ring-sized are the
+    # row write (a scatter in place) and nothing else; the gathered rows
+    # of the selection (64 x 2,176 x 640) belong to `dsa_select`
+    found = [f for f in weight_sized_writes(hlo, ring)
+             if "dsa_select" not in f[2] and "dsa_index" not in f[2]]
+    assert all("kv_write" in scope for _, _, scope in found), found
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= lane["bytes"] + cache["pool_bytes"]
+    loop = _loop_lines(hlo)
+    assert len([ln for ln in loop if "custom-call(" in ln
+                and "swa_attn" in ln]) == 3        # a call a window layer
+    assert len([ln for ln in loop if "custom-call(" in ln
+                and "dsa_attn" in ln]) == 2        # a call a full layer

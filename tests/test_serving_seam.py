@@ -20,7 +20,7 @@ from ray_tpu.serve import llm
 from ray_tpu.serve.llm import LLMEngine
 
 PRESETS = ("debug", "lfm2-debug", "mla-debug", "ssm-hybrid-debug",
-           "glm5-next-debug")
+           "glm5-next-debug", "dots3-note-debug")
 
 
 def _spec(preset):
@@ -41,7 +41,7 @@ def _reported(spec) -> set:
 
 @functools.cache
 def _family_names() -> frozenset:
-    """Every work counter any of the five families declares."""
+    """Every work counter any of the six families declares."""
     return frozenset().union(*(_spec(p)[1].counters for p in PRESETS))
 
 
@@ -64,6 +64,9 @@ def test_no_flash_counter_for_a_prefill_that_never_calls_flash():
     assert all("prefill_attn_blocks" in _spec(p)[1].counters
                for p in PRESETS[:4])
     assert "prefill_attn_blocks" not in _spec("glm5-next-debug")[1].counters
+    # a window layer's prefill IS `flash_fwd`, under a band
+    assert {"prefill_attn_blocks", "prefill_swa_blocks"} <= set(
+        _spec("dots3-note-debug")[1].counters)
 
 
 @dataclasses.dataclass(frozen=True)
