@@ -198,13 +198,14 @@ def serving_configs() -> dict[str, Dots3NoteConfig]:
     }
 
 
-def _decode_work(cfg: Dots3NoteConfig, rows, k: int) -> tuple[dict, dict]:
+def _decode_work(cfg: Dots3NoteConfig, rows, k: int, page: int, maxp: int
+                 ) -> tuple[dict, dict]:
     """One decode window of `k` steps over live lanes that start it on
     `rows` cached rows each: what the full layers' selection read (a key
     a token: every row below and at the query is scored) and what the
     window layers' rings gave."""
     return merged(dsa.decode_work(cfg.count(FULL), 1, cfg.index_topk, rows,
-                                  k),
+                                  k, page, maxp),
                   swa.decode_work(cfg.count(WINDOW), cfg.window, rows, k))
 
 
@@ -497,13 +498,16 @@ def full_prefill(x, lp, cfg: Dots3NoteConfig, true_lens,
 
 def full_decode(x, lp, latent_pages, index_pages, latent_tail, index_tail,
                 page_table, pos, tail_start, j, lanes, count,
-                cfg: Dots3NoteConfig, want_selection: bool = False):
+                cfg: Dots3NoteConfig, want_selection: bool = False,
+                plan: dict | None = None):
     """One token of the full layer's attention half for every lane,
     ABSORBED: x [B, d]; the two pool leaves of the layer (read-only) and
     their tails (the new row and key land at column j).  Returns (what
     it adds, latent tail, index tail); with `want_selection` a fourth
-    entry, (the positions of the rows gathered and of the tail's [B, S +
-    K], whether each is attended): a judge's reading."""
+    entry, (the positions of the rows read and of the tail's, whether
+    each is attended): a judge's reading.  `plan`: the window's
+    `attention_plan`, for the form of `dsa.decode_attend` that walks
+    pages (built there if not given)."""
     B = x.shape[0]
     k = cfg.full
     h = rmsnorm(x, lp["norm1"], cfg.norm_eps)
@@ -517,17 +521,14 @@ def full_decode(x, lp, latent_pages, index_pages, latent_tail, index_tail,
             (0, 0, j, 0))
         index_tail = lax.dynamic_update_slice(
             index_tail, ki.astype(cfg.dtype)[:, :, None, :], (0, 0, j, 0))
-    rows_at, ok = dsa.decode_select(
+    rows_at, ok, chosen = dsa.decode_select(
         qi[:, 0], w[:, 0], index_pages, index_tail, page_table, pos,
         tail_start, 1, cfg.index_topk, own=True)
-    rows, bias, tail_bias, rpos, admit = dsa.select_rows(
-        latent_pages, latent_tail, page_table, pos, tail_start, rows_at,
-        ok, 1)
     q = _absorbed(q_nope[:, 0], q_rope[:, 0], lp, k)
-    with jax.named_scope("dsa_attn"):
-        o = dsa.dsa_decode_attention(
-            q, rows, bias, latent_tail[:, 0], tail_bias, lanes, count,
-            dv=k.kv_lora_rank, sm_scale=k.qk_head_dim ** -0.5)
+    o, rpos, admit = dsa.decode_attend(
+        q, latent_pages, latent_tail, page_table, pos, tail_start, rows_at,
+        ok, chosen, lanes, count, group=1, dv=k.kv_lora_rank,
+        sm_scale=k.qk_head_dim ** -0.5, plan=plan)
     with jax.named_scope("mla_absorb"):
         ov = jnp.einsum("bhc,hcv->bhv", o, lp["w_uv"])
     y = gated(ov, h, lp, cfg)
@@ -677,8 +678,9 @@ def decode_step_paged(params: dict, pages: dict, tails: dict, state: dict,
     the lanes' rings.  A lane whose table row starts at the trash page
     holds no request: it attends nothing, is routed nowhere and its rings
     are not touched.
-    `plan` (the paged kernels' work list of pages) is not read: a
-    selection names rows and a ring needs no table.  Returns (logits [B,
+    `plan` (the paged kernels' work list of pages) is the full layers'
+    where their attention walks pages (`dsa.walks`); a ring needs no
+    table.  Returns (logits [B,
     vocab] float32, tails, state, counts int32 [routed layers, 4])."""
     live = lanes_live(page_table)
     lanes, count = ssm.live_lanes(live)
@@ -695,7 +697,7 @@ def decode_step_paged(params: dict, pages: dict, tails: dict, state: dict,
             y, latent_t[i], index_t[i] = full_decode(
                 x, lp, pages["latent"][i], pages["index"][i], latent_t[i],
                 index_t[i], page_table, pos, tail_start, j, lanes, count,
-                cfg)
+                cfg, plan=plan)
         else:
             y, rings[i] = window_decode(x, lp, rings[i], pos, max_len,
                                         live, lanes, count, cfg)

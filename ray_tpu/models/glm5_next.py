@@ -49,8 +49,11 @@ k^I = RoPE(LayerNorm(u W_kI)), w = (J d_I)^-0.5 u W_w; the index pool
 holds ONE key a COMPLETE group of `index_pool` positions, their mean
 (assumed; `pool_index_keys`); a query scores the complete groups below
 it, keeps the `index_topk / index_pool` best and attends their
-positions and its own incomplete group (`select_rows` /
-`selected_mask`).  k_{s,i} = c_s W_UK,i, v_{s,i} = c_s W_UV,i; prefill
+positions and its own incomplete group (`selected_mask`; a decode step:
+`dsa.decode_attend`, which walks the lane's pages under a bias a row
+where the table holds at most `dsa.RATIO` selections' rows, as every
+served cell's does, and gathers the rows through `select_rows` under a
+longer table).  k_{s,i} = c_s W_UK,i, v_{s,i} = c_s W_UV,i; prefill
 runs expanded, decode absorbed over the selected rows.  The indexer's
 rotary: the first `index_rope_dim` of `index_dim`, interleaved pairs,
 theta `index_theta` (assumed: `index_rope`).
@@ -190,13 +193,14 @@ def serving_configs() -> dict[str, Glm5NextConfig]:
 DSA_COUNTERS = dsa.COUNTERS
 
 
-def _decode_work(cfg: Glm5NextConfig, rows, k: int) -> tuple[dict, dict]:
+def _decode_work(cfg: Glm5NextConfig, rows, k: int, page: int, maxp: int
+                 ) -> tuple[dict, dict]:
     """One decode window of `k` steps over live lanes that start it on
     `rows` cached rows each: `kda_update`'s lane-steps and what the
     selection read (`ops/sparse_attention.decode_work`)."""
     return merged(ssm.update_work(cfg.count(KDA), len(rows), k),
                   dsa.decode_work(cfg.count(DSA), cfg.index_pool,
-                                  cfg.index_topk, rows, k))
+                                  cfg.index_topk, rows, k, page, maxp))
 
 
 def serving_spec(cfg: Glm5NextConfig) -> ServingSpec:
@@ -629,16 +633,18 @@ def dsa_prefill(x, lp, cfg: Glm5NextConfig, true_lens,
 
 def dsa_decode(x, lp, latent_pages, index_pages, latent_tail, index_tail,
                ipart, page_table, pos, tail_start, j, lanes, count,
-               cfg: Glm5NextConfig, want_selection: bool = False):
+               cfg: Glm5NextConfig, want_selection: bool = False,
+               plan: dict | None = None):
     """One token of the sparse latent mixer for every lane, ABSORBED:
     x [B, d]; the two pool leaves of the layer (read-only), their tails
     (the new latent row lands at column j; a group the token completes
     lands in the index tail), ipart [B, w] the lane's incomplete group's
     sum.  Returns (what it computes, latent tail, index tail, ipart);
     with `want_selection` a fifth entry, (the groups the scores chose
-    [B, n], which of them count [B, n], the positions of the rows
-    gathered and of the tail's [B, S + K], whether each is attended):
-    a judge's reading."""
+    [B, n], which of them count [B, n], the positions of the rows read
+    and of the tail's, whether each is attended): a judge's reading.
+    `plan`: the window's `attention_plan`, for the form of
+    `dsa.decode_attend` that walks pages (built there if not given)."""
     B = x.shape[0]
     g = cfg.index_pool
     h = rmsnorm(x, lp["norm1"], cfg.norm_eps)
@@ -655,19 +661,16 @@ def dsa_decode(x, lp, latent_pages, index_pages, latent_tail, index_tail,
             put[:, None, :, None],
             (acc / g).astype(cfg.dtype)[:, None, None, :], index_tail)
         ipart = jnp.where(full[:, None], 0.0, acc)
-    groups, ok = dsa.decode_select(
+    groups, ok, chosen = dsa.decode_select(
         qi[:, 0], w[:, 0], index_pages, index_tail, page_table, pos,
         tail_start, g, cfg.index_topk)
-    rows, bias, tail_bias, rpos, admit = dsa.select_rows(
-        latent_pages, latent_tail, page_table, pos, tail_start, groups, ok,
-        g)
     with jax.named_scope("mla_absorb"):
         qa = jnp.einsum("bhn,hnc->bhc", q[:, 0], lp["w_uk"]
                         ).astype(cfg.dtype)
-    with jax.named_scope("dsa_attn"):
-        o = dsa.dsa_decode_attention(
-            qa, rows, bias, latent_tail[:, 0], tail_bias, lanes, count,
-            dv=cfg.kv_lora_rank, sm_scale=cfg.qk_head_dim ** -0.5)
+    o, rpos, admit = dsa.decode_attend(
+        qa, latent_pages, latent_tail, page_table, pos, tail_start, groups,
+        ok, chosen, lanes, count, group=g, dv=cfg.kv_lora_rank,
+        sm_scale=cfg.qk_head_dim ** -0.5, plan=plan)
     with jax.named_scope("mla_absorb"):
         ov = jnp.einsum("bhc,hcv->bhv", o, lp["w_uv"])
     with jax.named_scope("mla_out"):
@@ -795,7 +798,8 @@ def decode_step_paged(params: dict, pages: dict, tails: dict, state: dict,
     the lanes' state.  A lane whose table row starts at the trash page
     holds no request: it attends nothing, is routed nowhere and its
     state matrices are not touched.  `plan` (the paged kernels' work
-    list of pages) is not read: a selection names rows.  Returns (logits
+    list of pages) is the sparse layers' where their attention walks
+    pages (`dsa.walks`).  Returns (logits
     [B, vocab] float32, tails, state, counts int32 [routed layers, 4])."""
     live = lanes_live(page_table)
     lanes, count = ssm.live_lanes(live)
@@ -819,7 +823,7 @@ def decode_step_paged(params: dict, pages: dict, tails: dict, state: dict,
                 y, lt, it, ip = dsa_decode(
                     x, lp, pages["latent"][i], pages["index"][i],
                     latent_t[i], index_t[i], ipart[i], page_table, pos,
-                    tail_start, j, lanes, count, cfg)
+                    tail_start, j, lanes, count, cfg, plan=plan)
                 return y, (lt, it, ip)
 
             X, (latent_t[i], index_t[i], ip) = sublayer(

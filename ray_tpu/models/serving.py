@@ -93,8 +93,9 @@ class ServingSpec:
     # return (work, shown): {counter name: what to add} and the
     # attributes the phase's span shows for it.
     counters: Mapping[str, str] = dataclasses.field(default_factory=dict)
-    # decode_work(rows, K): one decode window of K steps over the live
-    # lanes, `rows` their cached rows at its start (span
+    # decode_work(rows, K, page, maxp): one decode window of K steps over
+    # the live lanes, `rows` their cached rows at its start, under the
+    # engine's table of `maxp` columns of `page` rows (span
     # llm.loop.decode_dispatch)
     decode_work: Callable[..., tuple[dict, dict]] = no_work
     # prefill_work(true_lens, bucket): one full-prompt prefill program

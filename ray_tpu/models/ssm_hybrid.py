@@ -162,7 +162,8 @@ def serving_spec(cfg: SsmHybridConfig) -> ServingSpec:
     return ServingSpec(
         lane_state_layers=n, prefill_state_bytes=n * per_layer,
         counters={**PREFILL_COUNTERS, **ssm.SCAN_COUNTERS},
-        decode_work=lambda rows, k: ssm.update_work(n, len(rows), k),
+        decode_work=lambda rows, k, *_table: ssm.update_work(
+            n, len(rows), k),
         prefill_work=lambda true_lens, bucket: merged(
             prefill_work(true_lens, bucket),
             ssm.scan_work(n, cfg.ssm_chunk, true_lens, bucket)))

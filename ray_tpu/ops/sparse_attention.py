@@ -13,25 +13,41 @@ A query scores every complete group below it,
 keeps the `top` best (`select_groups`) and attends the positions of
 those groups and of its own incomplete group (`selected_mask`: the one
 reading of which rows a selection means, for whole rows of queries;
-`select_rows`: the same for one decode step, as rows gathered from the
-pool).  Below `top` complete groups the selection keeps everything and
-the layer is dense latent attention.  At `group` 1 (a key a TOKEN:
+`walk_bias` / `select_rows`: the same for one decode step, as a bias
+over the lane's table or as rows gathered from the pool).  Below `top`
+complete groups the selection keeps everything and the layer is dense
+latent attention.  At `group` 1 (a key a TOKEN:
 `models/dots3_note.py`) no group is ever incomplete, and `own` keeps the
 query's own row whatever its score.
 
-A decode step (`decode_select` + `dsa_decode_attention`): the index
-keys of the lane's pages are gathered through its table (256 B a group:
-37 MB a step at 64 lanes x 9,216 positions), scored, the top groups'
-latent rows are gathered `group` rows at a time (contiguous in a page)
-and the kernel `dsa_attn` attends them for every head at once, one grid
-step a LIVE lane: `attention_plan`'s work list names pages, a selection
-names rows, so this kernel walks lanes and reads rows that were
-gathered for it.  Rows of the running decode block are not in the pool
-yet (`ops/paged_attention`): they ride behind the gathered rows, each
-admitted if the selection holds its group.
+A decode step (`decode_select` + `decode_attend`): the index keys of
+the lane's pages are gathered through its table (256 B a group: 37 MB a
+step at 64 lanes x 9,216 positions), scored, the top groups taken, and
+the rows attended in one of two forms, named by the table's shape alone
+(`walks`: no option):
 
-Device-side names: `dsa_index`, `dsa_select`, `dsa_attn` (the kernel's
-`pallas_call` name too).
+  - the WALK, where the table holds at most RATIO times the rows a
+    selection gathers (both served cells: 9,216 rows against 2,176):
+    `walk_bias` turns the selection into a bias a row of the table (a
+    chosen group's bit repeated over its rows: no gather, no scatter) and
+    the kernel `dsa_walk_attention` walks `attention_plan`'s work list,
+    one grid step a live (lane, page) pair, flash accumulation across
+    pages, the rows nobody chose weighed 0.  It reads every page of the
+    lane, a few times the rows it needs, because a row of a whole page
+    streams at a tenth of what a gathered row costs;
+  - the GATHER, past that (a long-context engine: the selection is a
+    small share of the table): `select_rows` gathers the top groups'
+    latent rows `group` rows at a time (contiguous in a page) and the
+    kernel `dsa_decode_attention` attends them, one grid step a LIVE
+    lane: a selection names rows, so this kernel walks lanes and reads
+    rows that were gathered for it.
+
+Either kernel attends every head at once.  Rows of the running decode
+block are not in the pool yet (`ops/paged_attention`): they ride behind
+the pool's rows, each admitted if the selection holds its group.
+
+Device-side names: `dsa_index`, `dsa_select`, `dsa_attn` (either
+kernel's `pallas_call` name too: one call a sparse layer a step).
 """
 from __future__ import annotations
 
@@ -44,6 +60,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops import flash_attention
+from ray_tpu.ops.paged_attention import attention_plan, lanes_live
 
 F32 = jnp.float32
 NEG_INF = -1e30
@@ -84,7 +101,10 @@ def select_groups(scores, n_complete, top: int):
     rebuilds the same set without a scatter)."""
     G = scores.shape[-1]
     valid = jnp.arange(G) < n_complete[..., None]
-    masked = jnp.where(valid, scores, NEG_INF)
+    # (`lax.top_k` orders -0.0 below 0.0, the comparisons that rebuild
+    # its set from (kth, last) do not: one zero)
+    masked = jnp.where(valid, jnp.where(scores == 0.0, 0.0, scores),
+                       NEG_INF)
     if G < top:
         masked = jnp.pad(masked, [(0, 0)] * (masked.ndim - 1)
                          + [(0, top - G)], constant_values=NEG_INF)
@@ -93,6 +113,15 @@ def select_groups(scores, n_complete, top: int):
     kth = jnp.min(jnp.where(ok, val, -NEG_INF), axis=-1)
     last = jnp.max(jnp.where(ok & (val == kth[..., None]), idx, -1), axis=-1)
     return idx.astype(jnp.int32), ok, (kth, last)
+
+
+def _top_set(scores, kth, last):
+    """The set `lax.top_k` picked, rebuilt from `select_groups`' (kth,
+    last) as a bit a group [..., G]: above the k-th score, or at it and
+    no later than the last group taken there.  No scatter."""
+    g = jnp.arange(scores.shape[-1])
+    return ((scores > kth[..., None])
+            | ((scores == kth[..., None]) & (g <= last[..., None])))
 
 
 def selected_mask(scores, pos, n_keys: int, group: int, top: int,
@@ -112,10 +141,8 @@ def selected_mask(scores, pos, n_keys: int, group: int, top: int,
                            -NEG_INF, scores)
     _, _, (kth, last) = select_groups(scores, jnp.broadcast_to(
         n_complete, scores.shape[:-1]), top // group)
-    g = jnp.arange(scores.shape[-1])
-    chosen = ((scores > kth[..., None])
-              | ((scores == kth[..., None]) & (g <= last[..., None]))) \
-        & (g < n_complete[:, None])
+    chosen = _top_set(scores, kth, last) \
+        & (jnp.arange(scores.shape[-1]) < n_complete[:, None])
     keys = jnp.arange(n_keys)
     by_group = jnp.repeat(chosen, group, axis=-1)[..., :n_keys]
     if by_group.shape[-1] < n_keys:
@@ -134,7 +161,10 @@ def decode_select(q, w, idx_pages, idx_tail, page_table, pos, tail_start,
     index pool (groups complete below `tail_start`); idx_tail [B, 1, R,
     w] the groups the running block completed (row r = group
     tail_start // group + r).  Returns (groups [B, top // group] int32
-    absolute group numbers, ok [B, top // group])."""
+    absolute group numbers, ok [B, top // group], chosen [B, G + R] bool:
+    the same set as a bit a scored group, the pool's G = table columns x
+    page // group first and then the tail's, rebuilt from `select_groups`'
+    (kth, last) as `selected_mask` does: no scatter)."""
     B, maxp = page_table.shape
     with jax.named_scope("dsa_index"):
         kbar = idx_pages[page_table][:, :, 0]         # [B, maxp, rows, w]
@@ -154,10 +184,16 @@ def decode_select(q, w, idx_pages, idx_tail, page_table, pos, tail_start,
                  (g0[:, None] + r[None, :]) * group == pos[:, None]], axis=1)
             s = jnp.where(mine, -NEG_INF, s)
     with jax.named_scope("dsa_select"):
-        idx, ok, _ = select_groups(
+        idx, ok, (kth, last) = select_groups(
             s, jnp.full((B,), s.shape[1], jnp.int32), top // group)
         groups = jnp.where(idx < G, idx, g0[:, None] + idx - G)
-    return groups, ok
+        chosen = _top_set(s, kth, last) & (s > 0.5 * NEG_INF)
+    return groups, ok, chosen
+
+
+def _bias(admit):
+    """float32, 0 where a row is attended and -1e30 elsewhere."""
+    return jnp.where(admit, 0.0, NEG_INF).astype(F32)
 
 
 def select_rows(latent_pages, latent_tail, page_table, pos, tail_start,
@@ -180,7 +216,7 @@ def select_rows(latent_pages, latent_tail, page_table, pos, tail_start,
     with jax.named_scope("dsa_select"):
         # the query's own incomplete group rides behind the chosen ones:
         # its rows below `tail_start` are in the pool like any other's
-        n = -(-(groups.shape[1] + 1) * group // LANE) * LANE // group
+        n = rows_gathered(group, groups.shape[1] * group) // group
         fill = n - groups.shape[1] - 1
         groups = jnp.concatenate(
             [groups, ((pos + 1) // group)[:, None],
@@ -206,11 +242,7 @@ def select_rows(latent_pages, latent_tail, page_table, pos, tail_start,
         held = jnp.any((tpos // group)[:, :, None] == jnp.where(
             ok, groups, -1)[:, None, :], axis=-1)
         t_admit = (tpos <= pos[:, None]) & (own | held)
-
-        def bias(a):
-            return jnp.where(a, 0.0, NEG_INF).astype(F32)
-
-        return (rows, bias(admit), bias(t_admit),
+        return (rows, _bias(admit), _bias(t_admit),
                 jnp.concatenate([rpos, tpos], axis=1),
                 jnp.concatenate([admit, t_admit], axis=1))
 
@@ -282,6 +314,195 @@ def dsa_decode_attention(q, rows, bias, tail, tail_bias, lanes, count, *,
     listed = jnp.any((lanes[None, :] == jnp.arange(B)[:, None])
                      & (jnp.arange(B)[None, :] < count), axis=1)
     return jnp.where(listed[:, None, None], o, jnp.zeros_like(o))
+
+
+# -------------------------------------------- decode: the walk over pages
+def walk_bias(chosen, pos, tail_start, n_rows: int, K: int, group: int):
+    """The rows `decode_select` chose, as a bias a row of the lane's
+    table (`dsa_walk_attention`), with no gather and no scatter.
+
+    chosen [B, n_rows // group + R] (`decode_select`); n_rows = table
+    columns x page, K the tail's rows.  A pool row is admitted iff it is
+    below `tail_start` and its group is chosen or it lies in the query's
+    own incomplete group; a tail row by `select_rows`' rule.  Returns
+    (bias [B, n_rows], tail_bias [B, K]: float32, 0 or -1e30; rpos, admit
+    [B, n_rows + K]: every row's position and whether it is attended, the
+    tail's last)."""
+    B = chosen.shape[0]
+    G = n_rows // group
+    with jax.named_scope("dsa_select"):
+        open_at = ((pos + 1) // group * group)[:, None]
+        kpos = jnp.broadcast_to(jnp.arange(n_rows)[None, :], (B, n_rows))
+        g0 = (tail_start // group)[:, None]
+        # (the group `tail_start` cuts is scored in the tail: its bit
+        # there speaks for its rows below the cut)
+        admit = ((jnp.repeat(chosen[:, :G], group, axis=1)
+                  | ((kpos >= g0 * group) & chosen[:, G:G + 1])
+                  | (kpos >= open_at)) & (kpos < tail_start[:, None]))
+        tpos = tail_start[:, None] + jnp.arange(K)[None, :]      # [B, K]
+        r = tpos // group - g0
+        held = jnp.any((r[:, :, None] == jnp.arange(chosen.shape[1] - G))
+                       & chosen[:, None, G:], axis=-1)
+        t_admit = (tpos <= pos[:, None]) & ((tpos >= open_at) | held)
+        return (_bias(admit), _bias(t_admit),
+                jnp.concatenate([kpos, tpos], axis=1),
+                jnp.concatenate([admit, t_admit], axis=1))
+
+
+def _walk_kernel(lane_ref, col_ref, page_ref, ts_ref,       # prefetch
+                 q_ref, rp_ref, bias_ref, rt_ref, tbias_ref,
+                 o_ref, acc_ref, m_ref, l_ref,
+                 *, page: int, maxp: int, dv: int, sm_scale: float):
+    """One (lane, page) step of `dsa_walk_attention`: the walk of
+    `ops/paged_attention._mla_kernel`, every admission in the bias.
+    q_ref [H, dk]; rp_ref [page, dk] one page of the pool and rt_ref
+    [K, dk] the running block's rows (key AND value: the first dv
+    columns); bias_ref [1, page], tbias_ref [1, K]; o_ref [H, dv]."""
+    del page_ref                              # the index maps read it
+    i = pl.program_id(0)
+    col = col_ref[i]
+    ts = jnp.minimum(ts_ref[lane_ref[i]], maxp * page)
+    npages = (ts + page - 1) // page
+
+    @pl.when(col == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def flash_update(rows, bias):
+        s = lax.dot_general(
+            q_ref[...], rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=F32) * sm_scale + bias       # [H, n]
+        m_prev = m_ref[:, :1]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_cur)
+        # (a page with no row admitted: every weight 0, not exp(0))
+        p = jnp.where(s > 0.5 * NEG_INF, jnp.exp(s - m_cur), 0.0)
+        l_ref[:, :1] = l_ref[:, :1] * alpha + jnp.sum(p, axis=1,
+                                                      keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + lax.dot_general(
+            p.astype(rows.dtype), rows[:, :dv], (((1,), (0,)), ((), ())),
+            preferred_element_type=F32)
+        m_ref[:, :1] = m_cur
+
+    @pl.when(col < npages)
+    def _pages():
+        flash_update(rp_ref[...], bias_ref[...])
+
+    @pl.when(col >= npages - 1)               # the lane's last step
+    def _tail():
+        flash_update(rt_ref[...], tbias_ref[...])
+        l = l_ref[:, :1]
+        o_ref[...] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(
+            o_ref.dtype)
+
+
+def dsa_walk_attention(q, row_pages, bias, row_tail, tail_bias, page_table,
+                       tail_start, *, dv: int, sm_scale: float,
+                       plan: dict | None = None):
+    """Attention of every head over the rows a bias admits, the lane's
+    pool pages read where they lie.
+
+    q [B, H, dk] absorbed queries; row_pages [n_pages, 1, page, dk] the
+    layer's pool; bias [B, table columns x page], tail_bias [B, K]
+    (`walk_bias`); row_tail [B, 1, K, dk]; plan:
+    `attention_plan(page_table, tail_start, page)`, whose work list this
+    walks (one step a live (lane, page) pair, the tail attended in the
+    lane's last), built here if not given.  Returns o [B, H, dv]; an idle
+    lane reads 0."""
+    B, H, dk = q.shape
+    page = row_pages.shape[2]
+    K = row_tail.shape[2]
+    maxp = page_table.shape[1]
+    if plan is None:
+        plan = attention_plan(page_table, tail_start, page)
+
+    def page_map(i, lane, col, pages, *_):
+        return (pages[i], 0, 0, 0)
+
+    def bias_map(i, lane, col, *_):
+        return (lane[i], col[i], 0, 0)
+
+    def lane_map3(i, lane, *_):
+        return (lane[i], 0, 0)
+
+    def lane_map4(i, lane, *_):
+        return (lane[i], 0, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(plan["count"],),
+        in_specs=[pl.BlockSpec((None, H, dk), lane_map3),
+                  pl.BlockSpec((None, None, page, dk), page_map),
+                  pl.BlockSpec((None, None, 1, page), bias_map),
+                  pl.BlockSpec((None, None, K, dk), lane_map4),
+                  pl.BlockSpec((None, 1, K), lane_map3)],
+        out_specs=pl.BlockSpec((None, H, dv), lane_map3),
+        scratch_shapes=[pltpu.VMEM((H, dv), F32),
+                        pltpu.VMEM((H, LANE), F32),
+                        pltpu.VMEM((H, LANE), F32)],
+    )
+    o = pl.pallas_call(
+        functools.partial(_walk_kernel, page=page, maxp=maxp, dv=dv,
+                          sm_scale=sm_scale),
+        name="dsa_attn",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=_interpret(),
+    )(plan["lane"], plan["col"], plan["page"], tail_start,
+      q, row_pages, bias.reshape(B, maxp, 1, page), row_tail,
+      tail_bias[:, None, :])
+    live = lanes_live(page_table)
+    return jnp.where(live[:, None, None], o, jnp.zeros_like(o))
+
+
+# A gathered row costs what RATIO streamed ones do (one lookup and one
+# 1-row copy against a page's share of one long copy; PERF.md section 5,
+# the kernel-alone table by ratio)
+RATIO = 8
+
+
+def rows_gathered(group: int, top: int) -> int:
+    """Rows `select_rows` gathers a lane: those of the `top // group`
+    best groups and of the query's own, in whole lane tiles."""
+    return -(-(top // group + 1) * group // LANE) * LANE
+
+
+def walks(table_rows: int, group: int, top: int) -> bool:
+    """Which form a decode step's sparse attention takes, a rule of the
+    call's shape: the walk (`dsa_walk_attention`: every page of the
+    lane's table, a bias a row) where the table's rows are at most RATIO
+    times the rows a selection gathers, else `select_rows` +
+    `dsa_decode_attention`."""
+    return table_rows <= RATIO * rows_gathered(group, top)
+
+
+def decode_attend(q, latent_pages, latent_tail, page_table, pos, tail_start,
+                  groups, ok, chosen, lanes, count, *, group: int, dv: int,
+                  sm_scale: float, plan: dict | None = None):
+    """One decode step's attention over what `decode_select` chose, in
+    the form `walks` names for the table's shape.  Returns (o [B, H, dv],
+    rpos, admit: the positions read and whether each is attended)."""
+    n_rows = page_table.shape[1] * latent_pages.shape[2]
+    if walks(n_rows, group, groups.shape[1] * group):
+        bias, tail_bias, rpos, admit = walk_bias(
+            chosen, pos, tail_start, n_rows, latent_tail.shape[2], group)
+        with jax.named_scope("dsa_attn"):
+            o = dsa_walk_attention(
+                q, latent_pages, bias, latent_tail, tail_bias, page_table,
+                tail_start, dv=dv, sm_scale=sm_scale, plan=plan)
+        return o, rpos, admit
+    rows, bias, tail_bias, rpos, admit = select_rows(
+        latent_pages, latent_tail, page_table, pos, tail_start, groups, ok,
+        group)
+    with jax.named_scope("dsa_attn"):
+        o = dsa_decode_attention(
+            q, rows, bias, latent_tail[:, 0], tail_bias, lanes, count,
+            dv=dv, sm_scale=sm_scale)
+    return o, rpos, admit
 
 
 def selection_counts(context: int, group: int, top: int
@@ -402,20 +623,28 @@ COUNTERS = {
     "dsa_groups_scored": "Complete groups the indexer scored, summed "
                          "likewise",
     "dsa_rows_selected": "Rows the selection attended, summed likewise",
+    "dsa_rows_read": "Rows the attend path read from the pool (whole "
+                     "pages where it walks them, a selection's gathered "
+                     "rows elsewhere), summed likewise",
 }
 
 
-def decode_work(layers: int, group: int, top: int, rows, k: int
-                ) -> tuple[dict, dict]:
+def decode_work(layers: int, group: int, top: int, rows, k: int, page: int,
+                maxp: int) -> tuple[dict, dict]:
     """One decode window of `k` steps over live lanes that start it on
-    `rows` cached rows each, x `layers` sparse layers (host arithmetic,
-    `selection_counts`), as COUNTERS' rows; the span shows the same."""
-    sel = [0, 0, 0]         # in COUNTERS' order
+    `rows` cached rows each, x `layers` sparse layers, under a table of
+    `maxp` columns of `page` rows (host arithmetic, `selection_counts`
+    and `walks`), as COUNTERS' rows; the span shows the same."""
+    sel = [0, 0, 0, 0]      # in COUNTERS' order
+    walk = walks(maxp * page, group, top)
     for r in rows:
         for ctx in range(r + 1, r + 1 + k):
             scored, kept = selection_counts(ctx, group, top)
             sel[0] += ctx
             sel[1] += scored
             sel[2] += kept
+        # (the pool holds the window's first `r` rows for all its steps)
+        sel[3] += k * (-(-min(r, maxp * page) // page) * page if walk
+                       else rows_gathered(group, top))
     work = dict(zip(COUNTERS, (n * layers for n in sel)))
     return work, work

@@ -2739,7 +2739,8 @@ class LLMEngine:
             # rows below a lane's block-start position
             attn_steps = sum(max(-(-rows // self.page), 1)
                              for rows in lane_rows)
-            work, shown = self._spec.decode_work(lane_rows, k_win)
+            work, shown = self._spec.decode_work(lane_rows, k_win, self.page,
+                                                 self._maxp)
             ph.update(shown, attn_steps=attn_steps)
             win_traced = tracing.ENABLED and any(
                 self._slots[i] is not None

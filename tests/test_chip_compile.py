@@ -1008,6 +1008,51 @@ def test_dsa_attn_compiles_and_copies_no_pool(one_chip, compiled_kernels):
     assert mem.temp_size_in_bytes < 2 * B * 2176 * w * 2 + (64 << 20)
 
 
+@pytest.mark.parametrize("H,dk,dv,group", [
+    (128, 640, 512, 1),     # dots3-note-prev: a key a token, rotary rows
+    (64, 512, 512, 4),      # glm-5.3-flash: groups of 4
+])
+def test_dsa_walk_compiles_and_gathers_no_row(one_chip, compiled_kernels,
+                                              H, dk, dv, group):
+    """The sparse step's other form at both cells' shapes (64 lanes, 18
+    columns of 512: 4.2 selections of context, under `RATIO`): the bias
+    of a lane's 9,216 pool rows from the selection's bits, and ONE kernel
+    that walks the plan's pages under it.  No row gathered, no page
+    looked up a row, the pool never copied."""
+    from ray_tpu.ops import sparse_attention as dsa
+    from ray_tpu.ops.paged_attention import attention_plan
+
+    def s(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    B, page, maxp, n_pages, top, K = 64, 512, 18, 1153, 2048, 8
+    assert dsa.walks(maxp * page, group, top)
+    n_sel, scored = top // group, maxp * page // group + -(-K // group)
+
+    def step(q, pages, tail, table, pos, ts, groups, ok, chosen, lanes,
+             count):
+        o, _, _ = dsa.decode_attend(
+            q, pages, tail, table, pos, ts, groups, ok, chosen, lanes,
+            count, group=group, dv=dv, sm_scale=0.0625,
+            plan=attention_plan(table, ts, page))
+        return o
+
+    i32 = jnp.int32
+    low, c = _compile(
+        step, s((B, H, dk)), s((n_pages, 1, page, dk)), s((B, 1, K, dk)),
+        s((B, maxp), i32), s((B,), i32), s((B,), i32), s((B, n_sel), i32),
+        s((B, n_sel), jnp.bool_), s((B, scored), jnp.bool_), s((B,), i32),
+        s((), i32))
+    assert low.as_text().count("tpu_custom_call") == 1
+    assert "dsa_attn" in low.as_text()
+    hlo = c.as_text()
+    assert _pool_copies(hlo, n_pages * page * dk // 2) == []
+    # nothing the size of the rows a selection names is ever made: the
+    # bias, its bits and the plan
+    assert c.memory_analysis().temp_size_in_bytes < B * 2176 * dk * 2 // 4
+    assert not re.search(rf"(bf16|s32)\[{B * 2176}[,\]]", hlo)
+
+
 def test_dsa_prefill_kernel_compiles_at_the_served_widths(one_chip,
                                                           compiled_kernels):
     """The masked flash kernel of the 1 x 8192 prefill: 64 heads, q / k /

@@ -17,7 +17,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from serving_reference import served_logits  # rootdir-relative (no pkg)
+import sparse_walk_cases  # rootdir-relative (no pkg)
+from serving_reference import served_logits
 
 from benchmarks.harness.refs import dots3_note as ref
 from ray_tpu.models import dots3_note, named_config, serving_model
@@ -130,15 +131,20 @@ def test_prefill_logits_equal_the_reference(params, n):
     assert _gap(got, _ref_logits(params, tok)) < TOL
 
 
+@pytest.mark.parametrize("form", ["walk", "gather"])
 @pytest.mark.parametrize("n,bucket,new", [(21, 32, 11), (3, 16, 22),
                                           (WINDOW - 1, 16, 12)])
 def test_padded_prefill_then_paged_decode_equals_the_reference(
-        params, n, bucket, new):
+        params, monkeypatch, n, bucket, new, form):
     """The prompt padded to a bucket beside a longer row, scattered into
     both pool leaves and lane 1's rings, then decode in windows of four:
     from 3 rows the context passes the window (9), the ring's wrap (16)
     and the selection's size (16) while decoding; from 21 it starts past
-    all three; from 8 the first step fills the window."""
+    all three; from 8 the first step fills the window.  In both forms of
+    the full layers' decode attention (the table here is narrow: the
+    walk; RATIO 0: the gather a long table gets)."""
+    if form == "gather":
+        monkeypatch.setattr(dsa, "RATIO", 0)
     tok = _tokens(n + new, 3 * n)
     got = served_logits(_Jitted, params, CFG, tok[:n], tok[n:], bucket,
                         page=PAGE, k=K)
@@ -248,6 +254,28 @@ def test_the_selection_keeps_the_best_rows_and_the_own_row():
     assert dsa.selection_counts(40, 1, 16) == (40, 16)
 
 
+@pytest.mark.parametrize("case", sparse_walk_cases.CASES)
+def test_the_walk_attends_what_the_gather_attends(monkeypatch, case):
+    """A key a token, the query's own row forced: the walk over the
+    lane's pages under a bias, the gather + `dsa_attn` and a
+    sort-and-softmax oracle admit the same positions and give the same
+    output: past the selection's size, under it (dense), with the
+    window's rows chosen (held in the tail), with equal scores at the
+    k-th, with a page partly below the block start; an idle lane reads
+    0."""
+    out = sparse_walk_cases.check(case, 1, 16, True, monkeypatch)
+    sets = out["walk"][1]
+    pos = {0: 63, 2: 42, 3: 78}
+    if case == "dense":
+        assert sets[0] == list(range(13)) and sets[3] == list(range(8))
+    elif case != "cut_page":
+        assert all(len(sets[b]) == 16 and pos[b] in sets[b] for b in pos)
+    if case == "held":
+        # the window's rows outscore the pool's: lane 3 (block start 71,
+        # query 78) attends its 8 and the 8 best below
+        assert set(range(71, 79)) <= set(sets[3])
+
+
 # ------------------------------------------------ (c) through the engine
 PROMPTS = (40, 3, WINDOW - 1, 1, 17)
 NEW = 14
@@ -349,6 +377,11 @@ def test_the_engine_counts_what_the_layers_read(served):
     assert loop["dsa_rows_selected"] < loop["dsa_rows_context"]
     assert loop["dsa_rows_selected"] <= steps * n_full * TOP
     assert loop["dsa_groups_scored"] == loop["dsa_rows_context"]
+    # the walk reads whole pages of 16: no more than a page over the
+    # context a step, and more than the 16 rows a selection keeps
+    assert loop["dsa_rows_selected"] < loop["dsa_rows_read"] \
+        < loop["dsa_rows_context"] + steps * n_full * PAGE
+    assert loop["dsa_rows_read"] % PAGE == 0
     assert 0 < loop["prefill_swa_blocks"] <= loop["prefill_swa_blocks_dense"]
     assert loop["prefill_attn_blocks"] == loop["prefill_swa_blocks"]
     cache = st["cache"]
@@ -474,11 +507,16 @@ def test_the_seam_declares_what_the_engine_counts():
     assert spec.lane_state_layers == 2 and spec.routed_layers == 3
     assert not spec.caps
     # two full layers that select 16 rows, two window layers of 9 rows
-    assert spec.decode_work([40], 1)[0] == {
+    # (a table of 6 pages of 16 is walked: 40 rows lie in 3 pages)
+    assert spec.decode_work([40], 1, 16, 6)[0] == {
         "dsa_rows_context": 2 * 41, "dsa_groups_scored": 2 * 41,
-        "dsa_rows_selected": 2 * 16, "swa_rows_context": 2 * 41,
+        "dsa_rows_selected": 2 * 16, "dsa_rows_read": 2 * 48,
+        "swa_rows_context": 2 * 41,
         "swa_rows_attended": 2 * 9, "swa_lane_steps": 2}
-    assert spec.decode_work([3, 40], 2)[0]["swa_rows_attended"] \
+    # past RATIO selections of table the gather reads S = 128 a step
+    assert spec.decode_work([40], 1, 16, 8 * dsa.RATIO + 1)[0][
+        "dsa_rows_read"] == 2 * 128
+    assert spec.decode_work([3, 40], 2, 16, 6)[0]["swa_rows_attended"] \
         == 2 * (4 + 5 + 9 + 9)
     work, shown = spec.prefill_work([9, 17], 32)
     assert shown == {} and set(work) == {

@@ -16,7 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from serving_reference import served_logits  # rootdir-relative (no pkg)
+import sparse_walk_cases  # rootdir-relative (no pkg)
+from serving_reference import served_logits
 
 from benchmarks.harness.refs import glm5_next as ref
 from ray_tpu.models import glm5_next, named_config, serving_model
@@ -80,13 +81,18 @@ def test_prefill_logits_equal_the_reference(params, n):
     assert _gap(got, ref.logits(params, tok, MODEL)) < TOL
 
 
+@pytest.mark.parametrize("form", ["walk", "gather"])
 @pytest.mark.parametrize("n,bucket,new", [(21, 32, 11), (3, 16, 9)])
 def test_padded_prefill_then_paged_decode_equals_the_reference(
-        params, n, bucket, new):
+        params, monkeypatch, n, bucket, new, form):
     """The prompt padded to a bucket beside a longer row, scattered into
     both pool leaves and lane 1, then decode in windows of four: a group
     of index keys completes mid-window, groups straddle the windows'
-    edges, and the context passes the selection's size."""
+    edges, and the context passes the selection's size.  In both forms of
+    the decode step's sparse attention (the table here is narrow: the
+    walk; RATIO 0: the gather a long table gets)."""
+    if form == "gather":
+        monkeypatch.setattr(dsa, "RATIO", 0)
     tok = _tokens(n + new, 3 * n)
     got = served_logits(glm5_next, params, CFG, tok[:n], tok[n:], bucket,
                         page=PAGE, k=K)
@@ -142,6 +148,68 @@ def test_sparse_decode_is_dense_latent_decode_below_the_selections_size():
         q, pages, tail, table, pos, ts, dv=w, sm_scale=0.3)
     assert float(jnp.abs(got - want).max()) < 1e-5
     assert float(jnp.abs(got[1]).max()) == 0.0
+
+
+@pytest.mark.parametrize("case", sparse_walk_cases.CASES)
+def test_the_walk_attends_what_the_gather_attends(monkeypatch, case):
+    """Groups of 4, the query's own incomplete group always attended:
+    the walk over the lane's pages under a bias, the gather + `dsa_attn`
+    and a sort-and-softmax oracle admit the same positions and give the
+    same output: past the selection's size, under it (dense), with the
+    window's complete groups chosen (rows held in the tail, and the rows
+    of the group the block start cuts in the pool), with equal scores at
+    the k-th, with a page partly below the block start; an idle lane
+    reads 0."""
+    out = sparse_walk_cases.check(case, 4, 16, False, monkeypatch)
+    sets = out["walk"][1]
+    if case == "dense":
+        assert sets[0] == list(range(13)) and sets[3] == list(range(8))
+    elif case != "cut_page":
+        # four groups and the own one's rows (position 63: none open)
+        assert [len(sets[b]) for b in (0, 2, 3)] == [16, 16 + 3, 16 + 3]
+    if case == "held":
+        # lane 2 (block start 37, query 42): group 9 (36..39) is scored
+        # in the tail and row 36 lies in the pool
+        assert {36, 37, 38, 39} <= set(sets[2])
+
+
+@pytest.mark.parametrize("group,top,H,dk", [(1, 2048, 128, 640),
+                                            (4, 2048, 64, 512)])
+def test_the_tables_shape_names_the_form(group, top, H, dk):
+    """`walks`: a table of at most RATIO selections' rows is walked, a
+    wider one gathered from, at both cells' widths; 18 columns of 512 (the
+    cells') and the judge's narrower tables walk.  The rows read tell the
+    forms apart without running either."""
+    S = dsa.rows_gathered(group, top)
+    assert S == 2176 and dsa.walks(18 * 512, group, top)
+    edge = dsa.RATIO * S // 512
+    K = 8
+
+    def read(maxp):
+        i32 = jnp.int32
+        B, n = 2, top // group
+        sd = jax.ShapeDtypeStruct
+        _, rpos, admit = jax.eval_shape(
+            lambda *a: dsa.decode_attend(*a, group=group, dv=512,
+                                         sm_scale=0.1),
+            sd((B, H, dk), jnp.bfloat16),
+            sd((1 + B * maxp, 1, 512, dk), jnp.bfloat16),
+            sd((B, 1, K, dk), jnp.bfloat16), sd((B, maxp), i32),
+            sd((B,), i32), sd((B,), i32), sd((B, n), i32),
+            sd((B, n), jnp.bool_),
+            sd((B, maxp * 512 // group + -(-K // group)), jnp.bool_),
+            sd((B,), i32), sd((), i32))
+        assert rpos.shape == admit.shape
+        return rpos.shape[1] - K
+
+    assert dsa.walks(edge * 512, group, top)
+    assert not dsa.walks((edge + 1) * 512, group, top)
+    assert read(edge) == edge * 512 and read(edge + 1) == S
+    # host arithmetic of the same rule: whole pages on the walk, S a
+    # lane-step on the gather
+    for maxp, want in ((edge, 3 * 13 * 512), (edge + 1, 3 * S)):
+        work, shown = dsa.decode_work(1, group, top, [6500], 3, 512, maxp)
+        assert work == shown and work["dsa_rows_read"] == want
 
 
 @pytest.mark.parametrize("T", [128, 384])
@@ -490,9 +558,14 @@ def test_the_seam_declares_what_the_engine_counts():
     assert not spec.caps
     # one sparse layer that selects 16 rows by groups of 4, three KDA
     # layers whose scan walks chunks of 8: through what they count
-    assert spec.decode_work([40], 1)[0] == {
+    # a table of 6 pages of 16 is walked: 40 rows lie in 3 pages
+    assert spec.decode_work([40], 1, 16, 6)[0] == {
         "ssm_lane_steps": 3, "dsa_rows_context": 41,
-        "dsa_groups_scored": 10, "dsa_rows_selected": 16 + 1}
+        "dsa_groups_scored": 10, "dsa_rows_selected": 16 + 1,
+        "dsa_rows_read": 48}
+    # past RATIO selections of table the gather reads S = 128 a step
+    assert spec.decode_work([40], 1, 16, 8 * dsa.RATIO + 1)[0][
+        "dsa_rows_read"] == 128
     assert spec.prefill_work([9, 17], 32) == (
         {"prefill_scan_chunks": 3 * (2 + 3),
          "prefill_scan_chunks_dense": 3 * 2 * 4}, {"scan_chunks": 15})

@@ -30,7 +30,7 @@ def _spec(preset):
 
 def _reported(spec) -> set:
     """The counters the spec's three functions return, on any input."""
-    names = set(spec.decode_work([5, 20], 4)[0])
+    names = set(spec.decode_work([5, 20], 4, 16, 4)[0])
     names |= set(spec.prefill_work(np.array([9, 17]), 32)[0])
     if spec.routed_layers:
         counts = np.ones((spec.routed_layers, 4), np.int32)
@@ -77,7 +77,7 @@ class SixthConfig(llama.LlamaConfig):
 def _sixth_spec(cfg):
     base = llama.serving_spec(cfg)
 
-    def decode_work(rows, k):
+    def decode_work(rows, k, page, maxp):
         work = {"sixth_lane_windows": len(rows)}
         return work, work
 
