@@ -1,5 +1,6 @@
 """The served routed-expert layer, shared by every serving module that
-has one (`models/lfm2.py`, `models/mla_moe.py`): the router, the experts'
+has one (`models/lfm2.py`, `models/mla_moe.py`, `models/glm5_next.py`,
+`models/dots3_note.py`): the router, the experts'
 part over the dropless grouped matmul (`ops/grouped_matmul.gmm`) for the
 range of experts a chip holds, and the shared expert beside them.
 
@@ -12,6 +13,17 @@ scores every expert and selects and normalises over all of them whatever
 range is held: a chip that holds experts lo..hi computes THEIR part of
 the sum, and the parts of disjoint ranges add up to the layer.
 
+What is sized by the assignment list is walked over its HELD head.  The
+counting sort puts the rows of the experts this chip holds first and
+nobody's last; `total`, the length of that head, is a value the device
+holds.  The list is cut into static blocks of `BLOCK` rows and a loop of
+`cdiv(total, BLOCK)` trips runs the gather, both grouped matmuls and the
+SwiGLU on a block, and adds the block's weighted rows at their tokens'
+rows: a chip that holds an eighth of the experts moves about an eighth of
+the rows, at any skew, and drops none (every expert's rows on one chip:
+every block).  A list of at most `BLOCK` rows (every decode program) is
+one block with no loop.  Nothing but the call's static shape decides.
+
 Device-side names: `moe_router`, `moe_experts`, `shared_expert`; the
 grouped matmul's kernel is `moe_gmm`.
 """
@@ -21,9 +33,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.ops.grouped_matmul import gmm, n_visits, visits_static
+from ray_tpu.ops.grouped_matmul import gmm, row_tile, visits, visits_static
 
-COUNTS = 4      # entries of a routed layer's counts (`routed_ffn`)
+COUNTS = 5      # entries of a routed layer's counts (`routed_ffn`)
+# Rows of the sorted assignment list `routed_ffn` walks at a time (the
+# movers alone on a v5e picked it: PERF.md section 5, PR 47).
+BLOCK = 8192
+# The most bytes of a float32 column strip the blocks' rows are added into.
+ACC_BYTES = 32 << 20
 
 
 def route(h2, lp, cfg):
@@ -54,17 +71,21 @@ def routed_ffn(h2, lp, cfg, live=None,
     routed nowhere.  `route_fn`: the caller's router (default `route`;
     a serving module passes its own name for it, so that a test's
     control can stand in for that module's router alone).  Returns
-    (y [T, d], counts int32 [4]: experts of the range that hold a row,
-    the largest load, assignments computed, and the visits of ONE `gmm`
-    call that were work (both calls walk the same list; of
-    `visits_static` at most, the length the list is padded to))."""
+    (y [T, d], counts int32 [COUNTS]: experts of the range that hold a
+    row, the largest load, assignments computed, the visits of ONE `gmm`
+    call a block that were work, summed over the blocks (both calls walk
+    the same lists; of `routed_visits` at most), and the rows of the
+    sorted list the layer moved: the blocks it walked x their rows)."""
     T, d = h2.shape
     k, f = cfg.top_k, cfg.moe_ffn_dim
     lo, hi = experts or (0, cfg.n_experts)
     G = hi - lo
+    N = T * k
+    B = min(BLOCK, N)                  # rows a block
+    blocks = -(-N // B)                # the most blocks there can be
     idx, wts = (route_fn or route)(h2, lp, cfg)
     with jax.named_scope("moe_experts"):
-        flat = idx.reshape(T * k)
+        flat = idx.reshape(N)
         held = (flat >= lo) & (flat < hi)
         if live is not None:
             held &= jnp.repeat(live, k)
@@ -79,21 +100,66 @@ def routed_ffn(h2, lp, cfg, live=None,
         n_all = before[-1]
         place = (jnp.cumsum(n_all) - n_all)[group] + jnp.take_along_axis(
             before, group[:, None], axis=1)[:, 0] - 1
-        order = jnp.zeros((T * k,), jnp.int32).at[place].set(
-            jnp.arange(T * k, dtype=jnp.int32))
+        order = jnp.zeros((blocks * B,), jnp.int32).at[place].set(
+            jnp.arange(N, dtype=jnp.int32))
         sizes = n_all[:G]
-        rows = h2[order // k]                     # [T*k, d] by group
-        h13 = gmm(rows, lp["w13"], sizes)
-        gate, up = clamp(h13[:, :f], h13[:, f:],
-                         getattr(cfg, "swiglu_limit", 0.0))
-        act = jax.nn.silu(gate.astype(jnp.float32)).astype(h2.dtype) * up
-        y = gmm(act, lp["w2"], sizes)             # rows of nobody: 0
-        y = y[place].reshape(T, k, d).astype(jnp.float32)
-        out = jnp.sum(y * wts[..., None], axis=1).astype(h2.dtype)
+        total = jnp.sum(sizes)         # the held head of the list: [0, total)
+        ends = jnp.cumsum(sizes)
+
+        def block(b):
+            """Rows [b B, (b + 1) B) of the sorted list through the
+            experts: (the assignments they are [B], y [B, d], zero past
+            the head)."""
+            if blocks == 1:
+                ids, held_b = order, sizes
+            else:
+                ids = lax.dynamic_slice(order, (b * B,), (B,))
+                held_b = jnp.diff(jnp.clip(
+                    jnp.concatenate([jnp.zeros((1,), jnp.int32), ends]),
+                    b * B, (b + 1) * B))
+            rows = h2[ids // k]                   # [B, d] by group
+            h13 = gmm(rows, lp["w13"], held_b)
+            gate, up = clamp(h13[:, :f], h13[:, f:],
+                             getattr(cfg, "swiglu_limit", 0.0))
+            act = jax.nn.silu(gate.astype(jnp.float32)).astype(h2.dtype) * up
+            return ids, gmm(act, lp["w2"], held_b)  # rows of nobody: 0
+
+        if blocks == 1:
+            # every decode program and the smallest prefill waves: the
+            # whole list is the block, and a token's k parts are read
+            # back by place
+            y = block(0)[1][place].reshape(T, k, d).astype(jnp.float32)
+            out = jnp.sum(y * wts[..., None], axis=1)
+            walked = 1
+        else:
+            # the blocks that hold a held row, a count the device holds;
+            # each row is added at its token's row under its weight.  The
+            # sum is held as column strips of ACC_BYTES at most: the
+            # chip's scatter reads and writes a row of the operand for
+            # each update, and is several times faster on an operand it
+            # can keep in VMEM
+            w_flat = wts.reshape(N)
+            cols = max(128, min(d, ACC_BYTES // (4 * T) // 128 * 128))
+            strips = range(0, d, cols)
+
+            def step(b, acc):
+                ids, y = block(b)
+                w = w_flat[ids][:, None]
+                return tuple(a.at[ids // k].add(
+                    y[:, c:c + cols].astype(jnp.float32) * w)
+                    for a, c in zip(acc, strips))
+
+            walked = -(-total // B)
+            out = jnp.concatenate(lax.fori_loop(
+                0, walked, step,
+                tuple(jnp.zeros((T, min(cols, d - c)), jnp.float32)
+                      for c in strips)), axis=1)
+        tm = row_tile(B)
         counts = jnp.stack([jnp.sum(sizes > 0, dtype=jnp.int32),
-                            jnp.max(sizes), jnp.sum(sizes),
-                            n_visits(sizes, T * k)])
-    return out, counts
+                            jnp.max(sizes), total,
+                            visits(sizes, N + -N % tm, tm)[3],
+                            jnp.asarray(walked * B, jnp.int32)])
+    return out.astype(h2.dtype), counts
 
 
 def routed_visits(cfg, rows: int,
@@ -125,6 +191,9 @@ _ROUTED = {
                   "summed over routed layer-steps",
     "moe_visits_static": "The length its visit list is padded to (row "
                          "tiles + experts held - 1), summed likewise",
+    "moe_rows_moved": "Rows of the sorted assignment list a routed layer "
+                      "gathered (blocks walked x their rows), summed over "
+                      "routed layer-steps",
 }
 COUNTERS = {p + name: f"{text}, {where}"
             for p, where in (("", "in decode"),
@@ -147,13 +216,15 @@ def routed_work(cfg, experts: tuple[int, int] | None, counts, steps: int,
     layer-step."""
     layers = counts.shape[0]
     n = layers * steps
-    hit, load, computed, visits = (int(c) for c in counts.sum(axis=0))
+    hit, load, computed, visits, moved = (
+        int(c) for c in counts.sum(axis=0))
     work = {"moe_layer_steps": n, "moe_experts_hit": hit,
             "moe_max_load": load, "moe_assignments": computed,
             "moe_assignments_absent": rows * cfg.top_k * layers - computed,
             "moe_visits": visits,
             "moe_visits_static": n * routed_visits(cfg, shape_rows,
-                                                   experts)}
+                                                   experts),
+            "moe_rows_moved": moved}
     if prefill:
         return {"prefill_" + name: v for name, v in work.items()}, {}
     return work, {"experts_hit": round(hit / n, 2),

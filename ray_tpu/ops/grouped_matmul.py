@@ -36,6 +36,14 @@ rows make and not for the list; with no row at all no step runs, the
 output is left unwritten, and `gmm` zeroes it as it zeroes every row
 past `sum(group_sizes)`.
 
+Who zeroes what.  The kernel writes the rows its visits name and no
+other, so a row past `sum(group_sizes)` holds whatever the buffer held
+(NaN as well) until `gmm`'s `where` at its end, a pass over the whole
+[m, n] output.  A caller pays it for the rows it hands over:
+`models/routed.routed_ffn` hands a block of the sorted list at a time
+and only the blocks that hold a held row, and every output meets that
+`where` before it meets a router weight.
+
 Elsewhere (the CPU tests) it is `jax.lax.ragged_dot`, as `xla_attention`
 stands in for the flash kernel.
 """
@@ -145,13 +153,6 @@ def visits(group_sizes: jnp.ndarray, m: int, tm: int):
     offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32),
                                ends.astype(jnp.int32)])
     return g, tile, offsets, total
-
-
-def n_visits(group_sizes: jnp.ndarray, m: int):
-    """The visits `gmm` walks for `m` rows in groups of `group_sizes`: a
-    scalar the device holds, `visits_static(m, G)` at most."""
-    tm = row_tile(m)
-    return visits(group_sizes.astype(jnp.int32), m + -m % tm, tm)[3]
 
 
 def _kernel(g_ref, t_ref, off_ref, x_ref, w_ref, o_ref, *, tm: int):

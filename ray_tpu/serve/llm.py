@@ -727,19 +727,21 @@ class LLMEngine:
                                                  s))(seeds)
 
                 def step(carry, j):
-                    tails, state, pos, toks, counts = carry
+                    tails, state, pos, toks = carry
                     logits, tails, state, cnt = model.serve_decode_step(
                         params, pages, tails, state, toks, pos, ts, j,
                         table, cfg, lora, plan)
                     keys = jax.vmap(jax.random.fold_in)(lane_keys,
                                                         starts + j)
                     nxt = _sample_rows(logits, temps, keys)
-                    return (tails, state, pos + 1, nxt, counts + cnt), nxt
+                    return (tails, state, pos + 1, nxt), (nxt, cnt)
 
-                counts0 = jnp.zeros((spec.routed_layers, 4), jnp.int32)
-                (tails, state, pos, last, counts), seq = jax.lax.scan(
-                    step, (tails, cache["state"], ts, tokens, counts0),
+                # a step's counts are the model's own, columns and all:
+                # summed over the window, never read here
+                (tails, state, pos, last), (seq, counts) = jax.lax.scan(
+                    step, (tails, cache["state"], ts, tokens),
                     jnp.arange(K))
+                counts = counts.sum(axis=0)
                 merged = jax.tree.map(
                     lambda pool, tail: merge_tail_pages(
                         pool, tail, table, ts, K,

@@ -293,6 +293,15 @@ def _while_bodies(comps: dict) -> list:
             for b in re.findall(r"\bwhile\(.*body=%([\w.\-]+)", ln)]
 
 
+def _loops_of(hlo: str, scope: str) -> list:
+    """The `while` instructions of a compiled text under `scope` that
+    are not a binary search's (`searchsorted` lowers to one: the visit
+    list of every `gmm` call holds it, and held it before)."""
+    return [ln for ln in hlo.splitlines()
+            if re.search(r"\bwhile\(", ln) and scope in ln
+            and "searchsorted" not in ln]
+
+
 def _loop_lines(hlo: str) -> list:
     """Every instruction line of the `while` bodies and of whatever they
     call, fusions included."""
@@ -691,6 +700,7 @@ def test_sarvam_decode_step_loop_writes_no_weight_and_builds_no_plan(
     cfg, eng, lows = _sarvam_lowerings(one_chip, 2, [])
     hlo = lows["decode_k8"].compile().as_text()
     assert "while(" in hlo and "mla_attn" in hlo and "moe_gmm" in hlo
+    assert _loops_of(hlo, "moe_experts") == []      # one block: no loop
     assert weight_sized_writes(hlo, cfg.dim * cfg.row_used) == []
     assert _pool_copies(hlo, eng.n_pages * 512 * cfg.row_width) == []
     loop = _loop_lines(hlo)
@@ -1127,6 +1137,7 @@ def test_served_glm_engine_fits_one_chip_and_copies_no_state_or_pool(
     c = compiled["decode_k8"]
     hlo = c.as_text()
     assert "while(" in hlo
+    assert _loops_of(hlo, "moe_experts") == []      # one block: no loop
     layer_state = 64 * 64 * 128 * 128        # one KDA layer's lanes
     # (the one other array of that size is the sparse step's gathered
     # rows, 64 lanes x 2,176 rows x 512: what the selection reads, 0.14 GB)
@@ -1235,6 +1246,7 @@ def test_served_dots3_engine_fits_one_chip_and_copies_no_ring_or_pool(
     c = compiled["decode_k8"]
     hlo = c.as_text()
     assert "while(" in hlo
+    assert _loops_of(hlo, "moe_experts") == []      # one block: no loop
     lines = {m.group(1): ln for ln in hlo.splitlines()
              for m in [_INSTR.match(ln)] if m}
     # no copy of a ring (64 lanes x 640 rows) or of a pool leaf (1,153
@@ -1256,3 +1268,66 @@ def test_served_dots3_engine_fits_one_chip_and_copies_no_ring_or_pool(
                 and "swa_attn" in ln]) == 3        # a call a window layer
     assert len([ln for ln in loop if "custom-call(" in ln
                 and "dsa_attn" in ln]) == 2        # a call a full layer
+
+
+# ------------------------------- the routed layer's blocks (PR 47)
+def _routed_layer(config: str):
+    """(the serving module, its program config) of a benchmark
+    configuration with routed layers, as the benchmark builds it."""
+    from benchmarks.harness import spec
+    from ray_tpu.models import serving_model
+
+    conf = spec.load_json(os.path.join(spec.BENCH_DIR, "configs",
+                                       config + ".json"))
+    fam = spec.config_family(conf)
+    cfg = fam.program_config(fam.published(conf),
+                             max_seq=conf["engine"]["max_len"])
+    return serving_model(cfg), cfg
+
+
+@pytest.mark.parametrize("config,lanes,positions", [
+    ("dots3-note-prev-ep8", 64, 8192), ("glm-5.3-flash-ep8", 64, 8192),
+    ("sarvam-105b-ep4", 32, 8192), ("lfm2-24b-a2b-d9", 64, 4096)])
+def test_routed_layer_moves_a_block_of_rows_and_decode_holds_no_loop(
+        topo, one_chip, compiled_kernels, monkeypatch, config, lanes,
+        positions):
+    """One routed layer at the served widths, compiled for the chip in
+    its two shapes.  Prefill (`positions` x top k assignments, more than
+    `routed.BLOCK`): ONE loop over the blocks of the sorted list; the
+    rows gathered in, both `gmm` outputs with their zeroing selects and
+    the SwiGLU are a BLOCK's rows and the rows go back by an add at
+    their token's row, so NOTHING in the program has the list's rows at
+    the model's width or the experts'.  Decode (`lanes` x top k, one
+    block): straight-line code, no loop but the visit lists' binary
+    searches, and no scatter into the layer's output."""
+    from ray_tpu.models import routed
+
+    monkeypatch.setattr(jax, "devices", lambda *a: list(topo.devices))
+    mod, cfg = _routed_layer(config)
+    lid = max(i for i in range(cfg.n_layers) if cfg.is_routed(i))
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    lp = jax.tree.map(sds, jax.eval_shape(
+        lambda: mod.init_params(jax.random.PRNGKey(0), cfg))["layers"][lid])
+    k, d, f = cfg.top_k, cfg.dim, cfg.moe_ffn_dim
+    hlo = {}
+    for rows in (lanes, positions):
+        h2 = jax.ShapeDtypeStruct((rows, d), cfg.dtype, sharding=one_chip)
+        live = jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip)
+        hlo[rows] = jax.jit(
+            lambda h, lp, live: mod.routed_ffn(h, lp, cfg, live)
+        ).lower(h2, lp, live).compile().as_text()
+        assert "moe_gmm" in hlo[rows]
+    assert lanes * k <= routed.BLOCK < positions * k
+    assert _loops_of(hlo[lanes], "moe_experts") == []
+    assert len(_loops_of(hlo[positions], "moe_experts")) == 1
+    assert not re.search(rf"f32\[{lanes},\d+\]\S* scatter\(", hlo[lanes])
+    listed, text = positions * k, hlo[positions]
+    for width in (d, 2 * f, f):
+        assert not re.search(rf"\[{listed},{width}\]", text)
+        if width != f:
+            assert re.search(rf"bf16\[{routed.BLOCK},{width}\]", text)
+    strip = min(d, routed.ACC_BYTES // (4 * positions))
+    assert re.search(rf"f32\[{positions},{strip}\]\S* scatter\(", text)

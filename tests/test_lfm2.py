@@ -154,7 +154,7 @@ def test_the_routed_layer_drops_nothing_under_skew(params, case):
     y, counts = lfm2.routed_ffn(h2, lp, CFG)
     assert float(np.abs(np.asarray(y) - _loop_ffn(h2, lp, idx, wts)).max()) \
         < TOL
-    hit, load, n, _ = (int(c) for c in counts)
+    hit, load, n, *_ = (int(c) for c in counts)
     assert n == 40 * 4                      # every assignment computed
     if case == "all_to_one":
         assert (hit, load) == (4, 40)
@@ -385,6 +385,184 @@ def test_the_parts_of_four_expert_ranges_add_up_to_the_layer(params):
         parts, n = parts + y, n + int(c[2])
     assert float(jnp.abs(parts - whole).max()) < TOL
     assert n == int(counts[2]) == 24 * 4
+
+
+# ------------------------------------- (5b) the held head, in blocks
+def _whole_list_ffn(h2, lp, cfg, live=None, experts=None):
+    """The routed layer over the WHOLE sorted list, the form before the
+    blocks: every one of the T k rows gathered, multiplied and read back
+    by place.  The plain control: (y, sizes)."""
+    T, d = h2.shape
+    k, f = cfg.top_k, cfg.moe_ffn_dim
+    lo, hi = experts or (0, cfg.n_experts)
+    idx, wts = lfm2.route(h2, lp, cfg)
+    flat = np.asarray(idx).reshape(T * k)
+    held = (flat >= lo) & (flat < hi)
+    if live is not None:
+        held &= np.repeat(np.asarray(live), k)
+    group = np.where(held, flat - lo, hi - lo)
+    order = np.argsort(group, kind="stable")
+    place = np.argsort(order, kind="stable")
+    sizes = jnp.asarray(np.bincount(group, minlength=hi - lo + 1)[:hi - lo],
+                        jnp.int32)
+    h13 = grouped_matmul.gmm(h2[order // k], lp["w13"], sizes)
+    act = jax.nn.silu(h13[:, :f]) * h13[:, f:]
+    y = grouped_matmul.gmm(act, lp["w2"], sizes)[place].reshape(T, k, d)
+    return jnp.sum(y * wts[..., None], axis=1), np.asarray(sizes)
+
+
+def _blocked_case(params, case):
+    """(h2, lp, live, experts) of one case of the blocked layer at 40
+    rows x top 4 = 160 assignments."""
+    lp = dict(params["layers"][2])
+    live, experts = None, None
+    if case == "none_held":
+        live = jnp.zeros(40, bool)
+    elif case == "one_expert_holds_every_row":
+        lp["expert_bias"] = jnp.asarray([9., 8, 7, 6, 0, 0, 0, 0])
+        experts = (0, 1)                       # 40 rows, all expert 0's
+    elif case == "total_on_a_boundary":
+        live = jnp.arange(40) < 16             # 16 rows x 4 = 64 held
+    elif case == "a_group_split_by_a_boundary":
+        lp["expert_bias"] = jnp.asarray([9., 8, 7, 6, 0, 0, 0, 0])
+    elif case == "rows_dead":
+        live = jnp.arange(40) % 3 != 0
+    elif case == "a_range_held":
+        experts = (2, 5)
+    if experts:
+        lp.update(w13=lp["w13"][slice(*experts)],
+                  w2=lp["w2"][slice(*experts)])
+    h2 = jax.random.normal(jax.random.PRNGKey(11), (40, CFG.dim))
+    return h2, lp, live, experts
+
+
+BLOCKED_CASES = ["all_held", "none_held", "one_expert_holds_every_row",
+                 "total_on_a_boundary", "a_group_split_by_a_boundary",
+                 "rows_dead", "a_range_held"]
+
+
+@pytest.mark.parametrize("B", [16, 32, 64])
+@pytest.mark.parametrize("case", BLOCKED_CASES)
+def test_the_blocked_layer_equals_the_whole_list_layer(params, monkeypatch,
+                                                       case, B):
+    """`routed_ffn` walks the held head of the sorted list in blocks of
+    `BLOCK` rows (patched below the 160 assignments, so the loop runs):
+    the same layer as over the whole list, the blocks that hold a held
+    row and no other, every visit counted."""
+    h2, lp, live, experts = _blocked_case(params, case)
+    want, sizes = _whole_list_ffn(h2, lp, CFG, live, experts)
+    monkeypatch.setattr(lfm2.routed, "BLOCK", B)
+    got, counts = lfm2.routed_ffn(h2, lp, CFG, live=live, experts=experts)
+    assert float(jnp.abs(got - want).max()) < TOL
+    total = int(sizes.sum())
+    walked = -(-total // B)
+    assert [int(c) for c in counts] == [
+        int((sizes > 0).sum()), int(sizes.max()), total,
+        # a visit: a (group, block) pair that share a row (a block of at
+        # most 128 rows is one row tile)
+        sum(int(grouped_matmul.visits(
+            jnp.asarray(np.diff(np.clip(np.concatenate(
+                [[0], np.cumsum(sizes)]), b * B, (b + 1) * B)), jnp.int32),
+            B, B)[3]) for b in range(walked)),
+        walked * B]
+    blocks = -(-160 // B)
+    assert walked == {"all_held": blocks, "none_held": 0,
+                      "one_expert_holds_every_row": -(-40 // B),
+                      "total_on_a_boundary": 64 // B,
+                      "a_group_split_by_a_boundary": blocks}.get(case,
+                                                                 walked)
+
+
+def test_the_blocked_layer_walks_no_one_or_two_or_every_block(params,
+                                                              monkeypatch):
+    """The trip count is the device's: 0, 1, 2 and all 10 blocks of 16
+    rows as the live rows grow, and `live` rows dead change nothing
+    else."""
+    monkeypatch.setattr(lfm2.routed, "BLOCK", 16)
+    lp = params["layers"][2]
+    h2 = jax.random.normal(jax.random.PRNGKey(12), (40, CFG.dim))
+    for n_live, walked in ((0, 0), (3, 1), (4, 1), (5, 2), (8, 2),
+                           (40, 10)):
+        live = jnp.arange(40) < n_live
+        y, counts = lfm2.routed_ffn(h2, lp, CFG, live=live)
+        assert int(counts[4]) == walked * 16 and int(counts[2]) == n_live * 4
+        want, _ = _whole_list_ffn(h2, lp, CFG, live)
+        assert float(jnp.abs(y - want).max()) < TOL
+        assert float(jnp.abs(y[n_live:]).max(initial=0.0)) == 0.0
+
+
+def test_one_expert_holding_every_row_drops_nothing_in_any_block(
+        params, monkeypatch):
+    """Every row on ONE held expert, its group cut by every block
+    boundary: each row equals the dense product with that expert's
+    weights under the row's router weight, to float32 rounding."""
+    h2, lp, _, experts = _blocked_case(params, "one_expert_holds_every_row")
+    idx, wts = lfm2.route(h2, lp, CFG)
+    assert (np.asarray(idx) == 0).sum() == 40
+    w = np.asarray(jnp.sum(jnp.where(idx == 0, wts, 0.0), axis=1))
+    a = np.asarray(h2) @ np.asarray(lp["w13"][0])
+    f = CFG.moe_ffn_dim
+    want = w[:, None] * ((a[:, :f] / (1 + np.exp(-a[:, :f])) * a[:, f:])
+                         @ np.asarray(lp["w2"][0]))
+    for B in (16, 4096):
+        monkeypatch.setattr(lfm2.routed, "BLOCK", B)
+        got, counts = lfm2.routed_ffn(h2, lp, CFG, experts=experts)
+        assert float(np.abs(np.asarray(got) - want).max()) < TOL
+        assert [int(c) for c in counts[:3]] == [1, 40, 40]
+        assert int(counts[4]) == (48 if B == 16 else 160)
+
+
+def test_the_blocks_rows_are_added_into_column_strips(monkeypatch):
+    """The float32 sum the blocks' rows are added into is held as strips
+    of columns (`ACC_BYTES` each at most, 128 columns at least): at a
+    width of 320 and a budget that leaves 128 columns a strip it is
+    three strips, the last of 64, and the layer is the whole-list
+    layer."""
+    cfg = dataclasses.replace(CFG, dim=320)
+    ks = jax.random.split(jax.random.PRNGKey(13), 4)
+    lp = {"router": jax.random.normal(ks[0], (320, 8)) * 320 ** -0.5,
+          "expert_bias": jnp.zeros(8),
+          "w13": jax.random.normal(ks[1], (8, 320, 64)) * 320 ** -0.5,
+          "w2": jax.random.normal(ks[2], (8, 32, 320)) * 32 ** -0.5}
+    h2 = jax.random.normal(ks[3], (40, 320))
+    live = jnp.arange(40) % 4 != 1
+    want, sizes = _whole_list_ffn(h2, lp, cfg, live)
+    monkeypatch.setattr(lfm2.routed, "BLOCK", 32)
+    monkeypatch.setattr(lfm2.routed, "ACC_BYTES", 4 * 40 * 128)
+    got, counts = lfm2.routed_ffn(h2, lp, cfg, live=live)
+    assert float(jnp.abs(got - want).max()) < TOL
+    assert int(counts[4]) == -(-int(sizes.sum()) // 32) * 32
+    text = jax.jit(lambda h: lfm2.routed_ffn(h, lp, cfg, live=live)).lower(
+        h2).as_text()
+    assert text.count("tensor<40x128xf32>, tensor<40x128xf32>, "
+                      "tensor<40x64xf32>") > 0
+
+
+def test_a_jitted_blocked_layer_holds_one_loop_and_a_short_list_none(
+        params, monkeypatch):
+    """The behaviour follows the call's static shape and nothing else: at
+    most `BLOCK` assignments lower to straight-line code (every decode
+    program), more of them to ONE loop whose trip count the device
+    holds."""
+    monkeypatch.setattr(lfm2.routed, "BLOCK", 32)
+    lp = params["layers"][2]
+    text = {}
+    for rows in (8, 40):
+        h2 = jnp.zeros((rows, CFG.dim))
+        text[rows] = jax.jit(
+            lambda h: lfm2.routed_ffn(h, lp, CFG)).lower(h2).as_text()
+    assert "while" not in text[8] and text[40].count("stablehlo.while") == 1
+
+
+def test_routed_work_reports_the_rows_moved(params):
+    spec = serving_model(CFG).serving_spec(CFG)
+    counts = np.asarray([[3, 9, 20, 4, 64], [2, 8, 12, 3, 32]], np.int32)
+    for prefill, pre in ((False, ""), (True, "prefill_")):
+        work, _ = spec.routed_work(counts, 1, 16, 16, prefill)
+        assert work[pre + "moe_rows_moved"] == 96
+        assert work[pre + "moe_assignments"] == 32
+        assert work[pre + "moe_assignments_absent"] == 16 * 4 * 2 - 32
+        assert pre + "moe_rows_moved" in spec.counters
 
 
 # ---------------------------------------------------------- (6) controls

@@ -230,6 +230,47 @@ def test_attn_ctx_rows_counts_what_the_kernel_admits(params):
     assert loop["attn_steps"] == 2 + 2       # ceil(21/16), ceil(25/16)
 
 
+@pytest.mark.parametrize("held,block", [((0, 2), 64), ((0, 8), 128)],
+                         ids=["a_quarter", "all"])
+def test_the_rows_a_routed_layer_moves_follow_what_the_chip_holds(
+        monkeypatch, held, block):
+    """`moe_rows_moved` beside `moe_assignments` + `moe_assignments_absent`:
+    a prefill program whose sorted list (a bucket x top 4) is longer than
+    `routed.BLOCK` (patched below it) gathers the blocks that hold a held
+    row and no other, so the rows it moves follow the experts held and
+    the prompt's true length; a decode step (2 lanes x 4 assignments) is
+    one block, moved whole; and the tokens are the whole-list engine's."""
+    cfg = dataclasses.replace(CFG, experts_held=held)
+    params = mla_moe.init_params(jax.random.PRNGKey(7), cfg)
+    toks, loops = [], []
+    for b in (routed.BLOCK, block):
+        monkeypatch.setattr(routed, "BLOCK", b)
+        eng = LLMEngine(cfg, params, max_batch=2, max_len=96,
+                        page_size=PAGE, steps_per_sync=K)
+        eng.start()
+        try:
+            toks.append(eng.generate(_tokens(40, 5).tolist(),
+                                     max_new_tokens=5)["tokens"])
+            loops.append(eng.stats()["loop"])
+        finally:
+            eng.stop()
+    assert toks[0] == toks[1]
+    whole, loop = loops
+    layers = mla_moe.serving_spec(cfg).routed_layers
+    listed = loop["prefill_padded_tokens"] * cfg.top_k * layers
+    assert whole["prefill_moe_rows_moved"] == listed     # one block a layer
+    held_rows = loop["prefill_moe_assignments"]
+    moved = loop["prefill_moe_rows_moved"]
+    assert moved % block == 0
+    assert held_rows <= moved < held_rows + layers * block
+    assert held_rows + loop["prefill_moe_assignments_absent"] \
+        == 40 * cfg.top_k * layers
+    if held != (0, 8):
+        assert moved < listed                 # the saving
+    for lp in loops:            # a decode step: the whole list, 8 rows
+        assert lp["moe_rows_moved"] == lp["moe_layer_steps"] * 2 * cfg.top_k
+
+
 # ------------------------------------------------- (4) ranges of experts
 def test_the_parts_of_four_expert_ranges_and_the_shared_expert_once_add_up(
         params):

@@ -15,7 +15,8 @@ import numpy as np
 import pytest
 
 from ray_tpu import models
-from ray_tpu.models import ServingSpec, llama, named_config, serving_model
+from ray_tpu.models import (ServingSpec, llama, named_config, routed,
+                            serving_model)
 from ray_tpu.serve import llm
 from ray_tpu.serve.llm import LLMEngine
 
@@ -33,7 +34,7 @@ def _reported(spec) -> set:
     names = set(spec.decode_work([5, 20], 4, 16, 4)[0])
     names |= set(spec.prefill_work(np.array([9, 17]), 32)[0])
     if spec.routed_layers:
-        counts = np.ones((spec.routed_layers, 4), np.int32)
+        counts = np.ones((spec.routed_layers, routed.COUNTS), np.int32)
         for prefill in (False, True):
             names |= set(spec.routed_work(counts, 4, 8, 4, prefill)[0])
     return names
