@@ -18,7 +18,8 @@ _SERVING = {"LlamaConfig": "ray_tpu.models.llama",
             "MlaMoeConfig": "ray_tpu.models.mla_moe",
             "SsmHybridConfig": "ray_tpu.models.ssm_hybrid",
             "Glm5NextConfig": "ray_tpu.models.glm5_next",
-            "Dots3NoteConfig": "ray_tpu.models.dots3_note"}
+            "Dots3NoteConfig": "ray_tpu.models.dots3_note",
+            "NemotronHConfig": "ray_tpu.models.nemotron_h"}
 
 
 def serving_model(cfg):

@@ -1,6 +1,6 @@
 """The served routed-expert layer, shared by every serving module that
 has one (`models/lfm2.py`, `models/mla_moe.py`, `models/glm5_next.py`,
-`models/dots3_note.py`): the router, the experts'
+`models/dots3_note.py`, `models/nemotron_h.py`): the router, the experts'
 part over the dropless grouped matmul (`ops/grouped_matmul.gmm`) for the
 range of experts a chip holds, and the shared expert beside them.
 
@@ -11,7 +11,12 @@ width, every expert of the deployment), `top_k`, `moe_ffn_dim`,
 none).  The router
 scores every expert and selects and normalises over all of them whatever
 range is held: a chip that holds experts lo..hi computes THEIR part of
-the sum, and the parts of disjoint ranges add up to the layer.
+the sum, and the parts of disjoint ranges add up to the layer.  Two
+things are the caller's to say (`routed_ffn`): an expert's FORM
+(`swiglu_experts` over `w13` and `w2`, the default; `relu2_experts`, two
+matrices around a squared relu), and the rows the router READS where
+they are not the rows the experts multiply (a layer whose experts work in
+a latent narrower than the stream the router scores).
 
 What is sized by the assignment list is walked over its HELD head.  The
 counting sort puts the rows of the experts this chip holds first and
@@ -60,30 +65,60 @@ def route(h2, lp, cfg):
         return idx.astype(jnp.int32), wts * cfg.routed_scaling
 
 
+def swiglu_experts(rows, lp, sizes, cfg):
+    """An expert as a SwiGLU, W_2(silu(W_1 x) * W_3 x), for rows [m, d]
+    sorted by expert (`sizes` [G] of them each): `w13` [G, d, 2f] holds
+    W_1 and W_3 side by side, `w2` [G, f, d]; clamped by
+    `cfg.swiglu_limit` where the config has one."""
+    f = cfg.moe_ffn_dim
+    h13 = gmm(rows, lp["w13"], sizes)
+    gate, up = clamp(h13[:, :f], h13[:, f:],
+                     getattr(cfg, "swiglu_limit", 0.0))
+    act = jax.nn.silu(gate.astype(jnp.float32)).astype(rows.dtype) * up
+    return gmm(act, lp["w2"], sizes)
+
+
+def relu2_experts(rows, lp, sizes, cfg):
+    """An expert as two matrices, W_2 relu(W_1 x)**2, not gated: `w1`
+    [G, d, f], `w2` [G, f, d]."""
+    return gmm(relu2(gmm(rows, lp["w1"], sizes)), lp["w2"], sizes)
+
+
+def relu2(h):
+    """relu(h) squared, in h's dtype."""
+    return jnp.square(jnp.maximum(h, 0))
+
+
 def routed_ffn(h2, lp, cfg, live=None,
-               experts: tuple[int, int] | None = None, route_fn=None):
+               experts: tuple[int, int] | None = None, route_fn=None,
+               router_rows=None, expert_fn=swiglu_experts):
     """The routed experts' part of FF for rows h2 [T, d].
 
     `experts` = (lo, hi): the range of experts whose weights `lp` holds
-    (`w13` [hi-lo, d, 2f], `w2` [hi-lo, f, d]); default all.  The result
+    (`expert_fn`'s, e.g. `w13` [hi-lo, d, 2f], `w2` [hi-lo, f, d]);
+    default all.  The result
     is THEIR part of the sum, so the parts of disjoint ranges add up to
     the layer.  `live` [T] bool: rows that hold a request; the others are
     routed nowhere.  `route_fn`: the caller's router (default `route`;
     a serving module passes its own name for it, so that a test's
-    control can stand in for that module's router alone).  Returns
+    control can stand in for that module's router alone).
+    `router_rows` [T, any width]: what the router reads, where that is
+    not h2.  `expert_fn(rows, lp, sizes, cfg)`: the experts' form
+    (`swiglu_experts`, `relu2_experts`).  Returns
     (y [T, d], counts int32 [COUNTS]: experts of the range that hold a
     row, the largest load, assignments computed, the visits of ONE `gmm`
     call a block that were work, summed over the blocks (both calls walk
     the same lists; of `routed_visits` at most), and the rows of the
     sorted list the layer moved: the blocks it walked x their rows)."""
     T, d = h2.shape
-    k, f = cfg.top_k, cfg.moe_ffn_dim
+    k = cfg.top_k
     lo, hi = experts or (0, cfg.n_experts)
     G = hi - lo
     N = T * k
     B = min(BLOCK, N)                  # rows a block
     blocks = -(-N // B)                # the most blocks there can be
-    idx, wts = (route_fn or route)(h2, lp, cfg)
+    idx, wts = (route_fn or route)(
+        h2 if router_rows is None else router_rows, lp, cfg)
     with jax.named_scope("moe_experts"):
         flat = idx.reshape(N)
         held = (flat >= lo) & (flat < hi)
@@ -118,11 +153,7 @@ def routed_ffn(h2, lp, cfg, live=None,
                     jnp.concatenate([jnp.zeros((1,), jnp.int32), ends]),
                     b * B, (b + 1) * B))
             rows = h2[ids // k]                   # [B, d] by group
-            h13 = gmm(rows, lp["w13"], held_b)
-            gate, up = clamp(h13[:, :f], h13[:, f:],
-                             getattr(cfg, "swiglu_limit", 0.0))
-            act = jax.nn.silu(gate.astype(jnp.float32)).astype(h2.dtype) * up
-            return ids, gmm(act, lp["w2"], held_b)  # rows of nobody: 0
+            return ids, expert_fn(rows, lp, held_b, cfg)  # nobody's: 0
 
         if blocks == 1:
             # every decode program and the smallest prefill waves: the
@@ -268,8 +299,8 @@ def stack_counts(per_layer: list) -> jnp.ndarray:
 
 
 def prefill_params(cfg, rest: int, layers: int,
-                   experts: tuple[int, int] | None = None
-                   ) -> tuple[int, int]:
+                   experts: tuple[int, int] | None = None,
+                   one: int | None = None) -> tuple[int, int]:
     """(streamed, multiplied) matmul parameters of a model with `layers`
     routed layers over the range `experts` (default all) and `rest`
     parameters that every position multiplies (attention, convolutions,
@@ -278,9 +309,10 @@ def prefill_params(cfg, rest: int, layers: int,
     the grouped matmul reads a hit expert whole and ~100 positions hit
     them all.  MULTIPLIED: what ONE position multiplies: of its `top_k`
     experts those this chip holds.  Neither counts the embedding (a
-    lookup) or the head (one position a row)."""
+    lookup) or the head (one position a row).  `one`: an expert's
+    parameters where it is no SwiGLU of `dim` x `moe_ffn_dim`."""
     lo, hi = experts or (0, cfg.n_experts)
-    one = 3 * cfg.dim * cfg.moe_ffn_dim
+    one = one or 3 * cfg.dim * cfg.moe_ffn_dim
     rest += layers * cfg.dim * cfg.n_experts
     return (rest + layers * (hi - lo) * one,
             rest + layers * cfg.top_k * (hi - lo) * one // cfg.n_experts)

@@ -292,8 +292,8 @@ def scan_state(xs, dt, Bm, Cm, lp, cfg: SsmHybridConfig):
     """Step 5 over whole rows, from what `scan_inputs` gives: (y [b, T,
     H, P] float32, D x added; the state after each row's last position
     with dt > 0, [b, N, inner] in `state_dtype`: what a lane is handed)."""
-    y, state = ssm.ssd_scan(xs, dt, -jnp.exp(lp["A_log"]), Bm, Cm,
-                            cfg.ssm_chunk)
+    y, state = ssm.ssd_scan(xs, dt, -jnp.exp(lp["A_log"]), Bm[:, :, None],
+                            Cm[:, :, None], cfg.ssm_chunk)  # one group
     return (y + lp["D"][:, None] * xs.astype(F32),
             state.astype(cfg.state_dtype))
 
@@ -483,7 +483,8 @@ def mamba_decode(x, lp, conv, ssm_state, layer, lanes, count,
     z, xs, dt, Bv, Cv, conv = decode_inputs(x, lp, conv, cfg)
     ssm_state, y = ssm.ssm_update(
         ssm_state, layer, lanes, count, xs, jnp.repeat(dt, P, axis=-1),
-        Bv, Cv, jnp.repeat(lp["A_log"], P), jnp.repeat(lp["D"], P))
+        Bv[:, None], Cv[:, None],             # one group
+        jnp.repeat(lp["A_log"], P), jnp.repeat(lp["D"], P))
     d = _gate_out(y, z, lp, cfg)
     return (x + (cfg.residual_scale * d.astype(F32)).astype(cfg.dtype),
             conv, ssm_state)

@@ -6,7 +6,21 @@ cell) and of `benchmarks/tests/test_ssm_hybrid_family.py` (the
 `ssm_hybrid` family and its cell) and of
 `benchmarks/tests/test_dots3_note_family.py` (the `dots3_note` family and
 its cell), imported so that they run, and count, with `pytest tests/`."""
+from benchmarks.harness import spec
+from benchmarks.tests import test_dots3_note_family as _dots3
 from benchmarks.tests.test_family import *  # noqa: F401,F403
 from benchmarks.tests.test_lfm2_family import *  # noqa: F401,F403
 from benchmarks.tests.test_ssm_hybrid_family import *  # noqa: F401,F403
 from benchmarks.tests.test_dots3_note_family import *  # noqa: F401,F403
+
+
+def test_the_dots3_cell_is_found_by_its_files(dots_cell, monkeypatch):
+    """The benchmark's own case, given the benchmark as PR 45 knew it:
+    the case counts the cells (9, one of them on four chips), later PRs
+    add cells, and its file is the benchmark's, which only a `benchmark`
+    PR edits.  Everything else it checks runs as written."""
+    bench = spec.benchmark_json()
+    known = dict(bench, workloads=bench["workloads"][:9])
+    monkeypatch.setattr(spec, "benchmark_json",
+                        lambda root=spec.ROOT: known)
+    _dots3.test_the_dots3_cell_is_found_by_its_files(dots_cell)

@@ -788,22 +788,27 @@ def _granite_lowerings(one_chip, shapes, layer_types=None):
     return cfg, eng, out
 
 
-@pytest.mark.parametrize("lanes,layers", [(64, 36), (64, 9), (8, 36)])
+@pytest.mark.parametrize("lanes,layers,HP,G", [
+    (64, 36, 4096, 1), (64, 9, 4096, 1), (8, 36, 4096, 1),
+    (64, 5, 8192, 8)])
 def test_ssm_update_compiles_at_the_served_widths(one_chip, compiled_kernels,
-                                                  lanes, layers):
+                                                  lanes, layers, HP, G):
     """granite-4.0-h-micro's lanes: 36 layers x [128, 4096] float32 a
-    lane, one 2 MB block a grid step, read and written through the alias;
-    nothing the size of the state is a temporary."""
+    lane in one group, one 2 MB block a grid step; the nemotron_h cut's:
+    5 layers x [128, 8192] in eight groups, a 0.5 MB block of one group's
+    1,024 columns a step (the lane's whole 4 MB, in and out and
+    double-buffered, is over the scoped VMEM): read and written through
+    the alias; nothing the size of the state is a temporary."""
     from ray_tpu.ops import ssm
 
     def s(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    L, N, HP = layers, 128, 4096
+    L, N = layers, 128
     low = jax.jit(ssm.ssm_update, donate_argnums=(0,)).lower(
         s((L, lanes, N, HP), jnp.float32), s((), jnp.int32),
         s((lanes,), jnp.int32), s((), jnp.int32), s((lanes, HP)),
-        s((lanes, HP), jnp.float32), s((lanes, N)), s((lanes, N)),
+        s((lanes, HP), jnp.float32), s((lanes, G, N)), s((lanes, G, N)),
         s((HP,), jnp.float32), s((HP,), jnp.float32))
     assert low.as_text().count("tpu_custom_call") == 1
     c = low.compile()
@@ -892,6 +897,92 @@ def test_granite_decode_step_loop_copies_no_lane_state_and_no_weight(
     loop = _loop_lines(hlo)
     calls = [ln for ln in loop if "custom-call(" in ln and "ssm_update" in ln]
     assert len(calls) == 2                  # a body a Mamba run
+
+
+# ------------------------------------- Nemotron-3-Super, one period (PR 48)
+def _nemotron_lowerings(one_chip, shapes):
+    """benchmarks/configs/nemotron-3-super-120b-a12b-ep4.json as the
+    benchmark builds it."""
+    from benchmarks.harness import spec
+    from ray_tpu.serve.llm import LLMEngine
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def abstract(tree):
+        return jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
+
+    conf = spec.load_json(os.path.join(
+        spec.BENCH_DIR, "configs", "nemotron-3-super-120b-a12b-ep4.json"))
+    fam = spec.config_family(conf)
+    eng_kw = dict(conf["engine"], paged=True)
+    cfg = fam.program_config(fam.published(conf), max_seq=eng_kw["max_len"])
+    params = abstract(jax.eval_shape(
+        lambda: fam.init_params(jax.random.PRNGKey(0), cfg)))
+    eng = LLMEngine(cfg, params, **eng_kw)
+    i32, f32 = jnp.int32, jnp.float32
+    b, k = eng.max_batch, eng.steps_per_sync
+    out = {f"decode_k{k}": eng._decode_fns[k].lower(
+        params, abstract(eng.cache), sds((b,), i32), sds((b,), f32),
+        sds((b, eng._maxp), i32), sds((b,), i32), sds((b,), i32), None)}
+    for w, p in shapes:
+        out[f"prefill_w{w}_p{p}"] = eng._prefill_fwd.lower(
+            params, sds((w, p), i32), sds((w,), i32), sds((w,), i32),
+            sds((w,), f32), sds((w,), i32), sds((w,), i32), None)
+    return cfg, eng, out
+
+
+@pytest.mark.time_limit(900)
+def test_served_nemotron_engine_fits_one_chip_and_copies_no_lane_state(
+        topo, one_chip, compiled_kernels, monkeypatch):
+    """nemotron-3-super-120b-a12b-ep4 as the benchmark serves it (one
+    period MEMEMEM*EME, 128 of 512 experts held, 64 lanes, 1,153 pages):
+    the decode program and the ONE prefill program its traffic reaches
+    (1 x 8192) compile for one chip beside 11.3 GB of weights, lane
+    state and pool; inside the K-step loop the lanes' state (1.34 GB) is
+    touched by `ssm_update` alone, which aliases it."""
+    monkeypatch.setattr(jax, "devices", lambda *a: list(topo.devices))
+    cfg, eng, lows = _nemotron_lowerings(one_chip, [(1, 8192)])
+    lane = eng.stats()["lane_state"]
+    assert lane["by_kind"] == {"conv": 5 * 64 * 3 * 10240 * 2,
+                               "ssm": 5 * 64 * 128 * 8192 * 4}
+    cache = eng._cache_stats()
+    assert (cache["kind"], cache["layers"], cache["row_bytes"]) == (
+        "kv", 1, 2 * 2 * 128 * 2)
+    streamed, multiplied = eng._spec.prefill_params
+    assert eng._prefill_floor == 256 * streamed // multiplied == 1112
+    weights = sum(math.prod(a.shape) * a.dtype.itemsize
+                  for a in jax.tree.leaves(eng.params))
+    # bfloat16 but for dt_bias, A_log, D and the router's biases (float32)
+    assert weights == 2 * 4_648_163_712 + 2 * (5 * 3 * 128 + 5 * 512)
+    resident = weights + lane["bytes"] + cache["pool_bytes"]
+    assert 11.2e9 < resident < 11.4e9        # 67 % of the chip
+    kernels = {"decode_k8": ("ssm_update", "moe_gmm", "paged_attn"),
+               "prefill_w1_p8192": ("flash_fwd", "moe_gmm")}
+    for name, low in lows.items():
+        txt = low.as_text()
+        for kern in kernels[name]:
+            assert kern in txt, (name, kern)
+        c = low.compile()
+        mem = c.memory_analysis()
+        held = resident + mem.temp_size_in_bytes + (
+            mem.output_size_in_bytes - mem.alias_size_in_bytes)
+        print(f"{name}: temps {mem.temp_size_in_bytes / 1e9:.2f} GB, "
+              f"resident {resident / 1e9:.2f} GB, held {held / 1e9:.2f} GB")
+        assert held < 16.9e9 - 1.0e9, (name, held)
+        if name != "decode_k8":
+            continue
+        hlo = c.as_text()
+        layer_state = 64 * 128 * 8192
+        found = weight_sized_writes(hlo, layer_state)
+        assert found and all(op == "custom-call" and "ssm_update" in scope
+                             for _, op, scope in found), found
+        assert mem.alias_size_in_bytes >= 5 * layer_state * 4
+        loop = _loop_lines(hlo)
+        assert len([ln for ln in loop if "custom-call(" in ln
+                    and "ssm_update" in ln]) == 5
+        assert len([ln for ln in loop if "custom-call(" in ln
+                    and "moe_gmm" in ln]) == 10
 
 
 # ------------------------------------------------- GLM-5.3-Flash (PR 41)

@@ -21,7 +21,7 @@ from ray_tpu.serve import llm
 from ray_tpu.serve.llm import LLMEngine
 
 PRESETS = ("debug", "lfm2-debug", "mla-debug", "ssm-hybrid-debug",
-           "glm5-next-debug", "dots3-note-debug")
+           "glm5-next-debug", "dots3-note-debug", "nemotron-h-debug")
 
 
 def _spec(preset):
@@ -42,7 +42,7 @@ def _reported(spec) -> set:
 
 @functools.cache
 def _family_names() -> frozenset:
-    """Every work counter any of the six families declares."""
+    """Every work counter any of the served families declares."""
     return frozenset().union(*(_spec(p)[1].counters for p in PRESETS))
 
 

@@ -18,6 +18,7 @@ import pytest
 
 from serving_reference import served_logits  # rootdir-relative (no pkg)
 
+from benchmarks.harness.refs import nemotron_h as ref_groups
 from benchmarks.harness.refs import ssm_hybrid as ref
 from ray_tpu.models import named_config, serving_model, ssm_hybrid
 from ray_tpu.ops import paged_attention, ssm
@@ -61,7 +62,8 @@ def _tokens(n, seed=0):
     return np.random.default_rng(seed).integers(0, 256, n).astype(np.int32)
 
 
-def _scan_inputs(b, T, H=4, P=16, N=16, lens=None, seed=0):
+def _scan_inputs(b, T, H=4, P=16, N=16, lens=None, seed=0, G=1):
+    """B and C [b, T, G, N]: `ssd_scan` takes them a group of heads."""
     k = jax.random.split(jax.random.PRNGKey(seed), 5)
     x = jax.random.normal(k[0], (b, T, H, P))
     dt = jax.nn.softplus(jax.random.normal(k[1], (b, T, H)) - 2)
@@ -69,27 +71,36 @@ def _scan_inputs(b, T, H=4, P=16, N=16, lens=None, seed=0):
         dt = jnp.where(jnp.arange(T)[None, :, None]
                        < jnp.asarray(lens)[:, None, None], dt, 0.0)
     A = -jnp.exp(jax.random.normal(k[2], (H,)))
-    B = jax.random.normal(k[3], (b, T, N))
-    C = jax.random.normal(k[4], (b, T, N))
+    B = jax.random.normal(k[3], (b, T, G, N))
+    C = jax.random.normal(k[4], (b, T, G, N))
     return x, dt, A, B, C
 
 
 def _recurrence(x, dt, A, B, C):
-    """The recurrence token by token for ONE row (the plain reference's
-    `lax.scan` over positions), in `ssd_scan`'s shapes."""
-    y, h = ref.recurrence(x[0], dt[0], A, B[0], C[0])
+    """The recurrence token by token for ONE row (the plain references'
+    `lax.scan` over positions: granite's for one group, the grouped
+    model's for more), in `ssd_scan`'s shapes."""
+    if B.shape[2] == 1:
+        y, h = ref.recurrence(x[0], dt[0], A, B[0, :, 0], C[0, :, 0])
+    else:
+        y, h = ref_groups.recurrence(x[0], dt[0], A, B[0], C[0])
     return y[None], h[None]
 
 
 # ------------------------------------------ (a) the chunked scan, ops/ssm
-@pytest.mark.parametrize("T,chunk,lens", [
-    (20, 8, [13, 20]),      # no multiple of the chunk, right padding
-    (32, 8, [1, 31]),       # a row of one token
-    (5, 8, [5, 3]),         # a bucket under one chunk: one short chunk
-    (64, 16, [64, 17]),
+@pytest.mark.parametrize("T,chunk,lens,H,G", [
+    (20, 8, [13, 20], 4, 1),    # no multiple of the chunk, right padding
+    (32, 8, [1, 31], 4, 1),     # a row of one token
+    (5, 8, [5, 3], 4, 1),       # a bucket under one chunk: one short chunk
+    (64, 16, [64, 17], 4, 1),
+    (20, 8, [13, 20], 16, 8),   # eight groups of two heads
+    (32, 8, [1, 31], 4, 2),
+    (64, 16, [64, 17], 8, 8),   # a head a group
+    (5, 8, [5, 3], 16, 8),
 ])
-def test_ssd_scan_equals_the_recurrence_at_the_true_length(T, chunk, lens):
-    x, dt, A, B, C = _scan_inputs(2, T, lens=lens)
+def test_ssd_scan_equals_the_recurrence_at_the_true_length(T, chunk, lens,
+                                                           H, G):
+    x, dt, A, B, C = _scan_inputs(2, T, H=H, lens=lens, G=G)
     y, h = ssm.ssd_scan(x, dt, A, B, C, chunk)
     for row, n in enumerate(lens):
         cut = [a[row:row + 1, :n] for a in (x, dt)] + [A] \
@@ -99,6 +110,66 @@ def test_ssd_scan_equals_the_recurrence_at_the_true_length(T, chunk, lens):
         assert float(jnp.max(jnp.abs(y[row, :n] - want_y[0]))) < 1e-5 * scale
         assert float(jnp.max(jnp.abs(h[row] - want_h[0]))) \
             < 1e-5 * float(jnp.max(jnp.abs(want_h)))
+
+
+def _ssd_scan_one_group(x, dt, A, B, C, chunk: int):
+    """`ssd_scan` as it stood while every head shared ONE B and C ([b, T,
+    N]; PR 39 to PR 47), kept here to hold the grouped form to its bits."""
+    F32, _HI = jnp.float32, jax.lax.Precision.HIGHEST
+    b, T, H, P = x.shape
+    N = B.shape[-1]
+    Q = min(chunk, T)
+    pad = -T % Q
+    if pad:
+        x, dt, B, C = (
+            jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+            for a in (x, dt, B, C))
+    nc = (T + pad) // Q
+
+    def chunks(a):
+        return jnp.moveaxis(a.reshape(b, nc, Q, *a.shape[2:]), 1, 0)
+
+    tri = jnp.tril(jnp.ones((Q, Q), bool))
+
+    def step(h, xs):
+        xc, dtc, Bc, Cc = xs
+        cs = jnp.cumsum(dtc * A, axis=1)
+        csh = jnp.moveaxis(cs, 2, 1)
+        seg = csh[:, :, :, None] - csh[:, :, None, :]
+        Lm = jnp.exp(jnp.where(tri, seg, -jnp.inf))
+        G = jnp.einsum("bin,bjn->bij", Cc, Bc, preferred_element_type=F32)
+        xdt = xc.astype(F32) * dtc[..., None]
+        y = jnp.einsum("bhij,bjhp->bihp", (G[:, None] * Lm).astype(x.dtype),
+                       xdt.astype(x.dtype), preferred_element_type=F32)
+        hh = h.reshape(b, N, H, P)
+        y += jnp.einsum("bin,bnhp->bihp", Cc.astype(F32), hh,
+                        preferred_element_type=F32) * jnp.exp(cs)[..., None]
+        to_end = jnp.exp(cs[:, -1:, :] - cs)
+        hh = (jnp.exp(cs[:, -1])[:, None, :, None] * hh
+              + jnp.einsum("bjn,bjhp->bnhp", Bc.astype(F32),
+                           xdt * to_end[..., None], precision=_HI,
+                           preferred_element_type=F32))
+        return hh.reshape(b, N, H * P), y
+
+    h, ys = jax.lax.scan(step, jnp.zeros((b, N, H * P), F32),
+                         tuple(chunks(a) for a in (x, dt, B, C)))
+    y = jnp.moveaxis(ys, 0, 1).reshape(b, nc * Q, H, P)
+    return y[:, :T], h
+
+
+@pytest.mark.parametrize("T,chunk,lens,dtype", [
+    (20, 8, [13, 20], jnp.float32), (32, 8, [1, 31], jnp.float32),
+    (5, 8, [5, 3], jnp.float32), (32, 8, [32, 9], jnp.bfloat16)])
+def test_ssd_scan_with_one_group_is_the_one_group_form_bit_for_bit(
+        T, chunk, lens, dtype):
+    """Granite's call (G = 1, the debug preset's 4 heads of 16 over a
+    state of 16): the grouped scan gives the bits the one-group scan
+    gave."""
+    x, dt, A, B, C = _scan_inputs(2, T, lens=lens)
+    x, B, C = (a.astype(dtype) for a in (x, B, C))
+    y, h = ssm.ssd_scan(x, dt, A, B, C, chunk)
+    y1, h1 = _ssd_scan_one_group(x, dt, A, B[:, :, 0], C[:, :, 0], chunk)
+    assert bool(jnp.all(y == y1)) and bool(jnp.all(h == h1))
 
 
 def test_ssd_scan_carries_the_state_between_chunks():
@@ -115,25 +186,32 @@ def test_ssd_scan_carries_the_state_between_chunks():
 
 
 # -------------------------------------- (b) the one-step kernel, ops/ssm
-def _update_inputs(nb=6, L=3, H=4, P=16, N=16, seed=1):
+def _update_inputs(nb=6, L=3, H=4, P=16, N=16, seed=1, G=1):
+    """B and C [nb, G, N]: `ssm_update` takes them a group of heads."""
     k = jax.random.split(jax.random.PRNGKey(seed), 6)
     state = jax.random.normal(k[0], (L, nb, N, H * P))
     x = jax.random.normal(k[1], (nb, H * P))
     dt = jnp.repeat(jax.random.normal(k[2], (nb, H)), P, axis=1)
     A_log = jnp.repeat(jnp.log(jnp.arange(1.0, H + 1)), P)
     D = jnp.repeat(jax.random.normal(k[3], (H,)), P)
-    return (state, x, dt, jax.random.normal(k[4], (nb, N)),
-            jax.random.normal(k[5], (nb, N)), A_log, D)
+    return (state, x, dt, jax.random.normal(k[4], (nb, G, N)),
+            jax.random.normal(k[5], (nb, G, N)), A_log, D)
 
 
-@pytest.mark.parametrize("live,heads", [
-    ([0, 1, 1, 0, 1, 0], 16), ([1, 1, 1, 1, 1, 1], 4),
-    ([0, 0, 0, 0, 0, 1], 16), ([0, 0, 0, 0, 0, 0], 4)])
+@pytest.mark.parametrize("live,heads,G", [
+    ([0, 1, 1, 0, 1, 0], 16, 1), ([1, 1, 1, 1, 1, 1], 4, 1),
+    ([0, 0, 0, 0, 0, 1], 16, 1), ([0, 0, 0, 0, 0, 0], 4, 1),
+    # groups: a step's block is one group's columns of one lane.  16
+    # heads in 2 groups: a block of one register-wide tile; in 8: a
+    # narrower tile; 64 heads in 8: a register-wide tile a group
+    ([0, 1, 1, 0, 1, 0], 16, 2), ([1, 1, 1, 1, 1, 1], 16, 8),
+    ([0, 1, 0, 0, 1, 1], 64, 8), ([0, 0, 0, 0, 0, 0], 16, 8),
+    ([0, 0, 0, 1, 0, 0], 4, 4)])
 def test_ssm_update_is_one_recurrence_step_and_leaves_idle_lanes(
-        live, heads):
+        live, heads, G):
     # 16 heads of 16: a lane's block is two register-wide column tiles;
     # 4 heads: one narrower tile
-    state, x, dt, B, C, A_log, D = _update_inputs(H=heads)
+    state, x, dt, B, C, A_log, D = _update_inputs(H=heads, G=G)
     live = jnp.asarray(live, bool)
     lanes, count = ssm.live_lanes(live)
     assert int(count) == int(live.sum())
@@ -141,9 +219,13 @@ def test_ssm_update_is_one_recurrence_step_and_leaves_idle_lanes(
     new, y = ssm.ssm_update(state, jnp.int32(1), lanes, count, x, dt, B, C,
                             A_log, D)
     d = jax.nn.softplus(dt)
+    # a column reads its group's B and C: [nb, N, HP]
+    per = state.shape[-1] // G
+    Bc = jnp.repeat(jnp.moveaxis(B, 1, 2), per, axis=2)
+    Cc = jnp.repeat(jnp.moveaxis(C, 1, 2), per, axis=2)
     want = (jnp.exp(d * -jnp.exp(A_log))[:, None] * state[1]
-            + B[:, :, None] * (d * x)[:, None])
-    want_y = jnp.einsum("bn,bnc->bc", C, want) + D * x
+            + Bc * (d * x)[:, None])
+    want_y = jnp.sum(Cc * want, axis=1) + D * x
     on, off = np.flatnonzero(live), np.flatnonzero(~live)
     assert float(jnp.max(jnp.abs(new[1][on] - want[on]), initial=0)) < 1e-5
     assert float(jnp.max(jnp.abs(y[on] - want_y[on]), initial=0)) < 1e-4
@@ -153,6 +235,65 @@ def test_ssm_update_is_one_recurrence_step_and_leaves_idle_lanes(
     assert bool(jnp.all(y[off] == 0))
     assert bool(jnp.all(new[0] == state[0])) \
         and bool(jnp.all(new[2] == state[2]))
+
+
+def _ssm_update_one_group(state, layer, lanes, count, x, dt, B, C, A_log, D):
+    """`ssm_update`'s call as it stood while every head shared ONE B and C
+    (a step a lane, the lane's whole [N, HP] block; PR 39 to PR 47)
+    around the kernel body of today, kept here to hold the grouped call
+    to its bits."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    F32 = jnp.float32
+    L, nb, N, HP = state.shape
+    tw = ssm.LANES if HP % ssm.LANES == 0 else HP
+    state_map = lambda i, lanes, layer: (layer[0], lanes[i], 0, 0)  # noqa: E731
+    row_map = lambda i, lanes, layer: (lanes[i], 0, 0)      # noqa: E731
+    const_map = lambda i, lanes, layer: (0, 0)              # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(count,),
+        in_specs=[pl.BlockSpec((1, 1, N, HP), state_map),
+                  pl.BlockSpec((1, 1, HP), row_map),
+                  pl.BlockSpec((1, 1, HP), row_map),
+                  pl.BlockSpec((1, 1, N), row_map),
+                  pl.BlockSpec((1, 1, N), row_map),
+                  pl.BlockSpec((1, HP), const_map),
+                  pl.BlockSpec((1, HP), const_map)],
+        out_specs=[pl.BlockSpec((1, 1, N, HP), state_map),
+                   pl.BlockSpec((1, 1, HP), row_map)],
+        scratch_shapes=[pltpu.VMEM((2, N, tw), F32)])
+    new, y = pl.pallas_call(
+        ssm._update_kernel, name="ssm_update", grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
+                   jax.ShapeDtypeStruct((nb, 1, HP), F32)],
+        input_output_aliases={2: 0}, interpret=True,
+    )(lanes, jnp.reshape(layer, (1,)).astype(jnp.int32), state,
+      x[:, None, :], dt[:, None, :], B[:, None, :], C[:, None, :],
+      A_log.astype(F32)[None, :], D.astype(F32)[None, :])
+    listed = jnp.any((lanes[None, :] == jnp.arange(nb)[:, None])
+                     & (jnp.arange(nb)[None, :] < count), axis=1)
+    return new, jnp.where(listed[:, None], y[:, 0], 0.0)
+
+
+@pytest.mark.parametrize("live,heads", [
+    ([0, 1, 1, 0, 1, 0], 4), ([1, 1, 1, 1, 1, 1], 16),
+    ([0, 0, 0, 0, 0, 0], 4)])
+def test_ssm_update_with_one_group_is_the_one_group_call_bit_for_bit(
+        live, heads):
+    """Granite's call (G = 1; the debug preset's 4 heads of 16, and a
+    block of two register-wide tiles): the work list of (lane, group)
+    pairs is the work list of lanes."""
+    state, x, dt, B, C, A_log, D = _update_inputs(H=heads)
+    lanes, count = ssm.live_lanes(jnp.asarray(live, bool))
+    new, y = ssm.ssm_update(state, jnp.int32(2), lanes, count, x, dt, B, C,
+                            A_log, D)
+    new1, y1 = _ssm_update_one_group(state, jnp.int32(2), lanes, count, x,
+                                     dt, B[:, 0], C[:, 0], A_log, D)
+    on = np.flatnonzero(np.asarray(live))
+    assert bool(jnp.all(new[2][on] == new1[2][on]))
+    assert bool(jnp.all(y == y1))
+    assert bool(jnp.all(new[:2] == state[:2]))
 
 
 # ------------------------- (c) prefill, then decode, against the forward
