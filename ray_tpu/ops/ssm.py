@@ -33,16 +33,16 @@ afterwards.  Nothing the size of a layer's lanes is copied or selected
 over.
 
 `ssd_scan` (XLA einsums under `jax.named_scope("ssd_scan")`): the same
-recurrence over whole rows in the chunked ("SSD") form.  Inside a chunk
-of Q positions Y = (L o (C B^T)) (dt * X), L[i, j] = exp(sum_{j<k<=i}
-dt_k A) for i >= j, all matmuls; between chunks the state is carried by
-a `lax.scan`.  L comes from differences of one cumulative sum of dt * A
-in float32, masked BEFORE the exponential (never a quotient of
-exponentials).  The caller sets dt = 0 past a row's true length: the
-decay is then 1 and the input 0, so the state returned IS the state at
-the true length.  What feeds the state (the chunk's input to it and its
-decay) is computed in float32 at `Precision.HIGHEST`: a lane keeps that
-state for hundreds of steps.
+recurrence over whole rows, chunked ("SSD").  In a chunk of Q positions Y
+= (L o (C B^T)) (dt X), L[i, j] = exp(sum_{j<k<=i} dt_k A) for i >= j.
+That, each chunk's input to the state and what a carried state adds are
+matmuls BATCHED over the chunks, outside the loop; the `lax.scan` carries
+the state ALONE and stacks it a chunk's start, chunk major.  (A loop that
+stacked y wrote a chunk's 4 MB in 512-byte pieces, 140 us where the bytes
+take 5: the function says more.)  L comes from differences of one cumsum
+of dt A in float32, masked BEFORE the exponential.  The caller sets dt = 0
+past a row's true length, so the state returned IS the state there.  What
+feeds the state is float32 at `Precision.HIGHEST`: a lane keeps it long.
 """
 from __future__ import annotations
 
@@ -254,7 +254,26 @@ def ssd_scan(x, dt, A, B, C, chunk: int):
     h reads group h // (H / G)); `chunk` positions a chunk (a T under it
     is one short chunk; T is padded up to whole chunks with dt = 0).
     Returns (y [b, T, H, P] float32 without the D term, the state after
-    the last position [b, N, H * P] float32)."""
+    the last position [b, N, H * P] float32).
+
+    Mamba-2's four steps.  What happens inside a chunk, a chunk's input
+    to the state, and what a state carried into a chunk adds (C h decayed
+    from the chunk's start) depend on the chunk's own inputs and on the
+    state at its start alone, so they are computed for ALL chunks at
+    once, batched over (row, chunk, group).  The loop carries the state,
+    h' = decay * h + S_c (4 MB a row at the served widths), and emits it
+    at each chunk's START, stacked on the major-most axis: a chunk's slab
+    is contiguous.  A loop that emits y a chunk (the form to PR 48) has
+    its stacked output laid out by the product inside the loop, positions
+    minor and the chunk's index second-minor, and wrote each chunk's 4 MB
+    in 512-byte pieces: 140 us a chunk where the bytes take 5, 9.0 of the
+    scan's 10.65 ms a layer at 64 chunks (PERF.md section 6, PR 49).
+
+    The compiler takes the SMALL factor of a product for the matmul's
+    weights, so [Q, Q] times [Q, P] comes out [P, Q], positions minor,
+    whatever is written.  So x is transposed once a chunk going in, the
+    state is kept [G, K, P, N] as its product gives it, and y is
+    transposed once a chunk coming out: whole rows of [T, H * P]."""
     b, T, H, P = x.shape
     G, N = B.shape[-2:]
     K = H // G                                # heads a group
@@ -266,18 +285,12 @@ def ssd_scan(x, dt, A, B, C, chunk: int):
             for a in (x, dt, B, C))
     nc = (T + pad) // Q
     with jax.named_scope("ssd_scan"):
-        def chunks(a):                        # [b, T, ...] -> [nc, b, Q, ...]
-            return jnp.moveaxis(a.reshape(b, nc, Q, *a.shape[2:]), 1, 0)
-
-        def grouped(a):                 # [b, Q, H, ...] -> [b, Q, G, K, ...]
-            return a.reshape(b, Q, G, K, *a.shape[3:])
-
         def by_group(eq, lhs, rhs, **kw):
             """`einsum(eq)`, where `eq` names the group axis `g` in both
             operands and in the result.  At ONE group the axis is
-            dropped from all three: the one-group contraction itself,
-            bit for bit and program for program (as a batch axis of
-            length 1 the CPU backend rounds it otherwise)."""
+            dropped from all three: the one-group contraction itself
+            (as a batch axis of length 1 the CPU backend rounds it
+            otherwise)."""
             if G > 1:
                 return jnp.einsum(eq, lhs, rhs, preferred_element_type=F32,
                                   **kw)
@@ -287,40 +300,57 @@ def ssd_scan(x, dt, A, B, C, chunk: int):
                              preferred_element_type=F32, **kw)
             return jnp.expand_dims(out, at[2])
 
+        def rows(a):      # [b, nc, Q, H]: a head's values along its chunk
+            return jnp.moveaxis(a, 3, 2).reshape(b, nc, G, K, 1, Q)
+
+        # views, chunk by chunk: [b, nc, Q, ...]
+        xc, dtc, Bc, Cc = (a.reshape(b, nc, Q, *a.shape[2:])
+                           for a in (x, dt, B, C))
+        # (1) inside every chunk at once.  The running sum of dt * A of
+        # each chunk, [b, nc, Q, H], falling.  `cumsum`, not a product
+        # with a triangle of ones: on the chip its float32 error is 1e-5
+        # of a sum of -43, a product at the default precision rounds to
+        # bfloat16 (2e-2), and one at Precision.HIGHEST never came back
+        # in a replica's program (PERF.md section 6, PR 39)
+        cs = jnp.cumsum(dtc * A, axis=2)
+        csh = jnp.moveaxis(cs, 3, 2)                        # [b, nc, H, Q]
+        seg = csh[..., :, None] - csh[..., None, :]
         tri = jnp.tril(jnp.ones((Q, Q), bool))
+        Lm = jnp.exp(jnp.where(tri, seg, -jnp.inf))         # [b, nc, H, i, j]
+        CB = by_group("bcign,bcjgn->bcgij", Cc, Bc)
+        # x with a chunk's positions MINOR, [b, nc, G, K, P, j]: ONE
+        # transposition a chunk, and behind the barrier it moves x's own
+        # dtype, not a float32 copy
+        xt = jnp.swapaxes(xc.reshape(b, nc, Q, H * P), 2, 3)
+        xt = lax.optimization_barrier(xt.reshape(b, nc, G, K, P, Q))
+        xdt = xt.astype(F32) * rows(dtc)
+        y = by_group("bcgkij,bcgkpj->bcgkpi",
+                     (CB[:, :, :, None] * Lm.reshape(b, nc, G, K, Q, Q)
+                      ).astype(x.dtype), xdt.astype(x.dtype))
+        # (2) every chunk's input to the state, decayed to the chunk's
+        # end, and the chunk's own decay, chunk-major: the loop's operands.
+        # The state is [b, G, K, P, N] here, as the product gives it
+        to_end = jnp.exp(cs[:, :, -1:] - cs)                # [b, nc, Q, H]
+        S = by_group("bcjgn,bcgkpj->cbgkpn", Bc.astype(F32),
+                     xdt * rows(to_end), precision=_HI)
+        if nc > 1:
+            # (3) the loop carries the state alone and emits it at each
+            # chunk's START: whole slabs of a stack whose MAJOR axis is
+            # the chunk
+            def step(h, xs):
+                decay, Sc = xs
+                return decay.reshape(b, G, K, 1, 1) * h + Sc, h
 
-        def step(h, xs):
-            xc, dtc, Bc, Cc = xs        # [b,Q,H,P] [b,Q,H] [b,Q,G,N] x2
-            # the running sum of dt * A, [b, Q, H], falling.  `cumsum`, not
-            # a product with a triangle of ones: on the chip its float32
-            # error is 1e-5 of a sum of -43, a product at the default
-            # precision rounds to bfloat16 (2e-2), and one at
-            # Precision.HIGHEST never came back in a replica's program
-            # (PERF.md section 6, PR 39)
-            cs = jnp.cumsum(dtc * A, axis=1)
-            csh = jnp.moveaxis(cs, 2, 1)      # [b, H, Q]
-            seg = csh[:, :, :, None] - csh[:, :, None, :]
-            Lm = jnp.exp(jnp.where(tri, seg, -jnp.inf))     # [b, H, i, j]
-            CB = by_group("bign,bjgn->bgij", Cc, Bc)
-            xdt = xc.astype(F32) * dtc[..., None]           # [b, Q, H, P]
-            y = by_group("bgkij,bjgkp->bigkp",
-                         (CB[:, :, None] * Lm.reshape(b, G, K, Q, Q)
-                          ).astype(x.dtype),
-                         grouped(xdt.astype(x.dtype)))
-            # what the carried state adds: C_i h, decayed from the
+            h, starts = lax.scan(
+                step, jnp.zeros((b, G, K, P, N), F32),
+                (jnp.moveaxis(jnp.exp(cs[:, :, -1]), 1, 0), S))
+            # (4) what the carried state adds: C_i h, decayed from the
             # chunk's start to i
-            hh = h.reshape(b, N, G, K, P)
-            y += by_group("bign,bngkp->bigkp", Cc.astype(F32), hh) \
-                * grouped(jnp.exp(cs))[..., None]
-            # the state at the chunk's end
-            to_end = jnp.exp(cs[:, -1:, :] - cs)            # [b, Q, H]
-            hh = (jnp.exp(cs[:, -1]).reshape(b, 1, G, K, 1) * hh
-                  + by_group("bjgn,bjgkp->bngkp", Bc.astype(F32),
-                             grouped(xdt * to_end[..., None]),
-                             precision=_HI))
-            return hh.reshape(b, N, H * P), y.reshape(b, Q, H, P)
-
-        h, ys = lax.scan(step, jnp.zeros((b, N, H * P), F32),
-                         tuple(chunks(a) for a in (x, dt, B, C)))
-        y = jnp.moveaxis(ys, 0, 1).reshape(b, nc * Q, H, P)
-    return y[:, :T], h
+            y += by_group("bcign,cbgkpn->bcgkpi", Cc.astype(F32), starts) \
+                * rows(jnp.exp(cs))
+        else:                     # one chunk: a program without a loop
+            h = S[0]
+        # back to positions major, ONE transposition a chunk: whole rows
+        y = jnp.swapaxes(y.reshape(b, nc, H * P, Q), 2, 3)
+        h = jnp.moveaxis(h, 4, 1)                           # [b, N, G, K, P]
+    return y.reshape(b, nc * Q, H, P)[:, :T], h.reshape(b, N, H * P)

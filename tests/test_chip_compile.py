@@ -360,6 +360,66 @@ def weight_sized_writes(hlo: str, min_elems: int) -> list:
     return found
 
 
+def scan_loop_writes(hlo: str, min_elems: int) -> list:
+    """What the chunk loop of `ssd_scan` writes that has at least
+    `min_elems` elements, as (name, op, dimensions, minor-to-major
+    layout); a `dynamic-update-slice` is named so, fused or not."""
+    lines = {m.group(1): m.group(2) for ln in hlo.splitlines()
+             for m in [_INSTR.match(ln)] if m}
+    out = []
+    for name, op, scope in weight_sized_writes(hlo, min_elems):
+        if "ssd_scan/while" not in scope:
+            continue
+        dims, layout = max(_ARRAY.findall(lines[name]),
+                           key=lambda a: math.prod(map(int, a[0].split(","))))
+        if "dynamic_update_slice" in scope.rsplit("/", 1)[-1]:
+            op = "dynamic-update-slice"
+        out.append((name, op, [int(d) for d in dims.split(",")],
+                    [int(d) for d in layout.split(",")]))
+    return out
+
+
+def assert_scan_loop_stacks_whole_slabs(hlo: str, slab_elems: int,
+                                        row_elems: int, chunks: int):
+    """PR 49: the loop carries the state alone.  Of what it writes that is
+    at least one chunk's states (`slab_elems` = b x N x H x P), nothing
+    but an in-place `dynamic-update-slice` has a whole row's y of elements
+    (`row_elems` = b x T x H x P), and every such update stacks on its
+    MAJOR-most dimension, the chunk's index (`lax.scan` stacks on axis 0):
+    a chunk's slab is contiguous.  Until PR 48 the loop stacked y itself
+    with the chunk second-minor: 140 us a chunk where the bytes take 5."""
+    assert "ssd_scan/while" in hlo            # the scope is there to read
+    for name, op, dims, layout in scan_loop_writes(hlo, slab_elems):
+        if op == "dynamic-update-slice":
+            assert dims[0] == chunks and layout[-1] == 0, (name, dims, layout)
+        else:
+            assert math.prod(dims) < row_elems, (name, op, dims)
+
+
+def test_scan_loop_writes_reads_a_program_text():
+    """The parent's layout is found (f32[64,1,128,128,64]{2,0,1,4,3}: the
+    chunk second-minor) and a stack of whole slabs passes."""
+    def text(shape, layout):
+        return f"""HloModule m
+%body (p: (s32[], {shape})) -> (s32[], {shape}) {{
+  %p = (s32[], {shape}{{{layout}}}) parameter(0)
+  %dynamic_update_slice.15 = {shape}{{{layout}:T(8,128)}} dynamic-update-slice(%a, %b, %i), metadata={{op_name="jit(f)/ssd_scan/while/body/dynamic_update_slice"}}
+}}
+ENTRY %main () -> f32[] {{
+  %w = (s32[], {shape}{{{layout}}}) while(%t), condition=%cond, body=%body
+}}
+"""
+    bad = text("f32[64,1,128,128,64]", "2,0,1,4,3")
+    assert scan_loop_writes(bad, 8192 * 8192) == [
+        ("dynamic_update_slice.15", "dynamic-update-slice",
+         [64, 1, 128, 128, 64], [2, 0, 1, 4, 3])]
+    with pytest.raises(AssertionError):
+        assert_scan_loop_stacks_whole_slabs(bad, 128 * 8192, 8192 * 8192, 64)
+    assert_scan_loop_stacks_whole_slabs(
+        text("bf16[64,1,8,16,64,128]", "5,4,3,2,1,0"), 128 * 8192,
+        8192 * 8192, 64)
+
+
 def _pool_copies(hlo: str, min_elems: int) -> list:
     """Names of the `copy` instructions of at least `min_elems` elements
     anywhere outside a fusion: a layout change of something resident."""
@@ -848,12 +908,18 @@ def test_served_granite_engine_fits_one_chip(topo, one_chip, compiled_kernels,
         txt = low.as_text()
         for kern in kernels[name]:
             assert kern in txt, (name, kern)
-        mem = low.compile().memory_analysis()
+        c = low.compile()
+        mem = c.memory_analysis()
         held = resident + mem.temp_size_in_bytes + (
             mem.output_size_in_bytes - mem.alias_size_in_bytes)
         print(f"{name}: temps {mem.temp_size_in_bytes / 1e9:.2f} GB, "
               f"resident {resident / 1e9:.2f} GB, held {held / 1e9:.2f} GB")
         assert held < 16.9e9 - 1.5e9, (name, held)
+        if name == "prefill_w8_p1024":
+            # four chunks of 256 a row (0.67 GB of temporaries to PR 48)
+            assert_scan_loop_stacks_whole_slabs(
+                c.as_text(), 8 * cfg.ssm_state * cfg.inner,
+                8 * 1024 * cfg.inner, 4)
 
 
 def test_granite_decode_step_loop_copies_no_lane_state_and_no_weight(
@@ -970,9 +1036,14 @@ def test_served_nemotron_engine_fits_one_chip_and_copies_no_lane_state(
         print(f"{name}: temps {mem.temp_size_in_bytes / 1e9:.2f} GB, "
               f"resident {resident / 1e9:.2f} GB, held {held / 1e9:.2f} GB")
         assert held < 16.9e9 - 1.0e9, (name, held)
-        if name != "decode_k8":
-            continue
         hlo = c.as_text()
+        if name != "decode_k8":
+            # 64 chunks of 128 (2.42 GB of temporaries to PR 48, of which
+            # the stacked y and its re-layout)
+            assert_scan_loop_stacks_whole_slabs(
+                hlo, cfg.ssm_state * cfg.inner, 8192 * cfg.inner, 64)
+            assert mem.temp_size_in_bytes < 2.0e9
+            continue
         layer_state = 64 * 128 * 8192
         found = weight_sized_writes(hlo, layer_state)
         assert found and all(op == "custom-call" and "ssm_update" in scope

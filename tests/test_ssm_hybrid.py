@@ -97,10 +97,14 @@ def _recurrence(x, dt, A, B, C):
     (32, 8, [1, 31], 4, 2),
     (64, 16, [64, 17], 8, 8),   # a head a group
     (5, 8, [5, 3], 16, 8),
+    # the served SHAPE of the walk, 64 chunks a row (the 1 x 8192 program
+    # at a chunk of 128): one token, a length inside a chunk, the whole row
+    (512, 8, [1, 300, 512], 16, 8),
+    (512, 8, [1, 300, 512], 4, 1),
 ])
 def test_ssd_scan_equals_the_recurrence_at_the_true_length(T, chunk, lens,
                                                            H, G):
-    x, dt, A, B, C = _scan_inputs(2, T, H=H, lens=lens, G=G)
+    x, dt, A, B, C = _scan_inputs(len(lens), T, H=H, lens=lens, G=G)
     y, h = ssm.ssd_scan(x, dt, A, B, C, chunk)
     for row, n in enumerate(lens):
         cut = [a[row:row + 1, :n] for a in (x, dt)] + [A] \
@@ -114,7 +118,9 @@ def test_ssd_scan_equals_the_recurrence_at_the_true_length(T, chunk, lens,
 
 def _ssd_scan_one_group(x, dt, A, B, C, chunk: int):
     """`ssd_scan` as it stood while every head shared ONE B and C ([b, T,
-    N]; PR 39 to PR 47), kept here to hold the grouped form to its bits."""
+    N]; PR 39 to PR 47) and a `lax.scan` over the chunks did a chunk's
+    whole work and stacked its y (to PR 48), kept here to hold the grouped,
+    batched form to it."""
     F32, _HI = jnp.float32, jax.lax.Precision.HIGHEST
     b, T, H, P = x.shape
     N = B.shape[-1]
@@ -157,19 +163,44 @@ def _ssd_scan_one_group(x, dt, A, B, C, chunk: int):
     return y[:, :T], h
 
 
-@pytest.mark.parametrize("T,chunk,lens,dtype", [
-    (20, 8, [13, 20], jnp.float32), (32, 8, [1, 31], jnp.float32),
-    (5, 8, [5, 3], jnp.float32), (32, 8, [32, 9], jnp.bfloat16)])
+F32_SUM = 1e-6     # float32 sums in another order, of the largest value
+BF16_EPS = 2.0 ** -8    # one rounding of a bfloat16 operand
+
+
+@pytest.mark.parametrize("T,chunk,lens,dtype,y_tol,h_tol", [
+    (20, 8, [13, 20], jnp.float32, F32_SUM, F32_SUM),
+    (32, 8, [1, 31], jnp.float32, F32_SUM, F32_SUM),
+    # one chunk: no state comes in, so y is the product inside the chunk
+    # alone and keeps its bits; the state is the batched product's
+    (5, 8, [5, 3], jnp.float32, 0.0, F32_SUM),
+    (32, 8, [32, 9], jnp.bfloat16, BF16_EPS, BF16_EPS)])
 def test_ssd_scan_with_one_group_is_the_one_group_form_bit_for_bit(
-        T, chunk, lens, dtype):
+        T, chunk, lens, dtype, y_tol, h_tol):
     """Granite's call (G = 1, the debug preset's 4 heads of 16 over a
-    state of 16): the grouped scan gives the bits the one-group scan
-    gave."""
+    state of 16): the grouped scan, its products batched over the chunks
+    (PR 49), against the one-group scan that walked them in a loop.  The
+    same products on the same operands in the same precisions; a batched
+    product sums in another order on this backend, so a case is held to
+    `==` where the bits still agree and to a sum's rounding where not."""
     x, dt, A, B, C = _scan_inputs(2, T, lens=lens)
     x, B, C = (a.astype(dtype) for a in (x, B, C))
     y, h = ssm.ssd_scan(x, dt, A, B, C, chunk)
     y1, h1 = _ssd_scan_one_group(x, dt, A, B[:, :, 0], C[:, :, 0], chunk)
-    assert bool(jnp.all(y == y1)) and bool(jnp.all(h == h1))
+    for got, want, tol in ((y, y1, y_tol), (h, h1, h_tol)):
+        assert float(jnp.max(jnp.abs(got - want))) \
+            <= tol * float(jnp.max(jnp.abs(want)))
+
+
+@pytest.mark.parametrize("T,chunk,G,loops", [
+    (8, 8, 8, False), (5, 8, 8, False), (8, 8, 1, False), (16, 8, 8, True)])
+def test_ssd_scan_of_one_chunk_lowers_to_a_program_without_a_loop(
+        T, chunk, G, loops):
+    """Every served program of at most one chunk (granite's of <= 256
+    positions): nothing is carried, so nothing loops; two chunks do."""
+    x, dt, A, B, C = _scan_inputs(2, T, H=8, G=G)
+    text = jax.jit(ssm.ssd_scan, static_argnums=5).lower(
+        x, dt, A, B, C, chunk).as_text()
+    assert ("while" in text) == loops
 
 
 def test_ssd_scan_carries_the_state_between_chunks():
