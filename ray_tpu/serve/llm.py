@@ -42,8 +42,12 @@ from __future__ import annotations
 import collections
 import concurrent.futures
 import contextlib
+import gc
+import json
 import os
 import queue
+import resource
+import sys
 import threading
 import time
 from dataclasses import dataclass, field
@@ -151,17 +155,23 @@ _BUILDS = {"program_builds": 0,         # compile stages
 _BUILDS_LOCK = threading.Lock()
 _BUILD_OPEN = threading.local()     # .depth: stages open on this thread
 _build_listening = False            # under _BUILDS_LOCK
-_build_root = None                  # under _BUILDS_LOCK: see _build_ctx
+_build_root = None                  # under _BUILDS_LOCK: see _programs_root
+# (t0, t1) of the newest outermost stages, and the start of each thread's
+# outermost stage still open: a stall's `build_ms`
+_BUILD_RECENT: collections.deque = collections.deque(maxlen=1024)
+_BUILD_OPEN_AT: dict = {}
 # a stage that runs INSIDE another (a jitted helper traced while its
 # caller is) and ends under this gets no span: one program's build must
 # not flood the ring.  Its seconds are its caller's already.
 _BUILD_SPAN_MIN_S = 1e-3
 
 
-def _on_build_stage_start(event: str, _start: float, **_kw) -> None:
+def _on_build_stage_start(event: str, start: float, **_kw) -> None:
     if event in _BUILD_STAGES:
         _BUILD_OPEN.depth = getattr(_BUILD_OPEN, "depth", 0) + 1
         _BUILD_OPEN.cache = "off"
+        if _BUILD_OPEN.depth == 1:
+            _BUILD_OPEN_AT[threading.get_ident()] = start
 
 
 def _on_cache_event(event: str, **_kw) -> None:
@@ -185,6 +195,8 @@ def _on_build_stage_end(event: str, t0: float, t1: float,
     with _BUILDS_LOCK:
         if depth == 0:      # nested stages' seconds lie inside this one's
             _BUILDS["program_build_s"] += t1 - t0
+            _BUILD_RECENT.append((t0, t1))
+            _BUILD_OPEN_AT.pop(threading.get_ident(), None)
         if stage == "compile":
             attrs["cache"] = getattr(_BUILD_OPEN, "cache", "off")
             _BUILDS["program_builds"] += 1
@@ -201,10 +213,13 @@ def _build_ctx() -> tuple | None:
     root (as the loop's phases hang off `llm.engine`).  Never a trace of
     its own: `tracing.slowest` and `attribution` would rank every stage
     of every build as a request."""
+    return tracing.current() or _programs_root()
+
+
+def _programs_root() -> tuple | None:
+    """This process's one zero-length `llm.programs` span: what the
+    builds outside any request and the collector's pauses hang off."""
     global _build_root
-    ctx = tracing.current()
-    if ctx is not None:
-        return ctx
     with _BUILDS_LOCK:
         if _build_root is None:
             now = time.time()
@@ -229,15 +244,71 @@ def _listen_for_program_builds() -> None:
         monitoring.register_event_time_span_listener(_on_build_stage_end)
 
 
+# The cyclic collector's pauses, PROCESS-wide like the builds: whichever
+# thread starts a collection holds the interpreter for its whole length,
+# and the engine thread then stands with no CPU of its own.  No lock:
+# collections do not nest (the interpreter runs one at a time, its
+# callbacks inside it), and one that starts on a thread inside
+# `with _BUILDS_LOCK` must not wait for that lock.
+_GC = {"gc_pauses": 0, "gc_pause_s": 0.0}
+_GC_BY_GENERATION = {g: [0, 0.0] for g in range(3)}    # [pauses, seconds]
+# (t0, t1) of the newest pauses: a stall's `gc_ms`
+_GC_RECENT: collections.deque = collections.deque(maxlen=1024)
+_GC_SPAN_MIN_S = 1e-3       # a shorter pause is counted and has no span
+_gc_open = None             # (wall, perf_counter) at a collection's start
+_gc_listening = False       # under _BUILDS_LOCK
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    global _gc_open
+    if phase == "start":
+        _gc_open = (time.time(), time.perf_counter())
+        return
+    if _gc_open is None:
+        return
+    (w0, p0), _gc_open = _gc_open, None
+    dur = time.perf_counter() - p0
+    _GC["gc_pauses"] += 1
+    _GC["gc_pause_s"] += dur
+    row = _GC_BY_GENERATION[info["generation"]]
+    row[0], row[1] = row[0] + 1, row[1] + dur
+    _GC_RECENT.append((w0, w0 + dur))
+    root = _build_root      # read, never made here: see the lock above
+    if dur >= _GC_SPAN_MIN_S and tracing.ENABLED and root is not None:
+        tracing.emit("llm.gc_pause", w0, w0 + dur, ctx=root, attrs={
+            "generation": info["generation"],
+            "collected": info["collected"],
+            "uncollectable": info["uncollectable"],
+            "thread": _thread_row(threading.current_thread().name)})
+
+
+def _listen_for_gc_pauses() -> None:
+    """Register the collector's callback, once a process, beside the
+    program-build listeners, and make the root its spans hang off (every
+    call: the recorder may have been off at the first).  Nothing here
+    changes what the collector does."""
+    global _gc_listening
+    _programs_root()
+    with _BUILDS_LOCK:
+        if _gc_listening:
+            return
+        _gc_listening = True
+        gc.callbacks.append(_on_gc)
+
+
 # The ledger's rows.  A thread is filed under the first of these its
 # name starts with (a pool's threads are "<prefix>_<n>", an actor's
 # executors "actor-<id>[-<group>]_<n>") and any other thread under
 # "other": the rows, and with them the labels of
 # serve_llm_thread_cpu_seconds, are a fixed set however many
 # connections, actors and default-named threads come and go.
-_THREAD_ROWS = ("llm-engine", "llm-kv-export", "serve-call", "actor",
-                "task-exec", "raytpu-io", "raytpu-putcopy", "asyncio",
-                "MainThread")
+_THREAD_ROWS = ("llm-engine", "llm-kv-export", "llm-stall-watch",
+                "serve-call", "actor", "task-exec", "raytpu-io",
+                "raytpu-putcopy", "asyncio", "MainThread")
+
+
+def _thread_row(name: str) -> str:
+    return next((r for r in _THREAD_ROWS if name.startswith(r)), "other")
 
 
 def _thread_cpu_ledger() -> dict:
@@ -259,11 +330,90 @@ def _thread_cpu_ledger() -> dict:
             cpu = time.clock_gettime((~t.native_id << 3) | 6)
         except OSError:     # ended since enumerate()
             continue
-        row = next((r for r in _THREAD_ROWS if t.name.startswith(r)),
-                   "other")
+        row = _thread_row(t.name)
         by[row] = by.get(row, 0.0) + cpu
     return {"wall_s": time.time(), "process_cpu_s": time.process_time(),
             "by_name": by}
+
+
+# A stall of the engine loop (the watcher thread `llm-stall-watch`, one
+# an engine: LLMEngine._watch).  Constants, not options.
+WATCH_S = 0.05          # the watcher's sleep; how LATE it wakes is the
+#                         measurement of an interpreter or a process that
+#                         did not run
+WATCH_LEDGER_EVERY = 4  # wakes between two readings of the thread ledger
+#                         and the process's CPU (`_watch_reading`: a
+#                         system call a thread); the others read
+#                         `perf_counter` alone
+HOST_STALL_S = 0.2      # a host phase open this long (ISSUE 50 asked 0.1:
+#                         one generation-1 collection a run takes
+#                         0.10-0.11 s, in `deliver` when the engine thread
+#                         trips it; the longest sound host phase is
+#                         `decode_dispatch` at 32.5 ms: PERF.md section 6)
+LATE_WAKE_S = 0.4       # the watcher woken this late: twice the latest
+#                         wake of a sound run (0.10-0.19 s while the
+#                         engine thread loads a warmed program and keeps
+#                         the interpreter: PERF.md section 6)
+SYNC_STALL_S = 1.0      # a `_sync` phase open this long
+SYNC_STALL_X = 3.0      # and this many times the longest seen close on time
+# `prefill_dispatch` is in neither list: its last line
+# (`_cur_dev.at[slots].set(nxt)`) waits for the wave's programs on the
+# device (0.15-0.39 s a wave of the dense model, 1.2 s the ramp's first
+# wave of granite, seconds a wave of 1 x 8192 programs: PERF.md section
+# 6, PR 50), and the longest wave of a run is the ramp's first, so no
+# constant and no multiple of what warm-up showed clears it: only a late
+# wake of the watcher opens a stall there
+_HOST_PHASES = ("admit", "fund", "decode_dispatch", "deliver")
+_SYNC_PHASES = ("prefill_sync", "decode_sync")
+# every thread's stack goes to stderr for the first stalls of a process
+# with work waiting that no program build explains; then the span and
+# the counters only
+_STALL_BLOCKS = 16
+_stall_blocks = 0
+
+
+def _watch_reading() -> dict:
+    """The watcher's full reading, every WATCH_LEDGER_EVERY wakes and at
+    a stall's close: the thread ledger, and the process's page faults and
+    context switches (`resource.getrusage`), which tell a page-fault
+    storm from a process the machine did not run when a stall's `held`
+    is `process`."""
+    out = _thread_cpu_ledger()
+    out["ru"] = resource.getrusage(resource.RUSAGE_SELF)
+    return out
+
+
+def _overlap_s(intervals, t0: float, t1: float) -> float:
+    """Seconds of [t0, t1] the (start, end) pairs cover, summed."""
+    return sum(max(0.0, min(t1, b) - max(t0, a)) for a, b in list(intervals))
+
+
+def _frame_lines(frame, limit: int | None = None) -> list[str]:
+    """`dir/file.py:line func` of a thread's frames, innermost first."""
+    out = []
+    while frame is not None and (limit is None or len(out) < limit):
+        code = frame.f_code
+        out.append("%s:%d %s" % ("/".join(code.co_filename.split("/")[-2:]),
+                                 frame.f_lineno, code.co_name))
+        frame = frame.f_back
+    return out
+
+
+def _stall_held(trigger: str, stood_ms: float, late_wake_ms: float,
+                process_cpu_ms: float) -> str:
+    """Who held the engine thread, from the stall's own numbers and
+    nothing else.  The watcher on time: only the engine thread waited,
+    for the `device` inside a `_sync` phase, else behind a lock, a queue
+    or a blocking call of its own (`engine`: its stack names the line).
+    The watcher late (by more than HOST_STALL_S, the shortest stall of a
+    host phase): nothing of the interpreter ran; if the process
+    burned CPU meanwhile some thread or the collector held the
+    `interpreter`, and under a tenth of the wall nobody ran at all
+    (`process`: the machine, a page-fault storm, a runtime call that
+    sleeps holding the interpreter)."""
+    if late_wake_ms <= HOST_STALL_S * 1e3:
+        return "device" if trigger == "sync" else "engine"
+    return "process" if process_cpu_ms < 0.1 * stood_ms else "interpreter"
 
 
 # Latency-histogram bucket upper bounds in ms: sub-ms router picks
@@ -367,6 +517,21 @@ def _engine_metrics(work=_LOOP_WORK):
                     um.Counter, "serve_llm_program_build_seconds",
                     "Seconds the process spent tracing, lowering and "
                     "compiling (or loading) programs", tk),
+                "stalls": um.get_or_create(
+                    um.Counter, "serve_llm_stalls",
+                    "Stalls of the engine loop with work waiting: a host "
+                    "phase open over 0.2 s, the watcher thread woken over "
+                    "0.4 s late, or a _sync phase open over 1 s and three "
+                    "times its longest (the llm.stall span names the "
+                    "phase and who held it)", tk),
+                "stall_s": um.get_or_create(
+                    um.Counter, "serve_llm_stall_seconds",
+                    "Seconds the engine loop stood in those stalls", tk),
+                "gc_pause_s": um.get_or_create(
+                    um.Counter, "serve_llm_gc_pause_seconds",
+                    "Seconds the process's cyclic garbage collector held "
+                    "the interpreter, by generation",
+                    ("engine", "generation")),
                 "preemptions": um.get_or_create(
                     um.Counter, "serve_llm_preemptions",
                     "Requests preempted for KV blocks", tk),
@@ -524,6 +689,7 @@ class LLMEngine:
 
         _check_paged(paged)
         _listen_for_program_builds()    # before the engine's own programs
+        _listen_for_gc_pauses()
         model = self._model = serving_model(cfg)
         # The model's ONE declaration (models/serving.ServingSpec), read
         # here and nowhere else.  Per-lane state beside the page pool (a
@@ -942,6 +1108,16 @@ class LLMEngine:
         self.work = dict.fromkeys((*_LOOP_WORK, *spec.counters), 0)
         self.phase_s = dict.fromkeys(_LOOP_PHASES, 0.0)
         self.phase_cpu_s = dict.fromkeys(_LOOP_PHASES, 0.0)
+        # The phase the loop is in, published for the stall watcher:
+        # (key, perf_counter at entry, wall at entry, iter), None between
+        # phases.  Stored at entry and cleared at exit, no lock.
+        self._phase_now: tuple | None = None
+        self._watch_thread: threading.Thread | None = None
+        self._watch_stop = threading.Event()
+        # stalls with work waiting (one in `idle` is a span and no count)
+        # and their seconds; bumped on the watcher thread
+        self.stalls = 0
+        self.stall_s = 0.0
         # (device array, rows routed, rows of its shape) a prefill
         # program with routed layers: fetched with the wave's first tokens
         self._prefill_counts: list = []
@@ -1652,7 +1828,9 @@ class LLMEngine:
         span `llm.loop.<key>`, carrying `cpu_ms`, when tracing is on;
         (3) runs under a
         `jax.profiler.TraceAnnotation`, so it is a host event, on the
-        profiler's clock, in any profiler trace that is running.  `with`
+        profiler's clock, in any profiler trace that is running; (4) is
+        published in `_phase_now` while it is open, for the stall watcher
+        (`_watch`).  `with`
         yields the span's attrs: the block adds what it learns (the
         annotation carries the attrs known at entry)."""
         from jax.profiler import TraceAnnotation
@@ -1661,9 +1839,11 @@ class LLMEngine:
             w0 = time.time() if tracing.ENABLED else 0.0
             t0 = time.perf_counter()
             c0 = time.thread_time()
+            self._phase_now = (key, t0, w0, attrs.get("iter"))
             try:
                 yield attrs
             finally:
+                self._phase_now = None
                 cpu = time.thread_time() - c0
                 self.phase_s[key] += time.perf_counter() - t0
                 self.phase_cpu_s[key] += cpu
@@ -1682,6 +1862,203 @@ class LLMEngine:
         for name, n in (shown or {}).items():
             ph[name] = ph.get(name, 0) + n
 
+    # ------------------------------------------------ the stall watcher
+    def _watch(self) -> None:
+        """The thread `llm-stall-watch`, alive from `start()` to `stop()`:
+        every WATCH_S it reads the phase the loop published and notes how
+        LATE it woke, by `perf_counter` alone; every WATCH_LEDGER_EVERY
+        wakes it reads the CPU clocks too (`_watch_reading`).
+        It opens a stall when (a) a host phase (`_HOST_PHASES`) has been
+        open longer than HOST_STALL_S, (b) it woke itself more than
+        LATE_WAKE_S late, whatever the phase: the interpreter was held
+        or the process did not run, which is what shows inside a `_sync`
+        phase, or (c) a `_sync` phase (`_SYNC_PHASES`) has been open
+        longer than SYNC_STALL_S and SYNC_STALL_X times the longest
+        instance of it the watcher saw close with itself on time (warm-up
+        included; the instance that trips this raises the longest too, or
+        a model whose sound prefill passes SYNC_STALL_S would report
+        every wave).  One stall at a time; it ends when its phase closes,
+        or for (b) when the watcher is on time again and no host phase it
+        caught is still open.  See `_stall_open` and `_stall_close` for
+        the record."""
+        longest = dict.fromkeys(_SYNC_PHASES, 0.0)
+        # the last reading of the CPU clocks, and the last before `seen`
+        # was entered
+        full = before = _watch_reading()
+        seen = last = tainted = stall = None
+        sync_age = 0.0      # how long `seen`, a _sync phase, was seen open
+        wakes = 0
+        due = time.perf_counter() + WATCH_S
+        while not self._watch_stop.wait(max(0.0, due - time.perf_counter())):
+            now = time.perf_counter()
+            late, due = now - due, now + WATCH_S
+            cur = self._phase_now
+            if cur is not seen:
+                # entered since the last wake, whose readings precede it
+                if (seen is not None and seen is not tainted
+                        and seen[0] in longest):
+                    longest[seen[0]] = max(longest[seen[0]], sync_age)
+                seen, before, sync_age = cur, full, 0.0
+            key, age = (cur[0], now - cur[1]) if cur else (None, 0.0)
+            if key in longest:
+                sync_age = age
+            if late > LATE_WAKE_S:
+                tainted = cur
+            if stall is not None:
+                stall["late_s"] = max(stall["late_s"], late)
+                if late > LATE_WAKE_S:
+                    stall["late_t1"] = time.time()
+            elif late > LATE_WAKE_S:
+                stall = self._stall_open("late_wake", cur, last, late, full)
+            elif key in _HOST_PHASES and age > HOST_STALL_S:
+                stall = self._stall_open("host_phase", cur, last, late,
+                                         before)
+            elif key in longest and age > max(
+                    SYNC_STALL_S, SYNC_STALL_X * longest[key]):
+                stall = self._stall_open("sync", cur, last, late, before)
+            if stall is not None:
+                t1 = self._stall_end(stall, cur, late, now)
+                if t1 is not None:
+                    full = _watch_reading()     # the first after `t1`
+                    self._stall_close(stall, t1, full)
+                    stall, wakes = None, 0
+            elif wakes % WATCH_LEDGER_EVERY == 0:
+                full = _watch_reading()
+            wakes += 1
+            last = cur or last
+        if stall is not None:       # stopped inside one: it ends here
+            self._stall_close(stall, time.time(), _watch_reading())
+
+    def _stall_open(self, trigger: str, rec: tuple | None,
+                    last: tuple | None, late: float, r0: dict) -> dict:
+        """What is taken ONCE a stall, at its trigger: every thread's
+        stack, the device allocator's numbers, the queue and the lanes.
+        `rec` is the phase record the stall waits for (None: a late wake
+        between phases, filed under `last`, the phase seen before it),
+        `r0` the last reading of the CPU clocks (`_watch_reading`) before
+        the stall began: the phase's entry, or for a late wake the time
+        the missed wake was due."""
+        now_wall, now = time.time(), time.perf_counter()
+        shown = rec or last
+        # a phase's wall at entry is the recorder's when it is on
+        entered = (rec[2] or now_wall - (now - rec[1])) if rec else None
+        frames = sys._current_frames()
+        names = {t.ident: t.name for t in threading.enumerate()}
+        eng = self._thread
+        try:
+            import jax
+
+            mem = jax.local_devices()[0].memory_stats() or {}
+        except Exception:  # noqa: BLE001 - a backend without the call
+            mem = {}
+        t0 = now_wall - late if trigger == "late_wake" else entered
+        return {
+            "trigger": trigger, "rec": rec, "t0": t0, "r0": r0,
+            "entered": entered,
+            "phase": shown[0] if shown else "none",
+            "iter": shown[3] if shown else None,
+            "late_s": late, "late_t1": now_wall,
+            "age": now - rec[1] if rec else 0.0,
+            "phase_s0": self.phase_s[rec[0]] if rec else 0.0,
+            "taken_ms": (now_wall - t0) * 1e3,
+            "engine_frames": _frame_lines(
+                frames.get(eng.ident if eng else None), 8),
+            # for the block on stderr, which a stall in `idle` has none of
+            "stacks": [(names.get(i, "?"), _frame_lines(f))
+                       for i, f in frames.items()
+                       if _stall_blocks < _STALL_BLOCKS
+                       and shown and shown[0] != "idle"],
+            "pending": len(self._pending),
+            "lanes": sum(s is not None for s in self._slots),
+            "mem": {k: mem.get(k) for k in (
+                "bytes_in_use", "peak_bytes_in_use",
+                "largest_free_block_bytes")}}
+
+    def _stall_end(self, stall: dict, cur: tuple | None, late: float,
+                   now: float) -> float | None:
+        """The wall time the stall ended at, None while it lasts.  A
+        phase's end is its entry plus its length, which is the smaller of
+        what the watcher saw and what `phase_s` gained since the trigger
+        (later instances of the phase are in that too)."""
+        rec = stall["rec"]
+
+        def phase_end():
+            return stall["entered"] + max(stall["age"], min(
+                now - rec[1], self.phase_s[rec[0]] - stall["phase_s0"]))
+
+        if stall["trigger"] != "late_wake":
+            return None if cur is rec else phase_end()
+        if late > LATE_WAKE_S:
+            return None
+        if rec is None or rec[0] not in _HOST_PHASES:
+            return stall["late_t1"]
+        return None if cur is rec else max(stall["late_t1"], phase_end())
+
+    def _stall_close(self, stall: dict, t1: float, r1: dict) -> None:
+        """ONE span `llm.stall` on the engine's own trace, the counters
+        (none for a stall in `idle`: no work waited), and every thread's
+        stack on stderr under the span's id.  `r1` is the first reading
+        of the CPU clocks after `t1`: every CPU number and the faults are
+        gains over `ledger_ms`, which begins up to WATCH_LEDGER_EVERY
+        wakes before the stall, so `held` reads `process` only where even
+        that longer stretch's CPU is under a tenth of the stall."""
+        global _stall_blocks
+        t0, r0 = stall["t0"], stall["r0"]
+        stood_ms = max(0.0, t1 - t0) * 1e3
+        gain = {n: (c - r0["by_name"].get(n, 0.0)) * 1e3
+                for n, c in r1["by_name"].items()}
+        process_ms = (r1["process_cpu_s"] - r0["process_cpu_s"]) * 1e3
+        late_ms = stall["late_s"] * 1e3
+        build_ms = _overlap_s([*_BUILD_RECENT, *(
+            (at, t1) for at in list(_BUILD_OPEN_AT.values()))], t0, t1) * 1e3
+        held = _stall_held(stall["trigger"], stood_ms, late_ms, process_ms)
+        rivals = sorted(((n, round(v, 3)) for n, v in gain.items()
+                         if n != "llm-engine"), key=lambda kv: -kv[1])[:5]
+        counted = stall["phase"] != "idle"
+        if counted:
+            self.stalls += 1
+            self.stall_s += stood_ms / 1e3
+        attrs = {
+            "phase": stall["phase"], "trigger": stall["trigger"],
+            "held": held,
+            # which of `loop.stalls` this is (0: in `idle`, not counted):
+            # the watcher counts a stall at its first wake after it
+            # ended, which may be after a reading of `stats` it preceded
+            "nth": self.stalls if counted else 0,
+            "stood_ms": round(stood_ms, 3),
+            "engine_cpu_ms": round(gain.get("llm-engine", 0.0), 3),
+            "late_wake_ms": round(late_ms, 3),
+            "process_cpu_ms": round(process_ms, 3),
+            "by_thread_cpu_ms": json.dumps(rivals),
+            "native_cpu_ms": round(process_ms - sum(gain.values()), 3),
+            "ledger_ms": round((r1["wall_s"] - r0["wall_s"]) * 1e3, 3),
+            "gc_ms": round(_overlap_s(_GC_RECENT, t0, t1) * 1e3, 3),
+            "build_ms": round(build_ms, 3),
+            "engine_frames": " | ".join(stall["engine_frames"]),
+            "faults_major": r1["ru"].ru_majflt - r0["ru"].ru_majflt,
+            "faults_minor": r1["ru"].ru_minflt - r0["ru"].ru_minflt,
+            "switches_involuntary": r1["ru"].ru_nivcsw - r0["ru"].ru_nivcsw,
+            "mem_in_use": stall["mem"]["bytes_in_use"],
+            "mem_largest_free": stall["mem"]["largest_free_block_bytes"],
+            "pending": stall["pending"], "lanes": stall["lanes"]}
+        if stall["iter"] is not None:       # the `idle` phase has none
+            attrs["iter"] = stall["iter"]
+        ctx = (tracing.emit("llm.stall", t0, t1, ctx=self._loop_ctx(),
+                            attrs=attrs) if tracing.ENABLED else None)
+        # the stacks are kept for stalls with work waiting that no build
+        # explains (a build names itself)
+        if build_ms >= 0.5 * stood_ms or not stall["stacks"]:
+            return
+        _stall_blocks += 1
+        head = {k: v for k, v in attrs.items() if k != "engine_frames"}
+        lines = ["llm.stall %s engine=%s %s mem_peak=%s stacks_taken_ms=%.1f"
+                 % (ctx[1] if ctx else "-", self.name, json.dumps(head),
+                    stall["mem"]["peak_bytes_in_use"], stall["taken_ms"])]
+        for name, frames in stall["stacks"]:
+            lines.append("  thread %s [%s]" % (name, _thread_row(name)))
+            lines += ["    " + ln for ln in frames]
+        print("\n".join(lines), file=sys.stderr, flush=True)
+
     def start(self) -> None:
         if self._thread is None or not self._thread.is_alive():
             self._stop.clear()
@@ -1689,6 +2066,12 @@ class LLMEngine:
             self._thread = threading.Thread(
                 target=self._loop, name="llm-engine", daemon=True)
             self._thread.start()
+            if self._watch_thread is None \
+                    or not self._watch_thread.is_alive():
+                self._watch_stop.clear()
+                self._watch_thread = threading.Thread(
+                    target=self._watch, name="llm-stall-watch", daemon=True)
+                self._watch_thread.start()
             self._register_memledger_provider()
 
     def _register_memledger_provider(self) -> None:
@@ -1742,6 +2125,10 @@ class LLMEngine:
         self._wake.set()
         if self._thread is not None:
             self._thread.join(timeout=10.0)
+        self._watch_stop.set()
+        if self._watch_thread is not None:
+            self._watch_thread.join(timeout=10.0)
+            self._watch_thread = None
         if self._export_thread is not None:
             # Sentinel AFTER the loop stopped: pending exports drain in
             # order, then the thread exits.
@@ -2865,9 +3252,12 @@ class LLMEngine:
         with _BUILDS_LOCK:
             cur["program_builds"] = _BUILDS["program_builds"]
             cur["program_build_s"] = _BUILDS["program_build_s"]
+        cur["stalls"], cur["stall_s"] = self.stalls, self.stall_s
         # (counter, tag it is split by, its rows)
         split = [("phase_cpu_s", "phase", dict(self.phase_cpu_s)),
-                 ("thread_cpu_s", "thread", threads or {})]
+                 ("thread_cpu_s", "thread", threads or {}),
+                 ("gc_pause_s", "generation",
+                  {str(g): row[1] for g, row in _GC_BY_GENERATION.items()})]
         with self._metrics_lock:
             self._metrics_t = now
             for key, val in cur.items():
@@ -2997,6 +3387,16 @@ class LLMEngine:
             # was made; the ledger of threads is the process's too
             out["loop"].update(
                 _BUILDS, program_build_s=round(_BUILDS["program_build_s"], 6))
+        # stalls of this engine's loop with work waiting (`llm.stall`
+        # spans say which phase stood and who held it), and the
+        # PROCESS's collector pauses (`llm.gc_pause` from 1 ms up)
+        out["loop"].update(
+            stalls=self.stalls, stall_s=round(self.stall_s, 6),
+            gc_pauses=_GC["gc_pauses"],
+            gc_pause_s=round(_GC["gc_pause_s"], 6),
+            gc_by_generation={
+                str(g): {"pauses": n, "pause_s": round(sec, 6)}
+                for g, (n, sec) in _GC_BY_GENERATION.items()})
         out["threads"] = _thread_cpu_ledger()
         if self._spec.lane_state_layers:
             out["lane_state"] = dict(self._lane_info)

@@ -203,7 +203,7 @@ def test_prefill_counters_by_hand(small):
 
     def delta(a, b):
         return {k: b["loop"][k] - a["loop"][k] for k in b["loop"]
-                if k not in ("phase_s", "phase_cpu_s")}
+                if not isinstance(b["loop"][k], dict)}     # the splits
 
     # three equal rows: 4 x 128 = 512 positions, under three 1 x 128 at
     # the floor each (768): one program, as arrival order gave
@@ -638,6 +638,7 @@ def test_the_ledger_sums_a_pool_under_its_prefix(small):
     ("actor-0000000375f5-io_1", "actor"),       # and its named group's
     (None, "other"),            # "Thread-<n> (serve)": a connection's
     ("kv-store", "other"),
+    ("llm-stall-watch", "llm-stall-watch"),     # PR 50: the stall watcher
 ])
 def test_the_ledger_rows_are_a_fixed_set(name, row):
     """However threads are named and however many come and go, the
@@ -848,6 +849,389 @@ def test_operator_metrics(small):
     assert 0 < sum(by_phase.values()) <= sum(eng.phase_cpu_s.values()) + 1e-6
     assert rows("thread_cpu_s", "thread")["llm-engine"] > 0
     assert value("program_builds") >= 1 and value("program_build_s") > 0
+    # PR 50: the loop's stalls (the warm-up's cold builds are stalls) and
+    # the collector's pauses by generation
+    assert [m[k].name for k in ("stalls", "stall_s", "gc_pause_s")] == [
+        "serve_llm_stalls", "serve_llm_stall_seconds",
+        "serve_llm_gc_pause_seconds"]
+    loop = eng.stats()["loop"]
+    if loop["stalls"]:
+        assert value("stalls") == loop["stalls"]
+        assert value("stall_s") == pytest.approx(loop["stall_s"], abs=1e-5)
+    by_gen = rows("gc_pause_s", "generation")
+    assert by_gen and set(by_gen) <= {"0", "1", "2"}
+    assert sum(by_gen.values()) <= loop["gc_pause_s"] + 1e-5
+
+
+# ------------------------- PR 50: a stall of the loop leaves a record of
+# ------------------------- itself, and the collector's pauses are spans
+def _spans_named(name, since=0.0):
+    from ray_tpu import tracing
+
+    return sorted((r for r in tracing.snapshot()
+                   if r["name"] == name and r["t0"] >= since),
+                  key=lambda r: r["t0"])
+
+
+def _decoding(eng, windows):
+    """A request of `windows` decode windows, submitted; returns its
+    future once the engine has run three of them."""
+    steps0 = eng.work["decode_steps"]
+    fut = eng.submit(_prompt(40, 5), max_new_tokens=4 * windows + 1,
+                     _cache_ok=False)
+    deadline = time.time() + 60.0
+    while eng.work["decode_steps"] < steps0 + 12 and time.time() < deadline:
+        time.sleep(0.002)
+    return fut
+
+
+def _hold_the_interpreter(seconds):
+    """(function, its argument): keep the interpreter for `seconds` on
+    the wall clock, as one long C call that never lets go would (a call
+    sized by a count of work holds it half as long on a box that starved
+    the sizing): no waiter's timer asks for a handoff meanwhile."""
+    import sys
+
+    def hold(seconds):
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(60.0)
+        try:
+            end = time.perf_counter() + seconds
+            while time.perf_counter() < end:
+                pass
+        finally:
+            sys.setswitchinterval(interval)
+
+    return hold, seconds
+
+
+def _rival(fn, arg):
+    """Run `fn(arg)` on a thread named as a caller's is; (start, end) on
+    the wall clock.  The thread lives on a little, as a pool's does: the
+    ledger holds no thread that has ended."""
+    import threading
+
+    at = []
+
+    def run():
+        at.append(time.time())
+        fn(arg)
+        at.append(time.time())
+        time.sleep(0.2)
+
+    t = threading.Thread(target=run, name="serve-call_0", daemon=True)
+    t.start()
+    t.join(timeout=120.0)
+    assert not t.is_alive()
+    return at
+
+
+class _SleepsOnce:
+    """A request's token sink whose sixth `put` sleeps: a callback that
+    blocks the engine thread inside `deliver`."""
+
+    def __init__(self, seconds):
+        self.seconds, self.n, self.at = seconds, 0, None
+
+    def put(self, tok):
+        self.n += 1
+        if self.n == 6:
+            t0 = time.time()
+            time.sleep(self.seconds)
+            self.at = (t0, time.time())
+
+
+def test_a_callback_that_sleeps_in_deliver_is_one_stall_held_by_the_engine(
+        small, capfd, monkeypatch):
+    import json
+
+    from ray_tpu import tracing
+    from ray_tpu.serve import llm
+
+    monkeypatch.setattr(llm, "_stall_blocks", 0)
+    eng = _engine(small)
+    eng.start()
+    try:
+        _one_wave(eng, [_prompt(40)], 9)        # warm
+        time.sleep(0.1)
+        tracing.clear()
+        capfd.readouterr()
+        s0, mark = eng.stats()["loop"], time.time()
+        sink = _SleepsOnce(0.4)
+        eng.submit(_prompt(40, 3), max_new_tokens=41, _cache_ok=False,
+                   token_queue=sink).result(timeout=120.0)
+        time.sleep(0.15)
+        s1 = eng.stats()["loop"]
+    finally:
+        eng.stop()
+    stalls = _spans_named("llm.stall", mark)
+    # a busy box may add a late wake of its own: the one under test is
+    # the one over the sleep
+    (st,) = [s for s in stalls if s["t0"] < sink.at[1] and s["t1"] > sink.at[0]]
+    a = st["attrs"]
+    assert (a["phase"], a["trigger"], a["held"]) == (
+        "deliver", "host_phase", "engine")
+    assert st["tid"], st["par"] == eng._loop_trace
+    assert 300.0 <= a["stood_ms"] <= 520.0
+    assert a["stood_ms"] == pytest.approx((st["t1"] - st["t0"]) * 1e3, abs=1.0)
+    # inside the provoked interval to 60 ms
+    assert -0.06 <= st["t0"] - sink.at[0] <= 0.0
+    assert abs(st["t1"] - sink.at[1]) <= 0.06
+    # the thread slept: no CPU of its own, the watcher on time
+    assert a["engine_cpu_ms"] < 100.0 and a["late_wake_ms"] < 200.0
+    assert a["gc_ms"] < 100.0 and a["build_ms"] == 0.0
+    assert (a["lanes"], a["pending"]) == (1, 0)
+    assert min(a["faults_major"], a["faults_minor"],
+               a["switches_involuntary"]) >= 0
+    frames = a["engine_frames"].split(" | ")
+    assert len(frames) <= 8 and "test_engine_timeline.py" in frames[0]
+    assert frames[0].endswith(" put") and "_loop_once" in a["engine_frames"]
+    assert isinstance(json.loads(a["by_thread_cpu_ms"]), list)
+    counted = [s for s in stalls if s["attrs"]["phase"] != "idle"]
+    # a span says which of `loop.stalls` it is
+    assert [s["attrs"]["nth"] for s in counted] == list(
+        range(s0["stalls"] + 1, s1["stalls"] + 1))
+    assert s1["stalls"] - s0["stalls"] == len(counted) >= 1
+    assert s1["stall_s"] - s0["stall_s"] == pytest.approx(
+        sum(s["attrs"]["stood_ms"] for s in counted) / 1e3, abs=1e-3)
+    # every thread's stack on stderr, one block, headed by the span's id
+    err = capfd.readouterr().err
+    block = err[err.index("llm.stall " + st["sid"]):]
+    assert "  thread llm-engine [llm-engine]" in block
+    assert "  thread llm-stall-watch [llm-stall-watch]" in block
+    assert "  thread MainThread [MainThread]" in block
+    assert "test_engine_timeline.py" in block and " put" in block
+
+
+def test_a_thread_that_keeps_the_interpreter_is_a_late_wake_held_by_it(small):
+    import json
+
+    from ray_tpu import tracing
+    from ray_tpu.serve import llm
+
+    # half as long again as the latest wake a sound run may show
+    fn, arg = _hold_the_interpreter(1.5 * llm.LATE_WAKE_S)
+    eng = _engine(small)
+    eng.start()
+    try:
+        _one_wave(eng, [_prompt(40)], 9)        # warm
+        tracing.clear()
+        s0 = eng.stats()["loop"]
+        fut = _decoding(eng, 50)
+        held = _rival(fn, arg)
+        fut.result(timeout=120.0)
+        time.sleep(0.15)
+        s1 = eng.stats()["loop"]
+    finally:
+        eng.stop()
+    (st,) = [s for s in _spans_named("llm.stall", held[0] - 0.06)
+             if s["t0"] < held[1]]
+    a = st["attrs"]
+    assert a["trigger"] == "late_wake" and a["held"] == "interpreter"
+    assert a["late_wake_ms"] >= llm.LATE_WAKE_S * 1e3
+    assert a["phase"] != "idle" and s0["stalls"] < a["nth"] <= s1["stalls"]
+    # the missed wake was due within one sleep of the hold's start
+    assert -0.01 <= st["t0"] - held[0] <= 0.06
+    assert abs(st["t1"] - held[1]) <= 0.06
+    rows = json.loads(a["by_thread_cpu_ms"])
+    assert rows[0][0] == "serve-call" and rows[0][1] >= 0.2 * a["stood_ms"]
+    assert a["process_cpu_ms"] >= rows[0][1] and a["engine_cpu_ms"] < 100.0
+    assert s1["stalls"] - s0["stalls"] >= 1
+    assert s1["stall_s"] - s0["stall_s"] >= llm.LATE_WAKE_S
+    # the rows were read over a stretch that begins at most
+    # WATCH_LEDGER_EVERY wakes before the stall, and a wake after it
+    assert a["stood_ms"] <= a["ledger_ms"] <= a["stood_ms"] + 1e3 * (
+        (llm.WATCH_LEDGER_EVERY + 2) * llm.WATCH_S + 0.06)
+
+
+def test_a_collection_on_a_rival_thread_is_a_gc_pause_the_stall_accounts_for(
+        small, monkeypatch):
+    import gc
+
+    from ray_tpu import tracing
+    from ray_tpu.serve import llm
+
+    # a collection of a few million objects takes 0.3-0.4 s: the watcher
+    # is held to the host rule's constant here, not to twice a sound
+    # run's latest wake, so that the heap under test stays small
+    monkeypatch.setattr(llm, "LATE_WAKE_S", llm.HOST_STALL_S)
+    eng = _engine(small)
+    eng.start()
+    gc.collect()
+    gc.disable()        # the collection under test is the rival's
+    try:
+        junk = [[] for _ in range(4_000_000)]
+        _one_wave(eng, [_prompt(40)], 9)        # warm
+        tracing.clear()
+        s0 = eng.stats()["loop"]
+        fut = _decoding(eng, 50)
+        at = _rival(lambda _: gc.collect(), None)
+        fut.result(timeout=120.0)
+        time.sleep(0.15)
+        s1 = eng.stats()["loop"]
+    finally:
+        gc.enable()
+        eng.stop()
+        junk = None     # noqa: F841 - held until here
+    (pause,) = [s for s in _spans_named("llm.gc_pause", at[0] - 0.01)
+                if s["attrs"]["generation"] == 2 and s["t1"] <= at[1] + 0.01]
+    assert pause["t1"] - pause["t0"] >= 0.2
+    assert pause["attrs"]["thread"] == "serve-call"
+    assert set(pause["attrs"]) == {"generation", "collected",
+                                   "uncollectable", "thread"}
+    # never a trace of its own: it hangs off the process's one root
+    assert (pause["tid"], pause["par"]) == llm._build_root
+    (st,) = [s for s in _spans_named("llm.stall", at[0] - 0.06)
+             if s["t0"] < at[1]]
+    a = st["attrs"]
+    assert a["trigger"] == "late_wake" and a["held"] == "interpreter"
+    over = min(st["t1"], pause["t1"]) - max(st["t0"], pause["t0"])
+    assert over >= 0.2 and a["gc_ms"] == pytest.approx(over * 1e3, abs=2.0)
+    assert a["gc_ms"] >= 0.8 * a["stood_ms"]
+    assert -0.01 <= st["t0"] - pause["t0"] <= 0.06
+    assert abs(st["t1"] - pause["t1"]) <= 0.06
+    g0, g1 = s0["gc_by_generation"]["2"], s1["gc_by_generation"]["2"]
+    assert g1["pauses"] - g0["pauses"] >= 1
+    assert g1["pause_s"] - g0["pause_s"] >= pause["t1"] - pause["t0"] - 1e-3
+    assert s1["gc_pauses"] - s0["gc_pauses"] >= 1
+    assert s1["gc_pause_s"] - s0["gc_pause_s"] >= g1["pause_s"] - g0["pause_s"] \
+        - 1e-5
+    assert set(s1["gc_by_generation"]) == {"0", "1", "2"}
+
+
+def test_a_sound_run_of_fifty_windows_records_no_stall(small):
+    from ray_tpu import tracing
+
+    eng = _engine(small)
+    eng.start()
+    try:
+        _one_wave(eng, [_prompt(40)], 9)        # warm
+        for _ in range(3):      # a busy box may stall one run, not three
+            time.sleep(0.1)
+            tracing.clear()
+            s0, mark = eng.stats()["loop"], time.time()
+            eng.generate(_prompt(40, 3), max_new_tokens=4 * 50 + 1,
+                         _cache_ok=False)
+            time.sleep(0.15)
+            s1 = eng.stats()["loop"]
+            stalls = _spans_named("llm.stall", mark)
+            assert s1["stalls"] - s0["stalls"] == sum(
+                s["attrs"]["phase"] != "idle" for s in stalls)
+            if not stalls:
+                break
+    finally:
+        eng.stop()
+    assert s1["decode_steps"] - s0["decode_steps"] == 4 * 50
+    assert not stalls and s1["stalls"] == s0["stalls"]
+    assert s1["stall_s"] == s0["stall_s"]
+
+
+def test_a_stall_in_idle_is_a_span_and_is_not_counted(small):
+    from ray_tpu import tracing
+    from ray_tpu.serve import llm
+
+    fn, arg = _hold_the_interpreter(1.5 * llm.LATE_WAKE_S)
+    eng = _engine(small)
+    eng.start()
+    try:
+        _one_wave(eng, [_prompt(40)], 9)        # warm
+        time.sleep(0.2)                         # the loop is in its wait
+        tracing.clear()
+        s0 = eng.stats()["loop"]
+        held = _rival(fn, arg)
+        time.sleep(0.15)
+        s1 = eng.stats()["loop"]
+    finally:
+        eng.stop()
+    stalls = [s for s in _spans_named("llm.stall", held[0] - 0.06)
+              if s["t0"] < held[1]]
+    assert stalls and {s["attrs"]["phase"] for s in stalls} == {"idle"}
+    assert stalls[0]["attrs"]["trigger"] == "late_wake"
+    assert stalls[0]["attrs"]["held"] == "interpreter"
+    assert "iter" not in stalls[0]["attrs"]     # the wait has none
+    assert stalls[0]["attrs"]["nth"] == 0
+    assert (s1["stalls"], s1["stall_s"]) == (s0["stalls"], s0["stall_s"])
+
+
+def test_stall_and_gc_counters_advance_with_tracing_off(small):
+    import gc
+
+    from ray_tpu import tracing
+
+    tracing.set_enabled(False)
+    try:
+        tracing.clear()
+        eng = _engine(small)
+        eng.start()
+        try:
+            _one_wave(eng, [_prompt(40)], 9)    # warm
+            s0 = eng.stats()["loop"]
+            eng.submit(_prompt(40, 3), max_new_tokens=41, _cache_ok=False,
+                       token_queue=_SleepsOnce(0.4)).result(timeout=120.0)
+            gc.collect()
+            time.sleep(0.15)
+            s1 = eng.stats()["loop"]
+        finally:
+            eng.stop()
+        assert s1["stalls"] - s0["stalls"] >= 1
+        # with the recorder off the phase's wall at entry is the watcher's
+        assert 0.3 <= s1["stall_s"] - s0["stall_s"] <= 1.0
+        assert s1["gc_pauses"] - s0["gc_pauses"] >= 1
+        assert s1["gc_pause_s"] > s0["gc_pause_s"]
+        assert s1["gc_by_generation"]["2"]["pauses"] \
+            > s0["gc_by_generation"]["2"]["pauses"]
+        assert not [r for r in tracing.snapshot()
+                    if r["name"].startswith("llm.")]
+    finally:
+        tracing.set_enabled(True)
+
+
+def test_the_watcher_has_a_ledger_row_and_ends_with_stop(small):
+    import threading
+
+    from ray_tpu.serve import llm
+
+    def watchers():
+        return [t for t in threading.enumerate()
+                if t.name == "llm-stall-watch"]
+
+    assert "llm-stall-watch" in llm._THREAD_ROWS
+    before = len(watchers())
+    eng = _engine(small)
+    assert len(watchers()) == before            # not before start()
+    eng.start()
+    try:
+        eng.start()                             # once, however often
+        assert len(watchers()) == before + 1
+        eng.generate(_prompt(40), max_new_tokens=9, _cache_ok=False)
+        assert "llm-stall-watch" in eng.stats()["threads"]["by_name"]
+        # two attribute stores a phase: what the watcher reads
+        assert eng._phase_now is None or eng._phase_now[0] in PHASES
+    finally:
+        eng.stop()
+    assert len(watchers()) == before and eng._watch_thread is None
+    assert eng._phase_now is None
+    eng.start()                                 # and comes back with it
+    try:
+        assert len(watchers()) == before + 1
+    finally:
+        eng.stop()
+    assert len(watchers()) == before
+
+
+@pytest.mark.parametrize("trigger,stood,late,cpu,held", [
+    ("host_phase", 400.0, 2.0, 5.0, "engine"),      # the watcher on time
+    ("sync", 2500.0, 2.0, 5.0, "device"),
+    ("late_wake", 400.0, 390.0, 395.0, "interpreter"),  # somebody ran
+    ("host_phase", 400.0, 300.0, 395.0, "interpreter"),
+    ("late_wake", 400.0, 390.0, 12.0, "process"),   # nobody did
+    ("sync", 2500.0, 900.0, 100.0, "process"),
+])
+def test_held_is_derived_from_the_stalls_own_numbers(trigger, stood, late,
+                                                     cpu, held):
+    from ray_tpu.serve import llm
+
+    assert llm._stall_held(trigger, stood, late, cpu) == held
 
 
 # ------------------------------------------------- the kernels' names
@@ -931,7 +1315,18 @@ def _build(fun, stage, t0, t1, depth=0, **attrs):
                       "thread": "llm-engine", **attrs}}
 
 
-def _synthetic_run(parent=False):
+def _stall(t0, t1, **attrs):
+    return {"name": "llm.stall", "t0": t0, "t1": t1, "tid": "e",
+            "attrs": {"stood_ms": (t1 - t0) * 1e3, **attrs}}
+
+
+def _gc_pause(t0, t1, generation, thread):
+    return {"name": "llm.gc_pause", "t0": t0, "t1": t1, "tid": "b",
+            "attrs": {"generation": generation, "collected": 7,
+                      "uncollectable": 0, "thread": thread}}
+
+
+def _synthetic_run(parent=False, pr50=True):
     """Twelve iterations of 100 ms starting at t = 1000: 2 ms admit, 1 ms
     fund, 3 ms decode_dispatch, 90 ms decode_sync, 4 ms deliver; then
     idle.  The chip is idle in the first 10 ms of each iteration (admit +
@@ -941,7 +1336,13 @@ def _synthetic_run(parent=False):
     replica built 7.5 s of programs before the window and one of 250 ms
     inside it; between the readings of `stats`, 4 s apart, its other
     threads gained 1.0 CPU second.  `parent`: the run of a program from
-    before PR 36, which records none of that."""
+    before PR 36, which records none of that.  Since PR 50 (`pr50`) the
+    loop stalled twice with work waiting: 300 ms in iteration 5's deliver
+    (a callback slept; over three of the chip's 10 ms gaps) and 120 ms
+    after the traced stretch, inside the profiler's stop, behind a
+    `serve-call` thread; once more in `idle` (150 ms of the idle gap: a
+    span, no count); and the collector ran 60 times for 350 ms, 250 of
+    them one generation-2 pause on a `serve-call` thread."""
     spans, gaps = [], []
     for i in range(12):
         t = 1000.0 + 0.1 * i
@@ -989,8 +1390,36 @@ def _synthetic_run(parent=False):
             "wall_s": 1003.0, "process_cpu_s": 23.0,
             "by_name": {"llm-engine": 11.0, "serve-call": 5.6,
                         "raytpu-io": 1.3, "llm-kv-export": 0.1}}
+    if pr50 and not parent:
+        spans += [
+            _stall(1000.5, 1000.8, phase="deliver", iter=5, nth=4,
+                   trigger="host_phase", held="engine", late_wake_ms=1.0,
+                   by_thread_cpu_ms='[["serve-call", 2.5]]'),
+            _stall(1001.3, 1001.45, phase="idle", nth=0,
+                   trigger="late_wake", held="interpreter",
+                   late_wake_ms=150.0,
+                   by_thread_cpu_ms='[["serve-call", 149.0]]'),
+            _stall(1002.5, 1002.62, phase="decode_sync", iter=13, nth=5,
+                   trigger="late_wake", held="interpreter",
+                   late_wake_ms=120.0,
+                   by_thread_cpu_ms='[["serve-call", 118.0]]'),
+            _gc_pause(1000.52, 1000.77, 2, "serve-call"),
+            _gc_pause(1001.0, 1001.004, 1, "serve-call"),
+            _gc_pause(1002.0, 1002.05, 2, "llm-engine"),
+            _gc_pause(990.0, 990.3, 2, "MainThread")]    # the set-up's
+        loop0.update(stalls=3, stall_s=9.0, gc_pauses=100, gc_pause_s=0.5,
+                     gc_by_generation={
+                         "0": {"pauses": 90, "pause_s": 0.1},
+                         "1": {"pauses": 8, "pause_s": 0.1},
+                         "2": {"pauses": 2, "pause_s": 0.3}})
+        loop1.update(stalls=5, stall_s=9.42, gc_pauses=160, gc_pause_s=0.85,
+                     gc_by_generation={
+                         "0": {"pauses": 140, "pause_s": 0.12},
+                         "1": {"pauses": 16, "pause_s": 0.13},
+                         "2": {"pauses": 4, "pause_s": 0.6}})
     return {
         "spans": spans, "window_wall": (1000.0, 1002.0),
+        "trace_wall": (999.9, 1002.9),
         "setup": {"serve_run_s": 20.0, "warmup_s": 9.0},
         "stats": (stats0, stats1),
         "trace": {"start_wall_s": 1000.0, "t_lo": 0.0, "t_hi": 2.0,
@@ -1015,6 +1444,12 @@ EMPTY_RUN = {"spans": [], "window_wall": (0.0, 1.0), "stats": ({}, {}),
     ("engine.program_build_ms_in_window.open", 250.0),
     ("engine.program_build_ms_in_window.closed", 250.0),
     ("setup.program_build_s", 7.5),
+    # PR 50's four: 300 ms stalled (the one in idle is no count, the 120
+    # ms under the profiler's stop are tracing's), the collector's 350 ms
+    ("engine.stall_ms_in_window.open", 300.0),
+    ("engine.stall_ms_in_window.closed", 300.0),
+    ("engine.gc_pause_ms_in_window.open", 350.0),
+    ("engine.gc_pause_ms_in_window.closed", 350.0),
 ])
 def test_timeline_readers_on_a_synthetic_run(fn, want, capsys):
     import json
@@ -1066,6 +1501,57 @@ def test_timeline_readers_on_a_synthetic_run(fn, want, capsys):
                 by["admit"])                # one shape, whatever the clock
             assert part["by_phase_mean_ms"]["decode_dispatch"]["stood"] \
                 == pytest.approx(3.0 - 2.5)
+        elif "stall_ms" in fn:
+            # a program that records PR 36's keys and not PR 50's
+            assert read(_synthetic_run(pr50=False)) is None
+            assert part["step"] == "stalls_in_window"
+            assert (part["stalls"], part["stall_s"]) == (
+                2, pytest.approx(0.42))
+            assert part["by_held_n_ms"] == {
+                "engine": [1, pytest.approx(300.0)],
+                "interpreter": [1, pytest.approx(120.0)]}
+            assert part["in_profiler_ms"] == pytest.approx(120.0)
+            first, idle, last = part["spans"]
+            assert (first["phase"], first["held"], first["iter"]) == (
+                "deliver", "engine", 5)
+            assert first["at_s"] == pytest.approx(1.5)
+            assert first["by_thread_cpu_ms"] == [["serve-call", 2.5]]
+            # three of the chip's 10 ms gaps lie under it
+            assert first["device_idle_s"] == pytest.approx(0.03)
+            assert first["in_measured_window"] and not first[
+                "in_profiler"]
+            assert idle["phase"] == "idle"
+            assert idle["device_idle_s"] == pytest.approx(0.15)
+            # after the traced stretch: no device time to set it against
+            assert last["device_idle_s"] is None
+            assert last["in_profiler"] and not last[
+                "in_measured_window"]
+            # a run that was not traced: the counters and the spans alone,
+            # and no profiler to set a stall aside for
+            run = _synthetic_run()
+            run["trace"] = None
+            capsys.readouterr()
+            assert read(run) == pytest.approx(420.0)
+            part = json.loads(capsys.readouterr().out.splitlines()[0])
+            assert [r["device_idle_s"] for r in part["spans"]] == [None] * 3
+            assert part["in_profiler_ms"] == 0.0
+        elif "gc_pause" in fn:
+            assert read(_synthetic_run(pr50=False)) is None
+            assert part["step"] == "gc_in_window"
+            assert (part["pauses"], part["pause_s"]) == (
+                60, pytest.approx(0.35))
+            assert part["by_generation"]["2"] == {
+                "pauses": 2, "pause_s": pytest.approx(0.3)}
+            assert part["by_generation"]["0"]["pauses"] == 50
+            assert part["spans"] == 3           # not the set-up's
+            assert part["by_thread_n_s"] == {
+                "serve-call": [2, pytest.approx(0.254)],
+                "llm-engine": [1, pytest.approx(0.05)]}
+            assert part["longest_ms"][0] == [
+                pytest.approx(250.0), 2, "serve-call", 7,
+                pytest.approx(1.52)]
+            assert [r[0] for r in part["longest_ms"]] == pytest.approx(
+                [250.0, 50.0, 4.0])
         elif "in_window" in fn:
             assert part["step"] == "builds_in_window"
             assert (part["program_builds"],
