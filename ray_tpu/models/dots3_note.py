@@ -210,11 +210,10 @@ def _decode_work(cfg: Dots3NoteConfig, rows, k: int, page: int, maxp: int
 
 
 def serving_spec(cfg: Dots3NoteConfig) -> ServingSpec:
-    """No optional capability.  A window layer keeps a ring of rows a
-    lane, which the scatter fills from a prefill row's last positions:
-    the bytes of the rings ONE prefill row hands it.  The window layers'
-    prefill attention is `flash_fwd` under a band; the full layers' is
-    `dsa.masked_prefill_attention`."""
+    """No optional capability.  A window layer keeps a ring of rows a lane,
+    filled from a prefill row's last positions: the bytes of the rings ONE
+    prefill row hands the scatter.  Prefill attention: `flash_fwd` under a
+    band (window layers), `dsa.masked_prefill_attention` (full layers)."""
     n_win = cfg.count(WINDOW)
     return ServingSpec(
         lane_state_layers=n_win,
@@ -223,11 +222,12 @@ def serving_spec(cfg: Dots3NoteConfig) -> ServingSpec:
         prefill_params=prefill_params(cfg),
         routed_layers=_routed_layers(cfg),
         counters={**flash_attention.PREFILL_COUNTERS,
-                  **flash_attention.BAND_COUNTERS, **dsa.COUNTERS,
-                  **swa.COUNTERS, **routed.COUNTERS},
+                  **flash_attention.BAND_COUNTERS, **dsa.PREFILL_COUNTERS,
+                  **dsa.COUNTERS, **swa.COUNTERS, **routed.COUNTERS},
         decode_work=functools.partial(_decode_work, cfg),
-        prefill_work=functools.partial(flash_attention.band_work,
-                                       cfg.window),
+        prefill_work=lambda true_lens, bucket: merged(
+            flash_attention.band_work(cfg.window, true_lens, bucket),
+            dsa.prefill_work(cfg.count(FULL), true_lens, bucket)),
         routed_work=functools.partial(routed.routed_work, cfg,
                                       cfg.experts_held))
 
@@ -470,8 +470,8 @@ def full_prefill(x, lp, cfg: Dots3NoteConfig, true_lens,
     """The full layer's attention half over whole rows x [b, T, d],
     EXPANDED: (what it adds to x, (latent rows [b, T, 1, row_width],
     index keys [b, T, 1, index_dim])); with `want_selection` a third
-    entry, the rows each query attends [b, T, T] (a judge's reading)."""
-    del true_lens           # causal true rows need no length
+    entry, the rows each query attends [b, T, T] (a judge's reading).
+    `true_lens` [b]: the kernel walks no query block wholly past them."""
     b, T, _ = x.shape
     k = cfg.full
     h = rmsnorm(x, lp["norm1"], cfg.norm_eps)
@@ -484,7 +484,7 @@ def full_prefill(x, lp, cfg: Dots3NoteConfig, true_lens,
     with jax.named_scope("dsa_attn"):
         if dsa.prefill_block(T):
             o = dsa.masked_prefill_attention(
-                q, kk, v, mask.astype(jnp.int8),
+                q, kk, v, mask.astype(jnp.int8), true_lens,
                 sm_scale=k.qk_head_dim ** -0.5)
         else:
             o = _dense_masked_attention(q, kk, v, mask,

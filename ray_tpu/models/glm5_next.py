@@ -190,9 +190,6 @@ def serving_configs() -> dict[str, Glm5NextConfig]:
     }
 
 
-DSA_COUNTERS = dsa.COUNTERS
-
-
 def _decode_work(cfg: Glm5NextConfig, rows, k: int, page: int, maxp: int
                  ) -> tuple[dict, dict]:
     """One decode window of `k` steps over live lanes that start it on
@@ -210,7 +207,7 @@ def serving_spec(cfg: Glm5NextConfig) -> ServingSpec:
     rows; a sparse layer an index key in the making: the bytes of all
     that ONE prefill row hands the scatter program.  The sparse
     prefill attention is `dsa.masked_prefill_attention`, not
-    `flash_fwd`: no `prefill_attn_blocks`."""
+    `flash_fwd`: `dsa_prefill_blocks*`, no `prefill_attn_blocks`."""
     n_kda = cfg.count(KDA)
     kda_layer = (cfg.kda_inner * cfg.kda_head_dim
                  * jnp.dtype(cfg.state_dtype).itemsize
@@ -222,9 +219,12 @@ def serving_spec(cfg: Glm5NextConfig) -> ServingSpec:
                              + cfg.count(DSA) * 4 * cfg.index_dim),
         prefill_params=prefill_params(cfg),
         routed_layers=_routed_layers(cfg),
-        counters={**ssm.SCAN_COUNTERS, **DSA_COUNTERS, **routed.COUNTERS},
+        counters={**ssm.SCAN_COUNTERS, **dsa.PREFILL_COUNTERS,
+                  **dsa.COUNTERS, **routed.COUNTERS},
         decode_work=functools.partial(_decode_work, cfg),
-        prefill_work=functools.partial(ssm.scan_work, n_kda, cfg.kda_chunk),
+        prefill_work=lambda true_lens, bucket: merged(
+            ssm.scan_work(n_kda, cfg.kda_chunk, true_lens, bucket),
+            dsa.prefill_work(cfg.count(DSA), true_lens, bucket)),
         routed_work=functools.partial(routed.routed_work, cfg,
                                       cfg.experts_held))
 
@@ -615,11 +615,11 @@ def dsa_prefill(x, lp, cfg: Glm5NextConfig, true_lens,
 
     with jax.named_scope("dsa_attn"):
         if dsa.prefill_block(T):
-            # the kernel walks the block pairs below the diagonal; the
-            # mask rides as bytes
+            # the kernel walks the block pairs below the diagonal and
+            # inside the true lengths; the mask rides as bytes
             o = dsa.masked_prefill_attention(
                 q, k.astype(q.dtype), v.astype(q.dtype),
-                whole(masks, T).astype(jnp.int8),
+                whole(masks, T).astype(jnp.int8), true_lens,
                 sm_scale=cfg.qk_head_dim ** -0.5)
         else:               # a short bucket: XLA, the scores in memory
             o = _masked_attention(q, k, v, masks, cfg.qk_head_dim ** -0.5)

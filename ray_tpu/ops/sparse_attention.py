@@ -524,93 +524,148 @@ def attn_cost(H: int, dk: int, dv: int, rows: float
     return 2.0 * H * (dk + dv) * rows, 2.0 * dk * rows
 
 
-# ------------------------------------------------- prefill: masked flash
-def _prefill_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, acc_ref, m_ref,
-                    l_ref, *, sm_scale: float):
-    """One (query block i, key block j <= i) pair of one head: the flash
-    accumulation of `ops/flash_attention`, with the pairs a query attends
-    given as a mask block."""
-    i, j = pl.program_id(2), pl.program_id(3)
+# ------------------------------------ prefill: masked flash on flash's walk
+# `dsa_prefill` walks what `flash_fwd` walks (`flash_attention._walk` over
+# `key_blocks`): the (row, query block, key block) triples under the
+# diagonal and inside the rows' true lengths, one step each, the minor
+# grid axis bounded by the longest row's count; a query block wholly past
+# its row's length takes ONE step, which writes its zeros.  What it adds
+# to that walk is the mask block a step, which alone says which pairs
+# count: causality too, so the blocks the diagonal crosses (and the half
+# of a 1,024-key block that lies above it) are no special case.
+M_FLOOR = -1e20     # the running max starts here: exp(NEG_INF - m) is 0
 
-    @pl.when(j == 0)
+
+def _prefill_kernel(qi_ref, ki_ref, flag_ref, q_ref, k_ref, v_ref, mask_ref,
+                    o_ref, acc_ref, m_ref, l_ref, *, sm_scale: float,
+                    stride: int):
+    """One step of the walk of one (row, head): `_walk`'s flag says
+    whether it opens a query block, multiplies a key block, closes the
+    query block.  The flash accumulation of `ops/flash_attention`, the
+    pairs a query attends given as a mask block: a pair the mask leaves
+    out scores NEG_INF under a running max that never falls below
+    M_FLOOR, so its weight is exactly 0."""
+    fa = flash_attention
+    flag = flag_ref[pl.program_id(0) * stride + pl.program_id(2)]
+
+    @pl.when(flag & fa._FIRST != 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        m_ref[...] = jnp.full_like(m_ref, M_FLOOR)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(j <= i)
+    @pl.when(flag & (fa._INSIDE | fa._EDGE) != 0)
     def _pair():
-        keep = mask_ref[...] != 0
         s = lax.dot_general(q_ref[...], k_ref[...], (((1,), (1,)), ((), ())),
                             preferred_element_type=F32) * sm_scale
-        s = jnp.where(keep, s, NEG_INF)
-        m_prev = m_ref[:, :1]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        s = jnp.where(mask_ref[...] != 0, s, NEG_INF)
+        m_prev = m_ref[:, 0]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
         alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.where(keep, jnp.exp(s - m_cur), 0.0)
-        l_ref[:, :1] = l_ref[:, :1] * alpha + jnp.sum(p, axis=1,
-                                                      keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + lax.dot_general(
+        p = jnp.exp(s - m_cur[:, None])
+        l_ref[:, 0] = l_ref[:, 0] * alpha + jnp.sum(p, axis=1)
+        acc_ref[...] = acc_ref[...] * alpha[:, None] + lax.dot_general(
             p.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())),
             preferred_element_type=F32)
-        m_ref[:, :1] = m_cur
+        m_ref[:, 0] = m_cur
 
-    @pl.when(j == i)
+    @pl.when(flag & fa._LAST != 0)
     def _done():
-        l = l_ref[:, :1]
-        o_ref[...] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(
-            o_ref.dtype)
+        l = l_ref[:, 0]
+        l = jnp.where(l == 0.0, 1.0, l)     # nothing attended: zeros
+        o_ref[...] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
 
 
 def prefill_block(T: int) -> int:
-    """Queries (and keys) a block of `masked_prefill_attention` for rows
-    of T positions: the largest of 512, 256, 128 that divides T; 0 where
-    none does (the caller then runs the attention in XLA)."""
+    """Whether `masked_prefill_attention` takes rows of T positions: the
+    largest of 512, 256, 128 that divides T; 0 where none does (the
+    caller then runs the attention in XLA)."""
     return next((n for n in (512, 256, 128) if T % n == 0), 0)
 
 
-def masked_prefill_attention(q, k, v, mask, *, sm_scale: float):
-    """softmax(sm_scale q k^T over the pairs `mask` admits) v, causal
-    block pairs only (a pair of blocks above the diagonal is neither
-    copied nor computed).
+def masked_prefill_attention(q, k, v, mask, lengths, *, sm_scale: float,
+                             block_q: int = flash_attention.DEFAULT_BLOCK_Q,
+                             block_k: int = flash_attention.DEFAULT_BLOCK_K):
+    """softmax(sm_scale q k^T over the pairs `mask` admits) v over
+    right-padded rows, the walk of `flash_fwd`: the block pairs under the
+    diagonal and inside a row's true length, nothing else copied or
+    computed.
 
     q, k [b, T, H, dq]; v [b, T, H, dv]; mask [b, T, T] int8 (1: the
-    query attends the key; nothing above the diagonal is read).  T a
-    multiple of `prefill_block(T)`.  Returns o [b, T, H, dv]; a query that
-    attends nothing reads 0."""
+    query attends the key; it holds nothing above the diagonal, which
+    is all the causality the kernel has); lengths int32 [b], the rows'
+    true lengths.  T a multiple of 128 (`prefill_block`); the blocks are
+    `flash_attention.fit_blocks(T, T, block_q, block_k)`.  Returns o
+    [b, T, H, dv]; a query that attends nothing reads 0, and so does
+    every query of a query block wholly past its row's length."""
     b, T, H, dq = q.shape
     dv = v.shape[-1]
-    n = prefill_block(T)
-    nb = T // n
+    fa = flash_attention
+    bq, bk = fa.fit_blocks(T, T, block_q, block_k)
+    steps = int(fa.key_blocks(T, T, None, bq, bk).sum())  # a full-length row
+    *tables, total = fa._walk(
+        fa.key_blocks(T, T, lengths.astype(jnp.int32), bq, bk, True, jnp),
+        steps, bq, bk, True, jnp)
 
-    def qmap(bi, h, i, j):
-        return (bi, h, i, 0)
+    def qmap(bi, h, p, qi, ki, flag):
+        return (bi, h, qi[bi * steps + p], 0)
 
-    def kmap(bi, h, i, j):
-        return (bi, h, jnp.minimum(j, i), 0)    # above the diagonal: no copy
+    def kmap(bi, h, p, qi, ki, flag):
+        return (bi, h, ki[bi * steps + p], 0)
 
-    def mmap(bi, h, i, j):
-        return (bi, i, jnp.minimum(j, i))
+    def mmap(bi, h, p, qi, ki, flag):
+        return (bi, qi[bi * steps + p], ki[bi * steps + p])
 
     qh, kh, vh = (jnp.swapaxes(a, 1, 2) for a in (q, k, v))   # [b, H, T, w]
     o = pl.pallas_call(
-        functools.partial(_prefill_kernel, sm_scale=sm_scale),
+        functools.partial(_prefill_kernel, sm_scale=sm_scale, stride=steps),
         name="dsa_prefill",
-        grid=(b, H, nb, nb),
-        in_specs=[pl.BlockSpec((None, None, n, dq), qmap),
-                  pl.BlockSpec((None, None, n, dq), kmap),
-                  pl.BlockSpec((None, None, n, dv), kmap),
-                  pl.BlockSpec((None, n, n), mmap)],
-        out_specs=pl.BlockSpec((None, None, n, dv), qmap),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, H, jnp.max(total)),        # the device's number
+            in_specs=[pl.BlockSpec((None, None, bq, dq), qmap),
+                      pl.BlockSpec((None, None, bk, dq), kmap),
+                      pl.BlockSpec((None, None, bk, dv), kmap),
+                      pl.BlockSpec((None, bq, bk), mmap)],
+            out_specs=pl.BlockSpec((None, None, bq, dv), qmap),
+            scratch_shapes=[pltpu.VMEM((bq, dv), F32),
+                            pltpu.VMEM((bq, LANE), F32),
+                            pltpu.VMEM((bq, LANE), F32)]),
         out_shape=jax.ShapeDtypeStruct((b, H, T, dv), q.dtype),
-        scratch_shapes=[pltpu.VMEM((n, dv), F32), pltpu.VMEM((n, LANE), F32),
-                        pltpu.VMEM((n, LANE), F32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary",
-                                 "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
-    )(qh, kh, vh, mask)
+    )(*tables, qh, kh, vh, mask)
     return jnp.swapaxes(o, 1, 2)
+
+
+# What a serving module whose prefill calls `dsa_prefill` reports of it
+# (models/serving.ServingSpec.counters): dsa_prefill_blocks /
+# dsa_prefill_blocks_dense = the share of the causal walk that lies
+# inside the rows' true lengths.
+PREFILL_COUNTERS = {
+    "dsa_prefill_blocks": "(row, query block, key block) triples "
+                          "dsa_prefill multiplies, a full-prompt prefill "
+                          "program, x sparse layers",
+    "dsa_prefill_blocks_dense": "The triples the same calls would multiply "
+                                "for rows of the full length (causal)",
+}
+
+
+def prefill_work(layers: int, true_lens, bucket: int) -> tuple[dict, dict]:
+    """`ServingSpec.prefill_work` of a program of len(true_lens) rows
+    padded to `bucket` whose `layers` sparse layers each call
+    `masked_prefill_attention` (host arithmetic,
+    `flash_attention.attn_blocks`); 0 where the bucket runs in XLA
+    (`prefill_block`)."""
+    walked = dense = 0
+    if prefill_block(bucket):
+        bq, bk = flash_attention.fit_blocks(bucket, bucket)
+        walked, dense = (
+            layers * flash_attention.attn_blocks(bucket, lens, bq, bk)
+            for lens in (true_lens, [bucket] * len(true_lens)))
+    return {"dsa_prefill_blocks": walked,
+            "dsa_prefill_blocks_dense": dense}, {}
 
 
 # What a serving module with learned sparse attention reports of its

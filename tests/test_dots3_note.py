@@ -521,7 +521,8 @@ def test_the_seam_declares_what_the_engine_counts():
     work, shown = spec.prefill_work([9, 17], 32)
     assert shown == {} and set(work) == {
         "prefill_attn_blocks", "prefill_attn_blocks_dense",
-        "prefill_swa_blocks", "prefill_swa_blocks_dense"}
+        "prefill_swa_blocks", "prefill_swa_blocks_dense",
+        "dsa_prefill_blocks", "dsa_prefill_blocks_dense"}
     streamed, multiplied = spec.prefill_params
     assert (streamed, multiplied) == model.prefill_params(CFG)
     assert streamed > multiplied > 0

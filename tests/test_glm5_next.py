@@ -212,27 +212,86 @@ def test_the_tables_shape_names_the_form(group, top, H, dk):
         assert work == shown and work["dsa_rows_read"] == want
 
 
-@pytest.mark.parametrize("T", [128, 384])
-def test_the_masked_prefill_kernel_equals_a_masked_softmax(T):
-    """`dsa_prefill` (interpret mode): one block, and three blocks of 128
-    with the pairs above the diagonal never walked; a query that attends
-    nothing reads 0."""
-    b, H, dq = 1, 2, 32
+# (T, the rows' true lengths, block_q, block_k; None: `fit_blocks`' own)
+_PREFILL_CASES = {
+    "128": (128, [128], None, None),
+    "384": (384, [384], 128, 128),
+    "two_lengths": (512, [200, 512], 128, 128),
+    "keys_past_the_diagonal": (512, [512], 128, 256),
+    "four_key_blocks": (512, [512, 130], 256, 128),
+    "length_inside_a_block": (384, [300], 128, 128),
+}
+
+
+@pytest.mark.parametrize("case", list(_PREFILL_CASES))
+def test_the_masked_prefill_kernel_equals_a_masked_softmax(case):
+    """`dsa_prefill` (interpret mode): one block; three blocks of 128 with
+    the pairs above the diagonal never walked; rows of two true lengths
+    in one call (a query block wholly past its row's length reads exactly
+    0); key blocks that reach past the diagonal (the mask alone carries
+    causality); several key blocks a query block; a length that ends
+    inside a block.  A query that attends nothing reads 0 under the
+    floored max, in a key block or in all of them."""
+    T, lens, bq, bk = _PREFILL_CASES[case]
+    b, H, dq = len(lens), 2, 32
     ks = jax.random.split(jax.random.PRNGKey(T), 4)
     q, k, v = (jax.random.normal(ks[i], (b, T, H, dq)) for i in range(3))
     mask = ((jax.random.uniform(ks[3], (b, T, T)) < 0.3)
             | jnp.eye(T, dtype=bool)[None]) & jnp.tril(
                 jnp.ones((T, T), bool))[None]
     mask = mask.at[:, 5].set(False)
-    got = dsa.masked_prefill_attention(q, k, v, mask.astype(jnp.int8),
-                                       sm_scale=0.2)
+    if T > 300:     # a query whose first two key blocks admit nothing
+        mask = mask.at[:, 300, :256].set(False)
+    blocks = {} if bq is None else {"block_q": bq, "block_k": bk}
+    got = dsa.masked_prefill_attention(
+        q, k, v, mask.astype(jnp.int8), jnp.asarray(lens, jnp.int32),
+        sm_scale=0.2, **blocks)
     s = jnp.where(mask[:, None],
                   jnp.einsum("bthd,bshd->bhts", q, k) * 0.2, -1e30)
     want = jnp.einsum("bhts,bshd->bthd",
                       jax.nn.softmax(s, -1) * mask[:, None], v)
-    assert float(jnp.abs(got - want).max()) < 1e-5
+    for r, n in enumerate(lens):
+        assert float(jnp.abs(got[r, :n] - want[r, :n]).max()) < 1e-5
+        past = -(-n // (bq or T)) * (bq or T)   # the first block past it
+        assert float(jnp.abs(got[r, past:]).sum()) == 0.0
     assert float(jnp.abs(got[:, 5]).max()) == 0.0
     assert dsa.prefill_block(8192) == 512 and dsa.prefill_block(37) == 0
+
+
+@pytest.mark.parametrize("lens, bucket", [([8192], 8192), ([4097], 8192),
+                                          ([6216, 900], 8192),
+                                          ([2750], 2816), ([9, 17], 32)])
+def test_dsa_prefill_blocks_count_the_steps_the_walk_multiplies(lens, bucket):
+    """Host arithmetic: `dsa_prefill_blocks` is the steps `_walk` flags
+    as work for the same lengths, x sparse layers; the dense count is a
+    full-length row's; the rows that count `flash_fwd`'s banded walk in
+    dots3's spec are what `band_work` alone gives, to the integer; a
+    bucket the kernel does not take (XLA) counts nothing."""
+    from ray_tpu.models import dots3_note
+    from ray_tpu.ops import flash_attention as fa
+
+    big = dots3_note.Dots3NoteConfig(
+        vocab_size=19072, layer_types=(dots3_note.FULL,) * 2
+        + (dots3_note.WINDOW,) * 3, experts_held=(0, 32))
+    work, shown = dots3_note.serving_spec(big).prefill_work(lens, bucket)
+    band, _ = fa.band_work(big.window, lens, bucket)
+    assert shown == {} and {n: work[n] for n in band} == band
+    glm, _ = glm5_next.serving_spec(CFG).prefill_work(lens, bucket)
+    if not dsa.prefill_block(bucket):
+        assert work["dsa_prefill_blocks"] == glm["dsa_prefill_blocks"] == 0
+        assert work["dsa_prefill_blocks_dense"] == 0
+        return
+    bq, bk = fa.fit_blocks(bucket, bucket)
+    full = fa.key_blocks(bucket, bucket, None, bq, bk)
+    n_keys = fa.key_blocks(bucket, bucket, np.asarray(lens), bq, bk)
+    *_, flag, total = fa._walk(n_keys, int(full.sum()), bq, bk, True, np)
+    walked = int((flag & (fa._INSIDE | fa._EDGE) != 0).sum())
+    assert work["dsa_prefill_blocks"] == 2 * walked
+    assert work["dsa_prefill_blocks_dense"] == 2 * len(lens) * int(full.sum())
+    assert glm["dsa_prefill_blocks"] == walked          # one sparse layer
+    assert glm["dsa_prefill_blocks_dense"] == len(lens) * int(full.sum())
+    # the steps beyond the work: one a query block wholly past the length
+    assert int(total.sum()) - walked == int((n_keys == 0).sum())
 
 
 def test_a_prompt_of_a_kernel_bucket_equals_the_reference(params):
@@ -566,9 +625,12 @@ def test_the_seam_declares_what_the_engine_counts():
     # past RATIO selections of table the gather reads S = 128 a step
     assert spec.decode_work([40], 1, 16, 8 * dsa.RATIO + 1)[0][
         "dsa_rows_read"] == 128
+    # (a bucket of 32 runs the sparse attention in XLA: no `dsa_prefill`)
     assert spec.prefill_work([9, 17], 32) == (
         {"prefill_scan_chunks": 3 * (2 + 3),
-         "prefill_scan_chunks_dense": 3 * 2 * 4}, {"scan_chunks": 15})
+         "prefill_scan_chunks_dense": 3 * 2 * 4,
+         "dsa_prefill_blocks": 0, "dsa_prefill_blocks_dense": 0},
+        {"scan_chunks": 15})
     # its prefill attention is not `flash_fwd`
     assert "prefill_attn_blocks" not in spec.counters
     streamed, multiplied = spec.prefill_params
