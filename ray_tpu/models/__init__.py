@@ -19,7 +19,8 @@ _SERVING = {"LlamaConfig": "ray_tpu.models.llama",
             "SsmHybridConfig": "ray_tpu.models.ssm_hybrid",
             "Glm5NextConfig": "ray_tpu.models.glm5_next",
             "Dots3NoteConfig": "ray_tpu.models.dots3_note",
-            "NemotronHConfig": "ray_tpu.models.nemotron_h"}
+            "NemotronHConfig": "ray_tpu.models.nemotron_h",
+            "MimoV2Config": "ray_tpu.models.mimo_v2"}
 
 
 def serving_model(cfg):

@@ -41,13 +41,14 @@ def xla_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                   causal: bool = True,
                   q_offset: int | jnp.ndarray = 0,
                   sm_scale: float | None = None,
-                  window: int | None = None) -> jnp.ndarray:
+                  window: int | None = None, sink=None) -> jnp.ndarray:
     """Reference implementation: fp32 softmax, GQA, causal mask.
 
     q: [b, sq, hq, d]; k/v: [b, skv, hkv, d] (v may be narrower).
     q_offset shifts query positions relative to kv positions (decode
     with a cache); sm_scale defaults to d**-0.5; window (causal only):
-    a query attends its own position and the window - 1 before it.
+    a query attends its own position and the window - 1 before it;
+    sink [hq]: a column of the softmax a head that carries no value.
     """
     n_rep = q.shape[2] // k.shape[2]
     k = _repeat_kv(k, n_rep)
@@ -63,7 +64,14 @@ def xla_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         if window is not None:
             mask &= qpos - kpos < window
         logits = jnp.where(mask[None, None], logits, -1e30)
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    logits = logits.astype(jnp.float32)
+    if sink is None:
+        probs = jax.nn.softmax(logits, axis=-1)
+    else:
+        col = jnp.broadcast_to(sink.astype(jnp.float32)[None, :, None, None],
+                               logits.shape[:3] + (1,))
+        probs = jax.nn.softmax(jnp.concatenate([logits, col], axis=-1),
+                               axis=-1)[..., :-1]
     out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
     return out.astype(q.dtype)
@@ -76,7 +84,8 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
               q_offset: int | jnp.ndarray = 0,
               sm_scale: float | None = None,
               lengths: jnp.ndarray | None = None,
-              window: int | None = None) -> jnp.ndarray:
+              window: int | None = None,
+              sink: jnp.ndarray | None = None) -> jnp.ndarray:
     """Multi-head attention with GQA.
 
     impl: "auto" picks the Pallas flash kernel on TPU for long-enough
@@ -91,6 +100,9 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     window: a query attends its own position and the window - 1 before
     it (causal only, forward only); the flash kernel walks no key block
     that lies wholly before a query block's band.
+    sink: float [hq], a learned column of the softmax a head that
+    carries no value (forward only, one device; the flash kernel of such
+    a call is named `swa_band` on the device).
     """
     if window is not None and not causal:
         raise ValueError("a window takes causal attention")
@@ -112,13 +124,14 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                             "tokens)", *key)
             _XLA_FALLBACKS[key] = _XLA_FALLBACKS.get(key, 0) + 1
     if use_flash:
-        return _flash_padded(q, k, v, causal, sm_scale, lengths, window)
+        return _flash_padded(q, k, v, causal, sm_scale, lengths, window,
+                             sink)
     return xla_attention(q, k, v, causal=causal, q_offset=q_offset,
-                         sm_scale=sm_scale, window=window)
+                         sm_scale=sm_scale, window=window, sink=sink)
 
 
 def _flash_padded(q, k, v, causal: bool, sm_scale: float | None = None,
-                  lengths=None, window: int | None = None):
+                  lengths=None, window: int | None = None, sink=None):
     """The flash kernel at any head_dim: a width (q and k's, or v's)
     under 128 lanes is zero-padded to 128, the scale given as the TRUE
     head_dim's; wider ones go as they are (192 / 128 compiles:
@@ -128,19 +141,20 @@ def _flash_padded(q, k, v, causal: bool, sm_scale: float | None = None,
     are cut off."""
     d, dv = q.shape[-1], v.shape[-1]
     if min(d, dv) >= 128:
-        return _flash_per_shard(q, k, v, causal, sm_scale, lengths, window)
+        return _flash_per_shard(q, k, v, causal, sm_scale, lengths, window,
+                                sink)
 
     def pad(a):
         return jnp.pad(a, ((0, 0),) * 3 + ((0, max(128 - a.shape[-1], 0)),))
 
     o = _flash_per_shard(pad(q), pad(k), pad(v), causal,
                          d ** -0.5 if sm_scale is None else sm_scale,
-                         lengths, window)
+                         lengths, window, sink)
     return o[..., :dv]
 
 
 def _flash_per_shard(q, k, v, causal: bool, sm_scale: float | None = None,
-                     lengths=None, window: int | None = None):
+                     lengths=None, window: int | None = None, sink=None):
     """The Pallas kernel; under an ambient multi-device mesh, one call
     per shard (jax refuses a Mosaic kernel under GSPMD at lowering:
     "wrap the call in a shard_map").  The layout — what splits over
@@ -153,6 +167,10 @@ def _flash_per_shard(q, k, v, causal: bool, sm_scale: float | None = None,
                            sm_scale=sm_scale,
                            **({} if window is None else {"window": window}))
     specs = attention_shard_specs(q.shape, k.shape)
+    if sink is not None:
+        if specs is not None:
+            raise ValueError("a sink takes attention on one device")
+        fn = functools.partial(fn, sink=sink)
     if specs is None:
         return fn(q, k, v, lengths=lengths)
     mesh, axis_names, q_spec, kv_spec = specs

@@ -11,7 +11,8 @@ and no others (`key_blocks`, `_walk`): nothing above the diagonal and, with
 that lies wholly past its row's length, which comes out as zeros.  The
 minor grid axis is the walk, bounded by its longest row's count; the causal
 mask is applied only in the blocks the diagonal crosses; the log-sum-exp is
-an output only of the call that keeps it for the backward.  With `window`
+an output only of the call that keeps it (for the backward, or to fold a
+learned sink into the softmax afterwards).  With `window`
 (a sliding-window layer: a query attends its own position and the window -
 1 before it) the walk of a query block starts at the key block its band
 starts in (`first_key_blocks`), and the blocks the band's lower edge
@@ -156,7 +157,11 @@ def band_blocks(sq: int, block_q: int = DEFAULT_BLOCK_Q,
     """The blocks a BANDED call runs at: `fit_blocks`, the key block no
     longer than the query block (a query block's band of ~block_q keys
     then lies in two key blocks; at 1,024 keys a block it read three
-    halves of what it needs)."""
+    halves of what it needs).  The band's own width does not enter: at a
+    band of 128 the walk's steps are all fixed cost, and fewer, larger
+    steps win (1 x 64 heads over 8 x 8,192 at 192 / 128, 6,144 true
+    positions, my chip run, PR 52: 512 x 512 5.78 ms, 256 x 256 6.20,
+    256 x 128 6.87, 512 x 256 7.01, 128 x 128 7.89, 512 x 128 9.44)."""
     block_q, block_k = fit_blocks(sq, sq, block_q, block_k)
     return block_q, min(block_q, block_k)
 
@@ -286,7 +291,8 @@ def _fwd_kernel(qi_ref, ki_ref, flag_ref, q_ref, k_ref, v_ref, o_ref, *refs,
 
 
 def _flash_fwd(q, k, v, lengths, sm_scale, causal, block_q, block_k,
-               keep_lse: bool, window: int | None = None):
+               keep_lse: bool, window: int | None = None,
+               name: str = "flash_fwd"):
     """q: [b, hq, sq, d]; k: [b, hkv, skv, d]; v: [b, hkv, skv, dv]
     (dv = d everywhere but latent attention's expanded path, whose keys
     are wider than its values); lengths: int32 [b] or None (every row
@@ -294,7 +300,8 @@ def _flash_fwd(q, k, v, lengths, sm_scale, causal, block_q, block_k,
     past a row's length, and lse [b, hq, sq] if `keep_lse` (the backward
     kernels' residual), else None.  `window`: a query attends its own
     position and the window - 1 before it, and the key blocks wholly
-    before that band are not walked."""
+    before that band are not walked.  `name`: the kernel's device-side
+    name (`flash_attention` names the calls with a sink apart)."""
     b, hq, sq, d = q.shape
     hkv, skv = k.shape[1], k.shape[2]
     dv = v.shape[3]
@@ -344,7 +351,7 @@ def _flash_fwd(q, k, v, lengths, sm_scale, causal, block_q, block_k,
     out, *lse = pl.pallas_call(
         functools.partial(_fwd_kernel, sm_scale=sm_scale, stride=stride,
                           **({} if window is None else {"window": window})),
-        name="flash_fwd",
+        name=name,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((b, hq, sq, dv), q.dtype)]
         + [jax.ShapeDtypeStruct((b, hq, sq, 128), jnp.float32)] * keep_lse,
@@ -610,7 +617,7 @@ _flash_forward_only.defvjp(_no_backward, _no_backward)
 def flash_attention(q, k, v, causal: bool = True, sm_scale: float | None = None,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_k: int = DEFAULT_BLOCK_K, lengths=None,
-                    window: int | None = None):
+                    window: int | None = None, sink=None):
     """Flash attention with GQA.  q: [b, sq, hq, d]; k/v: [b, skv, hkv, d];
     returns [b, sq, hq, d] (layout matches ray_tpu.ops.attention).  v may
     be [b, skv, hkv, dv] with dv != d; the result is then [b, sq, hq, dv].
@@ -620,7 +627,13 @@ def flash_attention(q, k, v, causal: bool = True, sm_scale: float | None = None,
     window: a query attends its own position and the window - 1 before
     it (causal self-attention only; the key block is then no longer than
     the query block, `band_blocks`).
-    All three are forward only (the backward kernels take one width and
+    sink: float [hq], a learned column of the softmax a head that carries
+    no value (o = sum_j e^{a_j} v_j / (e^{sink} + sum_j e^{a_j})): the
+    kernel runs without it and hands back the log-sum-exp, and the
+    output is scaled by sigmoid(lse - sink), which is the same number;
+    the kernel of such a call is named `swa_band` on the device, so a
+    trace tells a model's sink layers from its causal ones.
+    All four are forward only (the backward kernels take one width and
     whole rows): differentiating such a call raises."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
@@ -638,7 +651,13 @@ def flash_attention(q, k, v, causal: bool = True, sm_scale: float | None = None,
         raise ValueError(
             f"block sizes ({block_q}, {block_k}) do not divide seq "
             f"({qt.shape[2]}, {kt.shape[2]}); use power-of-two blocks")
-    if window is not None:
+    if sink is not None:
+        o, lse = _flash_fwd(qt, kt, vt, lengths, sm_scale, causal, block_q,
+                            block_k, keep_lse=True, window=window,
+                            name="swa_band")
+        share = jax.nn.sigmoid(lse - sink.astype(jnp.float32)[None, :, None])
+        o = (o.astype(jnp.float32) * share[..., None]).astype(o.dtype)
+    elif window is not None:
         o = _flash_forward_only(qt, kt, vt, lengths, sm_scale, causal,
                                 block_q, block_k, window)
     elif lengths is None and vt.shape[3] == qt.shape[3]:

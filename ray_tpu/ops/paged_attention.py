@@ -94,8 +94,7 @@ def _kernel(lane_ref, col_ref, page_ref, pos_ref, ts_ref,   # scalar prefetch
             q_ref, kp_ref, vp_ref, kt_ref, vt_ref,   # blocked inputs
             o_ref,                            # output
             acc_ref, m_ref, l_ref,            # scratch
-            *, page: int, maxp: int, kvh: int, rep: int, hd: int, kt: int,
-            sm_scale: float):
+            *, page: int, maxp: int, rep: int, kt: int, sm_scale: float):
     del page_ref                              # the index maps read it
     i = pl.program_id(0)
     b = lane_ref[i]
@@ -113,7 +112,7 @@ def _kernel(lane_ref, col_ref, page_ref, pos_ref, ts_ref,   # scalar prefetch
 
     def flash_update(s, v):
         """Batched flash-accumulation: s [kvh, rep, n] admitted scores,
-        v [kvh, n, hd] values — one op set for ALL heads (per-head
+        v [kvh, n, dv] values — one op set for ALL heads (per-head
         loops cost ~4x in tiny-op dispatch at rep=2 shapes)."""
         m_prev = m_ref[:, :, 0]                       # [kvh, rep]
         m_cur = jnp.maximum(m_prev, jnp.max(s, axis=2))
@@ -123,7 +122,7 @@ def _kernel(lane_ref, col_ref, page_ref, pos_ref, ts_ref,   # scalar prefetch
         pv = jax.lax.dot_general(
             p, v.astype(jnp.float32),
             (((2,), (1,)), ((0,), (0,))),             # batch kvh
-            preferred_element_type=jnp.float32)       # [kvh, rep, hd]
+            preferred_element_type=jnp.float32)       # [kvh, rep, dv]
         acc_ref[...] = acc_ref[...] * alpha[..., None] + pv
         m_ref[:, :, 0] = m_cur
 
@@ -161,9 +160,10 @@ def paged_decode_attention(q, k_pages, v_pages, k_tail, v_tail,
     """Paged + tail decode attention (READ-only on every input).
 
     q:          [B, kvh, rep, hd]   current-token queries (RoPE applied)
-    k_pages/v_pages: [n_pages, kvh, page, hd]  shared page pools
-                (rows < tail_start; loop-invariant during a block)
-    k_tail/v_tail:   [B, kvh, kt, hd]  current block's accumulated rows
+    k_pages/v_pages: [n_pages, kvh, page, hd | dv]  shared page pools
+                (rows < tail_start; loop-invariant during a block); the
+                values may be narrower than the keys (q/k 192, v 128)
+    k_tail/v_tail:   [B, kvh, kt, hd | dv]  current block's accumulated rows
                 (row j = absolute position tail_start + j; the CURRENT
                 token's K/V must already be written at pos - tail_start)
     page_table: [B, maxp] int32     page ids per slot (page 0 = trash;
@@ -175,9 +175,10 @@ def paged_decode_attention(q, k_pages, v_pages, k_tail, v_tail,
                 block's layers x steps) builds once; built here if not
                 given
 
-    Returns o [B, kvh, rep, hd]; an idle lane's rows are 0.
+    Returns o [B, kvh, rep, dv]; an idle lane's rows are 0.
     """
     B, kvh, rep, hd = q.shape
+    dv = v_pages.shape[3]
     page = k_pages.shape[2]
     kt = k_tail.shape[2]
     maxp = page_table.shape[1]
@@ -201,24 +202,24 @@ def paged_decode_attention(q, k_pages, v_pages, k_tail, v_tail,
         in_specs=[
             pl.BlockSpec((1, kvh, rep, hd), lane_map),
             pl.BlockSpec((1, kvh, page, hd), page_map),
-            pl.BlockSpec((1, kvh, page, hd), page_map),
+            pl.BlockSpec((1, kvh, page, dv), page_map),
             pl.BlockSpec((1, kvh, kt, hd), lane_map),
-            pl.BlockSpec((1, kvh, kt, hd), lane_map),
+            pl.BlockSpec((1, kvh, kt, dv), lane_map),
         ],
-        out_specs=pl.BlockSpec((1, kvh, rep, hd), lane_map),
+        out_specs=pl.BlockSpec((1, kvh, rep, dv), lane_map),
         scratch_shapes=[
-            pltpu.VMEM((kvh, rep, hd), jnp.float32),
+            pltpu.VMEM((kvh, rep, dv), jnp.float32),
             pltpu.VMEM((kvh, rep, 128), jnp.float32),
             pltpu.VMEM((kvh, rep, 128), jnp.float32),
         ],
     )
-    kernel = functools.partial(_kernel, page=page, maxp=maxp, kvh=kvh,
-                               rep=rep, hd=hd, kt=kt, sm_scale=sm_scale)
+    kernel = functools.partial(_kernel, page=page, maxp=maxp, rep=rep,
+                               kt=kt, sm_scale=sm_scale)
     o = pl.pallas_call(
         kernel,
         name="paged_attn",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, kvh, rep, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, kvh, rep, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=_interpret(),
@@ -445,7 +446,7 @@ def paged_decode_reference(q, k_pages, v_pages, k_tail, v_tail,
     ks = k_pages[page_table]            # [B, maxp, kvh, page, hd]
     vs = v_pages[page_table]
     ks = ks.transpose(0, 2, 1, 3, 4).reshape(B, kvh, maxp * page, hd)
-    vs = vs.transpose(0, 2, 1, 3, 4).reshape(B, kvh, maxp * page, hd)
+    vs = vs.transpose(0, 2, 1, 3, 4).reshape(B, kvh, maxp * page, -1)
     kpos = jnp.arange(maxp * page)[None, None, None, :]
     sp = jnp.einsum("bhrd,bhkd->bhrk", q.astype(jnp.float32),
                     ks.astype(jnp.float32)) * sm_scale

@@ -184,6 +184,35 @@ def pytest_runtest_teardown(item):
     return (yield from _limited(item, "teardown"))
 
 
+_rss_after_trim = [0]
+
+
+def _rss_bytes() -> int:
+    with open("/proc/self/statm") as f:
+        return int(f.read().split()[1]) * os.sysconf("SC_PAGE_SIZE")
+
+
+@pytest.fixture(autouse=True)
+def _hand_freed_memory_back():
+    """A compile at a cell's real widths leaves GiBs of FREED heap in
+    glibc's arenas (two `test_chip_compile.py` cases: 2.29 GiB held, 0.65
+    after `malloc_trim`), so a worker's footprint only grew: six workers
+    held 75 GiB by a whole run's seventeenth minute and the sandbox's
+    monitor ended them (PR 52).  Trims when the process has grown by a
+    GiB since the last trim; reading the size costs microseconds."""
+    yield
+    if _rss_bytes() - _rss_after_trim[0] > 1 << 30:
+        import ctypes
+        import gc
+
+        gc.collect()
+        try:
+            ctypes.CDLL("libc.so.6").malloc_trim(0)
+        except (OSError, AttributeError):       # another libc: nothing to do
+            pass
+        _rss_after_trim[0] = _rss_bytes()
+
+
 @pytest.fixture
 def ray_shared():
     """Shared local cluster (4 CPUs): initialized on first use, re-created

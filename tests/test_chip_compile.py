@@ -11,6 +11,7 @@ load the TPU library (see the on-chip-measurement guide, section 2).
 Interpret mode is steered off from here (the program's own gate sees the
 CPU backend), not through an option of ops/.
 """
+import functools
 import math
 import os
 import re
@@ -1498,3 +1499,141 @@ def test_routed_layer_moves_a_block_of_rows_and_decode_holds_no_loop(
             assert re.search(rf"bf16\[{routed.BLOCK},{width}\]", text)
     strip = min(d, routed.ACC_BYTES // (4 * positions))
     assert re.search(rf"f32\[{positions},{strip}\]\S* scatter\(", text)
+
+
+# ------------------------------------------------- MiMo-V2-Flash (PR 52)
+def test_paged_attn_compiles_at_keys_wider_than_values(one_chip,
+                                                       compiled_kernels):
+    """MiMo-V2-Flash's global layers: 64 query heads over 4 kv heads (a
+    group of 16), K pages 192 wide beside V pages of 128, 64 lanes x 18
+    columns of 512 rows; the 192-wide keys stored 256 wide, and as they
+    are."""
+    from ray_tpu.ops.paged_attention import paged_decode_attention
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    B, kvh, rep, page, kt, maxp = 64, 4, 16, 512, 8, 18
+    for dk in (256, 192):
+        low, _ = _compile(
+            functools.partial(paged_decode_attention, sm_scale=192 ** -0.5),
+            s((B, kvh, rep, dk)), s((1153, kvh, page, dk)),
+            s((1153, kvh, page, 128)), s((B, kvh, kt, dk)),
+            s((B, kvh, kt, 128)), s((B, maxp), jnp.int32),
+            s((B,), jnp.int32), s((B,), jnp.int32))
+        assert low.as_text().count("tpu_custom_call") == 1
+        assert "paged_attn" in low.as_text()
+
+
+@pytest.mark.parametrize("lanes,with_lengths", [(64, True), (8, False)])
+def test_kv_ring_kernels_compile_at_the_served_widths(
+        one_chip, compiled_kernels, lanes, with_lengths):
+    """MiMo-V2-Flash's window layers: `swa_attn` over a lane's K ring [8,
+    128, 256] (192 stored 256) and V ring [8, 128, 128] for 8 query heads a kv head with
+    the sink (the rings read where they lie: no copy of them), the row
+    write a head, and the flash forward under the band of 128 with the
+    sink (`swa_band`) at 64 heads over 8 of 192 / 128 over 8,192
+    positions."""
+    from ray_tpu.ops import flash_attention, window_attention as swa
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(q, kr, vr, k, v, pos, sink, live, lanes_, count):
+        kr = swa.kv_ring_write(kr, k, pos, live)
+        vr = swa.kv_ring_write(vr, v, pos, live)
+        o = swa.kv_ring_attention(
+            q, kr, vr, swa.ring_bias(pos, 128, 128), sink, lanes_, count,
+            sm_scale=192 ** -0.5)
+        return o, kr, vr
+
+    low = jax.jit(step, donate_argnums=(1, 2)).lower(
+        s((lanes, 8, 8, 256)), s((lanes, 8, 128, 256)),
+        s((lanes, 8, 128, 128)), s((lanes, 8, 256)), s((lanes, 8, 128)),
+        s((lanes,), jnp.int32), s((8, 8), jnp.float32),
+        s((lanes,), jnp.bool_), s((lanes,), jnp.int32), s((), jnp.int32))
+    assert low.as_text().count("tpu_custom_call") == 1
+    assert "swa_attn" in low.as_text()
+    c = low.compile()
+    assert _pool_copies(c.as_text(), lanes * 8 * 128 * 128 // 2) == []
+
+    def band(q, k, v, sink, n):
+        return flash_attention.flash_attention(
+            q, k, v, sm_scale=192 ** -0.5, window=128, sink=sink,
+            lengths=n if with_lengths else None)
+
+    low, _ = _compile(band, s((1, 8192, 64, 192)), s((1, 8192, 8, 192)),
+                      s((1, 8192, 8, 128)), s((64,), jnp.float32),
+                      s((1,), jnp.int32))
+    assert low.as_text().count("tpu_custom_call") == 1
+    assert "swa_band" in low.as_text()
+
+
+@pytest.mark.time_limit(900)
+def test_served_mimo_engine_fits_one_chip_and_copies_no_ring_or_pool(
+        topo, one_chip, compiled_kernels, monkeypatch):
+    """mimo-v2-flash-ep16 as the benchmark serves it (7 layers, 64 lanes,
+    1,153 pages): the decode program and the 1 x 8192 prefill program its
+    traffic runs compile for one chip beside weights + rings + the K and
+    V pages of the two GLOBAL layers.  The window layers hold no page:
+    their rows are the lanes' rings, which the decode program hands back
+    in the buffers they came in, written a row a head a lane a step and
+    read by `swa_attn` where they lie; neither a ring nor a pool leaf is
+    copied, in the loop or outside it."""
+    monkeypatch.setattr(jax, "devices", lambda *a: list(topo.devices))
+    cfg, eng, lows = _glm_lowerings(one_chip, [(1, 8192)],
+                                    "mimo-v2-flash-ep16")
+    lane = eng.stats()["lane_state"]
+    ring = 64 * 8 * 128                     # rows of one layer's lanes
+    assert lane["layers"] == 5
+    assert lane["by_kind"] == {"window_k": 5 * ring * 256 * 2,
+                               "window_v": 5 * ring * 128 * 2}
+    cache = eng._cache_stats()
+    # a K row is stored 256 wide (192 + 64 of zeros: whole lane tiles)
+    assert cache["by_leaf"]["k"] == {
+        "row_bytes": 4 * 256 * 2, "positions_per_row": 1, "layers": 2,
+        "pool_bytes": 2 * 1153 * 512 * 4 * 256 * 2}
+    assert cache["by_leaf"]["v"]["pool_bytes"] == 2 * 1153 * 512 * 4 * 128 * 2
+    weights = sum(math.prod(a.shape) * a.dtype.itemsize
+                  for a in jax.tree.leaves(eng.params))
+    resident = weights + lane["bytes"] + cache["pool_bytes"]
+    assert 10.7e9 < resident < 10.8e9        # 64 % of the chip
+    assert (1, 8192) in eng._prefill_programs
+    assert eng._spec.prefill_state_bytes == 5 * 128 * 6144
+    kernels = {"decode_k8": ("swa_attn", "paged_attn", "moe_gmm"),
+               "prefill_w1_p8192": ("moe_gmm", "swa_band", "flash_fwd")}
+    compiled = {}
+    for name, low in lows.items():
+        txt = low.as_text()
+        for kern in kernels[name]:
+            assert kern in txt, (name, kern)
+        compiled[name] = c = low.compile()
+        mem = c.memory_analysis()
+        held = resident + mem.temp_size_in_bytes + (
+            mem.output_size_in_bytes - mem.alias_size_in_bytes)
+        print(f"{name}: temps {mem.temp_size_in_bytes / 1e9:.2f} GB, "
+              f"resident {resident / 1e9:.2f} GB, held {held / 1e9:.2f} GB")
+        assert held < 16.9e9 - 1.5e9, (name, held)
+    c = compiled["decode_k8"]
+    hlo = c.as_text()
+    assert "while(" in hlo
+    assert _loops_of(hlo, "moe_experts") == []      # one block: no loop
+    lines = {m.group(1): ln for ln in hlo.splitlines()
+             for m in [_INSTR.match(ln)] if m}
+    # no copy of a ring (64 lanes x 8 heads x 128 rows) or of a pool leaf
+    # (1,153 pages), anywhere in the program
+    copies = _pool_copies(hlo, ring * 128 // 2)
+    assert [n for n in copies
+            if "[64,8,128," in lines[n].split("copy(")[0]
+            or "[1153," in lines[n].split("copy(")[0]] == []
+    # inside the loop the only writers of something ring-sized are the
+    # rows' writes (scatters in place)
+    found = weight_sized_writes(hlo, ring * 128)
+    assert all("ring_write" in scope for _, _, scope in found), found
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= lane["bytes"] + cache["pool_bytes"]
+    loop = _loop_lines(hlo)
+    assert len([ln for ln in loop if "custom-call(" in ln
+                and "swa_attn" in ln]) == 5        # a call a window layer
+    assert len([ln for ln in loop if "custom-call(" in ln
+                and "paged_attn" in ln]) == 2      # a call a global layer

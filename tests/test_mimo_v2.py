@@ -1,0 +1,605 @@
+"""models/mimo_v2.py (window grouped-query layers with a learned sink kept
+as a K and a V ring a lane beside global grouped-query layers of another
+kv-head count in pages, keys wider than values, rotary on a part of each
+head, routed experts without a shared one) against the plain float32
+reference the benchmark holds it to (`benchmarks/harness/refs/mimo_v2.py`,
+which imports nothing of the program): the prompt pass, paged + ring
+decode across the ring's wrap, the ENGINE's own logits with lanes reused
+(one engine run shared by the file's cases), the banded `flash_fwd` with
+the sink, the ring kernel, the rings written in place, the expert shares,
+the counters and the controls a sound comparison must fail."""
+from __future__ import annotations
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from serving_reference import served_logits  # rootdir-relative (no pkg)
+
+from benchmarks.harness.refs import mimo_v2 as ref
+from ray_tpu.models import mimo_v2, named_config, serving_model
+from ray_tpu.ops import (flash_attention, paged_attention, ssm,
+                         window_attention as swa)
+from ray_tpu.ops.attention import xla_attention
+from ray_tpu.serve.llm import LLMEngine, LLMServer
+
+# float32 weights: the served path and the reference then differ by
+# summation order alone
+CFG = dataclasses.replace(named_config("mimo-v2-debug"), dtype=jnp.float32)
+PAGE, K = 16, 4
+TOL = 5e-5
+CONTROL = 2e-3
+WINDOW, RING = CFG.window, CFG.ring_rows        # 9, 9: the least ring
+
+
+def model_of(cfg) -> dict:
+    return dict(
+        hidden_size=cfg.dim, layernorm_epsilon=cfg.norm_eps,
+        hybrid_layer_pattern=[0 if k == mimo_v2.GLOBAL else 1
+                              for k in cfg.layer_types],
+        moe_layer_freq=list(cfg.moe_layers),
+        num_attention_heads=cfg.n_heads,
+        swa_num_attention_heads=cfg.n_heads,
+        num_key_value_heads=cfg.n_kv_heads,
+        swa_num_key_value_heads=cfg.swa_n_kv_heads,
+        head_dim=cfg.qk_head_dim, swa_head_dim=cfg.qk_head_dim,
+        v_head_dim=cfg.v_head_dim, swa_v_head_dim=cfg.v_head_dim,
+        rope_theta=cfg.rope_theta, swa_rope_theta=cfg.swa_rope_theta,
+        # 8 of 24 columns turn: int(0.334 x 24) = 8, as int(0.334 x 192) = 64
+        partial_rotary_factor=0.334, sliding_window=cfg.window,
+        attention_value_scale=cfg.value_scale,
+        num_experts_per_tok=cfg.top_k, norm_topk_prob=True,
+        routed_scaling_factor=None, experts_held=list(cfg.experts_held))
+
+
+MODEL = model_of(CFG)
+
+
+def _gap(got, want) -> float:
+    got, want = np.asarray(got), np.asarray(want)
+    return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+
+
+@pytest.fixture(scope="module")
+def params():
+    return jax.jit(lambda key: mimo_v2.init_params(key, CFG))(
+        jax.random.PRNGKey(7))
+
+
+class _Jitted:
+    """The module's seam with the prompt pass and the scatter jitted (as
+    the engine runs them), looked up at the call so that a control's
+    patch is traced."""
+    project_logits = staticmethod(mimo_v2.project_logits)
+    init_paged_cache = staticmethod(mimo_v2.init_paged_cache)
+
+    @staticmethod
+    def serve_prefill(params, tokens, cfg, true_lens):
+        return jax.jit(lambda p, t, n: mimo_v2.serve_prefill(
+            p, t, cfg, n))(params, tokens, true_lens)
+
+    @staticmethod
+    def serve_scatter(cache, *args):
+        return jax.jit(lambda c, *a: mimo_v2.serve_scatter(c, *a))(
+            cache, *args)
+
+    @staticmethod
+    def serve_decode_step(*args):
+        return mimo_v2.serve_decode_step(*args)
+
+
+def _tokens(n, seed=0):
+    return np.random.default_rng(seed).integers(0, 256, n).astype(np.int32)
+
+
+REF_LEN = 56
+_REF: dict = {}
+
+
+def _ref_logits(params, seq, last=None):
+    """The reference's logits for `seq`, computed on `seq` right-padded
+    to REF_LEN (causal: the padding cannot reach a true position), so
+    that the file compiles the reference for ONE length."""
+    seq = [int(t) for t in seq]
+    key = tuple(seq)
+    if key not in _REF:
+        padded = seq + [0] * (REF_LEN - len(seq))
+        _REF[key] = np.asarray(ref.logits(params, padded, MODEL))[:len(seq)]
+    return _REF[key] if last is None else _REF[key][-last:]
+
+
+def test_the_rotary_part_is_the_published_ones():
+    assert ref.rope_dims({"partial_rotary_factor": 0.334}, 192) == 64
+    assert ref.rope_dims(MODEL, CFG.qk_head_dim) == CFG.rope_dim == 8
+    assert mimo_v2.MimoV2Config().rope_dim == 64
+
+
+# --------------------------------------------------- (a) the prompt pass
+@pytest.mark.parametrize("n", [5, WINDOW, 16, 37])
+def test_prefill_logits_equal_the_reference(params, n):
+    """5: under the window; 9: the window full for the first time; 37:
+    the band has moved on four times over."""
+    tok = _tokens(n, n)
+    h = _Jitted.serve_prefill(params, jnp.asarray(tok[None]), CFG,
+                              jnp.asarray([n], jnp.int32))[0]
+    got = mimo_v2.project_logits(params, h[0])
+    assert _gap(got, _ref_logits(params, tok)) < TOL
+
+
+@pytest.mark.parametrize("n,bucket,new", [(21, 32, 11), (3, 16, 22),
+                                          (WINDOW - 1, 16, 12)])
+def test_padded_prefill_then_paged_decode_equals_the_reference(
+        params, n, bucket, new):
+    """The prompt padded to a bucket beside a longer row, scattered into
+    the K and V pages and lane 1's rings, then decode in windows of four:
+    from 3 rows the context passes the window and the ring's wrap (9)
+    twice over while decoding; from 21 it starts past both, every step
+    overwriting the row the window has just left; from 8 the first step
+    fills the window."""
+    tok = _tokens(n + new, 3 * n)
+    got = served_logits(_Jitted, params, CFG, tok[:n], tok[n:], bucket,
+                        page=PAGE, k=K)
+    assert _gap(got, _ref_logits(params, tok, last=new + 1)) < TOL
+
+
+def test_the_prefill_hands_pages_and_rings_their_rows(params):
+    """A row of true length 21 in a bucket of 32: a global layer's K and
+    V rows are the reference's (4 and 2 kv heads: the two kinds differ),
+    and slot i of a window layer's rings holds the last position below 21
+    that is i mod 9 (12 ... 20)."""
+    tok = _tokens(32, 5)
+    lens = jnp.asarray([32, 21], jnp.int32)
+    toks = jnp.asarray(np.stack([tok, tok]))
+    _, ks, vs, state, _ = _Jitted.serve_prefill(params, toks, CFG, lens)
+    dk = CFG.qk_head_dim        # 24, stored a lane tile wide (128)
+    assert ks[0].shape[2:] == (CFG.n_kv_heads, CFG.k_store)
+    assert vs[0].shape[2:] == (CFG.n_kv_heads, CFG.v_head_dim)
+    assert state["window_k"][0].shape == (2, CFG.swa_n_kv_heads, RING,
+                                          CFG.k_store)
+    assert (CFG.k_store, mimo_v2.MimoV2Config().k_store) == (128, 256)
+    x = ref.embed(params, tok[:21], MODEL)
+    seen = {mimo_v2.GLOBAL: 0, mimo_v2.WINDOW: 0}
+    for lid, lp in enumerate(params["layers"]):
+        kind = CFG.layer_types[lid]
+        x, _, info, _ = ref.layer(x, lp, lid, MODEL)
+        i = seen[kind]
+        seen[kind] += 1
+        if kind == mimo_v2.GLOBAL:
+            assert _gap(ks[i][1, :21, :, :dk], info["k"]) < TOL
+            assert not np.asarray(ks[i][..., dk:]).any()
+            assert _gap(vs[i][1, :21], info["v"]) < TOL
+            continue
+        for name, want in (("window_k", info["k"]), ("window_v", info["v"])):
+            ring = np.asarray(state[name][i][1])           # [G, R, w]
+            w = want.shape[-1]
+            assert not ring[..., w:].any()
+            for slot in range(RING):
+                p = 20 - (20 - slot) % RING
+                assert 12 <= p <= 20 and p % RING == slot
+                assert _gap(ring[:, slot, :w], np.asarray(want)[p]) < TOL
+
+
+# ------------------------------------------------------ (b) the kernels
+def _ring_case(B=3, G=2, rep=4, R=16, dk=24, dv=16, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(ks[0], (B, G, rep, dk)),
+            jax.random.normal(ks[1], (B, G, R, dk)),
+            jax.random.normal(ks[2], (B, G, R, dv)),
+            jax.random.normal(ks[3], (G, rep)))
+
+
+@pytest.mark.parametrize("ring,window", [(16, 9), (9, 9), (128, 128)])
+def test_the_ring_kernel_equals_a_dense_masked_softmax_with_the_sink(
+        ring, window):
+    """Grouped queries over a lane's K and V rings (keys wider than
+    values), the sink a column of the denominator: against a dense
+    softmax over [live slots | sink] whose sink column carries no value;
+    positions under the window, at the wrap and far past it; the lane
+    outside the work list reads 0."""
+    q, kr, vr, sink = _ring_case(R=ring)
+    pos = jnp.asarray([3, ring, 5 * ring + 2])
+    live = jnp.asarray([True, False, True])
+    lanes, count = ssm.live_lanes(live)
+    bias = swa.ring_bias(pos, ring, window)
+    got = swa.kv_ring_attention(q, kr, vr, bias, sink, lanes, count,
+                                sm_scale=0.2)
+    s = jnp.einsum("bgrd,bgkd->bgrk", q, kr) * 0.2 + bias[:, None, None, :]
+    col = jnp.broadcast_to(sink[None, :, :, None], s.shape[:3] + (1,))
+    p = jax.nn.softmax(jnp.concatenate([s, col], -1), axis=-1)[..., :-1]
+    want = jnp.einsum("bgrk,bgkv->bgrv", p, vr)
+    assert got.shape == (3, 2, 4, 16)
+    assert not np.asarray(got[1]).any()
+    for b in (0, 2):
+        assert _gap(got[b], want[b]) < 1e-5
+    # the sink takes its share: without it the weights sum to 1
+    assert float(jnp.abs(p.sum(-1) - 1).max()) > 0.1
+    n_live = np.asarray(bias == 0.0).sum(-1)
+    assert n_live.tolist() == [4, min(ring + 1, window), window]
+
+
+def test_a_ring_row_is_written_where_the_window_left_one():
+    """The step's K row a head at slot pos mod R of the lanes that hold a
+    request; an idle lane's ring is not touched."""
+    _, kr, _, _ = _ring_case(R=9)
+    new = jnp.full((3, 2, 24), 7.0)
+    pos = jnp.asarray([4, 13, 30])
+    out = np.asarray(swa.kv_ring_write(kr, new, pos,
+                                       jnp.asarray([True, False, True])))
+    before = np.asarray(kr)
+    assert (out[1] == before[1]).all()
+    for b, slot in ((0, 4), (2, 3)):
+        assert (out[b, :, slot] == 7.0).all()
+        others = np.arange(9) != slot
+        assert (out[b][:, others] == before[b][:, others]).all()
+    rows = jnp.arange(2 * 20 * 2 * 3, dtype=jnp.float32).reshape(2, 20, 2, 3)
+    ring = np.asarray(swa.kv_ring_from_rows(rows, jnp.asarray([20, 5]), 9))
+    assert ring.shape == (2, 2, 9, 3)
+    for slot in range(9):
+        p = 19 - (19 - slot) % 9
+        assert (ring[0, :, slot] == np.asarray(rows[0, p])).all()
+    assert (ring[1, :, :5] == np.asarray(rows[1, :5]).transpose(1, 0, 2)
+            ).all() and not ring[1, :, 5:].any()
+
+
+@pytest.mark.parametrize("T,window,lens", [(512, 128, None),
+                                           (384, 128, [384, 131]),
+                                           (256, 65, [77, 256])])
+def test_the_banded_flash_kernel_with_a_sink_equals_a_masked_softmax(
+        T, window, lens):
+    """`flash_fwd` under a band of 128 with a learned sink a head, 8
+    query heads over 2 kv heads at keys wider than values (192 / 128),
+    against XLA's masked softmax with the sink as an extra column; with
+    lengths, on every row's true positions."""
+    b, H, G = (1 if lens is None else len(lens)), 8, 2
+    ks = jax.random.split(jax.random.PRNGKey(T), 4)
+    q = jax.random.normal(ks[0], (b, T, H, 192))
+    k = jax.random.normal(ks[1], (b, T, G, 192))
+    v = jax.random.normal(ks[2], (b, T, G, 128))
+    sink = jax.random.normal(ks[3], (H,))
+    got = flash_attention.flash_attention(
+        q, k, v, sm_scale=0.07, window=window, sink=sink,
+        lengths=None if lens is None else jnp.asarray(lens, jnp.int32))
+    want = xla_attention(q, k, v, sm_scale=0.07, window=window, sink=sink)
+    bare = xla_attention(q, k, v, sm_scale=0.07, window=window)
+    for row, n in enumerate(lens or [T]):
+        assert _gap(got[row, :n], want[row, :n]) < 1e-5
+        assert _gap(bare[row, :n], want[row, :n]) > 1e-2
+    bq, _ = flash_attention.band_blocks(T)
+    if lens is not None:        # query blocks wholly past a length: zeros
+        assert not np.asarray(got[0, -(-lens[0] // bq) * bq:]).any()
+
+
+def test_a_band_of_128_walks_two_key_blocks_a_query_block():
+    """The band's width does not pick the blocks (the chip was fastest at
+    the blocks a band of 513 runs at: `band_blocks`): a 1 x 8192 call
+    under a band of 128 walks 2 key blocks of 512 a query block, the
+    first 1: 31 of the causal walk's 136."""
+    assert flash_attention.band_blocks(8192) == (512, 512)
+    work, _ = flash_attention.band_work(128, [8192], 8192)
+    assert (work["prefill_swa_blocks"], work["prefill_swa_blocks_dense"]) \
+        == (31, 136)
+    short, _ = flash_attention.band_work(128, [4097], 8192)
+    assert short["prefill_swa_blocks"] == 1 + 8 * 2
+
+
+# ------------------------------------------------ (c) through the engine
+PROMPTS = (40, 3, WINDOW - 1, 1, 17)
+NEW = 14
+
+
+@pytest.fixture(scope="module")
+def served(params):
+    """ONE engine run for the file: three lanes, five prompts (under, at
+    and past the window), every logit its programs computed, its stats
+    and its rings afterwards."""
+    seen = []
+
+    def note(toks, pos, live, logits):
+        for t, p, ok, lg in zip(*map(np.asarray, (toks, pos, live, logits))):
+            if ok:
+                seen.append((int(t), int(p), lg))
+
+    step, prefill = mimo_v2.serve_decode_step, mimo_v2.serve_prefill
+
+    def decode_step(params, pages, tails, state, tokens, pos, ts, j, table,
+                    cfg, lora=None, plan=None):
+        out = step(params, pages, tails, state, tokens, pos, ts, j, table,
+                   cfg, lora, plan)
+        jax.debug.callback(note, tokens, pos,
+                           paged_attention.lanes_live(table), out[0])
+        return out
+
+    def prefill_rows(params, tokens, cfg, true_lens, lora=None):
+        out = prefill(params, tokens, cfg, true_lens, lora)
+        rows = jnp.arange(tokens.shape[0])
+        last = out[0][rows, true_lens - 1]
+        jax.debug.callback(
+            note, tokens[rows, true_lens - 1], true_lens - 1,
+            jnp.ones_like(true_lens, bool),
+            mimo_v2.project_logits(params, last).astype(jnp.float32))
+        return out
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(mimo_v2, "serve_decode_step", decode_step)
+        mp.setattr(mimo_v2, "serve_prefill", prefill_rows)
+        eng = LLMEngine(CFG, params, max_batch=3, max_len=96,
+                        page_size=PAGE, kv_pages=19, steps_per_sync=K)
+        marked = jax.tree.map(lambda a: a + 1.0, eng.cache["state"])
+        eng.cache = {**eng.cache, "state": marked}
+        before = jax.tree.map(np.asarray, marked)
+        lowered = eng._decode_fns[K].lower(
+            eng.params, eng.cache, eng._cur_dev, jnp.zeros((3,)),
+            eng._table_dev, jnp.zeros((3,), jnp.int32),
+            jnp.zeros((3,), jnp.int32), None)
+        eng.start()
+        try:
+            first = eng.generate(_tokens(9, 1).tolist(), max_new_tokens=9)
+            after_one = jax.tree.map(np.asarray, eng.cache["state"])
+            prompts = [_tokens(n, 10 + n).tolist() for n in PROMPTS]
+            futs = [eng.submit(p, max_new_tokens=NEW) for p in prompts]
+            outs = [f.result(timeout=300) for f in futs]
+            jax.effects_barrier()
+            st = eng.stats()
+        finally:
+            eng.stop()
+    by_key = {}
+    for t, p, lg in seen:
+        by_key.setdefault((t, p), []).append(lg)
+    return {"prompts": prompts, "outs": outs, "logits": by_key, "stats": st,
+            "first": first, "rings": (before, after_one),
+            "lowered": lowered}
+
+
+@pytest.mark.parametrize("i", range(len(PROMPTS)))
+def test_engine_logits_equal_the_reference_across_lane_reuse(
+        params, served, i):
+    """A lane that served one request serves another, and neither a
+    ring's rows nor a page may leak.  The LOGITS the engine's own
+    programs computed at every served position equal the reference's
+    full forward."""
+    prompt, out = served["prompts"][i], served["outs"][i]
+    seq = prompt + out["tokens"]
+    want = _ref_logits(params, seq[:-1], last=len(out["tokens"]))
+    assert len(want) == NEW
+    for j, row in enumerate(want):
+        p = len(prompt) - 1 + j
+        got = served["logits"].get((seq[p], p), [])
+        assert got, (len(prompt), j)
+        assert min(_gap(g, row) for g in got) < TOL
+
+
+def test_the_engine_counts_what_the_layers_read(served):
+    st = served["stats"]
+    assert st["completed"] == 1 + len(PROMPTS) and st["preemptions"] == 0
+    loop = st["loop"]
+    n_glob, n_win = CFG.count(mimo_v2.GLOBAL), CFG.count(mimo_v2.WINDOW)
+    steps = loop["lane_steps_live"]
+    assert loop["swa_lane_steps"] == steps * n_win
+    # the engine's own count of the rows a lane's context holds a step (a
+    # lane and not a layer: the paged kernel's two layers read it twice)
+    assert loop["swa_rows_context"] == loop["attn_ctx_rows"] * n_win
+    # under 100 %: the window bounded the work
+    assert loop["swa_rows_attended"] < loop["swa_rows_context"]
+    assert loop["swa_rows_attended"] <= steps * n_win * WINDOW
+    assert 0 < loop["prefill_swa_blocks"] <= loop["prefill_swa_blocks_dense"]
+    assert loop["prefill_attn_blocks"] > 0
+    assert loop["moe_layer_steps"] > 0 and loop["moe_assignments"] > 0
+    cache = st["cache"]
+    # window layers hold no page: a K and a V leaf a GLOBAL layer only
+    assert cache["kind"] == "kv" and set(cache["by_leaf"]) == {"k", "v"}
+    assert cache["layers"] == n_glob
+    assert cache["row_bytes"] == 4 * CFG.n_kv_heads * (
+        CFG.k_store + CFG.v_head_dim)
+    lane = st["lane_state"]
+    assert lane["layers"] == n_win
+    assert set(lane["by_kind"]) == {"window_k", "window_v"}
+    assert lane["by_kind"]["window_k"] == n_win * 3 * RING \
+        * CFG.swa_n_kv_heads * CFG.k_store * 4
+    assert lane["by_kind"]["window_v"] == n_win * 3 * RING \
+        * CFG.swa_n_kv_heads * CFG.v_head_dim * 4
+    assert lane["prefix_cache"] == "off: lane state"
+
+
+def test_the_rings_are_written_in_place(served):
+    """One request of 9 + 9 tokens in an engine of three lanes: the idle
+    lanes' rings are bit-unchanged, the live lane's were written by the
+    scatter and then a slot a step; and the decode program hands every
+    ring back in the buffer it came in (donated and aliased: no second
+    ring)."""
+    before, after = served["rings"]
+    assert len(served["first"]["tokens"]) == 9
+    for name in ("window_k", "window_v"):
+        for b, a in zip(before[name], after[name]):
+            used = [i for i in range(3) if not (a[i] == b[i]).all()]
+            assert len(used) == 1
+    text = served["lowered"].as_text()
+    n_win = CFG.count(mimo_v2.WINDOW)
+    for w in (CFG.k_store, CFG.v_head_dim):
+        ring = f"tensor<3x{CFG.swa_n_kv_heads}x{RING}x{w}xf32>"
+        # each ring is an argument that aliases an output
+        assert text.count(ring + " {tf.aliasing_output") == n_win
+
+
+# ------------------------------------------------- (d) ranges of experts
+def test_the_shares_of_a_routed_layer_add_up_to_the_uncut_layer(params):
+    """Eight chips each hold one of the router's eight experts (the
+    deployment's sixteen of 256, at the debug size); there is no shared
+    expert, so nothing is counted twice: their parts are the uncut layer
+    of the reference."""
+    lp = params["layers"][1]
+    x = jax.random.normal(jax.random.PRNGKey(5), (24, CFG.dim))
+    want, _ = ref.ff(x, lp, 1, MODEL)
+    h2 = mimo_v2.rmsnorm(x, lp["norm2"], CFG.norm_eps)
+    parts, n = 0.0, 0
+    for lo in range(8):
+        chip = dataclasses.replace(CFG, experts_held=(lo, lo + 1))
+        held = dict(lp, w13=lp["w13"][lo:lo + 1], w2=lp["w2"][lo:lo + 1])
+        y, c = mimo_v2.routed_ffn(h2, held, chip)
+        parts, n = parts + y, n + int(c[2])
+    assert float(jnp.abs(parts - want).max()) < TOL
+    assert n == 24 * CFG.top_k
+    chip = dataclasses.replace(CFG, experts_held=(2, 5))
+    held = dict(lp, w13=lp["w13"][2:5], w2=lp["w2"][2:5])
+    got, _ = mimo_v2.ffn(x, held, 1, chip)
+    want, _ = ref.ff(x, held, 1, model_of(chip))
+    assert float(jnp.abs(got - want).max()) < TOL
+
+
+# ------------------------------------------------------- (e) the controls
+def _sound(params, cfg=CFG):
+    """The served path (a padded prompt pass, the scatter, eleven decode
+    steps in windows of four) against the reference's full forward."""
+    tok = _tokens(32, 41)
+    got = served_logits(_Jitted, params, cfg, tok[:21], tok[21:], 32,
+                        page=PAGE, k=K)
+    return _gap(got, _ref_logits(params, tok, last=12))
+
+
+def _global_grouping(q, k, v, _f=mimo_v2.attention, **kw):
+    """A window layer's queries grouped as a global layer's: twice as
+    many query heads a kv head, over the first half of the kv heads."""
+    if kw.get("sink") is not None:
+        k, v = (a[:, :, :CFG.n_kv_heads] for a in (k, v))
+    return _f(q, k, v, **kw)
+
+
+CONTROLS = {
+    "sink_left_out": lambda mp: mp.setattr(
+        swa, "kv_ring_attention",
+        lambda q, k, v, bias, sink, *a, _f=swa.kv_ring_attention, **kw: _f(
+            q, k, v, bias, jnp.full_like(sink, -1e30), *a, **kw)),
+    "value_scale_left_out": lambda mp: mp.setattr(
+        mimo_v2, "scaled_out",
+        lambda o, lp, cfg, _f=mimo_v2.scaled_out: _f(
+            o, lp, dataclasses.replace(cfg, value_scale=1.0))),
+    "global_head_grouping": lambda mp: mp.setattr(
+        mimo_v2, "attention", _global_grouping),
+    "fp8_ring": lambda mp: mp.setattr(
+        swa, "kv_ring_from_rows",
+        lambda rows, *a, _f=swa.kv_ring_from_rows: _f(
+            rows.astype(jnp.float8_e4m3fn).astype(rows.dtype), *a)),
+}
+
+
+def test_the_sound_program_is_inside_the_tolerance(params):
+    assert _sound(params) < TOL
+
+
+@pytest.mark.parametrize("control", sorted(CONTROLS))
+def test_every_control_exceeds_the_tolerance(params, monkeypatch, control):
+    CONTROLS[control](monkeypatch)
+    assert _sound(params) > CONTROL
+
+
+@pytest.mark.parametrize("change", [dict(window=WINDOW - 1),
+                                    dict(window=WINDOW + 1, ring_rows=16),
+                                    dict(rope_dim=12)],
+                         ids=["window_8", "window_10", "rotary_12_of_24"])
+def test_a_changed_equation_exceeds_the_tolerance(params, change):
+    assert _sound(params, dataclasses.replace(CFG, **change)) > CONTROL
+
+
+def test_the_references_window_edge_is_the_published_one(params):
+    """The reference given another window differs from itself: the
+    judge's edge reading has something to read."""
+    x = ref.embed(params, _tokens(30, 2), MODEL)
+    lid = CFG.layer_types.index(mimo_v2.WINDOW)
+    lp = params["layers"][lid]
+    y, info = ref.mixer(x, lp, lid, MODEL)
+    assert np.asarray(info["mask"]).sum(-1).max() == WINDOW
+    for w in (WINDOW - 1, WINDOW + 1):
+        other, _ = ref.mixer(x, lp, lid, MODEL, window=w)
+        assert _gap(other, y) > CONTROL
+        assert _gap(other[:w - 1], y[:w - 1]) < 1e-6
+
+
+# ------------------------------------------------------------ (f) serving
+def test_the_seam_declares_what_the_engine_counts():
+    model = serving_model(CFG)
+    assert model is mimo_v2
+    spec = model.serving_spec(CFG)
+    assert spec.lane_state_layers == 3 and spec.routed_layers == 4
+    assert not spec.caps
+    # three window layers of 9 rows; the global layers' rows are the
+    # engine's own counter
+    assert spec.decode_work([40], 1, 16, 6)[0] == {
+        "swa_rows_context": 3 * 41, "swa_rows_attended": 3 * 9,
+        "swa_lane_steps": 3}
+    assert spec.decode_work([3, 40], 2, 16, 6)[0]["swa_rows_attended"] \
+        == 3 * (4 + 5 + 9 + 9)
+    work, shown = spec.prefill_work([9, 17], 32)
+    assert shown == {} and set(work) == {
+        "prefill_attn_blocks", "prefill_attn_blocks_dense",
+        "prefill_swa_blocks", "prefill_swa_blocks_dense"}
+    streamed, multiplied = spec.prefill_params
+    assert (streamed, multiplied) == model.prefill_params(CFG)
+    assert streamed > multiplied > 0
+    big = dataclasses.replace(
+        mimo_v2.MimoV2Config(), vocab_size=19072,
+        layer_types=mimo_v2.MimoV2Config().layer_types[:7],
+        moe_layers=mimo_v2.MimoV2Config().moe_layers[:7],
+        experts_held=(0, 16))
+    assert big.layer_types == (mimo_v2.GLOBAL,) + (mimo_v2.WINDOW,) * 4 \
+        + (mimo_v2.GLOBAL, mimo_v2.WINDOW)
+    # a lane's rings: 5 layers x 128 rows x 8 kv heads x (192 stored 256
+    # wide + 128) bf16 (the ISSUE counted 5,120 B a row at 192)
+    assert mimo_v2.serving_spec(big).prefill_state_bytes == 5 * 128 * 6144
+    assert (mimo_v2.attn_params(big, mimo_v2.GLOBAL),
+            mimo_v2.attn_params(big, mimo_v2.WINDOW)) == (89_128_960,
+                                                          94_371_840)
+    # the 1 x 8192 program: the band's 31 of its causal 136 at 512 x 512,
+    # the global layers' causal 72 of 128 at 512 x 1024
+    work, _ = mimo_v2.serving_spec(big).prefill_work([8192], 8192)
+    assert (work["prefill_swa_blocks"], work["prefill_swa_blocks_dense"],
+            work["prefill_attn_blocks"],
+            work["prefill_attn_blocks_dense"]) == (31, 136, 72, 128)
+
+
+def test_lane_state_is_served_without_the_prefix_cache(params):
+    with pytest.raises(ValueError, match="prefix_cache=True refused"):
+        LLMEngine(CFG, params, max_batch=2, max_len=64, page_size=PAGE,
+                  kv_pages=9, prefix_cache=True)
+    with pytest.raises(ValueError, match="under the window"):
+        mimo_v2.init_paged_cache(
+            dataclasses.replace(CFG, ring_rows=8), 2, 9, PAGE)
+
+
+def test_the_server_serves_the_preset_by_name():
+    srv = LLMServer("mimo-v2-debug", max_batch=2, max_len=64,
+                    page_size=PAGE, kv_pages=9, steps_per_sync=K)
+    try:
+        out = srv.engine.generate([5, 6, 7, 8, 9], max_new_tokens=6)
+        assert len(out["tokens"]) == 6
+        st = srv.engine.stats()
+        assert st["cache"]["kind"] == "kv"
+        assert set(st["lane_state"]["by_kind"]) == {"window_k", "window_v"}
+    finally:
+        srv.shutdown()
+
+
+def test_the_preset_is_served_through_serve_run():
+    """`serve.run(LLMServer)` in the node's device worker, as the
+    benchmark's replica is started: the normal path end to end."""
+    import ray_tpu
+    from ray_tpu import serve
+
+    # `ray_shared` leaves its cluster up for the next test of the process.
+    if ray_tpu.is_initialized():
+        ray_tpu.shutdown()
+    ray_tpu.init(resources={"CPU": 4, "TPU": 1})
+    try:
+        app = serve.deployment(serve.LLMServer).options(
+            name="llm", ray_actor_options={"num_tpus": 1},
+        ).bind("mimo-v2-debug", max_batch=2, max_len=64, page_size=PAGE,
+               steps_per_sync=K)
+        handle = serve.run(app, name="mimo")
+        out = handle.remote({"prompt": list(range(1, 12)),
+                             "max_new_tokens": 12}).result(timeout_s=240)
+        assert len(out["tokens"]) == 12
+        serve.delete("mimo")
+    finally:
+        serve.shutdown()
+        ray_tpu.shutdown()

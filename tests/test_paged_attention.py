@@ -76,13 +76,13 @@ def test_merge_tail_roundtrip():
 
 
 @pytest.mark.parametrize("n_rows", [6, 4], ids=["whole", "short"])
-@pytest.mark.parametrize("hd", [128, 64], ids=["hd128", "hd64"])
+@pytest.mark.parametrize("hd", [128, 64, 256], ids=["hd128", "hd64", "hd256"])
 def test_merge_tail_pages_writes_the_block_rows_and_no_other(hd, n_rows):
-    """Both scatters of merge_tail_pages (rows of a whole lane tile go in
-    place, one contiguous row an update; narrower ones as a [kvh, hd]
-    window) against a loop in numpy: a block that crosses a page edge,
-    one that ends its page, a short block whose stale columns must land
-    on the trash page, and a slot without pages."""
+    """Both scatters of merge_tail_pages (rows of whole lane tiles, one
+    or two, go in place, one contiguous row an update; narrower ones as a
+    [kvh, hd] window) against a loop in numpy: a block that crosses a
+    page edge, one that ends its page, a short block whose stale columns
+    must land on the trash page, and a slot without pages."""
     from ray_tpu.ops.paged_attention import merge_tail_pages
 
     rng = np.random.default_rng(hd + n_rows)
@@ -155,15 +155,18 @@ def _lanes(live, page, maxp, seed):
     return table, ts, n_pages
 
 
-@pytest.mark.parametrize("hd,rep", [(128, 4), (64, 6), (128, 6), (64, 4)],
+@pytest.mark.parametrize("hd,rep,dv", [(128, 4, 128), (64, 6, 64),
+                                       (128, 6, 128), (64, 4, 64),
+                                       (192, 16, 128)],
                          ids=["hd128-rep4", "hd64-rep6", "hd128-rep6",
-                              "hd64-rep4"])
+                              "hd64-rep4", "hd192-dv128-rep16"])
 @pytest.mark.parametrize("mix", list(_LIVE_MIXES))
-def test_kernel_walks_live_lanes_only(mix, hd, rep):
+def test_kernel_walks_live_lanes_only(mix, hd, rep, dv):
     """The grid is the live (lane, page) pairs: with the trash page and
     every page no live lane lists holding NaN, live lanes give the
     reference's rows and idle lanes exactly 0, wherever the idle lanes
-    sit and however far their positions ran."""
+    sit and however far their positions ran.  Values may be narrower
+    than keys (192 / 128, sixteen query heads a kv head)."""
     from ray_tpu.ops.paged_attention import (paged_decode_attention,
                                              paged_decode_reference)
 
@@ -177,8 +180,8 @@ def test_kernel_walks_live_lanes_only(mix, hd, rep):
         return rng.normal(size=shape).astype(np.float32)
 
     q, kp, vp = rand(B, kvh, rep, hd), rand(n_pages, kvh, page, hd), \
-        rand(n_pages, kvh, page, hd)
-    ktail, vtail = rand(B, kvh, kt, hd), rand(B, kvh, kt, hd)
+        rand(n_pages, kvh, page, dv)
+    ktail, vtail = rand(B, kvh, kt, hd), rand(B, kvh, kt, dv)
     pos = ts + 2
     dead = np.setdiff1d(np.arange(n_pages), table[table > 0])
     assert 0 in dead and len(dead) > 1
@@ -190,6 +193,7 @@ def test_kernel_walks_live_lanes_only(mix, hd, rep):
     # 0 x NaN is NaN: it reads the pools as they were
     want = np.asarray(paged_decode_reference(
         jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), *rest))
+    assert got.shape == (B, kvh, rep, dv)
     np.testing.assert_array_equal(got[~live], 0.0)
     np.testing.assert_array_equal(want[~live], 0.0)
     np.testing.assert_allclose(got[live], want[live], atol=1e-5)

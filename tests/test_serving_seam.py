@@ -21,7 +21,8 @@ from ray_tpu.serve import llm
 from ray_tpu.serve.llm import LLMEngine
 
 PRESETS = ("debug", "lfm2-debug", "mla-debug", "ssm-hybrid-debug",
-           "glm5-next-debug", "dots3-note-debug", "nemotron-h-debug")
+           "glm5-next-debug", "dots3-note-debug", "nemotron-h-debug",
+           "mimo-v2-debug")
 
 
 def _spec(preset):
@@ -136,6 +137,22 @@ def _code_tokens(path):
             out.append(t.string)
         prev = t.type
     return out
+
+
+def test_no_line_under_serve_names_the_eighth_family():
+    """PR 52's family (window K/V rings with a sink beside pages of
+    another kv-head count) is served by `ray_tpu/serve/` as it stood."""
+    import os
+
+    root = os.path.dirname(llm.__file__)
+    for dirpath, _, files in os.walk(root):
+        for name in files:
+            if name.endswith(".py"):
+                with open(os.path.join(dirpath, name),
+                          encoding="utf-8") as f:
+                    text = f.read().lower()
+                for word in ("mimo", "kv_ring", "swa_"):
+                    assert word not in text, (name, word)
 
 
 def test_the_engine_names_no_family_and_none_of_their_kernels():
