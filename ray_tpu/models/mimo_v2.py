@@ -86,7 +86,8 @@ from ray_tpu.models.llama import (apply_rope, embed_lookup, rmsnorm,
                                   scatter_rows)
 from ray_tpu.models.routed import route
 from ray_tpu.models.serving import ServingSpec
-from ray_tpu.ops import flash_attention, ssm, window_attention as swa
+from ray_tpu.ops import (flash_attention, live_rows, ssm,
+                         window_attention as swa)
 from ray_tpu.ops.attention import attention
 from ray_tpu.ops.paged_attention import lanes_live, paged_decode_attention
 from ray_tpu.ops.rope import rope_frequencies
@@ -202,11 +203,13 @@ def _prefill_work(cfg: MimoV2Config, true_lens, bucket: int
     """One prefill program: the global layers' causal walk
     (`prefill_attn_blocks`) and the window layers' banded walk beside the
     causal walk at its own blocks (`prefill_swa_blocks`), a layer of each
-    kind."""
+    kind; and the positions its position-wise halves compute
+    (`prefill_walked_tokens`)."""
     band, _ = flash_attention.band_work(cfg.window, true_lens, bucket)
     work, _ = flash_attention.prefill_work(true_lens, bucket)
     work.update({k: band[k] for k in flash_attention.BAND_COUNTERS})
-    return work, {}
+    walked, shown = live_rows.prefill_work(true_lens, bucket)
+    return {**work, **walked}, shown
 
 
 def serving_spec(cfg: MimoV2Config) -> ServingSpec:
@@ -223,8 +226,8 @@ def serving_spec(cfg: MimoV2Config) -> ServingSpec:
         prefill_params=prefill_params(cfg),
         routed_layers=_routed_layers(cfg),
         counters={**flash_attention.PREFILL_COUNTERS,
-                  **flash_attention.BAND_COUNTERS, **swa.COUNTERS,
-                  **routed.COUNTERS},
+                  **flash_attention.BAND_COUNTERS, **live_rows.COUNTERS,
+                  **swa.COUNTERS, **routed.COUNTERS},
         decode_work=functools.partial(_decode_work, cfg),
         prefill_work=functools.partial(_prefill_work, cfg),
         routed_work=functools.partial(routed.routed_work, cfg,
@@ -286,13 +289,20 @@ def routed_ffn(h2, lp, cfg: MimoV2Config, live=None):
 
 def ffn(x, lp, lid: int, cfg: MimoV2Config, live=None):
     """The second half of layer `lid`, what it ADDS to x [..., d], and
-    the counts of a routed layer or None.  Prefill and decode share
-    it."""
-    h = rmsnorm(x, lp["norm2"], cfg.norm_eps)
+    the counts of a routed layer or None.  Prefill and decode share it;
+    whole rows x [b, T, d] pass the dense layer's up to the last `live`
+    position (`live_rows.walk`)."""
     if not cfg.is_routed(lid):
-        with jax.named_scope("mlp"):
-            return routed.swiglu(h, lp["w1"], lp["w3"], lp["w2"],
-                                 cfg.dtype), None
+        def dense(x, _first=None):
+            h = rmsnorm(x, lp["norm2"], cfg.norm_eps)
+            with jax.named_scope("mlp"):
+                return routed.swiglu(h, lp["w1"], lp["w3"], lp["w2"],
+                                     cfg.dtype)
+        if x.ndim == 3:
+            n_live = x.shape[1] if live is None else live_rows.count(live)
+            return live_rows.walk(dense, x, n_live), None
+        return dense(x), None
+    h = rmsnorm(x, lp["norm2"], cfg.norm_eps)
     y, counts = routed_ffn(h.reshape(-1, cfg.dim), lp, cfg,
                            None if live is None else live.reshape(-1))
     return y.reshape(x.shape), counts
@@ -307,13 +317,17 @@ def partial_rope(x, cos, sin, positions, cfg: MimoV2Config):
     return jnp.concatenate([turned, x[..., cfg.rope_dim:]], axis=-1)
 
 
-def qkv(h, lp, kind: str, cfg: MimoV2Config, positions, n_pos: int):
-    """h [b, T, d] normed, positions [b, T] or None (0..T-1), `n_pos` the
-    positions the rotary tables cover -> (q [b, T, H, dk], k [b, T, G,
-    dk], both turned; v [b, T, G, dv])."""
+def qkv(h, lp, kind: str, cfg: MimoV2Config, positions, n_pos: int,
+        first=None):
+    """h [b, T, d] normed, positions [b, T] or None (`first` .. `first` +
+    T - 1; 0 .. T - 1 without one), `n_pos` the positions the rotary
+    tables cover -> (q [b, T, H, dk], k [b, T, G, dk], both turned; v [b,
+    T, G, dv])."""
     b, T, _ = h.shape
     G = cfg.kv_heads(kind)
     tables = rope_frequencies(cfg.rope_dim, n_pos, cfg.theta(kind))
+    if first is not None:
+        tables = tuple(lax.dynamic_slice_in_dim(t, first, T) for t in tables)
     with jax.named_scope("attn_qkv"):
         q = (h @ lp["wq"]).reshape(b, T, cfg.n_heads, cfg.qk_head_dim)
         k = (h @ lp["wk"]).reshape(b, T, G, cfg.qk_head_dim)
@@ -342,31 +356,53 @@ def _scale(cfg: MimoV2Config) -> float:
     return cfg.qk_head_dim ** -0.5
 
 
+def normed_qkv(x, lp, kind: str, cfg: MimoV2Config, n_live):
+    """The first position-wise half of a prefill layer over whole rows x
+    [b, T, d], up to position `n_live` (`live_rows.walk`): the norm, the
+    three products and the rotary part."""
+    n_pos = x.shape[1]
+
+    def rows(x, first):
+        h = rmsnorm(x, lp["norm1"], cfg.norm_eps)
+        return qkv(h, lp, kind, cfg, None, n_pos, first)
+
+    return live_rows.walk(rows, x, n_live)
+
+
+def scaled_out_rows(o, lp, cfg: MimoV2Config, n_live):
+    """`scaled_out` of whole rows' heads o [b, T, H, dv], up to position
+    `n_live`."""
+    return live_rows.walk(lambda o, _first: scaled_out(o, lp, cfg), o,
+                          n_live)
+
+
 def global_prefill(x, lp, cfg: MimoV2Config, true_lens):
     """The global layer's attention half over whole rows x [b, T, d]:
     (what it adds to x, (K rows [b, T, G, k_store], V rows [b, T, G,
-    dv]))."""
-    h = rmsnorm(x, lp["norm1"], cfg.norm_eps)
-    q, k, v = qkv(h, lp, GLOBAL, cfg, None, x.shape[1])
+    dv])).  What is computed a position alone stops at the longest true
+    length."""
+    n_live = jnp.max(true_lens)
+    q, k, v = normed_qkv(x, lp, GLOBAL, cfg, n_live)
     with jax.named_scope("attn_global"):
         o = attention(q, k, v, sm_scale=_scale(cfg), lengths=true_lens)
-    return scaled_out(o, lp, cfg), (stored(k, cfg), v)
+    return scaled_out_rows(o, lp, cfg, n_live), (stored(k, cfg), v)
 
 
 def window_prefill(x, lp, cfg: MimoV2Config, true_lens):
     """The window layer's attention half over whole rows x [b, T, d]
     under the band, the sink in the denominator: (what it adds to x, each
     row's (K ring [b, G, ring_rows, k_store], V ring [b, G, ring_rows,
-    dv]) at its TRUE length)."""
-    h = rmsnorm(x, lp["norm1"], cfg.norm_eps)
-    q, k, v = qkv(h, lp, WINDOW, cfg, None, x.shape[1])
+    dv]) at its TRUE length).  What is computed a position alone stops at
+    the longest true length."""
+    n_live = jnp.max(true_lens)
+    q, k, v = normed_qkv(x, lp, WINDOW, cfg, n_live)
     with jax.named_scope("attn_window"):
         o = attention(q, k, v, sm_scale=_scale(cfg), lengths=true_lens,
                       window=cfg.window, sink=lp["sink"])
     with jax.named_scope("ring_write"):
         rings = tuple(swa.kv_ring_from_rows(a, true_lens, cfg.ring_rows)
                       for a in (stored(k, cfg), v))
-    return scaled_out(o, lp, cfg), rings
+    return scaled_out_rows(o, lp, cfg, n_live), rings
 
 
 def global_decode(x, lp, k_pages, v_pages, k_tail, v_tail, page_table, pos,

@@ -6,9 +6,10 @@ cell) and of `benchmarks/tests/test_ssm_hybrid_family.py` (the
 `ssm_hybrid` family and its cell) and of
 `benchmarks/tests/test_dots3_note_family.py` (the `dots3_note` family and
 its cell), of `benchmarks/tests/test_stall_reader.py` (PR 50's four
-readers) and of `benchmarks/tests/test_mimo_v2_family.py` (the `mimo_v2`
-family and its cell), imported so that they run, and count, with `pytest
-tests/`."""
+readers), of `benchmarks/tests/test_mimo_v2_family.py` (the `mimo_v2`
+family and its cell) and of
+`benchmarks/tests/test_prefill_walked_reader.py` (PR 53's reader),
+imported so that they run, and count, with `pytest tests/`."""
 from benchmarks.harness import spec
 from benchmarks.tests import test_dots3_note_family as _dots3
 from benchmarks.tests.test_family import *  # noqa: F401,F403
@@ -17,6 +18,7 @@ from benchmarks.tests.test_ssm_hybrid_family import *  # noqa: F401,F403
 from benchmarks.tests.test_dots3_note_family import *  # noqa: F401,F403
 from benchmarks.tests.test_stall_reader import *  # noqa: F401,F403
 from benchmarks.tests.test_mimo_v2_family import *  # noqa: F401,F403
+from benchmarks.tests.test_prefill_walked_reader import *  # noqa: F401,F403
 
 
 def test_the_dots3_cell_is_found_by_its_files(dots_cell, monkeypatch):

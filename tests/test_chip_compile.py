@@ -289,8 +289,10 @@ def _holds_matmul(comps, name, seen) -> bool:
                for ln in comps[name])
 
 
-def _while_bodies(comps: dict) -> list:
-    return [b for lines in comps.values() for ln in lines
+def _while_bodies(comps: dict, scope: str = "") -> list:
+    """The bodies of the `while` instructions (under `scope`, if
+    given)."""
+    return [b for lines in comps.values() for ln in lines if scope in ln
             for b in re.findall(r"\bwhile\(.*body=%([\w.\-]+)", ln)]
 
 
@@ -321,13 +323,18 @@ def _loop_lines(hlo: str) -> list:
     return lines
 
 
-def weight_sized_writes(hlo: str, min_elems: int) -> list:
+def weight_sized_writes(hlo: str, min_elems: int, scope: str = "",
+                        shapes=None) -> list:
     """(instruction, op, scope) of every instruction in a `while` body
     (and what it calls) whose output has at least `min_elems` elements
     and is not a matmul fusion, a view, or an asynchronous prefetch that
-    keeps the layout (a DMA of the stored bytes: the one read)."""
+    keeps the layout (a DMA of the stored bytes: the one read).  With
+    `scope`, of the loops under that name alone; with `shapes` (a set of
+    dimension tuples), of the outputs that are shaped like one of them
+    alone (a loop over chunks of activations writes chunks larger than a
+    small weight: a weight is known by its dimensions)."""
     comps = _computations(hlo)
-    todo = _while_bodies(comps)
+    todo = _while_bodies(comps, scope)
     seen, found = set(), []
     while todo:
         body = todo.pop()
@@ -351,6 +358,10 @@ def weight_sized_writes(hlo: str, min_elems: int) -> list:
                 if len({layout for _, layout in big}) <= 1:
                     continue
             if not any(n >= min_elems for n, _ in arrays):
+                continue
+            if shapes is not None and not shapes & {
+                    tuple(map(int, dims.split(",")))
+                    for dims, _ in _ARRAY.findall(out) if dims}:
                 continue
             if op == "fusion" and _holds_matmul(
                     comps, re.search(r"calls=%([\w.\-]+)", ln).group(1),
@@ -1614,6 +1625,24 @@ def test_served_mimo_engine_fits_one_chip_and_copies_no_ring_or_pool(
         print(f"{name}: temps {mem.temp_size_in_bytes / 1e9:.2f} GB, "
               f"resident {resident / 1e9:.2f} GB, held {held / 1e9:.2f} GB")
         assert held < 16.9e9 - 1.5e9, (name, held)
+    # The prefill program's position-wise halves are loops over chunks
+    # of the rows (`ops/live_rows.walk`: norm + q/k/v + rotary, and value
+    # scale + `wo`, a layer; the dense layer's norm + SwiGLU), under a
+    # count the device holds.  A weight is read by its matmul and by
+    # nothing else in a body (re-laid or converted inside, it would be
+    # written once a TRIP), and the program's temporaries are a chunk's,
+    # not a row's (the straight-line program's: 2.79 GB in this compile,
+    # PERF.md section 6, PR 53).
+    c = compiled["prefill_w1_p8192"]
+    hlo = c.as_text()
+    assert len(_loops_of(hlo, "/live_rows/")) == 2 * cfg.n_layers + 1
+    weights = {a.shape for a in jax.tree.leaves(eng.params) if a.ndim >= 2}
+    assert (4096, 12288) in weights and (16384, 4096) in weights
+    smallest = min(math.prod(w) for w in weights)
+    assert weight_sized_writes(hlo, smallest, "/live_rows/", weights) == []
+    # ... while the reader does see the chunks the bodies write
+    assert weight_sized_writes(hlo, smallest, "/live_rows/")
+    assert c.memory_analysis().temp_size_in_bytes <= 1.5e9
     c = compiled["decode_k8"]
     hlo = c.as_text()
     assert "while(" in hlo
