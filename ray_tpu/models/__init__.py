@@ -20,7 +20,8 @@ _SERVING = {"LlamaConfig": "ray_tpu.models.llama",
             "Glm5NextConfig": "ray_tpu.models.glm5_next",
             "Dots3NoteConfig": "ray_tpu.models.dots3_note",
             "NemotronHConfig": "ray_tpu.models.nemotron_h",
-            "MimoV2Config": "ray_tpu.models.mimo_v2"}
+            "MimoV2Config": "ray_tpu.models.mimo_v2",
+            "Cohere2MoeConfig": "ray_tpu.models.cohere2_moe"}
 
 
 def serving_model(cfg):

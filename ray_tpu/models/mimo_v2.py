@@ -538,10 +538,7 @@ def scatter_prefill_pages(cache: dict, ks, vs, state, page_ids, row_ids,
                for name, rows in (("k", ks), ("v", vs))}
         out["pos"] = cache["pos"].at[slots].set(true_lens)
     with jax.named_scope("ring_write"):
-        out["state"] = {
-            name: [lanes.at[slots].set(new.astype(lanes.dtype))
-                   for lanes, new in zip(cache["state"][name], state[name])]
-            for name in ("window_k", "window_v")}
+        out["state"] = swa.kv_rings_scatter(cache["state"], state, slots)
     return out
 
 

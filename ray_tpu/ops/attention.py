@@ -78,14 +78,15 @@ def xla_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "impl", "sm_scale",
-                                             "window"))
+                                             "window", "band_name"))
 def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
               causal: bool = True, impl: str = "auto",
               q_offset: int | jnp.ndarray = 0,
               sm_scale: float | None = None,
               lengths: jnp.ndarray | None = None,
               window: int | None = None,
-              sink: jnp.ndarray | None = None) -> jnp.ndarray:
+              sink: jnp.ndarray | None = None,
+              band_name: str = "flash_fwd") -> jnp.ndarray:
     """Multi-head attention with GQA.
 
     impl: "auto" picks the Pallas flash kernel on TPU for long-enough
@@ -125,13 +126,14 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             _XLA_FALLBACKS[key] = _XLA_FALLBACKS.get(key, 0) + 1
     if use_flash:
         return _flash_padded(q, k, v, causal, sm_scale, lengths, window,
-                             sink)
+                             sink, band_name)
     return xla_attention(q, k, v, causal=causal, q_offset=q_offset,
                          sm_scale=sm_scale, window=window, sink=sink)
 
 
 def _flash_padded(q, k, v, causal: bool, sm_scale: float | None = None,
-                  lengths=None, window: int | None = None, sink=None):
+                  lengths=None, window: int | None = None, sink=None,
+                  band_name: str = "flash_fwd"):
     """The flash kernel at any head_dim: a width (q and k's, or v's)
     under 128 lanes is zero-padded to 128, the scale given as the TRUE
     head_dim's; wider ones go as they are (192 / 128 compiles:
@@ -142,19 +144,20 @@ def _flash_padded(q, k, v, causal: bool, sm_scale: float | None = None,
     d, dv = q.shape[-1], v.shape[-1]
     if min(d, dv) >= 128:
         return _flash_per_shard(q, k, v, causal, sm_scale, lengths, window,
-                                sink)
+                                sink, band_name)
 
     def pad(a):
         return jnp.pad(a, ((0, 0),) * 3 + ((0, max(128 - a.shape[-1], 0)),))
 
     o = _flash_per_shard(pad(q), pad(k), pad(v), causal,
                          d ** -0.5 if sm_scale is None else sm_scale,
-                         lengths, window, sink)
+                         lengths, window, sink, band_name)
     return o[..., :dv]
 
 
 def _flash_per_shard(q, k, v, causal: bool, sm_scale: float | None = None,
-                     lengths=None, window: int | None = None, sink=None):
+                     lengths=None, window: int | None = None, sink=None,
+                     band_name: str = "flash_fwd"):
     """The Pallas kernel; under an ambient multi-device mesh, one call
     per shard (jax refuses a Mosaic kernel under GSPMD at lowering:
     "wrap the call in a shard_map").  The layout — what splits over
@@ -165,7 +168,8 @@ def _flash_per_shard(q, k, v, causal: bool, sm_scale: float | None = None,
 
     fn = functools.partial(flash_attention, causal=causal,
                            sm_scale=sm_scale,
-                           **({} if window is None else {"window": window}))
+                           **({} if window is None else {
+                               "window": window, "band_name": band_name}))
     specs = attention_shard_specs(q.shape, k.shape)
     if sink is not None:
         if specs is not None:

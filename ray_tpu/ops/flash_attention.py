@@ -596,11 +596,11 @@ def _flash_vjp_fwd(q, k, v, sm_scale, causal, block_q, block_k):
 _flash.defvjp(_flash_vjp_fwd, _flash_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def _flash_forward_only(q, k, v, lengths, sm_scale, causal, block_q,
-                        block_k, window=None):
+                        block_k, window=None, name="flash_fwd"):
     return _flash_fwd(q, k, v, lengths, sm_scale, causal, block_q, block_k,
-                      keep_lse=False, window=window)[0]
+                      keep_lse=False, window=window, name=name)[0]
 
 
 def _no_backward(q, k, v, lengths, *_):
@@ -617,7 +617,8 @@ _flash_forward_only.defvjp(_no_backward, _no_backward)
 def flash_attention(q, k, v, causal: bool = True, sm_scale: float | None = None,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_k: int = DEFAULT_BLOCK_K, lengths=None,
-                    window: int | None = None, sink=None):
+                    window: int | None = None, sink=None,
+                    band_name: str = "flash_fwd"):
     """Flash attention with GQA.  q: [b, sq, hq, d]; k/v: [b, skv, hkv, d];
     returns [b, sq, hq, d] (layout matches ray_tpu.ops.attention).  v may
     be [b, skv, hkv, dv] with dv != d; the result is then [b, sq, hq, dv].
@@ -633,6 +634,9 @@ def flash_attention(q, k, v, causal: bool = True, sm_scale: float | None = None,
     output is scaled by sigmoid(lse - sink), which is the same number;
     the kernel of such a call is named `swa_band` on the device, so a
     trace tells a model's sink layers from its causal ones.
+    band_name: the device-side name of a banded call WITHOUT a sink (a
+    model whose window layers have none passes `swa_band`, to the same
+    end).
     All four are forward only (the backward kernels take one width and
     whole rows): differentiating such a call raises."""
     if sm_scale is None:
@@ -659,7 +663,7 @@ def flash_attention(q, k, v, causal: bool = True, sm_scale: float | None = None,
         o = (o.astype(jnp.float32) * share[..., None]).astype(o.dtype)
     elif window is not None:
         o = _flash_forward_only(qt, kt, vt, lengths, sm_scale, causal,
-                                block_q, block_k, window)
+                                block_q, block_k, window, band_name)
     elif lengths is None and vt.shape[3] == qt.shape[3]:
         o = _flash(qt, kt, vt, sm_scale, causal, block_q, block_k)
     else:

@@ -20,14 +20,17 @@ def rmsnorm(x: jnp.ndarray, weight: jnp.ndarray,
     return (normed * weight.astype(jnp.float32)).astype(dtype)
 
 
-def layernorm(x: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
-              eps: float = 1e-6) -> jnp.ndarray:
+def layernorm(x: jnp.ndarray, scale: jnp.ndarray,
+              bias: jnp.ndarray | None, eps: float = 1e-6) -> jnp.ndarray:
     """Pre-LN transformer norm (ViT-style models); fp32 accumulation like
-    rmsnorm, same fuse-into-neighbors rationale."""
+    rmsnorm, same fuse-into-neighbors rationale.  `bias` None: a norm
+    with a weight alone (the Cohere decoders')."""
     dtype = x.dtype
     xf = x.astype(jnp.float32)
     mean = jnp.mean(xf, axis=-1, keepdims=True)
     var = jnp.mean(jnp.square(xf - mean), axis=-1, keepdims=True)
     normed = (xf - mean) * jnp.reciprocal(jnp.sqrt(var + eps))
-    return (normed * scale.astype(jnp.float32)
-            + bias.astype(jnp.float32)).astype(dtype)
+    out = normed * scale.astype(jnp.float32)
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
+    return out.astype(dtype)

@@ -32,3 +32,17 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray,
     out = jnp.concatenate(
         [x1 * cos_s - x2 * sin_s, x2 * cos_s + x1 * sin_s], axis=-1)
     return out.astype(x.dtype)
+
+
+def half_from_interleaved(head_dim: int, heads: int = 1) -> jnp.ndarray:
+    """[heads * head_dim] int32: the columns of a projection whose heads'
+    rotary pairs NEIGHBOURS, (2i, 2i + 1) (GPT-J's form, `rope_gptj`), in
+    the order that pairs the halves (each head's even columns, then its
+    odd ones).  q and k permuted alike keep every score, and `apply_rope`
+    over the permuted columns is the interleaved rotary over the
+    published ones, permuted: a served model permutes W_q and W_k once,
+    as a checkpoint loader would, and no program strides over the
+    lanes."""
+    one = jnp.concatenate([jnp.arange(0, head_dim, 2),
+                           jnp.arange(1, head_dim, 2)])
+    return (jnp.arange(heads)[:, None] * head_dim + one[None, :]).reshape(-1)

@@ -1666,3 +1666,129 @@ def test_served_mimo_engine_fits_one_chip_and_copies_no_ring_or_pool(
                 and "swa_attn" in ln]) == 5        # a call a window layer
     assert len([ln for ln in loop if "custom-call(" in ln
                 and "paged_attn" in ln]) == 2      # a call a global layer
+
+
+# ------------------------------------------------- Command A+ (PR 54)
+@pytest.mark.parametrize("ring,lanes", [(4096, 64), (128, 8)])
+def test_the_blocked_ring_kernel_compiles_at_the_served_widths(
+        one_chip, compiled_kernels, ring, lanes):
+    """Command A+'s window layers: `swa_attn` over a lane's K and V rings
+    [8, 4096, 128], 16 query heads a kv head and no sink, walked in
+    blocks of 1,024 rows under the step's plan (a ring of 128 rows: ONE
+    block, the same kernel); the rings are read where they lie (no copy
+    of them) and written a row a head."""
+    from ray_tpu.ops import ssm, window_attention as swa
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(q, kr, vr, k, v, pos, live):
+        lanes_, count = ssm.live_lanes(live)
+        kr = swa.kv_ring_write(kr, k, pos, live)
+        vr = swa.kv_ring_write(vr, v, pos, live)
+        o = swa.kv_ring_attention(
+            q, kr, vr, swa.ring_bias(pos, ring, ring), None, lanes_, count,
+            sm_scale=128 ** -0.5)
+        return o, kr, vr
+
+    low = jax.jit(step, donate_argnums=(1, 2)).lower(
+        s((lanes, 8, 16, 128)), s((lanes, 8, ring, 128)),
+        s((lanes, 8, ring, 128)), s((lanes, 8, 128)), s((lanes, 8, 128)),
+        s((lanes,), jnp.int32), s((lanes,), jnp.bool_))
+    assert low.as_text().count("tpu_custom_call") == 1
+    assert "swa_attn" in low.as_text()
+    c = low.compile()
+    assert _pool_copies(c.as_text(), lanes * 8 * ring * 128 // 2) == []
+    assert c.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_a_band_of_4096_without_a_sink_is_named_swa_band(
+        one_chip, compiled_kernels):
+    """The flash forward under a band of 4,096 at 128 heads over 8 of 128
+    over 8,192 positions, no sink: `band_name` names the kernel."""
+    from ray_tpu.ops import flash_attention
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def band(q, k, v, n):
+        return flash_attention.flash_attention(
+            q, k, v, window=4096, lengths=n, band_name="swa_band")
+
+    low, _ = _compile(band, s((1, 8192, 128, 128)), s((1, 8192, 8, 128)),
+                      s((1, 8192, 8, 128)), s((1,), jnp.int32))
+    assert low.as_text().count("tpu_custom_call") == 1
+    assert "swa_band" in low.as_text()
+    assert "flash_fwd" not in low.as_text()
+
+
+@pytest.mark.time_limit(900)
+def test_served_command_a_plus_fits_one_chip_and_copies_no_ring_or_pool(
+        topo, one_chip, compiled_kernels, monkeypatch):
+    """command-a-plus-ep16 as the benchmark serves it (4 layers, 64 lanes,
+    1,153 pages): the decode program and the 1 x 8192 prefill program its
+    traffic runs compile for one chip beside weights + 3.2 GB of rings +
+    the K and V pages of the ONE global layer.  The window layers hold no
+    page: their rows are the lanes' rings, which the decode program hands
+    back in the buffers they came in, written a row a head a lane a step
+    and read by `swa_attn` where they lie; neither a ring nor a pool leaf
+    is copied, in the loop or outside it."""
+    monkeypatch.setattr(jax, "devices", lambda *a: list(topo.devices))
+    cfg, eng, lows = _glm_lowerings(one_chip, [(1, 8192)],
+                                    "command-a-plus-ep16")
+    lane = eng.stats()["lane_state"]
+    ring = 64 * 8 * 4096                    # rows of one layer's lanes
+    assert lane["layers"] == 3
+    assert lane["by_kind"] == {"window_k": 3 * ring * 128 * 2,
+                               "window_v": 3 * ring * 128 * 2}
+    cache = eng._cache_stats()
+    assert cache["by_leaf"]["k"] == {
+        "row_bytes": 8 * 128 * 2, "positions_per_row": 1, "layers": 1,
+        "pool_bytes": 1153 * 512 * 8 * 128 * 2}
+    weights = sum(math.prod(a.shape) * a.dtype.itemsize
+                  for a in jax.tree.leaves(eng.params))
+    resident = weights + lane["bytes"] + cache["pool_bytes"]
+    assert 11.85e9 < resident < 11.92e9      # 70 % of the chip
+    assert (1, 8192) in eng._prefill_programs
+    assert eng._spec.prefill_state_bytes == 3 * 4096 * 8 * 256 * 2
+    kernels = {"decode_k8": ("swa_attn", "paged_attn", "moe_gmm"),
+               "prefill_w1_p8192": ("moe_gmm", "swa_band", "flash_fwd")}
+    compiled = {}
+    for name, low in lows.items():
+        txt = low.as_text()
+        for kern in kernels[name]:
+            assert kern in txt, (name, kern)
+        compiled[name] = c = low.compile()
+        mem = c.memory_analysis()
+        held = resident + mem.temp_size_in_bytes + (
+            mem.output_size_in_bytes - mem.alias_size_in_bytes)
+        print(f"{name}: temps {mem.temp_size_in_bytes / 1e9:.2f} GB, "
+              f"resident {resident / 1e9:.2f} GB, held {held / 1e9:.2f} GB")
+        assert held < 16.9e9 - 1.0e9, (name, held)
+    c = compiled["prefill_w1_p8192"]
+    hlo = c.as_text()
+    # two walks a layer: norm + q/k/v + rotary, and `wo` + shared experts
+    assert len(_loops_of(hlo, "/live_rows/")) == 2 * cfg.n_layers
+    c = compiled["decode_k8"]
+    hlo = c.as_text()
+    assert "while(" in hlo
+    assert _loops_of(hlo, "moe_experts") == []      # one block: no loop
+    lines = {m.group(1): ln for ln in hlo.splitlines()
+             for m in [_INSTR.match(ln)] if m}
+    # no copy of a ring (64 lanes x 8 heads x 4,096 rows) or of a pool
+    # leaf (1,153 pages), anywhere in the program
+    copies = _pool_copies(hlo, ring * 128 // 2)
+    assert [n for n in copies
+            if "[64,8,4096," in lines[n].split("copy(")[0]
+            or "[1153," in lines[n].split("copy(")[0]] == []
+    # inside the loop the only writers of something ring-sized are the
+    # rows' writes (scatters in place)
+    found = weight_sized_writes(hlo, ring * 128)
+    assert all("ring_write" in scope for _, _, scope in found), found
+    mem = c.memory_analysis()
+    assert mem.alias_size_in_bytes >= lane["bytes"] + cache["pool_bytes"]
+    loop = _loop_lines(hlo)
+    assert len([ln for ln in loop if "custom-call(" in ln
+                and "swa_attn" in ln]) == 3        # a call a window layer
+    assert len([ln for ln in loop if "custom-call(" in ln
+                and "paged_attn" in ln]) == 1      # the global layer's

@@ -22,7 +22,7 @@ from ray_tpu.serve.llm import LLMEngine
 
 PRESETS = ("debug", "lfm2-debug", "mla-debug", "ssm-hybrid-debug",
            "glm5-next-debug", "dots3-note-debug", "nemotron-h-debug",
-           "mimo-v2-debug")
+           "mimo-v2-debug", "cohere2-moe-debug")
 
 
 def _spec(preset):
@@ -139,9 +139,15 @@ def _code_tokens(path):
     return out
 
 
-def test_no_line_under_serve_names_the_eighth_family():
+@pytest.mark.parametrize("words", [
+    ("mimo", "kv_ring", "swa_"),
+    ("cohere", "command", "ring_plan", "layernorm")],
+    ids=["eighth", "ninth"])
+def test_no_line_under_serve_names_the_newest_families(words):
     """PR 52's family (window K/V rings with a sink beside pages of
-    another kv-head count) is served by `ray_tpu/serve/` as it stood."""
+    another kv-head count) and PR 54's (a parallel block under one
+    LayerNorm, rings of 4,096 rows walked in blocks, a tied head) are
+    served by `ray_tpu/serve/` as it stood."""
     import os
 
     root = os.path.dirname(llm.__file__)
@@ -151,7 +157,7 @@ def test_no_line_under_serve_names_the_eighth_family():
                 with open(os.path.join(dirpath, name),
                           encoding="utf-8") as f:
                     text = f.read().lower()
-                for word in ("mimo", "kv_ring", "swa_"):
+                for word in words:
                     assert word not in text, (name, word)
 
 
