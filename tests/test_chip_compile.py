@@ -1313,9 +1313,25 @@ def test_served_glm_engine_fits_one_chip_and_copies_no_state_or_pool(
     assert len(scans) == 4
     assert not [ln for ln in hlo.splitlines()
                 if re.search(r"f32\[1,8192,8192\]\S* copy\(", ln)]
+    # What follows a layer's kernel or its routed loop (the output
+    # projection, the shared expert, the residual path's second half),
+    # the dense feed-forward and the final norm are loops over chunks of
+    # the rows under a count the device holds (`ops/live_rows.walk`): two
+    # a layer and one.  A weight is read by its matmul and by nothing
+    # else in a body.  The program's temporaries stand 0.2 GB over the
+    # straight-line program's (2.66 GB in this compile: PERF.md section
+    # 6, PR 55; without the barrier in `kda_prefill` they were 4.07).
+    assert len(_loops_of(hlo, "/live_rows/")) == 2 * cfg.n_layers + 1
+    shapes = {a.shape for a in jax.tree.leaves(eng.params) if a.ndim >= 2}
+    assert (8192, 4096) in shapes and (16384, 24) in shapes
+    smallest = min(math.prod(w) for w in shapes)
+    assert weight_sized_writes(hlo, smallest, "/live_rows/", shapes) == []
+    assert weight_sized_writes(hlo, smallest, "/live_rows/")
+    assert compiled["prefill_w1_p8192"].memory_analysis(
+        ).temp_size_in_bytes <= 2.9e9
     c = compiled["decode_k8"]
     hlo = c.as_text()
-    assert "while(" in hlo
+    assert "while(" in hlo and "/live_rows/" not in hlo
     assert _loops_of(hlo, "moe_experts") == []      # one block: no loop
     layer_state = 64 * 64 * 128 * 128        # one KDA layer's lanes
     # (the one other array of that size is the sparse step's gathered

@@ -10,6 +10,7 @@ sound comparison must fail."""
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +22,8 @@ from serving_reference import served_logits
 
 from benchmarks.harness.refs import glm5_next as ref
 from ray_tpu.models import glm5_next, named_config, serving_model
-from ray_tpu.ops import paged_attention, sparse_attention as dsa, ssm
+from ray_tpu.ops import (live_rows, paged_attention,
+                         sparse_attention as dsa, ssm)
 from ray_tpu.serve.llm import LLMEngine, LLMServer
 
 # float32 weights: the served path and the reference then differ by
@@ -98,6 +100,152 @@ def test_padded_prefill_then_paged_decode_equals_the_reference(
                         page=PAGE, k=K)
     want = ref.logits(params, tok, MODEL, last=new + 1)
     assert _gap(got, want) < TOL
+
+
+def _apart(got, want) -> float:
+    return float(jnp.max(jnp.abs(got.astype(jnp.float32)
+                                 - want.astype(jnp.float32))))
+
+
+class _Jitted:
+    """The module's seam with the prompt pass and the scatter jitted (as
+    the engine runs them), looked up at the call so that a patch is
+    traced."""
+    project_logits = staticmethod(glm5_next.project_logits)
+    init_paged_cache = staticmethod(glm5_next.init_paged_cache)
+    serve_decode_step = staticmethod(glm5_next.serve_decode_step)
+
+    @staticmethod
+    def serve_prefill(params, tokens, cfg, true_lens):
+        return jax.jit(lambda p, t, n: glm5_next.serve_prefill(
+            p, t, cfg, n))(params, tokens, true_lens)
+
+    @staticmethod
+    def serve_scatter(cache, *args):
+        return jax.jit(lambda c, *a: glm5_next.serve_scatter(c, *a))(
+            cache, *args)
+
+
+def _chunked(mp, chunk):
+    """The prompt pass's position-wise parts walked in chunks of `chunk`
+    positions (None: the module's own, one chunk at these sizes: the bare
+    functions, straight-line)."""
+    if chunk:
+        mp.setattr(live_rows, "walk",
+                   functools.partial(live_rows.walk, chunk=chunk))
+
+
+@pytest.mark.parametrize("n,bucket,new,chunk", [(21, 32, 11, 8),
+                                                (3, 16, 9, 5)])
+def test_walked_prefill_then_paged_decode_equals_the_reference(
+        params, monkeypatch, n, bucket, new, chunk):
+    """The same with the prompt pass's walks looped over chunks of 8
+    positions and of 5 (which divide no bucket: the last chunk is
+    clamped)."""
+    _chunked(monkeypatch, chunk)
+    tok = _tokens(n + new, 3 * n)
+    got = served_logits(_Jitted, params, CFG, tok[:n], tok[n:], bucket,
+                        page=PAGE, k=K)
+    want = ref.logits(params, tok, MODEL, last=new + 1)
+    assert _gap(got, want) < TOL
+
+
+@pytest.mark.parametrize("lens,T,chunk", [
+    ([21], 32, 8), ([13, 32], 32, 8), ([30, 11], 37, 8), ([19], 32, 5),
+    ([1, 1], 16, 8)],
+    ids=lambda v: "_".join(map(str, v)) if isinstance(v, list) else str(v))
+def test_the_walked_prefill_hands_what_the_straight_line_hands(
+        params, monkeypatch, lens, T, chunk):
+    """Everything the seam returns, for one row and for two of unequal
+    lengths, at a T the chunk divides (32 by 8) and at ones it does not
+    (37 by 8, 32 by 5): the hidden row at each last true position, the
+    latent and index rows below the true lengths, the conv rows, the KDA
+    states, `ipart` and the routed counts are the straight-line
+    program's; past the walked chunks the hidden rows are zeros."""
+    toks = jnp.asarray(np.stack([_tokens(T, 5 + i) for i in range(len(lens))]))
+    tl = jnp.asarray(lens, jnp.int32)
+    want = _Jitted.serve_prefill(params, toks, CFG, tl)
+    _chunked(monkeypatch, chunk)
+    got = _Jitted.serve_prefill(params, toks, CFG, tl)
+    g = CFG.index_pool
+    for i, n in enumerate(lens):
+        assert _gap(got[0][i, n - 1], want[0][i, n - 1]) < TOL
+        assert _gap(got[1][0][i, :n], want[1][0][i, :n]) < TOL
+        if n >= g:
+            assert _gap(got[2][0][i, :n // g], want[2][0][i, :n // g]) < TOL
+        assert _apart(got[3]["ipart"][0][i], want[3]["ipart"][0][i]) < TOL
+        for j in range(N_KDA):
+            assert _gap(got[3]["kda"][j][i], want[3]["kda"][j][i]) < TOL
+            assert _apart(got[3]["conv"][j][i], want[3]["conv"][j][i]) < TOL
+    np.testing.assert_array_equal(np.asarray(got[4]), np.asarray(want[4]))
+    done = min(T, live_rows.walked(T, max(lens), chunk))
+    assert not np.asarray(got[0][:, done:]).any()
+    assert np.asarray(want[0][:, max(lens):]).any() or max(lens) == T
+
+
+@pytest.mark.parametrize("part", ["dense", "routed", "kda", "dsa"])
+@pytest.mark.parametrize("lens,T", [([21, 9], 32), ([19], 29)],
+                         ids=["two_rows_T32", "one_row_T29"])
+def test_a_sublayers_halves_ride_in_the_walks(params, monkeypatch, part,
+                                              lens, T):
+    """A prefill layer hands the residual path's halves, written over the
+    n streams apart, to the mixer or the feed-forward (`around=`), which
+    compute the second inside their walk of the rows: the streams after
+    it are `sublayer`'s around the bare function at every live position,
+    zeros past the walked chunks; and the bare function walked alone (the
+    judge's call) is the straight line too."""
+    b = len(lens)
+    tl = jnp.asarray(lens, jnp.int32)
+    live = jnp.arange(T)[None, :] < tl[:, None]
+    X = jax.random.normal(jax.random.PRNGKey(3), (b, T, CFG.hc_mult, CFG.dim))
+    lid = {"dense": 0, "routed": 1, "kda": 0, "dsa": 1}[part]
+    lp = params["layers"][lid]
+    hp = lp["hc_ffn" if part in ("dense", "routed") else "hc_mix"]
+
+    def fn(x, **kw):
+        if part in ("dense", "routed"):
+            return glm5_next.ffn(x, lp, lid, CFG, live, **kw)
+        mixer = glm5_next.kda_prefill if part == "kda" \
+            else glm5_next.dsa_prefill
+        return mixer(x, lp, CFG, tl, **kw)
+
+    want, aux = glm5_next.sublayer(X, hp, CFG, fn)
+    _chunked(monkeypatch, 8)
+    alone, _ = glm5_next.sublayer(X, hp, CFG, fn)
+    streams = tuple(X[:, :, j] for j in range(CFG.hc_mult))
+    got, aux2 = fn(streams, around=glm5_next.mhc_halves(hp, CFG))
+    got = jnp.stack(got, axis=2)
+    done = min(T, live_rows.walked(T, max(lens), 8))
+    for i, n in enumerate(lens):
+        assert _gap(got[i, :n], want[i, :n]) < TOL
+        assert _gap(alone[i, :n], want[i, :n]) < TOL
+    assert not np.asarray(got[:, done:]).any()
+    if part == "dsa":       # latent rows, index rows below the lengths
+        for i, n in enumerate(lens):
+            assert _apart(aux2[0][i, :n], aux[0][i, :n]) < TOL
+            assert _apart(aux2[1][i, :n // 4], aux[1][i, :n // 4]) < TOL
+        aux2, aux = aux2[2], aux[2]
+    for a, w in zip(jax.tree.leaves(aux2), jax.tree.leaves(aux)):
+        assert _apart(a, w) < TOL
+
+
+@pytest.mark.parametrize("lens,bucket,want", [
+    ([6216], 8192, 7 * 1024), ([4097], 8192, 5 * 1024),
+    ([8192], 8192, 8192), ([5000, 7169], 8192, 2 * 8192),
+    ([1500], 2048, 2048), ([900], 1024, 1024), ([300, 40, 7, 7], 512,
+                                                4 * 512)])
+def test_the_prefill_work_counts_the_positions_the_walks_compute(
+        lens, bucket, want):
+    """`prefill_walked_tokens`: rows x the chunks of `live_rows.CHUNK`
+    under the longest true length for a bucket over a chunk, rows x
+    bucket at or under one."""
+    C = live_rows.CHUNK
+    work, shown = glm5_next.serving_spec(CFG).prefill_work(
+        np.asarray(lens, np.int32), bucket)
+    assert work["prefill_walked_tokens"] == want == len(lens) * (
+        -(-max(lens) // C) * C if bucket > C else bucket)
+    assert shown["walked_tokens"] == want
+    assert set(work) <= set(glm5_next.serving_spec(CFG).counters)
 
 
 def test_the_prefill_hands_the_state_at_the_true_length(params):
@@ -629,8 +777,9 @@ def test_the_seam_declares_what_the_engine_counts():
     assert spec.prefill_work([9, 17], 32) == (
         {"prefill_scan_chunks": 3 * (2 + 3),
          "prefill_scan_chunks_dense": 3 * 2 * 4,
-         "dsa_prefill_blocks": 0, "dsa_prefill_blocks_dense": 0},
-        {"scan_chunks": 15})
+         "dsa_prefill_blocks": 0, "dsa_prefill_blocks_dense": 0,
+         "prefill_walked_tokens": 2 * 32},
+        {"scan_chunks": 15, "walked_tokens": 64})
     # its prefill attention is not `flash_fwd`
     assert "prefill_attn_blocks" not in spec.counters
     streamed, multiplied = spec.prefill_params
