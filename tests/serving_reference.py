@@ -4,6 +4,8 @@ the llama, LFM2 and latent-attention tests (imported rootdir-relative, like the 
 helpers in this directory)."""
 from __future__ import annotations
 
+import copy
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -26,7 +28,37 @@ def reference_greedy(params, cfg, prompt, n_new):
     return toks[0, n:].tolist()
 
 
-def served_logits(model, params, cfg, prompt, follow, bucket, *,
+class Seam:
+    """`module`'s serving seam as `served_logits` walks it, each of its
+    three programs under ONE `jax.jit` (as the engine runs them), the
+    module's function looked up at the trace.  Kept by a file (one a
+    configuration and form), the sound cases share its compiles: a case
+    that differs from another in a true length, and not in a shape,
+    compiles nothing.  Made inside a control, after the patch, it traces
+    the patch."""
+
+    def __init__(self, module, cfg):
+        self._of = (module, cfg)
+        self.project_logits = module.project_logits
+        self.init_paged_cache = module.init_paged_cache
+        self.serve_prefill = jax.jit(
+            lambda p, t, n: module.serve_prefill(p, t, cfg, n))
+        self.serve_scatter = jax.jit(
+            lambda c, *a: module.serve_scatter(c, *a))
+        self.decode_step = jax.jit(
+            lambda *a: module.serve_decode_step(*a, cfg))
+
+    def retraced(self, *names):
+        """A copy whose named programs are traced anew, at their first
+        call (under a patch that reaches those and no other), and whose
+        other programs are this seam's compiles."""
+        fresh, new = Seam(*self._of), copy.copy(self)
+        for name in names:
+            setattr(new, name, getattr(fresh, name))
+        return new
+
+
+def served_logits(seam: Seam, params, cfg, prompt, follow, bucket, *,
                   page=16, k=4):
     """Logits of the served path at every position from the prompt's last
     on: the prompt padded to `bucket` in a wave of two rows (the other a
@@ -40,21 +72,19 @@ def served_logits(model, params, cfg, prompt, follow, bucket, *,
     toks = np.zeros((2, bucket), np.int32)
     toks[0], toks[1, :n] = other, prompt
     true_lens = jnp.asarray([bucket, n], jnp.int32)
-    h, ks, vs, state, _ = model.serve_prefill(params, jnp.asarray(toks),
-                                              cfg, true_lens)
-    out = [model.project_logits(params, h[1, n - 1])]
+    h, ks, vs, state, _ = seam.serve_prefill(params, jnp.asarray(toks),
+                                             true_lens)
+    out = [seam.project_logits(params, h[1, n - 1])]
     maxp = 4
-    cache = model.init_paged_cache(cfg, 2, 1 + 2 * maxp, page)
+    cache = seam.init_paged_cache(cfg, 2, 1 + 2 * maxp, page)
     table = np.arange(1, 1 + 2 * maxp, dtype=np.int32).reshape(2, maxp)
     cols = np.arange(bucket) // page
-    cache = model.serve_scatter(
+    cache = seam.serve_scatter(
         cache, ks, vs, state, jnp.asarray(table[:, cols]),
         jnp.tile(jnp.arange(bucket) % page, (2, 1)), jnp.arange(2),
         true_lens)
     table = jnp.asarray(table)
     follow = list(follow)
-    # traced anew in every call: a control patches what it calls
-    step = jax.jit(lambda *a: model.serve_decode_step(*a, cfg))
     for w0 in range(0, len(follow), k):
         ts = cache["pos"]
         # the pool by the leaves the model gave it, as the engine's
@@ -69,7 +99,7 @@ def served_logits(model, params, cfg, prompt, follow, bucket, *,
                                 p.dtype), pages)
         st, pos = cache["state"], ts
         for j, t in enumerate(follow[w0:w0 + k]):
-            lg, tails, st, _ = step(
+            lg, tails, st, _ = seam.decode_step(
                 params, pages, tails, st, jnp.asarray([1, t], jnp.int32),
                 pos, ts, j, table)
             out.append(lg[1])

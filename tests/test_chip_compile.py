@@ -36,12 +36,17 @@ def topo():
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
+    # and every pass on: what is read here is the compiler's own
+    # memory analysis (tests/conftest.py turns most passes off)
+    passes_off = jax.config.read("jax_disable_most_optimizations")
+    jax.config.update("jax_disable_most_optimizations", False)
     try:
         desc = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 - no TPU compiler here
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     yield desc
+    jax.config.update("jax_disable_most_optimizations", passes_off)
     jax.config.update("jax_enable_compilation_cache", prev)
     compilation_cache.reset_cache()
 

@@ -15,14 +15,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from serving_reference import served_logits  # rootdir-relative (no pkg)
+import family_contract as contract  # rootdir-relative (no pkg)
+from family_contract import gap as _gap, tokens as _tokens
+from serving_reference import Seam, served_logits
 
 from benchmarks.harness.refs import cohere2_moe as ref
 from ray_tpu.models import cohere2_moe, named_config, routed, serving_model
-from ray_tpu.ops import live_rows, paged_attention, ssm
+from ray_tpu.ops import live_rows, ssm
 from ray_tpu.ops import rope as rope_ops
 from ray_tpu.ops import window_attention as swa
-from ray_tpu.serve.llm import LLMEngine, LLMServer
+from ray_tpu.serve.llm import LLMServer
 
 # float32 weights: the served path and the reference then differ by
 # summation order alone
@@ -49,9 +51,12 @@ def model_of(cfg) -> dict:
 MODEL = model_of(CFG)
 
 
-def _gap(got, want) -> float:
-    got, want = np.asarray(got), np.asarray(want)
-    return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+# The sound program's seam, compiled once a shape for the file (true
+# lengths are arguments), and the reference at ONE length (54 is the
+# longest sequence a case reads).
+SOUND = Seam(cohere2_moe, CFG)
+_ref_logits = contract.one_length(
+    lambda p, seq: ref.logits(p, seq, MODEL), 56)
 
 
 @pytest.fixture(scope="module")
@@ -60,64 +65,42 @@ def params():
         jax.random.PRNGKey(7))
 
 
-class _Jitted:
-    """The module's seam with the prompt pass and the scatter jitted (as
-    the engine runs them)."""
-    project_logits = staticmethod(cohere2_moe.project_logits)
-    init_paged_cache = staticmethod(cohere2_moe.init_paged_cache)
-
-    @staticmethod
-    def serve_prefill(params, tokens, cfg, true_lens):
-        return jax.jit(lambda p, t, n: cohere2_moe.serve_prefill(
-            p, t, cfg, n))(params, tokens, true_lens)
-
-    @staticmethod
-    def serve_scatter(cache, *args):
-        return jax.jit(lambda c, *a: cohere2_moe.serve_scatter(c, *a))(
-            cache, *args)
-
-    @staticmethod
-    def serve_decode_step(*args):
-        return cohere2_moe.serve_decode_step(*args)
-
-
-def _tokens(n, seed=0):
-    return np.random.default_rng(seed).integers(0, 256, n).astype(np.int32)
-
-
-REF_LEN = 56
-_REF: dict = {}
-
-
-def _ref_logits(params, seq, last=None):
-    """The reference's logits for `seq`, computed on `seq` right-padded
-    to REF_LEN (causal: the padding cannot reach a true position), so
-    that the file compiles the reference for ONE length."""
-    seq = [int(t) for t in seq]
-    key = tuple(seq)
-    if key not in _REF:
-        padded = seq + [0] * (REF_LEN - len(seq))
-        _REF[key] = np.asarray(ref.logits(params, padded, MODEL))[:len(seq)]
-    return _REF[key] if last is None else _REF[key][-last:]
-
-
 # ------------------------------------- (a) the served path, the reference
-@pytest.mark.parametrize("n", [5, WINDOW, 16, 37])
-def test_prefill_logits_equal_the_reference(params, n):
-    """A prompt shorter than, equal to and longer than the window (9)."""
-    tok = _tokens(n, n)
-    h = jax.jit(lambda p, t: cohere2_moe.prefill(p, t, CFG)[0])(
-        params, jnp.asarray(tok)[None])
-    got = cohere2_moe.project_logits(params, h[0])
-    assert _gap(got, _ref_logits(params, tok)) < TOL
+PREFILL_LENS = [5, WINDOW, 16, 37]
+
+
+@pytest.fixture(scope="module")
+def prefill_rows(params):
+    """ONE prompt pass for the lengths: a row each of one program."""
+    return contract.prefill_rows(
+        SOUND, params, [_tokens(n, n) for n in PREFILL_LENS], PREFILL_LENS)
+
+
+@pytest.mark.parametrize("n", PREFILL_LENS)
+def test_prefill_logits_equal_the_reference(params, prefill_rows, n):
+    """A prompt shorter than, equal to and longer than the window (9):
+    every true position of its row against the reference's."""
+    toks, h = prefill_rows
+    i = PREFILL_LENS.index(n)
+    got = cohere2_moe.project_logits(params, h[i, :n])
+    assert _gap(got, _ref_logits(params, toks[i, :n])) < TOL
+
+
+# The prompt pass walked in chunks of 8 and of 5 positions: its own
+# program a chunk (traced under `_chunked`), the scatter and the decode
+# step the sound seam's.
+_WALKED = {chunk: SOUND.retraced("serve_prefill") for chunk in (8, 5)}
 
 
 def _chunked(mp, chunk):
     """The prompt pass looped over chunks of `chunk` positions (None: as
-    the debug sizes run it, one chunk)."""
-    if chunk is not None:
-        mp.setattr(live_rows, "walk",
-                   functools.partial(live_rows.walk, chunk=chunk))
+    the debug sizes run it, one chunk), and the seam whose prompt pass is
+    traced under it."""
+    if chunk is None:
+        return SOUND
+    mp.setattr(live_rows, "walk",
+               functools.partial(live_rows.walk, chunk=chunk))
+    return _WALKED[chunk]
 
 
 @pytest.mark.parametrize("n,bucket,new,chunk", [
@@ -138,10 +121,9 @@ def test_padded_prefill_then_paged_decode_equals_the_reference(
     first step fills the window, from 9 it wraps.  And the same with the
     prompt pass looped over chunks of 8 positions and of 5 (which divide
     no bucket: the last chunk is clamped)."""
-    _chunked(monkeypatch, chunk)
     tok = _tokens(n + new, 3 * n)
-    got = served_logits(_Jitted, params, CFG, tok[:n], tok[n:], bucket,
-                        page=PAGE, k=K)
+    got = served_logits(_chunked(monkeypatch, chunk), params, CFG, tok[:n],
+                        tok[n:], bucket, page=PAGE, k=K)
     assert _gap(got, _ref_logits(params, tok, last=new + 1)) < TOL
 
 
@@ -152,8 +134,10 @@ def test_the_prefill_hands_pages_and_rings_their_rows(params):
     n, bucket = 21, 32
     tok = np.zeros((1, bucket), np.int32)
     tok[0, :n] = _tokens(n, 5)
-    _, ks, vs, state, _ = cohere2_moe.prefill(
-        params, jnp.asarray(tok), CFG, jnp.asarray([n], jnp.int32))
+    # (the second row of the seam's two-row program of 32 positions)
+    _, ks, vs, state, _ = jax.tree.map(lambda a: a[1:], SOUND.serve_prefill(
+        params, jnp.asarray(np.concatenate([tok, tok])),
+        jnp.asarray([bucket, n], jnp.int32)))
     x = ref.embed(params, tok[0, :n], MODEL)
     seen = {"window": 0, "global": 0}
     for lid, lp in enumerate(params["layers"]):
@@ -377,65 +361,12 @@ NEW = 12
 
 @pytest.fixture(scope="module")
 def served(params):
-    """ONE engine run for the file: three lanes, five prompts (under, at
-    and past the window), every logit its programs computed, its stats
-    and its rings afterwards."""
-    seen = []
-
-    def note(toks, pos, live, logits):
-        for t, p, ok, lg in zip(*map(np.asarray, (toks, pos, live, logits))):
-            if ok:
-                seen.append((int(t), int(p), lg))
-
-    step, prefill = cohere2_moe.serve_decode_step, cohere2_moe.serve_prefill
-
-    def decode_step(params, pages, tails, state, tokens, pos, ts, j, table,
-                    cfg, lora=None, plan=None):
-        out = step(params, pages, tails, state, tokens, pos, ts, j, table,
-                   cfg, lora, plan)
-        jax.debug.callback(note, tokens, pos,
-                           paged_attention.lanes_live(table), out[0])
-        return out
-
-    def prefill_rows(params, tokens, cfg, true_lens, lora=None):
-        out = prefill(params, tokens, cfg, true_lens, lora)
-        rows = jnp.arange(tokens.shape[0])
-        last = out[0][rows, true_lens - 1]
-        jax.debug.callback(
-            note, tokens[rows, true_lens - 1], true_lens - 1,
-            jnp.ones_like(true_lens, bool),
-            cohere2_moe.project_logits(params, last).astype(jnp.float32))
-        return out
-
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(cohere2_moe, "serve_decode_step", decode_step)
-        mp.setattr(cohere2_moe, "serve_prefill", prefill_rows)
-        eng = LLMEngine(CFG, params, max_batch=3, max_len=96,
-                        page_size=PAGE, kv_pages=19, steps_per_sync=K)
-        marked = jax.tree.map(lambda a: a + 1.0, eng.cache["state"])
-        eng.cache = {**eng.cache, "state": marked}
-        before = jax.tree.map(np.asarray, marked)
-        lowered = eng._decode_fns[K].lower(
-            eng.params, eng.cache, eng._cur_dev, jnp.zeros((3,)),
-            eng._table_dev, jnp.zeros((3,), jnp.int32),
-            jnp.zeros((3,), jnp.int32), None)
-        eng.start()
-        try:
-            first = eng.generate(_tokens(9, 1).tolist(), max_new_tokens=9)
-            after_one = jax.tree.map(np.asarray, eng.cache["state"])
-            prompts = [_tokens(n, 10 + n).tolist() for n in PROMPTS]
-            futs = [eng.submit(p, max_new_tokens=NEW) for p in prompts]
-            outs = [f.result(timeout=300) for f in futs]
-            jax.effects_barrier()
-            st = eng.stats()
-        finally:
-            eng.stop()
-    by_key = {}
-    for t, p, lg in seen:
-        by_key.setdefault((t, p), []).append(lg)
-    return {"prompts": prompts, "outs": outs, "logits": by_key, "stats": st,
-            "first": first, "rings": (before, after_one),
-            "lowered": lowered}
+    """ONE engine run for the file (`family_contract.served_run`): three
+    lanes whose rings were marked, a request of 9 + 9 tokens alone, then
+    five prompts at once (under, at and past the window)."""
+    return contract.served_run(
+        cohere2_moe, CFG, params, lanes=3, kv_pages=19, page=PAGE, k=K,
+        prompts=[_tokens(n, 10 + n).tolist() for n in PROMPTS], new=NEW)
 
 
 @pytest.mark.parametrize("i", range(len(PROMPTS)))
@@ -445,15 +376,9 @@ def test_engine_logits_equal_the_reference_across_lane_reuse(
     ring's rows nor a page may leak.  The LOGITS the engine's own
     programs computed at every served position equal the reference's
     full forward."""
-    prompt, out = served["prompts"][i], served["outs"][i]
-    seq = prompt + out["tokens"]
-    want = _ref_logits(params, seq[:-1], last=len(out["tokens"]))
-    assert len(want) == NEW
-    for j, row in enumerate(want):
-        p = len(prompt) - 1 + j
-        got = served["logits"].get((seq[p], p), [])
-        assert got, (len(prompt), j)
-        assert min(_gap(g, row) for g in got) < TOL
+    seq = served["prompts"][i] + served["outs"][i]["tokens"]
+    want = _ref_logits(params, seq[:-1], last=NEW)
+    assert contract.engine_gap(served, i, want) < TOL
 
 
 def test_the_engine_counts_what_the_layers_read(served):
@@ -496,12 +421,11 @@ def test_the_rings_are_written_in_place(served):
     scatter and then a slot a step; and the decode program hands every
     ring back in the buffer it came in (donated and aliased: no second
     ring)."""
-    before, after = served["rings"]
     assert len(served["first"]["tokens"]) == 9
     for name in ("window_k", "window_v"):
-        for b, a in zip(before[name], after[name]):
-            used = [i for i in range(3) if not (a[i] == b[i]).all()]
-            assert len(used) == 1
+        for layer in range(CFG.count(cohere2_moe.WINDOW)):
+            assert len(contract.lanes_written(
+                served, lambda s: s[name][layer])) == 1
     text = served["lowered"].as_text()
     ring = f"tensor<3x{CFG.n_kv_heads}x{RING}x{CFG.head_dim}xf32>"
     # each ring, a K and a V a window layer, is an argument that aliases
@@ -534,6 +458,8 @@ def test_the_seam_declares_what_the_engine_counts():
 
 
 def test_the_server_serves_the_preset_by_name():
+    # an engine of its own: the preset as published (bfloat16), found by
+    # its name and served through `LLMServer`
     srv = LLMServer(model="cohere2-moe-debug", max_batch=2, max_len=64,
                     page_size=PAGE, kv_pages=9, steps_per_sync=K)
     try:

@@ -5,7 +5,7 @@ reference the benchmark holds it to (`benchmarks/harness/refs/
 dots3_note.py`, which imports nothing of the program): the prompt pass,
 paged decode across the ring's wrap and the selection's switch, the
 ENGINE's own logits with lanes reused (one engine run shared by the
-file's cases), the banded `flash_fwd` at dv != d, the ring written in
+file's cases: `family_contract`), the banded `flash_fwd` at dv != d, the ring written in
 place, the expert shares, the counters and the controls a sound
 comparison must fail."""
 from __future__ import annotations
@@ -17,13 +17,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import sparse_walk_cases  # rootdir-relative (no pkg)
-from serving_reference import served_logits
+import family_contract as contract  # rootdir-relative (no pkg)
+import sparse_walk_cases
+from family_contract import gap as _gap, tokens as _tokens
+from serving_reference import Seam, served_logits
 
 from benchmarks.harness.refs import dots3_note as ref
 from ray_tpu.models import dots3_note, named_config, serving_model
-from ray_tpu.ops import (flash_attention, paged_attention,
-                         sparse_attention as dsa, ssm,
+from ray_tpu.ops import (flash_attention, sparse_attention as dsa,
                          window_attention as swa)
 from ray_tpu.ops.attention import xla_attention
 from ray_tpu.serve.llm import LLMEngine, LLMServer
@@ -66,9 +67,16 @@ def model_of(cfg) -> dict:
 MODEL = model_of(CFG)
 
 
-def _gap(got, want) -> float:
-    got, want = np.asarray(got), np.asarray(want)
-    return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+# The sound program's seam, compiled once a shape for the file (true
+# lengths are arguments), and the reference at ONE length (54 is the
+# longest sequence a case reads: 40 prompt tokens and 14 served).
+SOUND = Seam(dots3_note, CFG)
+_ref_logits = contract.one_length(
+    lambda p, seq: ref.logits(p, seq, MODEL), 56)
+# `dsa.RATIO` picks the form of the DECODE step's selected attention and
+# nothing else (`dsa.walks`): the gather's seam is the sound one with a
+# decode step of its own, traced (at its first call) under RATIO 0.
+_GATHER = SOUND.retraced("decode_step")
 
 
 @pytest.fixture(scope="module")
@@ -77,58 +85,26 @@ def params():
         jax.random.PRNGKey(7))
 
 
-class _Jitted:
-    """The module's seam with the prompt pass and the scatter jitted (as
-    the engine runs them), looked up at the call so that a control's
-    patch is traced."""
-    project_logits = staticmethod(dots3_note.project_logits)
-    init_paged_cache = staticmethod(dots3_note.init_paged_cache)
-
-    @staticmethod
-    def serve_prefill(params, tokens, cfg, true_lens):
-        return jax.jit(lambda p, t, n: dots3_note.serve_prefill(
-            p, t, cfg, n))(params, tokens, true_lens)
-
-    @staticmethod
-    def serve_scatter(cache, *args):
-        return jax.jit(lambda c, *a: dots3_note.serve_scatter(c, *a))(
-            cache, *args)
-
-    @staticmethod
-    def serve_decode_step(*args):
-        return dots3_note.serve_decode_step(*args)
-
-
-def _tokens(n, seed=0):
-    return np.random.default_rng(seed).integers(0, 256, n).astype(np.int32)
-
-
-REF_LEN = 56
-_REF: dict = {}
-
-
-def _ref_logits(params, seq, last=None):
-    """The reference's logits for `seq`, computed on `seq` right-padded
-    to REF_LEN (causal: the padding cannot reach a true position), so
-    that the file compiles the reference for ONE length."""
-    seq = [int(t) for t in seq]
-    key = tuple(seq)
-    if key not in _REF:
-        padded = seq + [0] * (REF_LEN - len(seq))
-        _REF[key] = np.asarray(ref.logits(params, padded, MODEL))[:len(seq)]
-    return _REF[key] if last is None else _REF[key][-last:]
-
-
 # --------------------------------------------------- (a) the prompt pass
-@pytest.mark.parametrize("n", [5, WINDOW, 16, 37])
-def test_prefill_logits_equal_the_reference(params, n):
+PREFILL_LENS = [5, WINDOW, 16, 37]
+
+
+@pytest.fixture(scope="module")
+def prefill_rows(params):
+    """ONE prompt pass for the lengths: a row each of one program."""
+    return contract.prefill_rows(
+        SOUND, params, [_tokens(n, n) for n in PREFILL_LENS], PREFILL_LENS)
+
+
+@pytest.mark.parametrize("n", PREFILL_LENS)
+def test_prefill_logits_equal_the_reference(params, prefill_rows, n):
     """5: under the window and the selection; 9: the window full for the
-    first time; 37: the selection drops rows and the band has moved on."""
-    tok = _tokens(n, n)
-    h = _Jitted.serve_prefill(params, jnp.asarray(tok[None]), CFG,
-                              jnp.asarray([n], jnp.int32))[0]
-    got = dots3_note.project_logits(params, h[0])
-    assert _gap(got, _ref_logits(params, tok)) < TOL
+    first time; 37: the selection drops rows and the band has moved on.
+    Every true position of the row against the reference's."""
+    toks, h = prefill_rows
+    i = PREFILL_LENS.index(n)
+    got = dots3_note.project_logits(params, h[i, :n])
+    assert _gap(got, _ref_logits(params, toks[i, :n])) < TOL
 
 
 @pytest.mark.parametrize("form", ["walk", "gather"])
@@ -143,10 +119,12 @@ def test_padded_prefill_then_paged_decode_equals_the_reference(
     all three; from 8 the first step fills the window.  In both forms of
     the full layers' decode attention (the table here is narrow: the
     walk; RATIO 0: the gather a long table gets)."""
+    seam = SOUND
     if form == "gather":
         monkeypatch.setattr(dsa, "RATIO", 0)
+        seam = _GATHER
     tok = _tokens(n + new, 3 * n)
-    got = served_logits(_Jitted, params, CFG, tok[:n], tok[n:], bucket,
+    got = served_logits(seam, params, CFG, tok[:n], tok[n:], bucket,
                         page=PAGE, k=K)
     assert _gap(got, _ref_logits(params, tok, last=new + 1)) < TOL
 
@@ -158,8 +136,7 @@ def test_the_prefill_hands_pool_and_ring_their_rows(params):
     tok = _tokens(32, 5)
     lens = jnp.asarray([32, 21], jnp.int32)
     toks = jnp.asarray(np.stack([tok, tok]))
-    _, latent, index, state, _ = _Jitted.serve_prefill(params, toks, CFG,
-                                                       lens)
+    _, latent, index, state, _ = SOUND.serve_prefill(params, toks, lens)
     x = ref.embed(params, tok[:21], MODEL)
     seen = {dots3_note.FULL: 0, dots3_note.WINDOW: 0}
     for lid, lp in enumerate(params["layers"]):
@@ -283,65 +260,13 @@ NEW = 14
 
 @pytest.fixture(scope="module")
 def served(params):
-    """ONE engine run for the file: two lanes, five prompts (under, at
-    and past the window and the selection's size), every logit its
-    programs computed, its stats and its rings afterwards."""
-    seen = []
-
-    def note(toks, pos, live, logits):
-        for t, p, ok, lg in zip(*map(np.asarray, (toks, pos, live, logits))):
-            if ok:
-                seen.append((int(t), int(p), lg))
-
-    step, prefill = dots3_note.serve_decode_step, dots3_note.serve_prefill
-
-    def decode_step(params, pages, tails, state, tokens, pos, ts, j, table,
-                    cfg, lora=None, plan=None):
-        out = step(params, pages, tails, state, tokens, pos, ts, j, table,
-                   cfg, lora, plan)
-        jax.debug.callback(note, tokens, pos,
-                           paged_attention.lanes_live(table), out[0])
-        return out
-
-    def prefill_rows(params, tokens, cfg, true_lens, lora=None):
-        out = prefill(params, tokens, cfg, true_lens, lora)
-        rows = jnp.arange(tokens.shape[0])
-        last = out[0][rows, true_lens - 1]
-        jax.debug.callback(
-            note, tokens[rows, true_lens - 1], true_lens - 1,
-            jnp.ones_like(true_lens, bool),
-            dots3_note.project_logits(params, last).astype(jnp.float32))
-        return out
-
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(dots3_note, "serve_decode_step", decode_step)
-        mp.setattr(dots3_note, "serve_prefill", prefill_rows)
-        eng = LLMEngine(CFG, params, max_batch=3, max_len=96,
-                        page_size=PAGE, kv_pages=19, steps_per_sync=K)
-        marked = jax.tree.map(lambda a: a + 1.0, eng.cache["state"])
-        eng.cache = {**eng.cache, "state": marked}
-        before = jax.tree.map(np.asarray, marked)
-        lowered = eng._decode_fns[K].lower(
-            eng.params, eng.cache, eng._cur_dev, jnp.zeros((3,)),
-            eng._table_dev, jnp.zeros((3,), jnp.int32),
-            jnp.zeros((3,), jnp.int32), None)
-        eng.start()
-        try:
-            first = eng.generate(_tokens(9, 1).tolist(), max_new_tokens=9)
-            after_one = jax.tree.map(np.asarray, eng.cache["state"])
-            prompts = [_tokens(n, 10 + n).tolist() for n in PROMPTS]
-            futs = [eng.submit(p, max_new_tokens=NEW) for p in prompts]
-            outs = [f.result(timeout=300) for f in futs]
-            jax.effects_barrier()
-            st = eng.stats()
-        finally:
-            eng.stop()
-    by_key = {}
-    for t, p, lg in seen:
-        by_key.setdefault((t, p), []).append(lg)
-    return {"prompts": prompts, "outs": outs, "logits": by_key, "stats": st,
-            "first": first, "rings": (before, after_one),
-            "lowered": lowered}
+    """ONE engine run for the file (`family_contract.served_run`): three
+    lanes whose rings were marked, a request of 9 + 9 tokens alone, then
+    five prompts at once (under, at and past the window and the
+    selection's size)."""
+    return contract.served_run(
+        dots3_note, CFG, params, lanes=3, kv_pages=19, page=PAGE, k=K,
+        prompts=[_tokens(n, 10 + n).tolist() for n in PROMPTS], new=NEW)
 
 
 @pytest.mark.parametrize("i", range(len(PROMPTS)))
@@ -351,15 +276,9 @@ def test_engine_logits_equal_the_reference_across_lane_reuse(
     ring's rows nor a pool row may leak.  The LOGITS the engine's own
     programs computed at every served position equal the reference's
     full forward."""
-    prompt, out = served["prompts"][i], served["outs"][i]
-    seq = prompt + out["tokens"]
-    want = _ref_logits(params, seq[:-1], last=len(out["tokens"]))
-    assert len(want) == NEW
-    for j, row in enumerate(want):
-        p = len(prompt) - 1 + j
-        got = served["logits"].get((seq[p], p), [])
-        assert got, (len(prompt), j)
-        assert min(_gap(g, row) for g in got) < TOL
+    seq = served["prompts"][i] + served["outs"][i]["tokens"]
+    want = _ref_logits(params, seq[:-1], last=NEW)
+    assert contract.engine_gap(served, i, want) < TOL
 
 
 def test_the_engine_counts_what_the_layers_read(served):
@@ -405,11 +324,10 @@ def test_the_ring_is_written_in_place(served):
     scatter and then a slot a step; and the decode program hands every
     ring back in the buffer it came in (donated and aliased: no second
     ring)."""
-    before, after = served["rings"]
     assert len(served["first"]["tokens"]) == 9
-    for b, a in zip(before["window"], after["window"]):
-        used = [i for i in range(3) if not (a[i] == b[i]).all()]
-        assert len(used) == 1
+    for layer in range(CFG.count(dots3_note.WINDOW)):
+        assert len(contract.lanes_written(
+            served, lambda s: s["window"][layer])) == 1
     text = served["lowered"].as_text()
     n_win = CFG.count(dots3_note.WINDOW)
     ring = f"tensor<3x{RING}x{CFG.swa.row_width}xf32>"
@@ -443,15 +361,30 @@ def test_the_eight_shares_and_the_shared_expert_once_add_up(params):
 
 
 # ------------------------------------------------------- (e) the controls
-def _sound(params, cfg=CFG, model=MODEL):
+# A control changes one equation of one kind of layer, and its patch has
+# to be traced: it runs on the model cut to two layers that keep both
+# kinds (the first full layer, its selection over the dense feed-forward,
+# and the first window layer, its ring over routed experts; both gated
+# and rescaled), against the reference of the same cut.
+SHALLOW = dataclasses.replace(
+    CFG, layer_types=(dots3_note.FULL, dots3_note.WINDOW))
+_KEPT = (0, CFG.layer_types.index(dots3_note.WINDOW))
+
+
+def _sound(params, cfg=SHALLOW, seam=None):
     """The served path (a padded prompt pass, the scatter, eleven decode
-    steps in windows of four) against the reference's full forward."""
+    steps in windows of four) against the reference's full forward, on
+    the cut's layers (the whole model's under the file's seam); without a
+    `seam`, every program traced anew.  The reference is the PUBLISHED
+    model's at that depth, whatever equation `cfg` changed."""
     tok = _tokens(32, 41)
-    got = served_logits(_Jitted, params, cfg, tok[:21], tok[21:], 32,
-                        page=PAGE, k=K)
-    if model is MODEL:
+    if seam is not SOUND:
+        params = dict(params, layers=[params["layers"][i] for i in _KEPT])
+    got = served_logits(seam or Seam(dots3_note, cfg), params, cfg,
+                        tok[:21], tok[21:], 32, page=PAGE, k=K)
+    if seam is SOUND:
         return _gap(got, _ref_logits(params, tok, last=12))
-    return _gap(got, ref.logits(params, tok, model, last=12))
+    return _gap(got, ref.logits(params, tok, model_of(SHALLOW), last=12))
 
 
 def _own_not_forced(scores, pos, n_keys, group, top, own=False,
@@ -471,6 +404,9 @@ CONTROLS = {
 
 
 def test_the_sound_program_is_inside_the_tolerance(params):
+    """The whole model (the file's seam), and the cut the controls run
+    on."""
+    assert _sound(params, CFG, seam=SOUND) < TOL
     assert _sound(params) < TOL
 
 
@@ -482,7 +418,8 @@ def test_every_control_exceeds_the_tolerance(params, monkeypatch, control):
 
 @pytest.mark.parametrize("window", [WINDOW - 1, WINDOW + 1])
 def test_a_window_off_by_one_exceeds_the_tolerance(params, window):
-    assert _sound(params, dataclasses.replace(CFG, window=window)) > CONTROL
+    assert _sound(params,
+                  dataclasses.replace(SHALLOW, window=window)) > CONTROL
 
 
 def test_the_references_window_edge_is_the_published_one(params):
@@ -542,6 +479,7 @@ def test_the_seam_declares_what_the_engine_counts():
 
 
 def test_lane_state_is_served_without_the_prefix_cache(params):
+    # (an engine that is refused at construction: nothing compiles)
     with pytest.raises(ValueError, match="prefix_cache=True refused"):
         LLMEngine(CFG, params, max_batch=2, max_len=64, page_size=PAGE,
                   kv_pages=9, prefix_cache=True)
@@ -551,6 +489,8 @@ def test_lane_state_is_served_without_the_prefix_cache(params):
 
 
 def test_the_server_serves_the_preset_by_name():
+    # an engine of its own: the preset as published (bfloat16), found by
+    # its name and served through `LLMServer`
     srv = LLMServer("dots3-note-debug", max_batch=2, max_len=64,
                     page_size=PAGE, kv_pages=9, steps_per_sync=K)
     try:

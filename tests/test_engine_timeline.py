@@ -66,10 +66,13 @@ def _loop_spans(trace_id=None):
     return sorted(out, key=lambda r: r["t0"])
 
 
-@pytest.fixture
+@pytest.fixture(scope="module")
 def traced_run(small):
     """Two waves and twenty decode windows on a warm engine: the spans
-    of the engine's own trace, its root, and the engine."""
+    of the engine's own trace, its root, and the engine.  ONE run for
+    the cases that only read a sound warm run's spans and counters (they
+    change nothing of it); a case that needs an engine of its own says
+    why where it builds it."""
     from ray_tpu import tracing
 
     eng = _engine(small)                # K = 4
@@ -152,6 +155,7 @@ def test_phases_hang_off_the_engine_root_and_iter_is_monotone(traced_run):
 def test_consecutive_idle_iterations_are_one_span(small):
     from ray_tpu import tracing
 
+    # its own: an engine that is left idle, its idle spans counted from its start
     eng = _engine(small)
     eng.start()
     try:
@@ -189,6 +193,7 @@ def test_consecutive_idle_iterations_are_one_span(small):
 def test_prefill_counters_by_hand(small):
     from ray_tpu.serve.prefill_plan import FLOOR_TOKENS
 
+    # its own: the counters are those of this test's waves alone
     eng = _engine(small)        # 4 lanes: widths {1, 4}, buckets 32 ... 256
     eng.start()
     try:
@@ -241,6 +246,7 @@ def test_mixed_wave_gives_the_tokens_of_one_at_a_time(small, kind):
     prompts = [_prompt(200, 1), _prompt(20, 2), _prompt(100, 3),
                _prompt(50, 4)]
     plan, cached = "8x64,1x128,1x256", ()
+    # two of their own: one serves the prompts one at a time, the other in one mixed wave
     ref_eng = _engine(small, max_batch=8)
     ref_eng.start()
     eng = _engine(small, max_batch=8, **kw)
@@ -279,6 +285,7 @@ def test_no_compile_after_a_warmup_of_equal_rows(small):
     """`bench_warmup`'s sequence (stop, submit `w` equal prompts, start)
     for every (width, bucket) the traffic can reach, then mixed waves of
     that range: the jitted prefill programs' caches do not grow."""
+    # its own: the programs' caches are counted from a cold engine
     eng = _engine(small, max_batch=8)
     lo, hi = 33, 128
     buckets = [b for b in eng._buckets if 64 <= b <= 128]
@@ -370,6 +377,7 @@ def test_a_routed_engine_plans_over_its_own_programs_and_builds_none_after():
 
 
 def test_lane_steps_live_by_hand(small):
+    # its own: the counters are those of this test's requests alone
     eng = _engine(small)                # K = 4, 4 lanes
     eng.start()
     try:
@@ -426,6 +434,7 @@ def test_prefill_attn_blocks_are_the_programs_the_engine_dispatched(small):
     lengths."""
     from ray_tpu.ops.flash_attention import attn_blocks, fit_blocks
 
+    # its own: the dispatch spans and the counter are those of this test's waves alone
     eng = _engine(small)
     sent = []
     fwd = eng._prefill_fwd
@@ -459,6 +468,7 @@ def test_attn_steps_by_hand(small):
     and 24), so the attention kernel's grid is 3 x 2 steps a window of
     the 4 x (16 + 1) a grid over lanes and columns would walk; the
     dispatch span carries the window's steps."""
+    # its own: the counters are those of this test's one request alone
     eng = _engine(small)                # K = 4
     eng.start()
     try:
@@ -481,6 +491,7 @@ def test_counters_advance_with_tracing_off(small):
     tracing.set_enabled(False)
     try:
         tracing.clear()
+        # its own: built and run with tracing off
         eng = _engine(small)
         eng.start()
         try:
@@ -572,6 +583,7 @@ def test_a_rival_thread_shows_in_stood_time_and_in_the_ledger(small, rival):
     t = threading.Thread(target=spin if rival == "spins" else sleep,
                          name="serve-call_0", daemon=True)
     interval = sys.getswitchinterval()
+    # its own: a rival thread runs beside its loop
     eng = _engine(small)
     eng.start()
     try:
@@ -614,6 +626,7 @@ def test_the_ledger_sums_a_pool_under_its_prefix(small):
         barrier.wait(timeout=30.0)      # one task a thread of the pool
         _burn(0.03)
 
+    # its own: a pool of rival threads runs beside its loop
     eng = _engine(small)
     pool = concurrent.futures.ThreadPoolExecutor(
         max_workers=3, thread_name_prefix="serve-call")
@@ -688,6 +701,7 @@ def test_an_unwarmed_shape_is_three_build_spans_and_a_count(small):
     shape builds nothing."""
     from ray_tpu import tracing
 
+    # its own: a shape it was never warmed for is built in mid-run
     eng = _engine(small)
     eng.start()
     try:
@@ -744,6 +758,7 @@ def test_request_scoped_spans_are_what_they_were(small):
     paged_attn_roofline) read exactly these."""
     from ray_tpu import tracing
 
+    # its own: the trace was cleared, so every span is this request's
     eng = _engine(small)
     eng.start()
     try:
@@ -779,6 +794,7 @@ def test_lora_request_carries_its_adapter_on_the_prefill_span(small):
     from ray_tpu import tracing
     from ray_tpu.models import llama
 
+    # its own: an engine with LoRA slots
     eng = _engine(small, lora_slots=2, lora_rank=4)
     eng.start()
     try:
@@ -803,6 +819,7 @@ def test_operator_metrics(small):
     as Prometheus counters (the 1 Hz delta path)."""
     from ray_tpu.serve import llm
 
+    # its own: a named engine, whose metrics carry the name
     eng = _engine(small, name="timeline-metrics")
     eng.start()
     try:
@@ -949,6 +966,7 @@ def test_a_callback_that_sleeps_in_deliver_is_one_stall_held_by_the_engine(
     from ray_tpu.serve import llm
 
     monkeypatch.setattr(llm, "_stall_blocks", 0)
+    # its own: a callback stalls its deliver phase
     eng = _engine(small)
     eng.start()
     try:
@@ -1011,6 +1029,7 @@ def test_a_thread_that_keeps_the_interpreter_is_a_late_wake_held_by_it(small):
 
     # half as long again as the latest wake a sound run may show
     fn, arg = _hold_the_interpreter(1.5 * llm.LATE_WAKE_S)
+    # its own: a rival thread keeps the interpreter from it
     eng = _engine(small)
     eng.start()
     try:
@@ -1055,6 +1074,7 @@ def test_a_collection_on_a_rival_thread_is_a_gc_pause_the_stall_accounts_for(
     # is held to the host rule's constant here, not to twice a sound
     # run's latest wake, so that the heap under test stays small
     monkeypatch.setattr(llm, "LATE_WAKE_S", llm.HOST_STALL_S)
+    # its own: a rival thread collects garbage beside it
     eng = _engine(small)
     eng.start()
     gc.collect()
@@ -1102,6 +1122,7 @@ def test_a_collection_on_a_rival_thread_is_a_gc_pause_the_stall_accounts_for(
 def test_a_sound_run_of_fifty_windows_records_no_stall(small):
     from ray_tpu import tracing
 
+    # its own: fifty windows, and the stalls counted from its start
     eng = _engine(small)
     eng.start()
     try:
@@ -1131,6 +1152,7 @@ def test_a_stall_in_idle_is_a_span_and_is_not_counted(small):
     from ray_tpu.serve import llm
 
     fn, arg = _hold_the_interpreter(1.5 * llm.LATE_WAKE_S)
+    # its own: the interpreter is kept from it while it idles
     eng = _engine(small)
     eng.start()
     try:
@@ -1161,10 +1183,14 @@ def test_stall_and_gc_counters_advance_with_tracing_off(small):
     tracing.set_enabled(False)
     try:
         tracing.clear()
+        # its own: built and run with tracing off
         eng = _engine(small)
         eng.start()
         try:
             _one_wave(eng, [_prompt(40)], 9)    # warm
+            # the watcher counts a stall at its first wake after it ended
+            # (every 0.05 s): the warm-up's builds are counted before s0
+            time.sleep(0.1)
             s0 = eng.stats()["loop"]
             eng.submit(_prompt(40, 3), max_new_tokens=41, _cache_ok=False,
                        token_queue=_SleepsOnce(0.4)).result(timeout=120.0)
@@ -1197,6 +1223,7 @@ def test_the_watcher_has_a_ledger_row_and_ends_with_stop(small):
 
     assert "llm-stall-watch" in llm._THREAD_ROWS
     before = len(watchers())
+    # its own: the watcher thread is counted before its start and after its stop
     eng = _engine(small)
     assert len(watchers()) == before            # not before start()
     eng.start()

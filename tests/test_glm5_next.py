@@ -4,7 +4,8 @@ experts) against the plain float32 reference the benchmark holds it to
 (`benchmarks/harness/refs/glm5_next.py`, which imports nothing of the
 program): the prompt pass, paged decode through both pool leaves and the
 lane state past the point where the selection starts to drop rows, the
-ENGINE's own logits with lanes reused, the pooled index key written once,
+ENGINE's own logits with lanes reused (one engine run shared by the
+file's cases: `family_contract`), the pooled index key written once,
 the expert shares, the residual maps, the counters and the controls a
 sound comparison must fail."""
 from __future__ import annotations
@@ -17,8 +18,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import sparse_walk_cases  # rootdir-relative (no pkg)
-from serving_reference import served_logits
+import family_contract as contract  # rootdir-relative (no pkg)
+import sparse_walk_cases
+from family_contract import gap as _gap, tokens as _tokens
+from serving_reference import Seam, served_logits
 
 from benchmarks.harness.refs import glm5_next as ref
 from ray_tpu.models import glm5_next, named_config, serving_model
@@ -56,11 +59,12 @@ def model_of(cfg) -> dict:
 
 
 MODEL = model_of(CFG)
-
-
-def _gap(got, want) -> float:
-    got, want = np.asarray(got), np.asarray(want)
-    return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+# The sound program's seam, compiled once a shape for the file (true
+# lengths are arguments), and the reference at ONE length (54 is the
+# longest sequence a case reads: 40 prompt tokens and 14 served).
+SOUND = Seam(glm5_next, CFG)
+_ref_logits = contract.one_length(
+    lambda p, seq: ref.logits(p, seq, MODEL), 56)
 
 
 @pytest.fixture(scope="module")
@@ -68,19 +72,26 @@ def params():
     return glm5_next.init_params(jax.random.PRNGKey(7), CFG)
 
 
-def _tokens(n, seed=0):
-    return np.random.default_rng(seed).integers(0, 256, n).astype(np.int32)
-
-
 # --------------------------------------------------- (a) the prompt pass
-@pytest.mark.parametrize("n", [5, 16, 37])
-def test_prefill_logits_equal_the_reference(params, n):
+PREFILL_LENS = [5, 16, 37]
+
+
+@pytest.fixture(scope="module")
+def prefill_rows(params):
+    """ONE prompt pass for the lengths: a row each of one program."""
+    return contract.prefill_rows(
+        SOUND, params, [_tokens(n, n) for n in PREFILL_LENS], PREFILL_LENS)
+
+
+@pytest.mark.parametrize("n", PREFILL_LENS)
+def test_prefill_logits_equal_the_reference(params, prefill_rows, n):
     """37 positions: nine complete groups of which four are kept, so the
-    selection drops rows in the second half of the prompt."""
-    tok = _tokens(n, n)
-    h = glm5_next.prefill(params, jnp.asarray(tok[None]), CFG)[0]
-    got = glm5_next.project_logits(params, h[0])
-    assert _gap(got, ref.logits(params, tok, MODEL)) < TOL
+    selection drops rows in the second half of the prompt.  Every true
+    position of the row against the reference's."""
+    toks, h = prefill_rows
+    i = PREFILL_LENS.index(n)
+    got = glm5_next.project_logits(params, h[i, :n])
+    assert _gap(got, _ref_logits(params, toks[i, :n])) < TOL
 
 
 @pytest.mark.parametrize("form", ["walk", "gather"])
@@ -93,13 +104,14 @@ def test_padded_prefill_then_paged_decode_equals_the_reference(
     edges, and the context passes the selection's size.  In both forms of
     the decode step's sparse attention (the table here is narrow: the
     walk; RATIO 0: the gather a long table gets)."""
+    seam = SOUND
     if form == "gather":
         monkeypatch.setattr(dsa, "RATIO", 0)
+        seam = _GATHER
     tok = _tokens(n + new, 3 * n)
-    got = served_logits(glm5_next, params, CFG, tok[:n], tok[n:], bucket,
+    got = served_logits(seam, params, CFG, tok[:n], tok[n:], bucket,
                         page=PAGE, k=K)
-    want = ref.logits(params, tok, MODEL, last=new + 1)
-    assert _gap(got, want) < TOL
+    assert _gap(got, _ref_logits(params, tok, last=new + 1)) < TOL
 
 
 def _apart(got, want) -> float:
@@ -107,32 +119,26 @@ def _apart(got, want) -> float:
                                  - want.astype(jnp.float32))))
 
 
-class _Jitted:
-    """The module's seam with the prompt pass and the scatter jitted (as
-    the engine runs them), looked up at the call so that a patch is
-    traced."""
-    project_logits = staticmethod(glm5_next.project_logits)
-    init_paged_cache = staticmethod(glm5_next.init_paged_cache)
-    serve_decode_step = staticmethod(glm5_next.serve_decode_step)
-
-    @staticmethod
-    def serve_prefill(params, tokens, cfg, true_lens):
-        return jax.jit(lambda p, t, n: glm5_next.serve_prefill(
-            p, t, cfg, n))(params, tokens, true_lens)
-
-    @staticmethod
-    def serve_scatter(cache, *args):
-        return jax.jit(lambda c, *a: glm5_next.serve_scatter(c, *a))(
-            cache, *args)
+# `dsa.RATIO` picks the form of the DECODE step's sparse attention and
+# nothing else (`dsa.walks`): the gather's seam is the sound one with a
+# decode step of its own, traced (at its first call) under RATIO 0.
+_GATHER = SOUND.retraced("decode_step")
+# The prompt pass walked in chunks of 8 and of 5 positions: its own
+# program a chunk (traced under `_chunked`), the scatter and the decode
+# step the sound seam's.
+_WALKED = {chunk: SOUND.retraced("serve_prefill")
+           for chunk in (8, 5)}
 
 
 def _chunked(mp, chunk):
     """The prompt pass's position-wise parts walked in chunks of `chunk`
     positions (None: the module's own, one chunk at these sizes: the bare
-    functions, straight-line)."""
+    functions, straight-line).  The seam whose prompt pass is traced
+    under it."""
     if chunk:
         mp.setattr(live_rows, "walk",
                    functools.partial(live_rows.walk, chunk=chunk))
+    return _WALKED[chunk]
 
 
 @pytest.mark.parametrize("n,bucket,new,chunk", [(21, 32, 11, 8),
@@ -142,12 +148,10 @@ def test_walked_prefill_then_paged_decode_equals_the_reference(
     """The same with the prompt pass's walks looped over chunks of 8
     positions and of 5 (which divide no bucket: the last chunk is
     clamped)."""
-    _chunked(monkeypatch, chunk)
     tok = _tokens(n + new, 3 * n)
-    got = served_logits(_Jitted, params, CFG, tok[:n], tok[n:], bucket,
-                        page=PAGE, k=K)
-    want = ref.logits(params, tok, MODEL, last=new + 1)
-    assert _gap(got, want) < TOL
+    got = served_logits(_chunked(monkeypatch, chunk), params, CFG, tok[:n],
+                        tok[n:], bucket, page=PAGE, k=K)
+    assert _gap(got, _ref_logits(params, tok, last=new + 1)) < TOL
 
 
 @pytest.mark.parametrize("lens,T,chunk", [
@@ -164,9 +168,8 @@ def test_the_walked_prefill_hands_what_the_straight_line_hands(
     program's; past the walked chunks the hidden rows are zeros."""
     toks = jnp.asarray(np.stack([_tokens(T, 5 + i) for i in range(len(lens))]))
     tl = jnp.asarray(lens, jnp.int32)
-    want = _Jitted.serve_prefill(params, toks, CFG, tl)
-    _chunked(monkeypatch, chunk)
-    got = _Jitted.serve_prefill(params, toks, CFG, tl)
+    want = SOUND.serve_prefill(params, toks, tl)
+    got = _chunked(monkeypatch, chunk).serve_prefill(params, toks, tl)
     g = CFG.index_pool
     for i, n in enumerate(lens):
         assert _gap(got[0][i, n - 1], want[0][i, n - 1]) < TOL
@@ -209,11 +212,15 @@ def test_a_sublayers_halves_ride_in_the_walks(params, monkeypatch, part,
             else glm5_next.dsa_prefill
         return mixer(x, lp, CFG, tl, **kw)
 
-    want, aux = glm5_next.sublayer(X, hp, CFG, fn)
+    # each of the three a program of its own (a compile, not a dispatch
+    # an operation)
+    around = jax.jit(lambda X: glm5_next.sublayer(X, hp, CFG, fn))
+    want, aux = around(X)
     _chunked(monkeypatch, 8)
-    alone, _ = glm5_next.sublayer(X, hp, CFG, fn)
-    streams = tuple(X[:, :, j] for j in range(CFG.hc_mult))
-    got, aux2 = fn(streams, around=glm5_next.mhc_halves(hp, CFG))
+    alone, _ = jax.jit(lambda X: glm5_next.sublayer(X, hp, CFG, fn))(X)
+    got, aux2 = jax.jit(lambda X: fn(
+        tuple(X[:, :, j] for j in range(CFG.hc_mult)),
+        around=glm5_next.mhc_halves(hp, CFG)))(X)
     got = jnp.stack(got, axis=2)
     done = min(T, live_rows.walked(T, max(lens), 8))
     for i, n in enumerate(lens):
@@ -252,7 +259,7 @@ def test_the_prefill_hands_the_state_at_the_true_length(params):
     tok = _tokens(32, 5)
     lens = jnp.asarray([32, 13], jnp.int32)
     toks = jnp.asarray(np.stack([tok, tok]))
-    _, latent, index, state, _ = glm5_next.prefill(params, toks, CFG, lens)
+    _, latent, index, state, _ = SOUND.serve_prefill(params, toks, lens)
     X = ref.embed(params, tok[:13], MODEL)
     kda_i = 0
     for lid, lp in enumerate(params["layers"]):
@@ -446,7 +453,9 @@ def test_a_prompt_of_a_kernel_bucket_equals_the_reference(params):
     """128 positions: the bucket at which the prefill's sparse attention
     runs in the kernel and not in XLA."""
     tok = _tokens(128, 9)
-    h = glm5_next.prefill(params, jnp.asarray(tok[None]), CFG)[0]
+    # (without lengths: every row is the program's width long)
+    h = jax.jit(lambda p, t: glm5_next.prefill(p, t, CFG)[0])(
+        params, jnp.asarray(tok[None]))
     got = glm5_next.project_logits(params, h[0])
     assert _gap(got, ref.logits(params, tok, MODEL)) < TOL
 
@@ -474,11 +483,11 @@ def test_a_group_completing_mid_window_writes_its_pooled_key_once(params):
     tok = _tokens(n + K, 17)
     toks = jnp.asarray(np.stack([tok[:16], tok[:16]]))
     lens = jnp.asarray([16, n], jnp.int32)
-    h, latent, index, state, _ = glm5_next.prefill(params, toks, CFG, lens)
+    h, latent, index, state, _ = SOUND.serve_prefill(params, toks, lens)
     cache = glm5_next.init_paged_cache(CFG, 2, 9, PAGE)
     table = np.arange(1, 9, dtype=np.int32).reshape(2, 4)
     cols = np.arange(16) // PAGE
-    cache = glm5_next.scatter_prefill_pages(
+    cache = SOUND.serve_scatter(
         cache, latent, index, state, jnp.asarray(table[:, cols]),
         jnp.tile(jnp.arange(16) % PAGE, (2, 1)), jnp.arange(2), lens)
     before = cache["index"][0]
@@ -488,9 +497,9 @@ def test_a_group_completing_mid_window_writes_its_pooled_key_once(params):
     st, ts = cache["state"], cache["pos"]
     seen = []
     for j in range(K):
-        _, tails, st, _ = glm5_next.decode_step_paged(
+        _, tails, st, _ = SOUND.decode_step(
             params, pages, tails, st, jnp.asarray([1, int(tok[n + j])]),
-            ts + j, ts, j, jnp.asarray(table), CFG)
+            ts + j, ts, j, jnp.asarray(table))
         seen.append(np.asarray(tails["index"][0][1, 0, 0]))
     assert not seen[0].any() and not seen[1].any()      # positions 13, 14
     assert seen[2].any() and (seen[3] == seen[2]).all()  # 15 completes it
@@ -507,79 +516,40 @@ def test_a_group_completing_mid_window_writes_its_pooled_key_once(params):
 
 
 # ------------------------------------------------ (c) through the engine
-def _record_engine_logits(monkeypatch):
-    """Every logit the engine's programs compute, as they compute it."""
-    seen = []
+PROMPTS = (40, 3, 17, 1, 29)
+NEW, LANES = 14, 3
 
-    def note(toks, pos, live, logits):
-        for t, p, ok, lg in zip(*map(np.asarray, (toks, pos, live, logits))):
-            if ok:
-                seen.append((int(t), int(p), lg))
 
-    step, prefill = glm5_next.serve_decode_step, glm5_next.serve_prefill
-
-    def decode_step(params, pages, tails, state, tokens, pos, ts, j, table,
-                    cfg, lora=None, plan=None):
-        out = step(params, pages, tails, state, tokens, pos, ts, j, table,
-                   cfg, lora, plan)
-        jax.debug.callback(note, tokens, pos,
-                           paged_attention.lanes_live(table), out[0])
-        return out
-
-    def prefill_rows(params, tokens, cfg, true_lens, lora=None):
-        out = prefill(params, tokens, cfg, true_lens, lora)
-        rows = jnp.arange(tokens.shape[0])
-        last = out[0][rows, true_lens - 1]
-        jax.debug.callback(
-            note, tokens[rows, true_lens - 1], true_lens - 1,
-            jnp.ones_like(true_lens, bool),
-            glm5_next.project_logits(params, last).astype(jnp.float32))
-        return out
-
-    monkeypatch.setattr(glm5_next, "serve_decode_step", decode_step)
-    monkeypatch.setattr(glm5_next, "serve_prefill", prefill_rows)
-    return seen
+@pytest.fixture(scope="module")
+def served(params):
+    """ONE engine run for the file (`family_contract.served_run`): three
+    lanes whose state was marked, a request of 9 + 9 tokens alone, then
+    five prompts at once."""
+    return contract.served_run(
+        glm5_next, CFG, params, lanes=LANES, kv_pages=19, page=PAGE, k=K,
+        prompts=[_tokens(n, 10 + n).tolist() for n in PROMPTS], new=NEW)
 
 
 def test_engine_logits_equal_the_reference_across_lane_reuse(
-        params, monkeypatch):
-    """Two lanes, five prompts: a lane that served one request serves
-    another, and neither the KDA state, the incomplete group's sum nor a
-    pool row may leak.  The LOGITS the engine's own programs computed at
-    every served position equal the reference's full forward; the
-    counters equal the host arithmetic they stand for."""
-    seen = _record_engine_logits(monkeypatch)
-    eng = LLMEngine(CFG, params, max_batch=2, max_len=96, page_size=PAGE,
-                    kv_pages=13, steps_per_sync=K)
-    eng.start()
-    try:
-        prompts = [_tokens(n, 10 + n).tolist() for n in (40, 3, 17, 1, 29)]
-        futs = [eng.submit(p, max_new_tokens=14) for p in prompts]
-        outs = [f.result(timeout=300) for f in futs]
-        jax.effects_barrier()
-        st = eng.stats()
-    finally:
-        eng.stop()
-    assert st["completed"] == 5 and st["preemptions"] == 0
-    by_key = {}
-    for t, p, lg in seen:
-        by_key.setdefault((t, p), []).append(lg)
-    checked = 0
-    for prompt, out in zip(prompts, outs):
-        seq = prompt + out["tokens"]
-        want = np.asarray(ref.logits(params, seq[:-1], MODEL,
-                                     last=len(out["tokens"])))
-        for i, row in enumerate(want):
-            p = len(prompt) - 1 + i
-            got = by_key.get((seq[p], p), [])
-            assert got, (len(prompt), i)
-            assert min(_gap(g, row) for g in got) < TOL
-            checked += 1
-    assert checked == 5 * 14
+        params, served):
+    """Three lanes, a request and then five prompts: a lane that served
+    one request serves another, and neither the KDA state, the incomplete
+    group's sum nor a pool row may leak.  The LOGITS the engine's own
+    programs computed at every served position equal the reference's
+    full forward; the counters equal the host arithmetic they stand
+    for."""
+    st = served["stats"]
+    assert st["completed"] == 1 + len(PROMPTS) and st["preemptions"] == 0
+    for i, (prompt, out) in enumerate(zip(served["prompts"],
+                                          served["outs"])):
+        assert len(out["tokens"]) == NEW
+        want = _ref_logits(params, (prompt + out["tokens"])[:-1], last=NEW)
+        assert contract.engine_gap(served, i, want) < TOL
     loop = st["loop"]
     assert loop["ssm_lane_steps"] == loop["lane_steps_live"] * N_KDA
     assert loop["prefill_scan_chunks"] == N_KDA * sum(
-        -(-len(p) // CFG.kda_chunk) for p in prompts)
+        -(-len(p) // CFG.kda_chunk)
+        for p in served["prompts"] + [served["first_prompt"]])
     # every live lane-step of the sparse layer: its context, the complete
     # groups it scored, the rows it attended (under 100 %: it was sparse)
     assert loop["dsa_groups_scored"] <= loop["dsa_rows_context"] // 4
@@ -595,28 +565,20 @@ def test_engine_logits_equal_the_reference_across_lane_reuse(
     lane = st["lane_state"]
     assert lane["layers"] == N_KDA and set(lane["by_kind"]) == {
         "conv", "kda", "ipart"}
-    assert lane["by_kind"]["kda"] == N_KDA * 2 * 4 * 16 * 16 * 4
+    assert lane["by_kind"]["kda"] == N_KDA * LANES * 4 * 16 * 16 * 4
     assert lane["prefix_cache"] == "off: lane state"
 
 
-def test_an_idle_lanes_state_is_bit_unchanged_by_a_decode_window(params):
-    eng = LLMEngine(CFG, params, max_batch=3, max_len=64, page_size=PAGE,
-                    kv_pages=13, steps_per_sync=K)
-    marked = jax.tree.map(lambda a: a + 1.0, eng.cache["state"])
-    eng.cache = {**eng.cache, "state": marked}
-    want = jax.tree.map(np.asarray, marked)
-    eng.start()
-    try:
-        eng.generate(_tokens(9, 1).tolist(), max_new_tokens=9)
-        got = jax.tree.map(np.asarray, eng.cache["state"])
-    finally:
-        eng.stop()
-    used = [i for i in range(3)
-            if not (got["kda"][:, i] == want["kda"][:, i]).all()]
+def test_an_idle_lanes_state_is_bit_unchanged_by_a_decode_window(served):
+    """The run's first request (9 + 9 tokens) alone in an engine of three
+    lanes: one lane's KDA state and incomplete group's sum were written,
+    the idle lanes' are bit-unchanged."""
+    assert len(served["first"]["tokens"]) == 9
+    used = contract.lanes_written(
+        served, lambda s: np.moveaxis(s["kda"], 1, 0))
     assert len(used) == 1
-    for name in ("kda", "ipart"):
-        idle = [i for i in range(3) if i not in used]
-        assert (got[name][:, idle] == want[name][:, idle]).all()
+    assert contract.lanes_written(
+        served, lambda s: np.moveaxis(s["ipart"], 1, 0)) in ([], used)
 
 
 # ------------------------------------------------------ (d) the residual
@@ -686,13 +648,25 @@ def test_the_clamp_bounds_a_swiglu():
 
 
 # ------------------------------------------------------- (f) the controls
-def _sound(params, cfg=CFG, model=MODEL):
+# A control changes one equation of one kind of layer, and its patch has
+# to be traced: it runs on the model cut to its first two layers, which
+# keep every kind (a KDA mixer over a dense feed-forward, the sparse
+# latent mixer over routed experts, each under the mHC residual, and the
+# head over the summed streams), against the reference of the same cut.
+SHALLOW = dataclasses.replace(CFG, layer_types=CFG.layer_types[:2],
+                              ffn_types=CFG.ffn_types[:2])
+
+
+def _sound(params, cfg=SHALLOW, model=None, seam=None):
     """The served path (a padded prompt pass, the scatter, eleven decode
-    steps in windows of four) against the reference's full forward."""
+    steps in windows of four) against the reference's full forward, on
+    `cfg`'s layers; without a `seam`, every program traced anew."""
+    params = dict(params, layers=params["layers"][:cfg.n_layers])
     tok = _tokens(32, 41)
-    got = served_logits(glm5_next, params, cfg, tok[:21], tok[21:], 32,
-                        page=PAGE, k=K)
-    return _gap(got, ref.logits(params, tok, model, last=12))
+    got = served_logits(seam or Seam(glm5_next, cfg), params, cfg,
+                        tok[:21], tok[21:], 32, page=PAGE, k=K)
+    return _gap(got, ref.logits(params, tok, model or model_of(cfg),
+                                last=12))
 
 
 def _no_tail(scores, pos, n_keys, group, top, _f=dsa.selected_mask):
@@ -728,6 +702,9 @@ CONTROLS = {
 
 
 def test_the_sound_program_is_inside_the_tolerance(params):
+    """The whole model (the file's seam), and the cut the controls run
+    on."""
+    assert _sound(params, CFG, seam=SOUND) < TOL
     assert _sound(params) < TOL
 
 
@@ -741,15 +718,15 @@ def test_a_bfloat16_state_exceeds_the_tolerance(params):
     """The lanes' state matrices kept in bfloat16: handed over rounded,
     re-rounded by every decode step."""
     assert _sound(params, dataclasses.replace(
-        CFG, state_dtype=jnp.bfloat16)) > 10 * TOL
+        SHALLOW, state_dtype=jnp.bfloat16), model_of(SHALLOW)) > 10 * TOL
 
 
 def test_an_unclamped_swiglu_exceeds_the_tolerance(params, monkeypatch):
     """At fan-in scaled weights no SwiGLU input reaches the published
     limit (|gate| ~ 1 against 10), so the control runs at a limit that
     binds, under which the sound program still equals the reference."""
-    cfg = dataclasses.replace(CFG, swiglu_limit=0.5)
-    model = dict(MODEL, swiglu_limit=0.5)
+    cfg = dataclasses.replace(SHALLOW, swiglu_limit=0.5)
+    model = model_of(cfg)
     assert _sound(params, cfg, model) < TOL
     monkeypatch.setattr(glm5_next.routed, "clamp",
                         lambda gate, up, limit: (gate, up))
@@ -796,6 +773,7 @@ def test_the_seam_declares_what_the_engine_counts():
 
 def test_a_latent_pool_with_lane_state_is_served_without_the_prefix_cache(
         params):
+    # (an engine that is refused at construction: nothing compiles)
     with pytest.raises(ValueError, match="prefix_cache=True refused"):
         LLMEngine(CFG, params, max_batch=2, max_len=64, page_size=PAGE,
                   kv_pages=9, prefix_cache=True)
@@ -804,6 +782,8 @@ def test_a_latent_pool_with_lane_state_is_served_without_the_prefix_cache(
 
 
 def test_the_server_serves_the_preset_by_name():
+    # an engine of its own: the preset as published (bfloat16), found by
+    # its name and served through `LLMServer`
     srv = LLMServer("glm5-next-debug", max_batch=2, max_len=64,
                     page_size=PAGE, kv_pages=9, steps_per_sync=K)
     try:

@@ -4,7 +4,7 @@ reference the benchmark holds it to (`benchmarks/harness/refs/
 lfm2_moe.py`, which imports nothing of the program): the prompt pass, the
 prompt pass at a padded bucket followed by paged decode through the pool
 and the lane state, the engine with lanes reused and a forced
-preempt-and-recompute, the grouped matmul under skew, expert ranges, the
+preempt-and-recompute (the file's one engine run: `family_contract`), the grouped matmul under skew, expert ranges, the
 controls a sound comparison must fail, and what the engine refuses for a
 model with lane state."""
 from __future__ import annotations
@@ -16,7 +16,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from serving_reference import served_logits  # rootdir-relative (no pkg)
+import family_contract as contract  # rootdir-relative (no pkg)
+from family_contract import tokens as _tokens
+from serving_reference import Seam, served_logits
 
 from benchmarks.harness.refs import lfm2_moe as ref
 from ray_tpu.models import lfm2, serving_model
@@ -41,32 +43,50 @@ CONTROL = 2e-2      # what every control must exceed, 100 x TOL
 PAGE, K = 16, 4
 
 
+# The sound program's seam, compiled once a shape for the file (true
+# lengths are arguments), and the reference at ONE length (54 is the
+# longest sequence a case reads: 40 prompt tokens and 14 served).
+SOUND = Seam(lfm2, CFG)
+_ref_logits = contract.one_length(
+    lambda p, seq: ref.logits(p, seq, MODEL), 56)
+
+
 @pytest.fixture(scope="module")
 def params():
     return lfm2.init_params(jax.random.PRNGKey(7), CFG)
 
 
-def _tokens(n, seed=0):
-    return np.random.default_rng(seed).integers(0, 256, n).astype(np.int32)
-
-
-def _worst(params_served, params_ref, n=21, bucket=32, follow=2 * K):
+def _worst(params_served, params_ref, n=21, bucket=32, follow=2 * K,
+           seam=SOUND):
+    """Through the file's seam; a control (the model is three layers:
+    cut to two, one of them routed, a changed router moves the logits by
+    less than the controls' bound) through one traced under its patch."""
     prompt, nxt = _tokens(n, 1), _tokens(follow, 2)
-    got = served_logits(lfm2, params_served, CFG, prompt, nxt, bucket,
+    got = served_logits(seam, params_served, CFG, prompt, nxt, bucket,
                         page=PAGE, k=K)
-    want = ref.logits(params_ref, list(prompt) + list(nxt), MODEL,
-                      last=follow + 1)
+    want = _ref_logits(params_ref, list(prompt) + list(nxt),
+                       last=follow + 1)
     return float(jnp.max(jnp.abs(got - want)))
 
 
 # ---------------------------------- (1), (2) against the full forward
-@pytest.mark.parametrize("n", [1, 2, 17, 32])
-def test_prefill_logits_equal_the_reference(params, n):
-    toks = _tokens(32, 3)[None]
-    h, *_ = lfm2.prefill(params, jnp.asarray(toks), CFG,
-                         jnp.asarray([n], jnp.int32))
-    got = lfm2.project_logits(params, h[0, :n])
-    want = ref.logits(params, toks[0, :n], MODEL)
+PREFILL_LENS = [1, 2, 17, 32]
+
+
+@pytest.fixture(scope="module")
+def prefill_rows(params):
+    """ONE prompt pass for the four lengths: the same 32 tokens in four
+    rows of one program, a true length each."""
+    toks, h = contract.prefill_rows(
+        SOUND, params, [_tokens(32, 3)] * len(PREFILL_LENS), PREFILL_LENS)
+    return toks[0], h
+
+
+@pytest.mark.parametrize("n", PREFILL_LENS)
+def test_prefill_logits_equal_the_reference(params, prefill_rows, n):
+    toks, h = prefill_rows
+    got = lfm2.project_logits(params, h[PREFILL_LENS.index(n), :n])
+    want = _ref_logits(params, toks[:n])
     assert float(jnp.max(jnp.abs(got - want))) < TOL
 
 
@@ -80,35 +100,38 @@ def test_padded_prefill_then_paged_decode_equals_the_reference(
 
 
 # ------------------------------------------------ (3) through the engine
+PROMPTS = (40, 3, 17, 1, 29)
+
+
+@pytest.fixture(scope="module")
+def served(params):
+    """ONE engine run for the file (`family_contract.served_run`): two
+    lanes over a pool of six pages, a request of 9 + 9 tokens alone, then
+    five prompts at once, which the pool cannot hold together."""
+    return contract.served_run(
+        lfm2, CFG, params, lanes=2, kv_pages=6, page=PAGE, k=K, new=14,
+        prompts=[_tokens(n, 10 + n).tolist() for n in PROMPTS])
+
+
 def _reference_agrees(params, prompt, served) -> int:
     """Teacher-forced under the reference's full forward: wherever its
     top-two margin exceeds the tolerance, the served token is its
     choice.  Returns how many positions were that clear."""
-    lg = np.asarray(ref.logits(params, list(prompt) + served[:-1], MODEL,
-                               last=len(served)))
+    lg = _ref_logits(params, list(prompt) + served[:-1], last=len(served))
     top2 = np.sort(lg, axis=-1)[:, -2:]
     clear = top2[:, 1] - top2[:, 0] > TOL
     assert (np.argmax(lg, -1)[clear] == np.asarray(served)[clear]).all()
     return int(clear.sum())
 
 
-def test_engine_generates_the_reference_tokens(params):
-    """Two lanes, five prompts of other lengths: lanes are reused, and a
-    pool too small for both forces a preempt-and-recompute.  Greedy
-    tokens equal the reference's wherever its top-two margin exceeds the
-    tolerance."""
-    eng = LLMEngine(CFG, params, max_batch=2, max_len=96, page_size=PAGE,
-                    kv_pages=6, steps_per_sync=K)
-    eng.start()
-    try:
-        prompts = [_tokens(n, 10 + n).tolist() for n in (40, 3, 17, 1, 29)]
-        futs = [eng.submit(p, max_new_tokens=14) for p in prompts]
-        outs = [f.result(timeout=300) for f in futs]
-        st = eng.stats()
-    finally:
-        eng.stop()
+def test_engine_generates_the_reference_tokens(params, served):
+    """Two lanes, a request and then five prompts of other lengths: lanes
+    are reused, and a pool too small for both forces a
+    preempt-and-recompute.  Greedy tokens equal the reference's wherever
+    its top-two margin exceeds the tolerance."""
+    prompts, outs, st = served["prompts"], served["outs"], served["stats"]
     assert st["preemptions"] >= 1
-    assert st["completed"] == 5
+    assert st["completed"] == 1 + len(PROMPTS)
     clear = sum(_reference_agrees(params, p, o["tokens"])
                 for p, o in zip(prompts, outs))
     assert clear >= 5 * 14 - 3
@@ -616,7 +639,10 @@ def test_every_control_exceeds_the_tolerance(params, monkeypatch, control):
         monkeypatch.setattr(lfm2, "route", _route_bias_in_weights)
     elif control == "lane_state_zeroed_at_admission":
         monkeypatch.setattr(lfm2, "serve_scatter", _scatter_zero_state)
-    worst = _worst(served, params)
+    # a patch has to be traced; changed parameters are arguments of the
+    # file's programs
+    seam = SOUND if served is not params else Seam(lfm2, CFG)
+    worst = _worst(served, params, seam=seam)
     if control == "sound":
         assert worst < TOL
     else:
@@ -625,6 +651,8 @@ def test_every_control_exceeds_the_tolerance(params, monkeypatch, control):
 
 # --------------------------------------------- (7) what the engine refuses
 def test_a_model_with_lane_state_is_served_without_the_prefix_cache(params):
+    # (engines that are refused at construction, and one never started:
+    # nothing of theirs compiles)
     assert serving_model(CFG) is lfm2
     with pytest.raises(ValueError, match="radix prefix hit cannot restore"):
         LLMEngine(CFG, params, max_batch=2, max_len=64, page_size=PAGE,
@@ -654,6 +682,8 @@ def test_the_server_refuses_at_construction(params, kw, match):
 
 
 def test_the_server_serves_a_preset_by_name():
+    # an engine of its own: the preset as published (bfloat16), found by
+    # its name and served through `LLMServer`
     srv = LLMServer("lfm2-debug", max_batch=2, max_len=64, page_size=PAGE)
     try:
         out = srv.engine.generate([5, 6, 7], max_new_tokens=5)
@@ -683,6 +713,8 @@ def test_prefill_params_equal_a_count_over_the_tree(params):
     assert streamed == matmul
     assert multiplied == matmul - experts + experts * CFG.top_k \
         // CFG.n_experts
+    # (engines that are built and never started: their plans are read,
+    # nothing of theirs compiles)
     eng = LLMEngine(CFG, params, max_batch=16, max_len=128, page_size=PAGE)
     floor = FLOOR_TOKENS * streamed // multiplied
     assert FLOOR_TOKENS < floor == eng._prefill_floor \

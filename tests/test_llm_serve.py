@@ -10,7 +10,7 @@ import concurrent.futures
 import numpy as np
 import pytest
 
-from serving_reference import reference_greedy, served_logits
+from serving_reference import Seam, reference_greedy, served_logits
 
 
 @pytest.fixture(scope="module")
@@ -48,7 +48,7 @@ def test_padded_prefill_then_paged_decode_equals_the_full_forward(
     params = llama.init_params(jax.random.PRNGKey(n_heads), cfg)
     rng = np.random.default_rng(1)
     prompt, follow = rng.integers(0, 256, 21), rng.integers(0, 256, 8)
-    got = served_logits(llama, params, cfg, prompt, follow, 32)
+    got = served_logits(Seam(llama, cfg), params, cfg, prompt, follow, 32)
     seq = jnp.asarray([list(prompt) + list(follow)])
     want = llama.forward(params, seq, cfg)[0, len(prompt) - 1:]
     assert got.shape == want.shape and float(jnp.abs(want).max()) > 0.1

@@ -5,11 +5,12 @@ head, routed experts without a shared one) against the plain float32
 reference the benchmark holds it to (`benchmarks/harness/refs/mimo_v2.py`,
 which imports nothing of the program): the prompt pass, paged + ring
 decode across the ring's wrap, the ENGINE's own logits with lanes reused
-(one engine run shared by the file's cases), the banded `flash_fwd` with
+(one engine run shared by the file's cases: `family_contract`), the banded `flash_fwd` with
 the sink, the ring kernel, the rings written in place, the expert shares,
 the counters and the controls a sound comparison must fail."""
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 
@@ -18,11 +19,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from serving_reference import served_logits  # rootdir-relative (no pkg)
+import family_contract as contract  # rootdir-relative (no pkg)
+from family_contract import gap as _gap, tokens as _tokens
+from serving_reference import Seam, served_logits
 
 from benchmarks.harness.refs import mimo_v2 as ref
 from ray_tpu.models import mimo_v2, named_config, serving_model
-from ray_tpu.ops import (flash_attention, live_rows, paged_attention, ssm,
+from ray_tpu.ops import (flash_attention, live_rows, ssm,
                          window_attention as swa)
 from ray_tpu.ops.attention import xla_attention
 from ray_tpu.serve.llm import LLMEngine, LLMServer
@@ -59,57 +62,18 @@ def model_of(cfg) -> dict:
 MODEL = model_of(CFG)
 
 
-def _gap(got, want) -> float:
-    got, want = np.asarray(got), np.asarray(want)
-    return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+# The sound program's seam, compiled once a shape for the file (true
+# lengths are arguments), and the reference at ONE length (54 is the
+# longest sequence a case reads: 40 prompt tokens and 14 served).
+SOUND = Seam(mimo_v2, CFG)
+_ref_logits = contract.one_length(
+    lambda p, seq: ref.logits(p, seq, MODEL), 56)
 
 
 @pytest.fixture(scope="module")
 def params():
     return jax.jit(lambda key: mimo_v2.init_params(key, CFG))(
         jax.random.PRNGKey(7))
-
-
-class _Jitted:
-    """The module's seam with the prompt pass and the scatter jitted (as
-    the engine runs them), looked up at the call so that a control's
-    patch is traced."""
-    project_logits = staticmethod(mimo_v2.project_logits)
-    init_paged_cache = staticmethod(mimo_v2.init_paged_cache)
-
-    @staticmethod
-    def serve_prefill(params, tokens, cfg, true_lens):
-        return jax.jit(lambda p, t, n: mimo_v2.serve_prefill(
-            p, t, cfg, n))(params, tokens, true_lens)
-
-    @staticmethod
-    def serve_scatter(cache, *args):
-        return jax.jit(lambda c, *a: mimo_v2.serve_scatter(c, *a))(
-            cache, *args)
-
-    @staticmethod
-    def serve_decode_step(*args):
-        return mimo_v2.serve_decode_step(*args)
-
-
-def _tokens(n, seed=0):
-    return np.random.default_rng(seed).integers(0, 256, n).astype(np.int32)
-
-
-REF_LEN = 56
-_REF: dict = {}
-
-
-def _ref_logits(params, seq, last=None):
-    """The reference's logits for `seq`, computed on `seq` right-padded
-    to REF_LEN (causal: the padding cannot reach a true position), so
-    that the file compiles the reference for ONE length."""
-    seq = [int(t) for t in seq]
-    key = tuple(seq)
-    if key not in _REF:
-        padded = seq + [0] * (REF_LEN - len(seq))
-        _REF[key] = np.asarray(ref.logits(params, padded, MODEL))[:len(seq)]
-    return _REF[key] if last is None else _REF[key][-last:]
 
 
 def test_the_rotary_part_is_the_published_ones():
@@ -119,23 +83,42 @@ def test_the_rotary_part_is_the_published_ones():
 
 
 # --------------------------------------------------- (a) the prompt pass
-@pytest.mark.parametrize("n", [5, WINDOW, 16, 37])
-def test_prefill_logits_equal_the_reference(params, n):
+PREFILL_LENS = [5, WINDOW, 16, 37]
+
+
+@pytest.fixture(scope="module")
+def prefill_rows(params):
+    """ONE prompt pass for the lengths: a row each of one program."""
+    return contract.prefill_rows(
+        SOUND, params, [_tokens(n, n) for n in PREFILL_LENS], PREFILL_LENS)
+
+
+@pytest.mark.parametrize("n", PREFILL_LENS)
+def test_prefill_logits_equal_the_reference(params, prefill_rows, n):
     """5: under the window; 9: the window full for the first time; 37:
-    the band has moved on four times over."""
-    tok = _tokens(n, n)
-    h = _Jitted.serve_prefill(params, jnp.asarray(tok[None]), CFG,
-                              jnp.asarray([n], jnp.int32))[0]
-    got = mimo_v2.project_logits(params, h[0])
-    assert _gap(got, _ref_logits(params, tok)) < TOL
+    the band has moved on four times over.  Every true position of the
+    row against the reference's."""
+    toks, h = prefill_rows
+    i = PREFILL_LENS.index(n)
+    got = mimo_v2.project_logits(params, h[i, :n])
+    assert _gap(got, _ref_logits(params, toks[i, :n])) < TOL
+
+
+# The prompt pass walked in chunks of some width: its own program a width
+# (traced under `_chunked`), the scatter and the decode step the sound
+# seam's.
+_WALKED = collections.defaultdict(lambda: SOUND.retraced("serve_prefill"))
 
 
 def _chunked(mp, chunk):
     """The prompt pass's position-wise halves walked in chunks of `chunk`
-    positions (None: the module's own, one chunk at these sizes)."""
-    if chunk:
-        mp.setattr(live_rows, "walk",
-                   functools.partial(live_rows.walk, chunk=chunk))
+    positions (None: the module's own, one chunk at these sizes), and the
+    seam whose prompt pass is traced under it."""
+    if not chunk:
+        return SOUND
+    mp.setattr(live_rows, "walk",
+               functools.partial(live_rows.walk, chunk=chunk))
+    return _WALKED[chunk]
 
 
 @pytest.mark.parametrize("chunk", [None, 8, 5], ids=lambda c: f"chunk_{c}")
@@ -151,10 +134,9 @@ def test_padded_prefill_then_paged_decode_equals_the_reference(
     fills the window.  And the same with the prompt pass looped over
     chunks of 8 positions (a chunk short of the bucket's end is zeros)
     and of 5 (which divide no bucket: the last chunk is clamped)."""
-    _chunked(monkeypatch, chunk)
     tok = _tokens(n + new, 3 * n)
-    got = served_logits(_Jitted, params, CFG, tok[:n], tok[n:], bucket,
-                        page=PAGE, k=K)
+    got = served_logits(_chunked(monkeypatch, chunk), params, CFG, tok[:n],
+                        tok[n:], bucket, page=PAGE, k=K)
     assert _gap(got, _ref_logits(params, tok, last=new + 1)) < TOL
 
 
@@ -166,7 +148,7 @@ def test_the_prefill_hands_pages_and_rings_their_rows(params):
     tok = _tokens(32, 5)
     lens = jnp.asarray([32, 21], jnp.int32)
     toks = jnp.asarray(np.stack([tok, tok]))
-    _, ks, vs, state, _ = _Jitted.serve_prefill(params, toks, CFG, lens)
+    _, ks, vs, state, _ = SOUND.serve_prefill(params, toks, lens)
     dk = CFG.qk_head_dim        # 24, stored a lane tile wide (128)
     assert ks[0].shape[2:] == (CFG.n_kv_heads, CFG.k_store)
     assert vs[0].shape[2:] == (CFG.n_kv_heads, CFG.v_head_dim)
@@ -209,12 +191,11 @@ def test_the_looped_prefill_is_the_straight_line_prefill_on_the_true_rows(
     tok = jnp.asarray(np.stack([_tokens(bucket, 7 + i)
                                 for i in range(len(lens))]))
     n = jnp.asarray(lens, jnp.int32)
-    want = _Jitted.serve_prefill(params, tok, CFG, n)
+    want = SOUND.serve_prefill(params, tok, n)
     with pytest.MonkeyPatch.context() as mp:
-        _chunked(mp, chunk)
-        low = jax.jit(lambda p, t, n: mimo_v2.serve_prefill(p, t, CFG, n)
-                      ).lower(params, tok, n)
-        got = _Jitted.serve_prefill(params, tok, CFG, n)
+        walked = _chunked(mp, chunk).serve_prefill
+        low = walked.lower(params, tok, n)
+        got = walked(params, tok, n)
     # two loops a layer and the dense layer's third
     assert low.as_text().count("stablehlo.while") >= 2 * CFG.n_layers + 1
     done = min(bucket, -(-max(lens) // chunk) * chunk)
@@ -365,65 +346,12 @@ NEW = 14
 
 @pytest.fixture(scope="module")
 def served(params):
-    """ONE engine run for the file: three lanes, five prompts (under, at
-    and past the window), every logit its programs computed, its stats
-    and its rings afterwards."""
-    seen = []
-
-    def note(toks, pos, live, logits):
-        for t, p, ok, lg in zip(*map(np.asarray, (toks, pos, live, logits))):
-            if ok:
-                seen.append((int(t), int(p), lg))
-
-    step, prefill = mimo_v2.serve_decode_step, mimo_v2.serve_prefill
-
-    def decode_step(params, pages, tails, state, tokens, pos, ts, j, table,
-                    cfg, lora=None, plan=None):
-        out = step(params, pages, tails, state, tokens, pos, ts, j, table,
-                   cfg, lora, plan)
-        jax.debug.callback(note, tokens, pos,
-                           paged_attention.lanes_live(table), out[0])
-        return out
-
-    def prefill_rows(params, tokens, cfg, true_lens, lora=None):
-        out = prefill(params, tokens, cfg, true_lens, lora)
-        rows = jnp.arange(tokens.shape[0])
-        last = out[0][rows, true_lens - 1]
-        jax.debug.callback(
-            note, tokens[rows, true_lens - 1], true_lens - 1,
-            jnp.ones_like(true_lens, bool),
-            mimo_v2.project_logits(params, last).astype(jnp.float32))
-        return out
-
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(mimo_v2, "serve_decode_step", decode_step)
-        mp.setattr(mimo_v2, "serve_prefill", prefill_rows)
-        eng = LLMEngine(CFG, params, max_batch=3, max_len=96,
-                        page_size=PAGE, kv_pages=19, steps_per_sync=K)
-        marked = jax.tree.map(lambda a: a + 1.0, eng.cache["state"])
-        eng.cache = {**eng.cache, "state": marked}
-        before = jax.tree.map(np.asarray, marked)
-        lowered = eng._decode_fns[K].lower(
-            eng.params, eng.cache, eng._cur_dev, jnp.zeros((3,)),
-            eng._table_dev, jnp.zeros((3,), jnp.int32),
-            jnp.zeros((3,), jnp.int32), None)
-        eng.start()
-        try:
-            first = eng.generate(_tokens(9, 1).tolist(), max_new_tokens=9)
-            after_one = jax.tree.map(np.asarray, eng.cache["state"])
-            prompts = [_tokens(n, 10 + n).tolist() for n in PROMPTS]
-            futs = [eng.submit(p, max_new_tokens=NEW) for p in prompts]
-            outs = [f.result(timeout=300) for f in futs]
-            jax.effects_barrier()
-            st = eng.stats()
-        finally:
-            eng.stop()
-    by_key = {}
-    for t, p, lg in seen:
-        by_key.setdefault((t, p), []).append(lg)
-    return {"prompts": prompts, "outs": outs, "logits": by_key, "stats": st,
-            "first": first, "rings": (before, after_one),
-            "lowered": lowered}
+    """ONE engine run for the file (`family_contract.served_run`): three
+    lanes whose rings were marked, a request of 9 + 9 tokens alone, then
+    five prompts at once (under, at and past the window)."""
+    return contract.served_run(
+        mimo_v2, CFG, params, lanes=3, kv_pages=19, page=PAGE, k=K,
+        prompts=[_tokens(n, 10 + n).tolist() for n in PROMPTS], new=NEW)
 
 
 @pytest.mark.parametrize("i", range(len(PROMPTS)))
@@ -433,15 +361,9 @@ def test_engine_logits_equal_the_reference_across_lane_reuse(
     ring's rows nor a page may leak.  The LOGITS the engine's own
     programs computed at every served position equal the reference's
     full forward."""
-    prompt, out = served["prompts"][i], served["outs"][i]
-    seq = prompt + out["tokens"]
-    want = _ref_logits(params, seq[:-1], last=len(out["tokens"]))
-    assert len(want) == NEW
-    for j, row in enumerate(want):
-        p = len(prompt) - 1 + j
-        got = served["logits"].get((seq[p], p), [])
-        assert got, (len(prompt), j)
-        assert min(_gap(g, row) for g in got) < TOL
+    seq = served["prompts"][i] + served["outs"][i]["tokens"]
+    want = _ref_logits(params, seq[:-1], last=NEW)
+    assert contract.engine_gap(served, i, want) < TOL
 
 
 def test_the_engine_counts_what_the_layers_read(served):
@@ -484,12 +406,11 @@ def test_the_rings_are_written_in_place(served):
     scatter and then a slot a step; and the decode program hands every
     ring back in the buffer it came in (donated and aliased: no second
     ring)."""
-    before, after = served["rings"]
     assert len(served["first"]["tokens"]) == 9
     for name in ("window_k", "window_v"):
-        for b, a in zip(before[name], after[name]):
-            used = [i for i in range(3) if not (a[i] == b[i]).all()]
-            assert len(used) == 1
+        for layer in range(CFG.count(mimo_v2.WINDOW)):
+            assert len(contract.lanes_written(
+                served, lambda s: s[name][layer])) == 1
     text = served["lowered"].as_text()
     n_win = CFG.count(mimo_v2.WINDOW)
     for w in (CFG.k_store, CFG.v_head_dim):
@@ -524,13 +445,28 @@ def test_the_shares_of_a_routed_layer_add_up_to_the_uncut_layer(params):
 
 
 # ------------------------------------------------------- (e) the controls
-def _sound(params, cfg=CFG):
+# A control changes one equation of one kind of layer, and its patch has
+# to be traced: it runs on the model cut to its first two layers, which
+# keep both kinds (a global layer over the dense feed-forward, a window
+# layer with its sink and rings over routed experts), against the
+# reference of the same cut.
+SHALLOW = dataclasses.replace(CFG, layer_types=CFG.layer_types[:2],
+                              moe_layers=CFG.moe_layers[:2])
+
+
+def _sound(params, cfg=SHALLOW, seam=None):
     """The served path (a padded prompt pass, the scatter, eleven decode
-    steps in windows of four) against the reference's full forward."""
+    steps in windows of four) against the reference's full forward, on
+    `cfg`'s layers; without a `seam`, every program traced anew.  The
+    reference is the PUBLISHED model's at that depth, whatever equation
+    `cfg` changed."""
+    params = dict(params, layers=params["layers"][:cfg.n_layers])
     tok = _tokens(32, 41)
-    got = served_logits(_Jitted, params, cfg, tok[:21], tok[21:], 32,
-                        page=PAGE, k=K)
-    return _gap(got, _ref_logits(params, tok, last=12))
+    got = served_logits(seam or Seam(mimo_v2, cfg), params, cfg, tok[:21],
+                        tok[21:], 32, page=PAGE, k=K)
+    if seam is SOUND:
+        return _gap(got, _ref_logits(params, tok, last=12))
+    return _gap(got, ref.logits(params, tok, model_of(SHALLOW), last=12))
 
 
 def _global_grouping(q, k, v, _f=mimo_v2.attention, **kw):
@@ -560,6 +496,9 @@ CONTROLS = {
 
 
 def test_the_sound_program_is_inside_the_tolerance(params):
+    """The whole model (the file's seam), and the cut the controls run
+    on."""
+    assert _sound(params, CFG, seam=SOUND) < TOL
     assert _sound(params) < TOL
 
 
@@ -574,7 +513,7 @@ def test_every_control_exceeds_the_tolerance(params, monkeypatch, control):
                                     dict(rope_dim=12)],
                          ids=["window_8", "window_10", "rotary_12_of_24"])
 def test_a_changed_equation_exceeds_the_tolerance(params, change):
-    assert _sound(params, dataclasses.replace(CFG, **change)) > CONTROL
+    assert _sound(params, dataclasses.replace(SHALLOW, **change)) > CONTROL
 
 
 def test_the_references_window_edge_is_the_published_one(params):
@@ -654,6 +593,7 @@ def test_the_prefill_work_counts_the_positions_the_halves_walk(
 
 
 def test_lane_state_is_served_without_the_prefix_cache(params):
+    # (an engine that is refused at construction: nothing compiles)
     with pytest.raises(ValueError, match="prefix_cache=True refused"):
         LLMEngine(CFG, params, max_batch=2, max_len=64, page_size=PAGE,
                   kv_pages=9, prefix_cache=True)
@@ -663,6 +603,8 @@ def test_lane_state_is_served_without_the_prefix_cache(params):
 
 
 def test_the_server_serves_the_preset_by_name():
+    # an engine of its own: the preset as published (bfloat16), found by
+    # its name and served through `LLMServer`
     srv = LLMServer("mimo-v2-debug", max_batch=2, max_len=64,
                     page_size=PAGE, kv_pages=9, steps_per_sync=K)
     try:
@@ -677,7 +619,8 @@ def test_the_server_serves_the_preset_by_name():
 
 def test_the_preset_is_served_through_serve_run():
     """`serve.run(LLMServer)` in the node's device worker, as the
-    benchmark's replica is started: the normal path end to end."""
+    benchmark's replica is started: the normal path end to end (an
+    engine of its own, in another process)."""
     import ray_tpu
     from ray_tpu import serve
 

@@ -4,7 +4,8 @@ benchmark holds it to (`benchmarks/harness/refs/mla_moe.py`, which
 imports nothing of the program and has the EXPANDED attention only): the
 prompt pass, the prompt pass at a padded bucket followed by ABSORBED paged
 decode through the latent pool, the engine with lanes reused and a forced
-preempt-and-recompute, the expert ranges and the shared expert adding up
+preempt-and-recompute (one engine run shared by the cases that read a
+sound run: `family_contract`), the expert ranges and the shared expert adding up
 to the uncut layer, the router, the controls a sound comparison must
 fail, the engine's counters, and what the engine refuses for a model
 whose pool is no K and V."""
@@ -17,7 +18,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from serving_reference import served_logits  # rootdir-relative (no pkg)
+import family_contract as contract  # rootdir-relative (no pkg)
+from family_contract import tokens as _tokens
+from serving_reference import Seam, served_logits
 
 from benchmarks.harness.refs import mla_moe as ref
 from ray_tpu.models import mla_moe, routed, serving_model
@@ -59,32 +62,60 @@ CONTROL = 2e-2      # what every control must exceed, 100 x TOL
 PAGE, K = 16, 4
 
 
+# The sound program's seam, compiled once a shape for the file (true
+# lengths are arguments), and the reference at ONE length (54 is the
+# longest sequence a case reads: 40 prompt tokens and 14 served).
+SOUND = Seam(mla_moe, CFG)
+_ref_logits = contract.one_length(
+    lambda p, seq: ref.logits(p, seq, MODEL), 56)
+# A control changes one equation of the attention or of the routed layer,
+# and its patch has to be traced: it runs on the model cut to its first
+# two layers, which keep both kinds (latent attention over the dense
+# feed-forward, then over the routed experts and the shared one), against
+# the reference of the same cut.
+SHALLOW = dataclasses.replace(CFG, n_layers=2)
+
+
 @pytest.fixture(scope="module")
 def params():
     return mla_moe.init_params(jax.random.PRNGKey(7), CFG)
 
 
-def _tokens(n, seed=0):
-    return np.random.default_rng(seed).integers(0, 256, n).astype(np.int32)
-
-
-def _worst(params, n=21, bucket=32, follow=2 * K):
+def _worst(params, n=21, bucket=32, follow=2 * K, cfg=CFG):
+    """The sound program through the file's seam; on the cut, through
+    programs traced anew."""
     prompt, nxt = _tokens(n, 1), _tokens(follow, 2)
-    got = served_logits(mla_moe, params, CFG, prompt, nxt, bucket,
+    seq = list(prompt) + list(nxt)
+    if cfg is CFG:
+        seam = SOUND
+        want = _ref_logits(params, seq, last=follow + 1)
+    else:
+        seam = Seam(mla_moe, cfg)
+        params = dict(params, layers=params["layers"][:cfg.n_layers])
+        want = ref.logits(params, seq, model_of(cfg), last=follow + 1)
+    got = served_logits(seam, params, cfg, prompt, nxt, bucket,
                         page=PAGE, k=K)
-    want = ref.logits(params, list(prompt) + list(nxt), MODEL,
-                      last=follow + 1)
     return float(jnp.max(jnp.abs(got - want)))
 
 
 # ---------------------------------- (1), (2) against the full forward
-@pytest.mark.parametrize("n", [1, 2, 17, 32])
-def test_prefill_logits_equal_the_reference(params, n):
-    toks = _tokens(32, 3)[None]
-    h, *_ = mla_moe.prefill(params, jnp.asarray(toks), CFG,
-                            jnp.asarray([n], jnp.int32))
-    got = mla_moe.project_logits(params, h[0, :n])
-    want = ref.logits(params, toks[0, :n], MODEL)
+PREFILL_LENS = [1, 2, 17, 32]
+
+
+@pytest.fixture(scope="module")
+def prefill_rows(params):
+    """ONE prompt pass for the four lengths: the same 32 tokens in four
+    rows of one program, a true length each."""
+    toks, h = contract.prefill_rows(
+        SOUND, params, [_tokens(32, 3)] * len(PREFILL_LENS), PREFILL_LENS)
+    return toks[0], h
+
+
+@pytest.mark.parametrize("n", PREFILL_LENS)
+def test_prefill_logits_equal_the_reference(params, prefill_rows, n):
+    toks, h = prefill_rows
+    got = mla_moe.project_logits(params, h[PREFILL_LENS.index(n), :n])
+    want = _ref_logits(params, toks[:n])
     assert float(jnp.max(jnp.abs(got - want))) < TOL
 
 
@@ -115,33 +146,37 @@ def test_the_yarn_frequencies_are_the_references():
 
 
 # ------------------------------------------------ (3) through the engine
+PROMPTS = (40, 3, 17, 1, 29)
+
+
+@pytest.fixture(scope="module")
+def served(params):
+    """ONE engine run for the file (`family_contract.served_run`): two
+    lanes over a pool of six pages, a request of 21 + 9 tokens alone,
+    then five prompts at once, which the pool cannot hold together."""
+    return contract.served_run(
+        mla_moe, CFG, params, lanes=2, kv_pages=6, page=PAGE, k=K,
+        first=(21, 5, 9), new=14,
+        prompts=[_tokens(n, 10 + n).tolist() for n in PROMPTS])
+
+
 def _reference_agrees(params, prompt, served) -> int:
-    lg = np.asarray(ref.logits(params, list(prompt) + served[:-1], MODEL,
-                               last=len(served)))
+    lg = _ref_logits(params, list(prompt) + served[:-1], last=len(served))
     top2 = np.sort(lg, axis=-1)[:, -2:]
     clear = top2[:, 1] - top2[:, 0] > TOL
     assert (np.argmax(lg, -1)[clear] == np.asarray(served)[clear]).all()
     return int(clear.sum())
 
 
-def test_engine_generates_the_reference_tokens(params):
-    """Two lanes, five prompts of other lengths: lanes are reused, and a
-    pool too small for both forces a preempt-and-recompute.  Greedy
-    tokens equal the reference's wherever its top-two margin exceeds the
-    tolerance; the counters say what the pool holds and what the chip's
-    range of experts computed."""
-    eng = LLMEngine(CFG, params, max_batch=2, max_len=96, page_size=PAGE,
-                    kv_pages=6, steps_per_sync=K)
-    eng.start()
-    try:
-        prompts = [_tokens(n, 10 + n).tolist() for n in (40, 3, 17, 1, 29)]
-        futs = [eng.submit(p, max_new_tokens=14) for p in prompts]
-        outs = [f.result(timeout=300) for f in futs]
-        st = eng.stats()
-    finally:
-        eng.stop()
+def test_engine_generates_the_reference_tokens(params, served):
+    """Two lanes, a request and then five prompts of other lengths: lanes
+    are reused, and a pool too small for both forces a
+    preempt-and-recompute.  Greedy tokens equal the reference's wherever
+    its top-two margin exceeds the tolerance; the counters say what the
+    pool holds and what the chip's range of experts computed."""
+    prompts, outs, st = served["prompts"], served["outs"], served["stats"]
     assert st["preemptions"] >= 1
-    assert st["completed"] == 5
+    assert st["completed"] == 1 + len(PROMPTS)
     clear = sum(_reference_agrees(params, p, o["tokens"])
                 for p, o in zip(prompts, outs))
     assert clear >= 5 * 14 - 3
@@ -180,6 +215,8 @@ def test_the_share_of_the_visit_list_that_is_work(held):
     boundary a group) is not all work.  (Under this
     model's floor the one-row program of the longest bucket holds the
     row where the 64-bucket's is not built: serve/prefill_plan.py.)"""
+    # an engine of its own a share: its experts are another range, and
+    # the counts are those of ONE prompt in a fresh engine
     cfg = dataclasses.replace(CFG, experts_held=held)
     eng = LLMEngine(cfg, mla_moe.init_params(jax.random.PRNGKey(7), cfg),
                     max_batch=2, max_len=96, page_size=PAGE,
@@ -207,19 +244,14 @@ def test_the_share_of_the_visit_list_that_is_work(held):
             loop["prefill_moe_visits_static"]
 
 
-def test_attn_ctx_rows_counts_what_the_kernel_admits(params):
-    """One request of 21 prompt tokens and 9 new ones: the first comes
-    from the prefill, two windows of K=4 from positions 21 and 25.  The
-    host's count equals the rows the kernel's masks admit (pages below
-    the block start, the tail up to the position), step by step."""
-    eng = LLMEngine(CFG, params, max_batch=2, max_len=96, page_size=PAGE,
-                    steps_per_sync=K)
-    eng.start()
-    try:
-        eng.generate(_tokens(21, 5).tolist(), max_new_tokens=9)
-        loop = eng.stats()["loop"]
-    finally:
-        eng.stop()
+def test_attn_ctx_rows_counts_what_the_kernel_admits(served):
+    """The run's first request, 21 prompt tokens and 9 new ones alone in
+    the engine: the first comes from the prefill, two windows of K=4 from
+    positions 21 and 25.  The host's count equals the rows the kernel's
+    masks admit (pages below the block start, the tail up to the
+    position), step by step."""
+    assert len(served["first_prompt"]) == 21
+    loop = served["first_stats"]["loop"]
     admitted = 0
     for ts in (21, 25):
         for j in range(K):
@@ -244,6 +276,7 @@ def test_the_rows_a_routed_layer_moves_follow_what_the_chip_holds(
     params = mla_moe.init_params(jax.random.PRNGKey(7), cfg)
     toks, loops = [], []
     for b in (routed.BLOCK, block):
+        # an engine a block size: its programs are traced under the patch
         monkeypatch.setattr(routed, "BLOCK", b)
         eng = LLMEngine(cfg, params, max_batch=2, max_len=96,
                         page_size=PAGE, steps_per_sync=K)
@@ -369,9 +402,11 @@ CONTROLS = {
 
 @pytest.mark.parametrize("control", ["sound"] + list(CONTROLS))
 def test_every_control_exceeds_the_tolerance(params, monkeypatch, control):
-    if control != "sound":
+    if control == "sound":       # the whole model, then the cut
+        assert _worst(params) < TOL
+    else:
         monkeypatch.setattr(mla_moe, *CONTROLS[control])
-    worst = _worst(params)
+    worst = _worst(params, cfg=SHALLOW)
     if control == "sound":
         assert worst < TOL
     else:
@@ -409,6 +444,8 @@ def test_the_familys_judge_reads_the_cache_rows(monkeypatch, rows):
 
 # --------------------------------------------- (6) what the engine refuses
 def test_a_latent_pool_is_served_without_what_reads_a_k_and_v_pool(params):
+    # (engines that are refused at construction, and one never started:
+    # nothing of theirs compiles)
     assert serving_model(CFG) is mla_moe
     assert mla_moe.route is routed.route       # one router, two modules
     with pytest.raises(ValueError, match="no prefill_with_prefix"):
@@ -439,6 +476,8 @@ def test_the_server_refuses_at_construction(params, kw, match):
 
 
 def test_the_server_serves_a_preset_by_name():
+    # an engine of its own: the preset as published (bfloat16), found by
+    # its name and served through `LLMServer`
     srv = LLMServer("mla-debug", max_batch=2, max_len=64, page_size=PAGE)
     try:
         out = srv.engine.generate([5, 6, 7], max_new_tokens=5)
@@ -471,6 +510,8 @@ def test_prefill_params_equal_a_count_over_the_tree(params):
     assert streamed == matmul
     assert multiplied == matmul - experts + experts * CFG.top_k \
         // CFG.n_experts
+    # (an engine that is built and never started: its plan is read,
+    # nothing compiles)
     eng = LLMEngine(CFG, params, max_batch=16, max_len=128, page_size=PAGE)
     assert FLOOR_TOKENS < eng._prefill_floor \
         == FLOOR_TOKENS * streamed // multiplied \

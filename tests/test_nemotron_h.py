@@ -6,7 +6,8 @@ nemotron_h.py`: the token-by-token recurrence, a dense loop over the
 experts, importing nothing of the program): the prompt pass at a padded
 bucket followed by paged decode through the pool and the lane state, the
 ENGINE's own logits with lanes of different lengths reused and a dead
-lane bit-unchanged, the expert ranges' parts adding up to the uncut
+lane bit-unchanged (one engine run shared by the file's cases:
+`family_contract`), the expert ranges' parts adding up to the uncut
 layer, the gated norm by group, the routed layer's two forms against a
 dense loop, the controls a sound comparison must fail, and the
 counters."""
@@ -19,11 +20,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from serving_reference import served_logits  # rootdir-relative (no pkg)
+import family_contract as contract  # rootdir-relative (no pkg)
+from family_contract import gap as _gap, tokens as _tokens
+from serving_reference import Seam, served_logits
 
 from benchmarks.harness.refs import nemotron_h as ref
 from ray_tpu.models import named_config, nemotron_h, routed, serving_model
-from ray_tpu.ops import paged_attention, ssm
+from ray_tpu.ops import ssm
 from ray_tpu.serve.llm import LLMEngine, LLMServer
 
 # float32 weights: the served path and the reference then differ by
@@ -44,11 +47,12 @@ N_MAMBA, N_MOE = CFG.count("M"), CFG.count("E")
 MOE_LAYER = CFG.pattern.index("E")
 
 
-def _gap(got, want) -> float:
-    """The largest difference of two arrays as a share of the
-    reference's largest entry."""
-    got, want = np.asarray(got), np.asarray(want)
-    return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+# The sound program's seam, compiled once a shape for the file (true
+# lengths are arguments), and the reference at ONE length (54 is the
+# longest sequence a case reads: 40 prompt tokens and 14 served).
+SOUND = Seam(nemotron_h, CFG)
+_ref_logits = contract.one_length(
+    lambda p, seq: ref.logits(p, seq, MODEL), 56)
 
 
 @pytest.fixture(scope="module")
@@ -56,28 +60,37 @@ def params():
     return nemotron_h.init_params(jax.random.PRNGKey(7), CFG)
 
 
-def _tokens(n, seed=0):
-    return np.random.default_rng(seed).integers(0, 256, n).astype(np.int32)
-
-
 def _worst(params_served, params_ref, n=21, bucket=32, follow=2 * K,
-           cfg=CFG, model=MODEL):
+           cfg=CFG, model=MODEL, seam=None):
+    """Without a `seam`, through programs traced anew (a control's patch
+    has to be traced)."""
     prompt, nxt = _tokens(n, 1), _tokens(follow, 2)
-    got = served_logits(nemotron_h, params_served, cfg, prompt, nxt, bucket,
-                        page=PAGE, k=K)
-    want = ref.logits(params_ref, list(prompt) + list(nxt), model,
-                      last=follow + 1)
-    return _gap(got, want)
+    got = served_logits(seam or Seam(nemotron_h, cfg), params_served, cfg,
+                        prompt, nxt, bucket, page=PAGE, k=K)
+    seq = list(prompt) + list(nxt)
+    if seam is SOUND:
+        return _gap(got, _ref_logits(params_ref, seq, last=follow + 1))
+    return _gap(got, ref.logits(params_ref, seq, model, last=follow + 1))
 
 
 # ------------------------- (a) prefill, then decode, against the forward
-@pytest.mark.parametrize("n", [1, 2, 17, 32])
-def test_prefill_logits_equal_the_reference(params, n):
-    toks = _tokens(32, 3)[None]
-    h, *_ = nemotron_h.prefill(params, jnp.asarray(toks), CFG,
-                               jnp.asarray([n], jnp.int32))
-    got = nemotron_h.project_logits(params, h[0, :n])
-    assert _gap(got, ref.logits(params, toks[0, :n], MODEL)) < TOL
+PREFILL_LENS = [1, 2, 17, 32]
+
+
+@pytest.fixture(scope="module")
+def prefill_rows(params):
+    """ONE prompt pass for the four lengths: the same 32 tokens in four
+    rows of one program, a true length each."""
+    toks, h = contract.prefill_rows(
+        SOUND, params, [_tokens(32, 3)] * len(PREFILL_LENS), PREFILL_LENS)
+    return toks[0], h
+
+
+@pytest.mark.parametrize("n", PREFILL_LENS)
+def test_prefill_logits_equal_the_reference(params, prefill_rows, n):
+    toks, h = prefill_rows
+    got = nemotron_h.project_logits(params, h[PREFILL_LENS.index(n), :n])
+    assert _gap(got, _ref_logits(params, toks[:n])) < TOL
 
 
 @pytest.mark.parametrize("n,bucket", [(21, 32), (1, 32), (2, 32), (3, 32),
@@ -88,13 +101,16 @@ def test_padded_prefill_then_paged_decode_equals_the_reference(
     lane state must be the state and the convolution rows at the TRUE
     length (zeros where the prompt is shorter than three), and two
     windows of K steps carry them on."""
-    assert _worst(params, params, n=n, bucket=bucket) < TOL
+    assert _worst(params, params, n=n, bucket=bucket, seam=SOUND) < TOL
 
 
 def test_the_prefill_hands_the_state_and_the_rows_at_the_true_length(params):
     toks = _tokens(32, 5)
-    _, ks, vs, state, counts = nemotron_h.prefill(
-        params, jnp.asarray(toks[None]), CFG, jnp.asarray([13], jnp.int32))
+    # (the second row of the seam's two-row program of 32 positions)
+    _, ks, vs, state, counts = SOUND.serve_prefill(
+        params, jnp.asarray(np.stack([toks, toks])),
+        jnp.asarray([32, 13], jnp.int32))
+    ks, vs, state = jax.tree.map(lambda a: a[1:], (ks, vs, state))
     x = ref.embed(params, toks[:13])
     want = {"state": [], "conv": [], "k": [], "v": []}
     for kind, lp in zip(CFG.pattern, params["layers"]):
@@ -111,87 +127,46 @@ def test_the_prefill_hands_the_state_and_the_rows_at_the_true_length(params):
         assert _gap(got[0], exp) < 1e-5
     for got, exp in zip(ks + vs, want["k"] + want["v"]):
         assert _gap(got[0, :13], exp) < 1e-5
-    # every position below the true length chose top_k of the 8 experts,
-    # all held
+    # every position below the true lengths (the program's two rows: 32
+    # and 13) chose top_k of the 8 experts, all held
     assert counts.shape == (N_MOE, routed.COUNTS)
-    assert counts[:, 2].tolist() == [13 * CFG.top_k] * N_MOE
+    assert counts[:, 2].tolist() == [(32 + 13) * CFG.top_k] * N_MOE
 
 
 # ------------------------------------------------ (a) through the engine
-def _record_engine_logits(monkeypatch):
-    """Every logit the engine's programs compute, as they compute it:
-    (input token, position, logits) of each live lane's decode step and of
-    each prefill row's last position."""
-    seen = []
+PROMPTS = (40, 3, 17, 1, 29)
+NEW, LANES = 14, 2
 
-    def note(toks, pos, live, logits):
-        for t, p, ok, lg in zip(*map(np.asarray, (toks, pos, live, logits))):
-            if ok:
-                seen.append((int(t), int(p), lg))
 
-    step, prefill = nemotron_h.serve_decode_step, nemotron_h.serve_prefill
-
-    def decode_step(params, pages, tails, state, tokens, pos, ts, j, table,
-                    cfg, lora=None, plan=None):
-        out = step(params, pages, tails, state, tokens, pos, ts, j, table,
-                   cfg, lora, plan)
-        jax.debug.callback(note, tokens, pos,
-                           paged_attention.lanes_live(table), out[0])
-        return out
-
-    def prefill_rows(params, tokens, cfg, true_lens, lora=None):
-        out = prefill(params, tokens, cfg, true_lens, lora)
-        rows = jnp.arange(tokens.shape[0])
-        last = out[0][rows, true_lens - 1]
-        jax.debug.callback(
-            note, tokens[rows, true_lens - 1], true_lens - 1,
-            jnp.ones_like(true_lens, bool),
-            nemotron_h.project_logits(params, last).astype(jnp.float32))
-        return out
-
-    monkeypatch.setattr(nemotron_h, "serve_decode_step", decode_step)
-    monkeypatch.setattr(nemotron_h, "serve_prefill", prefill_rows)
-    return seen
+@pytest.fixture(scope="module")
+def served(params):
+    """ONE engine run for the file (`family_contract.served_run`): two
+    lanes whose state was marked, a request of 9 + 9 tokens alone, then
+    five prompts at once (two lanes: every wave is as wide as its rows,
+    so the counters are the prompts' own)."""
+    return contract.served_run(
+        nemotron_h, CFG, params, lanes=LANES, kv_pages=12, page=PAGE, k=K,
+        prompts=[_tokens(n, 10 + n).tolist() for n in PROMPTS], new=NEW)
 
 
 def test_engine_logits_equal_the_reference_across_lane_reuse(
-        params, monkeypatch):
-    """Two lanes, five prompts of other lengths: more requests than lanes,
-    so a lane that served one request serves another, and no state may
-    leak.  The LOGITS the engine's own programs computed at every served
-    position equal the reference's full forward, and the counters equal
-    what the kernels' work lists admit."""
-    seen = _record_engine_logits(monkeypatch)
-    eng = LLMEngine(CFG, params, max_batch=2, max_len=96, page_size=PAGE,
-                    kv_pages=12, steps_per_sync=K)
-    eng.start()
-    try:
-        prompts = [_tokens(n, 10 + n).tolist() for n in (40, 3, 17, 1, 29)]
-        futs = [eng.submit(p, max_new_tokens=14) for p in prompts]
-        outs = [f.result(timeout=300) for f in futs]
-        jax.effects_barrier()
-        st = eng.stats()
-    finally:
-        eng.stop()
-    assert st["completed"] == 5 and st["preemptions"] == 0
-    by_key = {}
-    for t, p, lg in seen:
-        by_key.setdefault((t, p), []).append(lg)
-    checked = 0
-    for prompt, out in zip(prompts, outs):
-        seq = prompt + out["tokens"]
-        want = np.asarray(ref.logits(params, seq[:-1], MODEL,
-                                     last=len(out["tokens"])))
-        for i, row in enumerate(want):
-            p = len(prompt) - 1 + i
-            got = by_key.get((seq[p], p), [])
-            assert got, (len(prompt), i)
-            assert min(_gap(g, row) for g in got) < TOL
-            checked += 1
-    assert checked == 5 * 14
+        params, served):
+    """Two lanes, a request and then five prompts of other lengths: more
+    requests than lanes, so a lane that served one request serves
+    another, and no state may leak.  The LOGITS the engine's own programs
+    computed at every served position equal the reference's full forward,
+    and the counters equal what the kernels' work lists admit."""
+    st = served["stats"]
+    assert st["completed"] == 1 + len(PROMPTS) and st["preemptions"] == 0
+    for i, (prompt, out) in enumerate(zip(served["prompts"],
+                                          served["outs"])):
+        assert len(out["tokens"]) == NEW
+        want = _ref_logits(params, (prompt + out["tokens"])[:-1], last=NEW)
+        assert contract.engine_gap(served, i, want) < TOL
     # the counters: live lanes x K x Mamba layers a window; the chunks of
     # 8 positions below the true lengths; every assignment of a live lane
     # computed, for every expert is held
+    prompts = served["prompts"] + [served["first_prompt"]]
     loop = st["loop"]
     assert loop["ssm_lane_steps"] == loop["lane_steps_live"] * N_MAMBA
     assert loop["prefill_scan_chunks"] == N_MAMBA * sum(
@@ -204,30 +179,24 @@ def test_engine_logits_equal_the_reference_across_lane_reuse(
         len(p) for p in prompts)
     lane = st["lane_state"]
     assert lane["layers"] == N_MAMBA
-    assert lane["by_kind"] == {"conv": N_MAMBA * 2 * 3 * CFG.conv_dim * 4,
-                               "ssm": N_MAMBA * 2 * 16 * 64 * 4}
+    assert lane["by_kind"] == {
+        "conv": N_MAMBA * LANES * 3 * CFG.conv_dim * 4,
+        "ssm": N_MAMBA * LANES * 16 * 64 * 4}
     assert lane["prefix_cache"] == "off: lane state"
     assert st["cache"]["kind"] == "kv"
 
 
-def test_a_dead_lanes_state_is_bit_unchanged_by_a_decode_window(params):
-    """One of two lanes holds a request: the window's K steps update its
-    state matrices and its convolution rows and leave the other lane's
-    as they were, bit for bit."""
-    eng = LLMEngine(CFG, params, max_batch=2, max_len=64, page_size=PAGE,
-                    steps_per_sync=K)
+def test_a_dead_lanes_state_is_bit_unchanged_by_a_decode_window(served):
+    """The run's first request (9 + 9 tokens) alone in an engine of two
+    lanes whose state was marked: the windows' steps update its lane's
+    state matrices and convolution rows, every one, and leave the other
+    lane's as they were, bit for bit."""
     for name in ("ssm", "conv"):
-        eng.cache["state"][name] = jnp.full_like(eng.cache["state"][name],
-                                                 0.375)
-    eng.start()
-    try:
-        eng.generate(_tokens(9, 4).tolist(), max_new_tokens=2 * K)
-    finally:
-        eng.stop()
-    for name in ("ssm", "conv"):
-        after = np.asarray(eng.cache["state"][name])
-        assert (after[:, 1] == 0.375).all(), name    # the lane nobody held
-        assert not (after[:, 0] == 0.375).any(axis=(1, 2)).any(), name
+        (used,) = contract.lanes_written(
+            served, lambda s: np.moveaxis(s[name], 1, 0))
+        before, after = (s[name] for s in served["state"])
+        assert (after[:, used] != before[:, used]).any(
+            axis=(-1, -2)).all(), name
 
 
 # ----------------------------------- (b) the share ties to the model
@@ -276,10 +245,11 @@ def test_a_quarter_of_the_experts_served_equals_the_reference_of_the_share(
     cfg = dataclasses.replace(CFG, experts_held=(2, 4))
     held = _range_params(params, 2, 4)
     model = dict(MODEL, experts_held=[2, 4])
-    assert _worst(held, held, cfg=cfg, model=model) < TOL
+    seam = Seam(nemotron_h, cfg)         # the share's programs, once
+    assert _worst(held, held, cfg=cfg, model=model, seam=seam) < TOL
     # and it is no other share's
     assert _worst(held, _range_params(params, 4, 6), cfg=cfg,
-                  model=dict(MODEL, experts_held=[4, 6])) > CONTROL
+                  model=dict(MODEL, experts_held=[4, 6]), seam=seam) > CONTROL
 
 
 # --------------------------------------- (d) the gated norm, by group
@@ -448,6 +418,23 @@ _SCATTER, _UPDATE = nemotron_h.scatter_prefill_pages, ssm.ssm_update
 _SCAN_INPUTS = nemotron_h.scan_inputs
 
 
+# A control changes one equation of one kind of layer, and its patch has
+# to be traced: it runs on the model cut to the first layers that still
+# hold the layer it changes ("ME": a Mamba mixer and a routed layer,
+# which every control but two needs; "MEM" for the second Mamba layer
+# skipped; "MEM*" for the attention layer's scale), against the
+# reference of the same cut.
+DEPTH = {"a_mamba_layer_skipped": 3, "attention_scale_1": 4}
+
+
+def _cut(params, n):
+    """(parameters, program config, the reference's model) of the first
+    `n` layers."""
+    return (dict(params, layers=params["layers"][:n]),
+            dataclasses.replace(CFG, pattern=CFG.pattern[:n]),
+            dict(MODEL, hybrid_override_pattern=CFG.pattern[:n]))
+
+
 def _without(params, kind, name, nth=0):
     """The parameters with `name` of the nth layer of `kind` zeroed."""
     lid = [i for i, c in enumerate(CFG.pattern) if c == kind][nth]
@@ -466,7 +453,10 @@ def _without(params, kind, name, nth=0):
     "dt_unmasked_past_the_true_length", "lane_state_zeroed_at_admission",
     "a_mamba_layer_skipped", "state_through_bfloat16"])
 def test_every_control_exceeds_the_tolerance(params, monkeypatch, control):
-    served, cfg = params, CFG
+    if control == "sound":       # the whole model, the file's seam
+        assert _worst(params, params, seam=SOUND) < TOL
+    params, cfg, model = _cut(params, DEPTH.get(control, 2))
+    served = params
     if control == "norm_over_the_whole_row":
         monkeypatch.setattr(nemotron_h, "gated_group_norm", _whole_row_norm)
     elif control == "norm_before_gate":
@@ -483,13 +473,13 @@ def test_every_control_exceeds_the_tolerance(params, monkeypatch, control):
     elif control == "shared_expert_left_out":
         served = _without(params, "E", "sw2")
     elif control == "latent_up_left_out":
-        served = _without(params, "E", "fc2", 1)
+        served = _without(params, "E", "fc2")
     elif control == "D_left_out":
         served = _without(params, "M", "D")
     elif control == "attention_scale_1":
         monkeypatch.setattr(nemotron_h, "softmax_scale", lambda cfg: 1.0)
     elif control == "scaling_factor_left_out":
-        cfg = dataclasses.replace(CFG, routed_scaling=1.0)
+        cfg = dataclasses.replace(cfg, routed_scaling=1.0)
     elif control == "dt_unmasked_past_the_true_length":
         monkeypatch.setattr(nemotron_h, "scan_inputs", _dt_unmasked)
     elif control == "lane_state_zeroed_at_admission":
@@ -499,7 +489,7 @@ def test_every_control_exceeds_the_tolerance(params, monkeypatch, control):
         served = _without(params, "M", "out_proj", 1)
     elif control == "state_through_bfloat16":
         monkeypatch.setattr(ssm, "ssm_update", _state_through_bf16)
-    worst = _worst(served, params, cfg=cfg)
+    worst = _worst(served, params, cfg=cfg, model=model)
     if control == "sound":
         assert worst < TOL
     elif control == "state_through_bfloat16":
@@ -544,6 +534,7 @@ def test_the_published_preset_is_the_published_model():
 
 
 def test_a_model_with_lane_state_is_served_without_the_prefix_cache(params):
+    # (engines that are refused at construction: nothing compiles)
     assert serving_model(CFG) is nemotron_h
     with pytest.raises(ValueError, match="radix prefix hit cannot restore"):
         LLMEngine(CFG, params, max_batch=2, max_len=64, page_size=PAGE,
@@ -554,6 +545,8 @@ def test_a_model_with_lane_state_is_served_without_the_prefix_cache(params):
 
 
 def test_the_server_serves_the_preset_by_name():
+    # an engine of its own: the preset as published (bfloat16), found by
+    # its name and served through `LLMServer`
     srv = LLMServer("nemotron-h-debug", max_batch=2, max_len=64,
                     page_size=PAGE)
     try:

@@ -5,8 +5,9 @@ ssm_hybrid.py`: the token-by-token recurrence, importing nothing of the
 program): the chunked scan at lengths that are no multiple of the chunk,
 the one-step kernel with idle lanes, the prompt pass at a padded bucket
 followed by paged decode through the pool and the lane state, the ENGINE's
-own logits with lanes reused and more requests than lanes, the controls a
-sound comparison must fail, and the counters."""
+own logits with lanes reused and more requests than lanes (one engine run
+shared by the file's cases: `family_contract`), the controls a sound
+comparison must fail, and the counters."""
 from __future__ import annotations
 
 import dataclasses
@@ -16,7 +17,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from serving_reference import served_logits  # rootdir-relative (no pkg)
+import family_contract as contract  # rootdir-relative (no pkg)
+from family_contract import gap as _gap, tokens as _tokens
+from serving_reference import Seam, served_logits
 
 from benchmarks.harness.refs import nemotron_h as ref_groups
 from benchmarks.harness.refs import ssm_hybrid as ref
@@ -43,23 +46,21 @@ TOL = 2e-5          # float32 against float32, of the logits' scale
 CONTROL = 2e-3      # what every control must exceed, 100 x TOL
 
 
-def _gap(got, want) -> float:
-    """The largest difference of two arrays of logits as a share of the
-    reference's largest logit (the embedding is drawn small, so the
-    logits are of scale 1e-2: `ssm_hybrid.init_params`)."""
-    got, want = np.asarray(got), np.asarray(want)
-    return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+# (`_gap`: a share of the reference's largest logit; the embedding is
+# drawn small, so the logits are of scale 1e-2: `ssm_hybrid.init_params`)
 PAGE, K = 16, 4
 N_MAMBA = CFG.layer_types.count("mamba")
+# The sound program's seam, compiled once a shape for the file (true
+# lengths are arguments), and the reference at ONE length (54 is the
+# longest sequence a case reads: 40 prompt tokens and 14 served).
+SOUND = Seam(ssm_hybrid, CFG)
+_ref_logits = contract.one_length(
+    lambda p, seq: ref.logits(p, seq, MODEL), 56)
 
 
 @pytest.fixture(scope="module")
 def params():
     return ssm_hybrid.init_params(jax.random.PRNGKey(7), CFG)
-
-
-def _tokens(n, seed=0):
-    return np.random.default_rng(seed).integers(0, 256, n).astype(np.int32)
 
 
 def _scan_inputs(b, T, H=4, P=16, N=16, lens=None, seed=0, G=1):
@@ -328,24 +329,32 @@ def test_ssm_update_with_one_group_is_the_one_group_call_bit_for_bit(
 
 
 # ------------------------- (c) prefill, then decode, against the forward
-def _worst(params_served, params_ref, n=21, bucket=32, follow=2 * K,
-           cfg=CFG):
+def _worst(params, n=21, bucket=32, follow=2 * K):
+    """The sound program (the file's seam) against the reference."""
     prompt, nxt = _tokens(n, 1), _tokens(follow, 2)
-    got = served_logits(ssm_hybrid, params_served, cfg, prompt, nxt, bucket,
+    got = served_logits(SOUND, params, CFG, prompt, nxt, bucket,
                         page=PAGE, k=K)
-    want = ref.logits(params_ref, list(prompt) + list(nxt), MODEL,
-                      last=follow + 1)
+    want = _ref_logits(params, list(prompt) + list(nxt), last=follow + 1)
     return _gap(got, want)
 
 
-@pytest.mark.parametrize("n", [1, 2, 17, 32])
-def test_prefill_logits_equal_the_reference(params, n):
-    toks = _tokens(32, 3)[None]
-    h, *_ = ssm_hybrid.prefill(params, jnp.asarray(toks), CFG,
-                               jnp.asarray([n], jnp.int32))
-    got = ssm_hybrid.project_logits(params, h[0, :n])
-    want = ref.logits(params, toks[0, :n], MODEL)
-    assert _gap(got, want) < TOL
+PREFILL_LENS = [1, 2, 17, 32]
+
+
+@pytest.fixture(scope="module")
+def prefill_rows(params):
+    """ONE prompt pass for the four lengths: the same 32 tokens in four
+    rows of one program, a true length each."""
+    toks, h = contract.prefill_rows(
+        SOUND, params, [_tokens(32, 3)] * len(PREFILL_LENS), PREFILL_LENS)
+    return toks[0], h
+
+
+@pytest.mark.parametrize("n", PREFILL_LENS)
+def test_prefill_logits_equal_the_reference(params, prefill_rows, n):
+    toks, h = prefill_rows
+    got = ssm_hybrid.project_logits(params, h[PREFILL_LENS.index(n), :n])
+    assert _gap(got, _ref_logits(params, toks[:n])) < TOL
 
 
 @pytest.mark.parametrize("n,bucket", [(21, 32), (1, 32), (2, 32), (3, 32),
@@ -356,14 +365,16 @@ def test_padded_prefill_then_paged_decode_equals_the_reference(
     lane state must be the state and the convolution rows at the TRUE
     length (zeros where the prompt is shorter than three), and two
     windows of K steps carry them on."""
-    assert _worst(params, params, n=n, bucket=bucket) < TOL
+    assert _worst(params, n=n, bucket=bucket) < TOL
 
 
 def test_the_prefill_hands_the_state_at_the_true_length(params):
     toks = _tokens(32, 5)
-    _, _, _, state, _ = ssm_hybrid.prefill(
-        params, jnp.asarray(toks[None]), CFG, jnp.asarray([13], jnp.int32))
-    got = jnp.concatenate(state["ssm"])[:, 0]           # [Mamba layers, ...]
+    # (the second row of the seam's two-row program of 32 positions)
+    _, _, _, state, _ = SOUND.serve_prefill(
+        params, jnp.asarray(np.stack([toks, toks])),
+        jnp.asarray([32, 13], jnp.int32))
+    got = jnp.concatenate(state["ssm"])[:, 1]           # [Mamba layers, ...]
     x = ref.embed(params, toks[:13], MODEL)
     want = []
     for kind, lp in ref.layers(params, MODEL):
@@ -376,89 +387,48 @@ def test_the_prefill_hands_the_state_at_the_true_length(params):
 
 
 # ------------------------------------------------ (c) through the engine
-def _record_engine_logits(monkeypatch):
-    """Every logit the engine's programs compute, as they compute it:
-    (input token, position, logits) of each live lane's decode step and of
-    each prefill row's last position."""
-    seen = []
+PROMPTS = (40, 3, 17, 1, 29)
+NEW, LANES = 14, 2
 
-    def note(toks, pos, live, logits):
-        for t, p, ok, lg in zip(*map(np.asarray, (toks, pos, live, logits))):
-            if ok:
-                seen.append((int(t), int(p), lg))
 
-    step, prefill = ssm_hybrid.serve_decode_step, ssm_hybrid.serve_prefill
-
-    def decode_step(params, pages, tails, state, tokens, pos, ts, j, table,
-                    cfg, lora=None, plan=None):
-        out = step(params, pages, tails, state, tokens, pos, ts, j, table,
-                   cfg, lora, plan)
-        jax.debug.callback(note, tokens, pos,
-                           paged_attention.lanes_live(table), out[0])
-        return out
-
-    def prefill_rows(params, tokens, cfg, true_lens, lora=None):
-        out = prefill(params, tokens, cfg, true_lens, lora)
-        rows = jnp.arange(tokens.shape[0])
-        last = out[0][rows, true_lens - 1]
-        jax.debug.callback(
-            note, tokens[rows, true_lens - 1], true_lens - 1,
-            jnp.ones_like(true_lens, bool),
-            ssm_hybrid.project_logits(params, last).astype(jnp.float32))
-        return out
-
-    monkeypatch.setattr(ssm_hybrid, "serve_decode_step", decode_step)
-    monkeypatch.setattr(ssm_hybrid, "serve_prefill", prefill_rows)
-    return seen
+@pytest.fixture(scope="module")
+def served(params):
+    """ONE engine run for the file (`family_contract.served_run`): two
+    lanes whose state was marked, a request of 9 + 9 tokens alone, then
+    five prompts at once (two lanes: every wave is as wide as its rows,
+    so the scan's counters are the prompts' own)."""
+    return contract.served_run(
+        ssm_hybrid, CFG, params, lanes=LANES, kv_pages=12, page=PAGE, k=K,
+        prompts=[_tokens(n, 10 + n).tolist() for n in PROMPTS], new=NEW)
 
 
 def test_engine_logits_equal_the_reference_across_lane_reuse(
-        params, monkeypatch):
-    """Two lanes, five prompts of other lengths: more requests than lanes,
-    so a lane that served one request serves another, and no state may
-    leak.  The LOGITS the engine's own programs computed at every served
-    position equal the reference's full forward, and the new counters
-    equal what the kernel's work list admits."""
-    seen = _record_engine_logits(monkeypatch)
-    eng = LLMEngine(CFG, params, max_batch=2, max_len=96, page_size=PAGE,
-                    kv_pages=12, steps_per_sync=K)
-    eng.start()
-    try:
-        prompts = [_tokens(n, 10 + n).tolist() for n in (40, 3, 17, 1, 29)]
-        futs = [eng.submit(p, max_new_tokens=14) for p in prompts]
-        outs = [f.result(timeout=300) for f in futs]
-        jax.effects_barrier()
-        st = eng.stats()
-    finally:
-        eng.stop()
-    assert st["completed"] == 5 and st["preemptions"] == 0
-    by_key = {}
-    for t, p, lg in seen:
-        by_key.setdefault((t, p), []).append(lg)
-    checked = 0
-    for prompt, out in zip(prompts, outs):
-        seq = prompt + out["tokens"]
-        want = np.asarray(ref.logits(params, seq[:-1], MODEL,
-                                     last=len(out["tokens"])))
-        for i, row in enumerate(want):
-            p = len(prompt) - 1 + i
-            got = by_key.get((seq[p], p), [])
-            assert got, (len(prompt), i)
-            assert min(_gap(g, row) for g in got) < TOL
-            checked += 1
-    assert checked == 5 * 14
+        params, served):
+    """Two lanes, a request and then five prompts of other lengths:
+    more requests than lanes, so a lane that served one request serves
+    another, and no state may leak.  The LOGITS the engine's own programs
+    computed at every served position equal the reference's full forward,
+    and the new counters equal what the kernel's work list admits."""
+    st = served["stats"]
+    assert st["completed"] == 1 + len(PROMPTS) and st["preemptions"] == 0
+    for i, (prompt, out) in enumerate(zip(served["prompts"],
+                                          served["outs"])):
+        assert len(out["tokens"]) == NEW
+        want = _ref_logits(params, (prompt + out["tokens"])[:-1], last=NEW)
+        assert contract.engine_gap(served, i, want) < TOL
     # the counters: live lanes x K x Mamba layers a window; the chunks of
     # 8 positions below the true lengths and of the padded programs
     loop = st["loop"]
     assert loop["ssm_lane_steps"] == loop["lane_steps_live"] * N_MAMBA
     assert loop["prefill_scan_chunks"] == N_MAMBA * sum(
-        -(-len(p) // 8) for p in prompts)
+        -(-len(p) // 8)
+        for p in served["prompts"] + [served["first_prompt"]])
     assert loop["prefill_scan_chunks"] <= loop["prefill_scan_chunks_dense"]
     assert loop["prefill_scan_chunks_dense"] % N_MAMBA == 0
     lane = st["lane_state"]
     assert lane["layers"] == N_MAMBA
-    assert lane["by_kind"] == {"conv": N_MAMBA * 2 * 3 * 96 * 4,
-                               "ssm": N_MAMBA * 2 * 16 * 64 * 4}
+    assert lane["by_kind"] == {"conv": N_MAMBA * LANES * 3 * 96 * 4,
+                               "ssm": N_MAMBA * LANES * 16 * 64 * 4}
     assert lane["bytes"] == sum(lane["by_kind"].values())
     assert lane["prefix_cache"] == "off: lane state"
     assert st["prefix_cache"] is False
@@ -477,24 +447,43 @@ def test_the_host_counts_what_the_kernels_work_list_admits(live):
         [i for i, on in enumerate(live) if on]
 
 
-def test_an_idle_lanes_state_is_bit_unchanged_by_a_decode_window(params):
-    """One of two lanes holds a request: the window's K steps update its
-    state matrices and leave the other lane's as they were."""
-    eng = LLMEngine(CFG, params, max_batch=2, max_len=64, page_size=PAGE,
-                    steps_per_sync=K)
-    mark = jnp.full_like(eng.cache["state"]["ssm"], 0.375)
-    eng.cache["state"]["ssm"] = mark
-    eng.start()
-    try:
-        eng.generate(_tokens(9, 4).tolist(), max_new_tokens=2 * K)
-    finally:
-        eng.stop()
-    after = np.asarray(eng.cache["state"]["ssm"])
-    assert (after[:, 1] == 0.375).all()          # the lane nobody held
-    assert not (after[:, 0] == 0.375).any(axis=(1, 2)).any()
+def test_an_idle_lanes_state_is_bit_unchanged_by_a_decode_window(served):
+    """The run's first request (9 + 9 tokens) alone in an engine of two
+    lanes whose state was marked: the windows' steps update every state
+    matrix of its lane and leave the other lane's as they were."""
+    (used,) = contract.lanes_written(
+        served, lambda s: np.moveaxis(s["ssm"], 1, 0))
+    before, after = (s["ssm"] for s in served["state"])
+    assert (after[:, used] != before[:, used]).any(axis=(-1, -2)).all()
 
 
 # ------------------------------------- (d) each scalar, each order: controls
+# A control changes one scalar or one equation of one kind of layer, and
+# its patch has to be traced: it runs on the model cut to its first three
+# layers, which keep both kinds (two Mamba layers, the second the one a
+# control skips, and the NoPE attention layer, each over its MLP, under
+# Granite's scalars), against the reference of the same cut.
+SHALLOW = dataclasses.replace(CFG, layer_types=CFG.layer_types[:3])
+SHALLOW_MODEL = dict(MODEL, layer_types=list(SHALLOW.layer_types))
+
+
+@pytest.fixture(scope="module")
+def shallow():
+    return ssm_hybrid.init_params(jax.random.PRNGKey(7), SHALLOW)
+
+
+def _cut_worst(served, sound, cfg=SHALLOW, model=SHALLOW_MODEL):
+    """`_worst` for a control: `served` (the cut's parameters, or a
+    control's change of them) through programs traced anew for `cfg`,
+    against the reference `model` on the `sound` parameters."""
+    prompt, nxt = _tokens(21, 1), _tokens(2 * K, 2)
+    got = served_logits(Seam(ssm_hybrid, cfg), served, cfg, prompt, nxt,
+                        32, page=PAGE, k=K)
+    want = ref.logits(sound, list(prompt) + list(nxt), model,
+                      last=2 * K + 1)
+    return _gap(got, want)
+
+
 def _no(name):
     return lambda cfg: dataclasses.replace(cfg, **{name: 1.0})
 
@@ -555,23 +544,26 @@ _SCATTER, _UPDATE = ssm_hybrid.scatter_prefill_pages, ssm.ssm_update
     "dt_unmasked_past_the_true_length",
     "conv_rows_at_the_padded_length", "lane_state_zeroed_at_admission",
     "a_mamba_layer_skipped", "state_through_bfloat16"])
-def test_every_control_exceeds_the_tolerance(params, monkeypatch, control):
-    served, cfg = params, CFG
-    if control == "attention_scale_1_over_8":
-        cfg = dataclasses.replace(CFG, attn_scale=0.125)
+def test_every_control_exceeds_the_tolerance(params, shallow, monkeypatch,
+                                            control):
+    served, cfg = shallow, SHALLOW
+    if control == "sound":       # the whole model, then the cut
+        assert _worst(params) < TOL
+    elif control == "attention_scale_1_over_8":
+        cfg = dataclasses.replace(SHALLOW, attn_scale=0.125)
     elif control == "rope_applied":
         monkeypatch.setattr(ssm_hybrid, "attn_prefill", _rope_applied)
     elif control == "residual_multiplier_left_out":
-        cfg = _no("residual_scale")(CFG)
+        cfg = _no("residual_scale")(SHALLOW)
     elif control == "embedding_multiplier_left_out":
-        cfg = _no("embed_scale")(CFG)
+        cfg = _no("embed_scale")(SHALLOW)
     elif control == "logits_scaling_left_out":
-        cfg = _no("logits_scale")(CFG)
+        cfg = _no("logits_scale")(SHALLOW)
     elif control == "norm_before_gate":
         monkeypatch.setattr(ssm_hybrid, "_gate_out", _norm_before_gate)
     elif control == "D_left_out":
-        served = dict(params, mamba=dict(
-            params["mamba"], D=jnp.zeros_like(params["mamba"]["D"])))
+        served = dict(shallow, mamba=dict(
+            shallow["mamba"], D=jnp.zeros_like(shallow["mamba"]["D"])))
     elif control == "dt_unmasked_past_the_true_length":
         monkeypatch.setattr(ssm_hybrid, "scan_inputs", _dt_unmasked)
     elif control == "conv_rows_at_the_padded_length":
@@ -580,21 +572,23 @@ def test_every_control_exceeds_the_tolerance(params, monkeypatch, control):
     elif control == "lane_state_zeroed_at_admission":
         monkeypatch.setattr(ssm_hybrid, "serve_scatter", _scatter_zero_state)
     elif control == "a_mamba_layer_skipped":
-        served = dict(params, mamba=dict(
-            params["mamba"],
-            out_proj=params["mamba"]["out_proj"].at[1].set(0.0)))
+        served = dict(shallow, mamba=dict(
+            shallow["mamba"],
+            out_proj=shallow["mamba"]["out_proj"].at[1].set(0.0)))
     elif control == "state_through_bfloat16":
         monkeypatch.setattr(ssm, "ssm_update", _state_through_bf16)
-    worst = _worst(served, params, cfg=cfg)
-    if control == "sound":
-        assert worst < TOL
-    elif control == "state_through_bfloat16":
         # a rounding of 2**-9 of the state a step moves these logits by
         # 7e-4 of their scale over the eight steps walked: 400 x the
         # sound reading (1.5e-6) and 30 x the tolerance, where every
         # other control stands 5,000 x outside it (the benchmark's judge
-        # reads the state itself: families/ssm_hybrid.STATE_ERR_TOL)
-        assert worst > 10 * TOL
+        # reads the state itself: families/ssm_hybrid.STATE_ERR_TOL).  On
+        # the WHOLE model: the cut's two Mamba layers move them by
+        # 1.7 x this control's bound, the model's six by 3.4 x
+        assert _cut_worst(params, params, CFG, MODEL) > 10 * TOL
+        return
+    worst = _cut_worst(served, shallow, cfg)
+    if control == "sound":
+        assert worst < TOL
     else:
         assert worst > CONTROL
 
@@ -625,6 +619,8 @@ def test_the_published_state_fits_eight_rows_a_program():
 
 # --------------------------------------------- what the engine refuses
 def test_a_state_space_model_is_served_without_the_prefix_cache(params):
+    # (engines that are refused, and one that is never started: nothing
+    # of theirs compiles)
     assert serving_model(CFG) is ssm_hybrid
     with pytest.raises(ValueError, match="radix prefix hit cannot restore"):
         LLMEngine(CFG, params, max_batch=2, max_len=64, page_size=PAGE,
@@ -638,6 +634,8 @@ def test_a_state_space_model_is_served_without_the_prefix_cache(params):
 
 
 def test_the_server_serves_the_preset_by_name():
+    # an engine of its own: the preset as published (bfloat16), found by
+    # its name and served through `LLMServer`
     srv = LLMServer("ssm-hybrid-debug", max_batch=2, max_len=64,
                     page_size=PAGE)
     try:
