@@ -52,7 +52,17 @@ from jax.experimental.pallas import tpu as pltpu
 # A step that does nothing costs 0.16 us, which is why the walk's axis
 # is bounded by a count and not only clamped; the mask outside the
 # diagonal's blocks was 0.8 % and the scale over the scores 1 % (left
-# where it is: the bytes stay).
+# where it is: the bytes stay).  PR 57 (PERF.md section 6, the kernel
+# alone by form): the running max read and written as the [block_q, 128]
+# lane-broadcast array the scratch is, never as a column (`_lanes`), took
+# a 512 x 512 step from 1.85 to 1.11 us (the banded calls: -38 % under a
+# band of 4,096, -20 ... -26 % under 513 and 128) and a 512 x 1,024 step
+# from 2.20 to 2.14; the running sum kept a lane's share and added up
+# across lanes once a query block (`_lane_sums`) took 3 ... 6 % more off
+# the causal calls and the train step's forward and ~1 % off the banded.
+# The edge mask as one iota difference against two scalars, or dropped,
+# the scale dropped, and exp2 for exp moved no call by 1 %: left as they
+# were.  2 / 4 heads a step read 3 ... 6 % under one: not taken.
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 1024
 NEG_INF = -1e30
@@ -141,7 +151,9 @@ def prefill_work(true_lens, bucket: int) -> tuple[dict, dict]:
 # What a serving module whose window layers' prefill is `flash_fwd` under
 # a band reports beside PREFILL_COUNTERS (which then count the banded
 # walk): prefill_swa_blocks / prefill_swa_blocks_dense = the share of the
-# causal walk inside the rows' true lengths that the band leaves.
+# causal walk inside the rows' true lengths that the band leaves, and
+# prefill_swa_edge_blocks / prefill_swa_blocks = the share of the banded
+# walk's steps that pay the mask.
 BAND_COUNTERS = {
     "prefill_swa_blocks": "(row, query block, key block) triples flash_fwd "
                           "multiplies under a window layer's band, a "
@@ -149,21 +161,43 @@ BAND_COUNTERS = {
     "prefill_swa_blocks_dense": "The triples the same calls would multiply "
                                 "without the band (causal, inside the true "
                                 "lengths)",
+    "prefill_swa_edge_blocks": "Of prefill_swa_blocks, the triples the "
+                               "diagonal or the band's lower edge crosses "
+                               "(masked steps)",
 }
 
 
 def band_blocks(sq: int, block_q: int = DEFAULT_BLOCK_Q,
                 block_k: int = DEFAULT_BLOCK_K) -> tuple[int, int]:
     """The blocks a BANDED call runs at: `fit_blocks`, the key block no
-    longer than the query block (a query block's band of ~block_q keys
-    then lies in two key blocks; at 1,024 keys a block it read three
-    halves of what it needs).  The band's own width does not enter: at a
-    band of 128 the walk's steps are all fixed cost, and fewer, larger
-    steps win (1 x 64 heads over 8 x 8,192 at 192 / 128, 6,144 true
-    positions, my chip run, PR 52: 512 x 512 5.78 ms, 256 x 256 6.20,
-    256 x 128 6.87, 512 x 256 7.01, 128 x 128 7.89, 512 x 128 9.44)."""
+    longer than the query block, whatever the band's width.  Measured
+    alone on the chip at 8,192 positions, 6,144 of them true (PR 57,
+    PERF.md section 6), ms a call at 512 x 512 / 512 x 1,024: a band of
+    4,096 (128 heads over 8 of 128) 10.84 / 12.01, of 513 (64 heads of
+    256 / 128) 3.63 / 4.43, of 128 (64 over 8 of 192 / 128, the
+    log-sum-exp kept) 4.02 / 4.86; before `_fwd_kernel` kept its
+    running max and sum as lane tiles they read 17.87 / 12.46, 4.71 /
+    4.62 and 5.11 / 5.07.  A step now costs what it multiplies (1.1 us
+    at 512 x 512, 2.2 at 512 x 1,024), so the shorter key block wins by
+    the masked pairs it does not multiply: a query block past a band of
+    4,096 walks 9 key blocks of 512 (4,608 keys for its 4,096 + 511) or
+    5 of 1,024 (5,120)."""
     block_q, block_k = fit_blocks(sq, sq, block_q, block_k)
     return block_q, min(block_q, block_k)
+
+
+def edge_blocks(sq: int, lengths, block_q: int, block_k: int,
+                window: int | None = None) -> int:
+    """Of `attn_blocks`' triples, those `_walk` flags `_EDGE`: the steps
+    that build and apply the mask (host arithmetic on the walk's own
+    tables)."""
+    n_keys = key_blocks(sq, sq, np.asarray(lengths), block_q, block_k,
+                        window=window)
+    steps = int(key_blocks(sq, sq, None, block_q, block_k,
+                           window=window).sum())
+    flag = _walk(n_keys, steps, block_q, block_k, True, np,
+                 **_band(sq, block_q, block_k, window))[2]
+    return int((flag & _EDGE != 0).sum())
 
 
 def band_work(window: int, true_lens, bucket: int) -> tuple[dict, dict]:
@@ -176,7 +210,16 @@ def band_work(window: int, true_lens, bucket: int) -> tuple[dict, dict]:
             len(true_lens) * -(-bucket // bq) * -(-bucket // bk),
             "prefill_swa_blocks": walked,
             "prefill_swa_blocks_dense":
-            attn_blocks(bucket, true_lens, bq, bk)}, {}
+            attn_blocks(bucket, true_lens, bq, bk),
+            "prefill_swa_edge_blocks":
+            edge_blocks(bucket, true_lens, bq, bk, window)}, {}
+
+
+def _band(sq: int, block_q: int, block_k: int, window: int | None, xp=np):
+    """`_walk`'s two band arguments; none without a window."""
+    return {} if window is None else {
+        "first": first_key_blocks(sq, block_q, block_k, window, xp),
+        "window": window}
 
 
 def _walk(n_keys, steps: int, block_q: int, block_k: int, causal: bool, xp,
@@ -266,28 +309,56 @@ def _fwd_kernel(qi_ref, ki_ref, flag_ref, q_ref, k_ref, v_ref, o_ref, *refs,
             if window is not None:      # the band: the last `window` keys
                 keep &= qpos - kpos < window
             s = jnp.where(keep, s, NEG_INF)
-        m_prev = m_ref[:, 0]                      # [bq]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        alpha = jnp.exp(m_prev - m_cur)           # [bq]
-        p = jnp.exp(s - m_cur[:, None])           # [bq, bk] f32
-        l_ref[:, 0] = l_ref[:, 0] * alpha + jnp.sum(p, axis=1)
-        acc_ref[...] = (acc_ref[...] * alpha[:, None]
+        # m: [bq, 128], a row's max in every lane, as the scratch is;
+        # l: a lane's share of the row's sum, added up on the last step
+        m_prev = m_ref[...]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_cur)
+        p = jnp.exp(s - _lanes(m_cur, block_k))   # [bq, bk] f32
+        l_ref[...] = l_ref[...] * alpha + _lane_sums(p)
+        acc_ref[...] = (acc_ref[...] * _lanes(alpha, v.shape[1])
                         + jax.lax.dot_general(
                             p.astype(v.dtype), v,
                             (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32))
-        m_ref[:, 0] = m_cur
+        m_ref[...] = m_cur
 
     pl.when(flag & _EDGE != 0)(functools.partial(update, True))
     pl.when(flag & _INSIDE != 0)(functools.partial(update, False))
 
     @pl.when(flag & _LAST != 0)
     def _write():
-        l = l_ref[:, 0]
+        l = jnp.broadcast_to(jnp.sum(l_ref[...], axis=1, keepdims=True),
+                             l_ref.shape)
         l = jnp.where(l == 0.0, 1.0, l)   # nothing admitted: zeros, no NaN
-        o_ref[...] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / _lanes(l, o_ref.shape[1])
+                      ).astype(o_ref.dtype)
         for ref in lse_ref:
-            ref[:, 0] = m_ref[:, 0] + jnp.log(l)
+            ref[...] = m_ref[...] + jnp.log(l)
+
+
+def _lanes(x, n: int):
+    """[rows, 128], a row's value in every lane -> [rows, n] the same:
+    whole lane tiles side by side (no column is read out and broadcast:
+    at 512 keys a step that cost 0.75 us of a step's 1.85 on the chip)."""
+    if n % 128:
+        return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+    return x if n == 128 else pltpu.repeat(x, n // 128, 1)
+
+
+def _lane_sums(p):
+    """[rows, n] -> [rows, 128] whose lanes add up to each row's sum: the
+    lane tiles added to one another, and no sum ACROSS lanes a step (at
+    1,024 keys a step that was 5 % of the causal call on the chip).  A
+    block of no whole lane tiles: the row's sum in lane 0."""
+    rows, n = p.shape
+    if n % 128:
+        lane = jax.lax.broadcasted_iota(jnp.int32, (rows, 128), 1)
+        return jnp.where(lane == 0, jnp.sum(p, axis=1, keepdims=True), 0.0)
+    part = p[:, :128]
+    for i in range(1, n // 128):
+        part = part + p[:, i * 128:(i + 1) * 128]
+    return part
 
 
 def _flash_fwd(q, k, v, lengths, sm_scale, causal, block_q, block_k,
@@ -317,11 +388,8 @@ def _flash_fwd(q, k, v, lengths, sm_scale, causal, block_q, block_k,
                              "mask a true row sees the padded keys")
         n_keys, xp = key_blocks(sq, skv, lengths.astype(jnp.int32), block_q,
                                 block_k, causal, jnp, window), jnp
-    band = {} if window is None else {
-        "first": first_key_blocks(sq, block_q, block_k, window, xp),
-        "window": window}
     *tables, total = _walk(n_keys, steps, block_q, block_k, causal, xp,
-                           **band)
+                           **_band(sq, block_q, block_k, window, xp))
     # no lengths: one row of tables for every row, all of it static
     stride, n_steps = (0, steps) if lengths is None \
         else (steps, jnp.max(total))

@@ -1726,7 +1726,9 @@ def test_the_blocked_ring_kernel_compiles_at_the_served_widths(
 def test_a_band_of_4096_without_a_sink_is_named_swa_band(
         one_chip, compiled_kernels):
     """The flash forward under a band of 4,096 at 128 heads over 8 of 128
-    over 8,192 positions, no sink: `band_name` names the kernel."""
+    over 8,192 positions, no sink: `band_name` names the kernel, and the
+    call compiles at `band_blocks`' 512 x 512 with its running max and
+    sum kept lane-broadcast (a walk of 36 + 8 x 9 steps a head)."""
     from ray_tpu.ops import flash_attention
 
     def s(shape, dtype=jnp.bfloat16):
@@ -1741,6 +1743,9 @@ def test_a_band_of_4096_without_a_sink_is_named_swa_band(
     assert low.as_text().count("tpu_custom_call") == 1
     assert "swa_band" in low.as_text()
     assert "flash_fwd" not in low.as_text()
+    steps = flash_attention.attn_blocks(
+        8192, [8192], *flash_attention.band_blocks(8192), window=4096)
+    assert steps == 108 and f"tensor<{steps}xi32>" in low.as_text()
 
 
 @pytest.mark.time_limit(900)

@@ -21,9 +21,10 @@ from serving_reference import Seam, served_logits
 
 from benchmarks.harness.refs import cohere2_moe as ref
 from ray_tpu.models import cohere2_moe, named_config, routed, serving_model
-from ray_tpu.ops import live_rows, ssm
+from ray_tpu.ops import flash_attention, live_rows, ssm
 from ray_tpu.ops import rope as rope_ops
 from ray_tpu.ops import window_attention as swa
+from ray_tpu.ops.attention import xla_attention
 from ray_tpu.serve.llm import LLMServer
 
 # float32 weights: the served path and the reference then differ by
@@ -248,6 +249,70 @@ def test_a_ring_of_no_whole_number_of_blocks_is_refused():
     assert swa.ring_blocks(9) == (9, 1)
 
 
+@pytest.mark.parametrize("T,window,lens,blocks", [
+    (2048, 1025, [2048, 1100], (128, 128)),     # 9-10 key blocks a query block
+    (2048, 1024, [1300, 2048], (256, 256)),     # 5 of them, 2 of them edges
+    (1024, 1024, [1024, 515], (128, 128)),      # the band holds every key
+])
+def test_the_banded_flash_kernel_over_several_key_blocks_equals_a_masked_softmax(
+        T, window, lens, blocks):
+    """The window layers' prefill call (`flash_fwd` named `swa_band`, no
+    sink, 16 query heads over 1 kv head of 128) under a band that spans
+    several KEY blocks, ragged lengths with a row ending inside a query
+    block past the window, against XLA's masked softmax on every row's
+    true positions."""
+    ks = jax.random.split(jax.random.PRNGKey(window), 3)
+    q = jax.random.normal(ks[0], (2, T, 16, 128))
+    k = jax.random.normal(ks[1], (2, T, 1, 128))
+    v = jax.random.normal(ks[2], (2, T, 1, 128))
+    got = flash_attention.flash_attention(
+        q, k, v, sm_scale=0.09, block_q=blocks[0], block_k=blocks[1],
+        window=window, lengths=jnp.asarray(lens, jnp.int32),
+        band_name="swa_band")
+    want = xla_attention(q, k, v, sm_scale=0.09, window=window)
+    assert flash_attention.band_blocks(T, *blocks) == blocks
+    for row, n in enumerate(lens):
+        assert _gap(got[row, :n], want[row, :n]) < 1e-5
+    short = int(np.argmin(lens))
+    assert not np.asarray(
+        got[short, -(-lens[short] // blocks[0]) * blocks[0]:]).any()
+    # the band's walk: fewer steps than the causal walk, most unmasked
+    walked = flash_attention.attn_blocks(T, lens, *blocks, window)
+    assert walked < flash_attention.attn_blocks(T, lens, *blocks) \
+        or window >= T
+    assert flash_attention.edge_blocks(T, lens, *blocks, window) < walked
+
+
+def test_a_band_of_4096_walks_nine_key_blocks_a_query_block():
+    """Command A+'s window layers at 8,192 run at `band_blocks`' 512 x 512
+    (faster on the chip than `fit_blocks`' 512 x 1,024 once a step cost
+    what it multiplies): a query block past the window walks 9 key blocks
+    (5 at 1,024, of 1,024 keys more), one before it the causal count; two
+    of a query block's steps are masked edges (the diagonal's block and,
+    from the window on, the lower edge's); `band_work` counts the same."""
+    assert flash_attention.band_blocks(8192) == (512, 512)
+    n = flash_attention.key_blocks(8192, 8192, None, 512, 512, window=4096)
+    causal = flash_attention.key_blocks(8192, 8192, None, 512, 512)
+    assert n[0, :8].tolist() == causal[0, :8].tolist() == list(range(1, 9))
+    assert n[0, 8:].tolist() == [9] * 8
+    assert flash_attention.key_blocks(8192, 8192, None, 512, 1024,
+                                      window=4096)[0, 8:].tolist() == [5] * 8
+    first = flash_attention.first_key_blocks(8192, 512, 512, 4096)
+    qi, ki, flag, total = flash_attention._walk(
+        n, int(n.sum()), 512, 512, True, np, first=first, window=4096)
+    assert total.tolist() == [36 + 8 * 9]
+    tenth = ki[qi == 9].tolist(), flag[qi == 9].tolist()
+    assert tenth == (list(range(1, 10)), [1 | 8] + [4] * 7 + [2 | 8])
+    work, _ = flash_attention.band_work(4096, [8192], 8192)
+    assert work == {
+        "prefill_attn_blocks": 108, "prefill_attn_blocks_dense": 256,
+        "prefill_swa_blocks": 108, "prefill_swa_blocks_dense": 136,
+        "prefill_swa_edge_blocks": 16 + 8}
+    mean, _ = flash_attention.band_work(4096, [6689], 8192)
+    assert (mean["prefill_swa_blocks"], mean["prefill_swa_edge_blocks"]) \
+        == (36 + 6 * 9, 14 + 6)
+
+
 def test_decode_work_counts_the_rows_of_the_blocks_walked():
     """A ring of 4,096 rows in blocks of 1,024 at a window of 4,096:
     a lane on 1,500 rows attends them all and reads two blocks; one past
@@ -397,6 +462,7 @@ def test_the_engine_counts_what_the_layers_read(served):
     assert loop["swa_rows_read"] == steps * n_win * RING
     assert loop["swa_rows_read"] >= loop["swa_rows_attended"]
     assert 0 < loop["prefill_swa_blocks"] <= loop["prefill_swa_blocks_dense"]
+    assert 0 < loop["prefill_swa_edge_blocks"] <= loop["prefill_swa_blocks"]
     assert loop["prefill_attn_blocks"] > 0
     assert loop["prefill_walked_tokens"] == loop["prefill_padded_tokens"] > 0
     # every layer is routed
@@ -442,7 +508,8 @@ def test_the_seam_declares_what_the_engine_counts():
         * CFG.head_dim * 4
     assert {"swa_rows_read", "swa_rows_attended", "swa_rows_context",
             "swa_lane_steps", "prefill_walked_tokens", "moe_experts_hit",
-            "prefill_swa_blocks"} <= set(spec.counters)
+            "prefill_swa_blocks", "prefill_swa_edge_blocks"} <= set(
+                spec.counters)
     streamed, multiplied = spec.prefill_params
     attn = 2 * CFG.dim * CFG.head_dim * (CFG.n_heads + CFG.n_kv_heads)
     one = 3 * CFG.dim * CFG.moe_ffn_dim

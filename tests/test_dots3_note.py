@@ -171,10 +171,21 @@ def test_the_ring_names_the_windows_rows():
         assert (held[b] % 16 == np.arange(16)).all()
 
 
-@pytest.mark.parametrize("T,window,lens", [(512, 65, None),
-                                           (384, 129, [384, 131]),
-                                           (256, 257, [77, 256])])
-def test_the_banded_flash_kernel_equals_a_masked_softmax(T, window, lens):
+@pytest.mark.parametrize("T,window,lens,blocks", [
+    (512, 65, None, (128, 128)),
+    (384, 129, [384, 131], (128, 128)),
+    (256, 257, [77, 256], (128, 128)),
+    # a band of several KEY blocks: 1,025 + 127 keys of a query block lie
+    # in 9-10 blocks of 128 (two of them masked edges); row 1 ends inside
+    # a query block whose lower edge is a partial block; a caller's key
+    # block longer than its query block is cut to it (`band_blocks`)
+    (2048, 1025, [2048, 1100], (128, 128)),
+    (2048, 1024, [1300, 2048], (256, 512)),
+    # key blocks of no whole lane tile (the running sum's lane 0)
+    (192, 65, [192, 70], (64, 64)),
+])
+def test_the_banded_flash_kernel_equals_a_masked_softmax(T, window, lens,
+                                                         blocks):
     """`flash_fwd` under a band at keys wider than values (256 / 128, a
     window layer's expanded path) against XLA's masked softmax; with
     lengths, on every row's true positions."""
@@ -184,19 +195,26 @@ def test_the_banded_flash_kernel_equals_a_masked_softmax(T, window, lens):
     k = jax.random.normal(ks[1], (b, T, H, 256))
     v = jax.random.normal(ks[2], (b, T, H, 128))
     got = flash_attention.flash_attention(
-        q, k, v, sm_scale=0.07, block_q=128, block_k=128, window=window,
+        q, k, v, sm_scale=0.07, block_q=blocks[0], block_k=blocks[1],
+        window=window,
         lengths=None if lens is None else jnp.asarray(lens, jnp.int32))
     want = xla_attention(q, k, v, sm_scale=0.07, window=window)
     for row, n in enumerate(lens or [T]):
         assert _gap(got[row, :n], want[row, :n]) < 1e-5
+    bq, bk = flash_attention.band_blocks(T, *blocks)
+    assert (bq, bk) == (blocks[0], min(blocks))
     if lens is not None:        # wholly past a row's length: zeros
-        assert not np.asarray(got[1, -(-lens[1] // 128) * 128:]).any() \
-            or lens[1] == T
-    bq, bk = flash_attention.band_blocks(T, 128, 128)
+        short = int(np.argmin(lens))
+        assert not np.asarray(
+            got[short, -(-lens[short] // bq) * bq:]).any()
     walked = flash_attention.attn_blocks(T, lens or [T], bq, bk, window)
     assert walked <= flash_attention.attn_blocks(T, lens or [T], bq, bk)
     if window < T // 4:         # the band leaves blocks out
         assert walked < flash_attention.attn_blocks(T, lens or [T], bq, bk)
+    edges = flash_attention.edge_blocks(T, lens or [T], bq, bk, window)
+    assert 0 < edges <= walked
+    if window >= 4 * bk:        # a wide band: most steps pay no mask
+        assert edges < walked / 2
 
 
 def test_a_call_without_a_window_walks_what_it_walked():
@@ -302,6 +320,7 @@ def test_the_engine_counts_what_the_layers_read(served):
         < loop["dsa_rows_context"] + steps * n_full * PAGE
     assert loop["dsa_rows_read"] % PAGE == 0
     assert 0 < loop["prefill_swa_blocks"] <= loop["prefill_swa_blocks_dense"]
+    assert 0 < loop["prefill_swa_edge_blocks"] <= loop["prefill_swa_blocks"]
     assert loop["prefill_attn_blocks"] == loop["prefill_swa_blocks"]
     cache = st["cache"]
     # window layers hold no pool page: two leaves a FULL layer only
@@ -459,6 +478,7 @@ def test_the_seam_declares_what_the_engine_counts():
     assert shown == {} and set(work) == {
         "prefill_attn_blocks", "prefill_attn_blocks_dense",
         "prefill_swa_blocks", "prefill_swa_blocks_dense",
+        "prefill_swa_edge_blocks",
         "dsa_prefill_blocks", "dsa_prefill_blocks_dense"}
     streamed, multiplied = spec.prefill_params
     assert (streamed, multiplied) == model.prefill_params(CFG)

@@ -298,11 +298,17 @@ def test_a_ring_row_is_written_where_the_window_left_one():
             ).all() and not ring[1, :, 5:].any()
 
 
-@pytest.mark.parametrize("T,window,lens", [(512, 128, None),
-                                           (384, 128, [384, 131]),
-                                           (256, 65, [77, 256])])
+@pytest.mark.parametrize("T,window,lens,blocks", [
+    (512, 128, None, None),
+    (384, 128, [384, 131], None),
+    (256, 65, [77, 256], None),
+    # a band of several KEY blocks (9-10 of 128, 5 of 256 a query block),
+    # a row that ends inside a query block past the window
+    (2048, 1025, [2048, 1100], (128, 128)),
+    (2048, 1024, [1300, 2048], (256, 256)),
+])
 def test_the_banded_flash_kernel_with_a_sink_equals_a_masked_softmax(
-        T, window, lens):
+        T, window, lens, blocks):
     """`flash_fwd` under a band of 128 with a learned sink a head, 8
     query heads over 2 kv heads at keys wider than values (192 / 128),
     against XLA's masked softmax with the sink as an extra column; with
@@ -313,28 +319,34 @@ def test_the_banded_flash_kernel_with_a_sink_equals_a_masked_softmax(
     k = jax.random.normal(ks[1], (b, T, G, 192))
     v = jax.random.normal(ks[2], (b, T, G, 128))
     sink = jax.random.normal(ks[3], (H,))
+    given = {} if blocks is None else {"block_q": blocks[0],
+                                       "block_k": blocks[1]}
     got = flash_attention.flash_attention(
-        q, k, v, sm_scale=0.07, window=window, sink=sink,
+        q, k, v, sm_scale=0.07, window=window, sink=sink, **given,
         lengths=None if lens is None else jnp.asarray(lens, jnp.int32))
     want = xla_attention(q, k, v, sm_scale=0.07, window=window, sink=sink)
     bare = xla_attention(q, k, v, sm_scale=0.07, window=window)
     for row, n in enumerate(lens or [T]):
         assert _gap(got[row, :n], want[row, :n]) < 1e-5
         assert _gap(bare[row, :n], want[row, :n]) > 1e-2
-    bq, _ = flash_attention.band_blocks(T)
+    bq, _ = flash_attention.band_blocks(T, *(blocks or ()))
     if lens is not None:        # query blocks wholly past a length: zeros
-        assert not np.asarray(got[0, -(-lens[0] // bq) * bq:]).any()
+        short = int(np.argmin(lens))
+        assert not np.asarray(
+            got[short, -(-lens[short] // bq) * bq:]).any()
 
 
 def test_a_band_of_128_walks_two_key_blocks_a_query_block():
     """The band's width does not pick the blocks (the chip was fastest at
-    the blocks a band of 513 runs at: `band_blocks`): a 1 x 8192 call
-    under a band of 128 walks 2 key blocks of 512 a query block, the
-    first 1: 31 of the causal walk's 136."""
+    512 x 512 under a band of 128, of 513 and of 4,096: `band_blocks`):
+    a 1 x 8192 call under a band of 128 walks 2 key blocks of 512 a query
+    block, the first 1: 31 of the causal walk's 136, every one of them a
+    masked edge step."""
     assert flash_attention.band_blocks(8192) == (512, 512)
     work, _ = flash_attention.band_work(128, [8192], 8192)
     assert (work["prefill_swa_blocks"], work["prefill_swa_blocks_dense"]) \
         == (31, 136)
+    assert work["prefill_swa_edge_blocks"] == 31
     short, _ = flash_attention.band_work(128, [4097], 8192)
     assert short["prefill_swa_blocks"] == 1 + 8 * 2
 
@@ -380,6 +392,7 @@ def test_the_engine_counts_what_the_layers_read(served):
     assert loop["swa_rows_attended"] < loop["swa_rows_context"]
     assert loop["swa_rows_attended"] <= steps * n_win * WINDOW
     assert 0 < loop["prefill_swa_blocks"] <= loop["prefill_swa_blocks_dense"]
+    assert 0 < loop["prefill_swa_edge_blocks"] <= loop["prefill_swa_blocks"]
     assert loop["prefill_attn_blocks"] > 0
     # every bucket here is one chunk: the halves walk what the bucket pads
     assert loop["prefill_walked_tokens"] == loop["prefill_padded_tokens"] > 0
@@ -548,6 +561,7 @@ def test_the_seam_declares_what_the_engine_counts():
     assert shown == {"walked_tokens": 64} and set(work) == {
         "prefill_attn_blocks", "prefill_attn_blocks_dense",
         "prefill_swa_blocks", "prefill_swa_blocks_dense",
+        "prefill_swa_edge_blocks",
         "prefill_walked_tokens"}
     assert set(work) <= set(spec.counters)
     streamed, multiplied = spec.prefill_params
