@@ -21,7 +21,8 @@ _SERVING = {"LlamaConfig": "ray_tpu.models.llama",
             "Dots3NoteConfig": "ray_tpu.models.dots3_note",
             "NemotronHConfig": "ray_tpu.models.nemotron_h",
             "MimoV2Config": "ray_tpu.models.mimo_v2",
-            "Cohere2MoeConfig": "ray_tpu.models.cohere2_moe"}
+            "Cohere2MoeConfig": "ray_tpu.models.cohere2_moe",
+            "SolarOpen2Config": "ray_tpu.models.solar_open2"}
 
 
 def serving_model(cfg):

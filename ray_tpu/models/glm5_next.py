@@ -29,8 +29,10 @@ h(sum of the n rows of X_L) W_head (assumed: summed; head untied).  A
 PREFILL keeps the n streams apart, n arrays [b, T, d] (`mhc_halves`,
 `layer_prefill`): the same equations with no [.., n, d] array formed.
 
-**KDA mixer** (`layer_types[l] == "linear_attention"`; `kda_inputs`,
-`ops/kda.py`), u = h(input), per head of `kda_head_dim`:
+**KDA mixer** (`layer_types[l] == "linear_attention"`; the glue is
+`models/kda_layer.py`'s, shared with `models/solar_open2.py`, under THIS
+module's `kda_gate`; `ops/kda.py`), u = h(input), per head of
+`kda_head_dim`:
 
     q, k = L2Norm(silu(Conv(u W_q))), L2Norm(silu(Conv(u W_k)))
     v = silu(Conv(u W_v))          Conv: depthwise, causal, `conv_kernel`
@@ -100,11 +102,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models import routed
+from ray_tpu.models import kda_layer, routed
+from ray_tpu.models.kda_layer import BARE
 from ray_tpu.models.llama import embed_lookup, rmsnorm, scatter_rows
 from ray_tpu.models.routed import route, shared_ffn
 from ray_tpu.models.serving import ServingSpec, merged
-from ray_tpu.ops import kda, live_rows, sparse_attention as dsa, ssm
+from ray_tpu.ops import kda  # noqa: F401  (`kda.max_chunk`: `kda_chunk`)
+from ray_tpu.ops import live_rows, sparse_attention as dsa, ssm
 from ray_tpu.ops.norms import layernorm
 from ray_tpu.ops.paged_attention import lanes_live
 
@@ -211,13 +215,9 @@ def serving_spec(cfg: Glm5NextConfig) -> ServingSpec:
     prefill attention is `dsa.masked_prefill_attention`, not
     `flash_fwd`: `dsa_prefill_blocks*`, no `prefill_attn_blocks`."""
     n_kda = cfg.count(KDA)
-    kda_layer = (cfg.kda_inner * cfg.kda_head_dim
-                 * jnp.dtype(cfg.state_dtype).itemsize
-                 + (cfg.conv_kernel - 1) * 3 * cfg.kda_inner
-                 * jnp.dtype(cfg.dtype).itemsize)
     return ServingSpec(
         lane_state_layers=n_kda,
-        prefill_state_bytes=(n_kda * kda_layer
+        prefill_state_bytes=(n_kda * kda_layer.state_bytes(cfg)
                              + cfg.count(DSA) * 4 * cfg.index_dim),
         prefill_params=prefill_params(cfg),
         routed_layers=_routed_layers(cfg),
@@ -240,9 +240,7 @@ def prefill_params(cfg: Glm5NextConfig) -> tuple[int, int]:
     """Matmul parameters a prefill program STREAMS whatever it holds and
     those ONE position multiplies (`routed.prefill_params`)."""
     d, H = cfg.dim, cfg.n_heads
-    r = cfg.kda_head_dim
-    kda_p = (4 * d * cfg.kda_inner + 2 * (d * r + r * cfg.kda_inner)
-             + d * H)
+    kda_p = kda_layer.matmul_params(cfg)
     dsa_p = (d * cfg.q_lora_rank + cfg.q_lora_rank * H * cfg.qk_head_dim
              + d * cfg.kv_lora_rank
              + H * cfg.kv_lora_rank * (cfg.qk_head_dim + cfg.v_head_dim)
@@ -271,8 +269,7 @@ def init_params(key: jax.Array, cfg: Glm5NextConfig,
     = b_post = 0, b_res = 2 I, phi normal at (n d)^-0.5, so that H_res
     keeps most of a stream where it is and mixes the rest by the token.
     `expert_bias` N(0, expert_bias_std) over all `n_experts`."""
-    d, H, dk = cfg.dim, cfg.n_heads, cfg.kda_head_dim
-    inner, n = cfg.kda_inner, cfg.hc_mult
+    d, H, n = cfg.dim, cfg.n_heads, cfg.hc_mult
     f, fs = cfg.moe_ffn_dim, cfg.moe_ffn_dim * cfg.n_shared_experts
     G = cfg.experts_held[1] - cfg.experts_held[0]
     keys = iter(jax.random.split(key, 4 + 40 * cfg.n_layers))
@@ -294,17 +291,7 @@ def init_params(key: jax.Array, cfg: Glm5NextConfig,
               "norm2": jnp.ones((d,), cfg.dtype),
               "hc_mix": hc(), "hc_ffn": hc()}
         if kind == KDA:
-            lp.update(
-                w_qkv=w((d, 3 * inner), d),
-                conv_w=w((cfg.conv_kernel, 3 * inner), cfg.conv_kernel),
-                wf1=w((d, dk), d), wf2=w((dk, inner), dk),
-                A_log=jnp.log(jax.random.uniform(next(keys), (H,), F32,
-                                                 0.5, 2.0)),
-                dt_bias=jax.random.uniform(next(keys), (inner,), F32,
-                                           -6.0, -1.0),
-                w_beta=w((d, H), d), wg1=w((d, dk), d),
-                wg2=w((dk, inner), dk), o_norm=jnp.ones((dk,), cfg.dtype),
-                wo=w((inner, d), inner))
+            lp.update(kda_layer.init_layer(w, keys, cfg))
         else:
             r, qr = cfg.kv_lora_rank, cfg.q_lora_rank
             lp.update(
@@ -415,10 +402,6 @@ def mhc_halves(hp, cfg: Glm5NextConfig):
     return enter, leave
 
 
-# no residual path around: what a sublayer computes from its [..., d] input
-BARE = (lambda x: (x, ())), (lambda x, maps, y: y)
-
-
 def sublayer(X, hp, cfg: Glm5NextConfig, fn):
     """X <- H_res X + H_post (outer) fn(H_pre X); fn returns what the
     sublayer computes from its [..., d] input, and anything else it has
@@ -488,15 +471,11 @@ def ffn(x, lp, lid: int, cfg: Glm5NextConfig, live=None, around=BARE):
 
 
 # ---------------------------------------------------------------- KDA mixer
-def _l2norm(x):
-    xf = x.astype(F32)
-    return xf * lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True) + 1e-6)
-
-
 def kda_gate(h, lp, cfg: Glm5NextConfig):
     """The log decay a key channel, in [gate_lower_bound, 0] (assumed
     form), and the write strength a head: (g [..., H, dk], beta [..., H])
-    float32."""
+    float32.  THIS family's gate: the rest of the mixer is
+    `models/kda_layer.py`'s, which takes it as an argument."""
     f = (h @ lp["wf1"]) @ lp["wf2"]
     A = jnp.repeat(jnp.exp(lp["A_log"]), cfg.kda_head_dim)
     g = cfg.gate_lower_bound * jax.nn.sigmoid(
@@ -505,106 +484,28 @@ def kda_gate(h, lp, cfg: Glm5NextConfig):
     return g.reshape(*h.shape[:-1], cfg.n_heads, cfg.kda_head_dim), beta
 
 
-def _conv(rows, lp):
-    """silu(sum_i conv_w[i] * rows[i]) in float32; rows oldest first."""
-    acc = sum(r.astype(F32) * lp["conv_w"][i].astype(F32)
-              for i, r in enumerate(rows))
-    return jax.nn.silu(acc)
-
-
-def _qkv(act, cfg: Glm5NextConfig):
-    """The convolved projections [..., 3 inner] float32 -> (q scaled, k,
-    v) [..., H, dk], q and k of unit length."""
-    shape = act.shape[:-1] + (cfg.n_heads, cfg.kda_head_dim)
-    q, k, v = (a.reshape(shape) for a in jnp.split(act, 3, axis=-1))
-    return _l2norm(q) * cfg.kda_head_dim ** -0.5, _l2norm(k), v
-
-
 def kda_inputs(h, lp, cfg: Glm5NextConfig, true_lens):
-    """Everything the scan takes, over whole rows h [b, T, d] (normed):
-    (q, k, v [b, T, H, dk] float32, g [b, T, H, dk], beta [b, T, H], both
-    ZERO past each row's true length, conv rows [b, K-1, 3 inner]: the
-    pre-convolution rows before each row's TRUE length)."""
-    b, T, _ = h.shape
-    K = cfg.conv_kernel
-    with jax.named_scope("kda_in_proj"):
-        proj = h @ lp["w_qkv"]
-        g, beta = kda_gate(h, lp, cfg)
-    with jax.named_scope("kda_conv"):
-        xp = jnp.pad(proj, ((0, 0), (K - 1, 0), (0, 0)))
-        q, k, v = _qkv(_conv([xp[:, i:i + T] for i in range(K)], lp), cfg)
-        at = true_lens[:, None] + jnp.arange(K - 1)[None, :]
-        rows = jnp.take_along_axis(xp, at[..., None], axis=1)
-    live = jnp.arange(T)[None, :] < true_lens[:, None]
-    return (q, k, v, jnp.where(live[..., None, None], g, 0.0),
-            jnp.where(live[..., None], beta, 0.0), rows)
-
-
-def kda_out(o, h, lp, cfg: Glm5NextConfig):
-    """W_o (RMSNorm_head(o) * sigmoid(u W_g1 W_g2)); o [..., H, dv]
-    float32."""
-    with jax.named_scope("kda_out"):
-        gate = jax.nn.sigmoid(((h @ lp["wg1"]) @ lp["wg2"]).astype(F32))
-        y = rmsnorm(o, lp["o_norm"], cfg.norm_eps).reshape(gate.shape)
-        return (y * gate).astype(cfg.dtype) @ lp["wo"]
+    """`kda_layer.inputs` under this module's gate."""
+    return kda_layer.inputs(h, lp, cfg, true_lens, kda_gate)
 
 
 def kda_prefill(x, lp, cfg: Glm5NextConfig, true_lens, around=BARE):
-    """The KDA mixer over whole rows x [b, T, d]: (what it computes,
-    (conv rows [b, K-1, 3 inner], the state at each row's TRUE length
-    [b, H, dk, dv] in `state_dtype`)).  What follows the scan (the head
-    norm, the gate, `wo`) is computed a position alone and walks the rows
-    up to the longest true length (`live_rows.walk`): zeros past the
-    walked chunks.  What precedes it stays whole: it writes 24,576 + 4 x
-    8,192 columns a position, and walked, the copies of its chunks into
-    the buffers the loop carries cost more than the padding skipped
-    (PERF.md section 6, PR 55).  `around`: the halves of the residual
-    path (`mhc_halves`), the second computed inside the walk; x is then
-    the n streams apart and so is the result."""
-    enter, leave = around
-    x_in, maps = enter(x)
-    h = rmsnorm(x_in, lp["norm1"], cfg.norm_eps)
-    q, k, v, g, beta, rows = kda_inputs(h, lp, cfg, true_lens)
-    # the three conv rows a request are gathered BEFORE the scan: left to
-    # the scheduler the gather came last in a program with the walks, and
-    # every KDA layer's padded projection (0.4 GB) lived to its end
-    q, rows = lax.optimization_barrier((q, rows))
-    o, state = kda.kda_scan(q, k, v, g, beta, cfg.kda_chunk, true_lens)
-
-    def after(args, _first):
-        x, maps, h, o = args
-        return leave(x, maps, kda_out(o, h, lp, cfg))
-
-    return (live_rows.walk(after, (x, maps, h, o), jnp.max(true_lens)),
-            (rows, state.astype(cfg.state_dtype)))
+    """`kda_layer.prefill` under this module's gate (bounded: the scan's
+    bounded form); `around`: the halves of the residual path
+    (`mhc_halves`), x then the n streams apart and so the result."""
+    return kda_layer.prefill(x, lp, cfg, true_lens, kda_gate, around)
 
 
 def kda_decode_inputs(x, lp, conv, cfg: Glm5NextConfig):
-    """What `kda_update` takes for ONE token a lane: x [B, d], conv [B,
-    K-1, 3 inner] (the lane's last pre-convolution rows).  Returns (h
-    the normed input, (q, k, v [B, H, dk], g [B, H, dk], beta [B, H]),
-    conv shifted by the token's row)."""
-    h = rmsnorm(x, lp["norm1"], cfg.norm_eps)
-    with jax.named_scope("kda_in_proj"):
-        proj = h @ lp["w_qkv"]
-        g, beta = kda_gate(h, lp, cfg)
-    with jax.named_scope("kda_conv"):
-        q, k, v = _qkv(_conv([conv[:, i] for i in range(conv.shape[1])]
-                             + [proj], lp), cfg)
-        conv = jnp.concatenate([conv[:, 1:], proj[:, None]], axis=1)
-    return h, (q, k, v, g, beta), conv
+    """`kda_layer.decode_inputs` under this module's gate."""
+    return kda_layer.decode_inputs(x, lp, conv, cfg, kda_gate)
 
 
 def kda_decode(x, lp, conv, state, layer, lanes, count,
                cfg: Glm5NextConfig):
-    """One token of the KDA mixer for every lane: x [B, d], conv [B, K-1,
-    3 inner], state the lanes' state of EVERY KDA layer (updated in place
-    at `layer` for the listed lanes).  Returns (what it computes, conv,
-    state)."""
-    h, ins, conv = kda_decode_inputs(x, lp, conv, cfg)
-    with jax.named_scope("kda_update"):
-        state, o = kda.kda_update(state, layer, lanes, count, *ins)
-    return kda_out(o, h, lp, cfg), conv, state
+    """`kda_layer.decode` under this module's gate."""
+    return kda_layer.decode(x, lp, conv, state, layer, lanes, count, cfg,
+                            kda_gate)
 
 
 # ------------------------------------------------------ sparse latent mixer

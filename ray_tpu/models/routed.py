@@ -1,6 +1,7 @@
 """The served routed-expert layer, shared by every serving module that
 has one (`models/lfm2.py`, `models/mla_moe.py`, `models/glm5_next.py`,
-`models/dots3_note.py`, `models/nemotron_h.py`): the router, the experts'
+`models/dots3_note.py`, `models/nemotron_h.py`, `models/mimo_v2.py`,
+`models/cohere2_moe.py`, `models/solar_open2.py`): the router, the experts'
 part over the dropless grouped matmul (`ops/grouped_matmul.gmm`) for the
 range of experts a chip holds, and the shared expert beside them.
 

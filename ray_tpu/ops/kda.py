@@ -5,8 +5,9 @@ one-step update a decode step runs.
 The recurrence.  A head keeps a state matrix S [dk, dv] in float32; for
 token t with a key k_t and a query q_t [dk] (the caller normalises and
 scales them), a value v_t [dv], a decay a_t in (0, 1]^dk (one number a
-KEY CHANNEL, not one a head: `g_t = log a_t` is what is passed) and a
-write strength beta_t in [0, 1]:
+KEY CHANNEL, not one a head: `g_t = log a_t` is what is passed, ANY
+value <= 0) and a write strength beta_t in [0, 2] (past 1 the factor
+`I - beta k k^T` has a negative eigenvalue along k):
 
     S_t = (I - beta_t k_t k_t^T) Diag(a_t) S_{t-1} + beta_t k_t v_t^T
     o_t = S_t^T q_t
@@ -38,14 +39,40 @@ start) and S_0 the state at its start,
         B_tj = sum_d q_t[d] k_j[d] exp(G_t[d] - G_j[d])
     S_C = Diag(exp(G_C)) S_0 + (K * exp(G_C - G))^T U
 
-A and B are products of K exp(G - G_m) with K exp(G_m - G), G_m the
-chunk's MIDDLE (the reference cancels in every pair; on and below the
-diagonal the exponents add up to at most 0): either factor grows with
-half the chunk, so the chunk is bounded by the gate's lower bound (C / 2
-* |bound| <= 80, float32's range: `max_chunk`; 32 positions at the
-published -5; pairs above the diagonal may overflow and are masked).
-With T = (I + A)^-1 (`_unit_lower_inverse`: A is nilpotent), W = T (beta
-K exp(G)) and U0 = T (beta V): U = U0 - W S_0.
+Two forms of A and B, chosen by what the CALLER knows of the gate
+(`kda_scan(..., unbounded=)`; nothing is clamped in either).
+
+A gate bounded below (GLM's; the form a call that says nothing gets): A
+and B are products of K exp(G - G_m) with
+K exp(G_m - G), G_m the chunk's MIDDLE (the reference cancels in every
+pair; on and below the diagonal the exponents add up to at most 0):
+either factor grows with half the chunk, so the chunk is bounded by the
+gate's lower bound (C / 2 * |bound| <= 80, float32's range: `max_chunk`;
+32 positions at the published -5; pairs above the diagonal may overflow
+and are masked).
+
+No bound (the published layer's -exp(A_log) softplus(.), in (-inf, 0]):
+every pair (t, j), j < t, is anchored at a position BETWEEN them, so both
+exponents are <= 0 and an underflow is the true answer (`_halved_pairs`).
+By halving: at the level of half-size s the pairs whose t lies in the
+upper and j in the lower half of a block of 2 s positions take the lower
+half's last position as anchor; the levels s = 1, 2, 4, ... < C hold
+every pair once.  The exponents are SUMS of g over the stretch between
+(`_segment_sums`: shifted adds, restarted every s rows), never
+differences of two long sums, so a steep step earlier in the chunk costs
+a later pair no bits; a level is one masked product over the group's
+rows, log2(C) of them for the bounded form's one.
+
+T = (I + A)^-1 by halves too, in both forms (`_unit_lower_inverse`): T <-
+T - T A_off T a level, A_off the part of A between a block's two halves;
+its intermediate terms are entries of true inverses, where the terms of
+the finite product (I - A)(I + A^2)(I + A^4)... reach binomial(C, C / 2)
+beta^C on repeated keys and cancel to nothing (numpy float32, C = 32, a
+repeated unit key: beta 0.99 at g = -0.01 a step the product 4.0 off,
+beta 1.99 8e5; this 1e-7 ... 5e-6; on the chip the product is 2.3 % of the
+bounded kernel's time faster, 7.57 against 7.75 ms at [1, 8192, 64 x 128]).
+
+With T, W = T (beta K exp(G)) and U0 = T (beta V): U = U0 - W S_0.
 
 Where the bytes live.  q, k, v, g are read as [b, T, H dk]: a head's
 rows are a column block of one lane tile, no transpose is made, o
@@ -125,44 +152,6 @@ def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
                            preferred_element_type=F32)
 
 
-def _unit_lower_inverse(As, C: int):
-    """(I + A)^-1 for every A [R, R] of the list `As`, each strictly
-    lower triangular inside its diagonal blocks of C and zero outside
-    them (nilpotent of index C): the finite product (I - A)(I + A^2)
-    (I + A^4)...  The R / C blocks are held SIDE BY SIDE, [C, R], and
-    multiplied from the right by the block-diagonal [R, R] form: C rows
-    pushed through the MXU's whole width where [R, R] by [R, R] pushes R
-    for the same blocks; and a power's square and the running product's
-    next factor share that right-hand side, so they are ONE product.
-    The list's chains are independent and are written level by level,
-    side by side: the order the products are issued in."""
-    R = As[0].shape[0]
-    n = R // C
-    own = (lax.broadcasted_iota(jnp.int32, (R, R), 0) // C
-           == lax.broadcasted_iota(jnp.int32, (R, R), 1) // C)
-    eye = (lax.broadcasted_iota(jnp.int32, (C, R), 0)
-           == lax.broadcasted_iota(jnp.int32, (C, R), 1) % C)
-
-    def blocks(side):             # [C, R] -> block-diagonal [R, R]
-        return jnp.where(own, jnp.concatenate([side] * n), 0.0)
-
-    Xs = [-sum(A[i * C:(i + 1) * C] for i in range(n)) for A in As]
-    invs = [X + eye for X in Xs]
-    m = 2
-    if m < C:
-        Xs = [_dot(X, blocks(X)) for X in Xs]
-    while m < C:
-        m *= 2
-        if m < C:                 # X <- X^2 beside inv <- inv (I + X)
-            both = [_dot(jnp.concatenate([X, inv]), blocks(X))
-                    for X, inv in zip(Xs, invs)]
-            Xs = [x[:C] for x in both]
-            invs = [inv + x[C:] for inv, x in zip(invs, both)]
-        else:
-            invs = [inv + _dot(inv, blocks(X)) for X, inv in zip(Xs, invs)]
-    return [blocks(inv) for inv in invs]
-
-
 def _chunk_sums(g, C: int):
     """The running sum of g [R, dk] down the rows, restarted at every
     chunk of C rows: log2(C) shifted adds (no product: the sum is exact
@@ -175,6 +164,97 @@ def _chunk_sums(g, C: int):
     return g
 
 
+def _segment_sums(g, s: int, C: int, backward: bool = False):
+    """The running sum of g [R, dk] down the rows (`backward`: up them),
+    inclusive, restarted every `s` positions of a chunk of C rows: shifted
+    adds as `_chunk_sums`.  Exact float32 adds of the g's of one stretch."""
+    R = g.shape[0]
+    p = lax.broadcasted_iota(jnp.int32, g.shape, 0) % C
+    at = p % s
+    h = 1
+    while h < s:
+        if backward:    # the row h below, inside the stretch and the chunk
+            g = g + jnp.where((at < s - h) & (p < C - h),
+                              pltpu.roll(g, R - h, 0), 0.0)
+        else:
+            g = g + jnp.where(at >= h, pltpu.roll(g, h, 0), 0.0)
+        h *= 2
+    return g
+
+
+def _halved_pairs(q, k, g, C: int):
+    """[A / beta; B] [2 R, R] for q, k, g [R, dk] of one head, exact for
+    any g <= 0: A_tj = sum_d k_t k_j exp(G_t - G_j) strictly below the
+    diagonal of each chunk of C, B_tj = sum_d q_t k_j exp(G_t - G_j) on
+    and below it, zeros elsewhere.  A level of half-size s anchors the
+    pairs (t in the upper, j in the lower half of a block of 2 s
+    positions) at the lower half's last row a: exp(G_t - G_a) is the
+    forward stretch sum at t, exp(G_a - G_j) the backward one at j less
+    g_j; both <= 1, ONE exp a level since a row is upper or lower."""
+    R, dk = k.shape
+    row = lax.broadcasted_iota(jnp.int32, (2 * R, R), 0) % R
+    col = lax.broadcasted_iota(jnp.int32, (2 * R, R), 1)
+    lower_part = lax.broadcasted_iota(jnp.int32, (2 * R, R), 0) >= R
+    p = lax.broadcasted_iota(jnp.int32, (R, dk), 0) % C
+    out = jnp.where(lower_part & (row == col), jnp.concatenate(
+        [jnp.zeros((R, 1), F32), jnp.sum(q * k, axis=1, keepdims=True)]),
+        0.0)
+    s = 1
+    while s < C:
+        upper = (p // s) % 2 == 1
+        e = jnp.exp(jnp.where(
+            upper, _segment_sums(g, s, C),
+            _segment_sums(g, s, C, backward=True) - g))
+        up = jnp.where(upper, e, 0.0)
+        pairs = _dot(jnp.concatenate([k * up, q * up]),
+                     k * jnp.where(upper, 0.0, e), (((1,), (1,)), ((), ())))
+        same = ((row // C == col // C)
+                & (row % C // (2 * s) == col % C // (2 * s)))
+        out = out + jnp.where(same, pairs, 0.0)
+        s *= 2
+    return out
+
+
+def _unit_lower_inverse(As, C: int):
+    """(I + A)^-1 for every A [R, R] of the list `As`, each strictly
+    lower triangular inside its diagonal blocks of C and zero outside
+    them: block by block, T <- T - T A_off T for the half-sizes s = 1, 2,
+    4, ... < C, A_off the entries of A between the two halves of a block
+    of 2 s positions (the inverse of [[M1, 0], [A21, M2]] is [[T1, 0],
+    [-T2 A21 T1, T2]]); every intermediate term is an entry of a true
+    inverse.  (NOT A's powers summed, (I - A)(I + A^2)(I + A^4)...: their
+    terms reach binomial(C, C / 2) beta^C on keys that repeat and cancel
+    to nothing; module docstring.)  The R / C blocks are held SIDE BY
+    SIDE, [C, R], and multiplied from the right by the block-diagonal
+    [R, R] form: C rows pushed through the MXU's whole width where [R, R]
+    by [R, R] pushes R for the same blocks; two products a level from s =
+    2 (T is I below it).  The list's chains are independent and are
+    written level by level, side by side: the order the products are
+    issued in."""
+    R = As[0].shape[0]
+    n = R // C
+    own = (lax.broadcasted_iota(jnp.int32, (R, R), 0) // C
+           == lax.broadcasted_iota(jnp.int32, (R, R), 1) // C)
+    rowp = lax.broadcasted_iota(jnp.int32, (C, R), 0)
+    colp = lax.broadcasted_iota(jnp.int32, (C, R), 1) % C
+
+    def blocks(side):             # [C, R] -> block-diagonal [R, R]
+        return jnp.where(own, jnp.concatenate([side] * n), 0.0)
+
+    def between(X, s):            # the same block of 2 s, another half
+        return jnp.where((rowp // (2 * s) == colp // (2 * s))
+                         & (rowp // s != colp // s), X, 0.0)
+
+    Xs = [sum(A[i * C:(i + 1) * C] for i in range(n)) for A in As]
+    invs = [jnp.where(rowp == colp, 1.0, 0.0) - between(X, 1) for X in Xs]
+    s = 2
+    while s < C:
+        mids = [_dot(inv, blocks(between(X, s))) for X, inv in zip(Xs, invs)]
+        invs = [inv - _dot(mid, blocks(inv)) for mid, inv in zip(mids, invs)]
+        s *= 2
+    return [blocks(inv) for inv in invs]
+
+
 def _column(row):
     """A row [1, n] as a column [n, 1] (a masked sum along the lanes)."""
     n = row.shape[-1]
@@ -183,7 +263,7 @@ def _column(row):
     return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
 
 
-def _group_parts(ins, C: int):
+def _group_parts(ins, C: int, bounded: bool):
     """What a GROUP of R = n C positions needs that does not depend on
     the carried state, for every head of the list `ins` = [(q, k, v, g,
     beta)] (q, k, g [R, dk]; v [R, dv]; beta [R, 1]), made for the n
@@ -194,7 +274,9 @@ def _group_parts(ins, C: int):
     over it; U0 [R, dv]; Ke [R, dk]; dec: a chunk's decay [dk, 1]; B
     [R, R]), returned as five lists over the heads.  The heads are
     independent: every stage is written for all of them before the
-    next."""
+    next.  `bounded`: the caller's gate has a lower bound that lets the
+    chunk's middle anchor every pair; else the pairs by halves, exact
+    for any g <= 0 (the module's docstring)."""
     R, dk = ins[0][1].shape
     n = R // C
     row = lax.broadcasted_iota(jnp.int32, (R, R), 0)
@@ -206,25 +288,33 @@ def _group_parts(ins, C: int):
         return jnp.broadcast_to(x, (n, C, x.shape[-1])).reshape(R, -1)
 
     Gs = [_chunk_sums(g, C) for _, _, _, g, _ in ins]
-    ABs = []
-    for (q, k, _, _, _), G in zip(ins, Gs):
-        rel = G - of_chunk(G, (C - 1) // 2)           # from the middle
-        up = jnp.exp(rel)
-        ABs.append(_dot(jnp.concatenate([k * up, q * up]),
-                        k * jnp.exp(-rel), (((1,), (1,)), ((), ()))))
+    if bounded:
+        ABs = []
+        for (q, k, _, _, _), G in zip(ins, Gs):
+            rel = G - of_chunk(G, (C - 1) // 2)       # from the middle
+            up = jnp.exp(rel)
+            ABs.append(_dot(jnp.concatenate([k * up, q * up]),
+                            k * jnp.exp(-rel), (((1,), (1,)), ((), ()))))
+        As = [jnp.where(own & (col < row), AB[:R], 0.0) for AB in ABs]
+    else:
+        ABs = [_halved_pairs(q, k, g, C) for q, k, _, g, _ in ins]
+        As = [AB[:R] for AB in ABs]
     Ts = _unit_lower_inverse(
-        [jnp.where(own & (col < row), AB[:R], 0.0) * beta
-         for AB, (_, _, _, _, beta) in zip(ABs, ins)], C)
+        [A * beta for A, (_, _, _, _, beta) in zip(As, ins)], C)
     parts = []
-    for (q, k, v, _, beta), G, AB, T in zip(ins, Gs, ABs, Ts):
+    for (q, k, v, g, beta), G, AB, T in zip(ins, Gs, ABs, Ts):
         decay = jnp.exp(G)                # from the chunk's start: <= 1
-        last = of_chunk(G, C - 1)
+        # to the chunk's end: a difference of two sums from its start
+        # under a bound, else the sum of the stretch itself
+        last = of_chunk(G, C - 1) if bounded else None
         WU = _dot(T, jnp.concatenate([k * decay * beta, v * beta], axis=1))
         Qd = q * decay
         QW = [jnp.concatenate([Qd[i * C:(i + 1) * C],
                                WU[i * C:(i + 1) * C, :dk]])
               for i in range(n)]
-        parts.append((QW, WU[:, dk:], k * jnp.exp(last - G),
+        parts.append((QW, WU[:, dk:], k * jnp.exp(
+            last - G if bounded
+            else _segment_sums(g, C, C, backward=True) - g),
                       [_column(jnp.exp(G[(i + 1) * C - 1:(i + 1) * C]))
                        for i in range(n)],
                       jnp.where(own & (col <= row), AB[R:], 0.0)))
@@ -233,7 +323,8 @@ def _group_parts(ins, C: int):
 
 def _scan_kernel(lens_ref,                            # scalar prefetch
                  q_ref, k_ref, v_ref, g_ref, beta_ref,
-                 o_ref, s_ref, state, *, C: int, R: int, heads: int):
+                 o_ref, s_ref, state, *, C: int, R: int, heads: int,
+                 bounded: bool):
     """One position block of `heads` heads of one row.  q_ref, k_ref,
     g_ref [1, P, heads dk]; v_ref, o_ref [1, P, heads dv]; beta_ref
     [1, P, H]; s_ref [1, heads, dk, dv]; `state` the heads' [dk, dv] in
@@ -265,7 +356,7 @@ def _scan_kernel(lens_ref,                            # scalar prefetch
                     v_ref[0, rows, vv], g_ref[0, rows, kk],
                     jnp.sum(jnp.where(lane == head0 + h, betas, 0.0),
                             axis=1, keepdims=True)))
-            QW, U0, Ke, dec, Bm = _group_parts(ins, C)
+            QW, U0, Ke, dec, Bm = _group_parts(ins, C, bounded)
             # the chunks, from their state: `U = U0 - W S` beside the
             # rows `Qd S`, then `S <- dec S + Ke^T U`
             S = list(S)
@@ -315,17 +406,21 @@ def scan_tiles(dk: int, dv: int, chunk: int) -> None:
             f"chunk={chunk}")
 
 
-def kda_scan(q, k, v, g, beta, chunk: int, lengths=None):
+def kda_scan(q, k, v, g, beta, chunk: int, lengths=None,
+             unbounded: bool = False):
     """The recurrence over whole rows, chunked.
 
     q, k [b, T, H, dk] (normalised; q scaled); v [b, T, H, dv]; g
     [b, T, H, dk] float32 (the log decay, <= 0, ZERO past a row's true
-    length); beta [b, T, H] float32 (ZERO past it); `chunk` positions a
-    chunk (`max_chunk` bounds it); `lengths` [b] int32 or None: a
-    position block wholly at or past a row's length gets no step (its o
-    is 0, the state passes through: what g = 0 and beta = 0 there give
-    anyway).  Returns (o [b, T, H, dv] float32, the state after the last
-    position [b, H, dk, dv] float32)."""
+    length); beta [b, T, H] float32 in [0, 2] (ZERO past it); `chunk`
+    positions a chunk; `lengths` [b] int32 or None: a position block
+    wholly at or past a row's length gets no step (its o is 0, the state
+    passes through: what g = 0 and beta = 0 there give anyway).
+    `unbounded`: False, the caller's gate has a lower bound and `chunk` is
+    within `max_chunk` of it (the caller's to hold): one product anchors a
+    chunk's pairs at its middle; True: any g <= 0, the pairs anchored by
+    halves.  Returns (o [b, T, H, dv] float32, the state
+    after the last position [b, H, dk, dv] float32)."""
     b, T, H, dk = k.shape
     dv = v.shape[-1]
     C = min(chunk, T)
@@ -365,7 +460,8 @@ def kda_scan(q, k, v, g, beta, chunk: int, lengths=None):
         scratch_shapes=[pltpu.VMEM((heads, dk, dv), F32)],
     )
     o, S = pl.pallas_call(
-        functools.partial(_scan_kernel, C=C, R=R, heads=heads),
+        functools.partial(_scan_kernel, C=C, R=R, heads=heads,
+                          bounded=not unbounded),
         name="kda_scan",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((b, T + pad, H * dv), F32),
@@ -469,17 +565,21 @@ def update_cost(H: int, dk: int, dv: int, lane_steps: float
 
 
 def scan_cost(H: int, dk: int, dv: int, chunk: int, positions: float,
-              rows: float = 0.0) -> tuple[float, float]:
+              rows: float = 0.0, halved: bool = False
+              ) -> tuple[float, float]:
     """(flops, bytes) the `kda_scan` calls NEED for `positions` true
     positions in `rows` rows: q, k, g, v in and o out once (float32),
     beta, the state written a row; and a (head, chunk)'s products as the
-    kernel forms them (A and B; the 2 (log2(chunk) - 1) products of the
-    inverse; W and U0; `[Qd; W] S`; `B U`; `Ke^T U`), a multiply-add two
-    operations.  Six bfloat16 passes a float32 product are the chip's
-    price, not the algorithm's: not counted."""
+    kernel forms them (A and B, once under a bounded gate and once a
+    LEVEL by halves, `halved`: log2(chunk) masked products over the
+    chunk; the 2 (log2(chunk) - 1) products of the inverse; W and U0;
+    `[Qd; W] S`; `B U`; `Ke^T U`), a multiply-add two operations.  Six
+    bfloat16 passes a float32 product are the chip's price, not the
+    algorithm's: not counted."""
     C = chunk
-    inverse = 2 * max(0, (C - 1).bit_length() - 1)
-    a_chunk = (2 * C * C * (2 * dk)                   # A, B
+    levels = max(0, (C - 1).bit_length())
+    inverse = 2 * max(0, levels - 1)
+    a_chunk = ((levels if halved else 1) * 2 * C * C * (2 * dk)  # A, B
                + inverse * 2 * C ** 3
                + 2 * C * C * (dk + dv)                # W, U0
                + 2 * (2 * C) * dk * dv                # [Qd; W] S

@@ -57,6 +57,8 @@ LONG_CASES = {
         210,
     "tests/test_bench_families.py::test_the_cohere_cell_rehearses_on_the_cpu":
         146,
+    "tests/test_bench_families.py::test_the_solar_cell_rehearses_on_the_cpu":
+        118,
     "tests/test_chip_compile.py::test_served_glm_engine_fits_one_chip_and_"
     "copies_no_state_or_pool": 100,
     "tests/test_bench_families.py::"

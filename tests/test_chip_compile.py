@@ -1818,3 +1818,98 @@ def test_served_command_a_plus_fits_one_chip_and_copies_no_ring_or_pool(
                 and "swa_attn" in ln]) == 3        # a call a window layer
     assert len([ln for ln in loop if "custom-call(" in ln
                 and "paged_attn" in ln]) == 1      # the global layer's
+
+
+# --------------------------------------------- Solar-Open2-250B (PR 58)
+def test_kda_scan_compiles_in_both_forms(one_chip, compiled_kernels):
+    """The two forms of the scan at 64 heads of [128, 128], chunks of 32,
+    one row of 8,192: under GLM's bound the chunk's middle anchors every
+    pair; without one (Solar-Open2's gate, `unbounded`) the pairs by
+    halves; the inverse by halves in both.  Both are ONE kernel named
+    `kda_scan`, and the exact form is the larger program."""
+    from ray_tpu.ops import kda
+
+    def s(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    b, T, H, dk = 1, 8192, 64, 128
+    size = {}
+    for unbounded in (False, True):
+        def scan(q, k, v, g, beta, lens, unbounded=unbounded):
+            shape = (b, T, H, dk)
+            o, S = kda.kda_scan(q.reshape(shape), k.reshape(shape),
+                                v.reshape(shape), g.reshape(shape), beta,
+                                32, lens, unbounded=unbounded)
+            return o.reshape(b, T, H * dk), S
+
+        x = s((b, T, H * dk))
+        low, c = _compile(scan, x, x, x, x, s((b, T, H)),
+                          s((b,), jnp.int32))
+        text = low.as_text()
+        assert text.count("tpu_custom_call") == 1 and "kda_scan" in text
+        assert c.memory_analysis().temp_size_in_bytes < b * T * H * dk // 2
+        size[unbounded] = len(text)
+    assert size[True] > size[False]
+
+
+@pytest.mark.time_limit(900)
+def test_served_solar_open2_fits_one_chip_and_copies_no_state_or_pool(
+        topo, one_chip, compiled_kernels, monkeypatch):
+    """solar-open2-250b-ep8 as the benchmark serves it (one period G K K
+    K, 40 of 320 experts held, 64 lanes, 1,153 pages): the decode program
+    and the ONE prefill program its traffic reaches (1 x 8192) compile for
+    one chip beside 9.87 GB of weights, lane state and pool; inside the
+    K-step loop the lanes' state matrices (0.81 GB) are touched by
+    `kda_update` alone, which aliases them, and the pool is not copied."""
+    monkeypatch.setattr(jax, "devices", lambda *a: list(topo.devices))
+    cfg, eng, lows = _glm_lowerings(one_chip, [(1, 8192)],
+                                    "solar-open2-250b-ep8")
+    lane = eng.stats()["lane_state"]
+    assert lane["layers"] == 3
+    assert lane["by_kind"] == {"conv": 3 * 64 * 3 * 24576 * 2,
+                               "kda": 3 * 64 * 64 * 128 * 128 * 4}
+    cache = eng._cache_stats()
+    assert (cache["kind"], cache["layers"], cache["row_bytes"]) == (
+        "kv", 1, 2 * 8 * 128 * 2)
+    assert cache["pool_bytes"] == 1153 * 512 * 4096
+    weights = sum(math.prod(a.shape) * a.dtype.itemsize
+                  for a in jax.tree.leaves(eng.params))
+    # bfloat16 but for A_log, dt_bias and the router's biases (float32)
+    assert weights == 2 * 3_308_353_344 + 2 * (3 * (64 + 8192) + 4 * 320)
+    resident = weights + lane["bytes"] + cache["pool_bytes"]
+    assert 9.85e9 < resident < 9.89e9           # 58 % of the chip
+    assert (1, 8192) in eng._prefill_programs
+    assert eng._spec.prefill_state_bytes == 3 * (64 * 128 * 128 * 4
+                                                 + 3 * 24576 * 2)
+    kernels = {"decode_k8": ("kda_update", "paged_attn", "moe_gmm"),
+               "prefill_w1_p8192": ("kda_scan", "flash_fwd", "moe_gmm")}
+    for name, low in lows.items():
+        txt = low.as_text()
+        for kern in kernels[name]:
+            assert kern in txt, (name, kern)
+        c = low.compile()
+        mem = c.memory_analysis()
+        held = resident + mem.temp_size_in_bytes + (
+            mem.output_size_in_bytes - mem.alias_size_in_bytes)
+        print(f"{name}: temps {mem.temp_size_in_bytes / 1e9:.2f} GB, "
+              f"resident {resident / 1e9:.2f} GB, held {held / 1e9:.2f} GB")
+        assert held < 16.9e9 - 1.0e9, (name, held)
+        hlo = c.as_text()
+        if name != "decode_k8":
+            # a walk after each mixer and one after each routed loop
+            assert len(_loops_of(hlo, "/live_rows/")) == 2 * cfg.n_layers
+            assert mem.temp_size_in_bytes < 3.5e9
+            continue
+        layer_state = 64 * 64 * 128 * 128
+        found = weight_sized_writes(hlo, layer_state)
+        assert found and all(op == "custom-call" and "kda_update" in scope
+                             for _, op, scope in found), found
+        assert mem.alias_size_in_bytes >= lane["bytes"] + cache["pool_bytes"]
+        assert mem.temp_size_in_bytes < 0.1e9
+        loop = _loop_lines(hlo)
+        assert len([ln for ln in loop if "custom-call(" in ln
+                    and "kda_update" in ln]) == 3       # a call a KDA layer
+        assert len([ln for ln in loop if "custom-call(" in ln
+                    and "paged_attn" in ln]) == 1       # the GQA layer's
+        assert len([ln for ln in loop if "custom-call(" in ln
+                    and "moe_gmm" in ln]) == 8          # two a routed layer

@@ -1,8 +1,12 @@
 """ops/kda.py (the gated delta rule with a per-channel decay): the
-scan kernel and the one-step kernel (interpret mode) against the
-token-by-token recurrence, at lengths that end mid-chunk, across
-position blocks, with blocks past a row's length that get no step, with
-lanes that hold no request."""
+scan kernel in BOTH its forms (under a gate's lower bound, the form a
+call that says nothing gets: the chunk's middle anchors every pair;
+`unbounded`: pairs anchored by halves) and
+the one-step kernel (interpret mode) against the token-by-token
+recurrence, at lengths that end mid-chunk, across position blocks, with
+blocks past a row's length that get no step, with lanes that hold no
+request.  (`tests/test_solar_open2.py` holds the exact form where only it
+stands: beta near 2 on repeated keys, decays of -30 a step.)"""
 from __future__ import annotations
 
 import jax
@@ -35,6 +39,7 @@ def _rel(got, want) -> float:
     return float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
 
 
+@pytest.mark.parametrize("unbounded", [True, False])
 @pytest.mark.parametrize("T,chunk,lens", [
     (40, 16, [40, 27]),     # a row whose true length ends mid-chunk
     (16, 16, [16, 1]),      # one chunk; a row of one token
@@ -43,10 +48,12 @@ def _rel(got, want) -> float:
     (70, 32, [70, 41]),     # the served chunk
     (24, 1, [24, 9]),       # a chunk a position: the recurrence itself
 ])
-def test_kda_scan_equals_the_recurrence_at_the_true_length(T, chunk, lens):
+def test_kda_scan_equals_the_recurrence_at_the_true_length(T, chunk, lens,
+                                                           unbounded):
+    """Both forms: what a gate bounded at -5 allows, and the exact one."""
     q, k, v, g, beta = _inputs(2, T, lens=lens)
-    o, S = jax.jit(lambda *a: kda.kda_scan(*a, chunk=chunk))(
-        q, k, v, g, beta)
+    o, S = jax.jit(lambda *a: kda.kda_scan(
+        *a, chunk=chunk, unbounded=unbounded))(q, k, v, g, beta)
     for i, n in enumerate(lens):
         want_o, want_S = kda.kda_recurrence(
             q[i, :n], k[i, :n], v[i, :n], g[i, :n], beta[i, :n])
@@ -145,6 +152,33 @@ def test_the_unit_lower_inverse_is_the_inverse(n, C):
     assert float(jnp.abs(inv @ (eye + A * 0.3) - eye).max()) < 1e-5
 
 
+def _summed_powers(A):
+    """(I + A)^-1 as the finite product (I - A)(I + A^2)(I + A^4)...: what
+    the kernel formed before PR 58."""
+    n = A.shape[0]
+    inv, X, m = jnp.eye(n) - A, A @ A, 2
+    while m < n:
+        inv, X, m = inv + inv @ X, X @ X, 2 * m
+    return inv
+
+
+@pytest.mark.parametrize("beta,g,off", [(1.99, 0.0, 1e3), (1.99, -0.01, 1e3),
+                                        (0.99, 0.0, 1.0), (0.99, -0.01, 1.0)])
+def test_the_inverse_stands_where_the_summed_powers_cancel(beta, g, off):
+    """A chunk of 32 whose keys repeat at a slow decay g a step: A's entry
+    (t, j) is beta exp(g (t - j)), the true inverse's entries stay under
+    2, the power series' terms reach binomial(32, 16) beta^32 and cancel
+    to nothing: at a write strength inside (0, 1) too (GLM's range)."""
+    n = 32
+    at = jnp.arange(n)
+    A = jnp.where(at[None, :] < at[:, None],
+                  beta * jnp.exp(g * (at[:, None] - at[None, :])), 0.0)
+    want = jnp.linalg.inv(jnp.eye(n) + A)
+    inv, = kda._unit_lower_inverse([A], n)
+    assert float(jnp.abs(inv - want).max()) < 1e-4
+    assert float(jnp.abs(_summed_powers(A) - want).max()) > off
+
+
 @pytest.mark.parametrize("live", [[0, 1, 0, 1], [1, 1, 1, 1], [0, 0, 0, 0]])
 def test_kda_update_is_one_recurrence_step_and_leaves_idle_lanes(live):
     """Layer 1 of three, four lanes: each live lane's state and output are
@@ -216,3 +250,10 @@ def test_scan_cost_is_the_rows_in_and_out_and_the_chunks_products():
     fl8, _ = kda.scan_cost(1, 16, 16, 8, 8.0)
     assert fl8 == (4 * 2 * 8 * 8 * 16 + 4 * 2 * 8 ** 3
                    + 2 * 8 * 8 * 16 + 3 * 2 * 8 * 16 * 16)
+    # by halves the pairs are formed once a LEVEL (five at a chunk of 32,
+    # three at 8), the inverse in as many products as before
+    flh, byh = kda.scan_cost(64, 128, 128, 32, 8192.0, rows=1.0, halved=True)
+    assert byh == by
+    assert flh - fl == 4 * 2 * 2 * C * C * d * 64 * 8192 / 32
+    fl8h, _ = kda.scan_cost(1, 16, 16, 8, 8.0, halved=True)
+    assert fl8h - fl8 == 2 * 4 * 8 * 8 * 16

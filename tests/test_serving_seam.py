@@ -22,7 +22,7 @@ from ray_tpu.serve.llm import LLMEngine
 
 PRESETS = ("debug", "lfm2-debug", "mla-debug", "ssm-hybrid-debug",
            "glm5-next-debug", "dots3-note-debug", "nemotron-h-debug",
-           "mimo-v2-debug", "cohere2-moe-debug")
+           "mimo-v2-debug", "cohere2-moe-debug", "solar-open2-debug")
 
 
 def _spec(preset):
