@@ -23,6 +23,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models.serving import ServingSpec
 from ray_tpu.ops.attention import attention
@@ -58,9 +59,10 @@ class LlamaConfig:
     dtype: Any = jnp.bfloat16
     remat: bool = True
     # "flash_resid": recompute everything except the flash kernel's
-    # (o, lse) residuals — ~1.8x faster backward, costs (o + lse) per
-    # layer in HBM.  "nothing": full recompute (the old profile) for
-    # models at the HBM ceiling.
+    # (o, lse) residuals and the attention block's output — ~1.8x faster
+    # backward, and `o @ wo` with its collectives is not rebuilt; costs
+    # (o + lse + a residual-wide row) per layer in HBM.  "nothing": full
+    # recompute (the old profile) for models at the HBM ceiling.
     remat_mode: str = "flash_resid"
     use_ring_attention: bool = False   # set when mesh has a "seq" axis > 1
 
@@ -166,13 +168,25 @@ def remat_policy(cfg: "LlamaConfig | None" = None):
 
     "flash_resid" (default): recompute everything EXCEPT the
     flash-attention kernel's residuals (output + log-sum-exp, named in
-    ops/flash_attention._flash_vjp_fwd).  Attention dominates the step at
-    these shapes, and nothing_saveable re-runs the forward kernel inside
-    the backward just to rebuild (o, lse) — saving them took bench-350m
-    from 814ms to 449ms per step (MFU 0.335 -> 0.61) on v5e.  Costs
-    (o + lse) per layer in HBM: b*s*(h*d*2 + h*4) bytes — ~36 MB/layer at
-    b8 x s2048 x h8 x d128.  When the XLA fallback runs (no flash names),
-    this degrades to exactly nothing_saveable.
+    ops/flash_attention._flash_vjp_fwd) and the attention block's
+    output `x + o @ wo` (named in _attention_block).  Attention
+    dominates the step at these shapes, and nothing_saveable re-runs the
+    forward kernel inside the backward just to rebuild (o, lse) — saving
+    them took bench-350m from 814ms to 449ms per step (MFU 0.335 ->
+    0.61) on v5e.  Costs (o + lse) per layer in HBM: b*s*(h*d*2 + h*4)
+    bytes — ~36 MB/layer at b8 x s2048 x h8 x d128.  The block's output
+    is where the MLP's recompute starts: kept, the backward rebuilds
+    q, k and v for the kernels but not `o @ wo`, its partial sums over
+    "tensor" nor wo's gather over "fsdp" (one product, one all-reduce
+    and two all-gathers a layer fewer; +2.2 % tokens/s on mistral-7b,
+    fsdp=2 x tensor=2, v5e).  Costs b*s*D*2 bytes a layer: 1.33 GB a
+    chip over 20 layers at 2 x 4,096 positions a chip and a 4,096-wide
+    residual in bfloat16 (the compiler's temporaries 6.93 -> 8.26 GB).
+    q, k and v are NOT kept: beside the block's output they cost
+    0.79 GB more (16.0 of a v5e's 16.9 GB there) and ran no faster on
+    the chip — what the backward saves the forward spends stacking
+    them (PERF.md section 6, PR 59).  When the XLA fallback runs (no
+    flash names), only the block's output is kept.
 
     "nothing": full recompute — the minimal-HBM profile for models at
     the memory ceiling.
@@ -198,7 +212,7 @@ def remat_policy(cfg: "LlamaConfig | None" = None):
             f"unknown remat_mode {mode!r}; valid: 'flash_resid', "
             "'nothing', 'dots', 'flash_dots'")
     return jax.checkpoint_policies.save_only_these_names(
-        "flash_o", "flash_lse")
+        "flash_o", "flash_lse", "attn_block_out")
 
 
 # --------------------------------------------------------------- forward
@@ -219,7 +233,8 @@ def _attention_block(x, lp, cfg: LlamaConfig, cos, sin):
         o = attention(q, k, v, causal=True)
     o = o.reshape(b, s, cfg.n_heads * cfg.head_dim)
     with jax.named_scope("attn_out"):
-        return x + (o @ lp["wo"])
+        # named for remat_policy: the MLP's recompute starts from it
+        return checkpoint_name(x + (o @ lp["wo"]), "attn_block_out")
 
 
 @functools.partial(jax.named_call, name="mlp")

@@ -310,11 +310,12 @@ def _loops_of(hlo: str, scope: str) -> list:
             and "searchsorted" not in ln]
 
 
-def _loop_lines(hlo: str) -> list:
-    """Every instruction line of the `while` bodies and of whatever they
-    call, fusions included."""
+def _loop_lines(hlo: str, body: str | None = None) -> list:
+    """Every instruction line of the `while` bodies (of the one named
+    `body` alone, if given) and of whatever they call, fusions
+    included."""
     comps = _computations(hlo)
-    todo = _while_bodies(comps)
+    todo = _while_bodies(comps) if body is None else [body]
     seen, lines = set(), []
     while todo:
         name = todo.pop()
@@ -793,6 +794,27 @@ def test_sarvam_decode_step_loop_writes_no_weight_and_builds_no_plan(
                 for ln in calls}) == 1
 
 
+def _lowered_train_step(topo, cfg, opt, mesh_axes: dict, batch: int,
+                        seq: int):
+    """`train/step.py`'s sharded step over the described chips, lowered
+    from shapes (no array exists without a chip)."""
+    from ray_tpu.parallel.mesh import MeshConfig, create_mesh
+    from ray_tpu.train import step as train_step
+
+    mesh = create_mesh(MeshConfig(**mesh_axes), devices=list(topo.devices))
+    state = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        jax.eval_shape(
+            lambda k: train_step.create_train_state(k, cfg, opt),
+            jax.random.PRNGKey(0)),
+        train_step.state_shardings(cfg, mesh, opt))
+    tok = jax.ShapeDtypeStruct((batch, seq), jnp.int32,
+                               sharding=train_step.batch_shardings(mesh))
+    step = train_step.sharded_train_step(cfg, opt, mesh)
+    with jax.set_mesh(mesh):
+        return step.lower(state, {"inputs": tok, "targets": tok})
+
+
 def test_sharded_train_step_lowers_for_four_chips(topo, compiled_kernels,
                                                   monkeypatch):
     """`chip_smoke.py --chips 4`'s step (llama3-8b widths, fsdp=2 x
@@ -804,29 +826,59 @@ def test_sharded_train_step_lowers_for_four_chips(topo, compiled_kernels,
 
     import chip_smoke
     from ray_tpu.models import llama
-    from ray_tpu.parallel.mesh import MeshConfig, create_mesh
     from ray_tpu.train import step as train_step
 
     monkeypatch.setattr(jax, "devices", lambda *a: list(topo.devices))
     t = chip_smoke.TRAIN_SIZES["full"]
     cfg = dataclasses.replace(llama.llama_configs()[t["model"]],
                               n_layers=t["n_layers"], max_seq=t["seq"])
-    mesh = create_mesh(MeshConfig(fsdp=2, tensor=2),
-                       devices=list(topo.devices))
-    opt = train_step.default_optimizer(total_steps=10)
-    st_sh = train_step.state_shardings(cfg, mesh, opt)
-    state = jax.tree.map(
-        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
-        jax.eval_shape(
-            lambda k: train_step.create_train_state(k, cfg, opt),
-            jax.random.PRNGKey(0)),
-        st_sh)
-    tok = jax.ShapeDtypeStruct((t["batch"], t["seq"]), jnp.int32,
-                               sharding=train_step.batch_shardings(mesh))
-    step = train_step.sharded_train_step(cfg, opt, mesh)
-    with jax.set_mesh(mesh):
-        low = step.lower(state, {"inputs": tok, "targets": tok})
+    low = _lowered_train_step(
+        topo, cfg, train_step.default_optimizer(total_steps=10),
+        dict(fsdp=2, tensor=2), t["batch"], t["seq"])
     assert low.as_text().count("tpu_custom_call") == 3
+
+
+def test_train_cell_step_does_not_recompute_the_attention_output(
+        topo, compiled_kernels, monkeypatch):
+    """`mistral7b.train.fsdp2tp2`'s own step (the configuration's file
+    through its family, fsdp=2 x tensor=2 over the described 2x2),
+    COMPILED: under `remat_mode="flash_resid"` the scanned layer's
+    backward finds o, lse and the block's output saved, so the two loop
+    bodies hold 7 products (forward) and 19 (14 of the backward + the
+    recomputed q, k, v, gate and up; 20 while `o @ wo` was rebuilt),
+    one and two kernel calls, and the program fits the chip beside its
+    state."""
+    from benchmarks.harness import spec
+    from ray_tpu.train import step as train_step
+
+    monkeypatch.setattr(jax, "devices", lambda *a: list(topo.devices))
+    conf = spec.load_json(os.path.join(
+        spec.BENCH_DIR, "configs", "mistral-7b-v0.3-d20-train4.json"))
+    t, fam = conf["train"], spec.config_family(conf)
+    assert t["remat_mode"] == "flash_resid"
+    cfg = fam.program_config(fam.published(conf), max_seq=t["seq"],
+                             remat_mode=t["remat_mode"])
+    opt = getattr(train_step, t["optimizer"])(total_steps=t["total_steps"])
+    low = _lowered_train_step(topo, cfg, opt, t["mesh"], t["batch"],
+                              t["seq"])
+    assert low.as_text().count("tpu_custom_call") == 3
+    comp = low.compile()
+    hlo = comp.as_text()
+
+    def count(lines, what):
+        return sum(1 for ln in lines if re.search(what, ln))
+
+    bodies = sorted(
+        (count(lines, r"\b(convolution|dot)\("),
+         count(lines, r"custom-call\(.*tpu_custom_call"))
+        for lines in (_loop_lines(hlo, b)
+                      for b in set(_while_bodies(_computations(hlo)))))
+    assert bodies == [(7, 1), (19, 2)]
+    mem = comp.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    print(f"train_step: arguments {mem.argument_size_in_bytes / 1e9:.2f} "
+          f"GB, temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB")
+    assert held < 16.9e9, held
 
 
 # ------------------------------- the state-space hybrid model (PR 39)

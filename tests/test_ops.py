@@ -164,16 +164,67 @@ class TestFlashAttention:
         n_nothing = txt_nothing.count("pallas_call")
         assert 0 < n_flash < n_nothing, (n_flash, n_nothing)
 
+    # (products a layer's backward recomputes, Pallas calls in the whole
+    # step): `nothing` re-runs the forward kernel and recomputes six
+    # products a layer (q, k, v, wo, gate, up; w_down's is dead);
+    # `flash_resid` keeps the kernel's (o, lse) and the block's output,
+    # which takes the forward kernel and wo's product away.
+    @pytest.mark.parametrize("mode,recomputed_products,pallas_calls", [
+        ("nothing", 6, 4), ("flash_resid", 5, 3), ("dots", 0, 4),
+        ("flash_dots", 0, 3)])
+    def test_remat_modes_match_the_unremat_step(self, monkeypatch, mode,
+                                                recomputed_products,
+                                                pallas_calls):
+        """A two-layer llama step through the interpreted kernel under
+        every `remat_mode`: loss and every gradient leaf are the
+        un-checkpointed step's, and the backward holds the products and
+        kernel calls the mode's kept set leaves (the scanned layer is
+        ONE body in the jaxpr, so a difference is per layer)."""
+        import dataclasses
 
-def _jaxprs_in(jaxpr):
-    """jaxpr and every jaxpr nested in its equations' params."""
+        from ray_tpu.models import llama
+
+        monkeypatch.setattr(llama, "attention",
+                            functools.partial(attention, impl="flash"))
+        base = llama.LlamaConfig(
+            dim=256, n_layers=2, n_heads=2, n_kv_heads=1, ffn_dim=512,
+            vocab_size=256, max_seq=128, dtype=jnp.float32, remat=False)
+        params = llama.init_params(jax.random.PRNGKey(0), base)
+        batch = {"tokens": jax.random.randint(
+            jax.random.PRNGKey(1), (2, 129), 0, base.vocab_size)}
+
+        def step(**kw):
+            cfg = dataclasses.replace(base, **kw)
+            return jax.value_and_grad(
+                lambda p: llama.loss_fn(p, batch, cfg))
+
+        remat = step(remat=True, remat_mode=mode)
+        loss, grads = jax.jit(remat)(params)
+        loss_ref, grads_ref = jax.jit(step())(params)
+        np.testing.assert_allclose(loss, loss_ref, atol=1e-5)
+        for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(grads_ref)):
+            np.testing.assert_allclose(a, b, atol=1e-5)
+        names = [e.primitive.name
+                 for j in _jaxprs_in(jax.make_jaxpr(remat)(params).jaxpr,
+                                     skip=("pallas_call",))
+                 for e in j.eqns]
+        # un-checkpointed: 7 forward + 14 backward a layer, 3 at the head
+        assert names.count("dot_general") == 24 + recomputed_products
+        assert names.count("pallas_call") == pallas_calls
+
+
+def _jaxprs_in(jaxpr, skip=()):
+    """jaxpr and every jaxpr nested in its equations' params (but not in
+    those of the primitives named in `skip`)."""
     yield jaxpr
     for eqn in jaxpr.eqns:
+        if eqn.primitive.name in skip:
+            continue
         for val in eqn.params.values():
             for sub in val if isinstance(val, (list, tuple)) else [val]:
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    yield from _jaxprs_in(sub)
+                    yield from _jaxprs_in(sub, skip)
 
 
 def _flash_fwd_calls(fn, *args):
