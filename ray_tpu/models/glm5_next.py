@@ -84,7 +84,9 @@ positions, and the engine takes its tail and its merge from that shape.
 Not served: the multi-token-prediction layer (`num_nextn_predict_layers`)
 and the vision tower.
 
-Device-side names: `kda_in_proj`, `kda_conv`, `kda_scan` (the prefill
+Device-side names: `kda_in_proj`, `kda_conv` (in a prefill the kernel
+of that name: q, k, v from one pass over the projection; in a decode step
+an XLA expression), `kda_scan` (the prefill
 kernel: a head's state stays in VMEM across a row's chunks, position
 blocks past the row's true length get no step) / `kda_update` (the
 decode kernel), `kda_out`, `dsa_index`, `dsa_select`,

@@ -28,8 +28,12 @@ layer's weights: `norm1`, `w_qkv` [d, 3 H dk], `conv_w` [K, 3 H dk], the
 gate's (`wf1`, `wf2`, `A_log`, `dt_bias`, `w_beta`), `wg1`, `wg2`,
 `o_norm`, `wo` (`init_layer`).
 
-Device-side names: `kda_in_proj`, `kda_conv`, `kda_scan` (the prefill
-kernel) / `kda_update` (the decode kernel), `kda_out`.
+Device-side names: `kda_in_proj`, `kda_conv` (over whole rows the kernel
+of that name, `ops/kda.kda_conv`: the convolution, silu, the split and the
+unit length in one pass over the projection, and the gather of the rows a
+lane keeps; in a decode step the XLA expression `_qkv(_conv(...))`, one
+token a lane), `kda_scan` (the prefill kernel) / `kda_update` (the decode
+kernel), `kda_out`.
 """
 from __future__ import annotations
 
@@ -104,19 +108,20 @@ def _qkv(act, cfg):
 
 def inputs(h, lp, cfg, true_lens, gate):
     """Everything the scan takes, over whole rows h [b, T, d] (normed):
-    (q, k, v [b, T, H, dk] float32, g [b, T, H, dk], beta [b, T, H], both
-    ZERO past each row's true length, conv rows [b, K-1, 3 inner]: the
-    pre-convolution rows before each row's TRUE length)."""
-    b, T, _ = h.shape
-    K = cfg.conv_kernel
+    (q, k, v [b, T, H, dk] float32 as `_qkv(_conv(...))` has them, from
+    ONE pass over the projection (`ops/kda.kda_conv`), g [b, T, H, dk],
+    beta [b, T, H], all five ZERO at and past each row's true length,
+    conv rows [b, K-1, 3 inner]: the pre-convolution rows before each
+    row's TRUE length, zeros before position 0)."""
+    T, K = h.shape[1], cfg.conv_kernel
     with jax.named_scope("kda_in_proj"):
         proj = h @ lp["w_qkv"]
         g, beta = gate(h, lp, cfg)
     with jax.named_scope("kda_conv"):
-        xp = jnp.pad(proj, ((0, 0), (K - 1, 0), (0, 0)))
-        q, k, v = _qkv(_conv([xp[:, i:i + T] for i in range(K)], lp), cfg)
-        at = true_lens[:, None] + jnp.arange(K - 1)[None, :]
-        rows = jnp.take_along_axis(xp, at[..., None], axis=1)
+        q, k, v = kda.kda_conv(proj, lp["conv_w"], cfg.n_heads, true_lens)
+        at = true_lens[:, None] - (K - 1) + jnp.arange(K - 1)[None, :]
+        rows = jnp.where((at >= 0)[..., None], jnp.take_along_axis(
+            proj, jnp.maximum(at, 0)[..., None], axis=1), 0)
     live = jnp.arange(T)[None, :] < true_lens[:, None]
     return (q, k, v, jnp.where(live[..., None, None], g, 0.0),
             jnp.where(live[..., None], beta, 0.0), rows)
@@ -138,20 +143,22 @@ def prefill(x, lp, cfg, true_lens, gate, around=BARE,
     [b, H, dk, dv] in `state_dtype`)).  What follows the scan (the head
     norm, the gate, `wo`) is computed a position alone and walks the rows
     up to the longest true length (`live_rows.walk`): zeros past the
-    walked chunks.  What precedes it stays whole: it writes 24,576 + 4 x
-    8,192 columns a position, and walked, the copies of its chunks into
-    the buffers the loop carries cost more than the padding skipped
-    (PERF.md section 6, PR 55).  `around`: the halves of a residual path
-    (GLM's `mhc_halves`; `x + y` for a plain one), the second computed
-    inside the walk; x is then whatever the first takes and so is the
-    result."""
+    walked chunks.  What precedes it is not walked (walked, the copies of
+    its chunks into the buffers the loop carries cost more than the
+    padding skipped: PERF.md section 6, PR 55): the projection and the
+    gate stay whole, and `kda_conv` writes q, k, v in place a block of
+    positions at a time, zeros from a row's true length on, and fetches
+    no block wholly past it (PERF.md section 6, PR 60).  `around`: the
+    halves of a residual path (GLM's `mhc_halves`; `x + y` for a plain
+    one), the second computed inside the walk; x is then whatever the
+    first takes and so is the result."""
     enter, leave = around
     x_in, maps = enter(x)
     h = rmsnorm(x_in, lp["norm1"], cfg.norm_eps)
     q, k, v, g, beta, rows = inputs(h, lp, cfg, true_lens, gate)
     # the three conv rows a request are gathered BEFORE the scan: left to
     # the scheduler the gather came last in a program with the walks, and
-    # every KDA layer's padded projection (0.4 GB) lived to its end
+    # every KDA layer's projection (0.4 GB) lived to its end
     q, rows = lax.optimization_barrier((q, rows))
     o, state = kda.kda_scan(q, k, v, g, beta, cfg.kda_chunk, true_lens,
                             unbounded=unbounded)

@@ -54,7 +54,9 @@ lanes, K-1, 3 H dk] the last pre-convolution rows; "kda": [KDA layers,
 lanes, H, dk, dv] float32, updated in place by `kda_update`}`, beside a K
 and a V pool leaf a GQA layer.
 
-Device-side names: `kda_in_proj`, `kda_conv`, `kda_scan` (the prefill
+Device-side names: `kda_in_proj`, `kda_conv` (in a prefill the kernel
+of that name: q, k, v from one pass over the projection; in a decode step
+an XLA expression), `kda_scan` (the prefill
 kernel) / `kda_update` (the decode kernel), `kda_out`, `attn_qkv`, `attn`
 (`flash_fwd` / `paged_attn`), `gqa_gate`, `attn_out`, `moe_router`,
 `moe_experts` (the grouped matmul's kernel is `moe_gmm`),

@@ -1,5 +1,6 @@
 """Gated delta-rule linear attention with a per-channel decay (KDA, the
-Kimi Linear layer) for serving: the chunked scan a prefill runs and the
+Kimi Linear layer) for serving: the chunked scan a prefill runs, what
+forms its q, k and v from the layer's projection (`kda_conv`), and the
 one-step update a decode step runs.
 
 The recurrence.  A head keeps a state matrix S [dk, dv] in float32; for
@@ -96,6 +97,20 @@ crosses is computed whole).  The state returned IS the state at the true
 length.  All in float32 at `Precision.HIGHEST`: a lane keeps that state
 for hundreds of steps.  Interpret mode runs any shape; the chip takes
 what it can tile (`scan_tiles`).
+
+`kda_conv` (Pallas, `pallas_call(name="kda_conv")`): what the scan reads
+of a layer's projection `h W_qkv` [b, T, 3 H dk], in ONE pass over it:
+the short causal convolution down each column, silu, the split into
+heads, q and k of unit length.  It reads the bfloat16 projection once
+and writes q, k, v float32 once (1.21 GB at [1, 8192, 3 x 64 x 128]:
+1.84 ms on a v5e, 80 % of the memory's rate; no MXU), where the XLA
+expression wrote the convolved projection as float32 and read it back
+around the reduction (13.0 ms alone; PERF.md section 6, PR 60).  The
+grid is (row, a block of positions, a block of heads); a step takes the
+same block of each of the three sections and the sublane tile of rows
+before it (the convolution's reach), and writes its outputs at its own
+index: nothing is carried.  Zeros from a row's length on; a block
+wholly past it is not fetched.
 """
 from __future__ import annotations
 
@@ -114,6 +129,10 @@ _HI = lax.Precision.HIGHEST
 POSITIONS = 512     # a grid step's block of positions
 ROWS = 128          # a group: the chunks whose local parts are made at once
 HEADS = 4           # heads a grid step takes
+CONV_POSITIONS = 1024   # `kda_conv`: a grid step's block of positions,
+CONV_HEADS = 4          # its heads (of each of q, k and v),
+CONV_ROWS = 128         # the rows its body takes at once
+HALO = 16           # rows fetched before a block: a bfloat16 sublane tile
 
 
 def _interpret() -> bool:
@@ -389,19 +408,27 @@ def _scan_kernel(lens_ref,                            # scalar prefetch
         s_ref[0] = state[...]
 
 
-def heads_a_step(H: int) -> int:
+def heads_a_step(H: int, most: int | None = None) -> int:
     """The heads a grid step takes: the largest divisor of H that is at
-    most `HEADS`."""
-    return max(h for h in range(1, HEADS + 1) if H % h == 0)
+    most `most` (`HEADS`, where the call says nothing)."""
+    return max(h for h in range(1, (most or HEADS) + 1) if H % h == 0)
 
 
-def scan_tiles(dk: int, dv: int, chunk: int) -> None:
+def _held(lens, i, j, P: int):
+    """The position block a step (row i, block j) of blocks of P fetches:
+    its own, or past the row's length the block the length ends in (the
+    one the step before fetched: nothing is read)."""
+    return jnp.minimum(j, jnp.maximum(lens[i] - 1, 0) // P)
+
+
+def scan_tiles(dk: int, dv: int, chunk: int,
+               kernel: str = "kda_scan") -> None:
     """What the compiled kernel can tile: a head's block of the [b, T,
     H dk] views is a column block of whole lane tiles, a chunk whole
     sublane tiles.  (Interpret mode runs any shape.)"""
     if dk % 128 or dv % 128 or chunk % 8:
         raise ValueError(
-            f"kda_scan on the chip takes dk and dv multiples of 128 and "
+            f"{kernel} on the chip takes dk and dv multiples of 128 and "
             f"a chunk a multiple of 8; got dk={dk}, dv={dv}, "
             f"chunk={chunk}")
 
@@ -438,11 +465,8 @@ def kda_scan(q, k, v, g, beta, chunk: int, lengths=None,
     if lengths is None:
         lengths = jnp.full((b,), T, jnp.int32)
 
-    def held(i, j, lens):         # past the length: the block it ends in
-        return jnp.minimum(j, jnp.maximum(lens[i] - 1, 0) // P)
-
     def block(i, h, j, lens):
-        return (i, held(i, j, lens), h)
+        return (i, _held(lens, i, j, P), h)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -452,7 +476,7 @@ def kda_scan(q, k, v, g, beta, chunk: int, lengths=None,
                   pl.BlockSpec((1, P, heads * dv), block),
                   pl.BlockSpec((1, P, heads * dk), block),
                   pl.BlockSpec((1, P, H), lambda i, h, j, lens:
-                               (i, held(i, j, lens), 0))],
+                               (i, _held(lens, i, j, P), 0))],
         out_specs=[pl.BlockSpec((1, P, heads * dv),
                                 lambda i, h, j, lens: (i, j, h)),
                    pl.BlockSpec((1, heads, dk, dv),
@@ -472,6 +496,143 @@ def kda_scan(q, k, v, g, beta, chunk: int, lengths=None,
         interpret=interpret,
     )(lengths.astype(jnp.int32), q, k, v, g, beta)
     return o[:, :T].reshape(b, T, H, dv), S
+
+
+def _conv_kernel(lens_ref,                            # scalar prefetch
+                 xq_ref, xk_ref, xv_ref, bq_ref, bk_ref, bv_ref,
+                 wq_ref, wk_ref, wv_ref,
+                 q_ref, k_ref, v_ref, *, dk: int, R: int):
+    """One position block of `heads` heads of one row, for each of the
+    three sections of the projection: x*_ref [1, P, heads dk] (the
+    projection's dtype), b*_ref [1, HALO, heads dk] the rows before it,
+    w*_ref [K, heads dk] float32; q_ref, k_ref, v_ref [1, P, heads dk]
+    float32.  A head's [R, dk] at a time: the K - 1 shifted copies are
+    rolls down the sublanes of the rows with the 8 before them on top."""
+    i, j = pl.program_id(0), pl.program_id(1)
+    P, K = q_ref.shape[1], wq_ref.shape[0]
+    n = lens_ref[i]
+    live = j * P < n
+
+    @pl.when(live)
+    def _():
+        def rows(r, carry):
+            r0 = pl.multiple_of(r * R, R)
+            keep = (j * P + r0 + lax.broadcasted_iota(jnp.int32, (R, 1), 0)
+                    < n)
+            for x_ref, b_ref, w_ref, o_ref, scale in (
+                    (xq_ref, bq_ref, wq_ref, q_ref, dk ** -0.5),
+                    (xk_ref, bk_ref, wk_ref, k_ref, 1.0),
+                    (xv_ref, bv_ref, wv_ref, v_ref, None)):
+                for h in range(o_ref.shape[2] // dk):
+                    at = slice(h * dk, (h + 1) * dk)
+                    # the 8 rows before: the block's own, or (its first
+                    # rows) the block's before; zeros before position 0
+                    own = x_ref[0, pl.ds(pl.multiple_of(
+                        jnp.maximum(r0 - HALO, 0), HALO), HALO),
+                                at].astype(F32)
+                    before = jnp.where(
+                        r > 0, own,
+                        jnp.where(j > 0, b_ref[0, :, at].astype(F32), 0.0))
+                    x = jnp.concatenate(
+                        [before[HALO - 8:],
+                         x_ref[0, pl.ds(r0, R), at].astype(F32)])
+                    acc = x * w_ref[K - 1:K, at]
+                    for s in range(1, K):
+                        acc = acc + (pltpu.roll(x, s, 0)
+                                     * w_ref[K - 1 - s:K - s, at])
+                    act = jax.nn.silu(acc[8:])
+                    if scale is not None:             # unit length
+                        act = act * lax.rsqrt(jnp.sum(
+                            act * act, axis=1, keepdims=True) + 1e-6)
+                        if scale != 1.0:
+                            act = act * scale
+                    o_ref[0, pl.ds(r0, R), at] = jnp.where(keep, act, 0.0)
+            return carry
+
+        lax.fori_loop(0, P // R, rows, 0)
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        for o_ref in (q_ref, k_ref, v_ref):
+            o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def kda_conv(proj, conv_w, H: int, lengths=None):
+    """What `kda_scan` takes of a layer's projection, in one pass over it:
+    the short causal convolution down each column, silu, the split into
+    heads, q and k of unit length, q scaled by dk ** -0.5.
+
+    proj [b, T, 3 H dk] (q's columns, then k's, then v's; read ONCE, in
+    its own dtype); conv_w [K, 3 H dk], K - 1 <= 8 (row K - 1 multiplies
+    the position itself, row 0 the one K - 1 before; zeros before
+    position 0); `lengths` [b] int32 or None.  Returns q, k, v [b, T, H,
+    dk] float32, ZEROS at and past a row's length (a position block
+    wholly there is not fetched).  The grid is (row, a block of
+    `CONV_POSITIONS` positions, `CONV_HEADS` heads); a step writes its
+    three blocks at its own index and carries nothing.  Float32
+    throughout, the `1e-6` under the root `models/kda_layer._l2norm`'s.
+    Interpret mode runs any shape; the chip takes what it can tile
+    (`scan_tiles`)."""
+    b, T, width = proj.shape
+    K = conv_w.shape[0]
+    dk = width // (3 * H)
+    if K - 1 > 8:
+        raise ValueError(f"kda_conv takes a convolution of at most 9 "
+                         f"positions; got {K}")
+    interpret = _interpret()
+    if not interpret:
+        scan_tiles(dk, dk, HALO, "kda_conv")
+    heads = heads_a_step(H, CONV_HEADS)
+    P = min(CONV_POSITIONS, -(-T // HALO) * HALO)     # a position block
+    R = CONV_ROWS if P % CONV_ROWS == 0 else HALO
+    pad = -T % P
+    proj = jnp.pad(proj, ((0, 0), (0, pad), (0, 0)))
+    conv_w = conv_w.astype(F32)
+    if lengths is None:
+        lengths = jnp.full((b,), T, jnp.int32)
+    nh = H // heads                       # column blocks a section
+
+    def fetched(i, j, h, lens):
+        # at or past the length: the block the row's last live step read
+        return _held(lens, i, j, P), jnp.where(j * P < lens[i], h, nh - 1)
+
+    def block(section):
+        def index(i, j, h, lens):
+            at, col = fetched(i, j, h, lens)
+            return (i, at, section * nh + col)
+        return pl.BlockSpec((1, P, heads * dk), index)
+
+    def before(section):
+        def index(i, j, h, lens):
+            at, col = fetched(i, j, h, lens)
+            return (i, jnp.maximum(at * (P // HALO) - 1, 0),
+                    section * nh + col)
+        return pl.BlockSpec((1, HALO, heads * dk), index)
+
+    def weights(section):
+        return pl.BlockSpec((K, heads * dk), lambda i, j, h, lens:
+                            (0, section * nh + fetched(i, j, h, lens)[1]))
+
+    out = pl.BlockSpec((1, P, heads * dk), lambda i, j, h, lens: (i, j, h))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, (T + pad) // P, nh),
+        in_specs=([block(s) for s in range(3)]
+                  + [before(s) for s in range(3)]
+                  + [weights(s) for s in range(3)]),
+        out_specs=[out, out, out],
+    )
+    q, k, v = pl.pallas_call(
+        functools.partial(_conv_kernel, dk=dk, R=R),
+        name="kda_conv",
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((b, T + pad, H * dk), F32)] * 3,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=64 << 20),
+        interpret=interpret,
+    )(lengths.astype(jnp.int32), *[proj] * 6, *[conv_w] * 3)
+    return tuple(a[:, :T].reshape(b, T, H, dk) for a in (q, k, v))
 
 
 def _update_kernel(lanes_ref, layer_ref,              # scalar prefetch

@@ -1219,6 +1219,32 @@ def test_kda_scan_compiles_at_the_served_widths(one_chip, compiled_kernels,
         row // 8 if T % kda.POSITIONS == 0 else 6 * row)
 
 
+@pytest.mark.parametrize("b,T", [(1, 8192), (1, 2816), (2, 1024)])
+def test_kda_conv_compiles_at_the_served_widths(one_chip, compiled_kernels,
+                                                b, T):
+    """What precedes the scan in GLM-5.3-Flash's and Solar-Open2's KDA
+    layers: the bfloat16 projection [b, T, 3 x 64 x 128] under a
+    convolution of 4 positions, ONE kernel that writes q, k, v in the
+    [b, T, H dk] view `kda_scan` reads and nothing the size of either
+    beside them (the judges' row of 2,816 positions is no multiple of the
+    position block: the projection is padded and the outputs are cut)."""
+    from ray_tpu.ops import kda
+
+    def s(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    H, dk, K = 64, 128, 4
+    low, c = _compile(
+        lambda x, w, n: [a.reshape(b, T, H * dk)
+                         for a in kda.kda_conv(x, w, H, n)],
+        s((b, T, 3 * H * dk)), s((K, 3 * H * dk)), s((b,), jnp.int32))
+    assert low.as_text().count("tpu_custom_call") == 1
+    assert "kda_conv" in low.as_text()
+    proj = b * T * 3 * H * dk * 2
+    assert c.memory_analysis().temp_size_in_bytes < (
+        proj // 8 if T % kda.CONV_POSITIONS == 0 else 4 * proj)
+
+
 def test_dsa_attn_compiles_and_copies_no_pool(one_chip, compiled_kernels):
     """The sparse step's gather and kernel at the served widths: 513
     groups of 4 rows a lane gathered out of the latent pool into 2,176

@@ -6,14 +6,19 @@ the one-step kernel (interpret mode) against the token-by-token
 recurrence, at lengths that end mid-chunk, across position blocks, with
 blocks past a row's length that get no step, with lanes that hold no
 request.  (`tests/test_solar_open2.py` holds the exact form where only it
-stands: beta near 2 on repeated keys, decays of -30 a step.)"""
+stands: beta near 2 on repeated keys, decays of -30 a step.)  And
+`kda_conv`, what precedes the scan in one pass, against the XLA expression
+a decode step keeps (`models/kda_layer._qkv(_conv(...))`)."""
 from __future__ import annotations
+
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from ray_tpu.models import kda_layer
 from ray_tpu.ops import kda, ssm
 
 
@@ -257,3 +262,85 @@ def test_scan_cost_is_the_rows_in_and_out_and_the_chunks_products():
     assert flh - fl == 4 * 2 * 2 * C * C * d * 64 * 8192 / 32
     fl8h, _ = kda.scan_cost(1, 16, 16, 8, 8.0, halved=True)
     assert fl8h - fl8 == 2 * 4 * 8 * 8 * 16
+
+
+def _projection(b, T, H, dk, K, dtype, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 2)
+    return (jax.random.normal(ks[0], (b, T, 3 * H * dk)).astype(dtype),
+            (0.5 * jax.random.normal(ks[1], (K, 3 * H * dk))).astype(dtype))
+
+
+def _padded_rows(proj, K):
+    """The projection under K - 1 rows of zeros: what the convolution
+    reads before position 0."""
+    return jnp.pad(proj, ((0, 0), (K - 1, 0), (0, 0)))
+
+
+@pytest.mark.parametrize("T,lens,H,dk,K,P,heads,dtype", [
+    (40, [40, 27], 4, 16, 4, 16, 2, jnp.bfloat16),  # ragged; inside a block
+    (64, [32, 1], 4, 16, 4, 16, 2, jnp.bfloat16),   # at a block's edge; of 1
+    (48, [17, 33], 4, 16, 4, 16, 4, jnp.bfloat16),  # an edge in the reach
+    (50, [50, 18], 3, 16, 3, 32, 8, jnp.float32),   # T no multiple; K = 3
+    (7, None, 2, 8, 2, 1024, 4, jnp.bfloat16),      # a short row, whole
+    (160, [160, 130], 2, 128, 4, 128, 1, jnp.bfloat16),     # the served dk
+])
+def test_kda_conv_is_the_convolved_projection_split_and_of_unit_length(
+        monkeypatch, T, lens, H, dk, K, P, heads, dtype):
+    """q, k, v of ONE pass over the projection are `_qkv(_conv(...))` of
+    its shifted slices below each row's length and zeros from it on,
+    whatever the projection holds there (NaN here); blocks of P positions
+    (a block's first K - 1 positions read the block's before) and of
+    `heads` heads (two column blocks a section at H = 4 under 2)."""
+    monkeypatch.setattr(kda, "CONV_POSITIONS", P)
+    monkeypatch.setattr(kda, "CONV_HEADS", heads)
+    proj, conv_w = _projection(2, T, H, dk, K, dtype, seed=T)
+    xp = _padded_rows(proj, K)
+    want = kda_layer._qkv(
+        kda_layer._conv([xp[:, i:i + T] for i in range(K)],
+                        {"conv_w": conv_w}),
+        SimpleNamespace(n_heads=H, kda_head_dim=dk))
+    live = jnp.arange(T)[None, :] < jnp.asarray(lens or [T, T])[:, None]
+    got = jax.jit(lambda x, w, n: kda.kda_conv(x, w, H, n))(
+        jnp.where(live[..., None], proj, jnp.nan), conv_w,
+        None if lens is None else jnp.asarray(lens, jnp.int32))
+    for g, w in zip(got, want):
+        assert g.shape == (2, T, H, dk) and g.dtype == jnp.float32
+        for i, n in enumerate(lens or [T, T]):
+            assert float(jnp.max(jnp.abs(g[i, :n] - w[i, :n]))) < 2e-6
+            assert float(jnp.abs(g[i, n:]).sum()) == 0.0
+
+
+def test_kda_conv_names_what_it_cannot_take(monkeypatch):
+    proj, conv_w = _projection(1, 16, 2, 16, 10, jnp.bfloat16)
+    with pytest.raises(ValueError, match="at most 9 positions; got 10"):
+        kda.kda_conv(proj, conv_w, 2)
+    monkeypatch.setattr(kda, "_interpret", lambda: False)
+    with pytest.raises(ValueError, match="kda_conv on the chip.*dk=16"):
+        kda.kda_conv(proj, conv_w[:4], 2)
+
+
+@pytest.mark.parametrize("lens", [[0, 1], [2, 3], [4, 24]])
+def test_the_layers_inputs_hand_the_rows_before_each_true_length(lens):
+    """`kda_layer.inputs` hands the K - 1 pre-convolution rows before each
+    row's TRUE length, zeros standing for the rows before position 0 (a
+    length under K - 1), and g, beta zeroed from the length on."""
+    T, H, dk, K, d = 24, 2, 16, 4, 8
+    cfg = SimpleNamespace(n_heads=H, kda_head_dim=dk, conv_kernel=K)
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    lp = {"w_qkv": jax.random.normal(ks[0], (d, 3 * H * dk), jnp.bfloat16),
+          "conv_w": jax.random.normal(ks[1], (K, 3 * H * dk), jnp.bfloat16)}
+    h = jax.random.normal(ks[2], (2, T, d), jnp.bfloat16)
+
+    def gate(h, lp, cfg):
+        return (-jnp.ones(h.shape[:2] + (H, dk), jnp.float32),
+                jnp.ones(h.shape[:2] + (H,), jnp.float32))
+
+    n = jnp.asarray(lens, jnp.int32)
+    q, k, v, g, beta, rows = kda_layer.inputs(h, lp, cfg, n, gate)
+    xp = _padded_rows(h @ lp["w_qkv"], K)
+    assert rows.dtype == xp.dtype and rows.shape == (2, K - 1, 3 * H * dk)
+    for i, m in enumerate(lens):
+        assert bool(jnp.all(rows[i] == xp[i, m:m + K - 1]))
+        assert float(jnp.abs(g[i, m:]).sum() + jnp.abs(beta[i, m:]).sum()
+                     + jnp.abs(q[i, m:]).sum()) == 0.0
+        assert bool(jnp.all(g[i, :m] == -1.0) & jnp.all(beta[i, :m] == 1.0))
