@@ -22,7 +22,8 @@ _SERVING = {"LlamaConfig": "ray_tpu.models.llama",
             "NemotronHConfig": "ray_tpu.models.nemotron_h",
             "MimoV2Config": "ray_tpu.models.mimo_v2",
             "Cohere2MoeConfig": "ray_tpu.models.cohere2_moe",
-            "SolarOpen2Config": "ray_tpu.models.solar_open2"}
+            "SolarOpen2Config": "ray_tpu.models.solar_open2",
+            "MiniCpmSalaConfig": "ray_tpu.models.minicpm_sala"}
 
 
 def serving_model(cfg):

@@ -3294,7 +3294,8 @@ class LLMEngine:
 
     def _cache_stats(self) -> dict:
         """The page pool by what it holds: a word for it ("kv": a K and
-        a V pool; else its first entry's name), the bytes a token's rows
+        a V pool, whatever grouped rows ride beside them; else its first
+        entry's name), the bytes a token's rows
         take in one layer as stored, the layers that keep any, and the
         pool's bytes."""
         pool = _pool(self.cache)
@@ -3306,7 +3307,7 @@ class LLMEngine:
                    "pool_bytes": int(sum(a.size * a.dtype.itemsize
                                          for a in v))}
             for name, v in pool.items()}
-        return {"kind": ("kv" if list(pool) == ["k", "v"]
+        return {"kind": ("kv" if list(pool)[:2] == ["k", "v"]
                          else next(iter(pool))),
                 # a TOKEN's bytes: a row shared by g positions counts 1/g
                 "row_bytes": int(sum(

@@ -67,6 +67,8 @@ LONG_CASES = {
     "copies_no_ring_or_pool": 83,
     "tests/test_chip_compile.py::test_served_mimo_engine_fits_one_chip_and_"
     "copies_no_ring_or_pool": 82,
+    "tests/test_bench_families.py::test_the_sala_cell_rehearses_on_the_cpu":
+        75,
     "tests/test_chip_compile.py::test_served_granite_engine_fits_one_chip":
         72,
     "tests/test_chip_compile.py::test_served_command_a_plus_fits_one_chip_"

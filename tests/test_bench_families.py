@@ -26,6 +26,7 @@ from benchmarks.tests.test_mimo_v2_family import *  # noqa: F401,F403
 from benchmarks.tests.test_prefill_walked_reader import *  # noqa: F401,F403
 from benchmarks.tests.test_cohere2_moe_family import *  # noqa: F401,F403
 from benchmarks.tests.test_solar_open2_family import *  # noqa: F401,F403
+from benchmarks.tests.test_minicpm_sala_family import *  # noqa: F401,F403
 
 
 def test_the_dots3_cell_is_found_by_its_files(dots_cell, monkeypatch):
@@ -70,22 +71,23 @@ def _without_what_was_added(monkeypatch, *added):
 
 PR_54 = (COHERE_CELL, COHERE_CONFIG, COHERE_NEW_METRICS)        # noqa: F405
 PR_58 = (SOLAR_CELL, SOLAR_CONFIG, SOLAR_NEW_METRICS)           # noqa: F405
+PR_61 = (SALA_CELL, SALA_CONFIG, SALA_NEW_METRICS)              # noqa: F405
 
 
 def test_the_mimo_cell_is_found_by_its_files(monkeypatch):
-    _without_what_was_added(monkeypatch, PR_54, PR_58)
+    _without_what_was_added(monkeypatch, PR_54, PR_58, PR_61)
     _mimo.test_the_mimo_cell_is_found_by_its_files(
         spec.load_cell(_mimo.MIMO_CELL))
 
 
 def test_the_walked_factor_is_found_by_its_files(monkeypatch):
-    _without_what_was_added(monkeypatch, PR_54, PR_58)
+    _without_what_was_added(monkeypatch, PR_54, PR_58, PR_61)
     _walked.test_the_walked_factor_is_found_by_its_files()
 
 
 def test_the_cohere_cell_is_found_by_its_files(monkeypatch):
     """PR 58's cell reports `kernel.flash_fwd_roofline.closed` beside the
     one the case knew."""
-    _without_what_was_added(monkeypatch, PR_58)
+    _without_what_was_added(monkeypatch, PR_58, PR_61)
     _cohere.test_the_cohere_cell_is_found_by_its_files(
         spec.load_cell(COHERE_CELL))                            # noqa: F405

@@ -1991,3 +1991,143 @@ def test_served_solar_open2_fits_one_chip_and_copies_no_state_or_pool(
                     and "paged_attn" in ln]) == 1       # the GQA layer's
         assert len([ln for ln in loop if "custom-call(" in ln
                     and "moe_gmm" in ln]) == 8          # two a routed layer
+
+
+def test_bsa_attn_compiles_at_the_served_widths(one_chip, compiled_kernels):
+    """MiniCPM-SALA's decode step at the served widths (32 lanes, 2 kv
+    heads x 16 query heads x 128) under the benchmark's table of 66
+    columns: `bsa_index` scores and selects a lane's 528 blocks, the
+    selection is a bias a row and `bsa_attn` walks the lane's pages."""
+    from ray_tpu.ops import block_sparse_attention as bsa
+
+    shape = bsa.Shape()
+
+    def s(shape_, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape_, dtype, sharding=one_chip)
+
+    B, n_pages, maxp = 32, 2113, 66
+    low, c = _compile(
+        lambda *a: bsa.decode_attention(*a, shape, sm_scale=128 ** -0.5),
+        s((B, 2, 16, 128)), s((n_pages, 2, 512, 128)),
+        s((n_pages, 2, 512, 128)), s((n_pages, 2, 32, 128)),
+        s((B, 2, 8, 128)), s((B, 2, 8, 128)), s((B, 2, 1, 128)),
+        s((B, maxp), jnp.int32), s((B,), jnp.int32), s((B,), jnp.int32))
+    txt = low.as_text()
+    for kern in ("bsa_index", "bsa_attn", "paged_attn"):
+        assert txt.count(kern) >= 1, kern
+    # the pool is not copied
+    assert not _pool_copies(c.as_text(), n_pages * 2 * 512 * 128)
+
+
+@pytest.mark.parametrize("T", [32768,      # the cell's one bucket
+                               16512])     # the judge's sample: no whole
+#                                            number of query blocks
+def test_bsa_index_compiles_at_the_served_widths(one_chip, compiled_kernels,
+                                                 T):
+    """A prompt's scores and selection, 256 queries of a kv head's 16
+    heads a step against its T / 16 stride rows: a bit a (query, block)
+    leaves the kernel, the mask `bsa_prefill` reads, and nothing of [T, 16,
+    T / 16] is written."""
+    from ray_tpu.ops import block_sparse_attention as bsa
+
+    shape = bsa.Shape()
+
+    def s(shape_, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape_, dtype, sharding=one_chip)
+
+    low, c = _compile(
+        lambda q, m, lens: bsa.prefill_select(q, m, lens, shape,
+                                              128 ** -0.5),
+        s((1, 2, 16, T, 128)), s((1, 2, T // 16, 128)), s((1,), jnp.int32))
+    assert low.as_text().count("bsa_index") >= 1
+    mem = c.memory_analysis()
+    assert mem.output_size_in_bytes == 2 * T * bsa.blocks_padded(
+        T // 16, shape)                                   # int8
+    # (the stride rows re-laid; q padded where T is no whole query blocks)
+    assert mem.temp_size_in_bytes < (0.05e9 if T % 256 == 0 else 0.5e9)
+
+
+def test_bsa_prefill_compiles_at_the_served_widths(one_chip,
+                                                   compiled_kernels):
+    """One 32,768-row prompt, 16 query heads a kv head a step: the mask a
+    bit a (query, block), widened inside the kernel."""
+    from ray_tpu.ops import block_sparse_attention as bsa
+
+    shape = bsa.Shape()
+    assert bsa.prefill_blocks(32768, shape) == (128, 512)
+    T = 32768
+
+    def s(shape_, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape_, dtype, sharding=one_chip)
+
+    low, c = _compile(
+        lambda q, k, v, mask, lens: bsa.prefill_attention(
+            q, k, v, mask, lens, shape, sm_scale=128 ** -0.5),
+        s((1, 2, 16, T, 128)), s((1, T, 2, 128)), s((1, T, 2, 128)),
+        s((1, 2, T, T // 64), jnp.int8), s((1,), jnp.int32))
+    assert low.as_text().count("bsa_prefill") >= 1
+    # q and o transposed once each, the mask as bytes: nothing T x T
+    assert c.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+@pytest.mark.time_limit(600)
+def test_served_minicpm_sala_fits_one_chip_and_copies_no_state_or_pool(
+        topo, one_chip, compiled_kernels, monkeypatch):
+    """minicpm-sala-9b-d4 as the benchmark serves it (one period S L L L
+    at the published widths, 32 lanes, 2,113 pages): the decode program
+    and the ONE prefill program its traffic reaches (1 x 32,768) compile
+    for one chip beside 4.77 GB of weights, lane state and pools; inside
+    the K-step loop the lanes' state matrices (0.20 GB) are touched by
+    `ssm_update` alone, which aliases them, and no pool is copied."""
+    monkeypatch.setattr(jax, "devices", lambda *a: list(topo.devices))
+    cfg, eng, lows = _glm_lowerings(one_chip, [(1, 32768)],
+                                    "minicpm-sala-9b-d4")
+    lane = eng.stats()["lane_state"]
+    assert lane["layers"] == 3
+    assert lane["by_kind"] == {"lightning": 3 * 32 * 128 * 4096 * 4,
+                               "kpart": 32 * 256 * 4}
+    cache = eng._cache_stats()
+    assert (cache["kind"], cache["layers"], cache["row_bytes"]) == (
+        "kv", 1, 2 * 2 * 128 * 2 + 512 // 16)
+    assert cache["pool_bytes"] == 2113 * (512 * 1024 + 32 * 512)
+    weights = sum(math.prod(a.shape) * a.dtype.itemsize
+                  for a in jax.tree.leaves(eng.params))
+    assert weights == 2 * 1_711_216_000
+    resident = weights + lane["bytes"] + cache["pool_bytes"]
+    assert 4.75e9 < resident < 4.78e9           # 28 % of the chip
+    # one row of 32,768 is the only program at that bucket
+    # (serve/prefill_plan.PREFILL_MAX_TOKENS)
+    assert eng._spec.prefill_state_bytes == 3 * 32 * 128 * 128 * 4 + 1024
+    kernels = {"decode_k8": ("ssm_update", "bsa_index", "bsa_attn",
+                             "paged_attn"),
+               "prefill_w1_p32768": ("bsa_index", "bsa_prefill")}
+    for name, low in lows.items():
+        txt = low.as_text()
+        for kern in kernels[name]:
+            assert kern in txt, (name, kern)
+        c = low.compile()
+        mem = c.memory_analysis()
+        held = resident + mem.temp_size_in_bytes + (
+            mem.output_size_in_bytes - mem.alias_size_in_bytes)
+        print(f"{name}: temps {mem.temp_size_in_bytes / 1e9:.2f} GB, "
+              f"resident {resident / 1e9:.2f} GB, held {held / 1e9:.2f} GB")
+        assert held < 16.9e9 - 1.0e9, (name, held)
+        hlo = c.as_text()
+        if name != "decode_k8":
+            assert "flash_fwd" not in txt       # past dense_len
+            # a walk after each mixer and one for each SwiGLU
+            assert len(_loops_of(hlo, "/live_rows/")) == 2 * cfg.n_layers
+            # a quarter of the chip with what is resident, and no more
+            # than the scan's float32 arrays ask
+            assert 0.25 * 16.9e9 < held and mem.temp_size_in_bytes < 4.5e9
+            continue
+        layer_state = 32 * 128 * 4096
+        found = weight_sized_writes(hlo, layer_state)
+        assert found and all(op == "custom-call" and "ssm_update" in scope
+                             for _, op, scope in found), found
+        assert mem.alias_size_in_bytes >= lane["bytes"] + cache["pool_bytes"]
+        assert mem.temp_size_in_bytes < 0.1e9
+        loop = _loop_lines(hlo)
+        assert len([ln for ln in loop if "custom-call(" in ln
+                    and "ssm_update" in ln]) == 3   # a call a lightning layer
+        assert not _pool_copies(hlo, 2113 * 2 * 512 * 128)
